@@ -49,12 +49,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-if "xla_force_host_platform_device_count" not in os.environ.get(
-        "XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                               + " --xla_force_host_platform_device_count=8")
-
 METRIC = ("autoscale A/B: aggregate samples/sec + SLO attainment, "
           "policy off vs act (churning 3-tenant mix, 2-executor carve)")
 OUT_PATH = os.path.join(
@@ -277,4 +271,13 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    # a CPU-mesh benchmark: run standalone it opens no accelerator and
+    # carves its tenants out of 8 virtual CPU devices (importers keep
+    # their own platform — nothing is set at import)
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    if "xla_force_host_platform_device_count" not in os.environ.get(
+            "XLA_FLAGS", ""):
+        os.environ["XLA_FLAGS"] = (
+            os.environ.get("XLA_FLAGS", "")
+            + " --xla_force_host_platform_device_count=8")
     main()

@@ -135,6 +135,9 @@ def _spawn_standalone_service(cache_mb: int = 768, pin_cores=None):
     (HARMONY_INPUT_PIN_CORES — input capacity scaled separately from
     the trainers', which is the point of disaggregating)."""
     env = dict(os.environ)
+    # a host-side process: it must never reach for an accelerator the
+    # caller's process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env.setdefault("HARMONY_INPUT_CACHE_MB", str(cache_mb))
     if pin_cores:
         env["HARMONY_INPUT_PIN_CORES"] = ",".join(str(c) for c in pin_cores)
@@ -303,6 +306,9 @@ def run_service_bench(
                 "endpoint": list(endpoint) if endpoint else None,
             }
             wenv = dict(os.environ)
+            # host-side probe: tenant trainers run on the CPU backend,
+            # whatever accelerator the caller's process holds
+            wenv["JAX_PLATFORMS"] = "cpu"
             # hold ~3 epochs of fetched batches (live epoch + the
             # prespawned next + slack): an undersized client cache
             # evicts live entries and turns shared reads into misses

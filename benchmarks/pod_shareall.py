@@ -20,8 +20,6 @@ are not comparable across sessions). Writes
 benchmarks/POD_SHAREALL_<suffix>.json and prints one JSON line.
 
 Run: python benchmarks/pod_shareall.py [suffix]   (default r05)
-NOTE: pause bin/watch_chip.sh first — its jax-importing probes spike
-1-core CPU walls (ROUNDLOG round-3 note).
 """
 import json
 import os

@@ -45,8 +45,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
@@ -351,4 +349,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    # a CPU-backend benchmark: run standalone it opens no accelerator
+    # (importers keep their own platform — nothing is set at import)
+    jax.config.update("jax_platforms", "cpu")
     main()

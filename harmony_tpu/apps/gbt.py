@@ -51,6 +51,7 @@ import numpy as np
 
 from harmony_tpu.config.params import TableConfig
 from harmony_tpu.dolphin.trainer import Trainer
+from harmony_tpu.utils.platform import trace_is_tpu
 
 
 class GBTTrainer(Trainer):
@@ -90,14 +91,11 @@ class GBTTrainer(Trainer):
         self.step_size = step_size
         self.leaf_min_size = leaf_min_size
         # Histogram build strategy: "scatter" = XLA scatter-add; "matmul" =
-        # one-hot matmul (the harmony_tpu.ops Pallas kernel — MXU-bound,
-        # the TPU-fast path); "auto" picks matmul on TPU.
+        # one-hot matmul (the harmony_tpu.ops Pallas kernel on TPUs —
+        # MXU-bound, the TPU-fast path — and its XLA reference elsewhere);
+        # "auto" picks matmul when the step is traced for TPUs.
         if hist_mode not in ("auto", "scatter", "matmul"):
             raise ValueError(f"unknown hist_mode {hist_mode!r}")
-        if hist_mode == "auto":
-            from harmony_tpu.utils.platform import tpu_backend
-
-            hist_mode = "matmul" if tpu_backend() else "scatter"
         self.hist_mode = hist_mode
         # Full binary tree, levels 0..max_depth (ref: treeSize from treeMaxDepth).
         self.num_nodes = 2 ** (max_depth + 1) - 1
@@ -198,13 +196,19 @@ class GBTTrainer(Trainer):
                 hreps = jnp.broadcast_to(h_eff[:, None, :], (E, F, K)).reshape(-1, K)
                 creps = jnp.broadcast_to(live, (E, F)).reshape(-1)
                 nb = n_level * F * Bn
-                if self.hist_mode == "matmul":
+                on_tpu = trace_is_tpu()
+                if self.hist_mode == "matmul" or (
+                        self.hist_mode == "auto" and on_tpu):
                     # ONE MXU one-hot matmul builds g, h and count together
                     # (harmony_tpu.ops.weighted_histogram Pallas kernel).
-                    from harmony_tpu.ops import weighted_histogram
+                    from harmony_tpu.ops.histogram import (
+                        weighted_histogram,
+                        xla_histogram,
+                    )
 
                     stats = jnp.concatenate([reps, hreps, creps[:, None]], axis=1)
-                    hist = weighted_histogram(flat, stats, nb)
+                    hist = (weighted_histogram if on_tpu
+                            else xla_histogram)(flat, stats, nb)
                     hg, hh, hc = hist[:, :K], hist[:, K : 2 * K], hist[:, 2 * K]
                 else:
                     # ONE flat scatter-add per statistic.

@@ -187,8 +187,9 @@ def build_config(app: str, args: argparse.Namespace) -> JobConfig:
         # over (load_text_tokens shares those names). Args the file loader
         # does NOT take (e.g. seed) fail HERE, not mid-job. STATIC key set:
         # importing the models package (jax) into this otherwise-thin TCP
-        # submit path would cost seconds and touch the accelerator plugin;
-        # a test pins the set against the real signature.
+        # submit path would cost seconds and open the accelerator the
+        # jobserver process owns; a test pins the set against the real
+        # signature.
         user["data_fn"] = "harmony_tpu.models.transformer:load_text_tokens"
         stray = set(user["data_args"]) - FILE_CORPUS_KEYS
         if stray:
@@ -475,15 +476,6 @@ def main(argv: List[str] | None = None) -> int:
 
     args = ap.parse_args(argv)
 
-    if args.cmd in ("start-jobserver", "start-pod", "run", "dashboard"):
-        # JAX_PLATFORMS=cpu must mean cpu even where an accelerator
-        # plugin hijacks backend init (and hangs on a wedged transport)
-        # — same entry-point rule the benchmarks follow. ONLY the
-        # jax-using commands: the thin TCP submit/status path must never
-        # import jax (platform.py imports it at module top).
-        from harmony_tpu.utils.platform import mirror_env_platform_request
-
-        mirror_env_platform_request()
     if args.cmd == "start-jobserver":
         return _cmd_start_jobserver(args)
     if args.cmd == "start-pod":
@@ -559,14 +551,15 @@ def _chkp_root_of(args: argparse.Namespace) -> "str | None":
 
 
 def _make_server(num_executors: int, dashboard_url=None, chkp_root=None):
-    from harmony_tpu.jobserver.server import JobServer
-    from harmony_tpu.utils.devices import discover_devices
+    """The in-process JobServer over this process's devices — the one
+    process that opens the accelerator (clients stay jax-free)."""
+    import jax
 
-    # Bounded discovery: a wedged accelerator transport (dead tunnel to a
-    # remote chip) hangs jax.devices() forever inside backend init; the CLI
-    # must fail with a diagnosis instead.
-    devices = discover_devices()
-    n = num_executors or len(devices)
+    from harmony_tpu.jobserver.server import JobServer
+    from harmony_tpu.utils.compcache import enable_compile_cache
+
+    enable_compile_cache()
+    n = num_executors or len(jax.devices())
     server = JobServer(num_executors=n, dashboard_url=dashboard_url,
                        chkp_root=chkp_root)
     server.start()
@@ -1282,11 +1275,14 @@ def _cmd_start_jobserver_ha(args: argparse.Namespace) -> int:
                or _socket.gethostname())
 
     def factory():
-        from harmony_tpu.jobserver.server import JobServer
-        from harmony_tpu.utils.devices import discover_devices
+        import jax
 
-        devices = discover_devices()
-        return JobServer(num_executors=args.num_executors or len(devices),
+        from harmony_tpu.jobserver.server import JobServer
+        from harmony_tpu.utils.compcache import enable_compile_cache
+
+        enable_compile_cache()
+        return JobServer(num_executors=args.num_executors
+                         or len(jax.devices()),
                          dashboard_url=args.dashboard_url,
                          chkp_root=_chkp_root_of(args))
 
@@ -1340,6 +1336,9 @@ def _cmd_start_pod(args: argparse.Namespace) -> int:
 
     import jax
 
+    from harmony_tpu.utils.compcache import enable_compile_cache
+
+    enable_compile_cache()  # after distributed init: it opens the backend
     n_exec = args.num_executors or len(jax.devices())
     if pid == 0:
         from harmony_tpu.jobserver.pod import PodJobServer

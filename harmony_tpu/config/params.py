@@ -180,7 +180,7 @@ class TrainerParams(ConfigBase):
     # Comm/comp split probe period in epochs (WorkerTasklet._probe_comm —
     # the fused-mode analogue of the reference's per-op pull/push timers,
     # ModelAccessor.java:33-49). Each probe costs several BLOCKING device
-    # round-trips, which on a remote-attached chip is real wall time: jobs
+    # round-trips, which is real wall time: jobs
     # that feed an elasticity optimizer want 1; latency-sensitive jobs can
     # raise the period or disable with 0 (the last split stays in effect).
     comm_probe_period: int = 1

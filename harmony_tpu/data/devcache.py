@@ -11,8 +11,8 @@ keyed by the DATA SOURCE identity (generator/loader dotted path + args):
     a job does not regenerate/reload 100s of MB, and so every job with the
     same source sees the SAME dataset by definition;
   * this module's byte-bounded device cache of per-batch/stacked device
-    arrays, so the host->device transfer happens once — on a
-    remote-attached chip that transfer is seconds per submission.
+    arrays, so the host->device transfer happens once, not once per
+    submission.
 
 Cached device arrays are read-only by contract: training steps never donate
 batch arguments (only the table state), so a cached buffer is never

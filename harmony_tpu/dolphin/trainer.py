@@ -142,8 +142,7 @@ class Trainer:
         table/mesh/batch signatures) reuse each other's compiled step
         programs across submissions (runtime/progcache) — the long-running
         JobServer's resubmit-the-same-app pattern stops paying a recompile
-        per job, which on a remote-attached accelerator dominates short
-        jobs.
+        per job, which can dominate short jobs.
 
         Contract: the signature must determine everything the trainer's
         traced functions — ``compute``/``compute_with_local``,

@@ -370,14 +370,17 @@ class LedgerStore:
 
 
 def _peak_flops() -> Optional[float]:
-    """Chip peak bf16 FLOP/s, or None off-TPU / before backend init.
-    Lazy + guarded: the ledger must stay importable (and queryable) on a
-    box with no accelerator stack at all."""
+    """Chip peak bf16 FLOP/s, or None off-TPU. Lazy + guarded: the ledger
+    must stay importable (and queryable) on a box with no accelerator
+    stack at all — but a TPU whose device_kind has no peaks row raises
+    (utils.platform.chip_peaks), it is not quietly an unknown."""
     try:
         from harmony_tpu.utils.platform import peak_bf16_flops
-
+    except ImportError:
+        return None
+    try:
         return peak_bf16_flops()
-    except Exception:
+    except RuntimeError:  # no backend could be initialized
         return None
 
 

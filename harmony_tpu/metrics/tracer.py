@@ -36,27 +36,9 @@ class Tracer:
         if self._t0 is None:
             raise RuntimeError("record() without start()")
         if block_on is not None:
-            # NARROW import guard: only "utils.platform itself is absent"
-            # is tolerable (a stripped-down install without the jax-side
-            # helpers). Failures INSIDE the module — its own jax import
-            # failing (ImportError named "jax"), hard_sync renamed away
-            # (AttributeError from the attribute access below) — are real
-            # and must surface, not silently skip the sync and
-            # mis-attribute device time to the next phase. Module import
-            # + attribute access, NOT from-import: a from-import of a
-            # missing symbol raises ImportError named after the MODULE,
-            # indistinguishable from the module being absent.
-            import importlib
+            import jax
 
-            try:
-                _platform = importlib.import_module(
-                    "harmony_tpu.utils.platform")
-            except ImportError as e:  # pragma: no cover - stripped install
-                if e.name != "harmony_tpu.utils.platform":
-                    raise
-                _platform = None
-            if _platform is not None:
-                _platform.hard_sync(block_on)  # real sync on lazy backends
+            jax.block_until_ready(block_on)
         dt = time.perf_counter() - self._t0
         self.total_sec += dt
         self.count += 1

@@ -1,6 +1,8 @@
 """Numeric primitives shared by the model families (LM, ViT)."""
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -40,9 +42,33 @@ def flash_ok(seq: int, block: int | None = None) -> bool:
 
 
 def resolve_attn(attn: str, seq: int, block: int | None = None) -> str:
-    """'auto' -> 'flash' on TPU when the kernel can tile, else 'blockwise'."""
+    """'auto' -> 'flash' when the program being traced runs on TPUs
+    (utils.platform.trace_is_tpu) and the kernel can tile, else
+    'blockwise'. Call at trace time."""
     if attn != "auto":
         return attn
-    from harmony_tpu.utils.platform import tpu_backend
+    from harmony_tpu.utils.platform import trace_is_tpu
 
-    return "flash" if tpu_backend() and flash_ok(seq, block) else "blockwise"
+    return "flash" if trace_is_tpu() and flash_ok(seq, block) else "blockwise"
+
+
+def flash_on_mesh(q, k, v, **kw):
+    """:func:`harmony_tpu.ops.flash_attention` on [B, H, S, D] operands,
+    split over the traced mesh's data axis when there is one: a
+    pallas_call is opaque to the GSPMD partitioner, which would otherwise
+    all-gather the batch-sharded operands and run the whole attention on
+    every chip."""
+    from jax.sharding import PartitionSpec as P
+
+    from harmony_tpu.ops.attention import flash_attention
+    from harmony_tpu.parallel.mesh import DATA_AXIS
+    from harmony_tpu.utils.platform import trace_mesh
+
+    fn = functools.partial(flash_attention, **kw)
+    mesh = trace_mesh()
+    if mesh is None or mesh.devices.size == 1:
+        return fn(q, k, v)
+    data = mesh.shape.get(DATA_AXIS, 1)
+    spec = P(DATA_AXIS) if data > 1 and q.shape[0] % data == 0 else P()
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)

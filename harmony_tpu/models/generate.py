@@ -5,8 +5,7 @@ buffer per layer (XLA wants fixed shapes), each step writes its keys/
 values at the current position with `dynamic_update_slice` and attends
 over the whole buffer under a position mask, and the generation loop is
 one `lax.scan` — a single compiled program for the entire continuation,
-no per-token host round-trips (which on a remote-attached chip would
-cost a network RTT per token).
+no per-token host round-trips.
 
 Decode is memory-bound (one query row), so attention here is a plain
 masked softmax over the cache — the flash kernel's tiling buys nothing

@@ -34,7 +34,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from harmony_tpu.ops.attention import blockwise_attention, flash_attention
+from harmony_tpu.ops.attention import blockwise_attention
 from harmony_tpu.ops.ring import ring_attention
 from harmony_tpu.ops.ulysses import a2a_attention
 from harmony_tpu.parallel.mesh import DATA_AXIS, SEQ_AXIS
@@ -187,12 +187,12 @@ class TransformerLM:
             sp = a2a_attention if cfg.sp_attn == "a2a" else ring_attention
             return sp(q, k, v, axis_name=axis_name, causal=True)
         S = q.shape[2]
-        from harmony_tpu.models.common import resolve_attn
+        from harmony_tpu.models.common import flash_on_mesh, resolve_attn
 
         attn = resolve_attn(cfg.attn, S, block=128)  # matches blocks below
         if attn == "flash":
-            return flash_attention(q, k, v, causal=True,
-                                   block_q=min(128, S), block_k=min(128, S))
+            return flash_on_mesh(q, k, v, causal=True,
+                                 block_q=min(128, S), block_k=min(128, S))
         return blockwise_attention(q, k, v, causal=True)
 
     def _block(self, x, layer, axis_name: Optional[str],

@@ -24,12 +24,13 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from harmony_tpu.models.common import (
     dense_init,
+    flash_on_mesh,
     resolve_attn,
     rms_norm,
     validate_attn,
 )
 from harmony_tpu.models.pytree_trainer import PyTreeTrainer
-from harmony_tpu.ops import blockwise_attention, flash_attention
+from harmony_tpu.ops import blockwise_attention
 from harmony_tpu.parallel.mesh import DATA_AXIS
 
 
@@ -107,7 +108,7 @@ class ViT:
 
     def _attend(self, q, k, v):
         attn = resolve_attn(self.cfg.attn, self.cfg.seq)
-        fn = flash_attention if attn == "flash" else blockwise_attention
+        fn = flash_on_mesh if attn == "flash" else blockwise_attention
         return fn(q, k, v, causal=False)
 
     def apply(self, params, images: jnp.ndarray) -> jnp.ndarray:

@@ -1,7 +1,10 @@
 """ctypes bindings for the C++ runtime pieces in ``native/``.
 
-Lazy build-on-first-use (g++ -O3 -shared -fPIC, cached by source mtime),
-graceful degradation: every caller checks :func:`available` and falls back
+Lazy build-on-first-use (g++ -O3 -shared -fPIC; the built library's file
+name carries the SHA-256 of the source it was built from, so a library is
+only ever loaded for the source content it matches — mtimes, which a copy
+of the tree does not preserve, are never consulted), graceful
+degradation: every caller checks :func:`available` and falls back
 to its pure-Python path, and ``HARMONY_TPU_NO_NATIVE=1`` disables the
 native layer outright (for debugging or g++-less environments).
 
@@ -22,8 +25,9 @@ from typing import Optional, Tuple
 import numpy as np
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-_SRC = os.path.join(_REPO_ROOT, "native", "harmony_native.cc")
-_LIB = os.path.join(_REPO_ROOT, "native", "libharmony_native.so")
+_NATIVE_DIR = os.path.join(_REPO_ROOT, "native")
+_SRC = os.path.join(_NATIVE_DIR, "harmony_native.cc")
+_LIB_PREFIX = "libharmony_native-"
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -47,15 +51,39 @@ class BlockCorruptError(IOError):
     """A block file failed its CRC32 check (torn write / bit rot)."""
 
 
-def _build() -> bool:
-    os.makedirs(os.path.dirname(_LIB), exist_ok=True)
-    cmd = ["g++", "-O3", "-shared", "-fPIC", "-pthread", "-o", _LIB, _SRC,
+def _lib_path() -> str:
+    """Where the library built from the CURRENT source content lives."""
+    import hashlib
+
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_NATIVE_DIR, f"{_LIB_PREFIX}{digest}.so")
+
+
+def _build(lib: str) -> bool:
+    """Compile the source to ``lib`` (atomically: a concurrent loader sees
+    the whole file or none) and drop libraries built from other content."""
+    tmp = f"{lib}.{os.getpid()}.tmp"
+    cmd = ["g++", "-O3", "-shared", "-fPIC", "-pthread", "-o", tmp, _SRC,
            "-lz"]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        return True
-    except (subprocess.SubprocessError, FileNotFoundError):
+        os.replace(tmp, lib)
+    except (subprocess.SubprocessError, OSError):
+        try:
+            os.unlink(tmp)
+        except OSError:
+            pass
         return False
+    for name in os.listdir(_NATIVE_DIR):
+        stale = os.path.join(_NATIVE_DIR, name)
+        if (name.startswith(_LIB_PREFIX) and name.endswith(".so")
+                and stale != lib):
+            try:
+                os.unlink(stale)
+            except OSError:
+                pass
+    return True
 
 
 def _load() -> Optional[ctypes.CDLL]:
@@ -68,12 +96,11 @@ def _load() -> Optional[ctypes.CDLL]:
             return None
         if not os.path.exists(_SRC):
             return None
-        stale = (not os.path.exists(_LIB)
-                 or os.path.getmtime(_LIB) < os.path.getmtime(_SRC))
-        if stale and not _build():
+        path = _lib_path()
+        if not os.path.exists(path) and not _build(path):
             return None
         try:
-            lib = ctypes.CDLL(_LIB)
+            lib = ctypes.CDLL(path)
         except OSError:
             return None
         lib.ht_crc32.restype = ctypes.c_uint32

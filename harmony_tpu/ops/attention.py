@@ -45,14 +45,10 @@ def _apply_causal_mask(s, iq, ik, block_q, block_k):
     return jnp.where(row >= col, s, _NEG_INF)
 
 
-def _resolve_defaults(q, scale, interpret):
-    """One source of truth for the scale/interpret defaults used by the
-    primal forward, the VJP forward and the VJP backward."""
-    scale = scale if scale is not None else q.shape[-1] ** -0.5
-    from harmony_tpu.utils.platform import tpu_backend
-
-    interp = (not tpu_backend()) if interpret is None else interpret
-    return scale, interp
+def _resolve_scale(q, scale):
+    """One source of truth for the scale default used by the primal
+    forward, the VJP forward and the VJP backward."""
+    return scale if scale is not None else q.shape[-1] ** -0.5
 
 
 # ---------------------------------------------------------------------------
@@ -176,16 +172,10 @@ def _out_struct(shape, dtype, *refs):
     reference arrays' — required when a pallas_call runs INSIDE shard_map
     (the ring-attention inner): outputs vary over every axis an input
     does."""
-    typeof = getattr(jax, "typeof", None)
-    if typeof is None:  # older jax: no vma concept, no vma check either
-        return jax.ShapeDtypeStruct(shape, dtype)
     vma = frozenset()
     for r in refs:
-        vma = vma | getattr(typeof(r), "vma", frozenset())
-    try:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    except TypeError:
-        return jax.ShapeDtypeStruct(shape, dtype)
+        vma = vma | jax.typeof(r).vma
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
 def _flash_forward(q, k, v, causal, block_q, block_k, scale, interpret):
@@ -392,12 +382,14 @@ def flash_attention(
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
     scale: Optional[float] = None,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ) -> jnp.ndarray:
-    """Fused attention. Forward AND backward are Pallas kernels (interpreter
-    off-TPU/tests): the forward saves only O(S) softmax statistics (LSE) and
-    the backward recomputes each softmax tile from them — flash attention's
-    memory/FLOPs trade in both directions.
+    """Fused attention. Forward AND backward are Pallas TPU kernels
+    (``interpret=True`` runs them in the Pallas interpreter, for CPU
+    tests; off-TPU callers that want speed take
+    :func:`blockwise_attention` by name): the forward saves only O(S)
+    softmax statistics (LSE) and the backward recomputes each softmax tile
+    from them — flash attention's memory/FLOPs trade in both directions.
 
     Thin wrapper over :func:`flash_attention_lse` (the kernel always writes
     the LSE output; discarding it costs nothing, and a zero LSE cotangent
@@ -416,7 +408,7 @@ def flash_attention_lse(
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
     scale: Optional[float] = None,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ) -> "tuple[jnp.ndarray, jnp.ndarray]":
     """:func:`flash_attention` that ALSO returns the per-row log-sum-exp
     ([B, H, Sq], fp32) — the composable form: outputs of independent KV
@@ -429,22 +421,22 @@ def flash_attention_lse(
             f"q/k/v must share one dtype (got {q.dtype}/{k.dtype}/"
             f"{v.dtype}); cast the operands before the call"
         )
-    scale, interp = _resolve_defaults(q, scale, interpret)
-    return _flash_forward(q, k, v, causal, block_q, block_k, scale, interp)
+    return _flash_forward(q, k, v, causal, block_q, block_k,
+                          _resolve_scale(q, scale), interpret)
 
 
 def _fa_lse_fwd(q, k, v, causal, block_q, block_k, scale, interpret):
-    scale, interp = _resolve_defaults(q, scale, interpret)
-    out, lse = _flash_forward(q, k, v, causal, block_q, block_k, scale, interp)
+    out, lse = _flash_forward(q, k, v, causal, block_q, block_k,
+                              _resolve_scale(q, scale), interpret)
     return (out, lse), (q, k, v, out, lse)
 
 
 def _fa_lse_bwd(causal, block_q, block_k, scale, interpret, res, g):
     q, k, v, out, lse = res
     g_out, g_lse = g
-    scale, interp = _resolve_defaults(q, scale, interpret)
     return _flash_backward(q, k, v, out, lse, g_out, causal, block_q, block_k,
-                           scale, interp, lse_cotangent=g_lse)
+                           _resolve_scale(q, scale), interpret,
+                           lse_cotangent=g_lse)
 
 
 flash_attention_lse.defvjp(_fa_lse_fwd, _fa_lse_bwd)

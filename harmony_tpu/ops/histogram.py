@@ -15,12 +15,13 @@ so the kernel fits the scoped-VMEM limit (16 MB on v5e) at any input size.
 :func:`segment_sum` is the same op named for its other use — aggregating
 per-key push deltas by destination key (the table push path).
 
-Both fall back to a pure-XLA one-hot matmul off-TPU (interpret mode is used
-by tests to validate the kernel itself).
+:func:`xla_histogram` is the pure-XLA one-hot matmul reference; off-TPU
+callers take it by name (``interpret=True`` runs the kernel body in the
+Pallas interpreter, for CPU tests).
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -86,7 +87,8 @@ def _hist_kernel(ids_ref, w_ref, out_ref):
     )
 
 
-def _xla_histogram(ids, weights, num_bins):
+def xla_histogram(ids, weights, num_bins):
+    """The reference: one-hot matmul in plain XLA, any backend."""
     onehot = jax.nn.one_hot(ids, num_bins, dtype=jnp.float32)
     return onehot.T @ weights.astype(jnp.float32)
 
@@ -98,20 +100,16 @@ def weighted_histogram(
     block_n: int = DEFAULT_BLOCK_N,
     block_bins: int = DEFAULT_BLOCK_BINS,
     block_w: int = DEFAULT_BLOCK_W,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ) -> jnp.ndarray:
-    """``out[b, w] = sum over i with ids[i]==b of weights[i, w]``.
+    """``out[b, w] = sum over i with ids[i]==b of weights[i, w]``, as the
+    Pallas kernel.
 
     ids [N] int32 (out-of-range / negative ids contribute nothing),
     weights [N, W] -> [num_bins, W] float32.
     """
     if ids.ndim != 1 or weights.ndim != 2 or ids.shape[0] != weights.shape[0]:
         raise ValueError(f"bad shapes ids={ids.shape} weights={weights.shape}")
-    from harmony_tpu.utils.platform import tpu_backend
-
-    interp = (not tpu_backend()) if interpret is None else interpret
-    if interp and interpret is None:
-        return _xla_histogram(ids, weights, num_bins)  # off-TPU fast path
     N, W = weights.shape
     if N == 0 or W == 0:
         # A zero-size grid would skip the kernel's i==0 init entirely and
@@ -142,7 +140,8 @@ def weighted_histogram(
         ],
         out_specs=pl.BlockSpec((block_bins, block_w), lambda jw, jb, i: (jb, jw)),
         out_shape=jax.ShapeDtypeStruct((nb, Wp), jnp.float32),
-        interpret=interp,
+        interpret=interpret,
+        name="harmony_weighted_histogram",
     )(ids.astype(jnp.int32)[None, :], weights)
     return out[:num_bins, :W]
 

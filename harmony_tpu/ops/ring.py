@@ -30,32 +30,18 @@ _NEG_INF = -1e30
 
 
 def _resolve_inner(inner: str) -> str:
-    # "auto" = flash on TPU, einsum elsewhere. Validation status: the
-    # multi-device ring rotation is exact in interpret mode (CPU mesh
-    # tests) and the compiled Mosaic-kernel-under-shard_map path is exact
-    # on a real chip (benchmarks/micro.py ringflash, r02 capture: ok=true,
-    # max_abs_err 7.5e-4, 1.2x vs einsum) — but that capture ran on ONE
-    # chip, so the compiled-kernel-PLUS-rotation composition has not yet
-    # executed on multi-chip hardware (none attached here). Failures in
-    # that composition are loud (Mosaic compile/vma errors, like the one
-    # the skip-branch fix addressed), and HARMONY_RING_INNER=einsum gives
-    # operators a one-var rollback without touching call sites. Off-TPU
-    # the kernel would run in interpret mode (orders of magnitude
-    # slower), so einsum stays the fallback there.
+    # "auto" = flash only in a one-chip TPU process, einsum elsewhere. The
+    # compiled kernel PLUS a multi-chip ring rotation is opted into by
+    # name (inner="flash" / HARMONY_RING_INNER=flash); a failure there is
+    # loud (a Mosaic compile or vma error), never a fallback.
     if inner == "auto":
-        from harmony_tpu.utils.platform import env_choice, tpu_backend
+        from harmony_tpu.utils.platform import env_choice, trace_is_tpu
 
         forced = env_choice("HARMONY_RING_INNER", ("flash", "einsum"))
         if forced:
             return forced
-        # flash only where the composition has been captured: a single
-        # attached chip (r02 ringflash capture: exact, 1.2x). On MULTI-chip
-        # deployments the compiled-Mosaic-plus-ring-rotation composition
-        # has never executed — a loud mid-training Mosaic/vma failure on
-        # the default path is worse than the einsum fold until a
-        # multi-chip capture lands; HARMONY_RING_INNER=flash opts in.
         return ("flash"
-                if tpu_backend() and jax.device_count() == 1
+                if trace_is_tpu() and jax.device_count() == 1
                 else "einsum")
     if inner not in ("flash", "einsum"):
         raise ValueError(f"unknown ring inner {inner!r}")
@@ -70,6 +56,7 @@ def ring_attention(
     causal: bool = False,
     scale: Optional[float] = None,
     inner: str = "auto",
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """Exact attention over a sequence sharded on ``axis_name``.
 
@@ -84,11 +71,12 @@ def ring_attention(
         nothing (the einsum inner computes-then-masks them).
       * "einsum" — the original streaming-softmax fold (any backend, any
         shape).
-      * "auto"   — flash on a SINGLE attached TPU chip (the composition
-        captured exact on chip, r02 ringflash); einsum on multi-chip
-        deployments (compiled-Mosaic-plus-rotation is uncaptured there)
-        and off-TPU. HARMONY_RING_INNER overrides (see _resolve_inner).
-    """
+      * "auto"   — flash in a one-chip TPU process, einsum on multi-chip
+        deployments and off-TPU. HARMONY_RING_INNER overrides (see
+        _resolve_inner).
+
+    ``interpret=True`` runs the flash inner in the Pallas interpreter (CPU
+    tests)."""
     B, H, S, D = q.shape
     scale = scale if scale is not None else D ** -0.5
     n = lax.psum(1, axis_name)
@@ -100,7 +88,8 @@ def ring_attention(
     q_pos = my * S + jnp.arange(S)[:, None]            # global q positions
 
     if inner == "flash":
-        return _ring_flash(qf, k, v, axis_name, causal, n, my, perm, q.dtype)
+        return _ring_flash(qf, k, v, axis_name, causal, n, my, perm, q.dtype,
+                           interpret)
 
     def fold(acc, m, l, kb, vb, src):
         """Merge one visiting KV chunk (home shard ``src``) into the online
@@ -143,7 +132,8 @@ def ring_attention(
     return (acc / jnp.maximum(l, 1e-30)[..., None]).astype(q.dtype)
 
 
-def _ring_flash(qf, k, v, axis_name, causal, n, my, perm, out_dtype):
+def _ring_flash(qf, k, v, axis_name, causal, n, my, perm, out_dtype,
+                interpret=False):
     """Flash-inner ring: each visiting chunk through the Pallas kernel
     (out_t, lse_t), merged via the numerically-safe LSE running max.
 
@@ -161,12 +151,12 @@ def _ring_flash(qf, k, v, axis_name, causal, n, my, perm, out_dtype):
     def full(args):
         q_, k_, v_ = args
         return flash_attention_lse(q_, k_, v_, False, DEFAULT_BLOCK_Q,
-                                   DEFAULT_BLOCK_K, 1.0)
+                                   DEFAULT_BLOCK_K, 1.0, interpret)
 
     def diag(args):
         q_, k_, v_ = args
         return flash_attention_lse(q_, k_, v_, True, DEFAULT_BLOCK_Q,
-                                   DEFAULT_BLOCK_K, 1.0)
+                                   DEFAULT_BLOCK_K, 1.0, interpret)
 
     def skip(args):
         q_, _, _ = args
@@ -223,17 +213,18 @@ def ring_self_attention(
     causal: bool = False,
     inner: str = "auto",
     check_vma: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """Host-level wrapper: shard [B,H,S,D] inputs over ``mesh`` with the
     sequence dim on ``seq_axis`` (and optionally batch on ``batch_axis``),
     run :func:`ring_attention` under shard_map.
 
-    ``check_vma=False`` is needed to run the flash inner in INTERPRET mode
-    (off-TPU tests): the pallas HLO interpreter's internal slicing trips
-    shard_map's varying-axes checker."""
+    ``interpret=True`` runs the flash inner in the Pallas interpreter
+    (off-TPU tests) and needs ``check_vma=False``: the interpreter's
+    internal slicing trips shard_map's varying-axes checker."""
     spec = P(batch_axis, None, seq_axis, None)
     fn = functools.partial(ring_attention, axis_name=seq_axis, causal=causal,
-                           inner=inner)
+                           inner=inner, interpret=interpret)
     return jax.shard_map(
         fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=check_vma,

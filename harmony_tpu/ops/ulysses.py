@@ -57,9 +57,9 @@ def a2a_attention(
     # Pallas flash kernel's case (the edge a2a has over ring, whose inner
     # fold can't use it); blockwise is the any-backend/odd-shape tier.
     S = qh.shape[2]
-    from harmony_tpu.utils.platform import tpu_backend
+    from harmony_tpu.utils.platform import trace_is_tpu
 
-    if tpu_backend() and S % 128 == 0:
+    if trace_is_tpu() and S % 128 == 0:
         o = flash_attention(qh, kh, vh, causal=causal, scale=scale)
     else:
         o = blockwise_attention(qh, kh, vh, causal=causal, scale=scale)

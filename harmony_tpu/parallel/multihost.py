@@ -181,6 +181,4 @@ def sync_global_devices(tag: str = "barrier") -> None:
         x = jax.pmap(lambda x: jax.lax.psum(x, "i"), axis_name="i")(
             np.ones((len(jax.local_devices()),), np.float32)
         )
-        from harmony_tpu.utils.platform import hard_sync
-
-        hard_sync(x)
+        jax.block_until_ready(x)

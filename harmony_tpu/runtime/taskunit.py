@@ -84,8 +84,8 @@ class GlobalTaskUnitScheduler:
         # (blocking backends — CPU's in-process collectives): there the
         # single global slot IS the device schedule. On async backends
         # (real TPU) scope exit is just enqueue-complete; serializing
-        # enqueues across tenants would tax throughput (each enqueue can
-        # cost a remote-attach round trip) without governing device time —
+        # enqueues across tenants would tax throughput without governing
+        # device time —
         # fairness there comes from the deficit-ordered grants plus the
         # contended in-flight cap bounding every tenant's queue depth.
         # The JobServer flips this from its device pool at start.

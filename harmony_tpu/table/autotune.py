@@ -43,16 +43,14 @@ def _signature(spec, mesh, nkeys: int) -> Tuple:
 
 def _measure(fn, args, mesh) -> float:
     """min-of-3 after a compile dispatch, each dispatch inside the global
-    order scope, synced with hard_sync (block_until_ready is a no-op on
-    lazy remote backends)."""
+    order scope and timed to ``block_until_ready``."""
     from harmony_tpu.parallel.dispatch import dispatch_scope
-    from harmony_tpu.utils.platform import hard_sync
 
     def once() -> float:
         t0 = time.perf_counter()
         with dispatch_scope(mesh) as fin:
             out = fin(fn(*args))
-        hard_sync(out)
+        jax.block_until_ready(out)
         return time.perf_counter() - t0
 
     once()  # compile
@@ -70,14 +68,6 @@ def measurements() -> Dict[Tuple, Dict[str, float]]:
         return dict(_MEASUREMENTS)
 
 
-def _static_gate(spec, nkeys: int) -> str:
-    """The pre-measurement density heuristic — the fallback when a
-    measurement fails, and the deterministic choice on meshes where an
-    ad-hoc measurement dispatch is a hazard."""
-    dense_enough = nkeys >= max(32, spec.config.capacity // 256)
-    return "mxu" if dense_enough else "scatter"
-
-
 def choose_push_route(spec, mesh, nkeys: int, table=None) -> str:
     """The measured-faster keyed-push route for this shape on this mesh
     ("scatter" | "mxu"), cached per signature for the process lifetime.
@@ -86,9 +76,9 @@ def choose_push_route(spec, mesh, nkeys: int, table=None) -> str:
     commutative adds). When ``table`` (a DenseTable living on ``mesh``)
     is given, measurement runs NON-DONATING against its live array —
     no second table-sized allocation; without it a zero array is
-    device-allocated. A failed measurement caches the static-gate
-    fallback (retrying a multi-GB allocation on every build would be
-    worse than one wrong route) and never raises into a step build.
+    device-allocated. A measurement that raises — a route that does not
+    compile on this mesh included — propagates into the step build:
+    there is no unmeasured route to hide it behind.
     """
     if spec.update_fn.scatter_mode != "add":
         return "scatter"
@@ -97,44 +87,44 @@ def choose_push_route(spec, mesh, nkeys: int, table=None) -> str:
         hit = _ROUTES.get(sig)
     if hit is not None:
         return hit
-    try:
-        if table is not None:
-            with table._lock:
-                arr = table._arr
-        else:
-            from harmony_tpu.table.table import block_sharding
+    from harmony_tpu.utils.platform import traced_on
 
-            sharding = block_sharding(mesh, spec.num_blocks)
-            # lint: allow(jit-hygiene) one-shot push-route measurement at
-            # job-build time (never per batch) — a cached wrapper would
-            # only pin a program nothing ever reuses
-            arr = jax.jit(
-                lambda: jnp.zeros(spec.storage_shape, spec.dtype),
-                out_shardings=sharding,
-            )()
-        rng = np.random.default_rng(0)
-        keys = jnp.asarray(
-            rng.integers(0, spec.config.capacity, int(nkeys)), jnp.int32
-        )
-        deltas = jnp.zeros((int(nkeys), *spec.value_shape), spec.dtype)
+    if table is not None:
+        with table._lock:
+            arr = table._arr
+    else:
+        from harmony_tpu.table.table import block_sharding
 
-        def route_fn(via):
-            # deltas depend on the array so neither XLA nor a cached
-            # constant can fold the push away; non-donating (the live
-            # table array must survive)
-            return jax.jit(
-                lambda a, k, d: spec.push(
-                    a, k, d + 0.0 * jnp.ravel(a)[0], via=via
-                )
-            )
+        sharding = block_sharding(mesh, spec.num_blocks)
+        # lint: allow(jit-hygiene) one-shot push-route measurement at
+        # job-build time (never per batch) — a cached wrapper would
+        # only pin a program nothing ever reuses
+        arr = jax.jit(
+            lambda: jnp.zeros(spec.storage_shape, spec.dtype),
+            out_shardings=sharding,
+        )()
+    rng = np.random.default_rng(0)
+    keys = jnp.asarray(
+        rng.integers(0, spec.config.capacity, int(nkeys)), jnp.int32
+    )
+    deltas = jnp.zeros((int(nkeys), *spec.value_shape), spec.dtype)
 
-        t_scatter = _measure(route_fn("scatter"), (arr, keys, deltas), mesh)
-        t_mxu = _measure(route_fn("mxu"), (arr, keys, deltas), mesh)
-        route = "mxu" if t_mxu < t_scatter else "scatter"
-        meas = {"scatter_sec": t_scatter, "mxu_sec": t_mxu}
-    except Exception:
-        route = _static_gate(spec, nkeys)
-        meas = {"error": "measurement failed; static gate cached"}
+    def route_fn(via):
+        # deltas depend on the array so neither XLA nor a cached
+        # constant can fold the push away; non-donating (the live
+        # table array must survive). Traced for the table's mesh, so
+        # each route is measured as the lowering the step will bake.
+        return jax.jit(traced_on(
+            mesh,
+            lambda a, k, d: spec.push(
+                a, k, d + 0.0 * jnp.ravel(a)[0], via=via
+            ),
+        ))
+
+    t_scatter = _measure(route_fn("scatter"), (arr, keys, deltas), mesh)
+    t_mxu = _measure(route_fn("mxu"), (arr, keys, deltas), mesh)
+    route = "mxu" if t_mxu < t_scatter else "scatter"
+    meas = {"scatter_sec": t_scatter, "mxu_sec": t_mxu}
     with _LOCK:
         _ROUTES[sig] = route
         _MEASUREMENTS[sig] = meas

@@ -956,19 +956,10 @@ def _migrate_blocks_inner(arr: jax.Array, old_mesh: Mesh,
             shard = shard.astype(dtype)
         shards.append(shard)
         devices.append(d)
-    try:
-        new_arr = jax.make_array_from_single_device_arrays(
-            shape, new_sharding, shards,
-            dtype=dtype,  # required when this process holds no shards
-        )
-    except TypeError:
-        # older jax: no dtype kwarg. Only reachable with shards to infer
-        # from — a zero-shard participant needs the newer jax anyway.
-        if not shards:
-            raise
-        new_arr = jax.make_array_from_single_device_arrays(
-            shape, new_sharding, shards
-        )
+    new_arr = jax.make_array_from_single_device_arrays(
+        shape, new_sharding, shards,
+        dtype=dtype,  # required when this process holds no shards
+    )
     last_move_stats.clear()
     last_move_stats.update({
         "seq": seq,

@@ -133,6 +133,52 @@ def block_sharding(mesh: Mesh, num_blocks: int) -> NamedSharding:
     return NamedSharding(mesh, P())
 
 
+def _gather_on_mesh(mesh: Mesh, shards: int, flat: jnp.ndarray,
+                    idx: jnp.ndarray) -> jnp.ndarray:
+    """The Pallas gather partitioned by hand (a pallas_call is opaque to
+    the GSPMD partitioner): each device gathers from the rows it holds;
+    with the rows split over ``shards`` model-axis shards a key's row comes
+    from the one shard that owns it (the others contribute zeros to a
+    psum), so what crosses the model axis is the pulled rows — never the
+    table."""
+    from harmony_tpu.ops.sparse import gather_rows
+
+    if mesh.devices.size == 1:
+        return gather_rows(flat, idx)
+
+    def local(rows, ids):
+        if shards == 1:
+            return gather_rows(rows, ids)
+        lo = jax.lax.axis_index(MODEL_AXIS) * rows.shape[0]
+        mine = (ids >= lo) & (ids < lo + rows.shape[0])
+        got = gather_rows(rows, ids - lo)  # foreign ids clamp, then mask
+        return jax.lax.psum(
+            jnp.where(mine[:, None], got, jnp.zeros_like(got)), MODEL_AXIS)
+
+    rows = P(MODEL_AXIS) if shards > 1 else P()
+    return jax.shard_map(local, mesh=mesh, in_specs=(rows, P()),
+                         out_specs=P(), check_vma=False)(flat, idx)
+
+
+def _fold_on_mesh(mesh: Optional[Mesh], shards: int, fold,
+                  deltas: jnp.ndarray, idx: jnp.ndarray,
+                  num_rows: int) -> jnp.ndarray:
+    """``fold(deltas, idx, rows) -> [rows, W]`` (ids outside [0, rows)
+    contribute nothing) over the table's row shards: each device folds
+    only the keys that land in the rows it holds."""
+    if mesh is None or mesh.devices.size == 1:
+        return fold(deltas, idx, num_rows)
+    per = num_rows // shards
+
+    def local(d, ids):
+        lo = jax.lax.axis_index(MODEL_AXIS) * per if shards > 1 else 0
+        return fold(d, ids - lo, per)
+
+    rows = P(MODEL_AXIS) if shards > 1 else P()
+    return jax.shard_map(local, mesh=mesh, in_specs=(P(), P()),
+                         out_specs=rows, check_vma=False)(deltas, idx)
+
+
 class LayoutAnnouncerMixin:
     """Reshard announcements, shared by dense AND hash tables: the caller
     (TableHandle._announce_target) announces the TARGET mesh before the
@@ -254,20 +300,45 @@ class TableSpec:
         ) if vals.ndim == 1 and self.value_shape else vals
         return vals.astype(self.dtype).reshape(self.storage_shape)
 
+    def _kernel_layout(self) -> "Tuple[Optional[Mesh], int]":
+        """``(mesh, row shards)`` when the op being traced may take the
+        Pallas kernels — the traced mesh was named by the caller
+        (utils.platform.on_mesh) and is all-TPU — else ``(None, 1)``: a
+        pallas_call is opaque to the GSPMD partitioner, so without the
+        mesh there is no way to keep a sharded table's rows where they
+        are, and ops traced outside a scope take the XLA references.
+        Rows split over the model axis by block_sharding's rule."""
+        from harmony_tpu.utils.platform import mesh_is_tpu, trace_mesh
+
+        mesh = trace_mesh()
+        if mesh is None or not mesh_is_tpu(mesh):
+            return None, 1
+        model = mesh.shape.get(MODEL_AXIS, 1)
+        return mesh, (model if self.num_blocks % model == 0 else 1)
+
     def pull(self, arr: jnp.ndarray, keys: jnp.ndarray) -> jnp.ndarray:
         """multiGetOrInit: gather values for ``keys`` -> [n, *value_shape].
 
-        Routed through ops.sparse.gather_rows — the Pallas batched
-        embedding gather on TPU backends, a value-identical jnp gather
-        everywhere else (route picked at trace time, so tier-1 on CPU
-        walks the same call graph)."""
-        from harmony_tpu.ops.sparse import gather_rows, value_width
+        Traced for a TPU mesh (utils.platform.on_mesh) with rows the
+        kernel takes, this is ops.sparse.gather_rows — the Pallas batched
+        embedding gather, run per row shard; everywhere else the
+        value-identical XLA gather (gather_rows_ref)."""
+        from harmony_tpu.ops import sparse
 
         b, o = self.partitioner.locate(keys)
         flat_idx = (b * self.block_size + o).astype(jnp.int32)
-        flat = arr.reshape(self.num_blocks * self.block_size,
-                           value_width(self.value_shape))
-        rows = gather_rows(flat, flat_idx.reshape(-1))
+        num_rows = self.num_blocks * self.block_size
+        flat = arr.reshape(num_rows, sparse.value_width(self.value_shape))
+        idx = flat_idx.reshape(-1)
+        mesh, shards = self._kernel_layout()
+        if mesh is not None and sparse.gather_kernel_ok(
+                (num_rows // shards, flat.shape[1]), flat.dtype,
+                idx.shape[0]):
+            # clamp against the WHOLE table before ids are made shard-local
+            idx = jnp.clip(idx, 0, num_rows - 1)
+            rows = _gather_on_mesh(mesh, shards, flat, idx)
+        else:
+            rows = sparse.gather_rows_ref(flat, idx)
         return rows.reshape(*flat_idx.shape, *self.value_shape)
 
     def pull_all(self, arr: jnp.ndarray) -> jnp.ndarray:
@@ -300,15 +371,20 @@ class TableSpec:
             of the table (>= capacity/256 keys — the dense-add bandwidth
             amortises over duplicate folds), else "scatter" (a few rows
             into a huge table: streaming the table would dominate).
-          * "sparse" — pre-fold duplicates with the row-granular Pallas
-            segment-sum (ops.sparse.segment_sum_rows; jnp fallback off
-            TPU) and apply ONE dense add — the mxu route's shape without
-            the table-sized one-hot contraction.
+          * "sparse" — pre-fold duplicates with the row-granular
+            segment-sum (ops.sparse.segment_sum_rows) and apply ONE dense
+            add — the mxu route's shape without the table-sized one-hot
+            contraction.
           * "auto" — "scatter". The spec cannot see which devices the
             array lives on (the process default backend is NOT it — a CPU
             table in a TPU-default process is normal in tests/benchmarks),
             so platform-aware callers resolve DenseTable.push_via and pass
             it explicitly.
+
+        The two folds are Pallas kernels when traced for a TPU mesh
+        (utils.platform.on_mesh), run per row shard; their XLA references
+        (histogram.xla_histogram, sparse.segment_sum_rows_ref) everywhere
+        else.
         """
         b, o = self.partitioner.locate(keys)
         mode = self.update_fn.scatter_mode
@@ -323,18 +399,23 @@ class TableSpec:
             # vs row-granular Pallas/jnp segment-sum)
             if mode != "add":
                 raise ValueError(f"via={via!r} requires an additive update fn")
-            if via == "mxu":
-                from harmony_tpu.ops.histogram import segment_sum as fold
-            else:
-                from harmony_tpu.ops.sparse import segment_sum_rows as fold
+            from harmony_tpu.ops import histogram, sparse
 
             n = keys.shape[0]
             flat_idx = (b * self.block_size + o).astype(jnp.int32).reshape(-1)
-            folded = fold(
-                deltas.reshape(n, -1).astype(jnp.float32),
-                flat_idx,
-                self.num_blocks * self.block_size,
-            )
+            rows2d = deltas.reshape(n, -1).astype(jnp.float32)
+            num_rows = self.num_blocks * self.block_size
+            mesh, shards = self._kernel_layout()
+            if via == "mxu":
+                fold = (histogram.segment_sum if mesh is not None
+                        else lambda d, i, r: histogram.xla_histogram(i, d, r))
+            elif mesh is not None and sparse.segment_sum_kernel_ok(
+                    rows2d.shape, rows2d.dtype, num_rows // shards):
+                fold = sparse.segment_sum_rows
+            else:
+                fold = sparse.segment_sum_rows_ref
+            folded = _fold_on_mesh(mesh, shards, fold, rows2d, flat_idx,
+                                   num_rows)
             out = arr + folded.reshape(arr.shape).astype(arr.dtype)
             if self.update_fn.post is not None:
                 out = out.at[b, o].set(self.update_fn.post(out[b, o]))
@@ -560,11 +641,14 @@ class DenseTable(LayoutAnnouncerMixin):
 
     def _jitted(self, name: str, fn: Callable,
                 out_shardings=None) -> Callable:
+        from harmony_tpu.utils.platform import traced_on
+
         with self._lock:
             if name not in self._jit_cache:
+                mesh = self._mesh  # stable: cache cleared on reshard
+                fn = traced_on(mesh, fn)  # table ops pick kernels by mesh
                 jf = (jax.jit(fn) if out_shardings is None
                       else jax.jit(fn, out_shardings=out_shardings))
-                mesh = self._mesh  # stable: cache cleared on reshard
 
                 def wrapped(*args, _jf=jf, _mesh=mesh, **kw):
                     # host ops dispatch multi-device programs too (gathers/
@@ -599,16 +683,16 @@ class DenseTable(LayoutAnnouncerMixin):
         of fold-vs-scatter at real shapes are still settling (the first
         honest capture had scatter ahead at the bench shape); "sparse"
         opts into the row-granular Pallas fold (ops/sparse.py)."""
-        from harmony_tpu.utils.platform import device_is_tpu, env_choice
+        from harmony_tpu.utils.platform import env_choice, mesh_is_tpu
 
         forced = env_choice("HARMONY_PUSH_VIA",
                             ("scatter", "mxu", "mxu_auto", "sparse"))
         if forced:
             return forced
-        on_tpu = all(device_is_tpu(d) for d in self._mesh.devices.flat)
         return (
             "mxu_auto"
-            if on_tpu and self.spec.update_fn.scatter_mode == "add"
+            if mesh_is_tpu(self._mesh)
+            and self.spec.update_fn.scatter_mode == "add"
             else "scatter"
         )
 

@@ -140,7 +140,8 @@ def test_ring_flash_inner_matches_einsum(devices, causal):
     o_e = ring_self_attention(q, k, v, mesh, seq_axis="seq",
                               inner="einsum", **kw)
     o_f = ring_self_attention(q, k, v, mesh, seq_axis="seq",
-                              inner="flash", check_vma=False, **kw)
+                              inner="flash", check_vma=False,
+                              interpret=True, **kw)
     np.testing.assert_allclose(np.asarray(o_f), np.asarray(o_e), atol=2e-5)
 
     def loss_e(q, k, v):
@@ -150,7 +151,7 @@ def test_ring_flash_inner_matches_einsum(devices, causal):
     def loss_f(q, k, v):
         return (ring_self_attention(q, k, v, mesh, seq_axis="seq",
                                     inner="flash", check_vma=False,
-                                    **kw) ** 2).sum()
+                                    interpret=True, **kw) ** 2).sum()
 
     ge = jax.grad(loss_e, argnums=(0, 1, 2))(q, k, v)
     gf = jax.grad(loss_f, argnums=(0, 1, 2))(q, k, v)
@@ -167,7 +168,7 @@ def test_flash_attention_lse_matches_reference():
     q, k, v = _qkv(B=1, H=2, S=32, D=8, seed=9)
     scale = q.shape[-1] ** -0.5
     out, lse = jax.jit(
-        lambda q, k, v: flash_attention_lse(q, k, v, True)
+        lambda q, k, v: flash_attention_lse(q, k, v, True, interpret=True)
     )(q, k, v)
     s = jnp.einsum("bhqd,bhkd->bhqk", q * scale, k).astype(jnp.float32)
     mask = jnp.tril(jnp.ones((32, 32), bool))
@@ -177,7 +178,7 @@ def test_flash_attention_lse_matches_reference():
                                atol=1e-4)
 
     def loss_flash(q, k, v):
-        o, l = flash_attention_lse(q, k, v, True)
+        o, l = flash_attention_lse(q, k, v, True, interpret=True)
         return (o.astype(jnp.float32) ** 2).sum() + (l * 0.1).sum()
 
     def loss_ref(q, k, v):

@@ -1,7 +1,6 @@
 """Process-level program cache: resubmitted identical jobs reuse compiled
 steps (runtime/progcache) — the long-running JobServer's resubmit pattern
-must not pay a recompile per submission (on a remote-attached chip that
-recompile dominated the headline bench's measured pass)."""
+must not pay a recompile per submission."""
 import jax
 import jax.numpy as jnp
 import numpy as np

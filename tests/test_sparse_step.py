@@ -22,12 +22,17 @@ from harmony_tpu.dolphin import (
     TrainingDataProvider,
     WorkerTasklet,
 )
-from harmony_tpu.ops.sparse import gather_rows, kernel_route, segment_sum_rows
+from harmony_tpu.ops.sparse import (
+    gather_rows,
+    gather_rows_ref,
+    segment_sum_rows,
+    segment_sum_rows_ref,
+)
 from harmony_tpu.table import DenseTable, TableSpec
 
 
 # ---------------------------------------------------------------------------
-# ops/sparse.py: kernel (interpret mode) vs jnp fallback
+# ops/sparse.py: kernel (interpret mode) vs jnp reference
 # ---------------------------------------------------------------------------
 
 
@@ -36,7 +41,7 @@ def test_gather_rows_kernel_matches_fallback():
     table = jnp.asarray(rng.normal(size=(64, 128)).astype(np.float32))
     idx = jnp.asarray(rng.integers(0, 64, 40), jnp.int32)
     kernel = gather_rows(table, idx, interpret=True)
-    fallback = gather_rows(table, idx)  # CPU backend -> jnp route
+    fallback = gather_rows_ref(table, idx)
     # a gather copies bytes: the routes must agree EXACTLY
     np.testing.assert_array_equal(np.asarray(kernel), np.asarray(fallback))
 
@@ -48,7 +53,7 @@ def test_gather_rows_oob_clamps_like_jax_gather():
     # Python-style, which the kernel's clamp cannot reproduce)
     idx = jnp.asarray([0, 7, 9, 100, -1, -9], jnp.int32)
     kernel = gather_rows(table, idx, interpret=True)
-    fallback = gather_rows(table, idx)
+    fallback = gather_rows_ref(table, idx)
     np.testing.assert_array_equal(np.asarray(kernel), np.asarray(fallback))
     np.testing.assert_array_equal(np.asarray(fallback[4]), np.asarray(table[0]))
     np.testing.assert_array_equal(np.asarray(fallback[3]), np.asarray(table[7]))
@@ -62,7 +67,7 @@ def test_segment_sum_rows_kernel_matches_fallback_exact_counts():
         rng.integers(-3, 4, (200, 128)).astype(np.float32))
     idx = jnp.asarray(rng.integers(0, 16, 200), jnp.int32)
     kernel = segment_sum_rows(deltas, idx, 16, interpret=True)
-    fallback = segment_sum_rows(deltas, idx, 16)
+    fallback = segment_sum_rows_ref(deltas, idx, 16)
     np.testing.assert_array_equal(np.asarray(kernel), np.asarray(fallback))
 
 
@@ -71,7 +76,7 @@ def test_segment_sum_rows_kernel_matches_fallback_float():
     deltas = jnp.asarray(rng.normal(size=(100, 128)).astype(np.float32))
     idx = jnp.asarray(rng.integers(-2, 12, 100), jnp.int32)  # incl. OOB
     kernel = segment_sum_rows(deltas, idx, 10, interpret=True)
-    fallback = segment_sum_rows(deltas, idx, 10)
+    fallback = segment_sum_rows_ref(deltas, idx, 10)
     np.testing.assert_allclose(np.asarray(kernel), np.asarray(fallback),
                                atol=1e-5, rtol=1e-5)
     # OOB ids (negative / >= num_rows) contribute nothing on either route
@@ -81,13 +86,45 @@ def test_segment_sum_rows_kernel_matches_fallback_float():
     np.testing.assert_allclose(np.asarray(kernel), expect, atol=1e-4)
 
 
-def test_kernel_route_env_override(monkeypatch):
-    monkeypatch.setenv("HARMONY_SPARSE_KERNEL", "jnp")
-    assert kernel_route() is False
-    monkeypatch.setenv("HARMONY_SPARSE_KERNEL", "pallas")
-    assert kernel_route() is True
-    monkeypatch.delenv("HARMONY_SPARSE_KERNEL")
-    assert kernel_route(interpret=True) is True  # forced kernel for tests
+def test_kernels_refuse_shapes_they_cannot_tile():
+    """The kernels ARE the kernels: a shape they cannot take is an error
+    naming the reference, never a quiet switch of route."""
+    narrow = jnp.zeros((8, 3), jnp.float32)
+    with pytest.raises(ValueError, match="gather_rows_ref"):
+        gather_rows(narrow, jnp.zeros((4,), jnp.int32), interpret=True)
+    half = jnp.zeros((8, 128), jnp.bfloat16)  # packed rows: no 1-row DMA
+    with pytest.raises(ValueError, match="gather_rows_ref"):
+        gather_rows(half, jnp.zeros((4,), jnp.int32), interpret=True)
+    wide = jnp.zeros((8, 256), jnp.float32)  # tile-interleaved rows
+    with pytest.raises(ValueError, match="gather_rows_ref"):
+        gather_rows(wide, jnp.zeros((4,), jnp.int32), interpret=True)
+    with pytest.raises(ValueError, match="segment_sum_rows_ref"):
+        segment_sum_rows(jnp.zeros((4, 3), jnp.float32),
+                         jnp.zeros((4,), jnp.int32), 8, interpret=True)
+
+
+def _tpu_text(fn, *args):
+    """StableHLO of ``fn`` cross-lowered for the TPU from this CPU host —
+    runs the Pallas TPU front end (block-shape and memory-space checks)
+    without a chip."""
+    return jax.jit(fn).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+
+
+def test_sparse_kernels_lower_for_tpu():
+    """A block shape or op the Pallas TPU lowering refuses (the old
+    one-row gather block; pl.load/pl.store) can never again pass a
+    CPU-only review: both kernels must cross-lower, at a plain shape and
+    at the shapes that force padding."""
+    table = jnp.zeros((1000, 128), jnp.float32)
+    for n in (1, 8, 100, 4096):
+        text = _tpu_text(gather_rows, table, jnp.zeros((n,), jnp.int32))
+        assert "tpu_custom_call" in text
+    for n, rows in ((5, 64), (256, 64), (1000, 16384)):
+        text = _tpu_text(
+            lambda d, i, rows=rows: segment_sum_rows(d, i, rows),
+            jnp.zeros((n, 128), jnp.float32), jnp.zeros((n,), jnp.int32))
+        assert "tpu_custom_call" in text
 
 
 def test_spec_pull_matches_direct_gather(mesh8):

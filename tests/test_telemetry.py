@@ -303,25 +303,25 @@ class TestStragglerReport:
 
 
 class TestTracerSatellite:
-    def test_real_import_failure_is_not_swallowed(self, monkeypatch):
-        """A broken utils.platform (e.g. ITS jax import failing) must
-        surface from record(block_on=...), not silently skip the sync."""
-        import sys
-        import types
+    def test_record_blocks_on_the_given_value(self, monkeypatch):
+        """record(block_on=...) waits for the device value before it
+        stops the clock (jax.block_until_ready), so async device work is
+        charged to this phase and not the next."""
+        import jax
 
         from harmony_tpu.metrics.tracer import Tracer
 
-        fake = types.ModuleType("harmony_tpu.utils.platform")
-
-        def _getattr(name):
-            raise ImportError("No module named 'jax'", name="jax")
-
-        fake.__getattr__ = _getattr
-        monkeypatch.setitem(sys.modules, "harmony_tpu.utils.platform", fake)
+        waited = []
+        monkeypatch.setattr(jax, "block_until_ready",
+                            lambda x: waited.append(x) or x)
         tr = Tracer()
         tr.start()
-        with pytest.raises(ImportError):
-            tr.record(block_on=object())
+        token = object()
+        tr.record(block_on=token)
+        assert waited == [token]
+        tr.start()
+        tr.record()  # nothing to wait for
+        assert waited == [token]
 
     def test_instrumented_record_feeds_histogram(self, fresh_registry):
         from harmony_tpu.metrics.tracer import Tracer
