@@ -22,12 +22,17 @@ from typing import List, Set
 from harmony_tpu.analysis.core import CodebaseIndex, Finding, Pass
 
 
+_OPENERS = ("trace_span", "job_stage")
+
+
 def _is_trace_span_call(node: ast.AST) -> bool:
     if not isinstance(node, ast.Call):
         return False
     f = node.func
-    return ((isinstance(f, ast.Name) and f.id == "trace_span")
-            or (isinstance(f, ast.Attribute) and f.attr == "trace_span"))
+    # job_stage(...) returns a trace_span: its callers are held to the
+    # same shapes
+    return ((isinstance(f, ast.Name) and f.id in _OPENERS)
+            or (isinstance(f, ast.Attribute) and f.attr in _OPENERS))
 
 
 class SpanHygienePass(Pass):

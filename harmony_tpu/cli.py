@@ -1067,9 +1067,11 @@ def _render_incidents(incidents: dict) -> "List[str]":
 #: waterfall row order + short labels (docs/OBSERVABILITY.md §9 column
 #: glossary) — taxonomy order, residual last
 _CRITPATH_ROWS = (("input_wait", "input"), ("host_dispatch", "dispatch"),
+                  ("grant_wait", "grant"),
                   ("pull_comm", "pull"), ("compute", "compute"),
-                  ("push_comm", "push"), ("barrier_wait", "barrier"),
-                  ("residual", "residual"))
+                  ("push_comm", "push"), ("probe", "probe"),
+                  ("bookkeeping", "bookkeep"),
+                  ("barrier_wait", "barrier"), ("residual", "residual"))
 _CRITPATH_BAR = 30
 
 

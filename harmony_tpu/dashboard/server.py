@@ -552,8 +552,10 @@ class DashboardServer:
     #: stacked-bar colors per phase (taxonomy order; residual grey —
     #: the explicitly-unattributed share must LOOK unattributed)
     _PHASE_COLORS = (("input_wait", "#fa0"), ("host_dispatch", "#a6f"),
+                     ("grant_wait", "#c4a"),
                      ("pull_comm", "#46f"), ("compute", "#4a4"),
-                     ("push_comm", "#28c"), ("barrier_wait", "#e55"),
+                     ("push_comm", "#28c"), ("probe", "#8bd"),
+                     ("bookkeeping", "#a85"), ("barrier_wait", "#e55"),
                      ("residual", "#bbb"))
 
     @staticmethod

@@ -54,7 +54,8 @@ from harmony_tpu.metrics.collector import (
 from harmony_tpu.parallel.dispatch import dispatch_scope
 from harmony_tpu.parallel.mesh import DATA_AXIS
 from harmony_tpu.runtime import progcache
-from harmony_tpu.tracing import SpanContext, trace_span
+from harmony_tpu.tracing import SpanContext, job_stage, trace_span
+from harmony_tpu.tracing.span import job_stage_adder
 from harmony_tpu.tracing.profiler import maybe_profile_epoch
 from harmony_tpu.utils.platform import traced_on
 
@@ -603,6 +604,25 @@ def accessor_async_step(table, compute_fn, *, staleness_bound: int = 0,
                            mesh=mesh)
 
 
+class _TimedAdmission:
+    """A dispatch turn whose admission — entering it — is a
+    ``taskunit.wait`` light span (kind TURN) feeding ``acc``; the turn
+    itself is held and released as before."""
+
+    __slots__ = ("_turn", "_acc", "_job_id")
+
+    def __init__(self, turn, acc, job_id: str) -> None:
+        self._turn, self._acc, self._job_id = turn, acc, job_id
+
+    def __enter__(self):
+        with trace_span("taskunit.wait", record=False, acc=self._acc,
+                        job_id=self._job_id, kind="TURN"):
+            return self._turn.__enter__()
+
+    def __exit__(self, *exc):
+        return self._turn.__exit__(*exc)
+
+
 class WorkerTasklet:
     """Drives the training loop for one job over its mesh slice."""
 
@@ -801,6 +821,20 @@ class WorkerTasklet:
         self._phase_dispatch_acc = 0.0
         self._phase_pending: Dict[int, Dict[str, float]] = {}
         self._phase_input_wait: Dict[int, float] = {}
+        # the three control phases, measured by their spans' own clock
+        # reads (``acc`` of trace_span) on the training thread since the
+        # last budget feed: ``taskunit.wait`` + turn admission, the comm
+        # probe, the post-drain bookkeeping. _budget_mark is where the
+        # last fed stretch of wall ended: a feed's wall runs from there,
+        # so probe and bookkeeping — which sit BETWEEN the epochs' own
+        # timers — are inside the wall they are phases of.
+        self._phase_ctl = {"grant_wait": 0.0, "probe": 0.0,
+                           "bookkeeping": 0.0}
+        self._budget_mark: Optional[float] = None
+        # the step program whose first dispatch (trace + lower + compile
+        # or cache load, all lazy) has been made: job.build_step covers
+        # the first dispatch of every NEW program
+        self._step_dispatched: Any = None
 
     # -- step construction ----------------------------------------------
 
@@ -2037,6 +2071,14 @@ class WorkerTasklet:
                 faults.site("worker.pull", job=self.job_id,
                             worker=self.ctx.worker_id, batch=batch_idx)
             try:
+                if self._step is not self._step_dispatched:
+                    # the first dispatch of a new step program is where it
+                    # is traced, lowered and compiled (or loaded): lazy in
+                    # jit and in the program cache's wrapper alike
+                    self._step_dispatched = self._step
+                    with job_stage(self.job_id, "build_step", first=True):
+                        return self._dispatch_step(self._step, batch_dev,
+                                                   hyper)
                 return self._dispatch_step(self._step, batch_dev, hyper)
             except ValueError as e:
                 if not self._is_layout_race(e):
@@ -2107,7 +2149,8 @@ class WorkerTasklet:
             # collide FIFO at the raw dispatch lock behind peers' units —
             # both delaying this job's start and jittering the peers
             with self._turn(), self._taskunit_scope("CPU"):
-                self.trainer.init_global_settings(ctx)
+                with job_stage(self.job_id, "init"):
+                    self.trainer.init_global_settings(ctx)
         elif self._balanced_turns() or self.taskunit is not None:
             # siblings announce the SAME init unit (empty region): the
             # TaskUnit quorum needs every worker to wait on each (seq,
@@ -2141,11 +2184,23 @@ class WorkerTasklet:
 
     def _run_epoch_loop(self, params) -> Dict[str, Any]:
         ctx = self.ctx
-        self._build_step()
+        with job_stage(self.job_id, "build_step"):
+            self._build_step()
+        self._budget_mark = time.perf_counter()
+        # job.first_window: from here to the end of the first drained
+        # window's bookkeeping — when the job's counters first move
+        first_window = contextlib.ExitStack()
+        first_window.enter_context(job_stage(self.job_id, "first_window"))
+        try:
+            return self._epoch_loop_body(params, first_window)
+        finally:
+            first_window.close()
+
+    def _epoch_loop_body(self, params, first_window) -> Dict[str, Any]:
+        ctx = self.ctx
         stop = False
         global_batch_idx = 0
         epoch_losses: List[float] = []
-
         epoch = self.starting_epoch
         while epoch < params.num_epochs and not stop:
             # chief-only (the split is a property of the shared table, not
@@ -2183,7 +2238,7 @@ class WorkerTasklet:
                         with trace_span("dolphin.comm_probe",
                                         job_id=self.job_id, epoch=epoch):
                             with self._turn(), scope:
-                                self._probe_comm(first)
+                                self._timed_probe(first)
             window = self._epoch_window_len(epoch, params.num_epochs)
             if window > 1:
                 # Multi-epoch window: dispatches chain on the table state
@@ -2217,22 +2272,31 @@ class WorkerTasklet:
                                 epoch, window, global_batch_idx
                             )
                         )
-                for j, (epoch_examples, last_metrics, nb) in enumerate(results):
-                    # account THIS epoch's ops just before its callback
-                    # replays, so the callback's ServerMetrics delta covers
-                    # exactly one epoch
-                    self._account_ops(nb)
-                    self._finish_epoch(
-                        epoch + j,
-                        time.perf_counter() - per_epoch_sec,
-                        epoch_examples,
-                        last_metrics,
-                        epoch_losses,
-                        # all but the window's LAST hook ran between
-                        # dispatches; the last runs here, post-drain, as in
-                        # the unfused loop
-                        call_trainer_hook=(j == len(results) - 1),
-                    )
+                # an epoch's wall is the window's / k, so the window's
+                # control seconds are fed / k too (as drain_t / k is)
+                wall, ctl = self._take_budget_feed(window)
+                with trace_span("window.bookkeeping", job_id=self.job_id,
+                                epoch=epoch, epochs=window,
+                                acc=self._add_bookkeeping):
+                    for j, (epoch_examples, last_metrics, nb) in enumerate(
+                            results):
+                        # account THIS epoch's ops just before its callback
+                        # replays, so the callback's ServerMetrics delta
+                        # covers exactly one epoch
+                        self._account_ops(nb)
+                        self._finish_epoch(
+                            epoch + j,
+                            time.perf_counter() - per_epoch_sec,
+                            epoch_examples,
+                            last_metrics,
+                            epoch_losses,
+                            # all but the window's LAST hook ran between
+                            # dispatches; the last runs here, post-drain, as
+                            # in the unfused loop
+                            call_trainer_hook=(j == len(results) - 1),
+                            budget_wall=wall, budget_ctl=ctl,
+                        )
+                first_window.close()
                 epoch += window
                 continue
             epoch_t0 = time.perf_counter()
@@ -2260,7 +2324,14 @@ class WorkerTasklet:
                     span.discard()
             if epoch_examples == 0 and stop:
                 break  # stopped before any batch: not an epoch at all
-            self._finish_epoch(epoch, epoch_t0, epoch_examples, last_metrics, epoch_losses)
+            wall, ctl = self._take_budget_feed(1)
+            with trace_span("window.bookkeeping", job_id=self.job_id,
+                            epoch=epoch, epochs=1,
+                            acc=self._add_bookkeeping):
+                self._finish_epoch(epoch, epoch_t0, epoch_examples,
+                                   last_metrics, epoch_losses,
+                                   budget_wall=wall, budget_ctl=ctl)
+            first_window.close()
             epoch += 1
         self.trainer.cleanup(ctx)
         return {
@@ -2352,10 +2423,11 @@ class WorkerTasklet:
             # without per-step syncs; smear the epoch's work time (barrier
             # waits excluded) evenly — averages feeding the optimizer stay
             # right, per-batch variance is deliberately given up.
-            last_metrics = self._emit_batch_metrics(
-                epoch, host, batch_sizes, work_t / len(pending),
-                dispatch_sec=dispatch_sec,
-            )
+            with trace_span("drain.emit", acc=self._add_bookkeeping):
+                last_metrics = self._emit_batch_metrics(
+                    epoch, host, batch_sizes, work_t / len(pending),
+                    dispatch_sec=dispatch_sec,
+                )
             self._account_ops(len(pending))
         return epoch_examples, last_metrics, global_batch_idx, stop
 
@@ -2408,11 +2480,15 @@ class WorkerTasklet:
         stop = False
         pending: List[Dict[str, jnp.ndarray]] = []
         batch_sizes: List[int] = []
-        hyper = self._hyper()
         work_t = 0.0  # dispatch time, EXCLUDING admission/barrier waits
-        it = self._epoch_batch_stream(epoch)
-        try:
+        # between two epochs' dispatches the host puts the hyperparameters
+        # on the device and opens the next batch stream: a light span, so a
+        # device that runs dry here says so
+        with trace_span("epoch.turnover", record=False):
+            hyper = self._hyper()
+            it = self._epoch_batch_stream(epoch)
             nxt = next(it, None)
+        try:
             while nxt is not None and not stop:
                 with self._turn():
                     if self._pending_probe is not None:
@@ -2422,7 +2498,7 @@ class WorkerTasklet:
                         first, self._pending_probe = self._pending_probe, None
                         with trace_span("dolphin.comm_probe",
                                         job_id=self.job_id, epoch=epoch):
-                            self._probe_comm(first)
+                            self._timed_probe(first)
                     if self.batch_barrier is not None:  # SYNC TaskUnit
                         stop = self.batch_barrier(global_batch_idx)
                         if stop:
@@ -2440,16 +2516,20 @@ class WorkerTasklet:
                         while nxt is not None and done < group:
                             batch_idx, batch, staged = nxt
                             t0 = time.perf_counter()
-                            metrics = self._dispatch_batch(
-                                batch_idx, batch, hyper, staged
-                            )
+                            with trace_span("step.dispatch", record=False):
+                                metrics = self._dispatch_batch(
+                                    batch_idx, batch, hyper, staged
+                                )
                             pending.append(metrics)
                             cap = self._inflight_cap()
                             if len(pending) >= cap:
                                 # Sliding window: block on the OLDEST
                                 # outstanding step so the device queue stays
                                 # full.
-                                jax.block_until_ready(pending[len(pending) - cap])
+                                with trace_span("step.backpressure",
+                                                record=False):
+                                    jax.block_until_ready(
+                                        pending[len(pending) - cap])
                             # dt spans dispatch AND the backpressure sync: on
                             # async backends the sync absorbs real device time
                             # that would otherwise land in neither work_t nor
@@ -2519,7 +2599,7 @@ class WorkerTasklet:
         # that inverts a rendezvous and aborts the process
         # (parallel/dispatch.py). The D2H copies below stay outside.
         combined = None
-        with self.ctx.model_table._lock:
+        with trace_span("drain.stack"), self.ctx.model_table._lock:
             with dispatch_scope(self.mesh) as finish:
                 stacked = finish({
                     k: [jnp.stack([m[k] for m in r]) for r in runs]
@@ -2546,18 +2626,21 @@ class WorkerTasklet:
                             [stacked[k][0] for k in ks])))
                         for dt, ks in groups.items()
                     }
-        if combined is not None:
-            host = {}
-            for ks, arr in combined.values():
-                mat = np.asarray(arr)          # one D2H per dtype
-                for i, k in enumerate(ks):
-                    host[k] = np.atleast_1d(mat[i])
-        else:
-            host = {
-                k: np.concatenate(
-                    [np.atleast_1d(np.asarray(s)) for s in v])
-                for k, v in stacked.items()
-            }
+        # the copies wait for every step still in flight: this is where
+        # the host stands while the device finishes the window
+        with trace_span("drain.d2h"):
+            if combined is not None:
+                host = {}
+                for ks, arr in combined.values():
+                    mat = np.asarray(arr)          # one D2H per dtype
+                    for i, k in enumerate(ks):
+                        host[k] = np.atleast_1d(mat[i])
+            else:
+                host = {
+                    k: np.concatenate(
+                        [np.atleast_1d(np.asarray(s)) for s in v])
+                    for k, v in stacked.items()
+                }
         return host
 
     def _run_batched_epochs_window(
@@ -2577,11 +2660,12 @@ class WorkerTasklet:
             )
             per_epoch.append((pending, sizes, examples, work_t,
                               self._take_dispatch_sec()))
-            # next epoch's producer overlaps either the next dispatch run
-            # (j+1 < k) or the window drain below
-            self._spawn_next_pipeline(first_epoch + j + 1)
-            if j + 1 < k:
-                self.trainer.on_epoch_finished(self.ctx, first_epoch + j)
+            with trace_span("epoch.turnover", record=False):
+                # next epoch's producer overlaps either the next dispatch
+                # run (j+1 < k) or the window drain below
+                self._spawn_next_pipeline(first_epoch + j + 1)
+                if j + 1 < k:
+                    self.trainer.on_epoch_finished(self.ctx, first_epoch + j)
         all_pending = [m for p, _, _, _, _ in per_epoch for m in p]
         drain_t = 0.0
         host: Dict[str, np.ndarray] = {}
@@ -2598,22 +2682,55 @@ class WorkerTasklet:
             drain_t = time.perf_counter() - t0
         out = []
         off = 0
-        for pending, sizes, examples, work_t, disp_t in per_epoch:
-            nb = len(pending)
-            last: Dict[str, float] = {}
-            if nb:
-                epoch_host = {key: v[off:off + nb] for key, v in host.items()}
-                last = self._emit_batch_metrics(
-                    first_epoch + len(out), epoch_host, sizes,
-                    (work_t + drain_t / k) / nb,
-                    dispatch_sec=disp_t,
-                )
-            off += nb
-            # accounting deferred to run()'s replay loop (see
-            # _run_fused_epochs) so ServerMetrics deltas stay per-epoch
-            out.append((examples, last, nb))
+        # per-batch records, the ledger and the work split of k epochs:
+        # host bookkeeping the device does not wait for — unless both
+        # tenants do it at once
+        with trace_span("drain.emit", acc=self._add_bookkeeping):
+            for pending, sizes, examples, work_t, disp_t in per_epoch:
+                nb = len(pending)
+                last: Dict[str, float] = {}
+                if nb:
+                    epoch_host = {key: v[off:off + nb]
+                                  for key, v in host.items()}
+                    last = self._emit_batch_metrics(
+                        first_epoch + len(out), epoch_host, sizes,
+                        (work_t + drain_t / k) / nb,
+                        dispatch_sec=disp_t,
+                    )
+                off += nb
+                # accounting deferred to run()'s replay loop (see
+                # _run_fused_epochs) so ServerMetrics deltas stay per-epoch
+                out.append((examples, last, nb))
         per_epoch_sec = (time.perf_counter() - t_start) / k
         return out, global_batch_idx, per_epoch_sec
+
+    def _add_grant_wait(self, sec: float) -> None:
+        self._phase_ctl["grant_wait"] += sec
+
+    def _add_bookkeeping(self, sec: float) -> None:
+        self._phase_ctl["bookkeeping"] += sec
+
+    def _timed_probe(self, first) -> None:
+        """The comm probe, its seconds (admission excluded: the caller
+        holds the turn/unit already) added to the ``probe`` phase."""
+        t0 = time.perf_counter()
+        try:
+            self._probe_comm(first)
+        finally:
+            self._phase_ctl["probe"] += time.perf_counter() - t0
+
+    def _take_budget_feed(self, k: int) -> Tuple[float, Dict[str, float]]:
+        """``(wall per epoch, control phases per epoch)`` of the ``k``
+        epochs that just ran: the wall since the last feed's end and the
+        control seconds accumulated since, both / k; resets both."""
+        now = time.perf_counter()
+        mark = self._budget_mark if self._budget_mark is not None else now
+        self._budget_mark = now
+        k = max(int(k), 1)
+        ctl = {p: v / k for p, v in self._phase_ctl.items()}
+        for p in self._phase_ctl:
+            self._phase_ctl[p] = 0.0
+        return (now - mark) / k, ctl
 
     def _take_dispatch_sec(self) -> float:
         """Drain the host-dispatch accumulator (one epoch's placement
@@ -2752,7 +2869,11 @@ class WorkerTasklet:
                 devices=int(self.mesh.devices.size),
             )
             self._phase_pending[epoch] = {
-                "host_dispatch": float(dispatch_sec), **split}
+                "host_dispatch": float(dispatch_sec), **split,
+                # pull/compute/push: the unfused step's own timers, else
+                # the probe's seconds applied to the step wall — a model
+                "device_split": ("measured" if measured is not None
+                                 else "modelled")}
         except Exception:
             pass
         return {k: float(v[-1]) for k, v in host.items()}
@@ -2878,7 +2999,9 @@ class WorkerTasklet:
         if hit is not None:
             self._stacked_cache = hit
             return
-        with trace_span("dolphin.dataset_upload", job_id=self.job_id):
+        # a part of the job's data load: its seconds join that stage
+        with trace_span("dolphin.dataset_upload", job_id=self.job_id,
+                        acc=job_stage_adder(self.job_id, "data_load")):
             batches = list(self.data.epoch_batches())
             stacked_sharding = NamedSharding(table.mesh, P(None, DATA_AXIS))
             self._stacked_cache = tuple(
@@ -2986,7 +3109,9 @@ class WorkerTasklet:
         return float(metrics[k]) if k is not None else 0.0
 
     def _finish_epoch(self, epoch, epoch_t0, epoch_examples, last_metrics,
-                      epoch_losses, call_trainer_hook: bool = True):
+                      epoch_losses, call_trainer_hook: bool = True,
+                      budget_wall: Optional[float] = None,
+                      budget_ctl: Optional[Dict[str, float]] = None):
         # epoch-boundary fault site: the fused/windowed paths dispatch
         # whole epochs without per-batch host steps, so this is the
         # boundary every path crosses (checkpoint hooks fire right after)
@@ -3040,9 +3165,16 @@ class WorkerTasklet:
                 from harmony_tpu.metrics.phases import budget
 
                 ph["input_wait"] = input_wait
+                # grant_wait / probe / bookkeeping, measured by their
+                # spans; the wall is the one that holds them (see
+                # _take_budget_feed), not the epoch's own timer
+                ph.update(budget_ctl or {})
+                split = ph.pop("device_split", "modelled")
                 budget().observe_epoch(
                     self.job_id, self.attempt_key, self.ctx.worker_id,
-                    epoch, epoch_sec, ph)
+                    epoch,
+                    epoch_sec if budget_wall is None else budget_wall,
+                    ph, device_split=split)
             except Exception:
                 pass
         self._check_slo(epoch, epoch_examples, epoch_sec)
@@ -3120,13 +3252,16 @@ class WorkerTasklet:
     def _taskunit_scope(self, kind: str):
         if self.taskunit is None:
             return contextlib.nullcontext()
-        return self.taskunit.scope(kind)
+        return self.taskunit.scope(kind, wait_acc=self._add_grant_wait)
 
     def _turn(self):
-        """This worker's turnstile admission (pod lockstep), else a no-op."""
+        """This worker's turnstile admission (pod lockstep), else a no-op.
+        Entering the turn is the wait: a ``taskunit.wait`` light span
+        whose seconds join the ``grant_wait`` phase."""
         if self.dispatch_turn is None:
             return contextlib.nullcontext()
-        return self.dispatch_turn()
+        return _TimedAdmission(self.dispatch_turn(), self._add_grant_wait,
+                               self.job_id)
 
     def _balanced_turns(self) -> bool:
         """True when this worker must take no-op turns to keep the cyclic

@@ -32,6 +32,7 @@ from harmony_tpu.dolphin.trainer import TrainerContext
 from harmony_tpu.dolphin.worker import WorkerTasklet
 from harmony_tpu.metrics.collector import MetricCollector
 from harmony_tpu.runtime.master import ETMaster, TableHandle
+from harmony_tpu.tracing.span import job_stage
 from harmony_tpu.runtime.taskunit import (
     GlobalTaskUnitScheduler,
     LocalTaskUnitScheduler,
@@ -216,6 +217,14 @@ class DolphinJobEntity(JobEntity):
             self._setup_inner(master, executor_ids)
 
     def _setup_inner(self, master: ETMaster, executor_ids: List[str]) -> None:
+        with job_stage(self.config.job_id, "table_create"):
+            self._create_tables(master, executor_ids)
+        self._executor_ids = list(executor_ids)
+        with job_stage(self.config.job_id, "data_load"):
+            self._data_arrays = self._make_data()
+
+    def _create_tables(self, master: ETMaster,
+                       executor_ids: List[str]) -> None:
         self._master = master
         cfg = self.config
         data_axis = max(1, cfg.user.get("data_axis", 1))
@@ -277,8 +286,6 @@ class DolphinJobEntity(JobEntity):
             local_cfg = probe.local_table_config()
             local_cfg = local_cfg.replace(table_id=f"{cfg.job_id}:{local_cfg.table_id}")
             self._local_handle = master.create_table(local_cfg, executor_ids, data_axis)
-        self._executor_ids = list(executor_ids)
-        self._data_arrays = self._make_data()
 
     # -- run (the DolphinMaster.start analogue) --------------------------
 

@@ -44,6 +44,9 @@ from harmony_tpu.tracing.span import (
     SpanContext,
     current_span,
     get_tracing,
+    job_stage_adder,
+    job_stage_seconds,
+    record_span,
     trace_span,
     wire_context,
 )
@@ -61,6 +64,9 @@ class NotLeader(RuntimeError):
 class JobResult:
     def __init__(self) -> None:
         self.future: "Future[Dict[str, Any]]" = Future()
+        #: when the submission was registered (``time.monotonic_ns``):
+        #: the start of ``job.grant_wait``
+        self.submitted_ns = time.monotonic_ns()
 
 
 def _json_sanitize(obj: Any) -> Any:
@@ -668,6 +674,9 @@ class JobServer:
 
     def _dispatch_job(self, config: JobConfig, executor_ids: List[str]) -> None:
         jr = self._jobs[config.job_id]
+        # queued -> mesh granted: began on the submitting thread, ends here
+        record_span("job.grant_wait", jr.submitted_ns, job_id=config.job_id,
+                    acc=job_stage_adder(config.job_id, "grant_wait"))
         jlog = job_logger(config.job_id)
         jlog.info("dispatched on executors %s", executor_ids)
         from harmony_tpu.jobserver import elastic as _el
@@ -944,7 +953,7 @@ class JobServer:
     def _status(self) -> Dict[str, Any]:
         """STATUS reply body (subclasses extend, e.g. pod health)."""
         from harmony_tpu.jobserver import joblog
-
+        from harmony_tpu.runtime import progcache
         from harmony_tpu.tracing import flight
 
         # ONE straggler walk per STATUS: the report, the ledger join
@@ -980,6 +989,15 @@ class JobServer:
             # until now xplane dumps landed and nothing referenced them
             "profile_capture": flight.profile_capture_path(),
             "flight_records": flight.get_recorder().records(),
+            # every span open in this process right now and the longest
+            # closed span per description (tracing/span.py): a stall
+            # names itself here while it lasts, and afterwards
+            "flight_spans": flight.get_recorder().span_watch(),
+            # JAX's own compiles by the job whose span they ran under
+            # (runtime/progcache.py): "which job recompiled, and when"
+            "compiles": progcache.compiles_by_job(),
+            # seconds of each job's start by stage (the job.<stage> spans)
+            "job_stages": job_stage_seconds(),
             "metrics_port": (self.metrics_exporter.port
                              if self.metrics_exporter is not None else None),
             # telemetry history + doctor (metrics/history.py + doctor.py):
@@ -1223,7 +1241,11 @@ class JobServer:
                             # successor can replay
                             reply = self._not_leader_reply()
                 elif cmd == "STATUS":
-                    reply = self._status()
+                    # walks the ledger and the budget under the GIL,
+                    # beside the dispatching threads: a span, so a device
+                    # gap that falls under it says so
+                    with trace_span("jobserver.status"):
+                        reply = self._status()
                 elif cmd == "WAIT":
                     # bounded wait on a submission's result — the
                     # failover client's way to follow ONE submission

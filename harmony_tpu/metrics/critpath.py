@@ -43,9 +43,6 @@ COMM_BOUND_FRAC = 0.4
 DISPATCH_BOUND_FRAC = 0.3
 COMPUTE_BOUND_FRAC = 0.6
 
-#: the device-work phases a critical-path entry may name as gating
-_DEVICE_PHASES = ("pull_comm", "compute", "push_comm")
-
 
 def comm_fraction(fractions: Dict[str, float]) -> float:
     """Combined model-traffic fraction (pull + push) of one budget."""

@@ -23,13 +23,20 @@ CLOSED phase set:
   wall, refined by ``cost_analysis`` FLOP seconds when the backend
   exposes a cost model (the probe can overestimate comm on tiny
   tables; compute never drops below its FLOP floor);
+* ``grant_wait``    — admission: every ``taskunit.wait`` (COMP / NET /
+  CPU units) and dispatch-turn entry of the worker's training thread,
+  measured by the spans' own clock reads (tracing/span.py ``acc``);
+* ``probe``         — the blocking comm probe, admission excluded;
+* ``bookkeeping``   — the post-drain replay of ``_account_ops`` +
+  ``_finish_epoch`` (span ``window.bookkeeping``); the three are carved
+  out of what used to be ``residual`` and nothing else;
 * ``barrier_wait``  — the chief-observed gap between a worker's last
   step and the epoch drain (computed from sibling workers' epoch walls
   at the same epoch index — the straggler report says *who*, this says
   what the fast workers paid waiting);
-* ``residual``      — everything unattributed (admission waits, metric
-  drains' host share, epoch bookkeeping), kept as an EXPLICIT series,
-  never silently absorbed into a real phase.
+* ``residual``      — everything unattributed (metric drains' host
+  share, trainer hooks between epochs, whatever no span covers), kept as
+  an EXPLICIT series, never silently absorbed into a real phase.
 
 **Budget invariant**: per window, ``sum(phases) + residual == wall``
 within tolerance — feeds are sanitized (no negative phase, and a feed
@@ -60,8 +67,8 @@ ENV_PHASE_WINDOW = "HARMONY_PHASE_WINDOW"
 #: the closed phase taxonomy, in waterfall order (docs/OBSERVABILITY.md
 #: §9 documents each); ``residual`` rides beside them as the explicit
 #: unattributed series
-PHASES = ("input_wait", "host_dispatch", "pull_comm", "compute",
-          "push_comm", "barrier_wait")
+PHASES = ("input_wait", "host_dispatch", "grant_wait", "pull_comm",
+          "compute", "push_comm", "probe", "bookkeeping", "barrier_wait")
 RESIDUAL = "residual"
 
 #: feed samples kept per tenant — one per worker-epoch; covers days of
@@ -152,11 +159,15 @@ def split_device_phases(work_sec: float, steps: int, *,
 class _TenantPhases:
     """Mutable per-job phase state; all mutation under the store lock."""
 
-    __slots__ = ("job", "attempt", "samples")
+    __slots__ = ("job", "attempt", "samples", "device_split")
 
     def __init__(self, job: str) -> None:
         self.job = job
         self.attempt = job
+        #: how pull_comm / compute / push_comm were told apart in the
+        #: newest feed: "measured" (the unfused step's own timers) or
+        #: "modelled" (the probe's seconds applied to the fused step wall)
+        self.device_split = "modelled"
         #: (ts, attempt, worker, epoch_idx, wall_sec, {phase: sec}) —
         #: the attempt rides each sample so the barrier join never
         #: mixes epoch walls across an elastic restart (attempt 2
@@ -184,7 +195,8 @@ class PhaseBudgetStore:
 
     def observe_epoch(self, job: str, attempt: str, worker: str,
                       epoch_idx: int, wall_sec: float,
-                      phases: Dict[str, float]) -> None:
+                      phases: Dict[str, float],
+                      device_split: str = "modelled") -> None:
         """One worker-epoch's budget feed. Sanitized at the door: every
         phase is clamped non-negative, and a feed whose measured phases
         exceed its wall (elastic shrink truncating the epoch mid-window,
@@ -204,6 +216,7 @@ class PhaseBudgetStore:
                 t = self._tenants[job] = _TenantPhases(job)
             if attempt:
                 t.attempt = attempt
+            t.device_split = str(device_split)
             t.samples.append((now, str(attempt or job), str(worker),
                               int(epoch_idx), wall, clean))
             self._version += 1
@@ -214,8 +227,8 @@ class PhaseBudgetStore:
                  ) -> Dict[str, Dict[str, Any]]:
         """Per-tenant phase budgets over the window. Each row:
 
-        ``{job, attempt, window_sec, wall_sec, epochs, phases,
-        fractions, per_worker, epoch_walls}`` — ``phases`` maps every
+        ``{job, attempt, window_sec, wall_sec, epochs, device_split,
+        phases, fractions, per_worker, epoch_walls}`` — ``phases`` maps every
         taxonomy phase plus ``residual`` to windowed seconds;
         ``fractions`` the same over the tenant's wall (sums to 1.0 when
         wall > 0); ``per_worker`` one budget per worker;
@@ -233,10 +246,10 @@ class PhaseBudgetStore:
              else phase_window_seconds())
         cutoff = time.monotonic() - w
         with self._lock:
-            tenants = [(t.job, t.attempt, list(t.samples))
+            tenants = [(t.job, t.attempt, list(t.samples), t.device_split)
                        for t in self._tenants.values()]
         rows: Dict[str, Dict[str, Any]] = {}
-        for job, attempt, samples in tenants:
+        for job, attempt, samples, device_split in tenants:
             live = [(ts, wk, ep, wall, ph)
                     for (ts, att, wk, ep, wall, ph) in samples
                     if ts >= cutoff and att == attempt]
@@ -278,6 +291,9 @@ class PhaseBudgetStore:
                 "window_sec": w,
                 "wall_sec": round(wall_sum, 6),
                 "epochs": len(epoch_walls),
+                # pull_comm / compute / push_comm are a model in fused
+                # mode: no reader may mistake one for the other
+                "device_split": device_split,
                 "phases": {p: round(v, 6) for p, v in phases.items()},
                 "fractions": _fractions(phases, wall_sum),
                 "per_worker": {
@@ -397,9 +413,9 @@ def _install_callbacks() -> None:
         get_registry().register_callback(
             "harmony_phase_budget_seconds",
             "Windowed per-phase wall seconds per worker (input_wait / "
-            "host_dispatch / pull_comm / compute / push_comm / "
-            "barrier_wait / residual; phases + residual sum to the "
-            "window wall)",
+            "host_dispatch / grant_wait / pull_comm / compute / push_comm "
+            "/ probe / bookkeeping / barrier_wait / residual; phases + "
+            "residual sum to the window wall)",
             "gauge", sample)
     except Exception:
         pass  # already registered by an earlier store in this process

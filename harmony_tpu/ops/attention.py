@@ -197,6 +197,7 @@ def _flash_forward(q, k, v, causal, block_q, block_k, scale, interpret):
     )
     out, lse = pl.pallas_call(
         kernel,
+        name="harmony_flash_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
@@ -334,6 +335,7 @@ def _flash_backward(q, k, v, out, lse, do, causal, block_q, block_k, scale,
         block_q=block_q, block_k=block_k)
     dk, dv = pl.pallas_call(
         dkv,
+        name="harmony_flash_bwd_dkv",
         grid=(B * H, Sk // block_k, Sq // block_q),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
         out_specs=[
@@ -356,6 +358,7 @@ def _flash_backward(q, k, v, out, lse, do, causal, block_q, block_k, scale,
         block_q=block_q, block_k=block_k)
     dq = pl.pallas_call(
         dqk,
+        name="harmony_flash_bwd_dq",
         grid=(B * H, Sq // block_q, Sk // block_k),
         in_specs=[q_spec2, kv_spec2, kv_spec2, q_spec2, row_spec2, row_spec2],
         out_specs=pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),

@@ -34,6 +34,7 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, Hashable, List, Optional, Tuple
 
 import jax
+import jax.monitoring
 from jax.sharding import Mesh
 
 _MAX_ENTRIES = 32
@@ -390,6 +391,69 @@ def drop(predicate) -> int:
 def stats() -> dict:
     with _lock:
         return dict(_stats, entries=len(_cache))
+
+
+# -- JAX's own compile durations, by job -------------------------------------
+#
+# A trainer whose ``jit_signature()`` is None (the LM's) bypasses the cache
+# above, so nothing there sees its step compile. JAX reports every trace,
+# lowering and backend compile (or persistent-cache load) through
+# ``jax.monitoring`` on the compiling thread; the job is the one of the span
+# open there (tracing/span.py ``current_job``).
+
+_COMPILE_EVENTS = "/jax/core/compile/"
+_BACKEND_COMPILE = "backend_compile_duration"
+
+
+def _compile_families():
+    from harmony_tpu.metrics.registry import get_registry
+
+    reg = get_registry()
+    return (reg.counter(
+                "harmony_compile_seconds_total",
+                "Seconds of JAX trace / lowering / backend compile by job",
+                ("job", "stage")),
+            reg.counter(
+                "harmony_compiles_total",
+                "Backend compiles (or persistent-cache loads) by job",
+                ("job",)))
+
+
+def _on_jax_duration(name: str, seconds: float, **_: Any) -> None:
+    if not name.startswith(_COMPILE_EVENTS):
+        return
+    from harmony_tpu.tracing.span import current_job
+
+    job = current_job() or "-"
+    stage = name[len(_COMPILE_EVENTS):]
+    try:  # the registry must never fail a compile
+        seconds_by, compiles_by = _compile_families()
+        seconds_by.labels(job=job, stage=stage).inc(float(seconds))
+        if stage == _BACKEND_COMPILE:
+            compiles_by.labels(job=job).inc()
+    except Exception:
+        pass
+
+
+def compiles_by_job() -> Dict[str, Dict[str, float]]:
+    """``{job: {compiles, seconds}}`` of every compile JAX made in this
+    process under a span of that job (``-``: under none), read back from
+    the two counters — STATUS ``compiles``."""
+    out: Dict[str, Dict[str, float]] = {}
+    try:
+        seconds_by, compiles_by = _compile_families()
+        for (job, _stage), child in seconds_by.children():
+            row = out.setdefault(job, {"compiles": 0, "seconds": 0.0})
+            row["seconds"] = round(row["seconds"] + child.value, 6)
+        for (job,), child in compiles_by.children():
+            out.setdefault(job, {"compiles": 0, "seconds": 0.0})[
+                "compiles"] = int(child.value)
+    except Exception:
+        return {}
+    return out
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
 
 
 def clear() -> None:

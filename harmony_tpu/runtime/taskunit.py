@@ -29,6 +29,8 @@ import time
 from collections import deque
 from typing import Dict, List, Optional, Set, Tuple
 
+from harmony_tpu.tracing.span import trace_span
+
 # Unit kinds and which slot pool they consume (VOID consumes nothing —
 # barrier/sync phases, ref TaskUnitInfo ResourceType VOID).
 CPU = "CPU"
@@ -427,7 +429,8 @@ class TaskUnitClient:
         self._seq = itertools.count()
 
     @contextlib.contextmanager
-    def scope(self, phase: str, abort=None, poll: float = 0.25):
+    def scope(self, phase: str, abort=None, poll: float = 0.25,
+              wait_acc=None):
         """Accepts a phase name (PULL/COMP/PUSH/SYNC) or a raw resource
         kind. ``abort`` (optional callable) makes the admission wait
         interruptible: polled every ``poll`` seconds; when it returns True
@@ -435,21 +438,28 @@ class TaskUnitClient:
         that raced the abort is finished empty so the meter stays
         balanced). Background producers use it so their teardown never
         hangs on a grant that can no longer arrive (e.g. the job's
-        executor already left the quorum)."""
+        executor already left the quorum).
+
+        The admission wait — from the ask to the local slot taken — is the
+        light span ``taskunit.wait`` (tracing/span.py): an event on the
+        profiler's clock, visible as OPEN while it lasts, its seconds
+        handed to ``wait_acc`` (the worker's ``grant_wait`` phase)."""
         kind = PHASE_RESOURCE[phase]
         unit = TaskUnitInfo(self.job_id, self.executor_id, kind, next(self._seq))
-        if abort is None:
-            self._global.wait_ready(unit)
-        else:
-            while not self._global.wait_ready(unit, timeout=poll):
-                if abort():
-                    if self._global.cancel_wait(unit):
-                        self._global.on_unit_finished(unit)  # raced grant
-                    raise TaskUnitAborted(
-                        f"{self.job_id}/{self.executor_id} {kind} admission "
-                        "wait aborted"
-                    )
-        self._local.acquire(kind)
+        with trace_span("taskunit.wait", record=False, acc=wait_acc,
+                        job_id=self.job_id, kind=kind):
+            if abort is None:
+                self._global.wait_ready(unit)
+            else:
+                while not self._global.wait_ready(unit, timeout=poll):
+                    if abort():
+                        if self._global.cancel_wait(unit):
+                            self._global.on_unit_finished(unit)  # raced grant
+                        raise TaskUnitAborted(
+                            f"{self.job_id}/{self.executor_id} {kind} "
+                            "admission wait aborted"
+                        )
+            self._local.acquire(kind)
         try:
             yield
         finally:

@@ -7,10 +7,14 @@ from harmony_tpu.tracing.span import (
     Tracing,
     current_span,
     get_tracing,
+    job_stage,
+    open_spans,
+    longest_spans,
+    record_span,
     set_tracing,
     trace_span,
 )
-from harmony_tpu.tracing.profiler import device_trace, profile_session
+from harmony_tpu.tracing.profiler import profile_session
 from harmony_tpu.tracing.flight import FlightRecorder, get_recorder
 
 __all__ = [
@@ -24,8 +28,11 @@ __all__ = [
     "Tracing",
     "trace_span",
     "current_span",
+    "job_stage",
+    "open_spans",
+    "longest_spans",
+    "record_span",
     "get_tracing",
     "set_tracing",
-    "device_trace",
     "profile_session",
 ]

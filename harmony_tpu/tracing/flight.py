@@ -14,6 +14,12 @@ dumps it to a JSON file at the moments that matter:
   * ``SIGTERM`` lands on a long-running entry point
     (:func:`install_signal_dump` — wired by the CLI, never on import).
 
+Beside the ring the recorder reports every span STILL OPEN in the
+process and the LONGEST closed span of each description (tracing/span.py
+keeps both for either span form): STATUS ``flight_spans`` and every dump
+carry them, so a stall names itself (``open: taskunit.wait 4.2 s,
+job_id=...``) while it lasts and afterwards, with nothing attached.
+
 Each dump is correlated: it carries every ``trace_id`` seen in the ring
 and the elastic ``attempt_key`` (``job@aN``) when the trigger's context
 names one, so ``harmony-tpu obs flight`` / the STATUS endpoint can join
@@ -33,7 +39,13 @@ import time
 from collections import deque
 from typing import Any, Dict, List, Optional
 
-from harmony_tpu.tracing.span import Span, SpanReceiver, get_tracing
+from harmony_tpu.tracing.span import (
+    Span,
+    SpanReceiver,
+    get_tracing,
+    longest_spans,
+    open_spans,
+)
 
 ENV_DIR = "HARMONY_FLIGHT_DIR"
 ENV_CAP = "HARMONY_FLIGHT_CAP"
@@ -160,6 +172,13 @@ class FlightRecorder(SpanReceiver):
         with self._lock:
             self._ring.append(rec)
 
+    def span_watch(self, limit: int = 32) -> Dict[str, Any]:
+        """``{"open": [...], "longest": [...]}``: the spans open right now
+        (longest-open first) and the longest closed span per description —
+        the ring keeps the last 256 records, these keep the outliers."""
+        return {"open": open_spans()[:limit],
+                "longest": longest_spans()[:limit]}
+
     def ring_size(self) -> int:
         with self._lock:
             return len(self._ring)
@@ -214,6 +233,9 @@ class FlightRecorder(SpanReceiver):
             # (metrics/incidents.py): open episodes with their causal
             # chains, beside the diagnoses that fed them
             "incidents": _incidents_snapshot(),
+            # what was open when this was written, and the longest closed
+            # span of each kind: the stall, by name
+            "spans": self.span_watch(),
             "records": records,
         }
         path = os.path.join(

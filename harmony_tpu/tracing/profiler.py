@@ -3,9 +3,9 @@
 SURVEY.md §5.9 maps the reference's HTrace wiring to "native profiler hooks
 (xprof/jax profiler) + spans" on TPU. This module is that bridge:
 
-  * ``device_trace(name)`` — annotate a region so it shows up named in a
-    captured device profile (jax.profiler.TraceAnnotation), AND as a host
-    span via tracing.span (one call sites both worlds);
+  * naming a region in a captured profile is ``trace_span``'s own job
+    (tracing/span.py opens the ``jax.profiler.TraceAnnotation``): one call
+    sites both worlds, and there is no second entry point here;
   * ``profile_session(logdir)`` — capture a full device trace
     (jax.profiler.start_trace/stop_trace) around a code region; the
     resulting xplane dump is the TPU analogue of a Zipkin trace for kernels;
@@ -34,20 +34,6 @@ ENV_EVERY_N = "HARMONY_PROFILE_EVERY_N"
 ENV_DIR = "HARMONY_PROFILE_DIR"
 ENV_MAX_BYTES = "HARMONY_PROFILE_MAX_BYTES"
 _DEFAULT_MAX_BYTES = 256 * 1024 * 1024
-
-
-@contextlib.contextmanager
-def device_trace(name: str, **annotations) -> Iterator[None]:
-    """Host span + device TraceAnnotation with one context manager."""
-    try:
-        import jax.profiler
-
-        ann = jax.profiler.TraceAnnotation(name)
-    except Exception:  # pragma: no cover - profiler always importable in CI
-        ann = contextlib.nullcontext()
-    with trace_span(name, **annotations):
-        with ann:
-            yield
 
 
 @contextlib.contextmanager

@@ -22,36 +22,114 @@ Rebuilt here dependency-free:
     a compact dict carried inside control-plane messages so master↔worker
     protocol spans keep their parents across the jobserver's TCP boundary.
 
-Device-side profiling (the xprof/jax-profiler hook the survey calls for) is
-in tracing/profiler.py.
+One instrument, two sinks, one clock (docs/OBSERVABILITY.md §1):
+
+  * every ``trace_span`` also opens a ``jax.profiler.TraceAnnotation`` named
+    ``harmony/<description>`` — a flag test with no profiler session, and
+    with one an event on the calling thread's line of the trace's
+    ``/host:CPU`` plane, on the same clock as the ``/device:TPU:<n>``
+    planes, so a device idle gap can be named after what the host did;
+  * start/stop are taken on ``time.monotonic_ns()``; ``start_sec`` /
+    ``stop_sec`` are wall seconds derived from one per-process anchor, so
+    durations never depend on NTP while the wire format keeps its shape;
+  * ``record=False`` is the LIGHT form for per-step regions: annotation,
+    open/longest tracking and the ``acc`` callback, but no ``Span``, no ids
+    and nothing emitted to receivers;
+  * every open span (either form) is visible to ``open_spans()`` and the
+    longest closed one per description to ``longest_spans()`` — what the
+    flight recorder shows so a stall names itself.
+
+``profile_session`` and the sampled capture are in tracing/profiler.py.
 """
 from __future__ import annotations
 
-import contextlib
 import contextvars
-import dataclasses
 import json
 import os
+import random
+import sys
 import threading
 import time
-import uuid
-from typing import Any, Dict, Iterator, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Tuple
+
+#: one fixed prefix on the profiler's side, so a reader of the xplane tells
+#: the program's spans from PJRT's own names
+ANNOTATION_PREFIX = "harmony/"
+
+#: the per-process anchor: wall seconds are derived from the monotonic
+#: clock through it, never read again
+_ANCHOR_NS = time.monotonic_ns()
+_ANCHOR_WALL = time.time()
 
 
-@dataclasses.dataclass
+def wall_sec(ns: int) -> float:
+    """Wall-clock seconds of a ``time.monotonic_ns()`` reading."""
+    return _ANCHOR_WALL + (ns - _ANCHOR_NS) * 1e-9
+
+
 class Span:
-    trace_id: str
-    span_id: str
-    parent_id: Optional[str]
-    description: str
-    start_sec: float
-    stop_sec: Optional[float] = None
-    annotations: Dict[str, Any] = dataclasses.field(default_factory=dict)
-    process_id: str = ""
+    """One recorded region. ``trace_id`` / ``span_id`` / ``parent_id`` are
+    made on first read: a span nobody receives and nobody asks the wire
+    context of never pays for them."""
+
+    __slots__ = ("_trace_id", "_span_id", "_parent_id", "_parent_span",
+                 "description", "start_sec", "stop_sec", "annotations",
+                 "process_id", "start_ns", "stop_ns", "job", "_discarded")
+
+    def __init__(self, trace_id: Optional[str] = None,
+                 span_id: Optional[str] = None,
+                 parent_id: Optional[str] = None,
+                 description: str = "",
+                 start_sec: Optional[float] = None,
+                 stop_sec: Optional[float] = None,
+                 annotations: Optional[Dict[str, Any]] = None,
+                 process_id: str = "", *,
+                 start_ns: Optional[int] = None,
+                 parent_span: "Optional[Span]" = None) -> None:
+        self._trace_id = trace_id
+        self._span_id = span_id
+        self._parent_id = parent_id
+        self._parent_span = parent_span
+        self.description = description
+        self.start_ns = start_ns
+        self.stop_ns: Optional[int] = None
+        self.start_sec = (wall_sec(start_ns) if start_sec is None
+                          and start_ns is not None else start_sec)
+        self.stop_sec = stop_sec
+        self.annotations = {} if annotations is None else annotations
+        self.process_id = process_id
+        #: the job this span belongs to — its own ``job_id`` annotation,
+        #: else its parent's (what the compile counters label by)
+        self.job = (self.annotations.get("job_id")
+                    or (parent_span.job if parent_span is not None else None))
+        self._discarded = False
+
+    @property
+    def trace_id(self) -> str:
+        if self._trace_id is None:
+            self._trace_id = (self._parent_span.trace_id
+                              if self._parent_span is not None else _new_id())
+        return self._trace_id
+
+    @property
+    def span_id(self) -> str:
+        if self._span_id is None:
+            self._span_id = _new_id()
+        return self._span_id
+
+    @property
+    def parent_id(self) -> Optional[str]:
+        if self._parent_id is None and self._parent_span is not None:
+            self._parent_id = self._parent_span.span_id
+        return self._parent_id
 
     @property
     def duration_sec(self) -> float:
-        return (self.stop_sec or time.time()) - self.start_sec
+        if self.start_ns is not None:
+            stop = self.stop_ns if self.stop_ns is not None \
+                else time.monotonic_ns()
+            return (stop - self.start_ns) * 1e-9
+        return (self.stop_sec or time.time()) - (self.start_sec or 0.0)
 
     def annotate(self, key: str, value: Any) -> None:
         self.annotations[key] = value
@@ -61,17 +139,35 @@ class Span:
         covers turned out not to have happened — an aborted epoch)."""
         self._discarded = True
 
+    def _close(self, stop_ns: int) -> None:
+        self.stop_ns = stop_ns
+        self.stop_sec = wall_sec(stop_ns)
+
     def to_dict(self) -> Dict[str, Any]:
-        return dataclasses.asdict(self)
+        """The wire / receiver shape (unchanged since the dataclass)."""
+        out = {
+            "trace_id": self.trace_id,
+            "span_id": self.span_id,
+            "parent_id": self.parent_id,
+            "description": self.description,
+            "start_sec": self.start_sec,
+            "stop_sec": self.stop_sec,
+            "annotations": dict(self.annotations),
+            "process_id": self.process_id,
+        }
+        self._parent_span = None  # ids are resolved; let the parent go
+        return out
 
 
-@dataclasses.dataclass(frozen=True)
 class SpanContext:
     """What crosses a process/message boundary (ref: TraceInfo avro record:
     traceId + spanId are enough to re-parent remote child spans)."""
 
-    trace_id: str
-    span_id: str
+    __slots__ = ("trace_id", "span_id")
+
+    def __init__(self, trace_id: str, span_id: str) -> None:
+        self.trace_id = trace_id
+        self.span_id = span_id
 
     def to_wire(self) -> Dict[str, str]:
         return {"trace_id": self.trace_id, "span_id": self.span_id}
@@ -182,28 +278,28 @@ class Tracing:
     def __init__(self, process_id: str = "", sample_rate: float = 1.0) -> None:
         self.process_id = process_id or f"proc-{os.getpid()}"
         self.sample_rate = sample_rate
-        self._receivers: List[SpanReceiver] = []
+        #: replaced whole under the lock, read without it: a span that
+        #: closes with no receiver takes no lock
+        self._receivers: Tuple[SpanReceiver, ...] = ()
         self._lock = threading.Lock()
 
     def add_receiver(self, receiver: SpanReceiver) -> SpanReceiver:
         with self._lock:
-            self._receivers.append(receiver)
+            self._receivers = (*self._receivers, receiver)
         return receiver
 
     def remove_receiver(self, receiver: SpanReceiver) -> None:
         with self._lock:
-            if receiver in self._receivers:
-                self._receivers.remove(receiver)
+            self._receivers = tuple(r for r in self._receivers
+                                    if r is not receiver)
 
     def emit(self, span: Span) -> None:
-        with self._lock:
-            receivers = list(self._receivers)
-        for r in receivers:
+        for r in self._receivers:
             r.receive(span)
 
     def close(self) -> None:
         with self._lock:
-            receivers, self._receivers = list(self._receivers), []
+            receivers, self._receivers = self._receivers, ()
         for r in receivers:
             r.close()
 
@@ -230,7 +326,7 @@ def current_span() -> Optional[Span]:
 
 
 def _new_id() -> str:
-    return uuid.uuid4().hex[:16]
+    return "%016x" % random.getrandbits(64)
 
 
 def _sampled() -> bool:
@@ -239,49 +335,248 @@ def _sampled() -> bool:
         return True
     if rate <= 0.0:
         return False
-    import random
-
     if not hasattr(_rng, "r"):
         _rng.r = random.Random()
     return _rng.r.random() < rate
 
 
-@contextlib.contextmanager
-def trace_span(
-    description: str,
-    parent: Optional[SpanContext] = None,
-    **annotations: Any,
-) -> Iterator[Optional[Span]]:
-    """Open a span; nests under the current span unless ``parent`` (a wire
-    context from a remote caller) overrides it. Yields None when the trace
-    is sampled out — callers never branch on it."""
-    cur = _current.get()
-    if parent is None and cur is None and not _sampled():
-        yield None
-        return
-    if parent is not None:
-        trace_id, parent_id = parent.trace_id, parent.span_id
-    elif cur is not None:
-        trace_id, parent_id = cur.trace_id, cur.span_id
-    else:
-        trace_id, parent_id = _new_id(), None
-    span = Span(
-        trace_id=trace_id,
-        span_id=_new_id(),
-        parent_id=parent_id,
-        description=description,
-        start_sec=time.time(),
-        annotations=dict(annotations),
-        process_id=_tracing.process_id,
-    )
-    token = _current.set(span)
+def _scalars(annotations: Dict[str, Any]) -> Dict[str, Any]:
+    return {k: v for k, v in annotations.items()
+            if isinstance(v, (str, int, float, bool, type(None)))}
+
+
+# -- the profiler sink -----------------------------------------------------
+
+_annotation_cls: Any = None
+
+
+def _annotation(description: str, annotations: Dict[str, Any]):
+    """An entered ``jax.profiler.TraceAnnotation`` named
+    ``harmony/<description>`` carrying the small scalar annotations, or
+    None while nothing in the process has imported jax (a jax-free client
+    has no profiler to write to, and this module must not be what imports
+    it)."""
+    global _annotation_cls
+    cls = _annotation_cls
+    if cls is None:
+        if "jax" not in sys.modules:
+            return None
+        try:
+            from jax.profiler import TraceAnnotation as cls
+        except Exception:  # pragma: no cover - profiler always importable
+            cls = False
+        _annotation_cls = cls
+    if cls is False:
+        return None
     try:
-        yield span
-    finally:
-        _current.reset(token)
-        span.stop_sec = time.time()
-        if not getattr(span, "_discarded", False):
+        ann = cls(ANNOTATION_PREFIX + description, **_scalars(annotations))
+        ann.__enter__()
+        return ann
+    except Exception:
+        return None
+
+
+# -- what is open, and the longest of each kind ----------------------------
+#
+# Every open span of either form sits on its thread's stack; the stacks are
+# registered once per thread so another thread (STATUS, a flight dump) can
+# read them. Pushing and popping is a list operation on the owner's side.
+
+_open_lock = threading.Lock()
+#: thread ident -> (thread name, [[description, start_ns, annotations]])
+_open_stacks: Dict[int, Tuple[str, List[list]]] = {}
+_tls = threading.local()
+#: description -> (duration_ns, start_ns, annotations) of its longest close
+_longest: Dict[str, Tuple[int, int, Dict[str, Any]]] = {}
+
+
+def _stack() -> List[list]:
+    stack = getattr(_tls, "stack", None)
+    if stack is None:
+        stack = _tls.stack = []
+        t = threading.current_thread()
+        with _open_lock:
+            _open_stacks[t.ident] = (t.name, stack)
+    return stack
+
+
+def open_spans() -> List[Dict[str, Any]]:
+    """Every span open in this process right now, oldest first:
+    ``{description, thread, open_sec, start_sec, annotations}``."""
+    now = time.monotonic_ns()
+    alive = {t.ident for t in threading.enumerate()}
+    with _open_lock:
+        for ident in [i for i, (_, st) in _open_stacks.items()
+                      if i not in alive and not st]:
+            del _open_stacks[ident]
+        stacks = [(name, list(st)) for name, st in _open_stacks.values()]
+    out = [{"description": desc, "thread": name,
+            "open_sec": (now - start) * 1e-9, "start_sec": wall_sec(start),
+            "annotations": _scalars(dict(ann))}
+           for name, st in stacks for desc, start, ann in st]
+    out.sort(key=lambda r: -r["open_sec"])
+    return out
+
+
+def longest_spans() -> List[Dict[str, Any]]:
+    """The longest closed span of each description, longest first."""
+    rows = [{"description": d, "duration_sec": dur * 1e-9,
+             "start_sec": wall_sec(start),
+             "annotations": _scalars(dict(ann))}
+            for d, (dur, start, ann) in list(_longest.items())]
+    rows.sort(key=lambda r: -r["duration_sec"])
+    return rows
+
+
+def reset_span_watch() -> None:
+    """Forget the longest spans (tests)."""
+    _longest.clear()
+
+
+class trace_span:
+    """Open a span (a context manager); nests under the current span unless
+    ``parent`` (a wire context from a remote caller) overrides it. Yields
+    the :class:`Span`, or None when the trace is sampled out or the light
+    form was asked for — callers never branch on it.
+
+    ``record=False`` is the light form (module docstring): for regions
+    that run once a step. ``acc`` is called with the region's seconds when
+    it closes, whatever the form — how a span's time reaches a phase
+    accumulator or a counter without a second clock read."""
+
+    __slots__ = ("_description", "_parent", "_record", "_acc",
+                 "_annotations", "_span", "_token", "_entry", "_ann")
+
+    def __init__(self, description: str,
+                 parent: Optional[SpanContext] = None, *,
+                 record: bool = True,
+                 acc: Optional[Callable[[float], None]] = None,
+                 **annotations: Any) -> None:
+        self._description = description
+        self._parent = parent
+        self._record = record
+        self._acc = acc
+        self._annotations = annotations
+        self._span: Optional[Span] = None
+        self._token = None
+
+    def __enter__(self) -> Optional[Span]:
+        cur = _current.get()
+        if (self._record and self._parent is None and cur is None
+                and not _sampled()):
+            self._record = False
+        self._ann = _annotation(self._description, self._annotations)
+        start = time.monotonic_ns()
+        self._entry = [self._description, start, self._annotations]
+        _stack().append(self._entry)
+        if not self._record:
+            return None
+        parent = self._parent
+        self._span = span = Span(
+            trace_id=None if parent is None else parent.trace_id,
+            parent_id=None if parent is None else parent.span_id,
+            parent_span=cur if parent is None else None,
+            description=self._description,
+            annotations=self._annotations,
+            process_id=_tracing.process_id,
+            start_ns=start,
+        )
+        if parent is not None and span.job is None and cur is not None:
+            span.job = cur.job
+        self._token = _current.set(span)
+        return span
+
+    def __exit__(self, *exc: Any) -> None:
+        stop = time.monotonic_ns()
+        desc, start, _ = self._entry
+        stack = _stack()
+        if stack and stack[-1] is self._entry:
+            stack.pop()
+        else:  # closed out of order (interleaved tasks on one thread)
+            try:
+                stack.remove(self._entry)
+            except ValueError:
+                pass
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        span = self._span
+        if span is not None:
+            _current.reset(self._token)
+            span._close(stop)
+            if span._discarded:
+                return
             _tracing.emit(span)
+        dur = stop - start
+        best = _longest.get(desc)
+        if best is None or dur > best[0]:
+            _longest[desc] = (dur, start, self._annotations)
+        if self._acc is not None:
+            self._acc(dur * 1e-9)
+
+
+def record_span(description: str, start_ns: int,
+                stop_ns: Optional[int] = None, *,
+                acc: Optional[Callable[[float], None]] = None,
+                **annotations: Any) -> Span:
+    """Emit a span whose region was not a ``with`` block — it began on one
+    thread and ended on another (queued -> granted). Both ends are
+    ``time.monotonic_ns()`` readings; it nests under the current span and
+    reaches the receivers only (an annotation cannot be back-dated)."""
+    span = Span(parent_span=_current.get(), description=description,
+                annotations=annotations, process_id=_tracing.process_id,
+                start_ns=start_ns)
+    span._close(time.monotonic_ns() if stop_ns is None else stop_ns)
+    _tracing.emit(span)
+    if acc is not None:
+        acc(span.duration_sec)
+    return span
+
+
+def job_stage(job_id: str, stage: str, **annotations: Any) -> trace_span:
+    """The span ``job.<stage>`` of a job's start, whose seconds also go to
+    ``harmony_job_stage_seconds_total{job,stage}``."""
+    return trace_span(  # lint: allow(span-hygiene) a factory: span-hygiene holds every job_stage(...) caller to `with` / enter_context
+        "job." + stage, job_id=job_id,
+        acc=job_stage_adder(job_id, stage), **annotations)
+
+
+def _stage_family():
+    from harmony_tpu.metrics.registry import get_registry
+
+    return get_registry().counter(
+        "harmony_job_stage_seconds_total",
+        "Seconds of a job's start by stage (grant_wait / table_create / "
+        "init / data_load / build_step / first_window)",
+        ("job", "stage"),
+    )
+
+
+def job_stage_adder(job_id: str, stage: str
+                    ) -> Optional[Callable[[float], None]]:
+    """``add(seconds)`` of one (job, stage) cell of the stage counter, or
+    None when the registry cannot be had — a span never fails over it."""
+    try:
+        return _stage_family().labels(job=str(job_id), stage=stage).inc
+    except Exception:
+        return None
+
+
+def job_stage_seconds(newest: int = 64) -> Dict[str, Dict[str, float]]:
+    """``{job: {stage: seconds}}`` of the ``newest`` jobs that recorded a
+    stage — STATUS ``job_stages``."""
+    out: Dict[str, Dict[str, float]] = {}
+    try:
+        for (job, stage), child in _stage_family().children():
+            out.setdefault(job, {})[stage] = round(child.value, 6)
+    except Exception:
+        return {}
+    return dict(list(out.items())[-newest:])
+
+
+def current_job() -> Optional[str]:
+    """The job of the span open on this thread's context, if any."""
+    span = _current.get()
+    return None if span is None else span.job
 
 
 def wire_context() -> Optional[Dict[str, str]]:

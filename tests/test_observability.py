@@ -12,7 +12,6 @@ from harmony_tpu.tracing import (
     SpanContext,
     Tracing,
     current_span,
-    device_trace,
     set_tracing,
     trace_span,
 )
@@ -77,12 +76,18 @@ class TestSpans:
         assert lines[0]["description"] == "filed"
         set_tracing(Tracing())
 
-    def test_device_trace_wraps(self, tracing):
+    def test_trace_span_wraps_device_work(self, tracing):
+        """What ``device_trace`` was: one call records the host span AND
+        opens the profiler annotation (tests/test_span_clock.py looks for
+        the event in a captured xplane)."""
         import jax.numpy as jnp
 
-        with device_trace("devop"):
+        from harmony_tpu.tracing import span as span_mod
+
+        with trace_span("devop", job_id="j"):
             jnp.ones(4).sum()
         assert tracing.by_description("devop")
+        assert span_mod._annotation_cls is not None  # jax is imported here
 
 
 class TestDashboard:
