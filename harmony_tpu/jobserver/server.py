@@ -996,6 +996,9 @@ class JobServer:
             # JAX's own compiles by the job whose span they ran under
             # (runtime/progcache.py): "which job recompiled, and when"
             "compiles": progcache.compiles_by_job(),
+            # the tiles each traced Pallas kernel chose and the grid steps
+            # a call takes under them (ops/attention.py tile_plan)
+            "kernel_plans": progcache.kernel_plans(),
             # seconds of each job's start by stage (the job.<stage> spans)
             "job_stages": job_stage_seconds(),
             "metrics_port": (self.metrics_exporter.port

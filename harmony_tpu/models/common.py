@@ -28,28 +28,31 @@ def validate_attn(attn: str) -> str:
     return attn
 
 
-def flash_ok(seq: int, block: int | None = None) -> bool:
-    """Can the Pallas flash kernel tile this sequence length with the
-    caller's block size? Blocks clamp to min(block, seq), so any
-    seq <= block tiles exactly; longer sequences need divisibility.
-    ``block`` must match what the caller passes to flash_attention
-    (default: the kernel's DEFAULT_BLOCK_Q)."""
-    if block is None:
-        from harmony_tpu.ops.attention import DEFAULT_BLOCK_Q
+def flash_ok(seq: int, block: int | None = None, *, head_dim: int = 128,
+             dtype=jnp.bfloat16) -> bool:
+    """Can the Pallas flash kernels tile a self-attention over ``seq``
+    positions? The kernels' own answer (ops.attention.tile_plan): the gate
+    here and the kernel's ValueError cannot disagree. ``block`` is an
+    explicit block size the caller will pass to flash_attention (default:
+    none — the kernels choose their tiles from the shape)."""
+    from harmony_tpu.ops.attention import tile_plan
 
-        block = DEFAULT_BLOCK_Q
-    return seq % min(block, seq) == 0
+    return tile_plan(seq, seq, head_dim, dtype,
+                     block_q=block, block_k=block) is not None
 
 
-def resolve_attn(attn: str, seq: int, block: int | None = None) -> str:
+def resolve_attn(attn: str, seq: int, block: int | None = None,
+                 **operands) -> str:
     """'auto' -> 'flash' when the program being traced runs on TPUs
-    (utils.platform.trace_is_tpu) and the kernel can tile, else
-    'blockwise'. Call at trace time."""
+    (utils.platform.trace_is_tpu) and the kernels can tile (``operands``:
+    flash_ok's ``head_dim`` / ``dtype``), else 'blockwise'. Call at trace
+    time."""
     if attn != "auto":
         return attn
     from harmony_tpu.utils.platform import trace_is_tpu
 
-    return "flash" if trace_is_tpu() and flash_ok(seq, block) else "blockwise"
+    return ("flash" if trace_is_tpu() and flash_ok(seq, block, **operands)
+            else "blockwise")
 
 
 def flash_on_mesh(q, k, v, **kw):

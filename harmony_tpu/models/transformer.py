@@ -189,10 +189,9 @@ class TransformerLM:
         S = q.shape[2]
         from harmony_tpu.models.common import flash_on_mesh, resolve_attn
 
-        attn = resolve_attn(cfg.attn, S, block=128)  # matches blocks below
-        if attn == "flash":
-            return flash_on_mesh(q, k, v, causal=True,
-                                 block_q=min(128, S), block_k=min(128, S))
+        attn = resolve_attn(cfg.attn, S, head_dim=q.shape[3], dtype=q.dtype)
+        if attn == "flash":  # the kernels tile themselves from the shape
+            return flash_on_mesh(q, k, v, causal=True)
         return blockwise_attention(q, k, v, causal=True)
 
     def _block(self, x, layer, axis_name: Optional[str],

@@ -107,7 +107,8 @@ class ViT:
         return x.transpose(0, 1, 3, 2, 4, 5).reshape(B, n * n, cfg.patch_dim)
 
     def _attend(self, q, k, v):
-        attn = resolve_attn(self.cfg.attn, self.cfg.seq)
+        attn = resolve_attn(self.cfg.attn, self.cfg.seq,
+                            head_dim=q.shape[3], dtype=q.dtype)
         fn = flash_on_mesh if attn == "flash" else blockwise_attention
         return fn(q, k, v, causal=False)
 

@@ -1,4 +1,4 @@
-"""Flash attention for TPU: Pallas forward kernel + differentiable blockwise.
+"""Flash attention for TPU: three Pallas kernels and a differentiable blockwise.
 
 The reference has no attention models at all (SURVEY.md §5.7) — long-context
 support is a first-class extension of this framework, not a port. Two tiers:
@@ -6,26 +6,35 @@ support is a first-class extension of this framework, not a port. Two tiers:
   * :func:`blockwise_attention` — pure-JAX streaming-softmax attention
     (lax.scan over KV blocks, O(S) memory). Differentiable by autodiff;
     numerically identical to flash attention. Works on any backend.
-  * :func:`flash_attention` — Pallas TPU kernel for the forward pass
-    (grid (batch*heads, q_blocks, kv_blocks), online softmax state in VMEM
-    scratch, QK^T and PV on the MXU in fp32). Backward runs through the
-    blockwise implementation's VJP (recompute — the flash-attention trick of
-    trading FLOPs for HBM traffic, same spirit as jax.checkpoint).
+  * :func:`flash_attention` — Pallas TPU kernels forward AND backward
+    (``harmony_flash_fwd``, ``harmony_flash_bwd_dkv``, ``harmony_flash_bwd_dq``):
+    online softmax state in VMEM scratch, QK^T and PV on the MXU in the
+    operands' dtype with fp32 accumulation; the forward saves only the
+    per-row log-sum-exp and the backward recomputes each softmax tile from
+    it (the flash-attention trade of FLOPs for HBM traffic).
 
-Layout: (batch, heads, seq, head_dim). head_dim should be a multiple of 128
-for peak MXU utilisation; any size compiles (pallas pads tiles).
+A grid step of a Pallas kernel costs ~0.35 us on a v5e whatever it
+computes, so the kernels choose how much one step does from the shape
+(:func:`tile_plan`): a step holds one RESIDENT tile (q rows for the forward
+and dQ, kv rows for dK/dV) and one STREAMED tile of the other operand — the
+whole sequence where VMEM allows — which an in-kernel loop walks in
+sub-blocks. Under a causal mask the loop skips the sub-blocks above the
+diagonal and masks only those the diagonal crosses.
+
+Layout: (batch, heads, seq, head_dim). Any head_dim compiles; VMEM tiles pad
+it to the 128-lane width, so 64 (GPT-2) fills half of each vector register
+and half of the MXU's contraction depth.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-DEFAULT_BLOCK_Q = 256
-DEFAULT_BLOCK_K = 256
+DEFAULT_BLOCK_K = 256  # blockwise_attention's scan block
 _NEG_INF = -1e30  # finite "-inf": keeps masked softmax NaN-free
 _LANES = 128  # TPU lane width: per-row stats (LSE, delta) are stored
               # lane-replicated so their blocks are (8,128)-tileable
@@ -36,13 +45,22 @@ def _dot_f32(a, b, trans_b=False):
     return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
 
 
-def _apply_causal_mask(s, iq, ik, block_q, block_k):
-    """Mask one (q-block, kv-block) score tile. Shared by the forward and
-    both backward kernels — they MUST mask identically or gradients silently
-    diverge from the forward."""
-    row = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-    col = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-    return jnp.where(row >= col, s, _NEG_INF)
+def _dot_f32_trans_a(a, b):
+    """a^T @ b with fp32 accumulation (contracts the rows of both)."""
+    return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _apply_causal_mask(s, row0, col0):
+    """Mask one score tile whose first row / column are global positions
+    ``row0`` / ``col0``. Shared by the forward and both backward kernels —
+    they MUST mask identically or gradients silently diverge from the
+    forward."""
+    # row0 + i >= col0 + j, with the tile-invariant i - j on one side so a
+    # loop over sub-blocks pays one compare and one select per element
+    ahead = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+             - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
+    return jnp.where(ahead >= col0 - row0, s, _NEG_INF)
 
 
 def _resolve_scale(q, scale):
@@ -115,14 +133,221 @@ def blockwise_attention(
 
 
 # ---------------------------------------------------------------------------
-# Pallas forward kernel
+# The tile plan: how much work one grid step of each kernel does
 # ---------------------------------------------------------------------------
 
+class Tiles(NamedTuple):
+    """One kernel's tiling. ``block_q`` x ``block_k`` is what a grid step
+    holds in VMEM; ``sub`` is the length of the STREAMED tile (kv rows for
+    the forward and dQ, q rows for dK/dV) one in-kernel loop iteration
+    takes, so the score-sized temporaries are resident-block x ``sub``.
+    ``vmem_limit_bytes`` is set only where the tiles need more than
+    Mosaic's default scoped VMEM."""
+    block_q: int
+    block_k: int
+    sub: int
+    vmem_limit_bytes: Optional[int] = None
+
+
+class TilePlan(NamedTuple):
+    fwd: Tiles
+    dkv: Tiles
+    dq: Tiles
+    planned: bool  # False: the caller's explicit blocks, taken as given
+
+
+#: the kernels' names in a device trace (perf/trace_reduce.py reads them) and
+#: in STATUS ``kernel_plans``
+_KERNEL_NAMES = {"fwd": "harmony_flash_fwd", "dkv": "harmony_flash_bwd_dkv",
+                 "dq": "harmony_flash_bwd_dq"}
+_ONE_BLOCK = 256              # a whole length up to this is one block as it is
+_RESIDENT = (512, 256, 128)   # resident-block lengths tried, largest first
+_SUB = (1024, 512, 256, 128)  # sub-block lengths tried, largest first
+_TEMPS_MAX = 6 * 2**20        # score-sized temporaries of one sub-block: past
+                              # this a wider sub-block loses on the v5e (PERF.md)
+_VMEM_DEFAULT = 16 * 2**20    # Mosaic's scoped-VMEM default on every TPU so far
+_VMEM_FREE = 12 * 2**20       # estimates up to here run under that default
+_VMEM_CAP = 40 * 2**20        # the most a plan may need (a v5e has 128 MiB)
+
+
+def _temp_bytes(kernel, resident, sub):
+    """The score-sized f32 temporaries one sub-block keeps live: s, p and
+    p's cast in the forward; s/p, dp, ds and two casts in the backward."""
+    return (3 if kernel == "fwd" else 5) * resident * sub * 4
+
+
+def _vmem_bytes(kernel, block_q, block_k, sub, d, itemsize):
+    """VMEM one grid step of ``kernel`` needs, in bytes: the BlockSpec tiles
+    double-buffered (q/k/v/do and the outputs in the operands' dtype, the
+    lane-replicated statistics in f32), the f32 accumulators, and the
+    temporaries of one sub-block. A tile's last dim pads to the lane width
+    in VMEM."""
+    dl = -(-d // _LANES) * _LANES
+    opnd = lambda rows: rows * dl * itemsize
+    stat = lambda rows: rows * _LANES * 4
+    if kernel == "fwd":
+        tiles = 2 * opnd(block_q) + 2 * opnd(block_k) + stat(block_q)
+        scratch = 2 * stat(block_q) + block_q * dl * 4
+    elif kernel == "dkv":
+        tiles = 2 * opnd(block_q) + 2 * stat(block_q) + 4 * opnd(block_k)
+        scratch = 2 * block_k * dl * 4
+    else:  # dq
+        tiles = 3 * opnd(block_q) + 2 * stat(block_q) + 2 * opnd(block_k)
+        scratch = block_q * dl * 4
+    resident = block_k if kernel == "dkv" else block_q
+    return 2 * tiles + scratch + _temp_bytes(kernel, resident, sub)
+
+
+def _with_limit(kernel, block_q, block_k, sub, d, itemsize):
+    need = _vmem_bytes(kernel, block_q, block_k, sub, d, itemsize)
+    limit = None if need <= _VMEM_FREE else need + _VMEM_DEFAULT
+    return Tiles(block_q, block_k, sub, limit)
+
+
+def _plan_kernel(kernel, sq, sk, d, itemsize):
+    """Largest tiles that divide the lengths and fit the budget: the
+    resident block first, then the widest sub-block whose temporaries stay
+    under ``_TEMPS_MAX``, then as much of the streamed length as fits
+    ``_VMEM_CAP`` (the whole of it at every shape a model here runs)."""
+    res_len, str_len = (sk, sq) if kernel == "dkv" else (sq, sk)
+
+    def divisors(length, sizes):
+        if length <= _ONE_BLOCK:
+            return (length,)
+        return tuple(s for s in sizes if length % s == 0)
+
+    for res in divisors(res_len, _RESIDENT):
+        for sub in divisors(str_len, _SUB):
+            if sub > _LANES and _temp_bytes(kernel, res, sub) > _TEMPS_MAX:
+                continue
+            for n in range(str_len // sub, 0, -1):
+                if (str_len // sub) % n:
+                    continue
+                bq, bk = (n * sub, res) if kernel == "dkv" else (res, n * sub)
+                if _vmem_bytes(kernel, bq, bk, sub, d, itemsize) <= _VMEM_CAP:
+                    return _with_limit(kernel, bq, bk, sub, d, itemsize)
+    return None
+
+
+def tile_plan(sq, sk, d, dtype, causal=False, block_q=None, block_k=None):
+    """The tiles of the three kernels for q [.., sq, d] against k/v
+    [.., sk, d], or None where the kernels cannot tile the lengths: a
+    length over ``_ONE_BLOCK`` must divide by 128. The ONE gate: the kernels
+    raise where this returns None, and ``models.common.flash_ok`` asks here.
+
+    Inputs are what a trace can observe — lengths, head width, operand
+    dtype — and the budget is VMEM (``_vmem_bytes``); a plan over Mosaic's
+    default carries ``vmem_limit_bytes`` instead of shrinking. Explicit
+    ``block_q`` / ``block_k`` win over the plan and are taken as given (one
+    sub-block a grid step: the tiling of the interpreter tests and the
+    ring's callers). ``causal`` does not change the tiles: the in-kernel
+    loop bounds carry the causal skip at sub-block grain."""
+    del causal
+    itemsize = jnp.dtype(dtype).itemsize
+    if block_q is not None or block_k is not None:
+        bq = min(block_q or block_k, sq)  # one given alone stands for both
+        bk = min(block_k or block_q, sk)
+        if sq % bq or sk % bk:
+            return None
+        return TilePlan(_with_limit("fwd", bq, bk, bk, d, itemsize),
+                        _with_limit("dkv", bq, bk, bq, d, itemsize),
+                        _with_limit("dq", bq, bk, bk, d, itemsize), False)
+    tiles = [_plan_kernel(kern, sq, sk, d, itemsize)
+             for kern in ("fwd", "dkv", "dq")]
+    return None if None in tiles else TilePlan(*tiles, True)
+
+
+def _require_plan(q, k, causal, block_q, block_k):
+    sq, sk, d = q.shape[2], k.shape[2], q.shape[3]
+    plan = tile_plan(sq, sk, d, q.dtype, causal, block_q, block_k)
+    if plan is None:
+        raise ValueError(
+            f"flash attention cannot tile seq lens ({sq},{sk})"
+            + (f" by blocks ({block_q},{block_k})"
+               if block_q is not None or block_k is not None else
+               f": a length over {_ONE_BLOCK} must divide by {_LANES}"))
+    return plan
+
+
+def _note_plan(plan, kernels, bh, sq, sk):
+    """Trace-time record of the tiling a compiled program runs — the plan is
+    static per shape, so it engages always or never; STATUS ``kernel_plans``
+    (beside ``compiles``) says which one a job got. Never fails a trace."""
+    try:
+        from harmony_tpu.runtime.progcache import note_kernel_plan
+
+        for kern in kernels:
+            t = getattr(plan, kern)
+            note_kernel_plan(
+                _KERNEL_NAMES[kern], t.block_q, t.block_k, t.sub,
+                bh * (sq // t.block_q) * (sk // t.block_k), plan.planned)
+    except Exception:
+        pass
+
+
+def _compiler_params(tiles):
+    if tiles.vmem_limit_bytes is None:
+        return {}
+    from jax.experimental.pallas import tpu as pltpu
+
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=tiles.vmem_limit_bytes)}
+
+
+# ---------------------------------------------------------------------------
+# Pallas kernels
+# ---------------------------------------------------------------------------
+
+def _kv_sub_ranges(causal, q0, block_q, k0, sub, n_sub):
+    """Split the ``n_sub`` sub-blocks of a streamed KV tile (first column
+    ``k0``) against a resident q tile (rows ``q0 .. q0+block_q-1``):
+    sub-blocks ``[0, n_full)`` lie at or below the diagonal for every row
+    (no mask), ``[n_full, n_need)`` are crossed by it (masked), the rest lie
+    above it and contribute nothing (skipped)."""
+    if not causal:
+        return n_sub, n_sub
+    n_full = jnp.clip((q0 - k0 + 1) // sub, 0, n_sub)
+    n_need = jnp.clip((q0 + block_q - k0 + sub - 1) // sub, 0, n_sub)
+    return n_full, n_need
+
+
+def _q_sub_ranges(causal, q0, k0, block_k, sub, n_sub):
+    """The same split for a streamed q tile (first row ``q0``) against a
+    resident KV tile (columns ``k0 .. k0+block_k-1``): sub-blocks
+    ``[0, first)`` lie above the diagonal (skipped), ``[first, full_from)``
+    are crossed by it (masked), ``[full_from, n_sub)`` lie below it."""
+    if not causal:
+        return 0, 0
+    first = jnp.clip((k0 - q0) // sub, 0, n_sub)
+    full_from = jnp.clip((k0 + block_k - q0 + sub - 2) // sub, 0, n_sub)
+    return first, full_from
+
+
+def _sub_slice(i, sub):
+    if isinstance(i, int):
+        return pl.ds(i * sub, sub)
+    return pl.ds(pl.multiple_of(i * sub, sub), sub)
+
+
+def _for_sub_blocks(lo, hi, n_sub, body):
+    """``body(i)`` for each sub-block ``i`` in ``[lo, hi)`` of ``n_sub``. A
+    tile that is one sub-block takes it under a guard at a static offset
+    (any length compiles, a multiple of 8 or not); more are a loop whose
+    bounds may be traced values."""
+    if n_sub > 1:
+        jax.lax.fori_loop(lo, hi, lambda i, carry: (body(i), carry)[1], None)
+    elif isinstance(lo, int) and isinstance(hi, int):
+        if lo < hi:
+            body(0)
+    else:
+        pl.when(jnp.logical_and(lo <= 0, hi > 0))(lambda: body(0))
+
+
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
-               scale, causal, block_q, block_k):
-    iq = pl.program_id(1)
+               scale, causal, block_q, block_k, sub):
+    q0 = pl.program_id(1) * block_q
     ik = pl.program_id(2)
-    nk = pl.num_programs(2)
+    k0 = ik * block_k
 
     @pl.when(ik == 0)
     def _init():
@@ -130,31 +355,35 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    # Causal: KV blocks strictly above the diagonal contribute nothing.
-    needed = True if not causal else (ik * block_k <= iq * block_q + block_q - 1)
+    def step(masked):
+        def body(j):
+            cols = _sub_slice(j, sub)
+            # NATIVE-dtype operand feeds: a bf16 q/k/v runs the MXU at bf16
+            # throughput with fp32 accumulation (preferred_element_type).
+            # The scale applies to the fp32 product, exactly.
+            s = _dot_f32(q_ref[0], k_ref[0, cols, :], trans_b=True) * scale
+            if masked:
+                s = _apply_causal_mask(s, q0, k0 + j * sub)
+            m_prev = m_ref[:, :1]                        # (bq, 1)
+            m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)              # (bq, 1)
+            l_ref[:] = l_ref[:] * alpha + p.sum(axis=1, keepdims=True)
+            # p feeds the MXU in v's dtype (bf16 weights => bf16 p, the
+            # standard flash trade; fp32 v keeps p fp32 so tests/CPU are
+            # exact)
+            acc_ref[:] = acc_ref[:] * alpha + _dot_f32(
+                p.astype(v_ref.dtype), v_ref[0, cols, :])
+            m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+        return body
 
-    @pl.when(needed)
-    def _compute():
-        # NATIVE-dtype operand feeds: a bf16 q/k/v runs the MXU at bf16
-        # throughput with fp32 accumulation (preferred_element_type) —
-        # casting operands to fp32 first (the old code) forfeited most of
-        # the MXU for no accuracy the fp32 accumulator wasn't already
-        # providing. The scale applies to the fp32 product, exactly.
-        s = _dot_f32(q_ref[0], k_ref[0], trans_b=True) * scale  # (bq, bk)
-        if causal:
-            s = _apply_causal_mask(s, iq, ik, block_q, block_k)
-        m_prev = m_ref[:, :1]                        # (bq, 1)
-        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)              # (bq, 1)
-        l_ref[:] = l_ref[:] * alpha + p.sum(axis=1, keepdims=True)
-        # p feeds the MXU in v's dtype (bf16 weights => bf16 p, the
-        # standard flash trade; fp32 v keeps p fp32 so tests/CPU are exact)
-        acc_ref[:] = acc_ref[:] * alpha + _dot_f32(
-            p.astype(v_ref.dtype), v_ref[0])
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+    n_sub = block_k // sub
+    n_full, n_need = _kv_sub_ranges(causal, q0, block_q, k0, sub, n_sub)
+    _for_sub_blocks(0, n_full, n_sub, step(False))
+    if causal:
+        _for_sub_blocks(n_full, n_need, n_sub, step(True))
 
-    @pl.when(ik == nk - 1)
+    @pl.when(ik == pl.num_programs(2) - 1)
     def _write():
         l = jnp.maximum(l_ref[:, :1], 1e-30)
         o_ref[0] = (acc_ref[:] / l).astype(o_ref.dtype)
@@ -178,31 +407,53 @@ def _out_struct(shape, dtype, *refs):
     return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
-def _flash_forward(q, k, v, causal, block_q, block_k, scale, interpret):
+def _last_needed_kv(causal, block_q, block_k):
+    """index_map clamp for a streamed KV tile: a grid step above the
+    diagonal repeats the block index of the last needed one, so Pallas
+    fetches nothing for it (its arithmetic is skipped in the kernel)."""
+    if not causal:
+        return lambda i, j: j
+    return lambda i, j: jnp.minimum(j, (i * block_q + block_q - 1) // block_k)
+
+
+def _first_needed_q(causal, block_q, block_k, nq):
+    """The same clamp for the dK/dV kernel's streamed q tile: steps before
+    the first q tile that reaches this KV tile's columns fetch that one
+    (the last one where none does: more columns than rows)."""
+    if not causal:
+        return lambda j, i: i
+    return lambda j, i: jnp.maximum(
+        i, jnp.minimum((j * block_k) // block_q, nq - 1))
+
+
+# The kernel-calling functions below are jitted with everything but the
+# arrays static: a model calls attention once per layer at one shape, and a
+# jitted callee is traced and lowered ONCE per (shape, tiles) in a process —
+# the layers of a step, and every later job's re-trace of it, reuse that
+# (job.build_step: twelve layers' kernels cost one trace, not twelve).
+
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
+def _flash_forward(q, k, v, causal, tiles, scale, interpret):
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
-    block_q = min(block_q, Sq)
-    block_k = min(block_k, Sk)
-    if Sq % block_q or Sk % block_k:
-        raise ValueError(
-            f"seq lens ({Sq},{Sk}) must divide by blocks ({block_q},{block_k})"
-        )
+    block_q, block_k, sub = tiles[:3]
     qf = q.reshape(B * H, Sq, D)
     kf = k.reshape(B * H, Sk, D)
     vf = v.reshape(B * H, Sk, D)
     grid = (B * H, Sq // block_q, Sk // block_k)
     kernel = functools.partial(
         _fa_kernel, scale=scale, causal=causal,
-        block_q=block_q, block_k=block_k,
+        block_q=block_q, block_k=block_k, sub=sub,
     )
+    kv_j = _last_needed_kv(causal, block_q, block_k)
     out, lse = pl.pallas_call(
         kernel,
-        name="harmony_flash_fwd",
+        name=_KERNEL_NAMES["fwd"],
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, kv_j(i, j), 0)),
+            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, kv_j(i, j), 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
@@ -213,25 +464,24 @@ def _flash_forward(q, k, v, causal, block_q, block_k, scale, interpret):
             _out_struct((B * H, Sq, _LANES), jnp.float32, q, k, v),
         ],
         scratch_shapes=[
-            _vmem((block_q, 128)),   # running row-max m
-            _vmem((block_q, 128)),   # running normaliser l
-            _vmem((block_q, D)),     # unnormalised output accumulator
+            _vmem((block_q, _LANES)),   # running row-max m
+            _vmem((block_q, _LANES)),   # running normaliser l
+            _vmem((block_q, D)),        # unnormalised output accumulator
         ],
         interpret=interpret,
+        **_compiler_params(tiles),
     )(qf, kf, vf)
     return out.reshape(B, H, Sq, D), lse[:, :, 0].reshape(B, H, Sq)
 
 
-def _bwd_p_ds(q, k, v, do, lse, delta, iq, ik, scale, causal,
-              block_q, block_k):
-    """Shared backward math for one (q-block, kv-block) tile: returns
-    (p [bq,bk], ds [bq,bk]) with p the normalized softmax block.
-    ``lse``/``delta`` arrive as (bq, 1) column tiles (lane 0 of the
-    lane-replicated stats)."""
+def _bwd_p_ds(q, k, v, do, lse, delta, row0, col0, scale, masked):
+    """Shared backward math for one score tile: returns (p, ds) with p the
+    normalized softmax block. ``lse``/``delta`` arrive as (rows, 1) column
+    tiles (lane 0 of the lane-replicated stats)."""
     # native-dtype MXU feeds with fp32 accumulation (see _fa_kernel)
-    s = _dot_f32(q, k, trans_b=True) * scale                  # (bq, bk)
-    if causal:
-        s = _apply_causal_mask(s, iq, ik, block_q, block_k)
+    s = _dot_f32(q, k, trans_b=True) * scale
+    if masked:
+        s = _apply_causal_mask(s, row0, col0)
     p = jnp.exp(s - lse)                                      # normalized
     dp = _dot_f32(do, v, trans_b=True)
     ds = p * (dp - delta)
@@ -240,133 +490,166 @@ def _bwd_p_ds(q, k, v, do, lse, delta, iq, ik, scale, causal,
 
 def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                        dk_ref, dv_ref, dk_acc, dv_acc, *,
-                       scale, causal, block_q, block_k):
-    ik = pl.program_id(1)   # kv block (this output tile)
-    iq = pl.program_id(2)   # q blocks stream by
-    nq = pl.num_programs(2)
+                       scale, causal, block_q, block_k, sub):
+    k0 = pl.program_id(1) * block_k   # kv tile (this output tile)
+    iq = pl.program_id(2)             # q tiles stream by
+    q0 = iq * block_q
 
     @pl.when(iq == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    needed = True if not causal else (iq * block_q + block_q - 1 >= ik * block_k)
+    def step(masked):
+        def body(i):
+            rows = _sub_slice(i, sub)
+            q, do = q_ref[0, rows, :], do_ref[0, rows, :]
+            p, ds = _bwd_p_ds(
+                q, k_ref[0], v_ref[0], do,
+                lse_ref[0, rows, :1], delta_ref[0, rows, :1],
+                q0 + i * sub, k0, scale, masked)
+            dv_acc[:] += _dot_f32_trans_a(p.astype(do.dtype), do)   # (bk, d)
+            dk_acc[:] += _dot_f32_trans_a(ds.astype(q.dtype), q)    # (bk, d)
+        return body
 
-    @pl.when(needed)
-    def _compute():
-        p, ds = _bwd_p_ds(
-            q_ref[0], k_ref[0], v_ref[0], do_ref[0],
-            lse_ref[0, :, :1], delta_ref[0, :, :1],
-            iq, ik, scale, causal, block_q, block_k,
-        )
-        dv_acc[:] += jax.lax.dot_general(
-            p.astype(do_ref.dtype), do_ref[0], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)               # (bk, d)
-        dk_acc[:] += scale * jax.lax.dot_general(
-            ds.astype(q_ref.dtype), q_ref[0], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)               # (bk, d)
+    n_sub = block_q // sub
+    first, full_from = _q_sub_ranges(causal, q0, k0, block_k, sub, n_sub)
+    if causal:
+        _for_sub_blocks(first, full_from, n_sub, step(True))
+    _for_sub_blocks(full_from, n_sub, n_sub, step(False))
 
-    @pl.when(iq == nq - 1)
+    @pl.when(iq == pl.num_programs(2) - 1)
     def _write():
-        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+        dk_ref[0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
 def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                      dq_ref, dq_acc, *, scale, causal, block_q, block_k):
-    iq = pl.program_id(1)   # q block (this output tile)
-    ik = pl.program_id(2)   # kv blocks stream by
-    nk = pl.num_programs(2)
+                      dq_ref, dq_acc, *, scale, causal, block_q, block_k,
+                      sub):
+    q0 = pl.program_id(1) * block_q   # q tile (this output tile)
+    ik = pl.program_id(2)             # kv tiles stream by
+    k0 = ik * block_k
 
     @pl.when(ik == 0)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    needed = True if not causal else (ik * block_k <= iq * block_q + block_q - 1)
+    def step(masked):
+        def body(j):
+            cols = _sub_slice(j, sub)
+            k = k_ref[0, cols, :]
+            _, ds = _bwd_p_ds(
+                q_ref[0], k, v_ref[0, cols, :], do_ref[0],
+                lse_ref[0, :, :1], delta_ref[0, :, :1],
+                q0, k0 + j * sub, scale, masked)
+            dq_acc[:] += _dot_f32(ds.astype(k.dtype), k)
+        return body
 
-    @pl.when(needed)
-    def _compute():
-        _, ds = _bwd_p_ds(
-            q_ref[0], k_ref[0], v_ref[0], do_ref[0],
-            lse_ref[0, :, :1], delta_ref[0, :, :1],
-            iq, ik, scale, causal, block_q, block_k,
-        )
-        dq_acc[:] += scale * _dot_f32(ds.astype(k_ref.dtype), k_ref[0])
+    n_sub = block_k // sub
+    n_full, n_need = _kv_sub_ranges(causal, q0, block_q, k0, sub, n_sub)
+    _for_sub_blocks(0, n_full, n_sub, step(False))
+    if causal:
+        _for_sub_blocks(n_full, n_need, n_sub, step(True))
 
-    @pl.when(ik == nk - 1)
+    @pl.when(ik == pl.num_programs(2) - 1)
     def _write():
-        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+        dq_ref[0] = (dq_acc[:] * scale).astype(dq_ref.dtype)
 
 
-def _flash_backward(q, k, v, out, lse, do, causal, block_q, block_k, scale,
-                    interpret, lse_cotangent=None):
-    """Fused flash backward: dK/dV kernel (grid over kv tiles) + dQ kernel
-    (grid over q tiles); softmax recomputed per tile from the saved LSE —
-    the O(S) memory trade the forward made, carried into the backward.
+def _bwd_row_stats(out, lse, do, lse_cotangent):
+    """The backward kernels' per-row inputs, lane-replicated (see _LANES):
+    the saved LSE and delta_i = dO_i . O_i, cheap enough to leave to XLA —
+    which materializes the broadcasts; the kernels read lane 0.
 
     ``lse_cotangent`` supports callers that consume the LSE output (the
     ring-attention chunk merge): d lse_r / d s_rc = p_rc, so the extra term
     is ``g_lse_r * p_rc`` — algebraically it folds into the delta:
     ds = p * (dp - (delta - g_lse)). The kernels are unchanged."""
+    B, H, Sq, D = out.shape
+    lsef = jnp.broadcast_to(lse.reshape(B * H, Sq)[:, :, None],
+                            (B * H, Sq, _LANES))
+    delta = jnp.einsum("bsd,bsd->bs",
+                       do.reshape(B * H, Sq, D).astype(jnp.float32),
+                       out.reshape(B * H, Sq, D).astype(jnp.float32))
+    if lse_cotangent is not None:
+        delta = delta - lse_cotangent.reshape(B * H, Sq).astype(jnp.float32)
+    return lsef, jnp.broadcast_to(delta[:, :, None], (B * H, Sq, _LANES))
+
+
+def _flash_bwd_dkv(qf, kf, vf, dof, lsef, delta, causal, tiles, scale,
+                   interpret):
+    """dK/dV kernel on [B*H, S, D] operands: grid over kv tiles, q tiles
+    stream by; softmax recomputed per tile from the saved LSE."""
+    BH, Sq, D = qf.shape
+    Sk = kf.shape[1]
+    block_q, block_k, sub = tiles[:3]
+    grid = (BH, Sk // block_k, Sq // block_q)
+    q_i = _first_needed_q(causal, block_q, block_k, grid[2])
+    q_spec = pl.BlockSpec((1, block_q, D), lambda b, j, i: (b, q_i(j, i), 0))
+    kv_spec = pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0))
+    row_spec = pl.BlockSpec((1, block_q, _LANES),
+                            lambda b, j, i: (b, q_i(j, i), 0))
+    return pl.pallas_call(
+        functools.partial(_fa_bwd_dkv_kernel, scale=scale, causal=causal,
+                          block_q=block_q, block_k=block_k, sub=sub),
+        name=_KERNEL_NAMES["dkv"],
+        grid=grid,
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        out_specs=[kv_spec, kv_spec],
+        out_shape=[
+            _out_struct((BH, Sk, D), kf.dtype, qf, kf, vf, dof),
+            _out_struct((BH, Sk, D), vf.dtype, qf, kf, vf, dof),
+        ],
+        scratch_shapes=[_vmem((block_k, D)), _vmem((block_k, D))],
+        interpret=interpret,
+        **_compiler_params(tiles),
+    )(qf, kf, vf, dof, lsef, delta)
+
+
+def _flash_bwd_dq(qf, kf, vf, dof, lsef, delta, causal, tiles, scale,
+                  interpret):
+    """dQ kernel on [B*H, S, D] operands: grid over q tiles, kv tiles
+    stream by."""
+    BH, Sq, D = qf.shape
+    Sk = kf.shape[1]
+    block_q, block_k, sub = tiles[:3]
+    grid = (BH, Sq // block_q, Sk // block_k)
+    kv_j = _last_needed_kv(causal, block_q, block_k)
+    q_spec = pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0))
+    kv_spec = pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, kv_j(i, j), 0))
+    row_spec = pl.BlockSpec((1, block_q, _LANES), lambda b, i, j: (b, i, 0))
+    return pl.pallas_call(
+        functools.partial(_fa_bwd_dq_kernel, scale=scale, causal=causal,
+                          block_q=block_q, block_k=block_k, sub=sub),
+        name=_KERNEL_NAMES["dq"],
+        grid=grid,
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        out_specs=q_spec,
+        out_shape=_out_struct((BH, Sq, D), qf.dtype, qf, kf, vf, dof),
+        scratch_shapes=[_vmem((block_q, D))],
+        interpret=interpret,
+        **_compiler_params(tiles),
+    )(qf, kf, vf, dof, lsef, delta)
+
+
+@functools.partial(jax.jit, static_argnums=(7, 8, 9, 10))
+def _flash_backward(q, k, v, out, lse, do, lse_cotangent, causal, plan,
+                    scale, interpret):
+    """Fused flash backward: dK/dV kernel (grid over kv tiles) + dQ kernel
+    (grid over q tiles); softmax recomputed per tile from the saved LSE —
+    the O(S) memory trade the forward made, carried into the backward."""
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
-    block_q = min(block_q, Sq)
-    block_k = min(block_k, Sk)
     qf = q.reshape(B * H, Sq, D)
     kf = k.reshape(B * H, Sk, D)
     vf = v.reshape(B * H, Sk, D)
     dof = do.reshape(B * H, Sq, D)
-    # Per-row stats enter lane-replicated (see _LANES note in the forward);
-    # XLA materializes the broadcasts, the kernels read lane 0.
-    lsef = jnp.broadcast_to(lse.reshape(B * H, Sq)[:, :, None],
-                            (B * H, Sq, _LANES))
-    # delta_i = dO_i . O_i (rowwise), cheap enough to leave to XLA.
-    delta = jnp.einsum("bsd,bsd->bs", dof.astype(jnp.float32),
-                       out.reshape(B * H, Sq, D).astype(jnp.float32))
-    if lse_cotangent is not None:
-        delta = delta - lse_cotangent.reshape(B * H, Sq).astype(jnp.float32)
-    delta = jnp.broadcast_to(delta[:, :, None], (B * H, Sq, _LANES))
-
-    q_spec = pl.BlockSpec((1, block_q, D), lambda b, j, i: (b, i, 0))
-    kv_spec = pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0))
-    row_spec = pl.BlockSpec((1, block_q, _LANES), lambda b, j, i: (b, i, 0))
-    dkv = functools.partial(
-        _fa_bwd_dkv_kernel, scale=scale, causal=causal,
-        block_q=block_q, block_k=block_k)
-    dk, dv = pl.pallas_call(
-        dkv,
-        name="harmony_flash_bwd_dkv",
-        grid=(B * H, Sk // block_k, Sq // block_q),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-        out_specs=[
-            pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
-        ],
-        out_shape=[
-            _out_struct((B * H, Sk, D), k.dtype, q, k, v, do),
-            _out_struct((B * H, Sk, D), v.dtype, q, k, v, do),
-        ],
-        scratch_shapes=[_vmem((block_k, D)), _vmem((block_k, D))],
-        interpret=interpret,
-    )(qf, kf, vf, dof, lsef, delta)
-
-    q_spec2 = pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0))
-    kv_spec2 = pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0))
-    row_spec2 = pl.BlockSpec((1, block_q, _LANES), lambda b, i, j: (b, i, 0))
-    dqk = functools.partial(
-        _fa_bwd_dq_kernel, scale=scale, causal=causal,
-        block_q=block_q, block_k=block_k)
-    dq = pl.pallas_call(
-        dqk,
-        name="harmony_flash_bwd_dq",
-        grid=(B * H, Sq // block_q, Sk // block_k),
-        in_specs=[q_spec2, kv_spec2, kv_spec2, q_spec2, row_spec2, row_spec2],
-        out_specs=pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-        out_shape=_out_struct((B * H, Sq, D), q.dtype, q, k, v, do),
-        scratch_shapes=[_vmem((block_q, D))],
-        interpret=interpret,
-    )(qf, kf, vf, dof, lsef, delta)
-
+    lsef, delta = _bwd_row_stats(out, lse, do, lse_cotangent)
+    dk, dv = _flash_bwd_dkv(qf, kf, vf, dof, lsef, delta, causal, plan.dkv,
+                            scale, interpret)
+    dq = _flash_bwd_dq(qf, kf, vf, dof, lsef, delta, causal, plan.dq,
+                       scale, interpret)
     return (dq.reshape(B, H, Sq, D), dk.reshape(B, H, Sk, D),
             dv.reshape(B, H, Sk, D))
 
@@ -382,8 +665,8 @@ def flash_attention(
     k: jnp.ndarray,
     v: jnp.ndarray,
     causal: bool = False,
-    block_q: int = DEFAULT_BLOCK_Q,
-    block_k: int = DEFAULT_BLOCK_K,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
     scale: Optional[float] = None,
     interpret: bool = False,
 ) -> jnp.ndarray:
@@ -393,6 +676,8 @@ def flash_attention(
     :func:`blockwise_attention` by name): the forward saves only O(S)
     softmax statistics (LSE) and the backward recomputes each softmax tile
     from them — flash attention's memory/FLOPs trade in both directions.
+    The kernels tile themselves from the shape (:func:`tile_plan`);
+    ``block_q`` / ``block_k`` override it.
 
     Thin wrapper over :func:`flash_attention_lse` (the kernel always writes
     the LSE output; discarding it costs nothing, and a zero LSE cotangent
@@ -408,8 +693,8 @@ def flash_attention_lse(
     k: jnp.ndarray,
     v: jnp.ndarray,
     causal: bool = False,
-    block_q: int = DEFAULT_BLOCK_Q,
-    block_k: int = DEFAULT_BLOCK_K,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
     scale: Optional[float] = None,
     interpret: bool = False,
 ) -> "tuple[jnp.ndarray, jnp.ndarray]":
@@ -417,19 +702,22 @@ def flash_attention_lse(
     ([B, H, Sq], fp32) — the composable form: outputs of independent KV
     chunks merge exactly via their LSEs (``ring_attention``'s flash inner).
     Differentiable in both outputs; the LSE cotangent folds into the
-    backward kernels' delta term (see ``_flash_backward``)."""
+    backward kernels' delta term (see ``_bwd_row_stats``)."""
+    return _fa_lse_fwd(q, k, v, causal, block_q, block_k, scale,
+                       interpret)[0]
+
+
+def _fa_lse_fwd(q, k, v, causal, block_q, block_k, scale, interpret):
     if not (q.dtype == k.dtype == v.dtype):
         raise TypeError(
             f"flash attention feeds the MXU in the operands' dtype, so "
             f"q/k/v must share one dtype (got {q.dtype}/{k.dtype}/"
             f"{v.dtype}); cast the operands before the call"
         )
-    return _flash_forward(q, k, v, causal, block_q, block_k,
-                          _resolve_scale(q, scale), interpret)
-
-
-def _fa_lse_fwd(q, k, v, causal, block_q, block_k, scale, interpret):
-    out, lse = _flash_forward(q, k, v, causal, block_q, block_k,
+    plan = _require_plan(q, k, causal, block_q, block_k)
+    _note_plan(plan, ("fwd",), q.shape[0] * q.shape[1], q.shape[2],
+               k.shape[2])
+    out, lse = _flash_forward(q, k, v, causal, plan.fwd,
                               _resolve_scale(q, scale), interpret)
     return (out, lse), (q, k, v, out, lse)
 
@@ -437,9 +725,11 @@ def _fa_lse_fwd(q, k, v, causal, block_q, block_k, scale, interpret):
 def _fa_lse_bwd(causal, block_q, block_k, scale, interpret, res, g):
     q, k, v, out, lse = res
     g_out, g_lse = g
-    return _flash_backward(q, k, v, out, lse, g_out, causal, block_q, block_k,
-                           _resolve_scale(q, scale), interpret,
-                           lse_cotangent=g_lse)
+    plan = _require_plan(q, k, causal, block_q, block_k)
+    _note_plan(plan, ("dkv", "dq"), q.shape[0] * q.shape[1], q.shape[2],
+               k.shape[2])
+    return _flash_backward(q, k, v, out, lse, g_out, g_lse, causal, plan,
+                           _resolve_scale(q, scale), interpret)
 
 
 flash_attention_lse.defvjp(_fa_lse_fwd, _fa_lse_bwd)

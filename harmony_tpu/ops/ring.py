@@ -141,22 +141,19 @@ def _ring_flash(qf, k, v, axis_name, causal, n, my, perm, out_dtype,
     carries (num, m, den): num = unnormalized output in the running frame
     m, den = normalizer. A skipped chunk contributes lse=-inf and weight
     exactly 0 (guarded — exp(-inf - -inf) would be 1)."""
-    from harmony_tpu.ops.attention import (
-        DEFAULT_BLOCK_K,
-        DEFAULT_BLOCK_Q,
-        flash_attention_lse,
-    )
+    from harmony_tpu.ops.attention import flash_attention_lse
 
-    # positional args: custom_vjp + nondiff_argnums and keywords don't mix
+    # positional args: custom_vjp + nondiff_argnums and keywords don't mix;
+    # blocks None: the kernels tile each chunk from its shape
     def full(args):
         q_, k_, v_ = args
-        return flash_attention_lse(q_, k_, v_, False, DEFAULT_BLOCK_Q,
-                                   DEFAULT_BLOCK_K, 1.0, interpret)
+        return flash_attention_lse(q_, k_, v_, False, None, None, 1.0,
+                                   interpret)
 
     def diag(args):
         q_, k_, v_ = args
-        return flash_attention_lse(q_, k_, v_, True, DEFAULT_BLOCK_Q,
-                                   DEFAULT_BLOCK_K, 1.0, interpret)
+        return flash_attention_lse(q_, k_, v_, True, None, None, 1.0,
+                                   interpret)
 
     def skip(args):
         q_, _, _ = args

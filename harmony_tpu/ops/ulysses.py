@@ -26,7 +26,11 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from harmony_tpu.ops.attention import blockwise_attention, flash_attention
+from harmony_tpu.ops.attention import (
+    blockwise_attention,
+    flash_attention,
+    tile_plan,
+)
 
 
 def a2a_attention(
@@ -59,7 +63,7 @@ def a2a_attention(
     S = qh.shape[2]
     from harmony_tpu.utils.platform import trace_is_tpu
 
-    if trace_is_tpu() and S % 128 == 0:
+    if trace_is_tpu() and tile_plan(S, S, D, qh.dtype, causal) is not None:
         o = flash_attention(qh, kh, vh, causal=causal, scale=scale)
     else:
         o = blockwise_attention(qh, kh, vh, causal=causal, scale=scale)

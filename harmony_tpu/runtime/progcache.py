@@ -456,6 +456,48 @@ def compiles_by_job() -> Dict[str, Dict[str, float]]:
 jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
 
 
+# -- the tiles a traced kernel chose, by job ----------------------------------
+#
+# The flash kernels tile themselves from the shape (ops/attention.py
+# ``tile_plan``): static per compiled program, so the record is made once, at
+# trace time, under the job whose span is open on the tracing thread.
+
+def _kernel_plan_family():
+    from harmony_tpu.metrics.registry import get_registry
+
+    return get_registry().gauge(
+        "harmony_kernel_grid_steps",
+        "Grid steps a call of a Pallas kernel under the tiles it was traced "
+        "with, by job (planned=0: the caller's explicit blocks)",
+        ("job", "kernel", "block_q", "block_k", "sub", "planned"))
+
+
+def note_kernel_plan(kernel: str, block_q: int, block_k: int, sub: int,
+                     grid_steps: int, planned: bool) -> None:
+    from harmony_tpu.tracing.span import current_job
+
+    _kernel_plan_family().labels(
+        job=current_job() or "-", kernel=kernel, block_q=str(block_q),
+        block_k=str(block_k), sub=str(sub), planned=str(int(planned)),
+    ).set(grid_steps)
+
+
+def kernel_plans() -> Dict[str, list]:
+    """``{job: [{kernel, block_q, block_k, sub, planned, grid_steps}]}`` of
+    every kernel traced in this process — STATUS ``kernel_plans``."""
+    out: Dict[str, list] = {}
+    try:
+        for (job, kernel, bq, bk, sub, planned), child in \
+                _kernel_plan_family().children():
+            out.setdefault(job, []).append({
+                "kernel": kernel, "block_q": int(bq), "block_k": int(bk),
+                "sub": int(sub), "planned": planned == "1",
+                "grid_steps": int(child.value)})
+    except Exception:
+        return {}
+    return out
+
+
 def clear() -> None:
     with _lock:
         _cache.clear()

@@ -1,0 +1,51 @@
+"""The flash kernels under their own tile plan, compiled by Mosaic for a v5e
+that is described, not attached — what interpret mode and cross-lowering
+cannot see: a slice Mosaic cannot prove aligned, a plan over the scoped
+VMEM. Nothing runs; no time comes from here.
+
+The topology is described inside a fixture, never at import (only one
+process may load libtpu, and every xdist worker imports this file), and
+the compiles stay in this one file for the same reason.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from harmony_tpu.ops.attention import flash_attention_lse, tile_plan
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("b,h,s,d,dtype,causal", [
+    (8, 12, 1024, 64, jnp.bfloat16, True),    # the gpt2-124m cells' call
+    (8, 12, 197, 64, jnp.bfloat16, False),    # ViT-B/16: one block of 197
+    (2, 4, 197, 64, jnp.float32, True),       # ... under a dynamic guard
+    (1, 8, 8192, 128, jnp.bfloat16, True),    # carries vmem_limit_bytes
+])
+def test_planned_flash_kernels_compile_for_v5e(one_chip, b, h, s, d, dtype,
+                                               causal):
+    plan = tile_plan(s, s, d, dtype, causal)
+    assert (plan.dkv.vmem_limit_bytes is not None) == (s == 8192)
+    x = jax.ShapeDtypeStruct((b, h, s, d), dtype, sharding=one_chip)
+
+    def loss(q, k, v):
+        out, lse = flash_attention_lse(q, k, v, causal)
+        return out.astype(jnp.float32).sum() + lse.sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 3

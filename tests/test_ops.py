@@ -362,3 +362,195 @@ def test_flash_bf16_operands_match_f32_reference():
         np.testing.assert_allclose(np.asarray(gf, np.float32),
                                    np.asarray(gr, np.float32),
                                    rtol=0.1, atol=0.1)
+
+
+# -- the flash kernels' tile plan (ops/attention.py tile_plan) ---------------
+
+_PLAN_LENGTHS = [(65, 65), (197, 197), (256, 256), (257, 257), (512, 512),
+                 (1024, 1024), (2048, 2048), (256, 1024), (1024, 512),
+                 (200, 200), (300, 300), (384, 768), (8192, 8192)]
+
+
+@pytest.mark.parametrize("sq,sk", _PLAN_LENGTHS)
+@pytest.mark.parametrize("d,dtype", [(64, jnp.bfloat16), (128, jnp.float32)])
+def test_tile_plan_divides_fits_and_agrees_with_flash_ok(sq, sk, d, dtype):
+    """The plan as a pure function: tiles divide both lengths, the sub-block
+    divides the streamed tile, the VMEM estimate stays inside the budget
+    (and a plan over Mosaic's default says so), and it is None exactly
+    where flash_ok — the models' gate — is false."""
+    from harmony_tpu.models.common import flash_ok
+    from harmony_tpu.ops import attention as A
+
+    plan = A.tile_plan(sq, sk, d, dtype, causal=True)
+    tileable = all(s <= 256 or s % 128 == 0 for s in (sq, sk))
+    assert (plan is not None) == tileable
+    if sq == sk:
+        assert flash_ok(sq, head_dim=d, dtype=dtype) == tileable
+    if plan is None:
+        return
+    assert plan.planned
+    itemsize = jnp.dtype(dtype).itemsize
+    for kern in ("fwd", "dkv", "dq"):
+        t = getattr(plan, kern)
+        assert sq % t.block_q == 0 and sk % t.block_k == 0
+        streamed = t.block_q if kern == "dkv" else t.block_k
+        assert streamed % t.sub == 0
+        need = A._vmem_bytes(kern, t.block_q, t.block_k, t.sub, d, itemsize)
+        assert need <= A._VMEM_CAP
+        if t.vmem_limit_bytes is None:
+            assert need <= A._VMEM_FREE < A._VMEM_DEFAULT
+        else:
+            assert need < t.vmem_limit_bytes
+    if sq == sk == 1024 and d == 64:  # the gpt2 cell: 192 grid steps a call
+        assert plan.fwd[:3] == (512, 1024, 1024)
+        assert plan.dkv[:3] == (1024, 512, 512)
+        assert plan.dq[:3] == (512, 1024, 512)
+
+
+def test_tile_plan_explicit_blocks_win():
+    from harmony_tpu.models.common import flash_ok
+    from harmony_tpu.ops.attention import tile_plan
+
+    plan = tile_plan(1024, 1024, 64, jnp.bfloat16, True,
+                     block_q=128, block_k=256)
+    assert not plan.planned
+    assert plan.fwd[:3] == (128, 256, 256)     # one sub-block a grid step
+    assert plan.dkv[:3] == (128, 256, 128)
+    assert plan.dq[:3] == (128, 256, 256)
+    # blocks clamp to the length; a length they do not divide cannot tile
+    assert tile_plan(64, 64, 16, jnp.float32, block_q=128).fwd[:2] == (64, 64)
+    assert tile_plan(200, 200, 64, jnp.bfloat16, block_q=128) is None
+    assert flash_ok(200, block=128) is False and flash_ok(200)
+    q = jnp.zeros((1, 1, 200, 8))
+    with pytest.raises(ValueError, match="cannot tile"):
+        flash_attention(q, q, q, block_q=128, block_k=128, interpret=True)
+    q = jnp.zeros((1, 1, 300, 8))
+    with pytest.raises(ValueError, match="must divide by 128"):
+        flash_attention(q, q, q, interpret=True)
+
+
+def _assert_flash_matches_naive(q, k, v, causal, atol=2e-5, gtol=2e-4, **kw):
+    from harmony_tpu.ops.attention import flash_attention_lse
+
+    w = jax.random.normal(jax.random.PRNGKey(11), q.shape)
+    out, lse = flash_attention_lse(q, k, v, causal, kw.get("block_q"),
+                                   kw.get("block_k"), None, True)
+    np.testing.assert_allclose(out, naive_attention(q, k, v, causal),
+                               atol=atol)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * q.shape[-1] ** -0.5
+    if causal:
+        mask = jnp.arange(q.shape[2])[:, None] >= jnp.arange(k.shape[2])
+        s = jnp.where(mask, s, -1e30)
+    np.testing.assert_allclose(lse, jax.scipy.special.logsumexp(s, axis=-1),
+                               atol=1e-4)
+
+    def loss_flash(q, k, v):
+        return (flash_attention(q, k, v, causal=causal, interpret=True, **kw)
+                * w).sum()
+
+    def loss_naive(q, k, v):
+        return (naive_attention(q, k, v, causal) * w).sum()
+
+    g1 = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+    g2 = jax.grad(loss_naive, argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(g1, g2):
+        np.testing.assert_allclose(a, b, atol=gtol)
+
+
+def _qkv_lens(sq, sk, d, bh=1, seed=5):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return (jax.random.normal(ks[0], (1, bh, sq, d), jnp.float32),
+            jax.random.normal(ks[1], (1, bh, sk, d), jnp.float32),
+            jax.random.normal(ks[2], (1, bh, sk, d), jnp.float32))
+
+
+@pytest.mark.parametrize("sq,sk,causal", [
+    (1024, 1024, True),    # the gpt2 cell's plan: the diagonal crosses the
+                           # forward's one 512x1024 sub-block; dQ / dK/dV
+                           # walk two, skipping the one above the diagonal
+    (1024, 1024, False),   # every sub-block unmasked
+    (512, 1024, False),    # the ring's inner: a q chunk against a longer kv
+    (2048, 2048, True),    # the forward's loop runs 1 then 2 sub-blocks
+    (197, 197, True),      # one block of a length no multiple of 8
+])
+def test_flash_under_the_plan_matches_naive(sq, sk, causal):
+    """Forward, LSE and all three gradients under the tiles the kernels
+    choose for themselves, against the dense reference."""
+    q, k, v = _qkv_lens(sq, sk, 64, bh=2 if sq <= 1024 else 1)
+    _assert_flash_matches_naive(q, k, v, causal)
+
+
+@pytest.mark.parametrize("sq,sk,bq,bk", [
+    (128, 128, 64, 32),   # q block 0 skips kv steps 2, 3: their index_map
+                          # repeats step 1's block; the diagonal crosses
+                          # two kv blocks of every q block
+    (128, 256, 64, 64),   # more columns than rows: the LAST q block skips
+                          # kv blocks too, and kv tiles 2, 3 meet no row
+    (256, 128, 32, 64),   # more rows than columns: the late q blocks take
+                          # every kv block unmasked
+])
+def test_flash_causal_skips_and_clamps(sq, sk, bq, bk):
+    q, k, v = _qkv_lens(sq, sk, 16, bh=2, seed=6)
+    _assert_flash_matches_naive(q, k, v, True, block_q=bq, block_k=bk)
+
+
+@pytest.mark.parametrize("tiles", [(64, 256, 64), (128, 256, 128),
+                                   (64, 128, 32), (256, 256, 64)])
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_sub_block_walk_matches_naive(tiles, causal):
+    """The in-kernel walk at small sizes: a streamed tile of several
+    sub-blocks (skipped / masked / unmasked by the loop bounds) gives what
+    one sub-block a grid step gives, in all three kernels."""
+    from harmony_tpu.ops import attention as A
+
+    q, k, v = _qkv_lens(256, 256, 16, bh=2, seed=7)
+    do = jax.random.normal(jax.random.PRNGKey(8), q.shape)
+    scale = 16 ** -0.5
+    bq, bk, sub = tiles
+    out, lse = A._flash_forward(q, k, v, causal, A.Tiles(bq, bk, sub), scale,
+                                True)
+    np.testing.assert_allclose(out, naive_attention(q, k, v, causal),
+                               atol=2e-5)
+    plan = A.TilePlan(A.Tiles(bq, bk, sub), A.Tiles(bk, bq, sub),
+                      A.Tiles(bq, bk, sub), True)
+    got = A._flash_backward(q, k, v, out, lse, do, None, causal, plan, scale,
+                            True)
+    want = jax.vjp(lambda q, k, v: naive_attention(q, k, v, causal),
+                   q, k, v)[1](do)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=2e-4)
+
+
+def test_flash_plan_lowers_for_tpu_at_the_gpt2_shape():
+    """fwd + both backward kernels under the plan at the gpt2 cell's own
+    shape cross-lower through the Pallas TPU front end (block shapes,
+    memory spaces, dynamic loop bounds) without a chip."""
+    x = jax.ShapeDtypeStruct((8, 12, 1024, 64), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(x, x, x).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") == 3
+    for name in ("harmony_flash_fwd", "harmony_flash_bwd_dkv",
+                 "harmony_flash_bwd_dq"):
+        assert name in text
+
+
+def test_flash_plan_is_recorded_at_trace_time():
+    """STATUS ``kernel_plans``: which tiles each kernel of a traced program
+    runs and the grid steps a call takes under them."""
+    from harmony_tpu.runtime import progcache
+    from harmony_tpu.tracing import trace_span
+
+    x = jax.ShapeDtypeStruct((8, 12, 1024, 64), jnp.bfloat16)
+    with trace_span("job.build_step", job_id="plan-rec"):
+        jax.jit(jax.grad(lambda q: flash_attention(
+            q, q, q, causal=True).astype(jnp.float32).sum())).trace(x)
+    rows = {r["kernel"]: r for r in progcache.kernel_plans()["plan-rec"]}
+    assert rows["harmony_flash_fwd"] == {
+        "kernel": "harmony_flash_fwd", "block_q": 512, "block_k": 1024,
+        "sub": 1024, "planned": True, "grid_steps": 192}
+    assert rows["harmony_flash_bwd_dkv"]["grid_steps"] == 192
+    assert rows["harmony_flash_bwd_dq"]["sub"] == 512
