@@ -102,6 +102,11 @@ class Trainer:
     def cleanup(self, ctx: TrainerContext) -> None:
         """Final hook after the last epoch."""
 
+    def observe_step_vectors(self, job_id: str, vectors) -> None:
+        """Host side, at the metric drain: the step metrics that are not
+        scalars (``{name: array [steps, ...]}``, e.g. tokens per expert) —
+        they feed counters, never the per-batch loss series."""
+
     @classmethod
     def _epoch_hook_windowable(cls, trainer: "Trainer") -> bool:
         """Whether ``trainer``'s on_epoch_finished may run between the

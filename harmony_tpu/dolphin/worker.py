@@ -29,6 +29,7 @@ compute-heavy and network-heavy spans (ref: LocalTaskUnitScheduler.java:
 from __future__ import annotations
 
 import contextlib
+import logging
 import os
 import threading
 import time
@@ -735,6 +736,7 @@ class WorkerTasklet:
         self._probe_pull = None
         self._probe_pp = None
         self._comm_probe_times = (0.0, 0.0)
+        self._probe_failures = 0  # in a row: backs the next attempt off
         self._step_sharding = None
         self._local_sharding = None
         self._batch_sharding = NamedSharding(mesh, P(DATA_AXIS))
@@ -1576,8 +1578,8 @@ class WorkerTasklet:
         the job reads the chief's measurement instead of re-measuring the
         same table's cost (the probe blocks the table lock for several
         device round-trips; once per job per epoch is enough). A failed
-        probe just skips this epoch's measurement — the previous split
-        stays in effect."""
+        probe skips this measurement — the previous split stays in
+        effect — and backs off (see the handler below)."""
         spans = self._mesh_spans_processes(self.ctx.model_table.mesh)
         if spans and self.dispatch_turn is None and self.ctx.num_workers != 1:
             # Multi-process mesh with multiple dispatch threads and no
@@ -1631,7 +1633,7 @@ class WorkerTasklet:
                 batch_dev = self._shard_batch(batch)
                 t_pull = timed(self._probe_pull, state, batch_dev)
                 t_pp = timed(self._probe_pp, state, batch_dev)
-        except Exception:
+        except Exception as e:
             if spans:
                 # A one-sided probe failure on a multi-process mesh has
                 # already desynchronized the pod's dispatch order (this
@@ -1639,11 +1641,24 @@ class WorkerTasklet:
                 # peers). Failing the job fast beats wedging the pod in a
                 # collective that can never complete.
                 raise
-            # a probe failure (layout race, donated buffer, transient
-            # backend error) must never kill training — skip this epoch's
-            # measurement and rebuild the programs next time
-            self._probe_pull = None
+            # A probe failure (a table too large for the probe's
+            # non-donating copies, a layout race, a transient backend
+            # error) must never kill training, and must not slow it
+            # either: the programs stay built (no compile, and
+            # _epoch_window_len keeps its windows), this measurement is
+            # skipped, and the next attempt waits twice as long after
+            # each failure in a row — the caller has just set
+            # _next_probe one refresh period ahead.
+            self._probe_failures += 1
+            self._next_probe += 8 * self.comm_probe_every * (
+                2 ** min(self._probe_failures, 8) - 1)
+            if self._probe_failures == 1:
+                logging.getLogger(__name__).warning(
+                    "%s: comm probe failed (%s: %s); no new comm/comp "
+                    "split, the next attempts back off", self.job_id,
+                    type(e).__name__, str(e)[:300])
             return
+        self._probe_failures = 0
         self._comm_probe_times = (t_pull, max(t_pp - t_pull, 0.0))
         # publish for sibling workers sharing this table (read at emit
         # time) through the table's typed accessor — a lock-fenced
@@ -2760,6 +2775,13 @@ class WorkerTasklet:
             if n:
                 self.ctx.model_table.count_dropped(n)
         host = {k: v for k, v in host.items() if not k.startswith("_")}
+        # a step's vectors ([steps, ...]: tokens per expert) feed the
+        # trainer's counters; the series below are per-step scalars
+        vectors = {k: v for k, v in host.items() if v.ndim > 1}
+        if vectors:
+            with trace_span("drain.vectors"):
+                self.trainer.observe_step_vectors(self.job_id, vectors)
+            host = {k: v for k, v in host.items() if k not in vectors}
         # one shared fallback rule (_primary_key) for the per-batch series
         lkey = self._primary_key(host)
         losses = host[lkey] if lkey is not None else np.zeros(len(batch_sizes))

@@ -184,9 +184,15 @@ class MetricManager:
         store = peek_budget()
         budgets = (store.snapshot_memoized(window_sec)
                    if store is not None else {})
+        from harmony_tpu.metrics import moe
+
+        routing = moe.stats_by_job()
         for jid, row in rows.items():
             rep = stragglers.get(jid)
             row["straggler_ratio"] = rep["ratio"] if rep else None
+            # dropless expert tenants: share of token-slots computed here,
+            # most loaded held expert over the mean (metrics/moe.py)
+            row["moe"] = routing.get(jid)
             b = budgets.get(jid)
             if b:
                 from harmony_tpu.metrics import critpath

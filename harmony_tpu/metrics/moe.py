@@ -1,0 +1,88 @@
+"""Expert-routing counters of dropless MoE tenants (models/moe.py).
+
+A step of such a tenant reports, beside its scalars, the token-slots each
+expert of each expert layer was chosen for — a vector, which the worker's
+metric drain hands to the trainer (``Trainer.observe_step_vectors``) and the
+trainer hands here:
+
+  * ``harmony_moe_expert_tokens_total{job,layer,expert}`` — token-slots routed
+    to every expert the ROUTER scores (held on this device or not);
+  * ``harmony_moe_held_slots_total{job}`` — those of them routed to experts
+    this device holds (the rows its grouped matmuls computed);
+  * ``harmony_moe_experts_held{job}`` — how many experts (0 .. n-1) it holds.
+
+Under a profiler session the span ``moe.observe`` (light; opened at each
+drain) carries the drained steps' held token-slots one by one
+(``held_slots="n/n/..."``, summed over the layers): the only per-STEP record
+of the rows the grouped matmuls computed, which a trace reader lays over the
+kernels' own events.
+
+STATUS shows, per tenant, ``moe: {held_slot_share, load_max_over_mean}``
+(:func:`stats_by_job`): the share of all token-slots this device computed,
+and the most loaded held expert's tokens over the held experts' mean.
+"""
+from __future__ import annotations
+
+from typing import Dict
+
+import numpy as np
+
+
+def _families():
+    from harmony_tpu.metrics.registry import get_registry
+
+    reg = get_registry()
+    return (reg.counter(
+                "harmony_moe_expert_tokens_total",
+                "Token-slots routed to each expert of each expert layer",
+                ("job", "layer", "expert")),
+            reg.counter(
+                "harmony_moe_held_slots_total",
+                "Token-slots routed to experts this device holds", ("job",)),
+            reg.gauge(
+                "harmony_moe_experts_held",
+                "Experts (0 .. n-1) of each expert layer held on this device",
+                ("job",)))
+
+
+def observe(job: str, expert_tokens: np.ndarray, experts_held: int) -> None:
+    """Add ``expert_tokens [steps, layers, experts]`` to the counters."""
+    from harmony_tpu.tracing import trace_span
+
+    by_step = np.asarray(expert_tokens, np.float64)
+    held_by_step = by_step[:, :, :experts_held].sum(axis=(1, 2))
+    with trace_span("moe.observe", record=False, job=job,
+                    steps=len(held_by_step),
+                    held_slots="/".join(str(int(n)) for n in held_by_step)):
+        per = by_step.sum(axis=0)  # [layers, E]
+        tokens, held_slots, held = _families()
+        for layer, row in enumerate(per):
+            for expert, n in enumerate(row):
+                tokens.labels(job=job, layer=str(layer),
+                              expert=str(expert)).inc(float(n))
+        held_slots.labels(job=job).inc(float(held_by_step.sum()))
+        held.labels(job=job).set(experts_held)
+
+
+def stats_by_job() -> Dict[str, Dict[str, float]]:
+    """``{job: {held_slot_share, load_max_over_mean}}`` from the counters."""
+    out: Dict[str, Dict[str, float]] = {}
+    try:
+        tokens, held_slots, held = _families()
+        n_held = {job: int(c.value) for (job,), c in held.children()}
+        by_expert: Dict[str, Dict[int, float]] = {}
+        for (job, _layer, expert), c in tokens.children():
+            row = by_expert.setdefault(job, {})
+            row[int(expert)] = row.get(int(expert), 0.0) + c.value
+        slots = {job: c.value for (job,), c in held_slots.children()}
+        for job, row in by_expert.items():
+            total = sum(row.values())
+            mine = [row.get(e, 0.0) for e in range(n_held.get(job, 0))]
+            if total <= 0 or not mine or sum(mine) <= 0:
+                continue
+            out[job] = {
+                "held_slot_share": slots.get(job, 0.0) / total,
+                "load_max_over_mean": max(mine) / (sum(mine) / len(mine))}
+    except Exception:
+        return {}
+    return out

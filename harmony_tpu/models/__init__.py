@@ -8,7 +8,14 @@ and whose parameters live in the same elastic DenseTable substrate as every
 other app (so checkpointing, migration and multi-tenancy apply unchanged).
 """
 from harmony_tpu.models.generate import make_generate_fn
-from harmony_tpu.models.moe import MoEConfig, init_moe_params, moe_ffn
+from harmony_tpu.models.moe import (
+    DroplessConfig,
+    MoEConfig,
+    init_dropless_params,
+    init_moe_params,
+    moe_ffn,
+    moe_ffn_dropless,
+)
 from harmony_tpu.models.transformer import (
     TransformerConfig,
     TransformerLM,
@@ -19,6 +26,7 @@ from harmony_tpu.models.pytree_trainer import PyTreeTrainer
 from harmony_tpu.models.vit import ViT, ViTConfig, ViTTrainer
 
 __all__ = [
+    "DroplessConfig",
     "MoEConfig",
     "TransformerConfig",
     "TransformerLM",
@@ -27,8 +35,10 @@ __all__ = [
     "ViT",
     "ViTConfig",
     "ViTTrainer",
+    "init_dropless_params",
     "init_moe_params",
     "make_generate_fn",
     "make_lm_data",
     "moe_ffn",
+    "moe_ffn_dropless",
 ]

@@ -15,10 +15,10 @@ def dense_init(key, shape):
     return jax.random.normal(key, shape, jnp.float32) * shape[0] ** -0.5
 
 
-def rms_norm(x, w):
+def rms_norm(x, w, eps: float = 1e-6):
     """RMSNorm (f32 statistics regardless of activation dtype)."""
     xf = x.astype(jnp.float32)
-    scale = jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + 1e-6)
+    scale = jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
     return (xf * scale).astype(x.dtype) * w
 
 

@@ -128,6 +128,7 @@ def make_generate_fn(model, prompt_len: int, num_new: int,
     at temperature 0). ``prompt_len + num_new`` must fit
     ``config.max_seq``."""
     cfg = model.config
+    cfg.require_classic_block("make_generate_fn")
     total = prompt_len + num_new
     if total > cfg.max_seq:
         raise ValueError(

@@ -1,12 +1,26 @@
-"""Mixture-of-Experts FFN with expert parallelism (all_to_all routing).
+"""Mixture-of-Experts FFNs: two routings, each with its own use.
 
-Completes the parallelism set (dp/tp/sp/pp/**ep**). Switch-Transformer
-style: top-1 router with bounded per-expert capacity; dispatch/combine are
-one-hot einsums (MXU-friendly, static shapes); with expert parallelism the
-expert dimension is sharded over a mesh axis and token buckets move to
-their expert's device — and back — via ``lax.all_to_all`` over ICI.
+**Dropless top-k** (:func:`moe_ffn_dropless` — the block of today's open
+sparse models, OLMoE's in ``perf/configs/olmoe-1b-7b.json``): softmax over
+all experts in float32, the top ``k`` of it per token, NO capacity and NO
+drops, gate weights as the softmax gave them (never renormalised). The
+``T x k`` token-slots are sorted by expert, the rows gathered, and the gated-SiLU experts run as three ragged grouped matmuls
+(ops/grouped_matmul.py) over the sorted rows; the results return to their
+tokens weighted by the gates. A device may hold only the first
+``experts_held`` experts (its share of an expert-parallel deployment): the
+router still scores all of them, slots routed to an absent expert sort last
+and their tiles are never visited, and the layer's output is this device's
+part of the sum. Returns the routing statistics the auxiliary losses and the
+counters need.
 
-Semantics:
+**Switch top-1 with capacity** (:func:`moe_ffn` — the older path, with
+expert parallelism over a mesh axis): bounded per-expert capacity,
+dispatch/combine as one-hot einsums (MXU-friendly, static shapes); with
+expert parallelism the expert dimension is sharded over a mesh axis and
+token buckets move to their expert's device — and back — via
+``lax.all_to_all`` over ICI.
+
+Switch semantics:
   * capacity C per (expert, source shard) = ceil(T_local * capacity_factor
     / num_experts); tokens routed beyond capacity are DROPPED by dispatch
     (their combine weight is 0) — callers keep a residual connection so a
@@ -20,6 +34,7 @@ leading dim of the params is sharded over that mesh axis (inside shard_map).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -111,3 +126,92 @@ def moe_ffn(
 
     out = jnp.einsum("tec,ecd->td", combine, ye).astype(x.dtype)
     return out, aux
+
+
+# ---------------------------------------------------------------------------
+# Dropless top-k routing over ragged grouped matmuls
+# ---------------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class DroplessConfig:
+    num_experts: int          # the router's width
+    top_k: int
+    d_model: int
+    d_ff: int                 # one expert's width
+    experts_held: int         # experts 0 .. experts_held-1 live here
+
+
+def init_dropless_params(rng: jax.Array, cfg: DroplessConfig
+                         ) -> Dict[str, jnp.ndarray]:
+    """Router over ALL experts; gate/up/down weights of the HELD experts,
+    expert-stacked on the leading dim."""
+    kr, kg, ku, kd = jax.random.split(rng, 4)
+    E, H, d, f = cfg.num_experts, cfg.experts_held, cfg.d_model, cfg.d_ff
+    return {
+        "router": jax.random.normal(kr, (d, E), jnp.float32) * (d ** -0.5),
+        "wg": jax.random.normal(kg, (H, d, f), jnp.float32) * (d ** -0.5),
+        "wu": jax.random.normal(ku, (H, d, f), jnp.float32) * (d ** -0.5),
+        "wd": jax.random.normal(kd, (H, f, d), jnp.float32) * (f ** -0.5),
+    }
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _slot_rows(x, order, inv, k):
+    """Row ``x[s // k]`` for each slot ``s`` of the PERMUTATION ``order``
+    with inverse ``inv`` (slot ``s`` = token ``s // k``'s ``s % k``-th
+    choice; ``k = 1``: plainly ``x[order]``). The cotangent gathers by
+    ``inv`` and sums a token's ``k`` slots — autodiff's would be a
+    scatter-add, which a TPU serialises."""
+    return x[order // k]
+
+
+def _slot_rows_bwd(k, res, g):
+    inv, = res
+    per_slot = g[inv].reshape(-1, k, g.shape[-1])
+    return per_slot.astype(jnp.float32).sum(axis=1).astype(g.dtype), None, None
+
+
+_slot_rows.defvjp(lambda x, order, inv, k: (x[order // k], (inv,)),
+                  _slot_rows_bwd)
+
+
+def moe_ffn_dropless(params: Dict[str, jnp.ndarray], x: jnp.ndarray,
+                     cfg: DroplessConfig
+                     ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+    """``(out [T, d], stats)`` for ``x [T, d]``. ``out`` sums, per token,
+    ``p_e * down_e(silu(gate_e x) * up_e x)`` over the token's top-k experts
+    that are held here. ``stats``: ``tokens [E]`` (token-slots each expert
+    was chosen for), ``prob_sum [E]`` (sum over tokens of the router's
+    probability), ``z_sum`` (sum over tokens of logsumexp(logits)^2) and
+    ``n`` (tokens) — sums, so layers add before a mean is taken."""
+    from harmony_tpu.ops.grouped_matmul import grouped_matmul
+
+    T, d = x.shape
+    E, k, H = cfg.num_experts, cfg.top_k, cfg.experts_held
+    # a tiny matmul deciding discrete routes: full float32 passes on the MXU
+    logits = jnp.dot(x.astype(jnp.float32), params["router"],
+                     precision=lax.Precision.HIGHEST)            # [T, E]
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    probs = jnp.exp(logits - lse[:, None])
+    gate, expert = lax.top_k(probs, k)                           # [T, k]
+    slot_expert = expert.reshape(-1)                             # [T * k]
+    # a compare-and-sum, not a scatter-add (which a TPU serialises)
+    tokens = jnp.sum(slot_expert[:, None] == jnp.arange(E)[None, :], axis=0,
+                     dtype=jnp.int32)
+    stats = {"tokens": tokens.astype(jnp.float32), "n": jnp.float32(T),
+             "prob_sum": probs.sum(axis=0), "z_sum": jnp.sum(lse * lse)}
+    # slots sorted by expert: the held experts' runs come first (they are
+    # experts 0 .. H-1), absent experts' slots after them
+    order = jnp.argsort(slot_expert, stable=True)
+    inv = jnp.argsort(order)  # a permutation's inverse, again by sorting
+    sizes = tokens[:H]
+    rows = _slot_rows(x, order, inv, k)                          # [T * k, d]
+    dtype = x.dtype
+    h = (jax.nn.silu(grouped_matmul(rows, params["wg"].astype(dtype), sizes))
+         * grouped_matmul(rows, params["wu"].astype(dtype), sizes))
+    y = grouped_matmul(h, params["wd"].astype(dtype), sizes)     # [T * k, d]
+    # back to slot order; an absent expert's slot carries weight 0
+    weight = jnp.where(expert < H, gate, 0.0)                    # [T, k]
+    y = _slot_rows(y, inv, order, 1).reshape(T, k, d)
+    out = jnp.einsum("tkd,tk->td", y.astype(jnp.float32), weight)
+    return out.astype(dtype), stats

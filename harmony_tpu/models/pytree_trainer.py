@@ -49,6 +49,7 @@ class PyTreeTrainer(Trainer):
         step_size: float = 0.1,
         seed: int = 0,
         optimizer: str = "sgd",
+        beta2: "float | None" = None,
         **config_kwargs,
     ) -> None:
         from harmony_tpu.dolphin import optim
@@ -63,6 +64,9 @@ class PyTreeTrainer(Trainer):
         self.step_size = step_size
         self.seed = seed
         self.optimizer = optimizer
+        #: Adam's second-moment decay where a model's recipe departs from
+        #: the optimizer's 0.999 (dolphin/optim.py reads hyper["beta2"])
+        self.beta2 = beta2
         self.num_state_slots = optim.num_slots(optimizer)  # validates name
         template = jax.eval_shape(
             lambda: self.model.init(jax.random.PRNGKey(0))
@@ -79,6 +83,12 @@ class PyTreeTrainer(Trainer):
         """Pure scalar loss for one batch; subclasses bind the model's
         batch signature here."""
         raise NotImplementedError
+
+    def loss_and_metrics_on_batch(self, params, batch):
+        """``(loss, {name: array})``: what a step reports beside its loss
+        (scalars, or vectors the drain hands to ``observe_step_vectors``).
+        Default: nothing."""
+        return self.loss_on_batch(params, batch), {}
 
     def eval_metrics(self, params, batch) -> Dict[str, jnp.ndarray]:
         return {"loss": self.loss_on_batch(params, batch)}
@@ -128,14 +138,17 @@ class PyTreeTrainer(Trainer):
         return rows.reshape(-1)[: self.num_params]
 
     def hyperparams(self) -> Dict[str, float]:
-        return {"lr": self.step_size}
+        if self.beta2 is None:
+            return {"lr": self.step_size}
+        return {"lr": self.step_size, "beta2": self.beta2}
 
     def compute(self, model, batch, hyper):
         from harmony_tpu.dolphin import optim
 
         pflat = self._section(model, 0)
         params = self._unravel(pflat)
-        loss, grads = jax.value_and_grad(self.loss_on_batch)(params, batch)
+        (loss, extra), grads = jax.value_and_grad(
+            self.loss_and_metrics_on_batch, has_aux=True)(params, batch)
         gflat, _ = ravel_pytree(grads)
         slots = self.num_state_slots
         m = self._section(model, 1) if slots >= 1 else jnp.zeros_like(pflat)
@@ -153,7 +166,7 @@ class PyTreeTrainer(Trainer):
         if slots:
             counter = jnp.zeros((1, self.row_width), delta.dtype).at[0, 0].set(1.0)
             delta = jnp.concatenate([delta, counter])
-        return delta, {"loss": loss}
+        return delta, {"loss": loss, **extra}
 
     def evaluate(self, model, batch) -> Dict[str, jnp.ndarray]:
         params = self._unravel(self._section(model, 0))

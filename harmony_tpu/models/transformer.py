@@ -61,6 +61,25 @@ class TransformerConfig:
     moe_every: int = 2
     moe_capacity_factor: float = 1.5
     moe_aux_weight: float = 0.01
+    # -- the block's architecture (a model's published shape, not knobs):
+    # the defaults are the GPT-2-era block above, bit for bit ------------
+    pos: str = "learned"            # "learned" table | "rope" (rotate-half
+    rope_theta: float = 10000.0     # rotary on q and k, no table)
+    qk_norm: bool = False           # RMSNorm over the whole d_model-wide q
+                                    # and k before the head split (OLMoE)
+    ffn: str = "gelu"               # "gelu" MLP | "swiglu" (gated SiLU)
+    tie_embeddings: bool = True     # False: a separate [d, vocab] "head"
+    norm_eps: float = 1e-6
+    # Dropless top-k experts (models/moe.py moe_ffn_dropless): softmax over
+    # ``moe_experts``, the top ``moe_top_k`` per token, no capacity, no
+    # drops; 0 keeps the Switch top-1 capacity path. This device holds
+    # experts 0 .. moe_experts_held-1 (None: all) — its share of an
+    # expert-parallel deployment; the router keeps its full width. The
+    # load-balance loss joins at ``moe_aux_weight``, the router z-loss at
+    # ``moe_z_weight``.
+    moe_top_k: int = 0
+    moe_experts_held: Optional[int] = None
+    moe_z_weight: float = 0.0
 
     def __post_init__(self):
         from harmony_tpu.models.common import validate_attn
@@ -71,6 +90,27 @@ class TransformerConfig:
             raise ValueError(f"unknown sp_attn {self.sp_attn!r}")
         if self.moe_experts and self.moe_every < 1:
             raise ValueError("moe_every must be >= 1")
+        if self.pos not in ("learned", "rope"):
+            raise ValueError(f"unknown pos {self.pos!r}: 'learned' or 'rope'")
+        if self.ffn not in ("gelu", "swiglu"):
+            raise ValueError(f"unknown ffn {self.ffn!r}: 'gelu' or 'swiglu'")
+        if self.pos == "rope" and self.head_dim % 2:
+            raise ValueError(f"rotary positions rotate pairs: head width "
+                             f"{self.head_dim} is odd")
+        if not 0 <= self.moe_top_k <= self.moe_experts:
+            raise ValueError(f"moe_top_k {self.moe_top_k} must lie in 0.."
+                             f"moe_experts ({self.moe_experts})")
+        if self.moe_top_k and self.ffn != "swiglu":
+            raise ValueError("dropless experts (moe_top_k > 0) are gated-SiLU:"
+                             " set ffn='swiglu'")
+        if not self.moe_top_k and (self.moe_experts_held is not None
+                                   or self.moe_z_weight):
+            raise ValueError("moe_experts_held / moe_z_weight belong to "
+                             "dropless routing: set moe_top_k")
+        if self.moe_experts_held is not None and not (
+                1 <= self.moe_experts_held <= self.moe_experts):
+            raise ValueError(f"moe_experts_held {self.moe_experts_held} must "
+                             f"lie in 1..moe_experts ({self.moe_experts})")
         validate_attn(self.attn)
 
     def is_moe_layer(self, i: int) -> bool:
@@ -88,11 +128,49 @@ class TransformerConfig:
                          capacity_factor=self.moe_capacity_factor)
 
     @property
+    def dropless_cfg(self):
+        from harmony_tpu.models.moe import DroplessConfig
+
+        held = self.moe_experts_held
+        return DroplessConfig(
+            num_experts=self.moe_experts, top_k=self.moe_top_k,
+            d_model=self.d_model, d_ff=self.d_ff,
+            experts_held=self.moe_experts if held is None else held)
+
+    @property
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
 
+    def require_classic_block(self, who: str) -> None:
+        """The side training steps and the decode path below still assume
+        the GPT-2-era block (learned positions, GELU, tied readout, Switch
+        experts if any)."""
+        if not (self.pos == "learned" and self.ffn == "gelu"
+                and self.tie_embeddings and not self.qk_norm
+                and not self.moe_top_k):
+            raise ValueError(
+                f"{who} runs the GPT-2-era block only (learned positions, "
+                "GELU, tied readout, Switch experts); rotary / QK-norm / "
+                "SwiGLU / untied / dropless configs train through "
+                "TransformerLM.loss and TransformerTrainer")
+
 
 from harmony_tpu.models.common import rms_norm as _norm  # noqa: E402
+
+
+def rope(x, theta: float, pos_offset=0):
+    """Rotate-half rotary positions on ``x [B, H, S, hd]`` (positions
+    ``pos_offset .. pos_offset+S-1``): float32 angles, result in x's dtype."""
+    hd = x.shape[-1]
+    inv_freq = theta ** (-jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
+    ang = (pos_offset + jnp.arange(x.shape[2], dtype=jnp.float32)
+           )[:, None] * inv_freq[None, :]                        # [S, hd/2]
+    cos = jnp.concatenate([jnp.cos(ang)] * 2, axis=-1)
+    sin = jnp.concatenate([jnp.sin(ang)] * 2, axis=-1)
+    xf = x.astype(jnp.float32)
+    x1, x2 = jnp.split(xf, 2, axis=-1)
+    return (xf * cos + jnp.concatenate([-x2, x1], axis=-1) * sin
+            ).astype(x.dtype)
 
 
 class TransformerLM:
@@ -120,20 +198,34 @@ class TransformerLM:
                 "wo": dense(ks[1], (d, d)),
                 "ln2": jnp.ones((d,), jnp.float32),
             }
+            if cfg.qk_norm:
+                layer["q_norm"] = jnp.ones((d,), jnp.float32)
+                layer["k_norm"] = jnp.ones((d,), jnp.float32)
             if cfg.is_moe_layer(i):
-                from harmony_tpu.models.moe import init_moe_params
+                from harmony_tpu.models import moe
 
-                layer["moe"] = init_moe_params(ks[2], cfg.moe_cfg)
+                layer["moe"] = (
+                    moe.init_dropless_params(ks[2], cfg.dropless_cfg)
+                    if cfg.moe_top_k else
+                    moe.init_moe_params(ks[2], cfg.moe_cfg))
             else:
                 layer["w1"] = dense(ks[2], (d, f))
                 layer["w2"] = dense(ks[3], (f, d))
+                if cfg.ffn == "swiglu":  # w1 gates, w3 is the up projection
+                    layer["w3"] = dense(jax.random.fold_in(ks[2], 1), (d, f))
             layers.append(layer)
-        return {
+        params = {
             "embed": jax.random.normal(k_emb, (cfg.vocab_size, d), jnp.float32) * 0.02,
-            "pos": jax.random.normal(k_pos, (cfg.max_seq, d), jnp.float32) * 0.02,
             "ln_f": jnp.ones((d,), jnp.float32),
             "layers": layers,
         }
+        if cfg.pos == "learned":
+            params["pos"] = jax.random.normal(
+                k_pos, (cfg.max_seq, d), jnp.float32) * 0.02
+        if not cfg.tie_embeddings:
+            params["head"] = dense(jax.random.fold_in(k_emb, 1),
+                                   (d, cfg.vocab_size))
+        return params
 
     def init_numpy(self, seed: int = 0) -> Dict[str, Any]:
         """``init`` with numpy arrays and NO jax op — same layout and
@@ -157,27 +249,43 @@ class TransformerLM:
                 "wo": dense((d, d)),
                 "ln2": np.ones((d,), np.float32),
             }
+            if cfg.qk_norm:
+                layer["q_norm"] = np.ones((d,), np.float32)
+                layer["k_norm"] = np.ones((d,), np.float32)
             if cfg.is_moe_layer(i):
                 E = cfg.moe_experts
-                layer["moe"] = {
-                    "router": dense((d, E)),
-                    "w1": (rng.standard_normal((E, d, f)) * d ** -0.5
-                           ).astype(np.float32),
-                    "w2": (rng.standard_normal((E, f, d)) * f ** -0.5
-                           ).astype(np.float32),
-                }
+
+                def stacked(n, a, b):  # n experts of [a, b], fan-in a
+                    return (rng.standard_normal((n, a, b)) * a ** -0.5
+                            ).astype(np.float32)
+
+                if cfg.moe_top_k:
+                    H = cfg.dropless_cfg.experts_held
+                    layer["moe"] = {
+                        "router": dense((d, E)), "wg": stacked(H, d, f),
+                        "wu": stacked(H, d, f), "wd": stacked(H, f, d)}
+                else:
+                    layer["moe"] = {
+                        "router": dense((d, E)), "w1": stacked(E, d, f),
+                        "w2": stacked(E, f, d)}
             else:
                 layer["w1"] = dense((d, f))
                 layer["w2"] = dense((f, d))
+                if cfg.ffn == "swiglu":
+                    layer["w3"] = dense((d, f))
             layers.append(layer)
-        return {
+        params = {
             "embed": (0.02 * rng.standard_normal(
                 (cfg.vocab_size, d))).astype(np.float32),
-            "pos": (0.02 * rng.standard_normal(
-                (cfg.max_seq, d))).astype(np.float32),
             "ln_f": np.ones((d,), np.float32),
             "layers": layers,
         }
+        if cfg.pos == "learned":
+            params["pos"] = (0.02 * rng.standard_normal(
+                (cfg.max_seq, d))).astype(np.float32)
+        if not cfg.tie_embeddings:
+            params["head"] = dense((d, cfg.vocab_size))
+        return params
 
     # -- forward ---------------------------------------------------------
 
@@ -195,23 +303,33 @@ class TransformerLM:
         return blockwise_attention(q, k, v, causal=True)
 
     def _block(self, x, layer, axis_name: Optional[str],
-               moe_axis: Optional[str] = None):
+               moe_axis: Optional[str] = None, pos_offset: Any = 0):
         """One pre-norm decoder block — the shared body of ``apply`` and
         the pipeline-parallel stage fn. Returns ``(x, aux)``: aux is the
-        Switch load-balance loss when the block carries an MoE FFN, 0
-        otherwise. ``moe_axis`` = expert-parallel mesh axis (see
-        ffn_apply)."""
+        Switch load-balance loss when the block carries a Switch MoE FFN,
+        the dropless layer's routing statistics (a dict of sums,
+        models/moe.py) when it carries that, 0 otherwise. ``moe_axis`` =
+        expert-parallel mesh axis (see ffn_apply). The published q/k/v
+        projections are the three column blocks of ``wqkv``."""
         cfg = self.config
         B, S = x.shape[0], x.shape[1]
         d, h, hd = cfg.d_model, cfg.n_heads, cfg.head_dim
-        xn = _norm(x, layer["ln1"].astype(cfg.dtype))
+        eps = cfg.norm_eps
+        xn = _norm(x, layer["ln1"].astype(cfg.dtype), eps)
         qkv = xn @ layer["wqkv"].astype(cfg.dtype)              # [B, S, 3d]
         q, k, v = jnp.split(qkv, 3, axis=-1)
+        if cfg.qk_norm:
+            q = _norm(q, layer["q_norm"].astype(cfg.dtype), eps)
+            k = _norm(k, layer["k_norm"].astype(cfg.dtype), eps)
         to_heads = lambda t: t.reshape(B, S, h, hd).transpose(0, 2, 1, 3)
-        o = self._attention(to_heads(q), to_heads(k), to_heads(v), axis_name)
+        q, k, v = to_heads(q), to_heads(k), to_heads(v)
+        if cfg.pos == "rope":
+            q = rope(q, cfg.rope_theta, pos_offset)
+            k = rope(k, cfg.rope_theta, pos_offset)
+        o = self._attention(q, k, v, axis_name)
         o = o.transpose(0, 2, 1, 3).reshape(B, S, d)
         x = x + o @ layer["wo"].astype(cfg.dtype)
-        xn = _norm(x, layer["ln2"].astype(cfg.dtype))
+        xn = _norm(x, layer["ln2"].astype(cfg.dtype), eps)
         out, aux = ffn_apply(cfg, layer, xn, moe_axis=moe_axis)
         return x + out, aux
 
@@ -227,12 +345,16 @@ class TransformerLM:
 
     def _apply_with_aux(self, params, tokens, axis_name=None, pos_offset=0,
                         moe_axis=None):
-        """apply + the summed MoE aux loss (0 for dense configs)."""
+        """apply + the MoE aux: the summed Switch loss (0 for dense
+        configs), or for dropless configs the routing statistics summed
+        over the layers plus ``tokens_by_layer [moe layers, E]``."""
         cfg = self.config
-        x = _embed_in(cfg, params["embed"], params["pos"], tokens, pos_offset)
+        x = _embed_in(cfg, params["embed"], params.get("pos"), tokens,
+                      pos_offset)
 
         def block(x, layer):
-            return self._block(x, layer, axis_name, moe_axis=moe_axis)
+            return self._block(x, layer, axis_name, moe_axis=moe_axis,
+                               pos_offset=pos_offset)
 
         if cfg.remat:
             # Per-layer rematerialization: the backward recomputes each
@@ -242,22 +364,58 @@ class TransformerLM:
             # HBM usually doesn't).
             block = jax.checkpoint(block)
         aux = jnp.asarray(0.0, jnp.float32)
+        routed = []  # dropless layers' statistics
         for layer in params["layers"]:
             x, a = block(x, layer)
-            aux = aux + a
-        x = _norm(x, params["ln_f"].astype(cfg.dtype))
-        # Weight-tied readout, f32 logits for a stable softmax.
-        return x.astype(jnp.float32) @ params["embed"].T, aux
+            if isinstance(a, dict):
+                routed.append(a)
+            else:
+                aux = aux + a
+        if routed:
+            aux = jax.tree.map(lambda *xs: sum(xs), *routed)
+            aux["tokens_by_layer"] = jnp.stack([a["tokens"] for a in routed])
+        x = _norm(x, params["ln_f"].astype(cfg.dtype), cfg.norm_eps)
+        # f32 logits for a stable softmax; the readout is the embedding
+        # (weight-tied) unless the model has a head of its own
+        head = params["embed"].T if cfg.tie_embeddings else params["head"]
+        return x.astype(jnp.float32) @ head, aux
 
     def loss(self, params, tokens, axis_name=None) -> jnp.ndarray:
         """Mean next-token cross-entropy over the (single-device) batch,
-        plus the weighted MoE load-balance aux for expert configs."""
+        plus the weighted MoE auxiliary losses for expert configs."""
+        return self.loss_and_metrics(params, tokens, axis_name)[0]
+
+    def loss_and_metrics(self, params, tokens, axis_name=None):
+        """``(loss, metrics)``: metrics is empty except for dropless expert
+        configs, which report the loss's three terms and the token-slots
+        each expert of each layer was chosen for (a vector per step)."""
+        cfg = self.config
         logits, aux = self._apply_with_aux(params, tokens[:, :-1],
                                            axis_name=axis_name)
         ce = _next_token_ce(logits, tokens[:, 1:])
-        if self.config.moe_experts:
-            return ce + self.config.moe_aux_weight * aux
-        return ce
+        if cfg.moe_top_k:
+            lb, z = routing_losses(aux, cfg.moe_experts)
+            loss = ce + cfg.moe_aux_weight * lb + cfg.moe_z_weight * z
+            return loss, {"ce": ce, "aux_lb": lb, "aux_z": z,
+                          "moe_expert_tokens": aux["tokens_by_layer"]}
+        if cfg.moe_experts:
+            return ce + cfg.moe_aux_weight * aux, {}
+        return ce, {}
+
+
+def routing_losses(stats, num_experts: int):
+    """``(load_balance, router_z)`` from the dropless layers' summed
+    statistics, over ALL the layers' tokens at once as ``transformers``'
+    ``load_balancing_loss_func`` has it: ``E * sum_e f_e * P_e`` with
+    ``f_e`` the share of tokens whose top-k holds expert ``e`` (so
+    ``sum_e f_e = k``) and ``P_e`` the mean router probability of ``e``;
+    and the mean of ``logsumexp(router logits)^2``. Both on all experts'
+    logits whatever share of them is held here. ``f_e`` carries no
+    gradient (it counts)."""
+    n = stats["n"]  # layers x tokens
+    lb = num_experts * jnp.sum(
+        lax.stop_gradient(stats["tokens"]) / n * stats["prob_sum"] / n)
+    return lb, stats["z_sum"] / n
 
 
 def _next_token_ce(logits, targets) -> jnp.ndarray:
@@ -279,6 +437,13 @@ def ffn_apply(cfg, layer, xn, no_drop: bool = False,
     letting one sequence degrade another's output). ``moe_axis`` is the
     expert-parallel mesh axis: expert params are sharded on their leading
     dim and token buckets move over ICI via all_to_all (moe_ffn)."""
+    if "moe" in layer and cfg.moe_top_k:
+        from harmony_tpu.models.moe import moe_ffn_dropless
+
+        out, stats = moe_ffn_dropless(layer["moe"],
+                                      xn.reshape(-1, cfg.d_model),
+                                      cfg.dropless_cfg)
+        return out.reshape(xn.shape), stats
     if "moe" in layer:
         import dataclasses as _dc
 
@@ -290,14 +455,21 @@ def ffn_apply(cfg, layer, xn, no_drop: bool = False,
         flat = xn.reshape(-1, cfg.d_model)
         out, aux = moe_ffn(layer["moe"], flat, mcfg, axis_name=moe_axis)
         return out.reshape(xn.shape), aux
-    out = jax.nn.gelu(xn @ layer["w1"].astype(cfg.dtype)) \
-        @ layer["w2"].astype(cfg.dtype)
-    return out, jnp.asarray(0.0, jnp.float32)
+    hidden = xn @ layer["w1"].astype(cfg.dtype)
+    if cfg.ffn == "swiglu":
+        hidden = jax.nn.silu(hidden) * (xn @ layer["w3"].astype(cfg.dtype))
+    else:
+        hidden = jax.nn.gelu(hidden)
+    return (hidden @ layer["w2"].astype(cfg.dtype),
+            jnp.asarray(0.0, jnp.float32))
 
 
 def _embed_in(cfg, embed, pos, tokens, pos_offset=0) -> jnp.ndarray:
-    """Token+position embedding in activation dtype — shared by apply and
-    the pipeline-parallel path."""
+    """Token (+ learned position) embedding in activation dtype — shared by
+    apply and the pipeline-parallel path. Rotary configs have no table:
+    their positions enter in the block."""
+    if cfg.pos != "learned":
+        return embed[tokens].astype(cfg.dtype)
     idx = pos_offset + jnp.arange(tokens.shape[1])
     return (embed[tokens] + pos[idx]).astype(cfg.dtype)
 
@@ -343,6 +515,7 @@ def make_sp_train_step(
     params are replicated and stay replicated (grad psum over both axes).
     """
     axes = (data_axis, seq_axis)
+    model.config.require_classic_block("make_sp_train_step")
 
     def local_step(params, tokens, targets, mask):
         S_loc = tokens.shape[1]
@@ -456,6 +629,7 @@ def make_parallel_train_step(
     cfg = model.config
     from jax.sharding import NamedSharding
 
+    cfg.require_classic_block("make_parallel_train_step")
     if cfg.moe_experts:
         raise ValueError(
             "make_parallel_train_step is dense-only (its Megatron sharding "
@@ -558,6 +732,7 @@ def make_ep_train_step(
     from jax.sharding import NamedSharding
 
     cfg = model.config
+    cfg.require_classic_block("make_ep_train_step")
     ep = mesh.shape[data_axis]
     if not cfg.moe_experts:
         raise ValueError("make_ep_train_step needs an MoE config "
@@ -636,6 +811,7 @@ def make_pp_train_step(
     from harmony_tpu.parallel.pipeline import make_pipeline_fn
 
     cfg = model.config
+    cfg.require_classic_block("make_pp_train_step")
     if cfg.moe_experts:
         raise ValueError(
             "make_pp_train_step needs homogeneous layers to stage-stack; "
@@ -766,5 +942,15 @@ class TransformerTrainer(PyTreeTrainer):
         return TransformerLM(config)
 
     def loss_on_batch(self, params, batch):
+        return self.loss_and_metrics_on_batch(params, batch)[0]
+
+    def loss_and_metrics_on_batch(self, params, batch):
         tokens = batch[0] if isinstance(batch, (tuple, list)) else batch
-        return self.model.loss(params, tokens)
+        return self.model.loss_and_metrics(params, tokens)
+
+    def observe_step_vectors(self, job_id: str, vectors) -> None:
+        if "moe_expert_tokens" in vectors:
+            from harmony_tpu.metrics import moe
+
+            moe.observe(job_id, vectors["moe_expert_tokens"],
+                        self.config.dropless_cfg.experts_held)

@@ -229,6 +229,53 @@ class TestCommProbe:
                        - max(b.batch_time_sec,
                              b.pull_time_sec + b.push_time_sec)) < 1e-6
 
+    def test_failing_probe_backs_off_and_keeps_its_windows(self, mesh8,
+                                                            caplog):
+        """A probe that fails every time (on the chip: a pull-all table too
+        large for the probe's non-donating copies) is built ONCE, logged
+        once, retried twice as late after each failure in a row — and the
+        epochs between attempts dispatch in whole windows, not one by one."""
+        x, y = make_synthetic(64, num_features=8, num_classes=2)
+        trainer = MLRTrainer(num_classes=2, num_features=8,
+                             features_per_partition=4)
+        params = TrainerParams(num_epochs=60, num_mini_batches=2,
+                               comm_probe_period=1)
+        table = DenseTable(TableSpec(trainer.model_table_config()), mesh8)
+        ctx = TrainerContext(params=params, model_table=table)
+        w = WorkerTasklet("oom-j", ctx, trainer,
+                          TrainingDataProvider([x, y], 2), mesh8)
+        builds, attempts, windows = [], [], []
+
+        def fail(*_):
+            attempts.append(len(windows))
+            raise RuntimeError("RESOURCE_EXHAUSTED: Error loading program")
+
+        def build():
+            builds.append(1)
+            w._probe_pull = w._probe_pp = fail
+
+        window_len = w._epoch_window_len
+
+        def spy(epoch, num_epochs):
+            windows.append((epoch, window_len(epoch, num_epochs)))
+            return windows[-1][1]
+
+        w._build_comm_probe = build
+        w._epoch_window_len = spy
+        with caplog.at_level("WARNING", logger="harmony_tpu.dolphin.worker"):
+            result = w.run()
+        assert len(result["losses"]) == 60
+        assert len(builds) == 1
+        # attempts at epochs 0, 16 (8 x 2), 48 (16 + 8 x 4): each found
+        # that many windows dispatched before it
+        starts = [e for e, _ in windows]
+        assert [starts[n] for n in attempts] == [0, 16, 48]
+        assert [n for _, n in windows[:3]] == [8, 8, 8]
+        assert max(n for _, n in windows) == 8 and len(windows) <= 10
+        assert sum("comm probe failed" in r.message
+                   for r in caplog.records) == 1
+        assert table.comm_split() is None
+
     def test_probe_disabled_degenerates_to_comp(self, mesh8):
         from harmony_tpu.metrics import MetricCollector, MetricManager
 

@@ -35,11 +35,12 @@ def one_chip():
     (8, 12, 197, 64, jnp.bfloat16, False),    # ViT-B/16: one block of 197
     (2, 4, 197, 64, jnp.float32, True),       # ... under a dynamic guard
     (1, 8, 8192, 128, jnp.bfloat16, True),    # carries vmem_limit_bytes
+    (2, 16, 4096, 128, jnp.bfloat16, True),   # the olmoe-1b-7b cell's call
 ])
 def test_planned_flash_kernels_compile_for_v5e(one_chip, b, h, s, d, dtype,
                                                causal):
     plan = tile_plan(s, s, d, dtype, causal)
-    assert (plan.dkv.vmem_limit_bytes is not None) == (s == 8192)
+    assert (plan.dkv.vmem_limit_bytes is not None) == (s >= 4096)
     x = jax.ShapeDtypeStruct((b, h, s, d), dtype, sharding=one_chip)
 
     def loss(q, k, v):
@@ -49,3 +50,27 @@ def test_planned_flash_kernels_compile_for_v5e(one_chip, b, h, s, d, dtype,
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         x, x, x).compile()
     assert compiled.as_text().count("tpu_custom_call") == 3
+
+
+
+@pytest.mark.parametrize("m,k,n,groups,dtype", [
+    (65536, 2048, 1024, 16, jnp.bfloat16),  # olmoe-1b-7b.solo: gate / up
+    (65536, 1024, 2048, 16, jnp.bfloat16),  # ... and down
+    (300, 64, 32, 4, jnp.float32),          # rows and widths off every tile
+])
+def test_grouped_matmul_kernels_compile_for_v5e(one_chip, m, k, n, groups,
+                                                dtype):
+    """Forward and both backward products: a dynamic grid bound and
+    scalar-prefetched index maps, which the interpreter cannot vouch for."""
+    from harmony_tpu.ops.grouped_matmul import grouped_matmul
+
+    sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    fn = jax.grad(lambda x, w, g: grouped_matmul(
+        x, w, g, interpret=False).astype(jnp.float32).sum(), argnums=(0, 1))
+    compiled = jax.jit(fn).lower(sd((m, k), dtype), sd((groups, k, n), dtype),
+                                 sd((groups,), jnp.int32)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 2  # dx and dw
+    fwd = jax.jit(lambda x, w, g: grouped_matmul(x, w, g, interpret=False))
+    assert fwd.lower(sd((m, k), dtype), sd((groups, k, n), dtype),
+                     sd((groups,), jnp.int32)).compile().as_text().count(
+        "tpu_custom_call") == 1
