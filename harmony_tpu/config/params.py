@@ -17,6 +17,11 @@ from harmony_tpu.config.base import ConfigBase, config
 # (services/et/.../configuration/parameters/NumTotalBlocks.java).
 DEFAULT_NUM_BLOCKS = 1024
 
+#: Rows of one (8, 128) float32 tile on the TPU. A dense table whose
+#: ``block_size`` is a multiple of it, with no tail block, is stored as its
+#: own row matrix (metrics/table_layout.py says which tables are).
+TILE_ROWS = 8
+
 
 @config
 class TableConfig(ConfigBase):

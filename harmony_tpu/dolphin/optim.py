@@ -5,12 +5,13 @@ table's UpdateFunction (server-side fold). Momentum/Adam need per-parameter
 STATE shared exactly like the parameters — so the state lives in the same
 elastic table, as extra row sections:
 
-    rows = [ params | m (slot 1) | v (slot 2) | counter row ]
+    rows = [ params | m (slot 1) | v (slot 2) | counter block ]
 
 Every section reshards, checkpoints and migrates with the table for free.
-The update math is pure (jit-safe) over flat vectors; trainers split their
-pulled rows into sections, call :func:`apply`, and push back per-section
-deltas (additive fold — delta = new - old).
+The update math is pure (jit-safe) and elementwise, over arrays of any one
+shape; trainers split their pulled rows into sections, call :func:`apply`
+on them as they lie (models/pytree_trainer.py: ``[rows, row_width]``), and
+push back per-section deltas (additive fold — delta = new - old).
 """
 from __future__ import annotations
 
@@ -30,10 +31,10 @@ def num_slots(name: str) -> int:
 
 def apply(
     name: str,
-    params: jnp.ndarray,       # [n] flat
-    grads: jnp.ndarray,        # [n] flat
-    m: jnp.ndarray,            # [n] slot-1 state (ignored for sgd)
-    v: jnp.ndarray,            # [n] slot-2 state (adam only)
+    params: jnp.ndarray,       # any shape (a row section, a flat vector)
+    grads: jnp.ndarray,        # params' shape
+    m: jnp.ndarray,            # params' shape: slot-1 state (ignored for sgd)
+    v: jnp.ndarray,            # params' shape: slot-2 state (adam only)
     t: jnp.ndarray,            # scalar step count AFTER this update (>= 1)
     hyper: Dict[str, jnp.ndarray],
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:

@@ -276,6 +276,7 @@ class DolphinJobEntity(JobEntity):
                 table_id=f"{cfg.job_id}:{table_cfg.table_id}"
             )
             self._handle = master.create_table(table_cfg, executor_ids, data_axis)
+        self._note_table_layout(probe)
         self._trainer_factory = lambda: (
             resolve_symbol(cfg.trainer)(**cfg.params.app_params)
         )
@@ -286,6 +287,22 @@ class DolphinJobEntity(JobEntity):
             local_cfg = probe.local_table_config()
             local_cfg = local_cfg.replace(table_id=f"{cfg.job_id}:{local_cfg.table_id}")
             self._local_handle = master.create_table(local_cfg, executor_ids, data_axis)
+
+    def _note_table_layout(self, probe) -> None:
+        """STATUS ``tenants.<job>.table_layout`` / the gauge
+        ``harmony_table_tile_exact`` (metrics/table_layout.py), made once
+        here, where the table is created or restored. A trainer whose
+        sections do not fit a restored table's row count refuses it here,
+        by name (PyTreeTrainer.section_stride)."""
+        from harmony_tpu.metrics import table_layout
+        from harmony_tpu.table import TableSpec
+
+        spec = getattr(self._handle.table, "spec", None)
+        if isinstance(spec, TableSpec):  # hash tables have no block rows
+            stride_of = getattr(probe, "section_stride", None)
+            table_layout.note(
+                self.config.job_id, spec,
+                stride_of(spec.config.capacity) if stride_of else None)
 
     # -- run (the DolphinMaster.start analogue) --------------------------
 

@@ -86,7 +86,7 @@ class _Tenant:
                  "steps_total", "device_sec_total", "examples_total",
                  "flops_per_step", "resident", "bytes", "target_sps",
                  "slo_events", "first_ts", "last_ts", "async_state",
-                 "serving_state")
+                 "serving_state", "table_layout")
 
     def __init__(self, job: str) -> None:
         self.job = job
@@ -114,6 +114,8 @@ class _Tenant:
         #: what `obs top`, the doctor's serving_slo_breach rule and the
         #: policy engine's `protect` action all key on
         self.serving_state: Optional[Dict[str, Any]] = None
+        #: how the model table is stored (set_table_layout; static)
+        self.table_layout: Optional[Dict[str, Any]] = None
 
 
 class LedgerStore:
@@ -208,6 +210,12 @@ class LedgerStore:
                 "exposed_wait_sec": round(float(exposed_wait_sec), 6),
                 "overlapped_comm_sec": round(float(overlapped_comm_sec), 6),
             }
+
+    def set_table_layout(self, job: str, layout: Dict[str, Any]) -> None:
+        """How the tenant's dense model table is stored (metrics/
+        table_layout.py; once, where the table is created or restored)."""
+        with self._lock:
+            self._tenant(job).table_layout = dict(layout)
 
     def set_serving_state(self, job: str, attempt: Optional[str] = None,
                           *, enabled: bool,
@@ -355,6 +363,8 @@ class LedgerStore:
                               if t.async_state is not None else None),
                     "serving": (dict(t.serving_state)
                                 if t.serving_state is not None else None),
+                    "table_layout": (dict(t.table_layout)
+                                     if t.table_layout is not None else None),
                 }
         total_resident = sum(r["resident_bytes"] for r in rows.values())
         for r in rows.values():

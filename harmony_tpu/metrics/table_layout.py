@@ -1,0 +1,50 @@
+"""Whether a tenant's dense model table is stored tile-exact.
+
+A dense table is stored block-major, ``[num_blocks, block_size, *value]``.
+On the TPU its rows tile by eight: a ``block_size`` that is not a multiple
+of 8, or a tail block padded past ``capacity``, makes the compiler store the
+table dimension-permuted and every whole-table pull and push relay it out
+(PERF.md §6, PR 26: six whole-table copies a step under blocks of 9 rows).
+Static per table, so the record is made once, where the job's table is
+created or restored (jobserver/entity.py):
+
+  * ``harmony_table_tile_exact{job,table}`` — 1 when ``block_size % 8 == 0``
+    and no tail rows, else 0;
+  * STATUS ``tenants.<job>.table_layout`` = ``{block_size, tail_rows,
+    section_stride, rows, tile_exact}`` (a row of the tenant ledger,
+    metrics/accounting.py ``set_table_layout``); ``section_stride``
+    is the trainer's where it has one (``PyTreeTrainer.section_stride``:
+    rows between the ``[params | m | v]`` sections), else ``None``.
+"""
+from __future__ import annotations
+
+from typing import Any, Dict, Optional
+
+from harmony_tpu.config.params import TILE_ROWS
+
+
+def _family():
+    from harmony_tpu.metrics.registry import get_registry
+
+    return get_registry().gauge(
+        "harmony_table_tile_exact",
+        "1 when a dense table's blocks are whole 8-row tiles with no tail "
+        "rows (pull_all / push_all move nothing), else 0",
+        ("job", "table"))
+
+
+def note(job: str, spec, section_stride: Optional[int] = None
+         ) -> Dict[str, Any]:
+    """Record the storage layout of ``spec`` (a dense ``TableSpec``) as
+    ``job``'s model table; returns the STATUS row."""
+    from harmony_tpu.metrics.accounting import ledger
+
+    rows = spec.num_blocks * spec.block_size
+    tail = rows - spec.config.capacity
+    row = {"block_size": spec.block_size, "tail_rows": tail,
+           "section_stride": section_stride, "rows": rows,
+           "tile_exact": int(spec.block_size % TILE_ROWS == 0 and tail == 0)}
+    _family().labels(job=job, table=spec.config.table_id).set(
+        row["tile_exact"])
+    ledger().set_table_layout(job, row)
+    return row

@@ -12,9 +12,17 @@ its batch->loss signature.
 
 Stateful optimizers (harmony_tpu.dolphin.optim): momentum/Adam state
 occupies extra row sections of the SAME table — ``[params | m | v |
-counter row]`` — so optimizer state checkpoints, reshards and migrates
+counter block]`` — so optimizer state checkpoints, reshards and migrates
 with the parameters (the reference has no shared-optimizer-state
 mechanism at all; its trainers are plain SGD).
+
+The table's storage IS the row matrix: every section starts on a multiple
+of 8 rows, the counter has an 8-row block of its own, and blocks are whole
+(8, 128) tiles with no tail block, so ``pull_all``'s reshape is a bitcast
+and ``push_all`` pads nothing. A step changes layout twice, where the model
+needs it (parameters rows -> leaves, gradients leaves -> rows); the
+optimizer runs on row sections. Pad rows and pad lanes hold zeros and stay
+zero under every optimizer (g = m = v = 0 -> update 0).
 """
 from __future__ import annotations
 
@@ -25,7 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.flatten_util import ravel_pytree
 
-from harmony_tpu.config.params import TableConfig
+from harmony_tpu.config.params import TILE_ROWS, TableConfig
 from harmony_tpu.dolphin.trainer import Trainer, TrainerContext
 
 
@@ -76,6 +84,9 @@ class PyTreeTrainer(Trainer):
         )
         self.num_params = flat.shape[0]
         self.num_rows = -(-self.num_params // row_width)
+        #: rows from one section's start to the next (num_rows rounded up
+        #: to whole tiles)
+        self.section_rows = -(-self.num_rows // TILE_ROWS) * TILE_ROWS
 
     # -- model binding (subclass hooks) -----------------------------------
 
@@ -97,21 +108,59 @@ class PyTreeTrainer(Trainer):
 
     @property
     def capacity(self) -> int:
-        # param rows + one section per state slot + the step-counter row
-        extra = 1 if self.num_state_slots else 0
-        return self.num_rows * (1 + self.num_state_slots) + extra
+        # one tile-aligned section per [params | state slot], then the
+        # step counter's own block
+        slots = self.num_state_slots
+        return ((1 + slots) * self.section_rows
+                + (TILE_ROWS if slots else 0))
 
     def model_table_config(
         self, table_id: str = "", num_blocks: int = 0
     ) -> TableConfig:
+        capacity = self.capacity
+        if num_blocks:
+            # a caller's block count: blocks of whole tiles, no tail block
+            block = -(-capacity // (num_blocks * TILE_ROWS)) * TILE_ROWS
+            capacity = num_blocks * block
         return TableConfig(
             table_id=table_id or self.default_table_id,
-            capacity=self.capacity,
+            capacity=capacity,
             value_shape=(self.row_width,),
-            num_blocks=num_blocks or max(self.capacity // 8, 1),
+            num_blocks=num_blocks or capacity // TILE_ROWS,
             is_ordered=True,
             update_fn="add",
         )
+
+    def section_stride(self, capacity: int) -> int:
+        """Rows from one section's start to the next in a table of
+        ``capacity`` rows. A table restored from a chain written before
+        sections were tile-aligned (``[params | m | v | counter row]``,
+        ``(1 + slots) * num_rows + 1`` rows) keeps its stride of
+        ``num_rows``; any other row count is refused, never misread."""
+        slots = self.num_state_slots
+        if capacity >= self.capacity:
+            return self.section_rows
+        legacy = (1 + slots) * self.num_rows + (1 if slots else 0)
+        if capacity == legacy:
+            return self.num_rows
+        raise ValueError(
+            f"{type(self).__name__}: a model table of {capacity} rows fits "
+            f"neither this trainer's layout (capacity {self.capacity}: "
+            f"{1 + slots} sections of {self.section_rows} rows"
+            f"{' + the counter block' if slots else ''}) nor the unaligned "
+            f"one of older chains (capacity {legacy})")
+
+    def section(self, model, i: int):
+        """Rows ``[stride, row_width]`` of section i (0=params, 1=m, 2=v)
+        of a pulled model (or its host copy)."""
+        stride = self.section_stride(model.shape[0])
+        return model[i * stride:(i + 1) * stride]
+
+    def counter(self, model):
+        """Pushes folded into a pulled model so far (stateful optimizers):
+        the first cell after the sections."""
+        stride = self.section_stride(model.shape[0])
+        return model[(1 + self.num_state_slots) * stride, 0]
 
     # -- lifecycle --------------------------------------------------------
 
@@ -119,23 +168,24 @@ class PyTreeTrainer(Trainer):
         params = self.model.init(jax.random.PRNGKey(self.seed))
         flat, _ = ravel_pytree(params)
         ctx.model_table.multi_put(
-            list(range(self.num_rows)), np.asarray(self._to_rows(flat))
+            list(range(self.num_rows)),
+            np.asarray(self._to_rows(flat, self.num_rows)),
         )
-        # m/v sections and the counter row start (and stay, until the first
-        # push) at the table's init value 0.
+        # pad rows, m/v sections and the counter block start (and stay,
+        # until the first push) at the table's init value 0.
 
     # -- pure parts -------------------------------------------------------
 
-    def _to_rows(self, flat: jnp.ndarray) -> jnp.ndarray:
-        pad = self.num_rows * self.row_width - self.num_params
+    def _to_rows(self, flat: jnp.ndarray, rows: int) -> jnp.ndarray:
+        pad = rows * self.row_width - self.num_params
         return jnp.concatenate(
             [flat, jnp.zeros((pad,), flat.dtype)]
-        ).reshape(self.num_rows, self.row_width)
+        ).reshape(rows, self.row_width)
 
-    def _section(self, model: jnp.ndarray, i: int) -> jnp.ndarray:
-        """Flat [num_params] view of row section i (0=params, 1=m, 2=v)."""
-        rows = model[i * self.num_rows:(i + 1) * self.num_rows]
-        return rows.reshape(-1)[: self.num_params]
+    def _params(self, model: jnp.ndarray) -> Any:
+        """The parameter pytree of a pulled model: rows -> leaves."""
+        return self._unravel(
+            self.section(model, 0).reshape(-1)[: self.num_params])
 
     def hyperparams(self) -> Dict[str, float]:
         if self.beta2 is None:
@@ -145,29 +195,25 @@ class PyTreeTrainer(Trainer):
     def compute(self, model, batch, hyper):
         from harmony_tpu.dolphin import optim
 
-        pflat = self._section(model, 0)
-        params = self._unravel(pflat)
         (loss, extra), grads = jax.value_and_grad(
-            self.loss_and_metrics_on_batch, has_aux=True)(params, batch)
-        gflat, _ = ravel_pytree(grads)
+            self.loss_and_metrics_on_batch, has_aux=True
+        )(self._params(model), batch)
+        # the optimizer is elementwise: it runs on the sections as rows
         slots = self.num_state_slots
-        m = self._section(model, 1) if slots >= 1 else jnp.zeros_like(pflat)
-        v = self._section(model, 2) if slots >= 2 else jnp.zeros_like(pflat)
-        t = model[-1, 0] + 1.0 if slots else jnp.asarray(1.0)
+        p = self.section(model, 0)
+        g = self._to_rows(ravel_pytree(grads)[0], p.shape[0])
+        m = self.section(model, 1) if slots >= 1 else jnp.zeros_like(p)
+        v = self.section(model, 2) if slots >= 2 else jnp.zeros_like(p)
+        t = self.counter(model) + 1.0 if slots else jnp.asarray(1.0)
         new_p, new_m, new_v = optim.apply(
-            self.optimizer, pflat, gflat, m, v, t, hyper
+            self.optimizer, p, g, m, v, t, hyper
         )
-        sections = [self._to_rows(new_p - pflat)]
-        if slots >= 1:
-            sections.append(self._to_rows(new_m - m))
-        if slots >= 2:
-            sections.append(self._to_rows(new_v - v))
-        delta = jnp.concatenate(sections)
-        if slots:
-            counter = jnp.zeros((1, self.row_width), delta.dtype).at[0, 0].set(1.0)
-            delta = jnp.concatenate([delta, counter])
-        return delta, {"loss": loss, **extra}
+        sections = [new_p - p, new_m - m, new_v - v][: 1 + slots]
+        tail = model.shape[0] - len(sections) * p.shape[0]
+        if tail:  # the counter block (its first cell counts pushes)
+            block = jnp.zeros((tail, self.row_width), p.dtype)
+            sections.append(block.at[0, 0].set(1.0) if slots else block)
+        return jnp.concatenate(sections), {"loss": loss, **extra}
 
     def evaluate(self, model, batch) -> Dict[str, jnp.ndarray]:
-        params = self._unravel(self._section(model, 0))
-        return self.eval_metrics(params, batch)
+        return self.eval_metrics(self._params(model), batch)

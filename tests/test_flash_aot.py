@@ -74,3 +74,60 @@ def test_grouped_matmul_kernels_compile_for_v5e(one_chip, m, k, n, groups,
     assert fwd.lower(sd((m, k), dtype), sd((groups, k, n), dtype),
                      sd((groups,), jnp.int32)).compile().as_text().count(
         "tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("optimizer", ["adam", "momentum"])
+def test_lm_step_leaves_the_table_in_its_stored_layout(one_chip, optimizer):
+    """The small LM's fused PULL -> COMP -> PUSH step (the worker's
+    ``_step_core`` from its public parts, as perf/aot_compile.py builds it):
+    the table enters in the default layout and no whole-table copy, reshape,
+    pad or slice is among the ops the device runs — under blocks of 9 rows
+    the compiler stored it ``{2,0,1}`` and relaid it out six times a step.
+    (Not sgd: its table IS the parameter section, whose one relayout, rows
+    to leaves, the model needs.)"""
+    import math
+    import re
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from harmony_tpu.dolphin.worker import _phase_boundary
+    from harmony_tpu.models import TransformerConfig, TransformerTrainer
+    from harmony_tpu.parallel.mesh import build_mesh
+    from harmony_tpu.table.table import TableSpec, block_sharding
+    from harmony_tpu.utils.platform import traced_on
+
+    mesh = build_mesh(list(one_chip.device_set), data=1)
+    trainer = TransformerTrainer(
+        TransformerConfig(vocab_size=64, d_model=32, n_heads=2, n_layers=2,
+                          d_ff=64, max_seq=64, attn="blockwise"),
+        row_width=128, optimizer=optimizer)
+    spec = TableSpec(trainer.model_table_config())
+    assert trainer.num_rows % 8  # sections had to be rounded up
+
+    def step(arr, batch, hyper):
+        model = _phase_boundary(spec.pull_all(arr), replicate_on=mesh)
+        delta, metrics = _phase_boundary(
+            trainer.compute(model, batch, hyper), replicate_on=mesh)
+        return spec.push_all(arr, delta), metrics
+
+    tsh = block_sharding(mesh, spec.num_blocks)
+    scalar = jax.ShapeDtypeStruct((), jnp.float32,
+                                  sharding=NamedSharding(mesh, P()))
+    text = jax.jit(traced_on(mesh, step), out_shardings=(tsh, None),
+                   donate_argnums=0).lower(
+        jax.ShapeDtypeStruct(spec.storage_shape, spec.dtype, sharding=tsh),
+        (jax.ShapeDtypeStruct((4, 33), jnp.int32,
+                              sharding=NamedSharding(mesh, P("data"))),),
+        {k: scalar for k in trainer.hyperparams()}).compile().as_text()
+
+    stored = "f32[%s]{2,1,0:" % ",".join(map(str, spec.storage_shape))
+    layout = re.search(r"entry_computation_layout=\{\((\S+),", text).group(1)
+    assert layout.startswith(stored), layout
+    entry = text[text.index("\nENTRY "):]
+    table = math.prod(spec.storage_shape)
+    moved = [
+        line.strip()[:120] for line in entry.splitlines()
+        for m in [re.search(r"= \w+\[([\d,]+)\]\S* (copy|reshape|pad|slice)\(",
+                            line)]
+        if m and math.prod(map(int, m.group(1).split(","))) == table]
+    assert not moved, moved
