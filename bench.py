@@ -281,8 +281,7 @@ def measure_sparse_hot_path() -> dict:
     one donated-buffer program per batch) and unfused (ModelAccessor
     host round trip), interleaved, on the CPU backend. Returns fused/
     unfused samples-per-sec, the ratio, the unfused arm's measured
-    per-phase pull/comp/push seconds; a loss-parity break raises. Full A/B:
-    benchmarks/sparse_step_bench.py (SPARSE_STEP_r07.json)."""
+    per-phase pull/comp/push seconds; a loss-parity break raises."""
     import jax.numpy as jnp
     import numpy as np
 
@@ -349,49 +348,6 @@ def measure_sparse_hot_path() -> dict:
     }
 
 
-def measure_async_step() -> dict:
-    """Bounded-staleness async step probe (tracked round over round in
-    the BENCH json, and by --compare via the dotted async_step.* series):
-    a small MLR WorkerTasklet under an injected worker.pull delay, sync
-    unfused vs async bound 0 (the bit-identical control) vs async bound
-    1 (the overlap arm). Returns {sync_sps, b0_sps, b1_sps, speedup_b1,
-    max_lag_b1, parity}; a parity break raises (pinned capture:
-    benchmarks/ASYNC_STEP_r16.json)."""
-    import os
-    import sys
-
-    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-    from benchmarks.async_step_bench import run_arm
-
-    # comp ~ delay is the regime where overlap shows: either side
-    # dominating caps the win at the smaller of the two
-    # full-bench shape (comp ~ delay ~ 4ms), fewer epochs
-    probe = dict(epochs=2, batches=8)
-    # two interleaved rounds, best-of per arm: round 1 pays the
-    # compile (the progcache is warm from round 2 on), so a single
-    # cold pass would mis-rank the arms
-    sync_sps = b0_sps = b1_sps = 0.0
-    b1_stats = {}
-    for _ in range(2):
-        sps, sync_losses, _ = run_arm(False, 0, **probe)
-        sync_sps = max(sync_sps, sps)
-        sps, b0_losses, _ = run_arm(True, 0, **probe)
-        b0_sps = max(b0_sps, sps)
-        if b0_losses != sync_losses:
-            raise RuntimeError("staleness-0 loss parity broke")
-        sps, _, st = run_arm(True, 1, **probe)
-        if sps > b1_sps:
-            b1_sps, b1_stats = sps, st
-    return {
-        "sync_sps": round(sync_sps, 1),
-        "b0_sps": round(b0_sps, 1),
-        "b1_sps": round(b1_sps, 1),
-        "speedup_b1": round(b1_sps / sync_sps, 2),
-        "max_lag_b1": b1_stats.get("max_lag", 0),
-        "parity": "bit-identical",
-    }
-
-
 #: (key in the result line, probe). Host-side and control-plane probes
 #: tracked round over round beside the headline; each runs in this process
 #: (on the CPU backend or no backend at all) and a probe that raises fails
@@ -404,7 +360,6 @@ def _probes():
         ("obs", measure_scrape_latency),
         ("state_movement", measure_state_movement),
         ("sparse_hot_path", measure_sparse_hot_path),
-        ("async_step", measure_async_step),
         ("input_service", measure_input_service),
         ("lint", measure_lint),
         ("obs_doctor", measure_obs_doctor),
@@ -843,11 +798,10 @@ def measure_lint() -> dict:
 #: PR 10, which --compare skips rather than fails; the `autoscale.*`
 #: pair tracks the closed policy loop (aggregate samples/sec and SLO
 #: attainment of the churning-mix act arm) — absent before PR 15,
-#: skipped the same way; `async_step.b1_sps` tracks the bounded-
-#: staleness overlap arm (absent before PR 16, skipped the same way);
-#: `chaos.scenarios_ok` tracks the seeded chaos smoke pair — any drop
-#: means an invariant went red on a pinned schedule (absent before
-#: PR 18, skipped the same way); `obs_incidents.recall` tracks the
+#: skipped the same way; `chaos.scenarios_ok` tracks the seeded chaos
+#: smoke pair — any drop means an invariant went red on a pinned
+#: schedule (absent before PR 18, skipped the same way);
+#: `obs_incidents.recall` tracks the
 #: incident engine's synthetic correlation probe — a drop means seeded
 #: fault→diagnosis→action→resolution episodes stopped folding into
 #: resolved incidents (absent before PR 19, skipped the same way); the
@@ -857,9 +811,8 @@ def measure_lint() -> dict:
 #: latency RISE, not a drop.
 HEADLINE_SERIES = ("value", "cpu_rate", "input_service.svc_sps",
                    "autoscale.agg_sps", "autoscale.slo_attainment",
-                   "async_step.b1_sps", "chaos.scenarios_ok",
-                   "obs_incidents.recall", "serving.qps",
-                   "serving.p99_ms")
+                   "chaos.scenarios_ok", "obs_incidents.recall",
+                   "serving.qps", "serving.p99_ms")
 #: series where a smaller number is the good direction (latencies):
 #: compare_bench inverts the regression test for these
 LOWER_IS_BETTER = frozenset({"serving.p99_ms"})

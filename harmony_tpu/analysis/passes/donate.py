@@ -17,8 +17,8 @@ wrapper's donation positions are visible:
   in that loop → finding at the call (the next iteration re-donates a
   dead buffer). ``tbl = w(tbl, batch)`` is the sanctioned shape.
 
-Ping/pong double-buffer rotation (the async step's overlap window,
-docs/DEVICE_HOT_PATH.md §Async step mode) is understood: a pure-name
+Ping/pong double-buffer rotation (a compute/transfer overlap window)
+is understood: a pure-name
 tuple assignment like ``ping, pong = pong, ping`` MOVES handles — the
 RHS names are handle copies, not device reads, so the rotation itself
 never fires a finding, and a donated name whose handle rotates onto a

@@ -807,8 +807,7 @@ def _cmd_obs_inner(args: argparse.Namespace) -> int:
         if getattr(args, "json", False):
             print(json.dumps(status.get("phase_budget", {}), indent=2))
             return 0
-        for line in _render_critpath(status.get("phase_budget", {}),
-                                     status.get("tenants", {})):
+        for line in _render_critpath(status.get("phase_budget", {})):
             print(line)
         return 0
     if args.what == "plan":
@@ -1075,22 +1074,16 @@ _CRITPATH_ROWS = (("input_wait", "input"), ("host_dispatch", "dispatch"),
 _CRITPATH_BAR = 30
 
 
-def _render_critpath(budget: dict, tenants: "Optional[dict]" = None
-                     ) -> "List[str]":
+def _render_critpath(budget: dict) -> "List[str]":
     """One-screen per-tenant step-phase waterfall from a single STATUS
     scrape (docs/OBSERVABILITY.md §9 has the glossary): per tenant a
     classification header, one bar per phase (percent of window wall —
     phases + residual sum to ~100% by the budget invariant), and the
     per-epoch critical path (which worker and phase gated the epoch
-    barrier — the straggler report says who, this says why). When the
-    STATUS tenants payload is passed alongside, a tenant running
-    bounded-staleness async mode gets an extra line splitting its comm
-    time into overlapped (hidden behind compute) vs exposed
-    (staleness-gate wait that blocked compute)."""
+    barrier — the straggler report says who, this says why)."""
     if not budget:
         return ["(no phase budget recorded — no worker fed the "
                 "budget store in the window)"]
-    tenants = tenants or {}
     out: List[str] = []
     for job in sorted(budget,
                       key=lambda j: -(budget[j].get("wall_sec") or 0.0)):
@@ -1111,13 +1104,6 @@ def _render_critpath(budget: dict, tenants: "Optional[dict]" = None
                             1 if f > 0 else 0)
             out.append(f"  {label:9s} {100.0 * f:5.1f}% "
                        f"{ph.get(phase, 0.0):8.3f}s  {bar}")
-        a = (tenants.get(job) or {}).get("async") or {}
-        if a.get("enabled"):
-            out.append(
-                f"  async: staleness bound {a.get('staleness_bound', 0)}, "
-                f"max lag {a.get('max_lag', 0)}, comm "
-                f"{a.get('overlapped_comm_sec', 0.0):.3f}s overlapped / "
-                f"{a.get('exposed_wait_sec', 0.0):.3f}s exposed")
         cp = row.get("critical_path") or []
         if cp:
             gates = ", ".join(

@@ -84,6 +84,18 @@ def _decode(value: Any) -> Any:
             if cls is None:
                 raise KeyError(f"unregistered config type {value['_type']!r}")
             kwargs = {k: _decode(v) for k, v in value.items() if k != "_type"}
+            # fields a config type once had (its RETIRED_FIELDS: name ->
+            # (the default older writers serialized, what went)): dropped
+            # at that default, refused at any other value — the record
+            # asks for behaviour the program no longer has
+            retired = getattr(cls, "RETIRED_FIELDS", {})
+            for name, (old_default, why) in retired.items():
+                got = kwargs.pop(name, old_default)
+                if got != old_default:
+                    raise ValueError(
+                        f"{cls.__name__}.{name}={got!r} is refused: {why}; "
+                        "the retired field decodes only at its old "
+                        f"default {old_default!r}")
             return cls(**kwargs)
         return {k: _decode(v) for k, v in value.items()}
     if isinstance(value, list):

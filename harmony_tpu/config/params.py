@@ -9,7 +9,7 @@ from __future__ import annotations
 
 import dataclasses
 from dataclasses import field
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, ClassVar, Dict, List, Optional, Tuple
 
 from harmony_tpu.config.base import ConfigBase, config
 
@@ -229,35 +229,19 @@ class TrainerParams(ConfigBase):
     # scales on. The process-wide HARMONY_SLO_SPS env knob overrides
     # for every job (operator floor enforcement).
     target_samples_per_sec: float = 0.0
-    # Fused device hot path (dolphin/worker.py): compile each batch's
-    # PULL -> COMP -> PUSH into ONE jitted program with the table buffer
-    # donated (the dense SPMD fast path's contract). Default ON; OFF
-    # selects the unfused per-phase fallback — three separately-dispatched
-    # programs with a host round-trip between phases (the reference's
-    # ModelAccessor shape), bit-identical losses for a fixed seed, and
-    # REAL measured pull/push/comp phase seconds instead of the fused
-    # path's probe-derived split. The process-wide HARMONY_FUSED_STEP env
-    # knob (0/1) overrides for operator rollback. Multi-process meshes
-    # keep the fused path regardless: the unfused host round-trip would
-    # need every process to materialize cross-host shards.
-    fused_step: bool = True
-    # Bounded-staleness async aggregation (dolphin/worker.py): overlap
-    # step k's PUSH+PULL with step k+1's COMP by routing the comm phases
-    # through a dedicated comm thread that applies deltas and republishes
-    # the pulled view while the device computes on the previous view.
-    # Default OFF = today's synchronous contract. staleness_bound caps
-    # the applied-update lag a compute step may observe: compute for
-    # step k hard-blocks until at least k - staleness_bound deltas have
-    # been applied. Bound 0 fully serializes and is BIT-identical to the
-    # synchronous unfused path (pinned by tests/test_async_step.py).
-    # Process-wide HARMONY_ASYNC_STEP / HARMONY_STALENESS_BOUND env
-    # knobs override for operator rollback; elastic fences drain the
-    # in-flight window before snapshotting so the (seed, epoch,
-    # step-apply-order) replay contract holds. See
-    # docs/DEVICE_HOT_PATH.md §Async step mode.
-    async_step: bool = False
-    staleness_bound: int = 0
     app_params: Dict[str, Any] = field(default_factory=dict)
+
+    #: Fields this class had until PR 27 deleted the host-driven step
+    #: modes: name -> (the default every older ``to_dict`` wrote, what
+    #: went). A record from an HA log or an older client's SUBMIT carries
+    #: all three: at that default a field is dropped on decode, at any
+    #: other value the record asks for a step mode that no longer exists
+    #: and is refused by name (config/base.py ``_decode``).
+    RETIRED_FIELDS: ClassVar[Dict[str, Tuple[Any, str]]] = {
+        "fused_step": (True, "PR 27 deleted the unfused step mode"),
+        "async_step": (False, "PR 27 deleted the async step mode"),
+        "staleness_bound": (0, "PR 27 deleted the async step mode"),
+    }
 
 
 @config

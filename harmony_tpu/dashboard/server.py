@@ -916,19 +916,6 @@ class DashboardServer:
                            f"{_q(str(t.get('job', '?')))}'>"
                            f"{_h.escape(str(t['phase_class']))}</a>"
                            if t.get("phase_class") else "-")
-                        + "</td>"
-                        # bounded-staleness async lever: on -> bound +
-                        # observed lag + overlapped/exposed comm seconds;
-                        # "off" when the lever exists but is unused
-                        + "<td>"
-                        + ((lambda a:
-                            (f"b{a.get('staleness_bound', 0)} "
-                             f"lag{a.get('max_lag', 0)} "
-                             f"{a.get('overlapped_comm_sec', 0.0):.2f}s/"
-                             f"{a.get('exposed_wait_sec', 0.0):.2f}s"
-                             if a.get("enabled")
-                             else ("off" if a.get("available") else "-")))
-                           ((t.get("async") or {})))
                         + "</td></tr>"
                         for t in server.tenants()
                     )
@@ -937,9 +924,7 @@ class DashboardServer:
                         "<tr><th>job</th><th>attempt</th><th>dev-s</th>"
                         "<th>sps</th><th>MFU</th><th>HBM bytes</th>"
                         "<th>HBM%</th><th>in-wait%</th><th>SLO</th>"
-                        "<th>phase</th>"
-                        "<th title='async staleness: bound, max lag, "
-                        "overlapped/exposed comm'>async</th></tr>"
+                        "<th>phase</th></tr>"
                         f"{tenant_rows}</table>"
                     ) if tenant_rows else ""
 

@@ -57,28 +57,6 @@ class ModelAccessor:
 
         return FusedSparseStep(self._table, compute_fn, **kw)
 
-    def async_step(self, compute_fn, *, staleness_bound: int = 0,
-                   signature: "Any" = None) -> "Any":
-        """Bounded-staleness variant of :meth:`fused_step`
-        (dolphin.worker.AsyncStepDriver): the returned driver's
-        ``submit(*operands)`` computes against a published model view on
-        the calling thread while the PREVIOUS step's push+pull runs on a
-        comm thread, blocking only when the applied-update lag would
-        exceed ``staleness_bound`` (0 = fully serialized, bit-identical
-        to the synchronous per-phase cycle). Comm seconds are measured
-        on the driver's comm thread and surfaced via its
-        ``mean_phase_seconds``/``staleness_stats`` — an overlapped phase
-        is still a phase, never hidden — so this accessor's pull/push
-        tracers keep reporting zero, like the fused path. ``drain()`` is
-        the fence (every submitted delta applied, errors re-raised);
-        call it before any host read of the table. See
-        docs/DEVICE_HOT_PATH.md §Async step mode."""
-        from harmony_tpu.dolphin.worker import accessor_async_step
-
-        return accessor_async_step(self._table, compute_fn,
-                                   staleness_bound=staleness_bound,
-                                   signature=signature)
-
     def get_and_reset_times(self) -> tuple:
         pull, push = self.pull_tracer.total_sec, self.push_tracer.total_sec
         self.pull_tracer.reset()

@@ -24,13 +24,12 @@ step as arguments so per-epoch decay reaches the compiled program.
 Phase boundaries still exist for scheduling: each batch announces its
 TaskUnits to the (optional) TaskUnit scheduler so concurrent jobs interleave
 compute-heavy and network-heavy spans (ref: LocalTaskUnitScheduler.java:
-83-102) — in fused mode the whole step is announced as COMP.
+83-102) — the whole step is announced as COMP.
 """
 from __future__ import annotations
 
 import contextlib
 import logging
-import os
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -62,19 +61,20 @@ from harmony_tpu.utils.platform import traced_on
 
 
 def _phase_boundary(tree, replicate_on: "Optional[Mesh]" = None):
-    """Materialization point between the fused step's PULL/COMP/PUSH
-    stages (``lax.optimization_barrier``): XLA must not fuse across it, so
-    each stage computes exactly what its standalone program computes and
-    the fused/unfused A-B arms stay BIT-identical (cross-phase fusion
-    re-associates matmul accumulations — measured ~1e-7 loss drift).
+    """Materialization point between the step's PULL/COMP/PUSH stages
+    (``lax.optimization_barrier``): XLA does not fuse across it, so each
+    stage computes what its standalone program would (cross-phase fusion
+    re-associates matmul accumulations — measured ~1e-7 loss drift; the
+    parity tests in tests/test_sparse_step.py hold the step to a
+    per-phase accessor loop bit for bit).
     ``replicate_on`` additionally pins the boundary value replicated on
     that mesh — the PULL stage's documented contract (pull IS the
-    all-gather of the model-axis-sharded table; the host-driven path
-    materializes exactly this replica), without which GSPMD partitions
-    the downstream compute differently per mode and reduction orders
-    drift. On TPU the stages already end at Pallas kernel calls
-    (ops/sparse.py), which are materialization boundaries anyway — the
-    barrier codifies the contract rather than adding cost."""
+    all-gather of the model-axis-sharded table), without which GSPMD
+    partitions the downstream compute from whatever sharding propagates
+    backward and reduction orders drift with the layout. On TPU the
+    keyed stages already end at Pallas kernel calls (ops/sparse.py),
+    which are materialization boundaries anyway. Whether the LM step is
+    faster without the fence is ROADMAP S4's to measure."""
     if replicate_on is not None:
         tree = _replicated_tree(tree, replicate_on)
     return jax.lax.optimization_barrier(tree)
@@ -82,527 +82,14 @@ def _phase_boundary(tree, replicate_on: "Optional[Mesh]" = None):
 
 def _replicated_tree(tree, mesh: Mesh):
     """Constrain every array leaf replicated on ``mesh`` — the boundary
-    sharding both step modes share (see _phase_boundary): GSPMD
-    propagates shardings backward through unconstrained values, so a
-    phase-crossing value left natural partitions its producing reduction
-    differently in the one-program and per-program builds, and float
-    accumulation orders drift."""
+    sharding of _phase_boundary: GSPMD propagates shardings backward
+    through unconstrained values, so a phase-crossing value left natural
+    partitions its producing reduction by the consumer's layout, and
+    float accumulation orders drift."""
     rep = NamedSharding(mesh, P())
     return jax.tree_util.tree_map(
         lambda x: jax.lax.with_sharding_constraint(x, rep), tree
     )
-
-
-class _UnfusedStep:
-    """The host-driven per-phase step (``TrainerParams.fused_step=False``).
-
-    Dispatches PULL, COMP and PUSH as three separate compiled programs
-    with the MODEL traffic round-tripping through host numpy between
-    phases — the reference's ModelAccessor shape (pull -> host -> local
-    compute -> host -> push). Worker-LOCAL table state stays on device in
-    both modes (it is worker-private memory in the reference too); only
-    the PS-table traffic crosses the host. Plugs into the same
-    apply_step/commit machinery as the fused jit (it is just a callable),
-    and only its PUSH program donates the table buffer(s), so the commit
-    contract is unchanged.
-
-    Phase seconds are measured directly (perf_counter around each
-    blocked-on dispatch) and exposed via :meth:`mean_phase_seconds` —
-    the worker feeds them to BatchMetrics instead of the fused path's
-    probe-derived split. The FIRST call per build is excluded from the
-    accumulators: it compiles the three phase programs inside the timed
-    regions, and a compile-inflated mean would misattribute later
-    (compile-free) batches' comp time to comm — the same reason the comm
-    probe warms up before it measures.
-    """
-
-    def __init__(self, pull_p, comp_p, push_p, *, is_hash: bool,
-                 uses_local: bool, keys_push: bool, replicated) -> None:
-        self._pull_p = pull_p
-        self._comp_p = comp_p
-        self._push_p = push_p
-        self._is_hash = is_hash
-        self._uses_local = uses_local
-        self._keys_push = keys_push
-        self._replicated = replicated
-        self.pull_sec = 0.0
-        self.comp_sec = 0.0
-        self.push_sec = 0.0
-        self.steps = 0
-        self.timed_steps = 0
-
-    def mean_phase_seconds(self) -> Tuple[float, float, float]:
-        """(pull, comp, push) mean device+round-trip seconds per
-        steady-state step (the compile-bearing first call excluded)."""
-        n = max(self.timed_steps, 1)
-        return self.pull_sec / n, self.comp_sec / n, self.push_sec / n
-
-    def _roundtrip(self, value):
-        """Host round-trip of one phase boundary: D2H materialize, then
-        re-place replicated on the step's mesh (a raw uncommitted upload
-        racing the sharded batch operands would raise a device mismatch
-        inside the next phase's program)."""
-        import jax as _jax
-
-        host = np.asarray(value)
-        return _jax.device_put(host, self._replicated)
-
-    def __call__(self, *args):
-        if self._uses_local:
-            arr, larr, batch, hyper = args
-        else:
-            arr, batch, hyper = args
-            larr = None
-        t0 = time.perf_counter()
-        if self._is_hash:
-            if self._uses_local:
-                state2, rows, token, lmodel = jax.block_until_ready(
-                    self._pull_p(arr, larr, batch))
-            else:
-                state2, rows, token = jax.block_until_ready(self._pull_p(arr, batch))
-                lmodel = None
-            p_t = time.perf_counter() - t0
-            rows_d = self._roundtrip(rows)
-            t0 = time.perf_counter()
-            if self._uses_local:
-                delta, new_l, metrics = jax.block_until_ready(
-                    self._comp_p(rows_d, lmodel, batch, hyper))
-            else:
-                delta, metrics = jax.block_until_ready(
-                    self._comp_p(rows_d, batch, hyper))
-                new_l = None
-            c_t = time.perf_counter() - t0
-            delta_d = self._roundtrip(delta)
-            t0 = time.perf_counter()
-            if self._uses_local:
-                (new_state, new_larr), dropped = jax.block_until_ready(
-                    self._push_p(state2, larr, token, delta_d, new_l))
-            else:
-                new_state, dropped = jax.block_until_ready(
-                    self._push_p(state2, token, delta_d))
-                new_larr = None
-            u_t = time.perf_counter() - t0
-            metrics = dict(metrics)
-            metrics["_dropped"] = dropped
-        else:
-            if self._uses_local:
-                model, lmodel = jax.block_until_ready(self._pull_p(arr, larr))
-            else:
-                model = jax.block_until_ready(self._pull_p(arr))
-                lmodel = None
-            p_t = time.perf_counter() - t0
-            model_d = self._roundtrip(model)
-            t0 = time.perf_counter()
-            if self._uses_local:
-                delta, new_l, metrics = jax.block_until_ready(
-                    self._comp_p(model_d, lmodel, batch, hyper))
-            else:
-                delta, metrics = jax.block_until_ready(
-                    self._comp_p(model_d, batch, hyper))
-                new_l = None
-            c_t = time.perf_counter() - t0
-            delta_d = self._roundtrip(delta)
-            t0 = time.perf_counter()
-            if self._uses_local:
-                (new_arr, new_larr), sync = jax.block_until_ready(
-                    self._push_p(arr, larr, delta_d, new_l))
-            elif self._keys_push:
-                new_arr, sync = jax.block_until_ready(self._push_p(arr, batch, delta_d))
-                new_larr = None
-            else:
-                new_arr, sync = jax.block_until_ready(self._push_p(arr, delta_d))
-                new_larr = None
-            u_t = time.perf_counter() - t0
-            metrics = dict(metrics)
-            if not metrics:
-                # same guarantee as the fused path's _with_sync: at least
-                # one step-output-dependent metric (sync is one pushed
-                # element, computed inside the push program)
-                metrics = {"_sync": sync}
-            new_state = new_arr
-        if self.steps > 0:
-            # steady-state only: call 0 compiled the phase programs inside
-            # the timed regions (see class docstring)
-            self.pull_sec += p_t
-            self.comp_sec += c_t
-            self.push_sec += u_t
-            self.timed_steps += 1
-        self.steps += 1
-        if self._uses_local:
-            return (new_state, new_larr), metrics
-        return new_state, metrics
-
-
-class AsyncStepDriver:
-    """Bounded-staleness async aggregation (``TrainerParams.async_step``).
-
-    Wraps the unfused per-phase programs (same traced math, same host
-    round-trip boundaries — see :class:`_UnfusedStep`) but moves the
-    PUSH+PULL comm phases onto a dedicated comm thread so they overlap
-    the NEXT step's COMP on the training thread::
-
-        train thread:  COMP(k) on view v_k -> submit delta_k -> COMP(k+1)
-        comm thread:   PUSH(delta_k) ; PULL -> publish view k+1
-
-    Deltas ride a FIFO :class:`~harmony_tpu.data.loader.StageRing` with
-    a single consumer, so the table's update sequence is a deterministic
-    function of (seed, epoch, step-apply-order) — submission order IS
-    apply order, which is the replay contract elastic recovery depends
-    on. ``staleness_bound`` caps the applied-update lag a compute step
-    may observe: COMP for step k hard-blocks until the published view
-    reflects at least ``k - bound`` applied deltas. Bound 0 fully
-    serializes the pipeline and is BIT-identical to the synchronous
-    per-phase path (identical programs, identical round-trips, identical
-    apply order — pinned by tests/test_async_step.py; the per-phase path
-    is in turn pinned bit-identical to the fused step).
-
-    ``drain()`` is the fence: it blocks until every submitted delta is
-    applied and the post-apply view is published, re-raising any
-    comm-thread failure. The worker drains at every epoch boundary
-    (before metric drains, snapshots, trainer hooks) and before program
-    rebuilds, so elastic fences always observe an empty in-flight
-    window.
-
-    Comm seconds are measured ON the comm thread (they are real wire
-    time, merely overlapped) and exposed via :meth:`mean_phase_seconds`
-    exactly like _UnfusedStep's — the phase budget attributes them to
-    pull_comm/push_comm honestly instead of hiding the overlap;
-    :meth:`staleness_stats` additionally reports the exposed
-    (compute-blocking) wait so ``obs critpath``/the dashboard can show
-    overlapped vs exposed comm time.
-    """
-
-    #: comm-thread join grace on teardown (the prefetch pipeline's bound)
-    JOIN_TIMEOUT = 10.0
-
-    def __init__(self, inner: _UnfusedStep, *, bound: int, model_table,
-                 local_table=None, mesh: Mesh, job_id: str = "",
-                 worker_id: str = "") -> None:
-        if inner._is_hash or inner._keys_push:
-            raise ValueError(
-                "async step mode drives dense pull_mode='all' tables only "
-                "(a keys-mode pull depends on the batch, and the published-"
-                "view pipeline has no batch yet when it pulls)")
-        self._pull_p = inner._pull_p
-        self._comp_p = inner._comp_p
-        self._push_p = inner._push_p
-        self._uses_local = inner._uses_local
-        self._replicated = inner._replicated
-        self._bound = max(0, int(bound))
-        self._table = model_table
-        self._local = local_table
-        self._mesh = mesh
-        self._job_id = job_id
-        self._worker_id = worker_id
-        # Publication state: _version counts deltas REFLECTED in the
-        # published (model, lmodel) view, _applied counts deltas the comm
-        # thread has pushed. One condition guards both plus the error
-        # slot — StageRing.set_error flows producer->consumer, the wrong
-        # direction for comm-thread failures.
-        self._cond = threading.Condition()
-        self._version = -1  # -1 = initial view not yet published
-        self._applied = 0
-        self._submitted = 0
-        self._view: Optional[Tuple[Any, Any]] = None
-        self._err: Optional[BaseException] = None
-        # The in-flight delta window rides the shared staging primitive
-        # (the dolphin/prefetch.py precedent). The staleness gate in
-        # submit() is the real bound; the cap just keeps the ring honest.
-        self._ring = StageRing(cap_fn=lambda: self._bound + 1)
-        self._thread: Optional[threading.Thread] = None
-        # Phase accounting, _UnfusedStep's contract: the compile-bearing
-        # first step is excluded from every accumulator.
-        self.pull_sec = 0.0
-        self.comp_sec = 0.0
-        self.push_sec = 0.0
-        self.steps = 0
-        self.timed_steps = 0
-        self._comm_steps = 0
-        # staleness telemetry (tenant ledger + dashboards)
-        self.max_lag = 0
-        self.exposed_wait_sec = 0.0
-
-    def _roundtrip(self, value):
-        """Host round-trip of one phase boundary (see
-        _UnfusedStep._roundtrip — identical placement so bound 0 stays
-        bit-identical to the per-phase path)."""
-        host = np.asarray(value)
-        return jax.device_put(host, self._replicated)
-
-    def _raise_pending(self) -> None:
-        with self._cond:
-            err = self._err
-        if err is not None:
-            raise RuntimeError(
-                "async step comm thread failed; the in-flight window is "
-                "lost — fail this attempt (elastic recovery replays with "
-                "the same apply schedule)") from err
-
-    def _publish_initial(self) -> None:
-        """View v0: one PULL of the live table — exactly where the
-        synchronous step's first pull happens. Runs on the training
-        thread (before the comm thread starts) through the same
-        apply_step lock every table access takes."""
-        from harmony_tpu.table.table import DenseTable
-
-        if self._uses_local:
-            def init_fn(arr, larr):
-                model, lmodel = jax.block_until_ready(self._pull_p(arr, larr))
-                return (arr, larr), (model, lmodel)
-
-            model, lmodel = DenseTable.apply_step_multi(
-                [self._table, self._local], init_fn)
-        else:
-            def init_fn(arr):
-                return arr, jax.block_until_ready(self._pull_p(arr))
-
-            model = self._table.apply_step(init_fn)
-            lmodel = None
-        model_d = self._roundtrip(model)
-        with self._cond:
-            self._version = 0
-            self._view = (model_d, lmodel)
-            self._cond.notify_all()
-
-    def _ensure_started(self) -> None:
-        if self._thread is None:
-            self._publish_initial()
-            self._thread = threading.Thread(
-                target=self._comm_loop,
-                name=f"async-step-{self._job_id}", daemon=True)
-            self._thread.start()
-
-    def submit(self, *operands):
-        """One training step: staleness gate, COMP against the published
-        view, enqueue the delta for the comm thread. Returns the step's
-        metrics dict (device arrays — the epoch drain stacks them)."""
-        self._raise_pending()
-        self._ensure_started()
-        k = self._submitted
-        floor = k - self._bound  # the view must reflect >= this many applies
-        t0 = time.perf_counter()
-        model_d = lmodel = None
-        with self._cond:
-            while self._err is None and self._version < max(floor, 0):
-                self._cond.wait(0.05)
-            if self._err is None:
-                lag = k - self._version
-                if lag > self.max_lag:
-                    self.max_lag = lag
-                model_d, lmodel = self._view
-        wait_t = time.perf_counter() - t0
-        self._raise_pending()
-        if k > 1:
-            # k=1's wait absorbs cycle 0's push/pull compile — excluded
-            # for the same reason _UnfusedStep drops its first call
-            self.exposed_wait_sec += wait_t
-        t0 = time.perf_counter()
-        # standalone dispatch (the probe's pattern): scope wraps the
-        # dispatch, the sync happens outside the lock
-        with dispatch_scope(self._mesh) as fin:
-            if self._uses_local:
-                out = fin(self._comp_p(model_d, lmodel, *operands))
-            else:
-                out = fin(self._comp_p(model_d, *operands))
-        out = jax.block_until_ready(out)
-        if self._uses_local:
-            delta, new_l, metrics = out
-        else:
-            (delta, metrics), new_l = out, None
-        c_t = time.perf_counter() - t0
-        if self.steps > 0:
-            self.comp_sec += c_t
-            self.timed_steps += 1
-        self.steps += 1
-        self._submitted = k + 1
-        if not self._ring.put((k, delta, new_l)):
-            self._raise_pending()
-            raise RuntimeError("async step ring closed mid-training")
-        metrics = dict(metrics)
-        if not metrics:
-            # same guarantee as _UnfusedStep's _sync: at least one
-            # step-output-dependent metric. The push lands later on the
-            # comm thread, so the sentinel reads the delta instead of
-            # the pushed array.
-            leaf = jax.tree_util.tree_leaves(delta)[0]
-            metrics = {"_sync": jnp.ravel(leaf)[0]}
-        return metrics
-
-    def _comm_loop(self) -> None:
-        from harmony_tpu.table.table import DenseTable
-
-        try:
-            while True:
-                item = self._ring.get()
-                if item is StageRing.DONE:
-                    return
-                k, delta, new_l = item
-                # The model-pull wire-time fault site rides the COMM
-                # thread here: injected comm latency lands in the
-                # overlapped window — exactly where real wire time
-                # would — which is the async bench's A/B mechanism.
-                if faults.armed():
-                    faults.site("worker.pull", job=self._job_id,
-                                worker=self._worker_id, batch=k, comm=1)
-                timings: Dict[str, float] = {}
-                delta_d = self._roundtrip(delta)
-                if self._uses_local:
-                    def cycle(arr, larr):
-                        t1 = time.perf_counter()
-                        (new_arr, new_larr), sync = jax.block_until_ready(
-                            self._push_p(arr, larr, delta_d, new_l))
-                        timings["push"] = time.perf_counter() - t1
-                        t1 = time.perf_counter()
-                        model, lm = jax.block_until_ready(
-                            self._pull_p(new_arr, new_larr))
-                        timings["pull"] = time.perf_counter() - t1
-                        return (new_arr, new_larr), (model, lm, sync)
-
-                    model, lmodel, _sync = DenseTable.apply_step_multi(
-                        [self._table, self._local], cycle)
-                else:
-                    def cycle(arr):
-                        t1 = time.perf_counter()
-                        new_arr, sync = jax.block_until_ready(
-                            self._push_p(arr, delta_d))
-                        timings["push"] = time.perf_counter() - t1
-                        t1 = time.perf_counter()
-                        model = jax.block_until_ready(self._pull_p(new_arr))
-                        timings["pull"] = time.perf_counter() - t1
-                        return new_arr, (model, sync)
-
-                    model, _sync = self._table.apply_step(cycle)
-                    lmodel = None
-                model_d = self._roundtrip(model)
-                with self._cond:
-                    self._applied = k + 1
-                    self._version = k + 1
-                    self._view = (model_d, lmodel)
-                    if k > 0:
-                        # steady-state only: cycle 0 compiles the push
-                        # program inside its timed region
-                        self.push_sec += timings.get("push", 0.0)
-                        self.pull_sec += timings.get("pull", 0.0)
-                        self._comm_steps += 1
-                    self._cond.notify_all()
-        except BaseException as e:  # noqa: BLE001 - re-raised on submit/drain
-            with self._cond:
-                self._err = e
-                self._cond.notify_all()
-            # unblock a producer parked in ring.put (its next put
-            # returns False and submit re-raises the recorded error)
-            self._ring.close()
-
-    def mean_phase_seconds(self) -> Tuple[float, float, float]:
-        """(pull, comp, push) mean seconds per steady-state step. The
-        comm means are REAL wire time measured on the comm thread (they
-        overlap compute — the budget attributes them honestly); comp is
-        the training thread's. Compile-bearing first step excluded."""
-        with self._cond:
-            n_comm = max(self._comm_steps, 1)
-            n_comp = max(self.timed_steps, 1)
-            return (self.pull_sec / n_comm, self.comp_sec / n_comp,
-                    self.push_sec / n_comm)
-
-    def staleness_stats(self) -> Dict[str, Any]:
-        """Ledger feed: bound, observed lag, exposed vs overlapped comm."""
-        with self._cond:
-            return {
-                "bound": self._bound,
-                "max_lag": int(self.max_lag),
-                "exposed_wait_sec": self.exposed_wait_sec,
-                "overlapped_comm_sec": self.pull_sec + self.push_sec,
-                "applied": int(self._applied),
-                "submitted": int(self._submitted),
-            }
-
-    def drain(self) -> None:
-        """The fence: block until every submitted delta is APPLIED and
-        the post-apply view published; re-raise any comm failure."""
-        if self._thread is None:
-            self._raise_pending()
-            return
-        with self._cond:
-            while self._err is None and self._applied < self._submitted:
-                self._cond.wait(0.05)
-        self._raise_pending()
-
-    def close(self) -> None:
-        """Drain (raising on a comm failure — a rebuild must surface a
-        pending error, not drop it with the old driver), then join."""
-        self.drain()
-        self.shutdown()
-
-    def shutdown(self) -> None:
-        """Best-effort teardown (exception/run-end path): never raises.
-        The happy path drained at the last epoch boundary, so the ring
-        is empty; an exception path is abandoning the attempt anyway."""
-        t = self._thread
-        self._thread = None
-        self._ring.finish()
-        self._ring.close()
-        if t is not None:
-            t.join(self.JOIN_TIMEOUT)
-
-
-def accessor_async_step(table, compute_fn, *, staleness_bound: int = 0,
-                        signature: Optional[Any] = None) -> AsyncStepDriver:
-    """Bounded-staleness driver for ModelAccessor users (the host-driven
-    path outside WorkerTasklet — benchmarks, apps driving a table
-    directly). Builds the dense pull_all/compute/push_all phase programs
-    for ``table`` (progcache-cached when ``signature`` names the
-    compute_fn's traced behavior, the FusedSparseStep contract) and
-    returns an :class:`AsyncStepDriver` whose ``submit(*operands)``
-    overlaps the previous step's PUSH+PULL with this step's compute
-    under ``staleness_bound``. ``compute_fn`` maps
-    ``(model, *operands) -> delta`` or ``(delta, metrics_dict)``;
-    ``drain()``/``close()`` carry the same fence contract as the worker
-    path (docs/DEVICE_HOT_PATH.md §Async step mode)."""
-    from harmony_tpu.table.hashtable import DeviceHashTable
-    from harmony_tpu.table.table import DenseTable
-
-    if isinstance(table, DeviceHashTable):
-        raise TypeError(
-            "async step drives DenseTable workloads; hash-backed tables "
-            "keep the synchronous keyed step")
-    if not isinstance(table, DenseTable):
-        raise TypeError(f"need a DenseTable, got {type(table).__name__}")
-    spec = table.spec
-    mesh = table.mesh
-    tsh = table.sharding
-    replicated = NamedSharding(mesh, P())
-
-    def pull_fn(arr):
-        return _replicated_tree(spec.pull_all(arr), mesh)
-
-    def comp_fn(model, *operands):
-        out = compute_fn(model, *operands)
-        if not (isinstance(out, tuple) and len(out) == 2
-                and isinstance(out[1], dict)):
-            out = (out, {})
-        delta, metrics = out
-        return _replicated_tree(delta, mesh), dict(metrics)
-
-    def push_fn(arr, delta):
-        new_arr = spec.push_all(arr, delta)
-        return new_arr, jnp.ravel(new_arr)[0]
-
-    def cached(tag, build):
-        key = (None if signature is None else
-               (("accessor_async", signature,
-                 progcache.table_signature(table, sharding=tsh)), tag))
-        return progcache.get_or_build(key, build)
-
-    pull_p = cached("pull", lambda: jax.jit(traced_on(mesh, pull_fn)))
-    comp_p = cached("comp", lambda: jax.jit(traced_on(mesh, comp_fn)))
-    push_p = cached("push", lambda: jax.jit(traced_on(mesh, push_fn),
-                                            donate_argnums=(0,),
-                                            out_shardings=(tsh, None)))
-    inner = _UnfusedStep(pull_p, comp_p, push_p, is_hash=False,
-                         uses_local=False, keys_push=False,
-                         replicated=replicated)
-    return AsyncStepDriver(inner, bound=staleness_bound, model_table=table,
-                           mesh=mesh)
 
 
 class _TimedAdmission:
@@ -751,36 +238,6 @@ class WorkerTasklet:
         # default ON; _prefetch_usable() gates it off where a background
         # device_put would break pod-deterministic dispatch order.
         self._prefetch_on = bool(getattr(ctx.params, "input_prefetch", True))
-        # Fused device hot path (config default ON): each batch's
-        # PULL/COMP/PUSH compiles into one donated-buffer program. OFF
-        # selects the unfused per-phase fallback (_build_unfused): three
-        # separately-dispatched programs with a host round-trip between
-        # phases — the reference's host-driven ModelAccessor shape, kept
-        # as the bit-identical A/B arm and the operator rollback path.
-        # HARMONY_FUSED_STEP (0/1) overrides process-wide.
-        fused = bool(getattr(ctx.params, "fused_step", True))
-        env_fused = os.environ.get("HARMONY_FUSED_STEP")
-        if env_fused is not None:
-            fused = env_fused.strip().lower() not in ("0", "false", "off")
-        self._fused_on = fused
-        # Bounded-staleness async aggregation (AsyncStepDriver): overlap
-        # step k's PUSH+PULL with step k+1's COMP on a comm thread.
-        # Default OFF preserves today's synchronous contract; the env
-        # knobs are the process-wide operator override, same shape as
-        # HARMONY_FUSED_STEP above. See docs/DEVICE_HOT_PATH.md.
-        async_on = bool(getattr(ctx.params, "async_step", False))
-        env_async = os.environ.get("HARMONY_ASYNC_STEP")
-        if env_async is not None:
-            async_on = env_async.strip().lower() not in ("0", "false", "off")
-        self._async_on = async_on
-        bound = int(getattr(ctx.params, "staleness_bound", 0) or 0)
-        env_bound = os.environ.get("HARMONY_STALENESS_BOUND")
-        if env_bound is not None:
-            try:
-                bound = int(env_bound.strip())
-            except ValueError:
-                pass
-        self._staleness_bound = max(0, bound)
         self._active_pipeline: Optional[PrefetchPipeline] = None
         # (epoch, pipeline) spawned ahead of its epoch (see
         # _spawn_next_pipeline) — consumed by _epoch_batch_stream
@@ -912,7 +369,7 @@ class WorkerTasklet:
                 def _step(state, local, batch, hyper):
                     # the local pull belongs to the PULL stage even though
                     # it is traced inside the compute closure — barrier it
-                    # so the stage split matches the unfused build's
+                    # like the model pull
                     lmodel = _phase_boundary(local_spec.pull_all(local),
                                              replicate_on=mesh)
                     state, new_l, metrics = _hash_pull_push(
@@ -1044,12 +501,7 @@ class WorkerTasklet:
         hyper_sig = tuple(sorted(self.trainer.hyperparams().keys()))
         return (tsig, table_sig, local_sig, batch_sig, hyper_sig,
                 push_route,  # the BAKED lowering (measured; see caller)
-                self.data.num_mini_batches if self._use_fused_epoch() else None,
-                # fused and unfused builds trace DIFFERENT programs from
-                # otherwise-identical signatures — the mode is part of the
-                # structural identity
-                ("async" if self._async_mode() else
-                 "fused" if self._fused_mode() else "unfused"))
+                self.data.num_mini_batches if self._use_fused_epoch() else None)
 
     def _program_builders(self, tsh, lsh, push_route):
         """The step/epoch jit-wrapper constructors for a GIVEN layout
@@ -1086,144 +538,6 @@ class WorkerTasklet:
 
         return build_step, build_epoch
 
-    def _build_unfused(self, key, tsh, lsh, push_route) -> "_UnfusedStep":
-        """The per-phase fallback (fused_step=False): PULL, COMP and PUSH
-        as three separately-compiled programs with a host round-trip
-        between phases — the reference's host-driven ModelAccessor shape
-        (pull -> numpy -> compute -> numpy -> push), kept bit-identical to
-        the fused program (same traced math, different dispatch
-        boundaries; gathers/adds are boundary-insensitive). The phase
-        programs participate in the program cache under the same
-        structural key as the fused step (mode-tagged), so rebuilds and
-        resubmissions reuse them. Only the PUSH program donates the table
-        buffer(s) — PULL must read them first."""
-        from harmony_tpu.table.hashtable import DeviceHashTable
-
-        spec = self.ctx.model_table.spec
-        trainer = self.trainer
-        is_hash = isinstance(self.ctx.model_table, DeviceHashTable)
-        mesh = (tsh[0] if isinstance(tsh, tuple) else tsh).mesh
-        local_spec = (self.ctx.local_table.spec
-                      if trainer.uses_local_table else None)
-        replicated = NamedSharding(mesh, P())
-
-        mesh2 = mesh  # the boundary-replication mesh (see _replicated_tree)
-        keys_push = False
-        if is_hash:
-            if trainer.uses_local_table:
-                def pull_fn(state, larr, batch):
-                    keys = jax.lax.with_sharding_constraint(
-                        trainer.pull_keys(batch), replicated
-                    )
-                    state2, rows, token = spec.pull(state, keys)
-                    rows, lmodel = _replicated_tree(
-                        (rows, local_spec.pull_all(larr)), mesh2)
-                    return state2, rows, token, lmodel
-
-                def comp_fn(rows, lmodel, batch, hyper):
-                    return _replicated_tree(trainer.compute_with_local(
-                        rows, lmodel, batch, hyper), mesh2)
-
-                def push_fn(state, local, token, delta, new_l):
-                    delta = trainer.mask_delta(delta, token[2])
-                    new_state = spec.push(state, token, delta)
-                    dropped = jnp.sum(~token[2]).astype(jnp.float32)
-                    return ((new_state, local_spec.write_all(local, new_l)),
-                            dropped)
-
-                donate = (0, 1)
-            else:
-                def pull_fn(state, batch):
-                    keys = jax.lax.with_sharding_constraint(
-                        trainer.pull_keys(batch), replicated
-                    )
-                    state2, rows, token = spec.pull(state, keys)
-                    return state2, _replicated_tree(rows, mesh2), token
-
-                def comp_fn(rows, batch, hyper):
-                    return _replicated_tree(
-                        trainer.compute(rows, batch, hyper), mesh2)
-
-                def push_fn(state, token, delta):
-                    delta = trainer.mask_delta(delta, token[2])
-                    new_state = spec.push(state, token, delta)
-                    dropped = jnp.sum(~token[2]).astype(jnp.float32)
-                    return new_state, dropped
-
-                donate = (0,)
-        elif trainer.uses_local_table:
-            def pull_fn(arr, larr):
-                return _replicated_tree(
-                    (spec.pull_all(arr), local_spec.pull_all(larr)), mesh2)
-
-            def comp_fn(model, lmodel, batch, hyper):
-                return _replicated_tree(
-                    trainer.compute_with_local(model, lmodel, batch, hyper),
-                    mesh2)
-
-            def push_fn(arr, larr, delta, new_l):
-                new_arr = spec.push_all(arr, delta)
-                return ((new_arr, local_spec.write_all(larr, new_l)),
-                        jnp.ravel(new_arr)[0])
-
-            donate = (0, 1)
-        elif trainer.pull_mode == "all":
-            def pull_fn(arr):
-                return _replicated_tree(spec.pull_all(arr), mesh2)
-
-            def comp_fn(model, batch, hyper):
-                return _replicated_tree(
-                    trainer.compute(model, batch, hyper), mesh2)
-
-            def push_fn(arr, delta):
-                new_arr = spec.push_all(arr, delta)
-                return new_arr, jnp.ravel(new_arr)[0]
-
-            donate = (0,)
-        else:
-            keys_push = True
-
-            def pull_fn(arr, batch):
-                return _replicated_tree(
-                    spec.pull(arr, trainer.pull_keys(batch)), mesh2)
-
-            def comp_fn(model, batch, hyper):
-                return _replicated_tree(
-                    trainer.compute(model, batch, hyper), mesh2)
-
-            def push_fn(arr, batch, delta):
-                new_arr = spec.push(arr, trainer.pull_keys(batch), delta,
-                                    via=push_route)
-                return new_arr, jnp.ravel(new_arr)[0]
-
-            donate = (0,)
-
-        def cached(tag, build):
-            return progcache.get_or_build(
-                None if key is None else (key, tag), build)
-
-        # push output pinned to the layout snapshot, exactly as the fused
-        # build's out_shardings pin it (commit then re-homes nothing)
-        push_out = (((tsh, lsh), None) if trainer.uses_local_table
-                    else (tsh, None))
-        pull_p = cached("unfused_pull",
-                        lambda: jax.jit(traced_on(mesh, pull_fn),
-                                        donate_argnums=()))
-        comp_p = cached("unfused_comp",
-                        lambda: jax.jit(traced_on(mesh, comp_fn),
-                                        donate_argnums=()))
-        push_p = cached("unfused_push",
-                        lambda: jax.jit(traced_on(mesh, push_fn),
-                                        donate_argnums=donate,
-                                        out_shardings=push_out))
-        return _UnfusedStep(
-            pull_p, comp_p, push_p,
-            is_hash=is_hash,
-            uses_local=trainer.uses_local_table,
-            keys_push=keys_push,
-            replicated=replicated,
-        )
-
     def _prewarm_layout(self, new_mesh: Mesh) -> None:
         """Layout-announcement listener (TableHandle._reshard_to_owners
         announces the TARGET mesh before flipping ownership): build the
@@ -1239,8 +553,6 @@ class WorkerTasklet:
 
             table = self.ctx.model_table
             is_hash = isinstance(table, DeviceHashTable)
-            if not self._fused_mode():
-                return  # prewarm builds fused programs only
             if self.trainer.uses_local_table:
                 return  # the (model, local) pair reshards independently
             if (self.dispatch_turn is not None
@@ -1343,41 +655,15 @@ class WorkerTasklet:
         self._program_cache_key = self._program_key(tsh, lsh, self._push_route)
         key = self._program_cache_key
 
-        if isinstance(getattr(self, "_step", None), AsyncStepDriver):
-            # rebuild fence: drain the in-flight window under the OLD
-            # programs/layout before swapping them out (close re-raises a
-            # pending comm failure rather than dropping it with the old
-            # driver)
-            self._step.close()
-        if self._async_mode():
-            # bounded-staleness async driver over the per-phase programs
-            # (cached under the async-tagged key); the driver carries the
-            # phase timers and the staleness telemetry
-            inner = self._build_unfused(key, tsh, lsh, self._push_route)
-            self._step = AsyncStepDriver(
-                inner, bound=self._staleness_bound,
-                model_table=table,
-                local_table=(self.ctx.local_table
-                             if self.trainer.uses_local_table else None),
-                mesh=table.mesh, job_id=self.job_id,
-                worker_id=self.ctx.worker_id)
-            self._epoch_fn = None
-        elif not self._fused_mode():
-            # host-driven per-phase fallback: the phase programs ride the
-            # program cache under the same (mode-tagged) key; the wrapper
-            # object is rebuilt per build (it carries phase timers)
-            self._step = self._build_unfused(key, tsh, lsh, self._push_route)
-            self._epoch_fn = None
-        else:
-            build_step, build_epoch = self._program_builders(
-                tsh, lsh, self._push_route)
-            self._step = progcache.get_or_build(
-                None if key is None else (key, "step"), build_step
+        build_step, build_epoch = self._program_builders(
+            tsh, lsh, self._push_route)
+        self._step = progcache.get_or_build(
+            None if key is None else (key, "step"), build_step
+        )
+        if self._use_fused_epoch():
+            self._epoch_fn = progcache.get_or_build(
+                None if key is None else (key, "epoch"), build_epoch
             )
-            if self._use_fused_epoch():
-                self._epoch_fn = progcache.get_or_build(
-                    None if key is None else (key, "epoch"), build_epoch
-                )
         mesh_now = (tsh[0] if isinstance(tsh, tuple) else tsh).mesh
         self._eval_fn = progcache.get_or_build(
             None if key is None else (key, "eval"),
@@ -1533,44 +819,6 @@ class WorkerTasklet:
 
         return mesh_spans_processes(mesh)
 
-    def _fused_mode(self) -> bool:
-        """Whether this worker's step dispatches as ONE fused program.
-        The unfused fallback is host-driven (each phase round-trips
-        through host memory), so a multi-process mesh — whose shards no
-        single process can materialize — keeps the fused path regardless
-        of the knob."""
-        # async mode is host-driven per-phase BY CONSTRUCTION (the comm
-        # thread dispatches push/pull standalone) — it pre-empts the
-        # fused knob, and _async_mode() checks the mesh itself so there
-        # is no recursion through here
-        if self._async_mode():
-            return False
-        if self._fused_on:
-            return True
-        # the TABLE's mesh, not self.mesh: the decision must track the
-        # live layout even between a reshard and the post-flip rebuild
-        return self._mesh_spans_processes(self.ctx.model_table.mesh)
-
-    def _async_capable(self) -> bool:
-        """Whether the live (table, trainer, layout) combination can run
-        the bounded-staleness async step: dense pull_mode='all' tables
-        on a single-process mesh. Hash/keys-mode steps pull per-batch
-        rows (the published-view pipeline has no batch when it pulls),
-        and a multi-process mesh cannot materialize the host round-trip.
-        Exposed to the tenant ledger so the policy engine knows the
-        `async` lever exists before proposing it."""
-        from harmony_tpu.table.hashtable import DeviceHashTable
-
-        if isinstance(self.ctx.model_table, DeviceHashTable):
-            return False
-        if self.trainer.pull_mode != "all":
-            return False
-        return not self._mesh_spans_processes(self.ctx.model_table.mesh)
-
-    def _async_mode(self) -> bool:
-        """Whether this worker's step runs the async driver NOW."""
-        return self._async_on and self._async_capable()
-
     def _probe_comm(self, batch: Tuple[np.ndarray, ...]) -> None:
         """Time the probe programs on one batch (warmup dispatch first so
         compile never lands in the measurement); stores (pull_s, push_s)
@@ -1675,7 +923,6 @@ class WorkerTasklet:
             self.batch_barrier is None
             and self.taskunit is None
             and not self.data.is_shuffling
-            and self._fused_mode()  # host round-trips cannot lax.scan
         )
 
     # Max fused epochs per drain. Each drained window costs one full
@@ -1697,11 +944,6 @@ class WorkerTasklet:
         never crosses a comm-probe epoch — the probe measures the live
         table between dispatches."""
         if self.batch_barrier is not None:
-            return 1
-        if not self._fused_mode():
-            # unfused steps block on host round-trips per phase: a window
-            # would only batch the metric drain of an already-synchronous
-            # loop — keep the honest per-epoch cadence
             return 1
         if self.pod_contended is not None and self.pod_contended():
             # Cross-job pod tenancy: a multi-epoch window is one dispatch
@@ -2079,10 +1321,7 @@ class WorkerTasklet:
             # probe carries its twin): a "delay" rule makes each step
             # pay the injected comm latency the probe measured, so the
             # budget's pull_comm attribution matches the wall it splits.
-            # The async driver fires this site on its COMM thread instead
-            # (inside the overlapped window — firing it here too would
-            # double-bill the injected latency onto the compute thread).
-            if faults.armed() and not isinstance(self._step, AsyncStepDriver):
+            if faults.armed():
                 faults.site("worker.pull", job=self.job_id,
                             worker=self.ctx.worker_id, batch=batch_idx)
             try:
@@ -2118,12 +1357,6 @@ class WorkerTasklet:
 
         if hyper is None:
             hyper = self._hyper()
-        if isinstance(fn, AsyncStepDriver):
-            # the driver routes its own table-lock dispatches: COMP here
-            # on the training thread (against the published view — no
-            # table lock needed), PUSH+PULL on its comm thread through
-            # apply_step
-            return fn.submit(batch_like, hyper)
         if self.trainer.uses_local_table:
             return DenseTable.apply_step_multi(
                 [self.ctx.model_table, self.ctx.local_table],
@@ -2188,11 +1421,6 @@ class WorkerTasklet:
             # a pre-spawned next-epoch producer must not outlive the run
             # (early stop / exception): join it before reporting back
             self._close_next_pipeline()
-            # async comm thread likewise: on the happy path the last
-            # epoch's fence already drained it, so this is teardown; on
-            # an exception path it is best-effort and never raises
-            if isinstance(getattr(self, "_step", None), AsyncStepDriver):
-                self._step.shutdown()
             remove = getattr(ctx.model_table, "remove_layout_listener", None)
             if remove is not None:
                 remove(self._on_layout_announcement)
@@ -2225,8 +1453,6 @@ class WorkerTasklet:
             # order relative to a probe-free run.
             since = epoch - self.starting_epoch
             if self.comm_probe_every and self.global_init and (
-                self._fused_mode()  # unfused measures phases directly
-            ) and (
                 self._probe_pull is None or since >= self._next_probe
             ):
                 self._next_probe = since + 8 * self.comm_probe_every
@@ -2306,8 +1532,7 @@ class WorkerTasklet:
                             last_metrics,
                             epoch_losses,
                             # all but the window's LAST hook ran between
-                            # dispatches; the last runs here, post-drain, as
-                            # in the unfused loop
+                            # dispatches; the last runs here, post-drain
                             call_trainer_hook=(j == len(results) - 1),
                             budget_wall=wall, budget_ctl=ctl,
                         )
@@ -2400,15 +1625,6 @@ class WorkerTasklet:
         pending, batch_sizes, epoch_examples, global_batch_idx, stop, work_t = (
             self._dispatch_epoch_batches(epoch, global_batch_idx)
         )
-        if isinstance(self._step, AsyncStepDriver):
-            # epoch fence: every submitted delta applies (in submission
-            # order) before anything host-side observes the table —
-            # metric drains, snapshots, trainer epoch hooks, elastic
-            # fences. This is what keeps the (seed, epoch,
-            # step-apply-order) replay contract exact under async.
-            t0 = time.perf_counter()
-            self._step.drain()
-            work_t += time.perf_counter() - t0
         dispatch_sec = self._take_dispatch_sec()
         if not stop:
             # next epoch's host assembly runs while the drain below blocks
@@ -2788,16 +2004,9 @@ class WorkerTasklet:
         # honest comm/comp split from the last probe (see _probe_comm):
         # comp = measured step time minus the probed pull/push device time.
         # With the probe off both are 0 and comp degenerates to the whole
-        # batch time — the conservative fused-mode default. The unfused
-        # per-phase path needs no probe at all: its phases dispatch
-        # separately, so the split is MEASURED per step.
-        measured_fn = getattr(self._step, "mean_phase_seconds", None)
-        measured = measured_fn() if measured_fn is not None else None
-        if measured is not None:
-            t_pull, _t_comp, t_push = measured
-        else:
-            t_pull, t_push = (self.ctx.model_table.comm_split()
-                              or self._comm_probe_times)
+        # batch time — the conservative default.
+        t_pull, t_push = (self.ctx.model_table.comm_split()
+                          or self._comm_probe_times)
         comp = max(per_batch_time - t_pull - t_push, 0.0)
         # NOTE: the weighted-fair-queue unit cost is reported from the
         # dispatch scope only (per granted UNIT) — reporting the drain's
@@ -2846,28 +2055,12 @@ class WorkerTasklet:
                               self._input_resident_bytes())
             acct.set_resident(self.job_id, self.attempt_key, "program",
                               self._program_resident_bytes())
-            # async lever state: availability tells the policy engine the
-            # lever EXISTS for this tenant; when enabled, the staleness
-            # telemetry shows overlapped vs exposed comm time
-            stats_fn = getattr(self._step, "staleness_stats", None)
-            stats = stats_fn() if stats_fn is not None else None
-            acct.set_async_state(
-                self.job_id, self.attempt_key,
-                available=self._async_capable(),
-                enabled=stats is not None,
-                bound=(stats["bound"] if stats is not None
-                       else self._staleness_bound),
-                max_lag=(stats or {}).get("max_lag", 0),
-                exposed_wait_sec=(stats or {}).get("exposed_wait_sec", 0.0),
-                overlapped_comm_sec=(stats or {}).get(
-                    "overlapped_comm_sec", 0.0),
-            )
         except Exception:
             pass
         # Step-phase time budget (metrics/phases.py): split this epoch's
-        # measured work into pull/compute/push — the unfused step's REAL
-        # per-phase measurements, else the probe split refined by the
-        # compiled program's FLOP seconds — and stage it (with the
+        # measured work into pull/compute/push — the probe's seconds
+        # applied to the step wall (a model), refined by the compiled
+        # program's FLOP seconds — and stage it (with the
         # host-dispatch seconds) for _finish_epoch, where the epoch WALL
         # is known and the budget feeds. Guarded: the budget must never
         # fail (or slow) the drain.
@@ -2883,19 +2076,13 @@ class WorkerTasklet:
                 # (subtract it from the work split); the fused-epoch
                 # path's stacked upload happens OUTSIDE work_t
                 dispatch_sec=dispatch_sec if dispatch_in_work else 0.0,
-                measured=measured,
-                probe_split=(None if measured is not None
-                             else (t_pull, t_push)),
+                probe_split=(t_pull, t_push),
                 flops_per_step=self._program_flops_per_step(),
                 peak_flops=_peak_flops(),
                 devices=int(self.mesh.devices.size),
             )
             self._phase_pending[epoch] = {
-                "host_dispatch": float(dispatch_sec), **split,
-                # pull/compute/push: the unfused step's own timers, else
-                # the probe's seconds applied to the step wall — a model
-                "device_split": ("measured" if measured is not None
-                                 else "modelled")}
+                "host_dispatch": float(dispatch_sec), **split}
         except Exception:
             pass
         return {k: float(v[-1]) for k, v in host.items()}
@@ -2915,15 +2102,7 @@ class WorkerTasklet:
         key = self._program_cache_key
         if key is None:
             return None
-        if not self._fused_mode():
-            total = 0.0
-            for tag in ("unfused_pull", "unfused_comp", "unfused_push"):
-                cost = progcache.program_cost((key, tag))
-                if cost is None or cost.flops is None:
-                    return None
-                total += cost.flops
-            self._flops_per_step = total
-        elif self._use_fused_epoch():
+        if self._use_fused_epoch():
             cost = progcache.program_cost((key, "epoch"))
             if cost is None or cost.flops is None:
                 return None
@@ -2977,8 +2156,7 @@ class WorkerTasklet:
         if key is None:
             return 0
         total = 0
-        for tag in ("step", "epoch", "eval",
-                    "unfused_pull", "unfused_comp", "unfused_push"):
+        for tag in ("step", "epoch", "eval"):
             cost = progcache.program_cost((key, tag))
             if cost is not None:
                 total += ((cost.temp_bytes or 0)
@@ -3056,7 +2234,7 @@ class WorkerTasklet:
         """``k`` whole-epoch dispatches chained on the table state with ONE
         drain at the end (k=1 = the plain fused epoch). Windowable trainer
         hooks run BETWEEN dispatches so epoch-indexed hyperparams (decay,
-        PRNG folds) feed each dispatch exactly as in the unfused loop.
+        PRNG folds) feed each dispatch exactly as in the per-batch loop.
         Returns ([(examples, last_metrics)] per epoch, seconds_per_epoch)."""
         # cache build BEFORE the timer starts: the one-time dataset
         # stacking/transfer must not inflate per-batch times fed to the
@@ -3191,12 +2369,11 @@ class WorkerTasklet:
                 # spans; the wall is the one that holds them (see
                 # _take_budget_feed), not the epoch's own timer
                 ph.update(budget_ctl or {})
-                split = ph.pop("device_split", "modelled")
                 budget().observe_epoch(
                     self.job_id, self.attempt_key, self.ctx.worker_id,
                     epoch,
                     epoch_sec if budget_wall is None else budget_wall,
-                    ph, device_split=split)
+                    ph)
             except Exception:
                 pass
         self._check_slo(epoch, epoch_examples, epoch_sec)

@@ -70,7 +70,7 @@ ENV_MAX_ACTIONS = "HARMONY_POLICY_MAX_ACTIONS"
 #: SHARED gate's other tenants (the input autoscaler's "up"/"down"
 #: keys) keep their streaks
 _ACTION_KINDS = frozenset(
-    ("grow", "shrink", "pack", "preempt", "async", "protect"))
+    ("grow", "shrink", "pack", "preempt", "protect"))
 
 #: a serving tenant whose windowed p99 is at/over this fraction of its
 #: registered SLO is latency-critical: the `protect` action pins its
@@ -301,13 +301,10 @@ class PolicyAction:
     def fence_kind(self) -> str:
         """The elastic fence flavor carrying this action: capacity gains
         ride the re-grow fence, every reduction/consolidation the
-        shrink fence. `async` keeps the SAME executor set — it rides the
-        re-grow fence (no survivors-only retile; the next attempt merely
-        relaunches with the async knob pinned). `protect` never reaches
-        a fence at all (its actuator is planner-side victim exemption);
-        it classes with the non-reductions."""
-        return ("regrow" if self.kind in ("grow", "async", "protect")
-                else "shrink")
+        shrink fence. `protect` never reaches a fence at all (its
+        actuator is planner-side victim exemption); it classes with the
+        non-reductions."""
+        return "regrow" if self.kind in ("grow", "protect") else "shrink"
 
     def to_dict(self) -> Dict[str, Any]:
         return {s: getattr(self, s) for s in self.__slots__}
@@ -514,7 +511,6 @@ class PolicyEngine:
             return int((tenants.get(job) or {}).get("priority", 0))
 
         grow_wants: List[Tuple[float, str]] = []
-        async_wants: List[Tuple[float, str]] = []
         for job, t in sorted(tenants.items()):
             r = row(job)
             att = (r.get("slo") or {}).get("attainment")
@@ -525,25 +521,12 @@ class PolicyEngine:
                 note["blocked"] = "slo met or unknown"
             elif cls in _NO_GROW_CLASSES:
                 note["blocked"] = f"{cls}: more devices would not help"
-                # comm-bound is the one no-grow class with a better lever
-                # than capacity: overlap the comm instead of buying chips.
-                # Only when the worker reported the lever exists for this
-                # tenant's (table, trainer, layout) and it is still off —
-                # and within the same recovery budget every fenced action
-                # respects.
-                lever = r.get("async") or {}
-                if (cls == "comm-bound" and lever.get("available")
-                        and not lever.get("enabled")
-                        and int(t.get("attempt", 0)) < cap):
-                    note["async_candidate"] = True
-                    async_wants.append((att, job))
             elif int(t.get("attempt", 0)) >= cap:
                 note["blocked"] = "elastic recovery budget exhausted"
             else:
                 grow_wants.append((att, job))
             considered.append(note)
         grow_wants.sort(key=lambda x: (-prio(x[1]), x[0]))
-        async_wants.sort(key=lambda x: (-prio(x[1]), x[0]))
 
         if units is None:
             units = [[e] for e in idle]
@@ -578,21 +561,6 @@ class PolicyEngine:
                 # would be self-contradictory
                 protected.add(job)
             considered.append(note)
-        if async_wants:
-            # one async action per cycle (same ramp discipline as grow);
-            # the executor set is UNCHANGED — the fence relaunches the
-            # attempt with the async knob pinned via scheduler.plan_async
-            att, job = async_wants[0]
-            lever = (row(job).get("async") or {})
-            actions.append(PolicyAction(
-                "async", job,
-                list((tenants.get(job) or {}).get("executors") or ()),
-                signal="comm_wait",
-                reason=(f"SLO attainment {att:.2f} < {grow_below} and "
-                        "comm-bound: enabling bounded-staleness async "
-                        "aggregation to overlap pull/push with compute"),
-                evidence={"attainment": att, "class": "comm-bound",
-                          "async": dict(lever)}))
         if grow_wants and units:
             att, job = grow_wants[0]
             cur = list((tenants.get(job) or {}).get("executors") or ())
@@ -734,13 +702,6 @@ class PolicyEngine:
             self._record(a)
             return
         try:
-            if a.kind == "async":
-                # the async actuator: pin the knob for the next attempt
-                # (guarded getattr — an embedding scheduler predating the
-                # SPI method downgrades to a knob-less advisory fence)
-                plan_async = getattr(self._scheduler, "plan_async", None)
-                if plan_async is not None:
-                    plan_async(a.job, True)
             self._scheduler.plan_grant(a.job, a.executors, shared=a.shared)
             epoch = self._fence_fn(a.job, a.fence_kind)
         except Exception as e:  # noqa: BLE001 - surfaced in the plan

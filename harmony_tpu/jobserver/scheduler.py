@@ -64,28 +64,6 @@ class JobScheduler:
                       ) -> Optional[Tuple[List[str], bool]]:
         return self._policy_targets().get(job_id)
 
-    def _policy_async(self) -> Dict[str, bool]:
-        """``job_id -> enable`` pins from :meth:`plan_async` (same lazy
-        shape as the grant map, for direct-constructed test doubles)."""
-        t = getattr(self, "_policy_async_map", None)
-        if t is None:
-            t = self._policy_async_map = {}
-        return t
-
-    def plan_async(self, job_id: str, enabled: bool = True) -> None:
-        """Pin bounded-staleness async step mode for ``job_id``'s NEXT
-        attempt (the policy engine's `async` actuator — a comm-bound
-        tenant's comm phases overlap compute instead of growing it).
-        Like :meth:`plan_grant`, the pin lands when the elastic fence
-        ends the running attempt; the launcher consumes it via
-        :meth:`planned_async` when building the attempt's TrainerParams
-        (``async_step`` / ``staleness_bound``). One-shot."""
-        self._policy_async()[job_id] = bool(enabled)
-
-    def planned_async(self, job_id: str) -> Optional[bool]:
-        """Consume (pop) the async pin for ``job_id``, if any."""
-        return self._policy_async().pop(job_id, None)
-
     def idle_executors(self) -> List[str]:
         """Executors no running job holds — the policy engine's grow
         fodder. Overlap schedulers (share-all) have no idle notion and

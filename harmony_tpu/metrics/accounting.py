@@ -85,7 +85,7 @@ class _Tenant:
     __slots__ = ("job", "attempt", "workers", "devices", "samples",
                  "steps_total", "device_sec_total", "examples_total",
                  "flops_per_step", "resident", "bytes", "target_sps",
-                 "slo_events", "first_ts", "last_ts", "async_state",
+                 "slo_events", "first_ts", "last_ts",
                  "serving_state", "table_layout")
 
     def __init__(self, job: str) -> None:
@@ -105,10 +105,6 @@ class _Tenant:
         self.slo_events = 0
         self.first_ts: Optional[float] = None
         self.last_ts: Optional[float] = None
-        #: bounded-staleness async lever state (set_async_state): None
-        #: until the worker reports; availability is what the policy
-        #: engine keys its `async` proposal on
-        self.async_state: Optional[Dict[str, Any]] = None
         #: online-serving state (set_serving_state): None until the
         #: serving plane reports this tenant; the p99-vs-SLO pair is
         #: what `obs top`, the doctor's serving_slo_breach rule and the
@@ -190,26 +186,6 @@ class LedgerStore:
     def record_slo_event(self, job: str) -> None:
         with self._lock:
             self._tenant(job).slo_events += 1
-
-    def set_async_state(self, job: str, attempt: str, *, available: bool,
-                        enabled: bool, bound: int = 0, max_lag: int = 0,
-                        exposed_wait_sec: float = 0.0,
-                        overlapped_comm_sec: float = 0.0) -> None:
-        """Bounded-staleness async lever state (dolphin worker, once per
-        epoch drain). ``available`` says the lever EXISTS for this
-        tenant's (table, trainer, layout) — the policy engine proposes
-        `async` only for available-but-disabled comm-bound tenants;
-        the staleness telemetry shows overlapped vs exposed comm time
-        when the mode is on."""
-        with self._lock:
-            self._tenant(job, attempt).async_state = {
-                "available": bool(available),
-                "enabled": bool(enabled),
-                "staleness_bound": int(bound),
-                "max_lag": int(max_lag),
-                "exposed_wait_sec": round(float(exposed_wait_sec), 6),
-                "overlapped_comm_sec": round(float(overlapped_comm_sec), 6),
-            }
 
     def set_table_layout(self, job: str, layout: Dict[str, Any]) -> None:
         """How the tenant's dense model table is stored (metrics/
@@ -359,8 +335,6 @@ class LedgerStore:
                                        if attain is not None else None),
                         "events": t.slo_events,
                     },
-                    "async": (dict(t.async_state)
-                              if t.async_state is not None else None),
                     "serving": (dict(t.serving_state)
                                 if t.serving_state is not None else None),
                     "table_layout": (dict(t.table_layout)
@@ -475,21 +449,6 @@ def _install_callbacks(store: LedgerStore) -> None:
                              "kind": kind}, float(n)))
         return out
 
-    def async_of(sub):
-        # not gauge_of: the "async" row is None until the worker
-        # reports, and the staleness series only mean anything with the
-        # mode actually ON — absent otherwise, never 0
-        def sample():
-            out = []
-            for r in rows().values():
-                a = r.get("async")
-                if not a or not a.get("enabled"):
-                    continue
-                out.append(({"job": r["job"], "attempt": r["attempt"]},
-                            float(a[sub])))
-            return out
-        return sample
-
     def serving_of(sub):
         # not gauge_of: the "serving" row is None until the serving
         # plane reports, and a reported-None field (no traffic in the
@@ -538,16 +497,6 @@ def _install_callbacks(store: LedgerStore) -> None:
             "Cumulative state-movement bytes per tenant (kind: move / "
             "chkp_write / chkp_read)",
             "counter", bytes_samples)
-        reg.register_callback(
-            "harmony_tenant_staleness_lag",
-            "Max applied-update lag observed by the tenant's async step "
-            "(absent unless bounded-staleness async mode is on)",
-            "gauge", async_of("max_lag"))
-        reg.register_callback(
-            "harmony_tenant_async_exposed_seconds",
-            "Comm seconds the async step could NOT hide: staleness-gate "
-            "wait blocking compute (absent unless async mode is on)",
-            "gauge", async_of("exposed_wait_sec"))
         reg.register_callback(
             "harmony_tenant_serving_qps",
             "Windowed serving lookups/sec per tenant (absent unless the "

@@ -355,8 +355,7 @@ def _steady_points(ctx: "DoctorContext", series: str, labels: Dict[str, str],
                    ) -> List[Tuple[float, float]]:
     """Windowed points of one phase series MINUS the one-time
     compile-bearing first sample: a tenant's first epoch pays the step's
-    XLA compile inside its pull/push wall (the _UnfusedStep timers
-    established the exclusion on the worker side), so a series whose
+    XLA compile inside its pull/push wall, so a series whose
     first-EVER sample still sits inside the window would let capex
     masquerade as sustained traffic. Only that first-ever point is
     dropped — a long-lived tenant whose birth sample already aged out of
@@ -392,7 +391,7 @@ def _steady_phase_median(ctx: "DoctorContext", series: str,
              "step-phase budget, metrics/phases.py) — model traffic, "
              "not math, owns the step; packing this tenant tighter "
              "makes it worse. The one-time compile-bearing first sample "
-             "is excluded from the fractions (the _UnfusedStep pattern)")
+             "is excluded from the fractions")
 def _comm_bound(ctx: DoctorContext) -> List[Diagnosis]:
     out: List[Diagnosis] = []
     for labels, raw in ctx.store.range("tenant.phase.pull_comm",
@@ -508,11 +507,11 @@ def _policy_judge_age() -> float:
 
 
 @doctor_rule("rebalance_ineffective",
-             "an executed GROW or ASYNC policy action (kind=\"policy\" "
-             "joblog event, jobserver/policy.py) whose target tenant "
-             "shows no MFU or SLO-attainment improvement within two "
-             "policy windows of the fence — the engine backs the tenant "
-             "off on this diagnosis instead of churning it (shrink/pack/"
+             "an executed GROW policy action (kind=\"policy\" joblog "
+             "event, jobserver/policy.py) whose target tenant shows no "
+             "MFU or SLO-attainment improvement within two policy "
+             "windows of the fence — the engine backs the tenant off on "
+             "this diagnosis instead of churning it (shrink/pack/"
              "preempt victims degrade BY DESIGN and are never judged)")
 def _rebalance_ineffective(ctx: DoctorContext) -> List[Diagnosis]:
     judge_age = _policy_judge_age()
@@ -520,13 +519,10 @@ def _rebalance_ineffective(ctx: DoctorContext) -> List[Diagnosis]:
     for job, events in ctx.events.items():
         # only actions meant to HELP their target are judged by the
         # target's own series — a shrink/pack/preempt victim's numbers
-        # drop on purpose (the claimant got the capacity). `async` is
-        # judged exactly like grow: it promised the TARGET a speedup
-        # (overlapped comm), so flat series after the fence mean the
-        # lever did not pay and the engine should back off.
+        # drop on purpose (the claimant got the capacity)
         acts = [e for e in events
                 if e.get("kind") == "policy" and e.get("executed")
-                and e.get("action") in ("grow", "async")]
+                and e.get("action") == "grow"]
         if not acts:
             continue
         ev = acts[-1]

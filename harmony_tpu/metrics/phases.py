@@ -3,9 +3,8 @@
 The ledger (metrics/accounting.py) can say *that* a tenant is slow and
 the doctor (metrics/doctor.py) can say *who* lags, but until now nothing
 said *where inside the step* the wall time went: the fused step charged
-its whole wall to COMP, the unfused fallback's measured phase split died
-inside BatchMetrics, and the comm probe's split was stashed on a private
-table attr. The TPU-pod papers get their wins precisely from this
+its whole wall to COMP and the comm probe's split was stashed on a
+private table attr. The TPU-pod papers get their wins precisely from this
 breakdown — overlapping cross-host transfers with compute
 (arXiv:2011.03641) and per-phase tuning at pod scale (MLPerf-0.6 on
 v3 pods) — and the device autoscaler (ROADMAP item 1) cannot choose
@@ -17,12 +16,11 @@ CLOSED phase set:
 * ``input_wait``    — prefetch consumer-stall seconds (PR 1, measured);
 * ``host_dispatch`` — host seconds between batch-ready and device
   dispatch (placement/staging on the training thread, measured);
-* ``pull_comm`` / ``compute`` / ``push_comm`` — the device-work split:
-  unfused mode uses its REAL per-phase measurements; fused mode applies
-  the comm-probe's absolute pull/push seconds to the measured step
-  wall, refined by ``cost_analysis`` FLOP seconds when the backend
-  exposes a cost model (the probe can overestimate comm on tiny
-  tables; compute never drops below its FLOP floor);
+* ``pull_comm`` / ``compute`` / ``push_comm`` — the device-work split,
+  a MODEL: the comm-probe's absolute pull/push seconds applied to the
+  measured step wall, refined by ``cost_analysis`` FLOP seconds when
+  the backend exposes a cost model (the probe can overestimate comm on
+  tiny tables; compute never drops below its FLOP floor);
 * ``grant_wait``    — admission: every ``taskunit.wait`` (COMP / NET /
   CPU units) and dispatch-turn entry of the worker's training thread,
   measured by the spans' own clock reads (tracing/span.py ``acc``);
@@ -94,8 +92,6 @@ def phase_window_seconds() -> float:
 def split_device_phases(work_sec: float, steps: int, *,
                         dispatch_sec: float = 0.0,
                         probe_split: Optional[Tuple[float, float]] = None,
-                        measured: Optional[Tuple[float, float, float]]
-                        = None,
                         flops_per_step: Optional[float] = None,
                         peak_flops: Optional[float] = None,
                         devices: int = 1) -> Dict[str, float]:
@@ -103,21 +99,15 @@ def split_device_phases(work_sec: float, steps: int, *,
     smeared per-batch time × steps, which INCLUDES host placement) into
     ``pull_comm`` / ``compute`` / ``push_comm``.
 
-    * ``measured`` (unfused mode): the :class:`_UnfusedStep` per-step
-      (pull, comp, push) means — real measurements. They are scaled
-      DOWN if they exceed the available work (an elastic shrink or a
-      rebuild mid-window truncates the wall they were measured against)
-      and any leftover work stays UNattributed (it lands in the epoch
-      residual — drain/sync overhead is not compute).
-    * ``probe_split`` (fused mode): the comm probe's absolute per-step
-      (pull, push) device seconds applied to the measured wall;
-      ``compute`` is the remainder (PR 6's documented convention — with
-      the probe off the whole work charges to compute, the conservative
-      default). When ``flops_per_step`` AND ``peak_flops`` are known,
-      the remainder is refined: compute never drops below the FLOP
-      floor ``flops × steps / (peak × devices)`` — on tiny tables the
-      probe's sub-millisecond measurements can rival the step wall and
-      would otherwise starve compute to zero.
+    ``probe_split`` is the comm probe's absolute per-step (pull, push)
+    device seconds, applied to the measured wall; ``compute`` is the
+    remainder (PR 6's documented convention — with the probe off the
+    whole work charges to compute, the conservative default). When
+    ``flops_per_step`` AND ``peak_flops`` are known, the remainder is
+    refined: compute never drops below the FLOP floor
+    ``flops × steps / (peak × devices)`` — on tiny tables the probe's
+    sub-millisecond measurements can rival the step wall and would
+    otherwise starve compute to zero.
 
     Returns non-negative seconds with
     ``pull + comp + push <= max(work - dispatch, 0)``.
@@ -126,14 +116,6 @@ def split_device_phases(work_sec: float, steps: int, *,
     steps = max(int(steps), 0)
     if avail <= 0.0 or steps == 0:
         return {"pull_comm": 0.0, "compute": 0.0, "push_comm": 0.0}
-    if measured is not None:
-        pull0 = max(float(measured[0]), 0.0) * steps
-        comp0 = max(float(measured[1]), 0.0) * steps
-        push0 = max(float(measured[2]), 0.0) * steps
-        total0 = pull0 + comp0 + push0
-        scale = min(1.0, avail / total0) if total0 > 0 else 0.0
-        return {"pull_comm": pull0 * scale, "compute": comp0 * scale,
-                "push_comm": push0 * scale}
     pull0 = push0 = 0.0
     if probe_split is not None:
         pull0 = max(float(probe_split[0]), 0.0) * steps
@@ -149,9 +131,9 @@ def split_device_phases(work_sec: float, steps: int, *,
     comm = max(comm, 0.0)
     scale = comm / comm0 if comm0 > 0 else 0.0
     return {"pull_comm": pull0 * scale,
-            # fused mode has no way to separate in-work overhead from
-            # compute (one XLA program) — the remainder IS compute by
-            # the documented convention
+            # nothing separates in-work overhead from compute (one XLA
+            # program) — the remainder IS compute by the documented
+            # convention
             "compute": avail - comm,
             "push_comm": push0 * scale}
 
@@ -165,8 +147,9 @@ class _TenantPhases:
         self.job = job
         self.attempt = job
         #: how pull_comm / compute / push_comm were told apart in the
-        #: newest feed: "measured" (the unfused step's own timers) or
-        #: "modelled" (the probe's seconds applied to the fused step wall)
+        #: newest feed: "modelled" (the probe's seconds applied to the
+        #: step wall) is all a worker feeds today; "measured" is for a
+        #: feed that reads them off a trace (ROADMAP D10)
         self.device_split = "modelled"
         #: (ts, attempt, worker, epoch_idx, wall_sec, {phase: sec}) —
         #: the attempt rides each sample so the barrier join never

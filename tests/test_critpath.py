@@ -1,6 +1,6 @@
 """Step-phase time budget + cross-worker critical-path attribution
 (ISSUE 13): the split math and its invariant, the budget store's
-barrier join and shrink clamping, fused-vs-unfused budget parity, the
+barrier join and shrink clamping, the worker's real budget feed, the
 critpath classifier, the doctor's comm_bound/dispatch_bound rules, the
 profiler-capture surfaces, the shared obs endpoint resolution — and
 the fault-injected acceptance through the REAL stack (jobserver →
@@ -73,23 +73,6 @@ def _assert_invariant(row):
 
 
 class TestSplitMath:
-    def test_fused_and_unfused_report_the_same_budget(self):
-        """The acceptance's math half: fed CONSISTENT measurements —
-        the probe split on one side, the per-phase programs' measured
-        seconds on the other — the two modes' splits agree within the
-        5% invariant tolerance."""
-        wall, steps = 1.0, 10
-        pull, push = 0.02, 0.01
-        comp = wall / steps - pull - push
-        fused = split_device_phases(wall, steps,
-                                    probe_split=(pull, push))
-        unfused = split_device_phases(wall, steps,
-                                      measured=(pull, comp, push))
-        for k in ("pull_comm", "compute", "push_comm"):
-            assert fused[k] == pytest.approx(unfused[k],
-                                             abs=TOL * wall), k
-        assert sum(fused.values()) == pytest.approx(wall, abs=TOL)
-
     def test_probe_off_charges_compute_conservatively(self):
         out = split_device_phases(2.0, 4, probe_split=(0.0, 0.0))
         assert out == {"pull_comm": 0.0, "compute": 2.0,
@@ -110,16 +93,6 @@ class TestSplitMath:
         assert sum(out.values()) == pytest.approx(1.0, abs=1e-9)
         # probe proportions preserved under the scale-down
         assert out["pull_comm"] == pytest.approx(2 * out["push_comm"])
-
-    def test_measured_phases_scale_down_never_up(self):
-        """Unfused: measured phases exceeding the wall (shrink/rebuild
-        truncation) scale DOWN; measured phases below the wall leave
-        the leftover unattributed (it is drain/sync overhead, not
-        compute — the residual carries it)."""
-        over = split_device_phases(1.0, 10, measured=(0.1, 0.1, 0.1))
-        assert sum(over.values()) == pytest.approx(1.0, abs=1e-9)
-        under = split_device_phases(1.0, 2, measured=(0.05, 0.1, 0.05))
-        assert sum(under.values()) == pytest.approx(0.4, abs=1e-9)
 
     def test_dispatch_subtracts_from_available_work(self):
         out = split_device_phases(1.0, 4, dispatch_sec=0.4,
@@ -283,8 +256,8 @@ def _run_worker(job_id, *, num_epochs=3, features=64, classes=8, n=64,
 
 class TestWorkerBudget:
     """Fixed-seed real runs: the budget invariant holds through the
-    REAL worker paths, in both step modes, and the comm split flows
-    through the table's typed accessor, not a private-attr poke."""
+    REAL worker path, and the comm split flows through the table's typed
+    accessor, not a private-attr poke."""
 
     def test_fused_run_feeds_an_invariant_budget(self, devices,
                                                  fresh_phase):
@@ -295,45 +268,6 @@ class TestWorkerBudget:
         assert row["phases"]["compute"] > 0.0
         # the probe published through the typed accessor
         assert w.ctx.model_table.comm_split() is not None
-
-    def test_unfused_run_feeds_an_invariant_budget(self, devices,
-                                                   fresh_phase,
-                                                   monkeypatch):
-        monkeypatch.setenv("HARMONY_FUSED_STEP", "0")
-        _run_worker("unfused-j")
-        row = phases.peek_budget().snapshot(
-            window_sec=300.0)["unfused-j"]
-        _assert_invariant(row)
-        assert row["phases"]["compute"] > 0.0
-
-    def test_fused_and_unfused_budgets_agree(self, devices,
-                                             fresh_phase, monkeypatch):
-        """Same fixed-seed compute-heavy workload through both step
-        modes, STEADY STATE (a cold run per mode first — fused mode's
-        conservative remainder absorbs compile into compute while
-        unfused deliberately excludes it into residual, so only warm
-        budgets are comparable): both satisfy the invariant, both name
-        compute the dominant device phase, and the measured compute
-        SECONDS agree within a CPU-noise-sized factor — the two
-        estimation paths describe the same matmuls."""
-        kw = dict(features=1024, classes=32, n=512, num_epochs=2)
-        _run_worker("ab-f-cold", **kw)
-        _run_worker("ab-f", **kw)  # warm: programs cache-hit
-        monkeypatch.setenv("HARMONY_FUSED_STEP", "0")
-        _run_worker("ab-u-cold", **kw)
-        _run_worker("ab-u", **kw)
-        snap = phases.peek_budget().snapshot(window_sec=300.0)
-        f, u = snap["ab-f"], snap["ab-u"]
-        _assert_invariant(f)
-        _assert_invariant(u)
-        for row in (f, u):
-            dev = {p: row["phases"][p]
-                   for p in ("pull_comm", "compute", "push_comm")}
-            assert max(dev, key=dev.get) == "compute", row["phases"]
-        f_comp, u_comp = f["phases"]["compute"], u["phases"]["compute"]
-        assert f_comp > 0 and u_comp > 0
-        ratio = f_comp / u_comp
-        assert 1 / 3 <= ratio <= 3, (f["phases"], u["phases"])
 
     def test_ledger_join_carries_phases_and_class(self, devices,
                                                   fresh_phase):
@@ -648,6 +582,12 @@ class TestAcceptance:
         server._history_scraper.period = 3600.0  # polls driven by hand
         server.start()
         try:
+            # the same shapes once without a fault: a cold process puts
+            # the step's and the probe's compiles into the injected
+            # tenants' walls (pull_comm 0.37 of it against the 0.4
+            # threshold; 0.58 warm) and the verdict would hang on which
+            # tests this process ran before
+            server.submit(_job_cfg("warm-j")).result(timeout=300)
             server.submit(_job_cfg("comm-j")).result(timeout=300)
             server.submit(_job_cfg("disp-j")).result(timeout=300)
             faults.disarm()

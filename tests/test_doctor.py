@@ -184,6 +184,35 @@ class TestRuleCatalog:
             joblog.clear_events("lone-j")
 
 
+class TestDoctorCommBoundSteadyState:
+    """comm_bound ignores the compile-bearing first sample."""
+
+    @staticmethod
+    def _phase_store(job, pull, push):
+        store = HistoryStore(window_sec=900.0, resolution_sec=1.0)
+        labels = {"job": job, "attempt": job}
+        _feed(store, "tenant.phase.pull_comm", labels, pull, spacing=5.0)
+        _feed(store, "tenant.phase.push_comm", labels, push, spacing=5.0)
+        return store
+
+    def test_compile_bearing_first_sample_excluded(self):
+        """One compile-inflated pull sample followed by a healthy one
+        must NOT diagnose comm-bound (the pre-fix median of [0.85, 0.1]
+        is 0.475 — a false positive off one cold sample)."""
+        store = self._phase_store("cold-j", [0.85, 0.1], [0.1, 0.05])
+        doc = Doctor(store, events_fn=dict)
+        assert not [d for d in doc.diagnose() if d.rule == "comm_bound"]
+
+    def test_steady_comm_bound_still_fires(self):
+        """The exclusion must not kill the rule: a tenant whose steady
+        samples are ALSO comm-heavy still diagnoses."""
+        store = self._phase_store("hot-j", [0.7, 0.5, 0.5],
+                                  [0.1, 0.1, 0.1])
+        doc = Doctor(store, events_fn=dict)
+        comm = [d for d in doc.diagnose() if d.rule == "comm_bound"]
+        assert len(comm) == 1 and comm[0].job == "hot-j"
+
+
 class TestEngineSemantics:
     def test_once_per_window_then_rearms(self):
         s = _store(window=30.0)

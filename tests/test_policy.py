@@ -160,19 +160,28 @@ class TestDecisions:
         assert [a["outcome"] for a in plan["actions"]] == ["fenced"]
         assert fences == [("a", "regrow")]
 
-    @pytest.mark.parametrize("cls", ["input-bound", "dispatch-bound",
-                                     "comm-bound"])
-    def test_grow_blocked_for_non_compute_bound(self, act_mode, cls):
+    @pytest.mark.parametrize("cls,ledger_extra", [
+        ("input-bound", {}), ("dispatch-bound", {}), ("comm-bound", {}),
+        # the row a worker of before PR 27 fed: the `async` lever went
+        # with the async step mode, so a comm-bound tenant under its SLO
+        # is blocked like the others — no action, no fence, no relaunch
+        ("comm-bound", {"async": {"available": True, "enabled": False}}),
+    ], ids=["input-bound", "dispatch-bound", "comm-bound",
+            "comm-bound-old-async-row"])
+    def test_grow_blocked_for_non_compute_bound(self, act_mode, cls,
+                                                ledger_extra):
         sched = FakeScheduler(idle=["e1"])
         fences = []
-        eng = _engine({"a": _row(att=0.3, cls=cls)},
+        eng = _engine({"a": {**_row(att=0.3, cls=cls), **ledger_extra}},
                       {"a": {"executors": ["e0"], "attempt": 0,
                              "priority": 0}},
                       sched, fences)
         plan = eng.evaluate()
         assert plan["actions"] == [] and not fences
         (note,) = [c for c in plan["considered"] if c.get("job") == "a"]
-        assert cls in note["blocked"]
+        assert note["blocked"] == f"{cls}: more devices would not help"
+        assert set(note) == {"job", "check", "attainment", "class",
+                             "priority", "blocked"}
 
     def test_shrink_low_priority_under_contention(self, act_mode):
         sched = FakeScheduler(idle=[], queued=[_queued("hi", 2)])

@@ -232,7 +232,7 @@ def test_three_phases_are_carved_out_of_residual_only():
     assert sum(row["fractions"].values()) == pytest.approx(1.0, abs=1e-5)
 
 
-def test_device_split_says_measured_for_the_unfused_step():
+def test_budget_store_keeps_the_feeds_device_split():
     store = PhaseBudgetStore()
     store.observe_epoch("j", "j", "w0", 0, 1.0, {"compute": 0.5},
                         device_split="measured")

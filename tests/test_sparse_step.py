@@ -1,13 +1,15 @@
 """Fused device hot path: Pallas sparse kernels, FusedSparseStep, and the
-fused-vs-unfused (TrainerParams.fused_step) parity contract.
+step program's parity contract.
 
-Parity contract (docs/DEVICE_HOT_PATH.md): for a fixed seed, per-epoch
-LOSSES are bit-identical with the knob on vs off — the phase boundaries
-in the fused program (worker._phase_boundary) pin the same replicated
-shardings the host-driven path materializes. Table state matches to float
-tolerance (XLA may re-associate gradient-matmul accumulation differently
-across program boundaries; NMF/LDA state is exactly equal, MLR differs in
-final bits).
+Parity contract (docs/DEVICE_HOT_PATH.md): for a fixed seed, the ONE step
+program ``WorkerTasklet`` builds (pull -> compute -> push in one donated
+jit) gives the per-epoch LOSSES of a per-phase loop over the public
+accessor API — pull to the host, ``trainer.compute`` alone in a jit, push
+from the host — kept HERE as the reference, bit for bit where the step's
+phase boundaries (worker._phase_boundary) make that hold. Table state
+matches to float tolerance where a gradient matmul feeds it (XLA may
+re-associate its accumulation differently across program boundaries;
+NMF/LDA/LM state is exactly equal, MLR and FM differ in final bits).
 """
 import numpy as np
 import jax
@@ -163,138 +165,208 @@ def test_push_via_sparse_requires_additive():
 
 
 # ---------------------------------------------------------------------------
-# fused vs unfused WorkerTasklet parity (the knob's contract)
+# the step program against a per-phase reference over the public accessor
 # ---------------------------------------------------------------------------
 
 
-def _run_worker(trainer, arrays, mesh, fused, epochs=3, batches=4):
-    spec = TableSpec(trainer.model_table_config())
-    table = DenseTable(spec, mesh)
+def _tables(trainer, mesh):
+    from harmony_tpu.table.hashtable import DeviceHashTable, HashTableSpec
+
+    cfg = trainer.model_table_config()
+    table = (DeviceHashTable(HashTableSpec(cfg), mesh) if cfg.sparse
+             else DenseTable(TableSpec(cfg), mesh))
     ltable = (DenseTable(TableSpec(trainer.local_table_config()), mesh)
               if trainer.uses_local_table else None)
-    params = TrainerParams(num_epochs=epochs, num_mini_batches=batches,
-                           fused_step=fused)
+    return table, ltable
+
+
+def _run_worker(trainer, arrays, mesh, epochs, batches):
+    table, ltable = _tables(trainer, mesh)
+    params = TrainerParams(num_epochs=epochs, num_mini_batches=batches)
     ctx = TrainerContext(params=params, model_table=table,
                          local_table=ltable)
     data = TrainingDataProvider(arrays, batches)
-    w = WorkerTasklet(f"j-{fused}", ctx, trainer, data, mesh)
-    result = w.run()
-    return result, table, w
+    result = WorkerTasklet("j-step", ctx, trainer, data, mesh).run()
+    return result["losses"], table
 
 
-def test_mlr_fused_unfused_bit_identical_losses(mesh8):
+def _run_per_phase(trainer, arrays, mesh, epochs, batches):
+    """The reference: the trainer's lifecycle driven phase by phase through
+    the public host API. PULL reads rows to host numpy (``ModelAccessor``),
+    COMP is ``trainer.compute`` alone in a jit, PUSH sends the delta back
+    from the host. Operands are placed as the step's contract says — the
+    pulled model replicated on the mesh, the batch split over the data
+    axis, compute's outputs replicated — because that placement, not the
+    arithmetic, is what GSPMD partitions the reductions by."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from harmony_tpu.parallel.mesh import DATA_AXIS
+
+    table, ltable = _tables(trainer, mesh)
+    ctx = TrainerContext(
+        params=TrainerParams(num_epochs=epochs, num_mini_batches=batches),
+        model_table=table, local_table=ltable)
+    data = TrainingDataProvider(arrays, batches)
+    acc = ModelAccessor(table)
+    replicated = NamedSharding(mesh, P())
+    by_data = NamedSharding(mesh, P(DATA_AXIS))
+    local = trainer.uses_local_table
+    comp = jax.jit(trainer.compute_with_local if local else trainer.compute,
+                   out_shardings=replicated)
+    keyed = trainer.pull_mode == "keys"
+    keys_of = jax.jit(trainer.pull_keys) if keyed else None
+    all_keys = None if keyed else np.arange(table.spec.config.capacity)
+
+    trainer.init_global_settings(ctx)
+    trainer.on_training_start(ctx, 0)
+    losses = []
+    for epoch in range(epochs):
+        for batch in data.epoch_batches():
+            hyper = {k: jnp.asarray(v)
+                     for k, v in trainer.hyperparams().items()}
+            keys = (np.asarray(keys_of(tuple(map(jnp.asarray, batch))))
+                    if keyed else all_keys)
+            model = jax.device_put(acc.pull(keys), replicated)     # PULL
+            placed = tuple(jax.device_put(a, by_data) for a in batch)
+            if local:
+                lmodel = jax.device_put(np.asarray(ltable.pull_array()),
+                                        replicated)
+                delta, new_l, metrics = comp(model, lmodel, placed, hyper)
+                ltable.write_all(np.asarray(new_l))
+            else:
+                delta, metrics = comp(model, placed, hyper)        # COMP
+            acc.push(keys, np.asarray(delta))                      # PUSH
+        # an epoch's figure is its last step's objective (_finish_epoch)
+        losses.append(float(metrics[trainer.objective_metric or "loss"]))
+        trainer.on_epoch_finished(ctx, epoch)
+    return losses, table
+
+
+def _state(table):
+    items = getattr(table, "items", None)  # hash table: rows by key
+    if items is not None:
+        rows = items()
+        return np.stack([rows[k] for k in sorted(rows)])
+    return np.asarray(table.pull_array())
+
+
+def _mlr():
     from harmony_tpu.apps.mlr import MLRTrainer, make_synthetic
 
-    def mk():
-        return (MLRTrainer(num_classes=4, num_features=16,
-                           features_per_partition=8),
-                make_synthetic(64, 16, 4, seed=1))
-
-    t, a = mk()
-    r1, tb1, _ = _run_worker(t, a, mesh8, fused=True)
-    t, a = mk()
-    r0, tb0, _ = _run_worker(t, a, mesh8, fused=False)
-    assert r1["losses"] == r0["losses"]  # bit-identical
-    np.testing.assert_allclose(np.asarray(tb1.pull_array()),
-                               np.asarray(tb0.pull_array()), atol=1e-6)
+    return (MLRTrainer(num_classes=4, num_features=16,
+                       features_per_partition=8),
+            make_synthetic(64, 16, 4, seed=1))
 
 
-def test_nmf_fused_unfused_bit_identical(mesh8):
+def _nmf():
     from harmony_tpu.apps.nmf import NMFTrainer, make_synthetic
 
-    def mk():
-        return (NMFTrainer(num_rows=32, num_cols=24, rank=4, seed=2),
-                make_synthetic(32, 24, 4, seed=2))
-
-    t, a = mk()
-    r1, tb1, _ = _run_worker(t, a, mesh8, fused=True)
-    t, a = mk()
-    r0, tb0, _ = _run_worker(t, a, mesh8, fused=False)
-    assert r1["losses"] == r0["losses"]
-    np.testing.assert_array_equal(np.asarray(tb1.pull_array()),
-                                  np.asarray(tb0.pull_array()))
+    return (NMFTrainer(num_rows=32, num_cols=24, rank=4, seed=2),
+            make_synthetic(32, 24, 4, seed=2))
 
 
-def test_lda_fused_unfused_bit_identical(mesh8):
-    from harmony_tpu.apps.lda import LDATrainer, make_synthetic
+def _lda(sparse=False):
+    from harmony_tpu.apps import lda
 
-    def mk():
-        return (LDATrainer(vocab_size=50, num_topics=5, num_docs=32,
+    if sparse:  # hash-backed topic-word counts beside a dense local table
+        return (lda.LDATrainer(vocab_size=50, num_topics=5, num_docs=32,
+                               max_doc_len=10, sparse=True, slot_budget=256),
+                lda.make_synthetic_sparse(32, 50, 5, 10, seed=3))
+    return (lda.LDATrainer(vocab_size=50, num_topics=5, num_docs=32,
                            max_doc_len=10),
-                make_synthetic(32, 50, 5, 10, seed=3))
-
-    t, a = mk()
-    r1, tb1, _ = _run_worker(t, a, mesh8, fused=True)
-    t, a = mk()
-    r0, tb0, _ = _run_worker(t, a, mesh8, fused=False)
-    assert r1["losses"] == r0["losses"]
-    np.testing.assert_array_equal(np.asarray(tb1.pull_array()),
-                                  np.asarray(tb0.pull_array()))
+            lda.make_synthetic(32, 50, 5, 10, seed=3))
 
 
-def test_sparse_lda_fused_unfused_bit_identical(mesh8):
-    """The hash-backed (DeviceHashTable) keyed path through the knob."""
-    from harmony_tpu.apps.lda import LDATrainer, make_synthetic_sparse
-    from harmony_tpu.table.hashtable import DeviceHashTable, HashTableSpec
+def _fm(sparse=False):
+    """The keyed families of the benchmark's criteo cells: a dense keyed
+    table (on the cells' forced scatter route) and a DeviceHashTable."""
+    from harmony_tpu.apps import widedeep
 
-    def run(fused):
-        trainer = LDATrainer(vocab_size=50, num_topics=5, num_docs=32,
-                             max_doc_len=10, sparse=True, slot_budget=256)
-        table = DeviceHashTable(
-            HashTableSpec(trainer.model_table_config()), mesh8)
-        ltable = DenseTable(TableSpec(trainer.local_table_config()), mesh8)
-        params = TrainerParams(num_epochs=2, num_mini_batches=4,
-                               fused_step=fused)
-        ctx = TrainerContext(params=params, model_table=table,
-                             local_table=ltable)
-        data = TrainingDataProvider(
-            make_synthetic_sparse(32, 50, 5, 10, seed=3), 4)
-        return WorkerTasklet("j", ctx, trainer, data, mesh8).run()
-
-    assert run(True)["losses"] == run(False)["losses"]
+    data = (widedeep.make_synthetic_sparse if sparse
+            else widedeep.make_synthetic)
+    return (widedeep.FMTrainer(vocab_size=64, num_slots=4, emb_dim=7,
+                               sparse=sparse),
+            data(64, 64, 4, seed=4))
 
 
-def test_unfused_step_measures_phase_split(mesh8):
-    """Knob OFF: the worker's phase split comes from direct measurement
-    (no comm probe runs), and BatchMetrics carry a nonzero pull time."""
-    from harmony_tpu.apps.mlr import MLRTrainer, make_synthetic
-    from harmony_tpu.metrics.collector import MetricCollector
+def _lm(**arch):
+    """A tiny ``TransformerLM`` behind ``PyTreeTrainer`` (pull-all, Adam
+    state in the table): gpt2's family, and with ``arch`` OLMoE's."""
+    from harmony_tpu.models import TransformerTrainer, make_lm_data
 
-    trainer = MLRTrainer(num_classes=4, num_features=16,
-                         features_per_partition=8)
-    spec = TableSpec(trainer.model_table_config())
-    table = DenseTable(spec, mesh8)
-    params = TrainerParams(num_epochs=2, num_mini_batches=4,
-                           fused_step=False)
-    ctx = TrainerContext(params=params, model_table=table)
-    data = TrainingDataProvider(make_synthetic(64, 16, 4, seed=1), 4)
-    col = MetricCollector()
-    w = WorkerTasklet("j", ctx, trainer, data, mesh8, collector=col)
-    w.run()
-    step = w._step
-    assert step.steps == 8
-    pull, comp, push = step.mean_phase_seconds()
-    assert pull > 0 and push > 0
-    assert w._probe_pull is None  # the comm probe never built/ran
+    cfg = dict(vocab_size=64, d_model=32, n_heads=2, n_layers=2, d_ff=64,
+               max_seq=16)
+    cfg.update(arch)
+    return (TransformerTrainer(row_width=128, step_size=1e-2,
+                               optimizer="adam", **cfg),
+            (make_lm_data(16, 17, cfg["vocab_size"], seed=5),))
 
 
-def test_fused_step_env_override(mesh8, monkeypatch):
-    """HARMONY_FUSED_STEP=0 forces the unfused path process-wide even
-    when the config says fused."""
-    from harmony_tpu.apps.mlr import MLRTrainer, make_synthetic
-    from harmony_tpu.dolphin.worker import _UnfusedStep
+def _olmoe():
+    return _lm(vocab_size=96, d_model=64, n_heads=4, d_ff=32, pos="rope",
+               qk_norm=True, ffn="swiglu", tie_embeddings=False,
+               norm_eps=1e-5, moe_experts=8, moe_top_k=2, moe_every=1,
+               moe_aux_weight=0.01, moe_z_weight=0.001, moe_experts_held=3)
 
-    monkeypatch.setenv("HARMONY_FUSED_STEP", "0")
-    trainer = MLRTrainer(num_classes=4, num_features=16,
-                         features_per_partition=8)
-    table = DenseTable(TableSpec(trainer.model_table_config()), mesh8)
-    params = TrainerParams(num_epochs=1, num_mini_batches=2,
-                           fused_step=True)
-    ctx = TrainerContext(params=params, model_table=table)
-    data = TrainingDataProvider(make_synthetic(32, 16, 4, seed=1), 2)
-    w = WorkerTasklet("j", ctx, trainer, data, mesh8)
-    w._build_step()
-    assert isinstance(w._step, _UnfusedStep)
+
+#: family -> (make, epochs, batches a epoch, absolute tolerance of the
+#: final table state). State tolerance: MLR's and FM's deltas come out of
+#: a gradient matmul over the batch, whose accumulation XLA orders per
+#: PROGRAM — the step's compute and a compute jitted alone differ in the
+#: last bit (7e-9 / 1.5e-8 seen) although every loss along the way is
+#: equal; the others' state is counts, or passes through no such matmul
+#: on the way to the table, and is exact.
+_FAMILIES = {
+    "mlr": (_mlr, 3, 4, 1e-6),
+    "nmf": (_nmf, 3, 4, 0.0),
+    "lda": (_lda, 3, 4, 0.0),
+    "lda-hash": (lambda: _lda(sparse=True), 2, 4, 0.0),
+    "fm-scatter": (_fm, 3, 4, 1e-6),
+    "fm-hash": (lambda: _fm(sparse=True), 3, 4, 0.0),
+    "lm": (_lm, 2, 2, 0.0),
+    "olmoe": (_olmoe, 2, 2, 0.0),
+}
+#: mesh -> (data, model): one device is what the benchmark's one-chip
+#: cells run; 2 x 4 is where the boundaries' replication is a constraint
+_MESHES = {"one-device": (1, 1), "data2-model4": (2, 4)}
+
+
+@pytest.mark.parametrize("mesh_name", list(_MESHES))
+@pytest.mark.parametrize("family", list(_FAMILIES))
+def test_step_matches_per_phase_accessor_loop(family, mesh_name, devices,
+                                              monkeypatch):
+    """The one step program against the per-phase reference, family by
+    family: the classic apps, and the families the benchmark's cells run."""
+    from harmony_tpu.parallel import build_mesh
+
+    make, epochs, batches, state_atol = _FAMILIES[family]
+    data_ax, model_ax = _MESHES[mesh_name]
+    mesh = build_mesh(devices[:data_ax * model_ax], data=data_ax,
+                      model=model_ax)
+    if family == "fm-scatter":
+        monkeypatch.setenv("HARMONY_PUSH_VIA", "scatter")
+    trainer, arrays = make()
+    step_losses, step_table = _run_worker(trainer, arrays, mesh, epochs,
+                                          batches)
+    trainer, arrays = make()
+    ref_losses, ref_table = _run_per_phase(trainer, arrays, mesh, epochs,
+                                           batches)
+    if (family, mesh_name) == ("lda", "one-device"):
+        # LDA's objective is a float mean of per-token log-likelihoods;
+        # on one device XLA's CPU backend vectorises that reduction
+        # differently inside the whole step than in a compute jitted alone
+        # (1 ulp: -2.0623443 against -2.0623441). The state below — the
+        # counts every later step reads — is exact.
+        np.testing.assert_allclose(step_losses, ref_losses, rtol=1e-6)
+    else:
+        assert step_losses == ref_losses  # bit-identical
+    for table in (step_table, ref_table):
+        # nothing dropped: the reference cannot see an admission mask
+        assert getattr(table, "overflow_count", 0) == 0
+    if state_atol:
+        np.testing.assert_allclose(_state(step_table), _state(ref_table),
+                                   rtol=0, atol=state_atol)
+    else:
+        np.testing.assert_array_equal(_state(step_table), _state(ref_table))
 
 
 # ---------------------------------------------------------------------------
@@ -425,25 +497,3 @@ def test_fused_step_rejects_hash_tables(mesh8):
     ht = DeviceHashTable(HashTableSpec(cfg), mesh8)
     with pytest.raises(TypeError, match="hash"):
         FusedSparseStep(ht, _sgd_compute)
-
-
-def test_worker_program_key_carries_mode(mesh8):
-    """A fused and an unfused build of the same job must not collide in
-    the program cache."""
-    from harmony_tpu.apps.mlr import MLRTrainer, make_synthetic
-
-    def key_for(fused):
-        trainer = MLRTrainer(num_classes=4, num_features=16,
-                             features_per_partition=8)
-        table = DenseTable(TableSpec(trainer.model_table_config()), mesh8)
-        params = TrainerParams(num_epochs=1, num_mini_batches=2,
-                               fused_step=fused)
-        ctx = TrainerContext(params=params, model_table=table)
-        data = TrainingDataProvider(make_synthetic(32, 16, 4, seed=1), 2)
-        w = WorkerTasklet("j", ctx, trainer, data, mesh8)
-        w._build_step()
-        return w._program_cache_key
-
-    kf, ku = key_for(True), key_for(False)
-    assert kf is not None and ku is not None
-    assert kf != ku
