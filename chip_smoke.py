@@ -13,7 +13,8 @@ What it does, in ONE process (the process that owns the chips):
                 chip's peak);
   3. kernels    every Pallas kernel COMPILED on the device (never
                 ``interpret=True``) against its reference: ``gather_rows`` vs
-                ``table[idx]``, ``segment_sum_rows`` at its VMEM bound,
+                ``table[idx]``, ``scatter_add_rows`` vs ``.at[idx].add``,
+                ``segment_sum_rows`` at its VMEM bound,
                 ``weighted_histogram`` vs ``xla_histogram``, flash attention
                 forward and gradients vs ``blockwise_attention`` at the LM's
                 shapes in bf16;
@@ -356,6 +357,21 @@ def phase_kernels() -> Dict[str, Any]:
         "gather_rows out-of-range clamp != reference")
     out["gather_rows"] = {"table": [rows, width], "keys": nkeys,
                           "max_abs_err": 0.0}
+
+    # -- scatter_add_rows vs the XLA scatter: duplicates, two tiles, a tail
+    # and dropped ids; float deltas, and still bit for bit (an in-order fold)
+    sidx = np.concatenate([rng.integers(0, 64, nkeys // 2),  # hot duplicates
+                           rng.integers(-2, rows + 2, nkeys - nkeys // 2)]
+                          ).astype(np.int32)
+    sdel = jnp.asarray(rng.standard_normal((nkeys, width), dtype=np.float32))
+    _require(np.array_equal(
+        np.asarray(jax.jit(sparse.scatter_add_rows)(
+            table, jnp.asarray(sidx), sdel)),
+        np.asarray(jax.jit(sparse.scatter_add_rows_ref)(
+            table, jnp.asarray(sidx), sdel))),
+        "scatter_add_rows != .at[idx].add(deltas)")
+    out["scatter_add_rows"] = {"table": [rows, width], "keys": nkeys,
+                               "max_abs_err": 0.0}
 
     # -- segment_sum_rows at the accumulator's VMEM bound ------------------
     acc_rows = sparse._ACC_VMEM_BYTES // (width * 4)

@@ -57,7 +57,7 @@ from harmony_tpu.runtime import progcache
 from harmony_tpu.tracing import SpanContext, job_stage, trace_span
 from harmony_tpu.tracing.span import job_stage_adder
 from harmony_tpu.tracing.profiler import maybe_profile_epoch
-from harmony_tpu.utils.platform import traced_on
+from harmony_tpu.utils.platform import on_mesh, traced_on
 
 
 def _phase_boundary(tree, replicate_on: "Optional[Mesh]" = None):
@@ -440,6 +440,22 @@ class WorkerTasklet:
 
         return _step
 
+    def _note_push_lowering(self, mesh: Mesh) -> None:
+        """STATUS ``tenants.<job>.table_layout.push_lowering`` / the gauge
+        ``harmony_table_push_pallas_rows``: what the step just built does
+        for its keyed push (metrics/table_layout.py)."""
+        from harmony_tpu.metrics import table_layout
+        from harmony_tpu.table import TableSpec
+
+        spec = getattr(self.ctx.model_table, "spec", None)
+        if not isinstance(spec, TableSpec):  # hash tables: no block rows
+            return
+        route = self._push_route
+        if route in ("auto", "scatter"):
+            with on_mesh(mesh):
+                route = spec.push_lowering(self._pull_rows)
+        table_layout.note_push(self.job_id, spec.table_id, route)
+
     def _resolve_push_route(self) -> str:
         """The table's keyed-push route with "mxu_auto" resolved by a
         one-time MEASUREMENT at this job's actual push shape (the static
@@ -680,6 +696,7 @@ class WorkerTasklet:
             self._pull_rows = int(
                 jax.eval_shape(self.trainer.pull_keys, sample).shape[0]
             )
+            self._note_push_lowering(mesh_now)
         else:
             self._pull_rows = int(table.spec.config.capacity)
         self._step_sharding = tsh

@@ -193,6 +193,15 @@ class LedgerStore:
         with self._lock:
             self._tenant(job).table_layout = dict(layout)
 
+    def set_push_lowering(self, job: str, lowering: str) -> None:
+        """What the tenant's keyed push lowers to (table_layout.py
+        ``note_push``; at each step build, after the table's own record)
+        — a key of ``table_layout``."""
+        with self._lock:
+            t = self._tenant(job)
+            t.table_layout = {**(t.table_layout or {}),
+                              "push_lowering": lowering}
+
     def set_serving_state(self, job: str, attempt: Optional[str] = None,
                           *, enabled: bool,
                           qps: Optional[float] = None,

@@ -15,6 +15,16 @@ created or restored (jobserver/entity.py):
     metrics/accounting.py ``set_table_layout``); ``section_stride``
     is the trainer's where it has one (``PyTreeTrainer.section_stride``:
     rows between the ``[params | m | v]`` sections), else ``None``.
+
+And what a keyed tenant's push lowers to, recorded where its step program is
+built (dolphin/worker.py ``_build_step``; ``TableSpec.push_lowering``):
+
+  * ``harmony_table_push_pallas_rows{job,table}`` — 1 when the keyed push
+    is the in-place Pallas row scatter-add (ops.sparse.scatter_add_rows),
+    0 for any other lowering;
+  * STATUS ``tenants.<job>.table_layout.push_lowering`` = ``"pallas_rows"``
+    / ``"xla"``, or the route's name where the route is not ``scatter``
+    (``"mxu"``, ``"sparse"``, ``"mxu_auto"``).
 """
 from __future__ import annotations
 
@@ -48,3 +58,17 @@ def note(job: str, spec, section_stride: Optional[int] = None
         row["tile_exact"])
     ledger().set_table_layout(job, row)
     return row
+
+
+def note_push(job: str, table_id: str, lowering: str) -> None:
+    """Record what ``job``'s keyed push on ``table_id`` lowers to."""
+    from harmony_tpu.metrics.accounting import ledger
+    from harmony_tpu.metrics.registry import get_registry
+
+    get_registry().gauge(
+        "harmony_table_push_pallas_rows",
+        "1 when a tenant's keyed push is the in-place Pallas row "
+        "scatter-add, 0 for XLA's scatter or a fold route",
+        ("job", "table")).labels(job=job, table=table_id).set(
+            int(lowering == "pallas_rows"))
+    ledger().set_push_lowering(job, lowering)

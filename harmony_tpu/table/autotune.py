@@ -1,8 +1,11 @@
 """Measurement-driven push-route selection.
 
-The keyed additive push has two lowerings (TableSpec.push): XLA scatter
-(duplicate keys serialise on TPU) and the MXU duplicate-fold (one-hot
-segment-sum matmul + one dense add). Which wins depends on (capacity,
+The keyed additive push has two routes (TableSpec.push): "scatter" — on a
+TPU mesh over float32 rows 128 wide the in-place Pallas row scatter-add
+(ops.sparse.scatter_add_rows, PR 28; every measurement below predates it),
+elsewhere XLA's scatter (duplicate keys serialise on TPU) — and the MXU
+duplicate-fold (one-hot segment-sum matmul + one dense add). Which wins
+depends on (capacity,
 value width, dtype, key count, device) in ways a static heuristic gets
 wrong — the round-2 on-chip capture measured scatter 1.3x FASTER at the
 very shape the old ``capacity // 256`` gate routed to the MXU. So the

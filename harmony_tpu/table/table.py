@@ -160,6 +160,28 @@ def _gather_on_mesh(mesh: Mesh, shards: int, flat: jnp.ndarray,
                          out_specs=P(), check_vma=False)(flat, idx)
 
 
+def _scatter_on_mesh(mesh: Mesh, shards: int, flat: jnp.ndarray,
+                     idx: jnp.ndarray, deltas: jnp.ndarray) -> jnp.ndarray:
+    """The Pallas scatter-add partitioned by hand, in place: each device
+    updates the rows it holds (aliased shard by shard) from the keys that
+    land in them — ids are made shard-local, and the kernel drops the
+    foreign ones with every other id outside its rows. Nothing crosses
+    the model axis."""
+    from harmony_tpu.ops.sparse import scatter_add_rows
+
+    if mesh.devices.size == 1:
+        return scatter_add_rows(flat, idx, deltas)
+
+    def local(rows, ids, d):
+        if shards > 1:  # below this shard: negative; above, or -1: no row
+            ids = ids - jax.lax.axis_index(MODEL_AXIS) * rows.shape[0]
+        return scatter_add_rows(rows, ids, d)
+
+    rows = P(MODEL_AXIS) if shards > 1 else P()
+    return jax.shard_map(local, mesh=mesh, in_specs=(rows, P(), P()),
+                         out_specs=rows, check_vma=False)(flat, idx, deltas)
+
+
 def _fold_on_mesh(mesh: Optional[Mesh], shards: int, fold,
                   deltas: jnp.ndarray, idx: jnp.ndarray,
                   num_rows: int) -> jnp.ndarray:
@@ -316,6 +338,27 @@ class TableSpec:
         model = mesh.shape.get(MODEL_AXIS, 1)
         return mesh, (model if self.num_blocks % model == 0 else 1)
 
+    def push_lowering(self, n_keys: int) -> str:
+        """What ``push(via="scatter")`` of ``n_keys`` keys lowers to in
+        the program being traced: ``"pallas_rows"`` —
+        ops.sparse.scatter_add_rows, in place, per row shard — for an
+        additive update fn on a TPU mesh over rows the kernel takes,
+        stored in whole 8-row tiles (the kernel sees the storage as its
+        flat row matrix, which any other block size would copy, twice);
+        ``"xla"`` — one XLA scatter — for everything else."""
+        from harmony_tpu.config.params import TILE_ROWS
+        from harmony_tpu.ops import sparse
+
+        mesh, shards = self._kernel_layout()
+        rows = self.num_blocks * self.block_size // shards
+        if (mesh is not None and self.update_fn.scatter_mode == "add"
+                and self.block_size % TILE_ROWS == 0
+                and sparse.scatter_kernel_ok(
+                    (rows, sparse.value_width(self.value_shape)),
+                    self.dtype, n_keys)):
+            return "pallas_rows"
+        return "xla"
+
     def pull(self, arr: jnp.ndarray, keys: jnp.ndarray) -> jnp.ndarray:
         """multiGetOrInit: gather values for ``keys`` -> [n, *value_shape].
 
@@ -362,7 +405,14 @@ class TableSpec:
         per the update fn's scatter_mode.
 
         ``via`` picks the lowering of additive pushes:
-          * "scatter" — one XLA scatter (duplicate keys serialise on TPU).
+          * "scatter" — read-modify-write of the touched rows alone, in
+            place. Traced for a TPU mesh over float32 rows 128 wide it is
+            ops.sparse.scatter_add_rows (:meth:`push_lowering` says when):
+            the keys sorted a tile at a time, duplicates folded in VMEM in
+            occurrence order, each of a tile's distinct rows read and
+            written once by row DMA. Everywhere else, and for min / max /
+            set, one XLA scatter (duplicate keys serialise on TPU, 76 ns
+            a key whatever they repeat).
           * "mxu" — pre-fold duplicates with the one-hot segment-sum matmul
             (ops.histogram.segment_sum) and apply ONE dense add; the
             temporary is table-sized (memory is always affordable, but the
@@ -381,10 +431,10 @@ class TableSpec:
             so platform-aware callers resolve DenseTable.push_via and pass
             it explicitly.
 
-        The two folds are Pallas kernels when traced for a TPU mesh
-        (utils.platform.on_mesh), run per row shard; their XLA references
-        (histogram.xla_histogram, sparse.segment_sum_rows_ref) everywhere
-        else.
+        The two folds and the scatter are Pallas kernels when traced for
+        a TPU mesh (utils.platform.on_mesh), run per row shard; their XLA
+        references (histogram.xla_histogram, sparse.segment_sum_rows_ref,
+        ``.at[].add``) everywhere else.
         """
         b, o = self.partitioner.locate(keys)
         mode = self.update_fn.scatter_mode
@@ -423,7 +473,19 @@ class TableSpec:
         if via != "scatter":
             raise ValueError(f"unknown push route {via!r}")
         ref = arr.at[b, o]
-        if mode == "add":
+        if self.push_lowering(keys.shape[0]) == "pallas_rows":
+            # what ``.at[b, o].add`` does with an index outside its axis:
+            # a negative one counts from the end, anything else is dropped
+            nb, bs = self.num_blocks, self.block_size
+            bb, oo = jnp.where(b < 0, b + nb, b), jnp.where(o < 0, o + bs, o)
+            inside = (bb >= 0) & (bb < nb) & (oo >= 0) & (oo < bs)
+            mesh, shards = self._kernel_layout()
+            out = _scatter_on_mesh(
+                mesh, shards, arr.reshape(nb * bs, -1),
+                jnp.where(inside, bb * bs + oo, -1).astype(jnp.int32),
+                deltas.reshape(keys.shape[0], -1).astype(arr.dtype),
+            ).reshape(arr.shape)
+        elif mode == "add":
             out = ref.add(deltas.astype(arr.dtype))
         elif mode == "min":
             out = ref.min(deltas.astype(arr.dtype))

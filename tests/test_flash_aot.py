@@ -76,6 +76,57 @@ def test_grouped_matmul_kernels_compile_for_v5e(one_chip, m, k, n, groups,
         "tpu_custom_call") == 1
 
 
+@pytest.mark.parametrize("model,capacity", [
+    (1, 1 << 24),   # criteo-fm.solo: 2^24 rows, 8.6 GB, on one chip
+    (4, 1 << 26),   # criteo-fm-x4.solo: 2^26 rows, 8.6 GB a chip
+])
+def test_keyed_push_compiles_in_place_for_v5e(one_chip, model, capacity):
+    """The scatter route of the keyed cells' push, 212,993 keys into
+    float32 rows 128 wide, compiled by Mosaic and XLA for a v5e: the Pallas
+    row scatter-add is there, the table is aliased onto the result whole,
+    and nothing table-sized is copied — on one chip and row-sharded over
+    four under ``shard_map``. (An unaliased table would not fit: 2 x 8.6 GB
+    on a 16 GB chip.)"""
+    import math
+    import re
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from harmony_tpu.config.params import TableConfig
+    from harmony_tpu.table.table import TableSpec, block_sharding
+    from harmony_tpu.utils.platform import traced_on
+
+    from jax.experimental import topologies
+
+    devices = topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices[:model]
+    mesh = Mesh(np.array(devices).reshape(1, model), ("data", "model"))
+    spec = TableSpec(TableConfig(table_id="keyed", capacity=capacity,
+                                 value_shape=(128,), num_blocks=256))
+    tsh = block_sharding(mesh, spec.num_blocks)
+    flat = NamedSharding(mesh, P())
+    n = 212_993
+    compiled = jax.jit(
+        traced_on(mesh, lambda a, k, d: spec.push(a, k, d, via="scatter")),
+        out_shardings=tsh, donate_argnums=0).lower(
+        jax.ShapeDtypeStruct(spec.storage_shape, spec.dtype, sharding=tsh),
+        jax.ShapeDtypeStruct((n,), jnp.int32, sharding=flat),
+        jax.ShapeDtypeStruct((n, 128), jnp.float32, sharding=flat)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "harmony_scatter_add_rows" in text
+    on_chip = math.prod(spec.storage_shape) // model
+    assert compiled.memory_analysis().alias_size_in_bytes == on_chip * 4
+    entry = text[text.index("\nENTRY "):]
+    moved = [
+        line.strip()[:120] for line in entry.splitlines()
+        for m in [re.search(r"= \w+\[([\d,]+)\]\S* (copy|scatter|add|fusion)\(",
+                            line)]
+        if m and math.prod(map(int, m.group(1).split(","))) == on_chip]
+    assert not moved, moved
+
+
 @pytest.mark.parametrize("optimizer", ["adam", "momentum"])
 def test_lm_step_leaves_the_table_in_its_stored_layout(one_chip, optimizer):
     """The small LM's fused PULL -> COMP -> PUSH step (the worker's

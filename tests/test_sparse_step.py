@@ -24,9 +24,12 @@ from harmony_tpu.dolphin import (
     TrainingDataProvider,
     WorkerTasklet,
 )
+from harmony_tpu.ops import sparse
 from harmony_tpu.ops.sparse import (
     gather_rows,
     gather_rows_ref,
+    scatter_add_rows,
+    scatter_add_rows_ref,
     segment_sum_rows,
     segment_sum_rows_ref,
 )
@@ -105,6 +108,97 @@ def test_kernels_refuse_shapes_they_cannot_tile():
                          jnp.zeros((4,), jnp.int32), 8, interpret=True)
 
 
+def _sequential_scatter_add(table, idx, deltas):
+    """The serial scatter-add: every in-range key's delta added to its
+    row in occurrence order, ``((row + d0) + d1) + …`` in float32."""
+    out = np.array(table)
+    for i, k in enumerate(np.asarray(idx)):
+        if 0 <= k < out.shape[0]:
+            out[k] = out[k] + np.asarray(deltas)[i]
+    return out
+
+
+def _criteo_like(rng, rows, n):
+    """About a quarter of the keys distinct: a few hot ids, a long tail."""
+    hot = rng.integers(0, 8, n)
+    tail = rng.integers(0, rows, n)
+    return np.where(rng.random(n) < 0.8, hot, tail)
+
+
+_SCATTER_CASES = {
+    # name: (table rows, keys(rng), integer-valued deltas?)
+    "criteo_like_repeats": (512, lambda r: _criteo_like(r, 512, 700), False),
+    "all_keys_equal": (64, lambda r: np.full(300, 7), False),
+    "all_keys_distinct": (512, lambda r: r.permutation(512)[:300], False),
+    "n_not_a_multiple_of_the_tile": (96, lambda r: r.integers(0, 96, 301),
+                                     False),
+    "n_smaller_than_one_tile": (96, lambda r: r.integers(0, 96, 5), False),
+    "one_key": (16, lambda r: np.array([3]), False),
+    "run_crossing_a_tile_boundary": (
+        64, lambda r: np.concatenate([r.integers(0, 64, 120), np.full(16, 9),
+                                      r.integers(0, 64, 120)]), False),
+    "key_recurring_in_every_tile": (
+        64, lambda r: np.where(np.arange(640) % 5 == 0, 11,
+                               r.integers(0, 64, 640)), False),
+    "out_of_range_ids_dropped": (
+        64, lambda r: np.concatenate([r.integers(0, 64, 200),
+                                      [64, 65, 1000, 2 ** 30]]), False),
+    "negative_ids_dropped_not_clamped": (
+        64, lambda r: np.concatenate([[-1, -64, -2 ** 30],
+                                      r.integers(-5, 64, 200)]), False),
+    "every_id_dropped": (64, lambda r: np.full(130, -1), False),
+    "integer_valued_deltas": (64, lambda r: r.integers(0, 64, 400), True),
+}
+
+
+@pytest.mark.parametrize("case", list(_SCATTER_CASES))
+def test_scatter_add_rows_kernel_is_the_serial_scatter_add(case, monkeypatch):
+    """The kernel's fold is SEQUENTIAL and in occurrence order (a stable
+    sort, then one add per slot on top of the table row), so it is held
+    to the serial scatter-add bit for bit — float deltas included — and
+    to XLA's own scatter, which on this backend folds the same way. Tiles
+    of 128 slots, so a few hundred keys cross several boundaries."""
+    monkeypatch.setattr(sparse, "_SCATTER_TILE", 128)
+    rows, make_keys, integral = _SCATTER_CASES[case]
+    rng = np.random.default_rng(sum(map(ord, case)))
+    idx = np.asarray(make_keys(rng), np.int32)
+    table = rng.standard_normal((rows, 128)).astype(np.float32)
+    deltas = (rng.integers(-4, 5, (idx.size, 128)) if integral
+              else rng.standard_normal((idx.size, 128))).astype(np.float32)
+    got = np.asarray(jax.jit(
+        lambda t, i, d: scatter_add_rows(t, i, d, interpret=True))(
+            table, idx, deltas))
+    np.testing.assert_array_equal(
+        got, _sequential_scatter_add(table, idx, deltas))
+    np.testing.assert_array_equal(
+        got, np.asarray(scatter_add_rows_ref(jnp.asarray(table), idx, deltas)))
+
+
+def test_scatter_add_rows_default_tile_takes_a_whole_batch():
+    """One tile at the shipped size (no patched tile): keys, positions
+    and deltas of one 4,096-slot block, with a tail."""
+    rng = np.random.default_rng(5)
+    idx = _criteo_like(rng, 2048, 5000).astype(np.int32)
+    table = rng.standard_normal((2048, 128)).astype(np.float32)
+    deltas = rng.standard_normal((5000, 128)).astype(np.float32)
+    got = np.asarray(scatter_add_rows(table, idx, deltas, interpret=True))
+    np.testing.assert_array_equal(
+        got, _sequential_scatter_add(table, idx, deltas))
+
+
+def test_scatter_kernel_refuses_shapes_it_cannot_tile():
+    ids = jnp.zeros((4,), jnp.int32)
+    for table, deltas in (
+            (jnp.zeros((8, 64), jnp.float32), jnp.zeros((4, 64))),
+            (jnp.zeros((8, 256), jnp.float32), jnp.zeros((4, 256))),
+            (jnp.zeros((8, 128), jnp.bfloat16),
+             jnp.zeros((4, 128), jnp.bfloat16)),
+            (jnp.zeros((8, 128), jnp.float32),
+             jnp.zeros((4, 128), jnp.bfloat16))):
+        with pytest.raises(ValueError, match="scatter_add_rows_ref"):
+            scatter_add_rows(table, ids, deltas, interpret=True)
+
+
 def _tpu_text(fn, *args):
     """StableHLO of ``fn`` cross-lowered for the TPU from this CPU host —
     runs the Pallas TPU front end (block-shape and memory-space checks)
@@ -127,6 +221,136 @@ def test_sparse_kernels_lower_for_tpu():
             lambda d, i, rows=rows: segment_sum_rows(d, i, rows),
             jnp.zeros((n, 128), jnp.float32), jnp.zeros((n,), jnp.int32))
         assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("n", [1, 100, 5000, 212993])
+def test_scatter_kernel_lowers_for_tpu(n):
+    """SMEM index blocks, a tail block of deltas, the aliased table: the
+    Pallas TPU front end takes them at one key, at a padded tile and at
+    the keyed cells' 212,993 (an odd count: the last block holds one
+    row)."""
+    text = _tpu_text(scatter_add_rows, jnp.zeros((1000, 128), jnp.float32),
+                     jnp.zeros((n,), jnp.int32),
+                     jnp.zeros((n, 128), jnp.float32))
+    assert "tpu_custom_call" in text
+
+
+def _spec128(**kw):
+    kw.setdefault("value_shape", (128,))
+    return TableSpec(TableConfig(table_id="s", capacity=1024, num_blocks=16,
+                                 **kw))
+
+
+@pytest.fixture()
+def as_tpu(monkeypatch):
+    """Steer the route choice as on a TPU mesh, with the kernel body in
+    the Pallas interpreter: every mesh "is" all-TPU, and the row kernels
+    are called with ``interpret=True``."""
+    import functools
+
+    from harmony_tpu.utils import platform
+
+    monkeypatch.setattr(platform, "mesh_is_tpu", lambda mesh: True)
+    for kernel in ("scatter_add_rows", "gather_rows"):
+        monkeypatch.setattr(sparse, kernel, functools.partial(
+            getattr(sparse, kernel), interpret=True))
+    monkeypatch.setattr(sparse, "_SCATTER_TILE", 256)
+    return platform.on_mesh
+
+
+def test_scatter_route_keeps_xla_off_tpu(mesh8):
+    """On a CPU mesh — and outside any mesh scope — the scatter route is
+    XLA's scatter: no custom call in the lowered push."""
+    from harmony_tpu.utils.platform import on_mesh
+
+    spec = _spec128()
+    args = (jnp.zeros(spec.storage_shape, jnp.float32),
+            jnp.zeros((300,), jnp.int32), jnp.zeros((300, 128), jnp.float32))
+    push = lambda a, k, d: spec.push(a, k, d, via="scatter")
+    assert spec.push_lowering(300) == "xla"
+    assert "custom_call" not in jax.jit(push).lower(*args).as_text()
+    with on_mesh(mesh8):
+        assert spec.push_lowering(300) == "xla"
+        text = jax.jit(push).lower(*args).as_text()
+    assert "custom_call" not in text and "scatter" in text
+
+
+@pytest.mark.parametrize("why,kw", [
+    ("narrower_rows", dict(value_shape=(64,))),
+    ("wider_rows", dict(value_shape=(256,))),
+    ("bf16_rows", dict(dtype="bfloat16")),
+    ("min_mode", dict(update_fn="min")),
+    ("max_mode", dict(update_fn="max")),
+    ("set_mode", dict(update_fn="assign")),
+])
+def test_scatter_kernel_predicate_refuses(why, kw, as_tpu, mesh8):
+    """Even traced for a TPU mesh the kernel is for additive float32 rows
+    128 wide; everything else keeps XLA's scatter."""
+    with as_tpu(mesh8):
+        assert _spec128().push_lowering(300) == "pallas_rows"
+        assert _spec128(**kw).push_lowering(300) == "xla"
+        assert _spec128().push_lowering(0) == "xla"
+
+
+def test_scatter_kernel_predicate_refuses_blocks_off_the_row_tile(as_tpu,
+                                                                  mesh8):
+    """Blocks that are not whole 8-row tiles: the flat row matrix the
+    kernel needs would be a copy of the table each way."""
+    spec = TableSpec(TableConfig(table_id="odd", capacity=1000,
+                                 value_shape=(128,), num_blocks=8))
+    assert spec.block_size % 8
+    with as_tpu(mesh8):
+        assert spec.push_lowering(300) == "xla"
+
+
+@pytest.mark.parametrize("mesh_name", ["one_device", "mesh_2x4"])
+def test_scatter_route_on_a_tpu_mesh_is_the_xla_scatter(mesh_name, as_tpu,
+                                                        devices, mesh8):
+    """The route as a TPU mesh takes it (rows ``P(model)``, ids made
+    shard-local, foreign ids dropped, each shard updated in place) against
+    the same push through XLA's scatter on unsharded rows: equal bit for
+    bit, dropped and negative keys included (``.at[b, o]`` counts a
+    negative block from the end)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from harmony_tpu.parallel import build_mesh
+    from harmony_tpu.table.table import block_sharding
+
+    mesh = (mesh8 if mesh_name == "mesh_2x4"
+            else build_mesh(devices[:1], data=1, model=1))
+    spec = _spec128()
+    rng = np.random.default_rng(11)
+    arr = jnp.asarray(rng.standard_normal(spec.storage_shape), jnp.float32)
+    keys = jnp.asarray(np.concatenate([
+        _criteo_like(rng, 1024, 900), [-1, -3, 1024, 5000, -2000]]), jnp.int32)
+    deltas = jnp.asarray(rng.standard_normal((keys.shape[0], 128)),
+                         jnp.float32)
+    want = spec.push(arr, keys, deltas, via="scatter")  # XLA: no mesh scope
+    push = jax.jit(lambda a, k, d: spec.push(a, k, d, via="scatter"))
+    with as_tpu(mesh):
+        assert spec.push_lowering(keys.shape[0]) == "pallas_rows"
+        got = push(jax.device_put(arr, block_sharding(mesh, spec.num_blocks)),
+                   keys, deltas)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_scatter_kernel_route_still_applies_the_post_hook(as_tpu, mesh8):
+    """An NMF-style non-negative table 128 wide: the clamp runs after the
+    kernel as it does after XLA's scatter."""
+    spec = _spec128(update_fn="add_nonneg")
+    assert spec.update_fn.post is not None
+    rng = np.random.default_rng(2)
+    arr = jnp.asarray(np.abs(rng.standard_normal(spec.storage_shape)),
+                      jnp.float32)
+    keys = jnp.asarray(_criteo_like(rng, 1024, 600), jnp.int32)
+    deltas = jnp.asarray(-2.0 * np.abs(rng.standard_normal((600, 128))),
+                         jnp.float32)
+    want = spec.push(arr, keys, deltas, via="scatter")
+    with as_tpu(mesh8):
+        assert spec.push_lowering(600) == "pallas_rows"
+        got = jax.jit(lambda a, k, d: spec.push(a, k, d, via="scatter"))(
+            arr, keys, deltas)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert float(np.asarray(got).min()) >= 0.0
 
 
 def test_spec_pull_matches_direct_gather(mesh8):
@@ -367,6 +591,54 @@ def test_step_matches_per_phase_accessor_loop(family, mesh_name, devices,
                                    rtol=0, atol=state_atol)
     else:
         np.testing.assert_array_equal(_state(step_table), _state(ref_table))
+
+
+@pytest.mark.parametrize("mesh_name", list(_MESHES))
+def test_worker_step_on_the_kernel_lowering(mesh_name, devices, monkeypatch,
+                                            request):
+    """A keyed FM tenant with 128-wide rows on the scatter route, through
+    ``WorkerTasklet``: as a CPU mesh lowers it (XLA's scatter) and as a
+    TPU mesh does (the Pallas row scatter-add, interpreted) the losses and
+    the table are equal bit for bit, and each run says which it took in
+    the tenant ledger (STATUS ``table_layout.push_lowering``) and the
+    gauge."""
+    from harmony_tpu.apps import widedeep
+    from harmony_tpu.metrics.accounting import ledger
+    from harmony_tpu.metrics.registry import get_registry
+    from harmony_tpu.parallel import build_mesh
+
+    data_ax, model_ax = _MESHES[mesh_name]
+    mesh = build_mesh(devices[:data_ax * model_ax], data=data_ax,
+                      model=model_ax)
+    monkeypatch.setenv("HARMONY_PUSH_VIA", "scatter")
+
+    def run():
+        trainer = widedeep.FMTrainer(vocab_size=2047, num_slots=4,
+                                     emb_dim=127)
+        losses, table = _run_worker(
+            trainer, widedeep.make_synthetic(64, 2047, 4, seed=4), mesh, 2, 4)
+        layout = ledger().snapshot()["j-step"]["table_layout"]
+        gauge = [line for line in get_registry().expose().splitlines()
+                 if line.startswith("harmony_table_push_pallas_rows{")
+                 and 'job="j-step"' in line]
+        return losses, _state(table), layout["push_lowering"], gauge
+
+    losses, state, lowering, gauge = run()
+    assert lowering == "xla" and gauge[0].endswith(" 0"), (lowering, gauge)
+    request.getfixturevalue("as_tpu")
+    from harmony_tpu.runtime import progcache
+
+    progcache.clear()  # the step's key names the route, not its lowering
+    traced, kernel = [], sparse.scatter_add_rows
+    monkeypatch.setattr(
+        sparse, "scatter_add_rows",
+        lambda *a, **kw: traced.append(a[1].shape) or kernel(*a, **kw))
+    k_losses, k_state, lowering, gauge = run()
+    assert traced  # the step really was built on the kernel
+    assert lowering == "pallas_rows" and gauge[0].endswith(" 1"), (
+        lowering, gauge)
+    assert k_losses == losses
+    np.testing.assert_array_equal(k_state, state)
 
 
 # ---------------------------------------------------------------------------
