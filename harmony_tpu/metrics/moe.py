@@ -6,7 +6,8 @@ metric drain hands to the trainer (``Trainer.observe_step_vectors``) and the
 trainer hands here:
 
   * ``harmony_moe_expert_tokens_total{job,layer,expert}`` — token-slots routed
-    to every expert the ROUTER scores (held on this device or not);
+    to every expert the ROUTER scores (held on this device or not); ``layer``
+    is the block's index in the model, expert layers only;
   * ``harmony_moe_held_slots_total{job}`` — those of them routed to experts
     this device holds (the rows its grouped matmuls computed);
   * ``harmony_moe_experts_held{job}`` — how many experts (0 .. n-1) it holds.
@@ -23,7 +24,7 @@ and the most loaded held expert's tokens over the held experts' mean.
 """
 from __future__ import annotations
 
-from typing import Dict
+from typing import Dict, Optional, Sequence
 
 import numpy as np
 
@@ -45,8 +46,12 @@ def _families():
                 ("job",)))
 
 
-def observe(job: str, expert_tokens: np.ndarray, experts_held: int) -> None:
-    """Add ``expert_tokens [steps, layers, experts]`` to the counters."""
+def observe(job: str, expert_tokens: np.ndarray, experts_held: int,
+            layers: Optional[Sequence[int]] = None) -> None:
+    """Add ``expert_tokens [steps, expert layers, experts]`` to the counters;
+    ``layers`` are those layers' block indices (``TransformerConfig.
+    moe_layers()``: the ``layer`` label is the block's index, so a leading
+    dense block has no row at all; None: every block is an expert layer)."""
     from harmony_tpu.tracing import trace_span
 
     by_step = np.asarray(expert_tokens, np.float64)
@@ -56,7 +61,7 @@ def observe(job: str, expert_tokens: np.ndarray, experts_held: int) -> None:
                     held_slots="/".join(str(int(n)) for n in held_by_step)):
         per = by_step.sum(axis=0)  # [layers, E]
         tokens, held_slots, held = _families()
-        for layer, row in enumerate(per):
+        for layer, row in zip(layers or range(len(per)), per, strict=True):
             for expert, n in enumerate(row):
                 tokens.labels(job=job, layer=str(layer),
                               expert=str(expert)).inc(float(n))

@@ -11,7 +11,15 @@ tokens weighted by the gates. A device may hold only the first
 router still scores all of them, slots routed to an absent expert sort last
 and their tiles are never visited, and the layer's output is this device's
 part of the sum. Returns the routing statistics the auxiliary losses and the
-counters need.
+counters need. DeepSeek-V3's router (Moonlight's,
+``perf/configs/moonlight-16b-a3b.json``) enters BEFORE that body and leaves
+it as it is: ``score="sigmoid"`` scores each expert by a sigmoid, selects
+the top ``k`` of ``score + bias`` (a per-expert selection bias that carries
+no gradient and never reaches a weight), ``norm_topk`` divides the chosen
+experts' scores by their sum (over all ``k`` chosen, held here or not) and
+``routed_scale`` multiplies them; ``shared_experts`` adds one gated-SiLU MLP
+of ``shared_experts x d_ff`` to every token BESIDE the routed sum;
+``seq_aux`` adds the per-sequence balance statistic.
 
 **Switch top-1 with capacity** (:func:`moe_ffn` — the older path, with
 expert parallelism over a mesh axis): bounded per-expert capacity,
@@ -139,20 +147,36 @@ class DroplessConfig:
     d_model: int
     d_ff: int                 # one expert's width
     experts_held: int         # experts 0 .. experts_held-1 live here
+    score: str = "softmax"    # "softmax" | "sigmoid" (+ the selection bias)
+    norm_topk: bool = False   # chosen scores / their sum (+ 1e-20)
+    routed_scale: float = 1.0
+    shared_experts: int = 0   # one MLP of this many expert widths, all tokens
+    seq_aux: bool = False     # stats carry the sequence-wise balance term
 
 
 def init_dropless_params(rng: jax.Array, cfg: DroplessConfig
                          ) -> Dict[str, jnp.ndarray]:
     """Router over ALL experts; gate/up/down weights of the HELD experts,
-    expert-stacked on the leading dim."""
+    expert-stacked on the leading dim. A sigmoid router has its selection
+    ``bias [E]`` (zeros: the optimizer leaves it there, its gradient is 0);
+    shared experts are ``shared_wg`` / ``_wu`` / ``_wd``."""
     kr, kg, ku, kd = jax.random.split(rng, 4)
     E, H, d, f = cfg.num_experts, cfg.experts_held, cfg.d_model, cfg.d_ff
-    return {
+    params = {
         "router": jax.random.normal(kr, (d, E), jnp.float32) * (d ** -0.5),
         "wg": jax.random.normal(kg, (H, d, f), jnp.float32) * (d ** -0.5),
         "wu": jax.random.normal(ku, (H, d, f), jnp.float32) * (d ** -0.5),
         "wd": jax.random.normal(kd, (H, f, d), jnp.float32) * (f ** -0.5),
     }
+    if cfg.score == "sigmoid":
+        params["bias"] = jnp.zeros((E,), jnp.float32)
+    if cfg.shared_experts:
+        fs = cfg.shared_experts * f
+        ksg, ksu, ksd = jax.random.split(jax.random.fold_in(rng, 1), 3)
+        params["shared_wg"] = jax.random.normal(ksg, (d, fs), jnp.float32) * (d ** -0.5)
+        params["shared_wu"] = jax.random.normal(ksu, (d, fs), jnp.float32) * (d ** -0.5)
+        params["shared_wd"] = jax.random.normal(ksd, (fs, d), jnp.float32) * (fs ** -0.5)
+    return params
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -175,31 +199,74 @@ _slot_rows.defvjp(lambda x, order, inv, k: (x[order // k], (inv,)),
                   _slot_rows_bwd)
 
 
-def moe_ffn_dropless(params: Dict[str, jnp.ndarray], x: jnp.ndarray,
-                     cfg: DroplessConfig
-                     ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
-    """``(out [T, d], stats)`` for ``x [T, d]``. ``out`` sums, per token,
-    ``p_e * down_e(silu(gate_e x) * up_e x)`` over the token's top-k experts
-    that are held here. ``stats``: ``tokens [E]`` (token-slots each expert
-    was chosen for), ``prob_sum [E]`` (sum over tokens of the router's
-    probability), ``z_sum`` (sum over tokens of logsumexp(logits)^2) and
-    ``n`` (tokens) — sums, so layers add before a mean is taken."""
-    from harmony_tpu.ops.grouped_matmul import grouped_matmul
-
-    T, d = x.shape
-    E, k, H = cfg.num_experts, cfg.top_k, cfg.experts_held
+def _route(params, x, cfg: DroplessConfig, seqs: int):
+    """``(gate [T, k], expert [T, k], slot_expert [T * k], tokens [E] int32,
+    stats)``: each token's chosen experts (also slot by slot) and their
+    weights, and the token-slots each expert was chosen for, by the
+    configuration's router.
+    ``stats`` are sums, so layers add before a mean is taken: ``tokens [E]``
+    (token-slots each expert was chosen for), ``n`` (tokens), ``prob_sum
+    [E]`` (sum over tokens of the router's probability; a sigmoid router's
+    scores over their sum) and, softmax only, ``z_sum`` (sum over tokens of
+    logsumexp(logits)^2). ``seq_aux`` adds ``seq_lb``: the mean over this
+    layer's ``seqs`` sequences of ``sum_e f_e P_e`` (arXiv:2412.19437 eq.
+    17-20: ``f_e = E / (k S) x`` the sequence's slots that chose ``e``, bias
+    included, a count with no gradient; ``P_e`` the sequence's mean
+    normalised score) — a mean already, so layers ADD it."""
+    T = x.shape[0]
+    E, k = cfg.num_experts, cfg.top_k
     # a tiny matmul deciding discrete routes: full float32 passes on the MXU
     logits = jnp.dot(x.astype(jnp.float32), params["router"],
                      precision=lax.Precision.HIGHEST)            # [T, E]
-    lse = jax.nn.logsumexp(logits, axis=-1)
-    probs = jnp.exp(logits - lse[:, None])
-    gate, expert = lax.top_k(probs, k)                           # [T, k]
+    if cfg.score == "softmax":
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        probs = jnp.exp(logits - lse[:, None])
+        gate, expert = lax.top_k(probs, k)                       # [T, k]
+    else:
+        score = jax.nn.sigmoid(logits)
+        # the bias moves WHICH experts are chosen; weights are the scores
+        _, expert = lax.top_k(score + lax.stop_gradient(params["bias"]), k)
+        gate = jnp.take_along_axis(score, expert, axis=1)
+        probs = score / score.sum(axis=-1, keepdims=True)
+    if cfg.norm_topk:  # over all k chosen, held here or not
+        gate = gate / (gate.sum(axis=-1, keepdims=True) + 1e-20)
+    if cfg.routed_scale != 1.0:
+        gate = gate * cfg.routed_scale
     slot_expert = expert.reshape(-1)                             # [T * k]
     # a compare-and-sum, not a scatter-add (which a TPU serialises)
-    tokens = jnp.sum(slot_expert[:, None] == jnp.arange(E)[None, :], axis=0,
-                     dtype=jnp.int32)
+    if cfg.seq_aux:
+        by_seq = jnp.sum(slot_expert.reshape(seqs, -1)[:, :, None]
+                         == jnp.arange(E)[None, None, :], axis=1,
+                         dtype=jnp.int32)                        # [seqs, E]
+        tokens = by_seq.sum(axis=0)
+        f = by_seq.astype(jnp.float32) * (E / (k * (T // seqs)))
+        p = probs.reshape(seqs, T // seqs, E).mean(axis=1)
+        seq_lb = jnp.sum(f * p, axis=-1).mean()
+    else:
+        tokens = jnp.sum(slot_expert[:, None] == jnp.arange(E)[None, :],
+                         axis=0, dtype=jnp.int32)
     stats = {"tokens": tokens.astype(jnp.float32), "n": jnp.float32(T),
-             "prob_sum": probs.sum(axis=0), "z_sum": jnp.sum(lse * lse)}
+             "prob_sum": probs.sum(axis=0)}
+    if cfg.score == "softmax":
+        stats["z_sum"] = jnp.sum(lse * lse)
+    if cfg.seq_aux:
+        stats["seq_lb"] = seq_lb
+    return gate, expert, slot_expert, tokens, stats
+
+
+def moe_ffn_dropless(params: Dict[str, jnp.ndarray], x: jnp.ndarray,
+                     cfg: DroplessConfig, seqs: int = 1
+                     ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+    """``(out [T, d], stats)`` for ``x [T, d]`` (``seqs`` sequences of
+    ``T // seqs`` tokens, in order). ``out`` sums, per token,
+    ``w_e * down_e(silu(gate_e x) * up_e x)`` over the token's top-k experts
+    that are held here (``w_e``: :func:`_route`), plus the shared MLP where
+    the configuration has one. ``stats``: :func:`_route`'s."""
+    from harmony_tpu.ops.grouped_matmul import grouped_matmul
+
+    T, d = x.shape
+    k, H = cfg.top_k, cfg.experts_held
+    gate, expert, slot_expert, tokens, stats = _route(params, x, cfg, seqs)
     # slots sorted by expert: the held experts' runs come first (they are
     # experts 0 .. H-1), absent experts' slots after them
     order = jnp.argsort(slot_expert, stable=True)
@@ -214,4 +281,9 @@ def moe_ffn_dropless(params: Dict[str, jnp.ndarray], x: jnp.ndarray,
     weight = jnp.where(expert < H, gate, 0.0)                    # [T, k]
     y = _slot_rows(y, inv, order, 1).reshape(T, k, d)
     out = jnp.einsum("tkd,tk->td", y.astype(jnp.float32), weight)
-    return out.astype(dtype), stats
+    out = out.astype(dtype)
+    if cfg.shared_experts:  # plain matmuls on every token, beside the sum
+        hs = (jax.nn.silu(x @ params["shared_wg"].astype(dtype))
+              * (x @ params["shared_wu"].astype(dtype)))
+        out = out + hs @ params["shared_wd"].astype(dtype)
+    return out, stats

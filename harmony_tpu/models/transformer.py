@@ -80,6 +80,28 @@ class TransformerConfig:
     moe_top_k: int = 0
     moe_experts_held: Optional[int] = None
     moe_z_weight: float = 0.0
+    # DeepSeek-V3's block (Moonlight's): latent attention — q heads of
+    # [qk_nope | qk_rope], keys and values decompressed from ONE
+    # ``kv_lora_rank``-wide normed latent a token plus ONE rotary key every
+    # head shares, values ``v_head_dim`` wide (``d_model // n_heads`` plays
+    # no part); ``moe_first_dense`` leading dense layers of width
+    # ``dense_d_ff`` (``d_ff`` stays one expert's width); the router scores
+    # by sigmoid, selects with a gradient-free bias, renormalises the
+    # chosen scores and scales them (models/moe.py); ``moe_shared_experts``
+    # experts' width of MLP on every token; the sequence-wise balance loss
+    # joins at ``moe_aux_weight`` in place of the batch-wise one.
+    attn_kind: str = "mha"          # "mha" | "mla"
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    moe_first_dense: int = 0
+    dense_d_ff: int = 0
+    moe_shared_experts: int = 0
+    moe_score: str = "softmax"      # "softmax" | "sigmoid"
+    moe_norm_topk: bool = False
+    moe_routed_scale: float = 1.0
+    moe_seq_aux: bool = False
 
     def __post_init__(self):
         from harmony_tpu.models.common import validate_attn
@@ -94,9 +116,26 @@ class TransformerConfig:
             raise ValueError(f"unknown pos {self.pos!r}: 'learned' or 'rope'")
         if self.ffn not in ("gelu", "swiglu"):
             raise ValueError(f"unknown ffn {self.ffn!r}: 'gelu' or 'swiglu'")
-        if self.pos == "rope" and self.head_dim % 2:
+        mla = (self.kv_lora_rank, self.qk_nope_head_dim,
+               self.qk_rope_head_dim, self.v_head_dim)
+        if self.attn_kind not in ("mha", "mla"):
+            raise ValueError(f"unknown attn_kind {self.attn_kind!r}: 'mha' "
+                             "or 'mla'")
+        if self.attn_kind == "mha" and any(mla):
+            raise ValueError("kv_lora_rank / qk_nope_head_dim / "
+                             "qk_rope_head_dim / v_head_dim belong to latent "
+                             "attention: set attn_kind='mla'")
+        if self.attn_kind == "mla" and (
+                min(mla) < 1 or self.pos != "rope" or self.qk_norm):
+            raise ValueError(
+                "attn_kind='mla' needs kv_lora_rank, qk_nope_head_dim, "
+                "qk_rope_head_dim and v_head_dim, pos='rope' (rotary on the "
+                "qk_rope parts) and no qk_norm (the latent has its own norm)")
+        turned = (self.qk_rope_head_dim if self.attn_kind == "mla"
+                  else self.head_dim)  # the width rotary positions turn
+        if self.pos == "rope" and turned % 2:
             raise ValueError(f"rotary positions rotate pairs: head width "
-                             f"{self.head_dim} is odd")
+                             f"{turned} is odd")
         if not 0 <= self.moe_top_k <= self.moe_experts:
             raise ValueError(f"moe_top_k {self.moe_top_k} must lie in 0.."
                              f"moe_experts ({self.moe_experts})")
@@ -111,13 +150,55 @@ class TransformerConfig:
                 1 <= self.moe_experts_held <= self.moe_experts):
             raise ValueError(f"moe_experts_held {self.moe_experts_held} must "
                              f"lie in 1..moe_experts ({self.moe_experts})")
+        if self.moe_score not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown moe_score {self.moe_score!r}: "
+                             "'softmax' or 'sigmoid'")
+        if not self.moe_top_k and (
+                self.moe_shared_experts or self.moe_score != "softmax"
+                or self.moe_norm_topk or self.moe_routed_scale != 1.0
+                or self.moe_seq_aux):
+            raise ValueError(
+                "moe_shared_experts / moe_score / moe_norm_topk / "
+                "moe_routed_scale / moe_seq_aux belong to dropless routing: "
+                "set moe_top_k")
+        if self.moe_score == "sigmoid" and not self.moe_seq_aux:
+            raise ValueError("moe_score='sigmoid' is DeepSeek-V3's router: "
+                             "its balance loss is the sequence-wise one "
+                             "(moe_seq_aux)")
+        if self.moe_seq_aux and self.moe_z_weight:
+            raise ValueError("moe_seq_aux replaces the batch-wise balance "
+                             "loss and has no router z-loss: moe_z_weight "
+                             "must be 0")
+        if not 0 <= self.moe_first_dense <= self.n_layers or (
+                self.moe_first_dense and not self.moe_experts):
+            raise ValueError(
+                f"moe_first_dense {self.moe_first_dense}: leading dense "
+                f"layers of an expert model, 0..n_layers ({self.n_layers})")
+        if self.dense_d_ff and not self.moe_first_dense:
+            raise ValueError("dense_d_ff is the width of the moe_first_dense "
+                             "leading layers: set moe_first_dense")
         validate_attn(self.attn)
 
     def is_moe_layer(self, i: int) -> bool:
-        """Block i uses the MoE FFN (the last of every ``moe_every`` group —
-        Switch interleaves dense and expert blocks)."""
-        return bool(self.moe_experts) and (i % self.moe_every
-                                           == self.moe_every - 1)
+        """Block i uses the MoE FFN: past the ``moe_first_dense`` leading
+        dense layers, the last of every ``moe_every`` group (Switch
+        interleaves dense and expert blocks)."""
+        return (bool(self.moe_experts) and i >= self.moe_first_dense
+                and i % self.moe_every == self.moe_every - 1)
+
+    def moe_layers(self):
+        """The indices of the blocks that are expert layers, in order — the
+        ONE answer to "which layers route": the trainer's vectors
+        (``moe_expert_tokens [len(moe_layers()), experts]``), the counters'
+        ``layer`` label (metrics/moe.py) and the benchmark's work functions
+        ask here, so a leading dense layer never shows as idle experts."""
+        return tuple(i for i in range(self.n_layers) if self.is_moe_layer(i))
+
+    def ffn_width(self, i: int) -> int:
+        """The dense MLP's width in block i (an expert's, in an expert
+        layer)."""
+        return (self.dense_d_ff or self.d_ff) if i < self.moe_first_dense \
+            else self.d_ff
 
     @property
     def moe_cfg(self):
@@ -135,7 +216,10 @@ class TransformerConfig:
         return DroplessConfig(
             num_experts=self.moe_experts, top_k=self.moe_top_k,
             d_model=self.d_model, d_ff=self.d_ff,
-            experts_held=self.moe_experts if held is None else held)
+            experts_held=self.moe_experts if held is None else held,
+            score=self.moe_score, norm_topk=self.moe_norm_topk,
+            routed_scale=self.moe_routed_scale,
+            shared_experts=self.moe_shared_experts, seq_aux=self.moe_seq_aux)
 
     @property
     def head_dim(self) -> int:
@@ -147,12 +231,14 @@ class TransformerConfig:
         experts if any)."""
         if not (self.pos == "learned" and self.ffn == "gelu"
                 and self.tie_embeddings and not self.qk_norm
-                and not self.moe_top_k):
+                and not self.moe_top_k and self.attn_kind == "mha"
+                and not self.moe_first_dense):
             raise ValueError(
                 f"{who} runs the GPT-2-era block only (learned positions, "
                 "GELU, tied readout, Switch experts); rotary / QK-norm / "
-                "SwiGLU / untied / dropless configs train through "
-                "TransformerLM.loss and TransformerTrainer")
+                "SwiGLU / untied / dropless / latent-attention / leading-"
+                "dense configs train through TransformerLM.loss and "
+                "TransformerTrainer")
 
 
 from harmony_tpu.models.common import rms_norm as _norm  # noqa: E402
@@ -192,12 +278,27 @@ class TransformerLM:
         layers = []
         for i, kl in enumerate(k_layers):
             ks = jax.random.split(kl, 4)
-            layer = {
-                "ln1": jnp.ones((d,), jnp.float32),
-                "wqkv": dense(ks[0], (d, 3 * d)),
-                "wo": dense(ks[1], (d, d)),
-                "ln2": jnp.ones((d,), jnp.float32),
-            }
+            f = cfg.ffn_width(i)
+            if cfg.attn_kind == "mla":
+                kq, ka, kb = jax.random.split(ks[0], 3)
+                nope, rot = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+                r, vd = cfg.kv_lora_rank, cfg.v_head_dim
+                layer = {
+                    "ln1": jnp.ones((d,), jnp.float32),
+                    "wq": dense(kq, (d, h * (nope + rot))),
+                    "wkv_a": dense(ka, (d, r + rot)),
+                    "kv_norm": jnp.ones((r,), jnp.float32),
+                    "wkv_b": dense(kb, (r, h * (nope + vd))),
+                    "wo": dense(ks[1], (h * vd, d)),
+                    "ln2": jnp.ones((d,), jnp.float32),
+                }
+            else:
+                layer = {
+                    "ln1": jnp.ones((d,), jnp.float32),
+                    "wqkv": dense(ks[0], (d, 3 * d)),
+                    "wo": dense(ks[1], (d, d)),
+                    "ln2": jnp.ones((d,), jnp.float32),
+                }
             if cfg.qk_norm:
                 layer["q_norm"] = jnp.ones((d,), jnp.float32)
                 layer["k_norm"] = jnp.ones((d,), jnp.float32)
@@ -243,12 +344,27 @@ class TransformerLM:
 
         layers = []
         for i in range(cfg.n_layers):
-            layer = {
-                "ln1": np.ones((d,), np.float32),
-                "wqkv": dense((d, 3 * d)),
-                "wo": dense((d, d)),
-                "ln2": np.ones((d,), np.float32),
-            }
+            f = cfg.ffn_width(i)
+            if cfg.attn_kind == "mla":
+                h, nope, rot = (cfg.n_heads, cfg.qk_nope_head_dim,
+                                cfg.qk_rope_head_dim)
+                r, vd = cfg.kv_lora_rank, cfg.v_head_dim
+                layer = {
+                    "ln1": np.ones((d,), np.float32),
+                    "wq": dense((d, h * (nope + rot))),
+                    "wkv_a": dense((d, r + rot)),
+                    "kv_norm": np.ones((r,), np.float32),
+                    "wkv_b": dense((r, h * (nope + vd))),
+                    "wo": dense((h * vd, d)),
+                    "ln2": np.ones((d,), np.float32),
+                }
+            else:
+                layer = {
+                    "ln1": np.ones((d,), np.float32),
+                    "wqkv": dense((d, 3 * d)),
+                    "wo": dense((d, d)),
+                    "ln2": np.ones((d,), np.float32),
+                }
             if cfg.qk_norm:
                 layer["q_norm"] = np.ones((d,), np.float32)
                 layer["k_norm"] = np.ones((d,), np.float32)
@@ -264,6 +380,13 @@ class TransformerLM:
                     layer["moe"] = {
                         "router": dense((d, E)), "wg": stacked(H, d, f),
                         "wu": stacked(H, d, f), "wd": stacked(H, f, d)}
+                    if cfg.moe_score == "sigmoid":
+                        layer["moe"]["bias"] = np.zeros((E,), np.float32)
+                    if cfg.moe_shared_experts:
+                        fs = cfg.moe_shared_experts * f
+                        layer["moe"].update(
+                            shared_wg=dense((d, fs)), shared_wu=dense((d, fs)),
+                            shared_wd=dense((fs, d)))
                 else:
                     layer["moe"] = {
                         "router": dense((d, E)), "w1": stacked(E, d, f),
@@ -297,10 +420,35 @@ class TransformerLM:
         S = q.shape[2]
         from harmony_tpu.models.common import flash_on_mesh, resolve_attn
 
-        attn = resolve_attn(cfg.attn, S, head_dim=q.shape[3], dtype=q.dtype)
+        attn = resolve_attn(cfg.attn, S, head_dim=q.shape[3],
+                            v_head_dim=v.shape[3], dtype=q.dtype)
         if attn == "flash":  # the kernels tile themselves from the shape
             return flash_on_mesh(q, k, v, causal=True)
         return blockwise_attention(q, k, v, causal=True)
+
+    def _latent_qkv(self, xn, layer, pos_offset):
+        """DeepSeek-V3's latent attention operands from the normed input
+        ``xn [B, S, d]``: ``q [B, h, S, nope + rope]``, ``k`` the same width
+        (each head's decompressed ``k_nope`` beside the ONE rotary key all
+        heads share) and ``v [B, h, S, v_head_dim]``. Rotary turns only the
+        ``rope``-wide parts; the softmax scale is ``(nope + rope) ** -0.5``,
+        the kernels' default for a q that wide."""
+        cfg = self.config
+        B, S = xn.shape[0], xn.shape[1]
+        h, nope, rot = cfg.n_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+        r, vd = cfg.kv_lora_rank, cfg.v_head_dim
+        to_heads = lambda t, w: t.reshape(B, S, h, w).transpose(0, 2, 1, 3)
+        q = to_heads(xn @ layer["wq"].astype(cfg.dtype), nope + rot)
+        ckv = xn @ layer["wkv_a"].astype(cfg.dtype)          # [B, S, r + rot]
+        c = _norm(ckv[..., :r], layer["kv_norm"].astype(cfg.dtype),
+                  cfg.norm_eps)
+        kv = to_heads(c @ layer["wkv_b"].astype(cfg.dtype), nope + vd)
+        q_pe = rope(q[..., nope:], cfg.rope_theta, pos_offset)
+        k_pe = rope(ckv[:, None, :, r:], cfg.rope_theta, pos_offset)
+        q = jnp.concatenate([q[..., :nope], q_pe], axis=-1)
+        k = jnp.concatenate(
+            [kv[..., :nope], jnp.broadcast_to(k_pe, (B, h, S, rot))], axis=-1)
+        return q, k, kv[..., nope:]
 
     def _block(self, x, layer, axis_name: Optional[str],
                moe_axis: Optional[str] = None, pos_offset: Any = 0):
@@ -316,18 +464,21 @@ class TransformerLM:
         d, h, hd = cfg.d_model, cfg.n_heads, cfg.head_dim
         eps = cfg.norm_eps
         xn = _norm(x, layer["ln1"].astype(cfg.dtype), eps)
-        qkv = xn @ layer["wqkv"].astype(cfg.dtype)              # [B, S, 3d]
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        if cfg.qk_norm:
-            q = _norm(q, layer["q_norm"].astype(cfg.dtype), eps)
-            k = _norm(k, layer["k_norm"].astype(cfg.dtype), eps)
-        to_heads = lambda t: t.reshape(B, S, h, hd).transpose(0, 2, 1, 3)
-        q, k, v = to_heads(q), to_heads(k), to_heads(v)
-        if cfg.pos == "rope":
-            q = rope(q, cfg.rope_theta, pos_offset)
-            k = rope(k, cfg.rope_theta, pos_offset)
+        if cfg.attn_kind == "mla":
+            q, k, v = self._latent_qkv(xn, layer, pos_offset)
+        else:
+            qkv = xn @ layer["wqkv"].astype(cfg.dtype)          # [B, S, 3d]
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+            if cfg.qk_norm:
+                q = _norm(q, layer["q_norm"].astype(cfg.dtype), eps)
+                k = _norm(k, layer["k_norm"].astype(cfg.dtype), eps)
+            to_heads = lambda t: t.reshape(B, S, h, hd).transpose(0, 2, 1, 3)
+            q, k, v = to_heads(q), to_heads(k), to_heads(v)
+            if cfg.pos == "rope":
+                q = rope(q, cfg.rope_theta, pos_offset)
+                k = rope(k, cfg.rope_theta, pos_offset)
         o = self._attention(q, k, v, axis_name)
-        o = o.transpose(0, 2, 1, 3).reshape(B, S, d)
+        o = o.transpose(0, 2, 1, 3).reshape(B, S, h * v.shape[3])
         x = x + o @ layer["wo"].astype(cfg.dtype)
         xn = _norm(x, layer["ln2"].astype(cfg.dtype), eps)
         out, aux = ffn_apply(cfg, layer, xn, moe_axis=moe_axis)
@@ -393,6 +544,10 @@ class TransformerLM:
         logits, aux = self._apply_with_aux(params, tokens[:, :-1],
                                            axis_name=axis_name)
         ce = _next_token_ce(logits, tokens[:, 1:])
+        if cfg.moe_seq_aux:  # each layer's mean over sequences, summed
+            return ce + cfg.moe_aux_weight * aux["seq_lb"], {
+                "ce": ce, "aux_seq": aux["seq_lb"],
+                "moe_expert_tokens": aux["tokens_by_layer"]}
         if cfg.moe_top_k:
             lb, z = routing_losses(aux, cfg.moe_experts)
             loss = ce + cfg.moe_aux_weight * lb + cfg.moe_z_weight * z
@@ -442,7 +597,7 @@ def ffn_apply(cfg, layer, xn, no_drop: bool = False,
 
         out, stats = moe_ffn_dropless(layer["moe"],
                                       xn.reshape(-1, cfg.d_model),
-                                      cfg.dropless_cfg)
+                                      cfg.dropless_cfg, seqs=xn.shape[0])
         return out.reshape(xn.shape), stats
     if "moe" in layer:
         import dataclasses as _dc
@@ -953,4 +1108,5 @@ class TransformerTrainer(PyTreeTrainer):
             from harmony_tpu.metrics import moe
 
             moe.observe(job_id, vectors["moe_expert_tokens"],
-                        self.config.dropless_cfg.experts_held)
+                        self.config.dropless_cfg.experts_held,
+                        self.config.moe_layers())

@@ -23,7 +23,10 @@ diagonal and masks only those the diagonal crosses.
 
 Layout: (batch, heads, seq, head_dim). Any head_dim compiles; VMEM tiles pad
 it to the 128-lane width, so 64 (GPT-2) fills half of each vector register
-and half of the MXU's contraction depth.
+and half of the MXU's contraction depth. ``v`` (and with it the output, dO
+and dV) may have a width of its own: latent attention multiplies 192-wide q
+and k and sums 128-wide values, and pays for neither a padded v nor a
+second lowering — equal widths trace the programs they always did.
 """
 from __future__ import annotations
 
@@ -83,12 +86,13 @@ def blockwise_attention(
 ) -> jnp.ndarray:
     """Streaming-softmax attention: scan over KV blocks carrying (acc, m, l).
 
-    q [B,H,Sq,D], k/v [B,H,Sk,D] -> [B,H,Sq,D]. O(Sq * block_k) live memory
-    instead of O(Sq*Sk); autodiff through the scan gives the memory-efficient
-    backward.
+    q [B,H,Sq,D], k [B,H,Sk,D], v [B,H,Sk,Dv] -> [B,H,Sq,Dv] (``Dv`` may
+    differ from ``D``: latent attention's 192-wide q.k beside a 128-wide v).
+    O(Sq * block_k) live memory instead of O(Sq*Sk); autodiff through the
+    scan gives the memory-efficient backward.
     """
     B, H, Sq, D = q.shape
-    Sk = k.shape[2]
+    Sk, Dv = k.shape[2], v.shape[3]
     scale = scale if scale is not None else D ** -0.5
     block_k = min(block_k, Sk)
     nk, rem = divmod(Sk, block_k)
@@ -98,7 +102,7 @@ def blockwise_attention(
         v = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0)))
         nk += 1
     kb = k.reshape(B, H, nk, block_k, D).transpose(2, 0, 1, 3, 4)
-    vb = v.reshape(B, H, nk, block_k, D).transpose(2, 0, 1, 3, 4)
+    vb = v.reshape(B, H, nk, block_k, Dv).transpose(2, 0, 1, 3, 4)
 
     qf = q.astype(jnp.float32) * scale
     q_pos = jnp.arange(Sq)[:, None]
@@ -124,7 +128,8 @@ def blockwise_attention(
     # Derive the init carry from qf so its varying-axes type matches under
     # shard_map (plain zeros are "unvarying" and fail the scan's vma check
     # when attention runs inside a manual-axes region, e.g. a pipeline stage).
-    acc0 = jnp.zeros_like(qf)
+    acc0 = jnp.zeros_like(qf) if Dv == D else jnp.broadcast_to(
+        jnp.zeros_like(qf[..., :1]), (B, H, Sq, Dv))
     m0 = jnp.full_like(qf[..., 0], _NEG_INF)
     l0 = jnp.zeros_like(qf[..., 0])
     starts = jnp.arange(nk) * block_k
@@ -176,35 +181,40 @@ def _temp_bytes(kernel, resident, sub):
     return (3 if kernel == "fwd" else 5) * resident * sub * 4
 
 
-def _vmem_bytes(kernel, block_q, block_k, sub, d, itemsize):
+def _vmem_bytes(kernel, block_q, block_k, sub, d, itemsize, dv=None):
     """VMEM one grid step of ``kernel`` needs, in bytes: the BlockSpec tiles
-    double-buffered (q/k/v/do and the outputs in the operands' dtype, the
-    lane-replicated statistics in f32), the f32 accumulators, and the
-    temporaries of one sub-block. A tile's last dim pads to the lane width
-    in VMEM."""
+    double-buffered (q/k/dq/dk ``d`` wide, v/o/do/dv ``dv`` wide, in the
+    operands' dtype; the lane-replicated statistics in f32), the f32
+    accumulators, and the temporaries of one sub-block. A tile's last dim
+    pads to the lane width in VMEM: a 192-wide tile counts as 256 lanes."""
     dl = -(-d // _LANES) * _LANES
-    opnd = lambda rows: rows * dl * itemsize
+    dvl = dl if dv is None else -(-dv // _LANES) * _LANES
+    qk = lambda rows: rows * dl * itemsize
+    vo = lambda rows: rows * dvl * itemsize
     stat = lambda rows: rows * _LANES * 4
-    if kernel == "fwd":
-        tiles = 2 * opnd(block_q) + 2 * opnd(block_k) + stat(block_q)
-        scratch = 2 * stat(block_q) + block_q * dl * 4
-    elif kernel == "dkv":
-        tiles = 2 * opnd(block_q) + 2 * stat(block_q) + 4 * opnd(block_k)
-        scratch = 2 * block_k * dl * 4
-    else:  # dq
-        tiles = 3 * opnd(block_q) + 2 * stat(block_q) + 2 * opnd(block_k)
+    if kernel == "fwd":  # q, o | k, v
+        tiles = (qk(block_q) + vo(block_q) + qk(block_k) + vo(block_k)
+                 + stat(block_q))
+        scratch = 2 * stat(block_q) + block_q * dvl * 4
+    elif kernel == "dkv":  # q, do | k, dk, v, dv
+        tiles = (qk(block_q) + vo(block_q) + 2 * stat(block_q)
+                 + 2 * qk(block_k) + 2 * vo(block_k))
+        scratch = block_k * (dl + dvl) * 4
+    else:  # dq: q, dq, do | k, v
+        tiles = (2 * qk(block_q) + vo(block_q) + 2 * stat(block_q)
+                 + qk(block_k) + vo(block_k))
         scratch = block_q * dl * 4
     resident = block_k if kernel == "dkv" else block_q
     return 2 * tiles + scratch + _temp_bytes(kernel, resident, sub)
 
 
-def _with_limit(kernel, block_q, block_k, sub, d, itemsize):
-    need = _vmem_bytes(kernel, block_q, block_k, sub, d, itemsize)
+def _with_limit(kernel, block_q, block_k, sub, d, itemsize, dv):
+    need = _vmem_bytes(kernel, block_q, block_k, sub, d, itemsize, dv)
     limit = None if need <= _VMEM_FREE else need + _VMEM_DEFAULT
     return Tiles(block_q, block_k, sub, limit)
 
 
-def _plan_kernel(kernel, sq, sk, d, itemsize):
+def _plan_kernel(kernel, sq, sk, d, itemsize, dv):
     """Largest tiles that divide the lengths and fit the budget: the
     resident block first, then the widest sub-block whose temporaries stay
     under ``_TEMPS_MAX``, then as much of the streamed length as fits
@@ -224,18 +234,21 @@ def _plan_kernel(kernel, sq, sk, d, itemsize):
                 if (str_len // sub) % n:
                     continue
                 bq, bk = (n * sub, res) if kernel == "dkv" else (res, n * sub)
-                if _vmem_bytes(kernel, bq, bk, sub, d, itemsize) <= _VMEM_CAP:
-                    return _with_limit(kernel, bq, bk, sub, d, itemsize)
+                if _vmem_bytes(kernel, bq, bk, sub, d, itemsize,
+                               dv) <= _VMEM_CAP:
+                    return _with_limit(kernel, bq, bk, sub, d, itemsize, dv)
     return None
 
 
-def tile_plan(sq, sk, d, dtype, causal=False, block_q=None, block_k=None):
-    """The tiles of the three kernels for q [.., sq, d] against k/v
-    [.., sk, d], or None where the kernels cannot tile the lengths: a
-    length over ``_ONE_BLOCK`` must divide by 128. The ONE gate: the kernels
-    raise where this returns None, and ``models.common.flash_ok`` asks here.
+def tile_plan(sq, sk, d, dtype, causal=False, block_q=None, block_k=None,
+              dv=None):
+    """The tiles of the three kernels for q [.., sq, d] against k
+    [.., sk, d] and v [.., sk, dv] (``dv`` None: ``d``), or None where the
+    kernels cannot tile the lengths: a length over ``_ONE_BLOCK`` must
+    divide by 128. The ONE gate: the kernels raise where this returns None,
+    and ``models.common.flash_ok`` asks here.
 
-    Inputs are what a trace can observe — lengths, head width, operand
+    Inputs are what a trace can observe — lengths, both head widths, operand
     dtype — and the budget is VMEM (``_vmem_bytes``); a plan over Mosaic's
     default carries ``vmem_limit_bytes`` instead of shrinking. Explicit
     ``block_q`` / ``block_k`` win over the plan and are taken as given (one
@@ -244,22 +257,27 @@ def tile_plan(sq, sk, d, dtype, causal=False, block_q=None, block_k=None):
     loop bounds carry the causal skip at sub-block grain."""
     del causal
     itemsize = jnp.dtype(dtype).itemsize
+    dv = d if dv is None else dv
     if block_q is not None or block_k is not None:
         bq = min(block_q or block_k, sq)  # one given alone stands for both
         bk = min(block_k or block_q, sk)
         if sq % bq or sk % bk:
             return None
-        return TilePlan(_with_limit("fwd", bq, bk, bk, d, itemsize),
-                        _with_limit("dkv", bq, bk, bq, d, itemsize),
-                        _with_limit("dq", bq, bk, bk, d, itemsize), False)
-    tiles = [_plan_kernel(kern, sq, sk, d, itemsize)
+        return TilePlan(_with_limit("fwd", bq, bk, bk, d, itemsize, dv),
+                        _with_limit("dkv", bq, bk, bq, d, itemsize, dv),
+                        _with_limit("dq", bq, bk, bk, d, itemsize, dv), False)
+    tiles = [_plan_kernel(kern, sq, sk, d, itemsize, dv)
              for kern in ("fwd", "dkv", "dq")]
     return None if None in tiles else TilePlan(*tiles, True)
 
 
-def _require_plan(q, k, causal, block_q, block_k):
+def _require_plan(q, k, v, causal, block_q, block_k):
     sq, sk, d = q.shape[2], k.shape[2], q.shape[3]
-    plan = tile_plan(sq, sk, d, q.dtype, causal, block_q, block_k)
+    if k.shape[3] != d:
+        raise ValueError(f"flash attention: q is {d} wide and k "
+                         f"{k.shape[3]}; only v may have a width of its own")
+    plan = tile_plan(sq, sk, d, q.dtype, causal, block_q, block_k,
+                     dv=v.shape[3])
     if plan is None:
         raise ValueError(
             f"flash attention cannot tile seq lens ({sq},{sk})"
@@ -269,7 +287,7 @@ def _require_plan(q, k, causal, block_q, block_k):
     return plan
 
 
-def _note_plan(plan, kernels, bh, sq, sk):
+def _note_plan(plan, kernels, bh, sq, sk, d, dv):
     """Trace-time record of the tiling a compiled program runs — the plan is
     static per shape, so it engages always or never; STATUS ``kernel_plans``
     (beside ``compiles``) says which one a job got. Never fails a trace."""
@@ -280,7 +298,8 @@ def _note_plan(plan, kernels, bh, sq, sk):
             t = getattr(plan, kern)
             note_kernel_plan(
                 _KERNEL_NAMES[kern], t.block_q, t.block_k, t.sub,
-                bh * (sq // t.block_q) * (sk // t.block_k), plan.planned)
+                bh * (sq // t.block_q) * (sk // t.block_k), plan.planned,
+                d=d, dv=dv)
     except Exception:
         pass
 
@@ -435,11 +454,11 @@ def _first_needed_q(causal, block_q, block_k, nq):
 @functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
 def _flash_forward(q, k, v, causal, tiles, scale, interpret):
     B, H, Sq, D = q.shape
-    Sk = k.shape[2]
+    Sk, Dv = k.shape[2], v.shape[3]
     block_q, block_k, sub = tiles[:3]
     qf = q.reshape(B * H, Sq, D)
     kf = k.reshape(B * H, Sk, D)
-    vf = v.reshape(B * H, Sk, D)
+    vf = v.reshape(B * H, Sk, Dv)
     grid = (B * H, Sq // block_q, Sk // block_k)
     kernel = functools.partial(
         _fa_kernel, scale=scale, causal=causal,
@@ -453,25 +472,25 @@ def _flash_forward(q, k, v, causal, tiles, scale, interpret):
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, kv_j(i, j), 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, kv_j(i, j), 0)),
+            pl.BlockSpec((1, block_k, Dv), lambda b, i, j: (b, kv_j(i, j), 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, Dv), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_q, _LANES), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
-            _out_struct((B * H, Sq, D), q.dtype, q, k, v),
+            _out_struct((B * H, Sq, Dv), q.dtype, q, k, v),
             _out_struct((B * H, Sq, _LANES), jnp.float32, q, k, v),
         ],
         scratch_shapes=[
             _vmem((block_q, _LANES)),   # running row-max m
             _vmem((block_q, _LANES)),   # running normaliser l
-            _vmem((block_q, D)),        # unnormalised output accumulator
+            _vmem((block_q, Dv)),       # unnormalised output accumulator
         ],
         interpret=interpret,
         **_compiler_params(tiles),
     )(qf, kf, vf)
-    return out.reshape(B, H, Sq, D), lse[:, :, 0].reshape(B, H, Sq)
+    return out.reshape(B, H, Sq, Dv), lse[:, :, 0].reshape(B, H, Sq)
 
 
 def _bwd_p_ds(q, k, v, do, lse, delta, row0, col0, scale, masked):
@@ -566,7 +585,7 @@ def _bwd_row_stats(out, lse, do, lse_cotangent):
     ring-attention chunk merge): d lse_r / d s_rc = p_rc, so the extra term
     is ``g_lse_r * p_rc`` — algebraically it folds into the delta:
     ds = p * (dp - (delta - g_lse)). The kernels are unchanged."""
-    B, H, Sq, D = out.shape
+    B, H, Sq, D = out.shape  # the value width: out and do are v wide
     lsef = jnp.broadcast_to(lse.reshape(B * H, Sq)[:, :, None],
                             (B * H, Sq, _LANES))
     delta = jnp.einsum("bsd,bsd->bs",
@@ -579,15 +598,19 @@ def _bwd_row_stats(out, lse, do, lse_cotangent):
 
 def _flash_bwd_dkv(qf, kf, vf, dof, lsef, delta, causal, tiles, scale,
                    interpret):
-    """dK/dV kernel on [B*H, S, D] operands: grid over kv tiles, q tiles
-    stream by; softmax recomputed per tile from the saved LSE."""
+    """dK/dV kernel on [B*H, S, D] (q, k) and [B*H, S, Dv] (v, dO)
+    operands: grid over kv tiles, q tiles stream by; softmax recomputed per
+    tile from the saved LSE."""
     BH, Sq, D = qf.shape
-    Sk = kf.shape[1]
+    Sk, Dv = kf.shape[1], vf.shape[2]
     block_q, block_k, sub = tiles[:3]
     grid = (BH, Sk // block_k, Sq // block_q)
     q_i = _first_needed_q(causal, block_q, block_k, grid[2])
-    q_spec = pl.BlockSpec((1, block_q, D), lambda b, j, i: (b, q_i(j, i), 0))
-    kv_spec = pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0))
+    q_spec, do_spec = (pl.BlockSpec((1, block_q, w),
+                                    lambda b, j, i: (b, q_i(j, i), 0))
+                       for w in (D, Dv))
+    k_spec, v_spec = (pl.BlockSpec((1, block_k, w), lambda b, j, i: (b, j, 0))
+                      for w in (D, Dv))
     row_spec = pl.BlockSpec((1, block_q, _LANES),
                             lambda b, j, i: (b, q_i(j, i), 0))
     return pl.pallas_call(
@@ -595,13 +618,13 @@ def _flash_bwd_dkv(qf, kf, vf, dof, lsef, delta, causal, tiles, scale,
                           block_q=block_q, block_k=block_k, sub=sub),
         name=_KERNEL_NAMES["dkv"],
         grid=grid,
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-        out_specs=[kv_spec, kv_spec],
+        in_specs=[q_spec, k_spec, v_spec, do_spec, row_spec, row_spec],
+        out_specs=[k_spec, v_spec],
         out_shape=[
             _out_struct((BH, Sk, D), kf.dtype, qf, kf, vf, dof),
-            _out_struct((BH, Sk, D), vf.dtype, qf, kf, vf, dof),
+            _out_struct((BH, Sk, Dv), vf.dtype, qf, kf, vf, dof),
         ],
-        scratch_shapes=[_vmem((block_k, D)), _vmem((block_k, D))],
+        scratch_shapes=[_vmem((block_k, D)), _vmem((block_k, Dv))],
         interpret=interpret,
         **_compiler_params(tiles),
     )(qf, kf, vf, dof, lsef, delta)
@@ -609,22 +632,25 @@ def _flash_bwd_dkv(qf, kf, vf, dof, lsef, delta, causal, tiles, scale,
 
 def _flash_bwd_dq(qf, kf, vf, dof, lsef, delta, causal, tiles, scale,
                   interpret):
-    """dQ kernel on [B*H, S, D] operands: grid over q tiles, kv tiles
-    stream by."""
+    """dQ kernel on [B*H, S, D] (q, k) and [B*H, S, Dv] (v, dO) operands:
+    grid over q tiles, kv tiles stream by."""
     BH, Sq, D = qf.shape
-    Sk = kf.shape[1]
+    Sk, Dv = kf.shape[1], vf.shape[2]
     block_q, block_k, sub = tiles[:3]
     grid = (BH, Sq // block_q, Sk // block_k)
     kv_j = _last_needed_kv(causal, block_q, block_k)
-    q_spec = pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0))
-    kv_spec = pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, kv_j(i, j), 0))
+    q_spec, do_spec = (pl.BlockSpec((1, block_q, w), lambda b, i, j: (b, i, 0))
+                       for w in (D, Dv))
+    k_spec, v_spec = (pl.BlockSpec((1, block_k, w),
+                                   lambda b, i, j: (b, kv_j(i, j), 0))
+                      for w in (D, Dv))
     row_spec = pl.BlockSpec((1, block_q, _LANES), lambda b, i, j: (b, i, 0))
     return pl.pallas_call(
         functools.partial(_fa_bwd_dq_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, sub=sub),
         name=_KERNEL_NAMES["dq"],
         grid=grid,
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        in_specs=[q_spec, k_spec, v_spec, do_spec, row_spec, row_spec],
         out_specs=q_spec,
         out_shape=_out_struct((BH, Sq, D), qf.dtype, qf, kf, vf, dof),
         scratch_shapes=[_vmem((block_q, D))],
@@ -640,18 +666,18 @@ def _flash_backward(q, k, v, out, lse, do, lse_cotangent, causal, plan,
     (grid over q tiles); softmax recomputed per tile from the saved LSE —
     the O(S) memory trade the forward made, carried into the backward."""
     B, H, Sq, D = q.shape
-    Sk = k.shape[2]
+    Sk, Dv = k.shape[2], v.shape[3]
     qf = q.reshape(B * H, Sq, D)
     kf = k.reshape(B * H, Sk, D)
-    vf = v.reshape(B * H, Sk, D)
-    dof = do.reshape(B * H, Sq, D)
+    vf = v.reshape(B * H, Sk, Dv)
+    dof = do.reshape(B * H, Sq, Dv)
     lsef, delta = _bwd_row_stats(out, lse, do, lse_cotangent)
     dk, dv = _flash_bwd_dkv(qf, kf, vf, dof, lsef, delta, causal, plan.dkv,
                             scale, interpret)
     dq = _flash_bwd_dq(qf, kf, vf, dof, lsef, delta, causal, plan.dq,
                        scale, interpret)
     return (dq.reshape(B, H, Sq, D), dk.reshape(B, H, Sk, D),
-            dv.reshape(B, H, Sk, D))
+            dv.reshape(B, H, Sk, Dv))
 
 
 def _vmem(shape):
@@ -714,9 +740,9 @@ def _fa_lse_fwd(q, k, v, causal, block_q, block_k, scale, interpret):
             f"q/k/v must share one dtype (got {q.dtype}/{k.dtype}/"
             f"{v.dtype}); cast the operands before the call"
         )
-    plan = _require_plan(q, k, causal, block_q, block_k)
+    plan = _require_plan(q, k, v, causal, block_q, block_k)
     _note_plan(plan, ("fwd",), q.shape[0] * q.shape[1], q.shape[2],
-               k.shape[2])
+               k.shape[2], q.shape[3], v.shape[3])
     out, lse = _flash_forward(q, k, v, causal, plan.fwd,
                               _resolve_scale(q, scale), interpret)
     return (out, lse), (q, k, v, out, lse)
@@ -725,9 +751,9 @@ def _fa_lse_fwd(q, k, v, causal, block_q, block_k, scale, interpret):
 def _fa_lse_bwd(causal, block_q, block_k, scale, interpret, res, g):
     q, k, v, out, lse = res
     g_out, g_lse = g
-    plan = _require_plan(q, k, causal, block_q, block_k)
+    plan = _require_plan(q, k, v, causal, block_q, block_k)
     _note_plan(plan, ("dkv", "dq"), q.shape[0] * q.shape[1], q.shape[2],
-               k.shape[2])
+               k.shape[2], q.shape[3], v.shape[3])
     return _flash_backward(q, k, v, out, lse, g_out, g_lse, causal, plan,
                            _resolve_scale(q, scale), interpret)
 

@@ -60,8 +60,11 @@ class Tiles(NamedTuple):
 def _whole_or_tile(length: int, sizes) -> int:
     """The largest listed tile that divides ``length``; a length that none
     divides is one block as it is (a block equal to the array's dimension
-    always tiles)."""
-    return next((s for s in sizes if length % s == 0), length)
+    always tiles), and so is a multiple of the 128 lanes that no listed
+    tile ABOVE 128 divides (1408 = 11 x 128: whole, not eleven tiles of 128
+    at a grid step's fixed cost each)."""
+    tile = next((s for s in sizes if length % s == 0), length)
+    return length if tile == sizes[-1] else tile
 
 
 def _vmem_bytes(kernel: str, t: Tiles, itemsize: int) -> int:
@@ -78,8 +81,10 @@ def tile_plan(m: int, k: int, n: int, dtype) -> Tiles:
     three kernels: dx swaps the roles of k and n, dw accumulates over m).
     ``tm`` is the largest of 512/256/128 that ``m`` reaches (the caller pads
     ``m`` to a multiple: rows past the groups are never visited); ``tk`` and
-    ``tn`` the largest of 1024..128 dividing k and n, shrunk in turn while
-    a step would not fit Mosaic's default scoped VMEM."""
+    ``tn`` the largest of 1024..256 dividing k and n, else the whole width,
+    shrunk in turn while a step would not fit Mosaic's default scoped VMEM
+    (1408 stays whole and the other width gives way: ``[2048, 1408]`` plans
+    512 x 512 x 1408, ``[1408, 2048]`` 512 x 1408 x 512)."""
     itemsize = jnp.dtype(dtype).itemsize
     tm = next((s for s in _TM if m >= s), -(-m // _SUBLANES) * _SUBLANES)
     tk, tn = _whole_or_tile(k, _TKN), _whole_or_tile(n, _TKN)
@@ -124,7 +129,8 @@ def _note_plans(kernels, m: int, k: int, n: int, groups: int, dtype) -> None:
                  * (k // plan.tk) * (n // plan.tn))
         for kern in kernels:
             t = _kernel_tiles(kern, m, k, n, dtype)
-            note_kernel_plan(KERNEL_NAMES[kern], t.tm, t.tk, t.tn, steps, True)
+            note_kernel_plan(KERNEL_NAMES[kern], t.tm, t.tk, t.tn, steps, True,
+                             d=k, dv=n)
     except Exception:
         pass
 

@@ -78,6 +78,13 @@ def ring_attention(
     ``interpret=True`` runs the flash inner in the Pallas interpreter (CPU
     tests)."""
     B, H, S, D = q.shape
+    if k.shape[3] != D or v.shape[3] != D:
+        # the folds carry one [.., D] accumulator for q, k and v alike;
+        # latent attention's (192, 128) trains through the single-device
+        # tiers (flash_attention, blockwise_attention, a2a_attention)
+        raise ValueError(
+            f"ring attention folds chunks at one head width: q {D}, k "
+            f"{k.shape[3]}, v {v.shape[3]}")
     scale = scale if scale is not None else D ** -0.5
     n = lax.psum(1, axis_name)
     my = lax.axis_index(axis_name)

@@ -63,7 +63,8 @@ def a2a_attention(
     S = qh.shape[2]
     from harmony_tpu.utils.platform import trace_is_tpu
 
-    if trace_is_tpu() and tile_plan(S, S, D, qh.dtype, causal) is not None:
+    if trace_is_tpu() and tile_plan(S, S, D, qh.dtype, causal,
+                                    dv=vh.shape[3]) is not None:
         o = flash_attention(qh, kh, vh, causal=causal, scale=scale)
     else:
         o = blockwise_attention(qh, kh, vh, causal=causal, scale=scale)

@@ -469,29 +469,35 @@ def _kernel_plan_family():
         "harmony_kernel_grid_steps",
         "Grid steps a call of a Pallas kernel under the tiles it was traced "
         "with, by job (planned=0: the caller's explicit blocks)",
-        ("job", "kernel", "block_q", "block_k", "sub", "planned"))
+        ("job", "kernel", "block_q", "block_k", "sub", "planned", "d", "dv"))
 
 
 def note_kernel_plan(kernel: str, block_q: int, block_k: int, sub: int,
-                     grid_steps: int, planned: bool) -> None:
+                     grid_steps: int, planned: bool, *, d: int,
+                     dv: int) -> None:
+    """``d`` / ``dv``: the two widths the tiles were planned for — a flash
+    kernel's q.k and v head widths, a grouped matmul's k and n."""
     from harmony_tpu.tracing.span import current_job
 
     _kernel_plan_family().labels(
         job=current_job() or "-", kernel=kernel, block_q=str(block_q),
         block_k=str(block_k), sub=str(sub), planned=str(int(planned)),
+        d=str(d), dv=str(dv),
     ).set(grid_steps)
 
 
 def kernel_plans() -> Dict[str, list]:
-    """``{job: [{kernel, block_q, block_k, sub, planned, grid_steps}]}`` of
+    """``{job: [{kernel, block_q, block_k, sub, planned, d, dv,
+    grid_steps}]}`` of
     every kernel traced in this process — STATUS ``kernel_plans``."""
     out: Dict[str, list] = {}
     try:
-        for (job, kernel, bq, bk, sub, planned), child in \
+        for (job, kernel, bq, bk, sub, planned, d, dv), child in \
                 _kernel_plan_family().children():
             out.setdefault(job, []).append({
                 "kernel": kernel, "block_q": int(bq), "block_k": int(bk),
                 "sub": int(sub), "planned": planned == "1",
+                "d": int(d), "dv": int(dv),
                 "grid_steps": int(child.value)})
     except Exception:
         return {}

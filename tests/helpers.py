@@ -73,3 +73,46 @@ class MoveOncePodOptimizer:
         return DolphinPlan(transfer_steps=[
             TransferStep(params.table_id, src, "executor-0", n)
         ])
+
+
+from harmony_tpu.models.transformer import (  # noqa: E402
+    TransformerLM, TransformerTrainer)
+
+
+class _SeededBiasLM(TransformerLM):
+    def init(self, rng):
+        import jax
+
+        params = super().init(rng)
+        for i in self.config.moe_layers():
+            moe = params["layers"][i]["moe"]
+            moe["bias"] = 0.2 * jax.random.normal(
+                jax.random.fold_in(rng, 7), moe["bias"].shape)
+        return params
+
+
+class SeededBiasLMTrainer(TransformerTrainer):
+    """The LM trainer with a NON-ZERO seeded router selection bias,
+    reporting the bias rows beside each step's loss: what the optimizer does
+    to a leaf whose gradient is zero shows in ``seen[job]`` (``[steps,
+    expert layers, experts]`` per drain)."""
+
+    seen: dict = {}
+
+    def build_model(self, config):
+        return _SeededBiasLM(config)
+
+    def loss_and_metrics_on_batch(self, params, batch):
+        import jax.numpy as jnp
+
+        loss, m = super().loss_and_metrics_on_batch(params, batch)
+        return loss, {**m, "moe_bias": jnp.stack(
+            [params["layers"][i]["moe"]["bias"]
+             for i in self.config.moe_layers()])}
+
+    def observe_step_vectors(self, job_id, vectors):
+        import numpy as np
+
+        self.seen.setdefault(job_id, []).append(
+            np.asarray(vectors["moe_bias"]))
+        super().observe_step_vectors(job_id, vectors)

@@ -52,10 +52,34 @@ def test_planned_flash_kernels_compile_for_v5e(one_chip, b, h, s, d, dtype,
     assert compiled.as_text().count("tpu_custom_call") == 3
 
 
+@pytest.mark.parametrize("b,h,s,d,dv,dtype", [
+    (2, 16, 8192, 192, 128, jnp.bfloat16),   # the moonlight-16b-a3b cell's
+    (1, 4, 1024, 192, 128, jnp.float32),     # ... and its float32 twin
+    (2, 4, 197, 24, 16, jnp.bfloat16),       # one block, widths off a lane
+])
+def test_flash_kernels_with_a_value_width_compile_for_v5e(one_chip, b, h, s, d,
+                                                          dv, dtype):
+    """q and k 192 wide (a lane tile and a half: VMEM pads it to 256), v,
+    the output, dO and dV 128 wide: one lowering, no padded caller."""
+    sd = lambda w: jax.ShapeDtypeStruct((b, h, s, w), dtype, sharding=one_chip)
+
+    def loss(q, k, v):
+        out, lse = flash_attention_lse(q, k, v, True)
+        return out.astype(jnp.float32).sum() + lse.sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        sd(d), sd(d), sd(dv)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 3
+    dq, dk, dvv = compiled.output_shardings  # three outputs came back
+    assert [o.shape[-1] for o in jax.eval_shape(
+        jax.grad(loss, argnums=(0, 1, 2)), sd(d), sd(d), sd(dv))] == [d, d, dv]
+
 
 @pytest.mark.parametrize("m,k,n,groups,dtype", [
     (65536, 2048, 1024, 16, jnp.bfloat16),  # olmoe-1b-7b.solo: gate / up
     (65536, 1024, 2048, 16, jnp.bfloat16),  # ... and down
+    (98304, 2048, 1408, 8, jnp.bfloat16),   # moonlight-16b-a3b.solo: 1408
+    (98304, 1408, 2048, 8, jnp.bfloat16),   # whole, as n and as k
     (300, 64, 32, 4, jnp.float32),          # rows and widths off every tile
 ])
 def test_grouped_matmul_kernels_compile_for_v5e(one_chip, m, k, n, groups,

@@ -551,6 +551,6 @@ def test_flash_plan_is_recorded_at_trace_time():
     rows = {r["kernel"]: r for r in progcache.kernel_plans()["plan-rec"]}
     assert rows["harmony_flash_fwd"] == {
         "kernel": "harmony_flash_fwd", "block_q": 512, "block_k": 1024,
-        "sub": 1024, "planned": True, "grid_steps": 192}
+        "sub": 1024, "planned": True, "d": 64, "dv": 64, "grid_steps": 192}
     assert rows["harmony_flash_bwd_dkv"]["grid_steps"] == 192
     assert rows["harmony_flash_bwd_dq"]["sub"] == 512
