@@ -200,6 +200,22 @@ class Trainer:
         ``hyper`` carries the values from :meth:`hyperparams`."""
         raise NotImplementedError
 
+    def row_update_parts(self, capacity: int):
+        """``pull_mode == "all"`` trainers whose ``compute`` is an
+        elementwise update rule over a gradient may state it in two parts
+        for a model table of ``capacity`` rows: ``(rows, sections,
+        gradient, push_update)``. The model's first ``sections * rows``
+        rows are ``sections`` sections of state that update together, row
+        by row, the parameters first. ``gradient(model[:rows], batch) ->
+        (g, metrics)`` is the step's COMP: it needs the parameter section
+        alone. ``push_update(spec, arr, model, g, hyper) -> new_arr`` is
+        the step's PUSH: the rule folded into the table ``arr`` where the
+        rows lie (``TableSpec.fold_row_sections`` / ``push_row_ranges``;
+        ``model`` is the pulled table). ``compute`` stays, the same
+        arithmetic as one whole delta. Default: None — the step pushes
+        ``compute``'s whole delta."""
+        return None
+
     def compute_with_local(
         self,
         model: jnp.ndarray,

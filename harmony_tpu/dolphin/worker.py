@@ -73,8 +73,11 @@ def _phase_boundary(tree, replicate_on: "Optional[Mesh]" = None):
     partitions the downstream compute from whatever sharding propagates
     backward and reduction orders drift with the layout. On TPU the
     keyed stages already end at Pallas kernel calls (ops/sparse.py),
-    which are materialization boundaries anyway. Whether the LM step is
-    faster without the fence is ROADMAP S4's to measure."""
+    which are materialization boundaries anyway. What a stage holds is
+    the step's to say: the pull-all step of a two-part trainer puts the
+    COMP fence after the GRADIENT and runs the elementwise update rule
+    in PUSH (:func:`pull_all_step`) — no matmul crosses a fence it did
+    not cross before."""
     if replicate_on is not None:
         tree = _replicated_tree(tree, replicate_on)
     return jax.lax.optimization_barrier(tree)
@@ -90,6 +93,63 @@ def _replicated_tree(tree, mesh: Mesh):
     return jax.tree_util.tree_map(
         lambda x: jax.lax.with_sharding_constraint(x, rep), tree
     )
+
+
+def update_lowering(spec, trainer, mesh: Mesh) -> str:
+    """How a ``pull_mode == "all"`` step applies its update.
+    ``"row_ranges"``: the update rule runs in the PUSH stage on the stored
+    rows — the trainer states its step in two parts for this table
+    (``Trainer.row_update_parts``), the table folds row ranges
+    (``TableSpec.takes_row_ranges``: range-partitioned, the additive fold,
+    no ``post`` hook) and every device of ``mesh`` holds it whole. (On a
+    model-sharded table a parameter's row and its optimizer state's rows
+    lie on different shards, so an elementwise rule over them is no local
+    fold: that table keeps today's program.) ``"whole_delta"``:
+    ``compute``'s ``[capacity, ...]`` delta through ``push_all``. Decided
+    at step build from the table's schema, the trainer and the mesh."""
+    from harmony_tpu.table.table import row_shards
+
+    if (spec.takes_row_ranges and row_shards(mesh, spec.num_blocks) == 1
+            and trainer.row_update_parts(spec.config.capacity) is not None):
+        return "row_ranges"
+    return "whole_delta"
+
+
+def pull_all_step(spec, trainer, mesh: Mesh):
+    """The ``pull_mode == "all"`` step body ``(arr, batch, hyper) ->
+    (new_arr, metrics)`` on a dense model table — what ``_step_core``
+    runs, for callers that compile the shipped step without a worker.
+
+    ``whole_delta``: PULL the table | COMP ``trainer.compute`` | PUSH
+    ``push_all`` of its delta. ``row_ranges`` (:func:`update_lowering`):
+    PULL the table | COMP the gradient, fenced and pinned replicated as
+    the delta is — one section, not three | PUSH the update rule on the
+    stored rows and its fold, section by section where the rows lie: no
+    delta is concatenated and none is added to the whole table. Per
+    element the arithmetic is the same in both: ``stored + (new -
+    stored)``."""
+    if update_lowering(spec, trainer, mesh) == "whole_delta":
+
+        def _step(arr, batch, hyper):
+            model = _phase_boundary(spec.pull_all(arr),
+                                    replicate_on=mesh)             # PULL
+            delta, metrics = _phase_boundary(
+                trainer.compute(model, batch, hyper),
+                replicate_on=mesh)                                 # COMP
+            return spec.push_all(arr, delta), metrics              # PUSH
+
+        return _step
+    rows, _, gradient, push_update = trainer.row_update_parts(
+        spec.config.capacity)
+
+    def _step(arr, batch, hyper):
+        model = _phase_boundary(spec.pull_all(arr),
+                                replicate_on=mesh)                 # PULL
+        g, metrics = _phase_boundary(gradient(model[:rows], batch),
+                                     replicate_on=mesh)            # COMP
+        return push_update(spec, arr, model, g, hyper), metrics    # PUSH
+
+    return _step
 
 
 class _TimedAdmission:
@@ -415,14 +475,10 @@ class WorkerTasklet:
 
             return _step
         if trainer.pull_mode == "all":
+            body = pull_all_step(spec, trainer, mesh)
 
             def _step(arr, batch, hyper):
-                model = _phase_boundary(spec.pull_all(arr),
-                                        replicate_on=mesh)         # PULL
-                delta, metrics = _phase_boundary(
-                    trainer.compute(model, batch, hyper),
-                    replicate_on=mesh)                             # COMP
-                new_arr = spec.push_all(arr, delta)                # PUSH
+                new_arr, metrics = body(arr, batch, hyper)
                 return new_arr, sync(metrics, new_arr)
 
         else:
@@ -455,6 +511,23 @@ class WorkerTasklet:
             with on_mesh(mesh):
                 route = spec.push_lowering(self._pull_rows)
         table_layout.note_push(self.job_id, spec.table_id, route)
+
+    def _note_update_lowering(self, mesh: Mesh) -> None:
+        """STATUS ``tenants.<job>.table_layout.update_lowering`` /
+        ``fold_lowering`` and their gauges: how the pull-all step just
+        built applies its update (:func:`update_lowering`), and what the
+        fold of its row sections lowers to (``TableSpec.fold_lowering``)."""
+        from harmony_tpu.metrics import table_layout
+
+        spec, trainer = self.ctx.model_table.spec, self.trainer
+        lowering = update_lowering(spec, trainer, mesh)
+        table_layout.note_update(self.job_id, spec.table_id, lowering)
+        if lowering == "row_ranges":
+            rows, sections = trainer.row_update_parts(
+                spec.config.capacity)[:2]
+            with on_mesh(mesh):
+                fold = spec.fold_lowering(rows, sections)
+            table_layout.note_fold(self.job_id, spec.table_id, fold)
 
     def _resolve_push_route(self) -> str:
         """The table's keyed-push route with "mxu_auto" resolved by a
@@ -699,6 +772,7 @@ class WorkerTasklet:
             self._note_push_lowering(mesh_now)
         else:
             self._pull_rows = int(table.spec.config.capacity)
+            self._note_update_lowering(mesh_now)
         self._step_sharding = tsh
         self._local_sharding = lsh
         prev_batch_sig = self._batch_sig if self._built_once else None

@@ -193,14 +193,16 @@ class LedgerStore:
         with self._lock:
             self._tenant(job).table_layout = dict(layout)
 
-    def set_push_lowering(self, job: str, lowering: str) -> None:
-        """What the tenant's keyed push lowers to (table_layout.py
-        ``note_push``; at each step build, after the table's own record)
-        — a key of ``table_layout``."""
+    def set_step_lowering(self, job: str, key: str, lowering: str) -> None:
+        """What the tenant's step program does with its table — ``key`` is
+        ``push_lowering`` (the keyed push), ``update_lowering`` or
+        ``fold_lowering`` (the pull-all update), table_layout.py
+        ``note_push`` / ``note_update`` / ``note_fold``;
+        at each step build, after the table's own record — a key of
+        ``table_layout``."""
         with self._lock:
             t = self._tenant(job)
-            t.table_layout = {**(t.table_layout or {}),
-                              "push_lowering": lowering}
+            t.table_layout = {**(t.table_layout or {}), key: lowering}
 
     def set_serving_state(self, job: str, attempt: Optional[str] = None,
                           *, enabled: bool,

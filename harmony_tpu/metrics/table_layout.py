@@ -25,6 +25,21 @@ built (dolphin/worker.py ``_build_step``; ``TableSpec.push_lowering``):
   * STATUS ``tenants.<job>.table_layout.push_lowering`` = ``"pallas_rows"``
     / ``"xla"``, or the route's name where the route is not ``scatter``
     (``"mxu"``, ``"sparse"``, ``"mxu_auto"``).
+
+And how a pull-all tenant's step applies its update, recorded at the same
+place (dolphin/worker.py ``update_lowering``; ``TableSpec.fold_lowering``):
+
+  * ``harmony_table_update_row_ranges{job,table}`` — 1 when the update rule
+    runs in the PUSH stage on the stored rows, section by section
+    (``TableSpec.fold_row_sections``), 0 when ``compute``'s whole-table
+    delta goes through ``push_all``;
+  * STATUS ``tenants.<job>.table_layout.update_lowering`` =
+    ``"row_ranges"`` / ``"whole_delta"``;
+  * ``harmony_table_fold_pallas_sections{job,table}`` / STATUS
+    ``table_layout.fold_lowering`` (``row_ranges`` tenants only) — 1 /
+    ``"pallas_sections"`` when that fold is the one-pass in-place kernel
+    (ops.sections.fold_row_sections), 0 / ``"xla"`` when XLA rewrites the
+    sections through fresh buffers.
 """
 from __future__ import annotations
 
@@ -60,15 +75,47 @@ def note(job: str, spec, section_stride: Optional[int] = None
     return row
 
 
+def _note_lowering(gauge, job: str, table_id: str, key: str,
+                   lowering: str, engaged: str) -> None:
+    from harmony_tpu.metrics.accounting import ledger
+
+    gauge.labels(job=job, table=table_id).set(int(lowering == engaged))
+    ledger().set_step_lowering(job, key, lowering)
+
+
 def note_push(job: str, table_id: str, lowering: str) -> None:
     """Record what ``job``'s keyed push on ``table_id`` lowers to."""
-    from harmony_tpu.metrics.accounting import ledger
     from harmony_tpu.metrics.registry import get_registry
 
-    get_registry().gauge(
+    _note_lowering(get_registry().gauge(
         "harmony_table_push_pallas_rows",
         "1 when a tenant's keyed push is the in-place Pallas row "
         "scatter-add, 0 for XLA's scatter or a fold route",
-        ("job", "table")).labels(job=job, table=table_id).set(
-            int(lowering == "pallas_rows"))
-    ledger().set_push_lowering(job, lowering)
+        ("job", "table")), job, table_id, "push_lowering", lowering,
+        "pallas_rows")
+
+
+def note_update(job: str, table_id: str, lowering: str) -> None:
+    """Record how ``job``'s pull-all step applies its update to
+    ``table_id``."""
+    from harmony_tpu.metrics.registry import get_registry
+
+    _note_lowering(get_registry().gauge(
+        "harmony_table_update_row_ranges",
+        "1 when a tenant's pull-all step runs its update rule in the push "
+        "stage on the stored rows, section by section; 0 when it pushes a "
+        "whole-table delta", ("job", "table")), job, table_id,
+        "update_lowering", lowering, "row_ranges")
+
+
+def note_fold(job: str, table_id: str, lowering: str) -> None:
+    """Record what the fold of ``job``'s row sections on ``table_id``
+    lowers to (``row_ranges`` tenants)."""
+    from harmony_tpu.metrics.registry import get_registry
+
+    _note_lowering(get_registry().gauge(
+        "harmony_table_fold_pallas_sections",
+        "1 when a tenant's update rule folds into its row sections in one "
+        "in-place Pallas pass, 0 when XLA rewrites them",
+        ("job", "table")), job, table_id, "fold_lowering", lowering,
+        "pallas_sections")

@@ -182,38 +182,86 @@ class PyTreeTrainer(Trainer):
             [flat, jnp.zeros((pad,), flat.dtype)]
         ).reshape(rows, self.row_width)
 
+    def _leaves(self, p: jnp.ndarray) -> Any:
+        """The parameter pytree of the parameter section's rows."""
+        return self._unravel(p.reshape(-1)[: self.num_params])
+
     def _params(self, model: jnp.ndarray) -> Any:
         """The parameter pytree of a pulled model: rows -> leaves."""
-        return self._unravel(
-            self.section(model, 0).reshape(-1)[: self.num_params])
+        return self._leaves(self.section(model, 0))
 
     def hyperparams(self) -> Dict[str, float]:
         if self.beta2 is None:
             return {"lr": self.step_size}
         return {"lr": self.step_size, "beta2": self.beta2}
 
-    def compute(self, model, batch, hyper):
-        from harmony_tpu.dolphin import optim
+    # A step in two parts. ``gradient`` is COMP: it needs the parameter
+    # section alone. ``push_update`` is PUSH: the optimizer, elementwise on
+    # the stored sections, folded where the rows lie (``row_update_parts``;
+    # dolphin/worker.py ``pull_all_step``). ``compute`` is the same
+    # arithmetic as one whole-table delta.
 
+    def row_update_parts(self, capacity: int):
+        if self.section_stride(capacity) != self.section_rows:
+            return None  # an older chain's sections start off a tile
+        return (self.section_rows, 1 + self.num_state_slots, self.gradient,
+                self.push_update)
+
+    def gradient(self, p: jnp.ndarray, batch):
+        """``(g, metrics)``: the gradient as rows ``[stride, row_width]``
+        over the parameter section's rows ``p`` (rows -> leaves,
+        value_and_grad, leaves -> rows), and what the step reports."""
         (loss, extra), grads = jax.value_and_grad(
             self.loss_and_metrics_on_batch, has_aux=True
-        )(self._params(model), batch)
-        # the optimizer is elementwise: it runs on the sections as rows
-        slots = self.num_state_slots
-        p = self.section(model, 0)
+        )(self._leaves(p), batch)
         g = self._to_rows(ravel_pytree(grads)[0], p.shape[0])
-        m = self.section(model, 1) if slots >= 1 else jnp.zeros_like(p)
-        v = self.section(model, 2) if slots >= 2 else jnp.zeros_like(p)
+        return g, {"loss": loss, **extra}
+
+    def section_deltas(self, stored, g, scalars):
+        """The optimizer as an elementwise rule: ``stored`` — the
+        ``[params, m, v][: 1 + slots]`` sections, or equal blocks of them
+        — and the gradient ``g`` in one shape, ``scalars`` the step count
+        after this update (``"t"``) and the hyper-parameters, as scalars
+        or anything that broadcasts -> ``new - stored`` per section."""
+        from harmony_tpu.dolphin import optim
+
+        p, m, v = (*stored, *[jnp.zeros_like(g)] * (3 - len(stored)))
+        hyper = {k: x for k, x in scalars.items() if k != "t"}
+        new = optim.apply(self.optimizer, p, g, m, v, scalars["t"], hyper)
+        return tuple(n - o for n, o in zip(new, stored))
+
+    def _counter_block(self, rows: int) -> jnp.ndarray:
+        """The push's ``+1`` on the counter's first cell, as ``rows`` rows."""
+        return jnp.zeros((rows, self.row_width), jnp.float32
+                         ).at[0, 0].set(1.0)
+
+    def push_update(self, spec, arr, model, g, hyper):
+        """PUSH: the optimizer on the table's own sections under the
+        gradient rows ``g`` (``model``: the pulled table, for the step
+        count), each stored row read and written where it lies, then the
+        counter's ``+1``. Tile-aligned sections only."""
+        slots = self.num_state_slots
         t = self.counter(model) + 1.0 if slots else jnp.asarray(1.0)
-        new_p, new_m, new_v = optim.apply(
-            self.optimizer, p, g, m, v, t, hyper
-        )
-        sections = [new_p - p, new_m - m, new_v - v][: 1 + slots]
-        tail = model.shape[0] - len(sections) * p.shape[0]
+        arr = spec.fold_row_sections(
+            arr, g, {"t": t, **hyper}, self.section_deltas,
+            rows=self.section_rows, sections=1 + slots)
+        if slots:
+            arr = spec.push_row_ranges(arr, [(
+                (1 + slots) * self.section_rows,
+                self._counter_block(TILE_ROWS))])
+        return arr
+
+    def compute(self, model, batch, hyper):
+        slots = self.num_state_slots
+        stored = tuple(self.section(model, i) for i in range(1 + slots))
+        g, metrics = self.gradient(stored[0], batch)
+        t = self.counter(model) + 1.0 if slots else jnp.asarray(1.0)
+        sections = list(self.section_deltas(stored, g, {"t": t, **hyper}))
+        tail = model.shape[0] - len(sections) * g.shape[0]
         if tail:  # the counter block (its first cell counts pushes)
-            block = jnp.zeros((tail, self.row_width), p.dtype)
-            sections.append(block.at[0, 0].set(1.0) if slots else block)
-        return jnp.concatenate(sections), {"loss": loss, **extra}
+            sections.append(self._counter_block(tail) if slots else
+                            jnp.zeros((tail, self.row_width), g.dtype))
+        return jnp.concatenate(sections), metrics
 
     def evaluate(self, model, batch) -> Dict[str, jnp.ndarray]:
         return self.eval_metrics(self._params(model), batch)

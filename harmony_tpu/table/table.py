@@ -133,6 +133,13 @@ def block_sharding(mesh: Mesh, num_blocks: int) -> NamedSharding:
     return NamedSharding(mesh, P())
 
 
+def row_shards(mesh: Mesh, num_blocks: int) -> int:
+    """Shards :func:`block_sharding` splits a table's blocks into (1: every
+    device holds the table whole)."""
+    model = mesh.shape.get(MODEL_AXIS, 1)
+    return model if model > 1 and num_blocks % model == 0 else 1
+
+
 def _gather_on_mesh(mesh: Mesh, shards: int, flat: jnp.ndarray,
                     idx: jnp.ndarray) -> jnp.ndarray:
     """The Pallas gather partitioned by hand (a pallas_call is opaque to
@@ -335,8 +342,7 @@ class TableSpec:
         mesh = trace_mesh()
         if mesh is None or not mesh_is_tpu(mesh):
             return None, 1
-        model = mesh.shape.get(MODEL_AXIS, 1)
-        return mesh, (model if self.num_blocks % model == 0 else 1)
+        return mesh, row_shards(mesh, self.num_blocks)
 
     def push_lowering(self, n_keys: int) -> str:
         """What ``push(via="scatter")`` of ``n_keys`` keys lowers to in
@@ -533,6 +539,106 @@ class TableSpec:
             return out
         keys = jnp.arange(self.config.capacity, dtype=jnp.int32)
         return self.push(arr, keys, deltas)
+
+    @property
+    def takes_row_ranges(self) -> bool:
+        """Whether :meth:`push_row_ranges` / :meth:`fold_row_sections`
+        apply: keys lie in storage order (a range table) and the update
+        fn is the plain additive fold — a delta that names some rows and
+        one that pads the others with zeros then fold to the same table
+        (``min`` / ``max`` / a ``post`` hook touch every row of a whole
+        push)."""
+        return (isinstance(self.partitioner, RangePartitioner)
+                and self.update_fn.scatter_mode == "add"
+                and self.update_fn.post is None)
+
+    def _flat_rows(self, arr: jnp.ndarray, what: str) -> jnp.ndarray:
+        if not self.takes_row_ranges:
+            raise ValueError(
+                f"{what}: table {self.table_id!r} "
+                f"({type(self.partitioner).__name__}, update fn "
+                f"{self.update_fn.name!r}) takes whole deltas only")
+        return arr.reshape(self.num_blocks * self.block_size,
+                           *self.value_shape)
+
+    def push_row_ranges(self, arr: jnp.ndarray, ranges) -> jnp.ndarray:
+        """Dense push of row ranges: fold ``[(first_row, rows), ...]``
+        (static firsts, ascending and disjoint; ``rows`` is ``[n,
+        *value_shape]`` for keys ``first_row .. first_row + n``) into the
+        stored rows, read from ``arr`` itself and updated where they lie
+        (``dynamic-update-slice``: in place in a donated table, at a cost
+        that follows the rows named). No ``[capacity, ...]`` delta is
+        built. Equal, bit for bit, to ``push_all`` of the zero-padded whole
+        delta. The deltas must not read OTHER stored rows of ``arr`` — the
+        compiler would copy the table to keep them: a rule across rows is
+        :meth:`fold_row_sections`'s."""
+        flat = self._flat_rows(arr, "push_row_ranges")
+        at = 0
+        for first, rows in ranges:
+            end = first + rows.shape[0]
+            if first < at or end > self.config.capacity:
+                raise ValueError(
+                    f"push_row_ranges: rows {first}..{end} overlap the "
+                    f"range before them or lie outside table "
+                    f"{self.table_id!r} ({self.config.capacity} rows)")
+            flat = jax.lax.dynamic_update_slice_in_dim(
+                flat, flat[first:end] + rows.astype(arr.dtype), first,
+                axis=0)
+            at = end
+        return flat.reshape(arr.shape)
+
+    def fold_lowering(self, rows: int, sections: int) -> str:
+        """What :meth:`fold_row_sections` lowers to in the program being
+        traced: ``"pallas_sections"`` — ops.sections.fold_row_sections,
+        one pass in place — on a one-device TPU mesh over rows the kernel
+        takes, stored in whole 8-row tiles; ``"xla"`` — the rule on whole
+        sections, rewritten through fresh buffers — for everything else."""
+        from harmony_tpu.config.params import TILE_ROWS
+        from harmony_tpu.ops import sections as kernel
+
+        mesh, _ = self._kernel_layout()
+        if (mesh is not None and mesh.devices.size == 1
+                and self.block_size % TILE_ROWS == 0
+                and len(self.value_shape) == 1
+                and kernel.sections_kernel_ok(
+                    (self.num_blocks * self.block_size, *self.value_shape),
+                    self.dtype, rows, sections)):
+            return "pallas_sections"
+        return "xla"
+
+    def fold_row_sections(self, arr: jnp.ndarray, side: jnp.ndarray,
+                          scalars, rule, *, rows: int,
+                          sections: int) -> jnp.ndarray:
+        """Fold an elementwise update rule over aligned row sections:
+        keys ``k * rows .. (k + 1) * rows`` for k < ``sections`` hold
+        state that updates together, row by row — ``stored[k][r] +=
+        rule(stored, side, scalars)[k][r]``, where ``rule`` gets the
+        ``sections`` stored blocks and ``side`` (``[rows, *value_shape]``,
+        e.g. a gradient) in one shape, ``scalars`` as a dict of values
+        that broadcast against them, and returns one delta per section.
+        The update fn's fold of the table, with the delta computed where
+        the rows lie: in one in-place pass (:meth:`fold_lowering`), else
+        the rule on whole sections and one rewrite of them
+        (ops.sections.fold_row_sections_ref)."""
+        from harmony_tpu.ops.sections import (
+            fold_row_sections,
+            fold_row_sections_ref,
+        )
+
+        flat = self._flat_rows(arr, "fold_row_sections")
+        if self.fold_lowering(rows, sections) == "pallas_sections":
+            keys = sorted(scalars)
+            consts = jnp.stack([jnp.broadcast_to(
+                jnp.asarray(scalars[k], arr.dtype), flat.shape[1:])
+                for k in keys])
+            return fold_row_sections(
+                flat, side.astype(arr.dtype), consts,
+                lambda stored, g, c: rule(stored, g, {
+                    k: c[i:i + 1] for i, k in enumerate(keys)}),
+                rows=rows, sections=sections).reshape(arr.shape)
+        return fold_row_sections_ref(
+            flat, side.astype(arr.dtype), scalars, rule, rows=rows,
+            sections=sections).reshape(arr.shape)
 
     def write_all(self, arr: jnp.ndarray, values: jnp.ndarray) -> jnp.ndarray:
         """Overwrite the whole table from ``[capacity, *value_shape]`` in key

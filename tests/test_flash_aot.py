@@ -151,58 +151,152 @@ def test_keyed_push_compiles_in_place_for_v5e(one_chip, model, capacity):
     assert not moved, moved
 
 
-@pytest.mark.parametrize("optimizer", ["adam", "momentum"])
-def test_lm_step_leaves_the_table_in_its_stored_layout(one_chip, optimizer):
-    """The small LM's fused PULL -> COMP -> PUSH step (the worker's
-    ``_step_core`` from its public parts, as perf/aot_compile.py builds it):
-    the table enters in the default layout and no whole-table copy, reshape,
-    pad or slice is among the ops the device runs — under blocks of 9 rows
-    the compiler stored it ``{2,0,1}`` and relaid it out six times a step.
-    (Not sgd: its table IS the parameter section, whose one relayout, rows
-    to leaves, the model needs.)"""
-    import math
-    import re
-
+def _tiny_lm_step(mesh, optimizer):
+    """The small LM's step as the worker builds it (``pull_all_step``,
+    ``_step_core``'s own body), compiled for ``mesh``: ``(trainer, spec,
+    lowering, compiled)``."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from harmony_tpu.dolphin.worker import _phase_boundary
+    from harmony_tpu.dolphin.worker import pull_all_step, update_lowering
     from harmony_tpu.models import TransformerConfig, TransformerTrainer
-    from harmony_tpu.parallel.mesh import build_mesh
     from harmony_tpu.table.table import TableSpec, block_sharding
     from harmony_tpu.utils.platform import traced_on
 
-    mesh = build_mesh(list(one_chip.device_set), data=1)
     trainer = TransformerTrainer(
         TransformerConfig(vocab_size=64, d_model=32, n_heads=2, n_layers=2,
                           d_ff=64, max_seq=64, attn="blockwise"),
         row_width=128, optimizer=optimizer)
     spec = TableSpec(trainer.model_table_config())
-    assert trainer.num_rows % 8  # sections had to be rounded up
-
-    def step(arr, batch, hyper):
-        model = _phase_boundary(spec.pull_all(arr), replicate_on=mesh)
-        delta, metrics = _phase_boundary(
-            trainer.compute(model, batch, hyper), replicate_on=mesh)
-        return spec.push_all(arr, delta), metrics
-
     tsh = block_sharding(mesh, spec.num_blocks)
     scalar = jax.ShapeDtypeStruct((), jnp.float32,
                                   sharding=NamedSharding(mesh, P()))
-    text = jax.jit(traced_on(mesh, step), out_shardings=(tsh, None),
-                   donate_argnums=0).lower(
+    compiled = jax.jit(traced_on(mesh, pull_all_step(spec, trainer, mesh)),
+                       out_shardings=(tsh, None), donate_argnums=0).lower(
         jax.ShapeDtypeStruct(spec.storage_shape, spec.dtype, sharding=tsh),
         (jax.ShapeDtypeStruct((4, 33), jnp.int32,
                               sharding=NamedSharding(mesh, P("data"))),),
-        {k: scalar for k in trainer.hyperparams()}).compile().as_text()
+        {k: scalar for k in trainer.hyperparams()}).compile()
+    return trainer, spec, update_lowering(spec, trainer, mesh), compiled
+
+
+def _entry_results(text, sizes, ops):
+    """Lines of the entry computation whose op is one of ``ops`` and whose
+    result (any one array of it) has one of ``sizes`` elements."""
+    import math
+    import re
+
+    entry = text[text.index("\nENTRY "):]
+    hit = []
+    for line in entry.splitlines():
+        m = re.search(r"= (\(?\w+\[.*?) (%s)\(" % ops, line)
+        if m and any(math.prod(map(int, dims.split(","))) in sizes
+                     for dims in re.findall(r"\w+\[([\d,]+)\]", m.group(1))):
+            hit.append(line.strip()[:140])
+    return hit
+
+
+@pytest.mark.parametrize("optimizer", ["adam", "momentum"])
+def test_lm_step_leaves_the_table_in_its_stored_layout(one_chip, optimizer):
+    """The small LM's fused PULL -> COMP -> PUSH step, from the worker's own
+    function: the table enters in the default layout and no whole-table
+    copy, reshape, pad or slice is among the ops the device runs — under
+    blocks of 9 rows the compiler stored it ``{2,0,1}`` and relaid it out
+    six times a step. And the update runs in PUSH on the stored rows: the
+    table is aliased onto the result whole, and the ops whose result is the
+    size of the table or of its sections together are the two that update
+    it in place (the fold kernel, the counter's ``dynamic-update-slice``)
+    — no concatenated delta, no whole-table add.
+    (Not sgd: its table IS the parameter section, whose one relayout, rows
+    to leaves, the model needs.)"""
+    import math
+    import re
+
+    from harmony_tpu.parallel.mesh import build_mesh
+
+    mesh = build_mesh(list(one_chip.device_set), data=1)
+    trainer, spec, lowering, compiled = _tiny_lm_step(mesh, optimizer)
+    assert trainer.num_rows % 8  # sections had to be rounded up
+    assert lowering == "row_ranges"
+    text = compiled.as_text()
 
     stored = "f32[%s]{2,1,0:" % ",".join(map(str, spec.storage_shape))
     layout = re.search(r"entry_computation_layout=\{\((\S+),", text).group(1)
     assert layout.startswith(stored), layout
-    entry = text[text.index("\nENTRY "):]
     table = math.prod(spec.storage_shape)
-    moved = [
-        line.strip()[:120] for line in entry.splitlines()
-        for m in [re.search(r"= \w+\[([\d,]+)\]\S* (copy|reshape|pad|slice)\(",
-                            line)]
-        if m and math.prod(map(int, m.group(1).split(","))) == table]
+    moved = _entry_results(text, {table}, "copy|reshape|pad|slice")
     assert not moved, moved
+    assert compiled.memory_analysis().alias_size_in_bytes == table * 4
+    sections = ((1 + trainer.num_state_slots) * trainer.section_rows
+                * trainer.row_width)
+    wrote = _entry_results(text, {table, sections}, r"\S+")
+    # not writes of the device's memory: views, and the prefetch into fast
+    # memory that only a table this small gets
+    wrote = [w for w in wrote if not re.search(
+        r" (bitcast|parameter|tuple|get-tuple-element|copy-start|copy-done)"
+        r"\(", w)]
+    # both in place: the fold kernel, and the counter's +1 into 8 rows
+    assert len(wrote) == 2, wrote
+    assert "harmony_fold_row_sections" in wrote[0], wrote
+    assert "dynamic-update-slice" in wrote[1].split(" = ")[0], wrote
+
+
+def test_lm_step_on_a_row_sharded_table_keeps_the_whole_delta(one_chip):
+    """Four chips, the table's blocks split over the model axis: a
+    parameter's row and its m and v rows lie on different shards, so the
+    update is no local fold — the predicate says ``whole_delta`` and the
+    step compiles as before (the pull's all-gather, each shard's rows
+    aliased). Adam's 64 blocks split four ways; momentum's 43 do not, and
+    that table, whole on every chip, takes the row ranges."""
+    import math
+
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import Mesh
+
+    devices = topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices
+    mesh = Mesh(np.array(devices).reshape(1, 4), ("data", "model"))
+    trainer, spec, lowering, compiled = _tiny_lm_step(mesh, "adam")
+    assert spec.num_blocks % 4 == 0 and lowering == "whole_delta"
+    assert "all-gather" in compiled.as_text()
+    table = math.prod(spec.storage_shape)
+    assert compiled.memory_analysis().alias_size_in_bytes == table // 4 * 4
+    trainer, spec, lowering, compiled = _tiny_lm_step(mesh, "momentum")
+    assert spec.num_blocks % 4 and lowering == "row_ranges"
+    assert "all-gather" not in compiled.as_text()
+
+
+@pytest.mark.parametrize("rows,optimizer", [
+    (121_424, "adam"),       # gpt2-124m: three sections of 0.498 GB
+    (279_960, "adam"),       # olmoe-1b-7b
+    (261_008, "adam"),       # moonlight-16b-a3b
+    (121_424, "momentum"),   # two sections
+    (121_424, "sgd"),        # the parameters alone
+])
+def test_section_fold_compiles_in_place_for_v5e(one_chip, rows, optimizer):
+    """The optimizer inside ``harmony_fold_row_sections`` at the LM cells'
+    section sizes (1024 lanes; the last block of each overlaps the one
+    before it), compiled by Mosaic for a v5e: one custom call, the table
+    aliased onto the result whole, no temporary."""
+    from harmony_tpu.dolphin import optim
+    from harmony_tpu.ops.sections import fold_row_sections
+
+    sections = 1 + optim.num_slots(optimizer)
+
+    def rule(stored, g, consts):
+        p, m, v = (*stored, g, g)[:3]
+        new = optim.apply(optimizer, p, g, m, v, consts[0:1],
+                          {"lr": consts[1:2], "beta2": consts[2:3]})
+        return tuple(n - o for n, o in zip(new, stored))
+
+    sd = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32,
+                                             sharding=one_chip)
+    table = sd(sections * rows + 8, 1024)
+    compiled = jax.jit(
+        lambda t, g, c: fold_row_sections(t, g, c, rule, rows=rows,
+                                          sections=sections),
+        donate_argnums=0).lower(table, sd(rows, 1024), sd(3, 1024)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes == (sections * rows + 8) * 1024 * 4
+    assert ma.temp_size_in_bytes == 0

@@ -3,7 +3,9 @@
 Blocks are whole (8, 128) tiles with no tail block, every section starts a
 tile, the counter has a block of its own, and ``compute`` runs the optimizer
 on the sections as rows — against a frozen copy of the flat ``compute`` it
-replaced (kept here only), to the last bit.
+replaced (kept here only), to the last bit. And the step in two parts: the
+gradient is COMP, the update rule runs in PUSH on the stored rows
+(``pull_all_step``) — against ``compute`` + ``push_all``, to the last bit.
 """
 import jax
 import jax.numpy as jnp
@@ -11,8 +13,17 @@ import numpy as np
 import pytest
 from jax.flatten_util import ravel_pytree
 
+import dataclasses
+
+from jax.sharding import NamedSharding, PartitionSpec as P
+
 from harmony_tpu.config.params import TableConfig, TrainerParams
 from harmony_tpu.dolphin import TrainerContext, optim
+from harmony_tpu.dolphin.worker import (
+    _phase_boundary,
+    pull_all_step,
+    update_lowering,
+)
 from harmony_tpu.metrics import table_layout
 from harmony_tpu.models import (
     TransformerConfig,
@@ -20,7 +31,10 @@ from harmony_tpu.models import (
     make_lm_data,
 )
 from harmony_tpu.models.pytree_trainer import PyTreeTrainer
+from harmony_tpu.parallel import build_mesh
 from harmony_tpu.table import DenseTable, TableSpec
+from harmony_tpu.table.table import block_sharding, row_shards
+from harmony_tpu.utils.platform import traced_on
 
 CFG = TransformerConfig(vocab_size=64, d_model=32, n_heads=2, n_layers=2,
                         d_ff=64, max_seq=64, attn="blockwise")
@@ -219,6 +233,352 @@ def test_unknown_row_count_is_refused_by_name(off):
         assert str(n) in str(e.value)
 
 
+# -- (e) the step in two parts: gradient | update rule + fold ----------------
+
+def _whole_delta_step(spec, tr, mesh):
+    """The step before the update moved into PUSH: ``compute``'s whole
+    delta, fenced, through ``push_all``."""
+    def step(arr, batch, hyper):
+        model = _phase_boundary(spec.pull_all(arr), replicate_on=mesh)
+        delta, metrics = _phase_boundary(tr.compute(model, batch, hyper),
+                                         replicate_on=mesh)
+        return spec.push_all(arr, delta), metrics
+    return step
+
+
+def _run_step(body, tr, spec, mesh, steps):
+    tsh = block_sharding(mesh, spec.num_blocks)
+    step = jax.jit(traced_on(mesh, body), out_shardings=(tsh, None),
+                   donate_argnums=0)
+    start = np.zeros((spec.num_blocks * spec.block_size, tr.row_width),
+                     np.float32)
+    flat, _ = ravel_pytree(tr.model.init(jax.random.PRNGKey(tr.seed)))
+    start[: tr.num_rows] = np.asarray(tr._to_rows(flat, tr.num_rows))
+    arr = jax.device_put(start.reshape(spec.storage_shape), tsh)
+    batch = (jax.device_put(make_lm_data(4, 33, CFG.vocab_size, seed=7),
+                            NamedSharding(mesh, P("data"))),)
+    hyper = {k: jnp.asarray(v, jnp.float32)
+             for k, v in tr.hyperparams().items()}
+    losses = []
+    for _ in range(steps):
+        arr, metrics = step(arr, batch, hyper)
+        losses.append(float(metrics["loss"]))
+    return np.asarray(arr), losses
+
+
+def _mesh(name, devices):
+    return (build_mesh(devices[:1], data=1) if name == "one-device"
+            else build_mesh(devices[:8], data=2, model=4))
+
+
+@pytest.mark.parametrize("mesh_name", ["one-device", "data2-model4"])
+@pytest.mark.parametrize("optimizer", ["sgd", "momentum", "adam"])
+def test_shipped_step_equals_compute_then_push_all(optimizer, mesh_name,
+                                                   devices):
+    """Final table and every loss, bit for bit, over four steps. The
+    update runs on the stored rows wherever every device holds the table
+    whole; the one table here that the 2 x 4 mesh shards by rows (adam's
+    64 blocks) keeps the whole delta."""
+    mesh = _mesh(mesh_name, devices)
+    tr = _lm(optimizer, row_width=128)
+    spec = TableSpec(tr.model_table_config())
+    sharded = row_shards(mesh, spec.num_blocks) > 1
+    assert sharded == (mesh_name == "data2-model4" and optimizer == "adam")
+    assert update_lowering(spec, tr, mesh) == (
+        "whole_delta" if sharded else "row_ranges")
+    new, new_losses = _run_step(pull_all_step(spec, tr, mesh), tr, spec,
+                                mesh, 4)
+    old, old_losses = _run_step(_whole_delta_step(spec, tr, mesh), tr, spec,
+                                mesh, 4)
+    assert new_losses == old_losses and len(set(new_losses)) == 4
+    np.testing.assert_array_equal(new, old)
+    if tr.num_state_slots:
+        assert tr.counter(new.reshape(-1, tr.row_width)) == 4
+
+
+class _OnePart(TransformerTrainer):
+    def row_update_parts(self, capacity):
+        return None
+
+
+def _legacy_table(tr):
+    return dataclasses.replace(tr.model_table_config(),
+                               capacity=legacy_capacity(tr), num_blocks=5)
+
+
+_WHOLE_DELTA_CASES = {
+    # name: (trainer class, table config of the trainer, mesh)
+    "legacy_stride": (TransformerTrainer, _legacy_table, "one-device"),
+    "post_hook": (TransformerTrainer, lambda tr: dataclasses.replace(
+        tr.model_table_config(), update_fn="add_nonneg"), "one-device"),
+    "non_additive_fold": (TransformerTrainer, lambda tr: dataclasses.replace(
+        tr.model_table_config(), update_fn="max"), "one-device"),
+    "trainer_without_the_two_parts": (
+        _OnePart, lambda tr: tr.model_table_config(), "one-device"),
+    "rows_sharded_over_the_model_axis": (
+        TransformerTrainer, lambda tr: tr.model_table_config(),
+        "data2-model4"),
+}
+
+
+@pytest.mark.parametrize("case", list(_WHOLE_DELTA_CASES))
+def test_predicate_keeps_the_whole_delta(case, devices):
+    """Each thing the predicate looks at, one at a time: the step reads
+    ``whole_delta`` and IS ``compute`` + ``push_all``."""
+    cls, config, mesh_name = _WHOLE_DELTA_CASES[case]
+    mesh = _mesh(mesh_name, devices)
+    tr = cls(CFG, row_width=128, step_size=3e-3, optimizer="adam")
+    spec = TableSpec(config(tr))
+    assert update_lowering(spec, tr, mesh) == "whole_delta"
+    new, new_losses = _run_step(pull_all_step(spec, tr, mesh), tr, spec,
+                                mesh, 3)
+    old, old_losses = _run_step(_whole_delta_step(spec, tr, mesh), tr, spec,
+                                mesh, 3)
+    assert new_losses == old_losses
+    np.testing.assert_array_equal(new, old)
+
+
+def _padded(ranges, capacity):
+    delta = np.zeros((capacity, ranges[0][1].shape[1]), np.float32)
+    for first, rows in ranges:
+        delta[first:first + rows.shape[0]] = np.asarray(rows)
+    return jnp.asarray(delta)
+
+
+@pytest.mark.parametrize("firsts", [
+    [(0, 16), (24, 40), (72, 8)],   # most rows named
+    [(0, 16), (24, 8), (72, 8)],
+    [(40, 8)],
+])
+@pytest.mark.parametrize("num_blocks", [10, 3])  # 3: a padded tail block
+def test_range_push_equals_push_all_of_the_padded_delta(num_blocks, firsts):
+    rng = np.random.default_rng(0)
+    spec = TableSpec(TableConfig(table_id="r", capacity=80,
+                                 num_blocks=num_blocks, value_shape=(128,),
+                                 is_ordered=True))
+    assert spec.takes_row_ranges
+    arr = jnp.asarray(rng.normal(size=spec.storage_shape).astype(np.float32))
+    ranges = [(f, jnp.asarray(rng.normal(size=(n, 128)).astype(np.float32)))
+              for f, n in firsts]
+    np.testing.assert_array_equal(
+        spec.push_row_ranges(arr, ranges),
+        spec.push_all(arr, _padded(ranges, 80)))
+    names = {e.primitive.name for e in jax.make_jaxpr(
+        lambda a: spec.push_row_ranges(a, ranges))(arr).eqns}
+    assert "dynamic_update_slice" in names and not names & {
+        "scatter-add", "scatter_add", "gather", "pad", "concatenate"}, names
+
+
+@pytest.mark.parametrize("why,config,match", [
+    ("min", dict(update_fn="min"), "'min'"),
+    ("max", dict(update_fn="max"), "'max'"),
+    ("post_hook", dict(update_fn="add_nonneg"), "'add_nonneg'"),
+    ("hashed_keys", dict(is_ordered=False), "HashPartitioner"),
+])
+def test_range_push_refuses_by_name(why, config, match):
+    """A delta that names some rows and one that pads the others with
+    zeros fold to the same table only under the plain additive fold on
+    keys in storage order: anything else is refused, never approximated."""
+    spec = TableSpec(TableConfig(**{
+        "table_id": "r", "capacity": 80, "num_blocks": 10,
+        "value_shape": (128,), "is_ordered": True, **config}))
+    assert not spec.takes_row_ranges
+    arr = jnp.zeros(spec.storage_shape, jnp.float32)
+    with pytest.raises(ValueError, match=match):
+        spec.push_row_ranges(arr, [(0, jnp.ones((8, 128), jnp.float32))])
+    with pytest.raises(ValueError, match=match):
+        spec.fold_row_sections(arr, jnp.ones((8, 128), jnp.float32), {},
+                               lambda stored, g, scalars: (g,), rows=8,
+                               sections=1)
+
+
+@pytest.mark.parametrize("first,n", [(76, 8), (-1, 8), (8, 8)])
+def test_range_push_refuses_rows_outside_or_out_of_order(first, n):
+    spec = TableSpec(TableConfig(table_id="r", capacity=80, num_blocks=10,
+                                 value_shape=(128,), is_ordered=True))
+    arr = jnp.zeros(spec.storage_shape, jnp.float32)
+    ones = lambda k: jnp.ones((k, 128), jnp.float32)
+    with pytest.raises(ValueError, match="push_row_ranges"):
+        spec.push_row_ranges(arr, [(0, ones(16)), (first, ones(n))])
+
+
+# -- (f) the fold as one in-place pass: ops/sections.py ----------------------
+
+def _plain_rule(stored, g, consts):
+    """Elementwise across the sections, with no product next to a sum:
+    XLA's CPU backend contracts ``a * b + c`` where one fusion holds both,
+    so only such a rule is bit-equal between two CPU programs."""
+    p, m, v = stored
+    return ((g - m) - v, p - g,
+            jnp.sqrt(jnp.abs(v)) / (consts[0:1] + jnp.abs(g)))
+
+
+def _adam_rule(stored, g, consts):
+    new = optim.apply("adam", *stored[:1], g, *stored[1:], consts[0:1],
+                      {"lr": consts[1:2], "beta2": consts[2:3]})
+    return tuple(n - o for n, o in zip(new, stored))
+
+
+_FOLD_SHAPES = {
+    # name: (rows a section, row width, rows after the sections)
+    "one_tile": (8, 128, 8),
+    "one_short_block": (88, 128, 8),
+    "whole_blocks": (512, 128, 16),
+    "last_block_overlaps": (264, 128, 8),
+    "last_block_of_odd_tiles": (600, 256, 0),
+}
+
+
+def _fold_operands(rows, width, extra):
+    rng = np.random.default_rng(rows)
+    table = rng.normal(size=(3 * rows + extra, width)).astype(np.float32)
+    table[2 * rows:3 * rows] = np.abs(table[2 * rows:3 * rows])  # v >= 0
+    consts = np.broadcast_to(
+        np.asarray([3.0, 1e-3, 0.95], np.float32)[:, None], (3, width))
+    return (jnp.asarray(table),
+            jnp.asarray(rng.normal(size=(rows, width)).astype(np.float32)),
+            jnp.asarray(consts))
+
+
+@pytest.mark.parametrize("shape", list(_FOLD_SHAPES))
+def test_fold_kernel_equals_its_reference(shape):
+    """Every section's every row once, whatever the block count: a last
+    block that overlaps the one before it writes only its new rows, and
+    the rows after the sections are not touched."""
+    from harmony_tpu.ops.sections import (
+        fold_row_sections,
+        fold_row_sections_ref,
+    )
+
+    rows, width, extra = _FOLD_SHAPES[shape]
+    table, g, consts = _fold_operands(rows, width, extra)
+    fold = dict(rows=rows, sections=3)
+    want = jax.jit(lambda *a: fold_row_sections_ref(*a, _plain_rule, **fold))(
+        table, g, consts)
+    got = jax.jit(lambda *a: fold_row_sections(
+        *a, _plain_rule, interpret=True, **fold))(table, g, consts)
+    np.testing.assert_array_equal(got, want)
+    assert np.abs(np.asarray(want) - np.asarray(table))[:3 * rows].min() > 0
+    np.testing.assert_array_equal(got[3 * rows:], table[3 * rows:])
+    # Adam's rule: the same jnp ops, so at most the CPU's contraction of
+    # ``b1 * m + (1 - b1) * g`` apart (the chip reads equal: PERF.md PR 30)
+    want = jax.jit(lambda *a: fold_row_sections_ref(*a, _adam_rule, **fold))(
+        table, g, consts)
+    got = jax.jit(lambda *a: fold_row_sections(
+        *a, _adam_rule, interpret=True, **fold))(table, g, consts)
+    np.testing.assert_allclose(got, want, rtol=0, atol=5e-7)  # an ulp of 4
+
+
+@pytest.mark.parametrize("bad,match", [
+    (dict(rows=12), "8-row tiles"),
+    (dict(width=100), "whole lanes"),
+    (dict(dtype=jnp.bfloat16), "float32"),
+    (dict(sections=4), "fold_row_sections_ref"),   # more rows than it has
+])
+def test_fold_kernel_refuses_what_it_cannot_tile(bad, match):
+    from harmony_tpu.ops.sections import fold_row_sections
+
+    rows, width = bad.get("rows", 16), bad.get("width", 128)
+    dtype = bad.get("dtype", jnp.float32)
+    table = jnp.zeros((3 * rows + 8, width), dtype)
+    with pytest.raises(ValueError, match=match):
+        fold_row_sections(table, jnp.zeros((rows, width), dtype),
+                          jnp.zeros((1, width), dtype), _plain_rule,
+                          rows=rows, sections=bad.get("sections", 3),
+                          interpret=True)
+
+
+@pytest.fixture()
+def as_tpu(monkeypatch):
+    """Steer the lowering as on a TPU mesh, with the kernel body in the
+    Pallas interpreter."""
+    import functools
+
+    from harmony_tpu.ops import sections
+    from harmony_tpu.utils import platform
+
+    monkeypatch.setattr(platform, "mesh_is_tpu", lambda mesh: True)
+    traced = []
+    kernel = functools.partial(sections.fold_row_sections, interpret=True)
+    monkeypatch.setattr(
+        sections, "fold_row_sections",
+        lambda *a, **kw: traced.append(a[0].shape) or kernel(*a, **kw))
+    return traced
+
+
+def test_fold_lowering_is_chosen_from_what_is_traced(as_tpu, devices):
+    from harmony_tpu.utils.platform import on_mesh
+
+    one = build_mesh(devices[:1], data=1)
+    spec = TableSpec(_lm("adam", row_width=128).model_table_config())
+    rows = spec.config.capacity // 3 // 8 * 8
+    assert spec.fold_lowering(rows, 3) == "xla"  # no mesh named: XLA
+    with on_mesh(one):
+        assert spec.fold_lowering(rows, 3) == "pallas_sections"
+        assert spec.fold_lowering(rows + 4, 3) == "xla"
+        for field, value in (("value_shape", (100,)), ("dtype", "bfloat16"),
+                             ("value_shape", (2, 128))):
+            other = TableSpec(dataclasses.replace(spec.config,
+                                                  **{field: value}))
+            assert other.fold_lowering(rows, 3) == "xla", field
+        nine = TableSpec(dataclasses.replace(
+            spec.config, capacity=9 * 64, num_blocks=64))
+        assert nine.block_size == 9 and nine.fold_lowering(8, 3) == "xla"
+    with on_mesh(build_mesh(devices[:8], data=8)):  # replicas: XLA's
+        assert spec.fold_lowering(rows, 3) == "xla"
+
+
+@pytest.mark.parametrize("optimizer", ["sgd", "momentum", "adam"])
+def test_shipped_step_on_the_kernel_lowering(optimizer, as_tpu, devices):
+    """The step as a one-chip TPU mesh lowers it — the optimizer inside
+    ``harmony_fold_row_sections``, interpreted — against the step as the
+    CPU mesh lowers it: every loss equal, the table equal to the CPU's
+    contraction of a product into a sum (1 ulp of an element; on the chip
+    kernel and XLA read equal to the last bit, PERF.md PR 30)."""
+    from harmony_tpu.utils import platform
+
+    mesh = build_mesh(devices[:1], data=1)
+    tr = _lm(optimizer, row_width=128)
+    spec = TableSpec(tr.model_table_config())
+    new, new_losses = _run_step(pull_all_step(spec, tr, mesh), tr, spec,
+                                mesh, 4)
+    assert as_tpu == [(spec.num_blocks * spec.block_size, 128)]
+    platform.mesh_is_tpu = lambda mesh: False
+    old, old_losses = _run_step(_whole_delta_step(spec, tr, mesh), tr, spec,
+                                mesh, 4)
+    assert len(as_tpu) == 1
+    np.testing.assert_allclose(new_losses, old_losses, rtol=1e-6)
+    np.testing.assert_allclose(new, old, rtol=1e-5, atol=1e-8)
+    if tr.num_state_slots:
+        assert tr.counter(new.reshape(-1, tr.row_width)) == 4
+
+
+def test_worker_records_how_its_step_folds(as_tpu, devices):
+    """Through ``WorkerTasklet``: the step it builds for a one-chip TPU
+    mesh takes the kernel, and the tenant's ledger row says so."""
+    from harmony_tpu.dolphin import TrainingDataProvider, WorkerTasklet
+    from harmony_tpu.metrics.accounting import ledger
+    from harmony_tpu.runtime import progcache
+
+    mesh = build_mesh(devices[:1], data=1)
+    tr = _lm("adam", row_width=128)
+    table = DenseTable(TableSpec(tr.model_table_config()), mesh)
+    ctx = TrainerContext(
+        params=TrainerParams(num_epochs=2, num_mini_batches=2),
+        model_table=table)
+    data = TrainingDataProvider(
+        (make_lm_data(8, 33, CFG.vocab_size, seed=5),), 2)
+    progcache.clear()  # the step's key does not name its lowering
+    try:
+        losses = WorkerTasklet("j-fold", ctx, tr, data, mesh).run()["losses"]
+    finally:
+        progcache.clear()
+    assert as_tpu and len(losses) == 2 and losses[1] < losses[0]
+    layout = ledger().snapshot()["j-fold"]["table_layout"]
+    assert layout["update_lowering"] == "row_ranges"
+    assert layout["fold_lowering"] == "pallas_sections"
+
+
 # -- the record that says it engaged -----------------------------------------
 
 def test_table_layout_record():
@@ -244,6 +604,24 @@ def test_table_layout_record():
     assert gauge[("layout-lm", "lm")] == 1
     assert gauge[("layout-nine", "nine")] == 0
     assert "harmony_table_tile_exact{" in get_registry().expose()
+    # how the step applies its update: a key of the same row, and a gauge
+    table_layout.note_update("layout-lm", "lm", "row_ranges")
+    table_layout.note_fold("layout-lm", "lm", "pallas_sections")
+    table_layout.note_update("layout-nine", "nine", "whole_delta")
+    assert ledger().snapshot()["layout-lm"]["table_layout"] == {
+        **row, "update_lowering": "row_ranges",
+        "fold_lowering": "pallas_sections"}
+    assert ('harmony_table_fold_pallas_sections{job="layout-lm",table="lm",'
+            in get_registry().expose())
+    assert (ledger().snapshot()["layout-nine"]["table_layout"]
+            ["update_lowering"]) == "whole_delta"
+    exposed = {line.split(",pid=")[0]: line.rsplit(" ", 1)[1]
+               for line in get_registry().expose().splitlines()
+               if line.startswith("harmony_table_update_row_ranges{")}
+    assert exposed['harmony_table_update_row_ranges{job="layout-lm",'
+                   'table="lm"'] == "1", exposed
+    assert exposed['harmony_table_update_row_ranges{job="layout-nine",'
+                   'table="nine"'] == "0", exposed
 
 
 def test_status_carries_the_layout_of_a_submitted_lm_tenant():
@@ -273,4 +651,5 @@ def test_status_carries_the_layout_of_a_submitted_lm_tenant():
         server.shutdown(timeout=60)
     tr = TransformerTrainer(**app)
     assert row == {"block_size": 8, "tail_rows": 0, "tile_exact": 1,
-                   "section_stride": tr.section_rows, "rows": tr.capacity}
+                   "section_stride": tr.section_rows, "rows": tr.capacity,
+                   "update_lowering": "row_ranges", "fold_lowering": "xla"}
