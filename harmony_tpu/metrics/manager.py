@@ -184,15 +184,20 @@ class MetricManager:
         store = peek_budget()
         budgets = (store.snapshot_memoized(window_sec)
                    if store is not None else {})
-        from harmony_tpu.metrics import moe
+        from harmony_tpu.metrics import kda, moe
 
         routing = moe.stats_by_job()
+        kinds, mixers = kda.kinds_by_job(), kda.stats_by_job()
         for jid, row in rows.items():
             rep = stragglers.get(jid)
             row["straggler_ratio"] = rep["ratio"] if rep else None
             # dropless expert tenants: share of token-slots computed here,
             # most loaded held expert over the mean (metrics/moe.py)
             row["moe"] = routing.get(jid)
+            # blocks by token-mixer kind, and the KDA blocks' mean decay
+            # and beta (metrics/kda.py)
+            row["layer_kinds"] = kinds.get(jid)
+            row["kda"] = mixers.get(jid)
             b = budgets.get(jid)
             if b:
                 from harmony_tpu.metrics import critpath

@@ -26,7 +26,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -64,7 +64,8 @@ class TransformerConfig:
     # -- the block's architecture (a model's published shape, not knobs):
     # the defaults are the GPT-2-era block above, bit for bit ------------
     pos: str = "learned"            # "learned" table | "rope" (rotate-half
-    rope_theta: float = 10000.0     # rotary on q and k, no table)
+    rope_theta: float = 10000.0     # rotary on q and k, no table) | "none"
+                                    # (beside KDA blocks only, see below)
     qk_norm: bool = False           # RMSNorm over the whole d_model-wide q
                                     # and k before the head split (OLMoE)
     ffn: str = "gelu"               # "gelu" MLP | "swiglu" (gated SiLU)
@@ -102,6 +103,18 @@ class TransformerConfig:
     moe_norm_topk: bool = False
     moe_routed_scale: float = 1.0
     moe_seq_aux: bool = False
+    # Kimi Linear's hybrid (arXiv:2510.26692): the blocks listed in
+    # ``linear_layers`` (0-based indices) mix tokens by gated delta-rule
+    # linear attention with a per-channel decay (KDA, ops/kda.py) over
+    # ``linear_heads`` heads of ``linear_head_dim`` (q, k and v alike), each
+    # of q, k, v through a depthwise causal convolution of ``short_conv``
+    # taps and a SiLU; every other block keeps ``attn_kind``'s softmax
+    # attention. ``pos="none"``: no table and no rotary anywhere — the
+    # convolutions and the decay carry the order.
+    linear_layers: Tuple[int, ...] = ()
+    linear_heads: int = 0
+    linear_head_dim: int = 0
+    short_conv: int = 0
 
     def __post_init__(self):
         from harmony_tpu.models.common import validate_attn
@@ -112,8 +125,32 @@ class TransformerConfig:
             raise ValueError(f"unknown sp_attn {self.sp_attn!r}")
         if self.moe_experts and self.moe_every < 1:
             raise ValueError("moe_every must be >= 1")
-        if self.pos not in ("learned", "rope"):
-            raise ValueError(f"unknown pos {self.pos!r}: 'learned' or 'rope'")
+        # a job's app_params arrive as JSON: a list, held as a tuple
+        object.__setattr__(self, "linear_layers",
+                           tuple(int(i) for i in self.linear_layers))
+        if self.pos not in ("learned", "rope", "none"):
+            raise ValueError(f"unknown pos {self.pos!r}: 'learned', 'rope' "
+                             "or 'none'")
+        kda = (self.linear_heads, self.linear_head_dim, self.short_conv)
+        if self.linear_layers and (
+                min(kda) < 1 or sorted(set(self.linear_layers))
+                != list(self.linear_layers)
+                or not 0 <= self.linear_layers[0]
+                or self.linear_layers[-1] >= self.n_layers):
+            raise ValueError(
+                "linear_layers lists blocks 0..n_layers-1 in order, once "
+                "each, and needs linear_heads, linear_head_dim and "
+                f"short_conv (got {self.linear_layers}, {kda})")
+        if not self.linear_layers and any(kda):
+            raise ValueError("linear_heads / linear_head_dim / short_conv "
+                             "belong to KDA blocks: set linear_layers")
+        if self.pos == "none" and not self.linear_layers:
+            raise ValueError(
+                "pos='none' runs only beside KDA blocks (linear_layers): "
+                "their convolutions and decay carry the order, and a latent "
+                "(attn_kind='mla') block among them may then go without "
+                "rotary; a model of softmax blocks alone cannot tell "
+                "positions apart")
         if self.ffn not in ("gelu", "swiglu"):
             raise ValueError(f"unknown ffn {self.ffn!r}: 'gelu' or 'swiglu'")
         mla = (self.kv_lora_rank, self.qk_nope_head_dim,
@@ -126,11 +163,13 @@ class TransformerConfig:
                              "qk_rope_head_dim / v_head_dim belong to latent "
                              "attention: set attn_kind='mla'")
         if self.attn_kind == "mla" and (
-                min(mla) < 1 or self.pos != "rope" or self.qk_norm):
+                min(mla) < 1 or self.pos == "learned" or self.qk_norm):
             raise ValueError(
                 "attn_kind='mla' needs kv_lora_rank, qk_nope_head_dim, "
                 "qk_rope_head_dim and v_head_dim, pos='rope' (rotary on the "
-                "qk_rope parts) and no qk_norm (the latent has its own norm)")
+                "qk_rope parts; 'none' beside KDA blocks: the parts are "
+                "carried and never turned) and no qk_norm (the latent has "
+                "its own norm)")
         turned = (self.qk_rope_head_dim if self.attn_kind == "mla"
                   else self.head_dim)  # the width rotary positions turn
         if self.pos == "rope" and turned % 2:
@@ -194,6 +233,16 @@ class TransformerConfig:
         ask here, so a leading dense layer never shows as idle experts."""
         return tuple(i for i in range(self.n_layers) if self.is_moe_layer(i))
 
+    def layer_kinds(self) -> Tuple[str, ...]:
+        """Each block's token mixer, in order — the ONE answer to "which
+        kind of layer is this": ``"kda"`` for the blocks in
+        ``linear_layers``, else ``attn_kind`` (``"mha"`` | ``"mla"``). The
+        block, ``init``, the trainer's vectors (``kda_decay_mean [kda
+        blocks]``), STATUS ``layer_kinds`` and the benchmark's work
+        functions ask here."""
+        return tuple("kda" if i in self.linear_layers else self.attn_kind
+                     for i in range(self.n_layers))
+
     def ffn_width(self, i: int) -> int:
         """The dense MLP's width in block i (an expert's, in an expert
         layer)."""
@@ -232,13 +281,13 @@ class TransformerConfig:
         if not (self.pos == "learned" and self.ffn == "gelu"
                 and self.tie_embeddings and not self.qk_norm
                 and not self.moe_top_k and self.attn_kind == "mha"
-                and not self.moe_first_dense):
+                and not self.moe_first_dense and not self.linear_layers):
             raise ValueError(
                 f"{who} runs the GPT-2-era block only (learned positions, "
-                "GELU, tied readout, Switch experts); rotary / QK-norm / "
-                "SwiGLU / untied / dropless / latent-attention / leading-"
-                "dense configs train through TransformerLM.loss and "
-                "TransformerTrainer")
+                "GELU, tied readout, Switch experts); rotary / no-position / "
+                "QK-norm / SwiGLU / untied / dropless / latent-attention / "
+                "KDA linear-attention / leading-dense configs train through "
+                "TransformerLM.loss and TransformerTrainer")
 
 
 from harmony_tpu.models.common import rms_norm as _norm  # noqa: E402
@@ -259,6 +308,50 @@ def rope(x, theta: float, pos_offset=0):
             ).astype(x.dtype)
 
 
+#: a KDA block's initial decay, as flash-linear-attention's ``kda`` layer
+#: draws it: ``a_log = log U(1, 16)`` a head, ``dt_bias`` the inverse
+#: softplus of ``exp U(log 0.001, log 0.1)`` a channel; and the epsilon of
+#: its l2 norm (under the root, beside the sum of squares)
+KDA_A = (1.0, 16.0)
+KDA_DT = (1e-3, 1e-1)
+KDA_L2_EPS = 1e-6
+
+
+def init_kda_params(k_in: jax.Array, k_out: jax.Array,
+                    cfg: TransformerConfig) -> Dict[str, jnp.ndarray]:
+    """A KDA mixer's parameters (``TransformerLM._kda_mixer``): q / k / v
+    projections ``[d, H dh]`` with their convolution taps ``[K, H dh]``
+    (uniform in ``+-K^-1/2``, a depthwise ``Conv1d``'s default), the decay's
+    low-rank pair ``wf_a [d, dh]`` / ``wf_b [dh, H dh]`` with ``a_log [H]``
+    and ``dt_bias [H dh]``, ``wb [H, d]`` for beta, the output gate's pair
+    ``wg_a`` / ``wg_b``, the per-head norm ``o_norm [dh]`` (one weight for
+    every head) and ``wo [H dh, d]``."""
+    from harmony_tpu.models.common import dense_init as dense
+
+    d, H, dh, K = (cfg.d_model, cfg.linear_heads, cfg.linear_head_dim,
+                   cfg.short_conv)
+    kq, kk, kv, kcq, kck, kcv, kfa, kfb, ka, kdt, kb, kga, kgb = \
+        jax.random.split(k_in, 13)
+    taps = lambda key: jax.random.uniform(
+        key, (K, H * dh), jnp.float32, -K ** -0.5, K ** -0.5)
+    dt = jnp.exp(jax.random.uniform(kdt, (H * dh,), jnp.float32,
+                                    np.log(KDA_DT[0]), np.log(KDA_DT[1])))
+    return {
+        "wq": dense(kq, (d, H * dh)), "wk": dense(kk, (d, H * dh)),
+        "wv": dense(kv, (d, H * dh)),
+        "conv_q": taps(kcq), "conv_k": taps(kck), "conv_v": taps(kcv),
+        "wf_a": dense(kfa, (d, dh)), "wf_b": dense(kfb, (dh, H * dh)),
+        "a_log": jnp.log(jax.random.uniform(ka, (H,), jnp.float32, *KDA_A)),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+        # stored [H, d]: a leaf whose minor dimension is a handful of heads
+        # makes XLA lay the whole flat parameter vector out 8 wide
+        "wb": dense(kb, (d, H)).T,
+        "wg_a": dense(kga, (d, dh)), "wg_b": dense(kgb, (dh, H * dh)),
+        "o_norm": jnp.ones((dh,), jnp.float32),
+        "wo": dense(k_out, (H * dh, d)),
+    }
+
+
 class TransformerLM:
     """Pure-functional decoder-only LM: ``init`` -> params, ``apply`` ->
     logits, ``loss`` -> mean next-token cross-entropy."""
@@ -276,10 +369,17 @@ class TransformerLM:
         from harmony_tpu.models.common import dense_init as dense
 
         layers = []
+        kinds = cfg.layer_kinds()
         for i, kl in enumerate(k_layers):
             ks = jax.random.split(kl, 4)
             f = cfg.ffn_width(i)
-            if cfg.attn_kind == "mla":
+            if kinds[i] == "kda":
+                layer = {
+                    "ln1": jnp.ones((d,), jnp.float32),
+                    "kda": init_kda_params(ks[0], ks[1], cfg),
+                    "ln2": jnp.ones((d,), jnp.float32),
+                }
+            elif cfg.attn_kind == "mla":
                 kq, ka, kb = jax.random.split(ks[0], 3)
                 nope, rot = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
                 r, vd = cfg.kv_lora_rank, cfg.v_head_dim
@@ -343,9 +443,35 @@ class TransformerLM:
                     * shape[0] ** -0.5).astype(np.float32)
 
         layers = []
+        kinds = cfg.layer_kinds()
         for i in range(cfg.n_layers):
             f = cfg.ffn_width(i)
-            if cfg.attn_kind == "mla":
+            if kinds[i] == "kda":
+                H, dh, K = (cfg.linear_heads, cfg.linear_head_dim,
+                            cfg.short_conv)
+                taps = lambda: rng.uniform(-K ** -0.5, K ** -0.5,
+                                           (K, H * dh)).astype(np.float32)
+                dt = np.exp(rng.uniform(np.log(KDA_DT[0]), np.log(KDA_DT[1]),
+                                        H * dh))
+                layer = {
+                    "ln1": np.ones((d,), np.float32),
+                    "kda": {
+                        "wq": dense((d, H * dh)), "wk": dense((d, H * dh)),
+                        "wv": dense((d, H * dh)), "conv_q": taps(),
+                        "conv_k": taps(), "conv_v": taps(),
+                        "wf_a": dense((d, dh)), "wf_b": dense((dh, H * dh)),
+                        "a_log": np.log(rng.uniform(*KDA_A, H)
+                                        ).astype(np.float32),
+                        "dt_bias": (dt + np.log(-np.expm1(-dt))
+                                    ).astype(np.float32),
+                        "wb": dense((d, H)).T.copy(),
+                        "wg_a": dense((d, dh)),
+                        "wg_b": dense((dh, H * dh)),
+                        "o_norm": np.ones((dh,), np.float32),
+                        "wo": dense((H * dh, d))},
+                    "ln2": np.ones((d,), np.float32),
+                }
+            elif cfg.attn_kind == "mla":
                 h, nope, rot = (cfg.n_heads, cfg.qk_nope_head_dim,
                                 cfg.qk_rope_head_dim)
                 r, vd = cfg.kv_lora_rank, cfg.v_head_dim
@@ -431,8 +557,11 @@ class TransformerLM:
         ``xn [B, S, d]``: ``q [B, h, S, nope + rope]``, ``k`` the same width
         (each head's decompressed ``k_nope`` beside the ONE rotary key all
         heads share) and ``v [B, h, S, v_head_dim]``. Rotary turns only the
-        ``rope``-wide parts; the softmax scale is ``(nope + rope) ** -0.5``,
-        the kernels' default for a q that wide."""
+        ``rope``-wide parts, and is skipped under ``pos="none"`` (Kimi
+        Linear's latent blocks, ``mla_use_nope``: the parts are carried as
+        they are, the order comes from the KDA blocks around); the softmax
+        scale is ``(nope + rope) ** -0.5`` either way, the kernels' default
+        for a q that wide."""
         cfg = self.config
         B, S = xn.shape[0], xn.shape[1]
         h, nope, rot = cfg.n_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
@@ -443,27 +572,88 @@ class TransformerLM:
         c = _norm(ckv[..., :r], layer["kv_norm"].astype(cfg.dtype),
                   cfg.norm_eps)
         kv = to_heads(c @ layer["wkv_b"].astype(cfg.dtype), nope + vd)
-        q_pe = rope(q[..., nope:], cfg.rope_theta, pos_offset)
-        k_pe = rope(ckv[:, None, :, r:], cfg.rope_theta, pos_offset)
+        turn = ((lambda t: rope(t, cfg.rope_theta, pos_offset))
+                if cfg.pos == "rope" else (lambda t: t))
+        q_pe = turn(q[..., nope:])
+        k_pe = turn(ckv[:, None, :, r:])
         q = jnp.concatenate([q[..., :nope], q_pe], axis=-1)
         k = jnp.concatenate(
             [kv[..., :nope], jnp.broadcast_to(k_pe, (B, h, S, rot))], axis=-1)
         return q, k, kv[..., nope:]
 
+    def _kda_mixer(self, xn, p):
+        """Kimi Linear's KDA token mixer on the normed input ``xn [B, S,
+        d]``: ``(y [B, S, d], {"decay", "beta"})``. q, k, v each through a
+        depthwise causal convolution and a SiLU; per head ``q = l2norm(q)
+        dk^-1/2``, ``k = l2norm(k)``; the per-channel log-decay ``g =
+        -exp(a_log_h) softplus(W_f (x) + dt_bias)``, ``beta = sigmoid(w_b
+        x)``; the gated delta rule (ops/kda.py); the output ``W_o [rmsnorm
+        per head (o) * sigmoid(W_g (x))]``, both gates through a
+        ``linear_head_dim``-wide bottleneck. The statistics are the layer's
+        mean decay ``exp(g)`` and mean ``beta`` (no gradient)."""
+        from harmony_tpu.ops.kda import kda_attention
+
+        cfg = self.config
+        B, S = xn.shape[0], xn.shape[1]
+        H, dh, dt = cfg.linear_heads, cfg.linear_head_dim, cfg.dtype
+        f32 = jnp.float32
+        w = lambda name: p[name].astype(dt)
+        heads = lambda t: t.reshape(B, S, H, dh).transpose(0, 2, 1, 3)
+
+        def conv(t, taps):  # y_t = sum_j taps[j] t_{t - (K-1) + j}, no bias
+            K = taps.shape[0]
+            tp = jnp.pad(t.astype(f32), ((0, 0), (K - 1, 0), (0, 0)))
+            return heads(jax.nn.silu(
+                sum(tp[:, j:j + S] * taps[j] for j in range(K))))
+
+        def l2(t):
+            return t * lax.rsqrt(jnp.sum(t * t, axis=-1, keepdims=True)
+                                 + KDA_L2_EPS)
+
+        q = l2(conv(xn @ w("wq"), p["conv_q"])) * dh ** -0.5
+        k = l2(conv(xn @ w("wk"), p["conv_k"]))
+        v = conv(xn @ w("wv"), p["conv_v"])
+        f = heads(((xn @ w("wf_a")) @ w("wf_b")).astype(f32) + p["dt_bias"])
+        g = -jnp.exp(p["a_log"])[None, :, None, None] * jax.nn.softplus(f)
+        beta = jax.nn.sigmoid(
+            jnp.einsum("bsd,hd->bhs", xn, w("wb")).astype(f32))
+        o = kda_attention(q.astype(dt), k.astype(dt), v.astype(dt), g, beta)
+        gate = jax.nn.sigmoid(
+            heads((xn @ w("wg_a")) @ w("wg_b")).astype(f32)).astype(dt)
+        o = _norm(o, w("o_norm"), cfg.norm_eps) * gate
+        y = o.transpose(0, 2, 1, 3).reshape(B, S, H * dh) @ w("wo")
+        return y, {"decay": lax.stop_gradient(jnp.exp(g).mean()),
+                   "beta": lax.stop_gradient(beta.mean())}
+
     def _block(self, x, layer, axis_name: Optional[str],
                moe_axis: Optional[str] = None, pos_offset: Any = 0):
         """One pre-norm decoder block — the shared body of ``apply`` and
-        the pipeline-parallel stage fn. Returns ``(x, aux)``: aux is the
-        Switch load-balance loss when the block carries a Switch MoE FFN,
-        the dropless layer's routing statistics (a dict of sums,
-        models/moe.py) when it carries that, 0 otherwise. ``moe_axis`` =
-        expert-parallel mesh axis (see ffn_apply). The published q/k/v
-        projections are the three column blocks of ``wqkv``."""
+        the pipeline-parallel stage fn. Returns ``(x, aux, mix)``: aux is
+        the Switch load-balance loss when the block carries a Switch MoE
+        FFN, the dropless layer's routing statistics (a dict of sums,
+        models/moe.py) when it carries that, 0 otherwise; mix is a KDA
+        block's mixer statistics (``_kda_mixer``), None for a softmax block.
+        ``moe_axis`` = expert-parallel mesh axis (see ffn_apply). The
+        published q/k/v projections are the three column blocks of
+        ``wqkv``."""
         cfg = self.config
-        B, S = x.shape[0], x.shape[1]
-        d, h, hd = cfg.d_model, cfg.n_heads, cfg.head_dim
         eps = cfg.norm_eps
         xn = _norm(x, layer["ln1"].astype(cfg.dtype), eps)
+        if "kda" in layer:
+            y, mix = self._kda_mixer(xn, layer["kda"])
+        else:
+            y, mix = self._softmax_mixer(xn, layer, axis_name, pos_offset), None
+        x = x + y
+        xn = _norm(x, layer["ln2"].astype(cfg.dtype), eps)
+        out, aux = ffn_apply(cfg, layer, xn, moe_axis=moe_axis)
+        return x + out, aux, mix
+
+    def _softmax_mixer(self, xn, layer, axis_name, pos_offset):
+        """Softmax attention (``attn_kind``) on the normed input ``xn [B,
+        S, d]`` through its output projection."""
+        cfg = self.config
+        B, S = xn.shape[0], xn.shape[1]
+        h, hd, eps = cfg.n_heads, cfg.head_dim, cfg.norm_eps
         if cfg.attn_kind == "mla":
             q, k, v = self._latent_qkv(xn, layer, pos_offset)
         else:
@@ -479,10 +669,7 @@ class TransformerLM:
                 k = rope(k, cfg.rope_theta, pos_offset)
         o = self._attention(q, k, v, axis_name)
         o = o.transpose(0, 2, 1, 3).reshape(B, S, h * v.shape[3])
-        x = x + o @ layer["wo"].astype(cfg.dtype)
-        xn = _norm(x, layer["ln2"].astype(cfg.dtype), eps)
-        out, aux = ffn_apply(cfg, layer, xn, moe_axis=moe_axis)
-        return x + out, aux
+        return o @ layer["wo"].astype(cfg.dtype)
 
     def apply(
         self,
@@ -499,6 +686,14 @@ class TransformerLM:
         """apply + the MoE aux: the summed Switch loss (0 for dense
         configs), or for dropless configs the routing statistics summed
         over the layers plus ``tokens_by_layer [moe layers, E]``."""
+        return self._forward(params, tokens, axis_name, pos_offset,
+                             moe_axis)[:2]
+
+    def _forward(self, params, tokens, axis_name=None, pos_offset=0,
+                 moe_axis=None):
+        """``(logits, aux, mixers)``: ``_apply_with_aux``'s pair and the
+        KDA blocks' statistics, ``{"decay", "beta"}`` each ``[kda blocks]``
+        (None for a model without such blocks)."""
         cfg = self.config
         x = _embed_in(cfg, params["embed"], params.get("pos"), tokens,
                       pos_offset)
@@ -516,8 +711,11 @@ class TransformerLM:
             block = jax.checkpoint(block)
         aux = jnp.asarray(0.0, jnp.float32)
         routed = []  # dropless layers' statistics
+        mixers = []  # KDA blocks' statistics
         for layer in params["layers"]:
-            x, a = block(x, layer)
+            x, a, mix = block(x, layer)
+            if mix is not None:
+                mixers.append(mix)
             if isinstance(a, dict):
                 routed.append(a)
             else:
@@ -529,7 +727,9 @@ class TransformerLM:
         # f32 logits for a stable softmax; the readout is the embedding
         # (weight-tied) unless the model has a head of its own
         head = params["embed"].T if cfg.tie_embeddings else params["head"]
-        return x.astype(jnp.float32) @ head, aux
+        mixers = (jax.tree.map(lambda *xs: jnp.stack(xs), *mixers)
+                  if mixers else None)
+        return x.astype(jnp.float32) @ head, aux, mixers
 
     def loss(self, params, tokens, axis_name=None) -> jnp.ndarray:
         """Mean next-token cross-entropy over the (single-device) batch,
@@ -537,25 +737,29 @@ class TransformerLM:
         return self.loss_and_metrics(params, tokens, axis_name)[0]
 
     def loss_and_metrics(self, params, tokens, axis_name=None):
-        """``(loss, metrics)``: metrics is empty except for dropless expert
-        configs, which report the loss's three terms and the token-slots
-        each expert of each layer was chosen for (a vector per step)."""
+        """``(loss, metrics)``: dropless expert configs report the loss's
+        three terms and the token-slots each expert of each layer was
+        chosen for (a vector per step); models with KDA blocks each such
+        block's mean decay and mean beta (vectors ``[kda blocks]``)."""
         cfg = self.config
-        logits, aux = self._apply_with_aux(params, tokens[:, :-1],
-                                           axis_name=axis_name)
+        logits, aux, mixers = self._forward(params, tokens[:, :-1],
+                                            axis_name=axis_name)
         ce = _next_token_ce(logits, tokens[:, 1:])
+        kda = ({} if mixers is None else
+               {"kda_decay_mean": mixers["decay"],
+                "kda_beta_mean": mixers["beta"]})
         if cfg.moe_seq_aux:  # each layer's mean over sequences, summed
             return ce + cfg.moe_aux_weight * aux["seq_lb"], {
                 "ce": ce, "aux_seq": aux["seq_lb"],
-                "moe_expert_tokens": aux["tokens_by_layer"]}
+                "moe_expert_tokens": aux["tokens_by_layer"], **kda}
         if cfg.moe_top_k:
             lb, z = routing_losses(aux, cfg.moe_experts)
             loss = ce + cfg.moe_aux_weight * lb + cfg.moe_z_weight * z
             return loss, {"ce": ce, "aux_lb": lb, "aux_z": z,
-                          "moe_expert_tokens": aux["tokens_by_layer"]}
+                          "moe_expert_tokens": aux["tokens_by_layer"], **kda}
         if cfg.moe_experts:
-            return ce + cfg.moe_aux_weight * aux, {}
-        return ce, {}
+            return ce + cfg.moe_aux_weight * aux, kda
+        return ce, kda
 
 
 def routing_losses(stats, num_experts: int):
@@ -1103,6 +1307,13 @@ class TransformerTrainer(PyTreeTrainer):
         tokens = batch[0] if isinstance(batch, (tuple, list)) else batch
         return self.model.loss_and_metrics(params, tokens)
 
+    def init_global_settings(self, ctx) -> None:
+        super().init_global_settings(ctx)
+        from harmony_tpu.metrics import kda
+        from harmony_tpu.tracing.span import current_job
+
+        kda.note_layer_kinds(current_job() or "-", self.config.layer_kinds())
+
     def observe_step_vectors(self, job_id: str, vectors) -> None:
         if "moe_expert_tokens" in vectors:
             from harmony_tpu.metrics import moe
@@ -1110,3 +1321,8 @@ class TransformerTrainer(PyTreeTrainer):
             moe.observe(job_id, vectors["moe_expert_tokens"],
                         self.config.dropless_cfg.experts_held,
                         self.config.moe_layers())
+        if "kda_decay_mean" in vectors:
+            from harmony_tpu.metrics import kda
+
+            kda.observe(job_id, vectors["kda_decay_mean"],
+                        vectors["kda_beta_mean"], self.config.linear_layers)

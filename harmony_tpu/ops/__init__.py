@@ -4,10 +4,12 @@ The reference reaches native compute through Breeze -> netlib JNI -> BLAS
 (SURVEY.md §5.9 item 1); the TPU rebuild's equivalent is XLA for everything
 fusible plus hand-written Pallas kernels where a custom schedule beats the
 compiler: streaming-softmax attention (flash), MXU one-hot histograms
-(GBT's hot op), and segment reductions (push aggregation).
+(GBT's hot op), segment reductions (push aggregation), ragged grouped
+matmuls (experts) and the chunked gated delta-rule scan (KDA).
 """
 from harmony_tpu.ops.attention import blockwise_attention, flash_attention
 from harmony_tpu.ops.histogram import segment_sum, weighted_histogram
+from harmony_tpu.ops.kda import kda_attention
 from harmony_tpu.ops.mxu import mxu_dot
 from harmony_tpu.ops.ring import ring_attention
 from harmony_tpu.ops.sparse import gather_rows, segment_sum_rows
@@ -19,6 +21,7 @@ __all__ = [
     "blockwise_attention",
     "flash_attention",
     "gather_rows",
+    "kda_attention",
     "mxu_dot",
     "ring_attention",
     "segment_sum",
