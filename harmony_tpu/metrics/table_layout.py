@@ -10,9 +10,14 @@ created or restored (jobserver/entity.py):
 
   * ``harmony_table_tile_exact{job,table}`` — 1 when ``block_size % 8 == 0``
     and no tail rows, else 0;
-  * STATUS ``tenants.<job>.table_layout`` = ``{block_size, tail_rows,
-    section_stride, rows, tile_exact}`` (a row of the tenant ledger,
-    metrics/accounting.py ``set_table_layout``); ``section_stride``
+  * ``harmony_table_blocks{job,table}`` — the table's block count: what
+    any host work that walks the ownership map grows with (PERF.md §6,
+    PR 32: an epoch's bookkeeping cost 85-100 ns a block until the
+    per-executor counts were kept as ownership changes); read it beside
+    the ``window.bookkeeping`` span's seconds;
+  * STATUS ``tenants.<job>.table_layout`` = ``{block_size, blocks,
+    tail_rows, section_stride, rows, tile_exact}`` (a row of the tenant
+    ledger, metrics/accounting.py ``set_table_layout``); ``section_stride``
     is the trainer's where it has one (``PyTreeTrainer.section_stride``:
     rows between the ``[params | m | v]`` sections), else ``None``.
 
@@ -63,14 +68,21 @@ def note(job: str, spec, section_stride: Optional[int] = None
     """Record the storage layout of ``spec`` (a dense ``TableSpec``) as
     ``job``'s model table; returns the STATUS row."""
     from harmony_tpu.metrics.accounting import ledger
+    from harmony_tpu.metrics.registry import get_registry
 
     rows = spec.num_blocks * spec.block_size
     tail = rows - spec.config.capacity
-    row = {"block_size": spec.block_size, "tail_rows": tail,
-           "section_stride": section_stride, "rows": rows,
+    row = {"block_size": spec.block_size, "blocks": spec.num_blocks,
+           "tail_rows": tail, "section_stride": section_stride, "rows": rows,
            "tile_exact": int(spec.block_size % TILE_ROWS == 0 and tail == 0)}
     _family().labels(job=job, table=spec.config.table_id).set(
         row["tile_exact"])
+    get_registry().gauge(
+        "harmony_table_blocks",
+        "Blocks of a tenant's dense model table (host work that walks the "
+        "ownership map grows with it)",
+        ("job", "table")).labels(
+            job=job, table=spec.config.table_id).set(spec.num_blocks)
     ledger().set_table_layout(job, row)
     return row
 

@@ -34,11 +34,23 @@ class BlockManager:
         self.table_id = table_id
         self.num_blocks = num_blocks
         self._lock = threading.RLock()
-        self._executors: List[str] = list(executors)
-        # Even round-robin partitioning over associated executors
-        # (ref: BlockManager even initial partitioning).
-        self._owner: List[int] = [b % len(executors) for b in range(num_blocks)]
+        self._executors: List[str]
+        self._owner: List[int]
+        # Blocks per executor index, kept as ownership changes: an epoch
+        # boundary asks for the counts (ServerMetrics, the pod plan hook)
+        # and must not pay a pass over every block for them.
+        self._counts: List[int]
+        self._partition_evenly(executors)
         self._listeners: List[OwnershipListener] = []
+
+    def _partition_evenly(self, executors: Sequence[str]) -> None:
+        """Even round-robin partitioning over ``executors`` (ref:
+        BlockManager even initial partitioning)."""
+        n = len(executors)
+        self._executors = list(executors)
+        self._owner = [b % n for b in range(self.num_blocks)]
+        q, r = divmod(self.num_blocks, n)
+        self._counts = [q + (i < r) for i in range(n)]
 
     # -- queries ---------------------------------------------------------
 
@@ -57,11 +69,10 @@ class BlockManager:
             return [b for b, o in enumerate(self._owner) if o == idx]
 
     def block_counts(self) -> Dict[str, int]:
+        """Blocks per associated executor: a fresh dict (callers mutate
+        it), O(executors) whatever the table's size."""
         with self._lock:
-            counts = {e: 0 for e in self._executors}
-            for o in self._owner:
-                counts[self._executors[o]] += 1
-            return counts
+            return dict(zip(self._executors, self._counts))
 
     def ownership_vector(self) -> List[int]:
         with self._lock:
@@ -93,14 +104,16 @@ class BlockManager:
             if executor in self._executors:
                 raise ValueError(f"{executor} already associated")
             self._executors.append(executor)
+            self._counts.append(0)
 
     def unassociate(self, executor: str) -> None:
         """Remove an executor; it must no longer own blocks."""
         with self._lock:
             idx = self._executors.index(executor)
-            if any(o == idx for o in self._owner):
+            if self._counts[idx]:
                 raise ValueError(f"{executor} still owns blocks")
             self._executors.pop(idx)
+            self._counts.pop(idx)
             self._owner = [o - 1 if o > idx else o for o in self._owner]
             self._notify_locked()
 
@@ -118,6 +131,8 @@ class BlockManager:
             moved = owned[:num_blocks]
             for b in moved:
                 self._owner[b] = di
+            self._counts[si] -= len(moved)
+            self._counts[di] += len(moved)
             self._notify_locked()
         return moved
 
@@ -127,8 +142,7 @@ class BlockManager:
         if not executors:
             raise ValueError("need at least one executor")
         with self._lock:
-            self._executors = list(executors)
-            self._owner = [b % len(executors) for b in range(self.num_blocks)]
+            self._partition_evenly(executors)
             self._notify_locked()
 
 

@@ -589,12 +589,13 @@ def test_table_layout_record():
     lm = TableSpec(tr.model_table_config(table_id="lm"))
     row = table_layout.note("layout-lm", lm,
                             tr.section_stride(lm.config.capacity))
-    assert row == {"block_size": 8, "tail_rows": 0, "tile_exact": 1,
+    assert row == {"block_size": 8, "blocks": tr.capacity // 8,
+                   "tail_rows": 0, "tile_exact": 1,
                    "section_stride": tr.section_rows, "rows": tr.capacity}
     nine = TableSpec(TableConfig(table_id="nine", capacity=65, num_blocks=8,
                                  value_shape=(128,)))
     assert table_layout.note("layout-nine", nine) == {
-        "block_size": 9, "tail_rows": 7, "tile_exact": 0,
+        "block_size": 9, "blocks": 8, "tail_rows": 7, "tile_exact": 0,
         "section_stride": None, "rows": 72}
     from harmony_tpu.metrics.accounting import ledger
 
@@ -604,6 +605,17 @@ def test_table_layout_record():
     assert gauge[("layout-lm", "lm")] == 1
     assert gauge[("layout-nine", "nine")] == 0
     assert "harmony_table_tile_exact{" in get_registry().expose()
+
+    def exposed(name):
+        return {line.split(",pid=")[0]: line.rsplit(" ", 1)[1]
+                for line in get_registry().expose().splitlines()
+                if line.startswith(name + "{")}
+
+    blocks = exposed("harmony_table_blocks")
+    assert float(blocks['harmony_table_blocks{job="layout-nine",'
+                        'table="nine"']) == 8, blocks
+    assert float(blocks['harmony_table_blocks{job="layout-lm",'
+                        'table="lm"']) == tr.capacity // 8, blocks
     # how the step applies its update: a key of the same row, and a gauge
     table_layout.note_update("layout-lm", "lm", "row_ranges")
     table_layout.note_fold("layout-lm", "lm", "pallas_sections")
@@ -615,13 +627,11 @@ def test_table_layout_record():
             in get_registry().expose())
     assert (ledger().snapshot()["layout-nine"]["table_layout"]
             ["update_lowering"]) == "whole_delta"
-    exposed = {line.split(",pid=")[0]: line.rsplit(" ", 1)[1]
-               for line in get_registry().expose().splitlines()
-               if line.startswith("harmony_table_update_row_ranges{")}
-    assert exposed['harmony_table_update_row_ranges{job="layout-lm",'
-                   'table="lm"'] == "1", exposed
-    assert exposed['harmony_table_update_row_ranges{job="layout-nine",'
-                   'table="nine"'] == "0", exposed
+    ranges = exposed("harmony_table_update_row_ranges")
+    assert ranges['harmony_table_update_row_ranges{job="layout-lm",'
+                  'table="lm"'] == "1", ranges
+    assert ranges['harmony_table_update_row_ranges{job="layout-nine",'
+                  'table="nine"'] == "0", ranges
 
 
 def test_status_carries_the_layout_of_a_submitted_lm_tenant():
@@ -650,6 +660,7 @@ def test_status_carries_the_layout_of_a_submitted_lm_tenant():
     finally:
         server.shutdown(timeout=60)
     tr = TransformerTrainer(**app)
-    assert row == {"block_size": 8, "tail_rows": 0, "tile_exact": 1,
+    assert row == {"block_size": 8, "blocks": tr.capacity // 8,
+                   "tail_rows": 0, "tile_exact": 1,
                    "section_stride": tr.section_rows, "rows": tr.capacity,
                    "update_lowering": "row_ranges", "fold_lowering": "xla"}
