@@ -33,6 +33,7 @@ import numpy as np
 from harmony_tpu.config.params import TableConfig
 from harmony_tpu.dolphin.trainer import Trainer
 from harmony_tpu.table.update import UpdateFunction, get_update_fn
+from harmony_tpu.tracing.stepscopes import step_scope
 
 # Sparse mode reserves the TOP of the int32 key space for the non-embedding
 # rows (bias / raveled MLP); feature ids must stay below this base.
@@ -213,12 +214,14 @@ class FMTrainer(Trainer):
         B = ids.shape[0]
 
         def loss_fn(rows):
-            w, v, tail = self._split(rows, B)
-            logits = self._scores(w, v, tail)
-            ce = jnp.mean(
-                jnp.maximum(logits, 0) - logits * y + jnp.log1p(jnp.exp(-jnp.abs(logits)))
-            )
-            return ce + self.l2 * (rows * rows).mean(), ce
+            with step_scope("fm.interact"):
+                w, v, tail = self._split(rows, B)
+                logits = self._scores(w, v, tail)
+            with step_scope("fm.loss"):
+                ce = jnp.mean(
+                    jnp.maximum(logits, 0) - logits * y + jnp.log1p(jnp.exp(-jnp.abs(logits)))
+                )
+                return ce + self.l2 * (rows * rows).mean(), ce
 
         (_, ce), grads = jax.value_and_grad(loss_fn, has_aux=True)(model)
         # Duplicate ids: jax.grad of the gather already accumulated their

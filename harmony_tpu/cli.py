@@ -450,11 +450,22 @@ def main(argv: List[str] | None = None) -> int:
         "obs",
         help="observability tooling: per-tenant cost top, step-phase "
              "critpath, flight records, /metrics scrape, trace "
-             "timelines (docs/OBSERVABILITY.md)",
+             "timelines, the scope table of a device profile "
+             "(docs/OBSERVABILITY.md)",
     )
     p.add_argument("what",
                    choices=("top", "flight", "metrics", "trace",
-                            "doctor", "critpath", "plan", "incidents"))
+                            "doctor", "critpath", "plan", "incidents",
+                            "scopes"))
+    p.add_argument("path", nargs="?", default=None,
+                   help="scopes: a profile directory (a sampled "
+                        "HARMONY_PROFILE_EVERY_N capture included) or an "
+                        ".xplane.pb file")
+    p.add_argument("--blocks", action="store_true",
+                   help="scopes: one row per block (blk3/ffn) instead of "
+                        "blocks folded (blk*/ffn)")
+    p.add_argument("--top", type=int, default=40,
+                   help="scopes: rows a module (default 40)")
     p.add_argument("--port", type=int, default=None,
                    help="jobserver TCP port (top/flight/doctor/critpath/"
                         "plan/incidents: STATUS query; default "
@@ -754,9 +765,47 @@ def _obs_status_sender(kind: str, endpoint):
     return CommandSender(endpoint)
 
 
+def _cmd_obs_scopes(args: argparse.Namespace) -> int:
+    """``obs scopes``: where a captured profile's device time went, by the
+    step program's own scopes (tracing/stepscopes.py) — by device and
+    module, ms a step. Reads files only: no server, no jax."""
+    from harmony_tpu.tracing import stepscopes
+
+    path = stepscopes.find_xplane(args.path) if args.path else None
+    if path is None:
+        print("obs scopes needs a profile directory or an .xplane.pb "
+              f"(got {args.path!r})", file=sys.stderr)
+        return 2
+    reduced = stepscopes.reduce_file(path)
+    if getattr(args, "json", False):
+        print(json.dumps({"file": path, "devices": {
+            str(dev): {name: {
+                "seconds": entry["seconds"],
+                "executions": entry["executions"],
+                "rows": [{"scope": r.scope, "pass": r.which,
+                          "class": r.klass, "seconds": r.seconds,
+                          "calls": r.calls, "flops": r.flops,
+                          "inherited_s": r.inherited_s}
+                         for r in entry["rows"]]}
+                for name, entry in by_module.items()}
+            for dev, by_module in reduced.items()}}, indent=1))
+        return 0
+    if not reduced:
+        print(f"{path}: no /host:metadata plane with HLO, or no device ran "
+              "anything: nothing to name")
+        return 1
+    print(path)
+    for line in stepscopes.render(reduced, top=args.top,
+                                  blocks=args.blocks):
+        print(line)
+    return 0
+
+
 def _cmd_obs_inner(args: argparse.Namespace) -> int:
     import urllib.request
 
+    if args.what == "scopes":
+        return _cmd_obs_scopes(args)
     try:
         kind, endpoint = _resolve_obs_endpoint(args)
     except SystemExit as e:

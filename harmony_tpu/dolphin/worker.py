@@ -57,6 +57,7 @@ from harmony_tpu.runtime import progcache
 from harmony_tpu.tracing import SpanContext, job_stage, trace_span
 from harmony_tpu.tracing.span import job_stage_adder
 from harmony_tpu.tracing.profiler import maybe_profile_epoch
+from harmony_tpu.tracing.stepscopes import in_cache_key, step_scope
 from harmony_tpu.utils.platform import on_mesh, traced_on
 
 
@@ -131,23 +132,30 @@ def pull_all_step(spec, trainer, mesh: Mesh):
     if update_lowering(spec, trainer, mesh) == "whole_delta":
 
         def _step(arr, batch, hyper):
-            model = _phase_boundary(spec.pull_all(arr),
-                                    replicate_on=mesh)             # PULL
-            delta, metrics = _phase_boundary(
-                trainer.compute(model, batch, hyper),
-                replicate_on=mesh)                                 # COMP
-            return spec.push_all(arr, delta), metrics              # PUSH
+            with step_scope("table.pull"):
+                model = _phase_boundary(spec.pull_all(arr),
+                                        replicate_on=mesh)         # PULL
+            with step_scope("compute"):
+                delta, metrics = _phase_boundary(
+                    trainer.compute(model, batch, hyper),
+                    replicate_on=mesh)                             # COMP
+            with step_scope("table.push"):
+                return spec.push_all(arr, delta), metrics          # PUSH
 
         return _step
     rows, _, gradient, push_update = trainer.row_update_parts(
         spec.config.capacity)
 
     def _step(arr, batch, hyper):
-        model = _phase_boundary(spec.pull_all(arr),
-                                replicate_on=mesh)                 # PULL
-        g, metrics = _phase_boundary(gradient(model[:rows], batch),
-                                     replicate_on=mesh)            # COMP
-        return push_update(spec, arr, model, g, hyper), metrics    # PUSH
+        with step_scope("table.pull"):
+            model = _phase_boundary(spec.pull_all(arr),
+                                    replicate_on=mesh)             # PULL
+            params = model[:rows]
+        with step_scope("compute"):
+            g, metrics = _phase_boundary(gradient(params, batch),
+                                         replicate_on=mesh)        # COMP
+        with step_scope("table.push"):
+            return push_update(spec, arr, model, g, hyper), metrics  # PUSH
 
     return _step
 
@@ -397,19 +405,22 @@ class WorkerTasklet:
             mandatory _dropped count — drops are drained into
             table.overflow_count at epoch end, never silent)."""
             replicated = NamedSharding(mesh, P())
-            keys = jax.lax.with_sharding_constraint(
-                trainer.pull_keys(batch), replicated
-            )
-            state, rows, token = spec.pull(state, keys)            # PULL
-            rows = _phase_boundary(rows, replicate_on=mesh)
-            delta, aux, metrics = _phase_boundary(compute(rows),
-                                                  replicate_on=mesh)  # COMP
+            with step_scope("table.pull"):
+                keys = jax.lax.with_sharding_constraint(
+                    trainer.pull_keys(batch), replicated
+                )
+                state, rows, token = spec.pull(state, keys)        # PULL
+                rows = _phase_boundary(rows, replicate_on=mesh)
+            with step_scope("compute"):
+                delta, aux, metrics = _phase_boundary(
+                    compute(rows), replicate_on=mesh)              # COMP
             # SPI hook (identity by default): trainers maintaining cross-row
             # invariants (e.g. LDA's summary row = sum of word rows)
             # reconcile the delta with the admission mask so a dropped
             # row's contribution drops EVERYWHERE, not just at its own slot
-            delta = trainer.mask_delta(delta, token[2])
-            state = spec.push(state, token, delta)                 # PUSH
+            with step_scope("table.push"):
+                delta = trainer.mask_delta(delta, token[2])
+                state = spec.push(state, token, delta)             # PUSH
             metrics = dict(metrics)
             metrics["_dropped"] = jnp.sum(~token[2]).astype(jnp.float32)
             return state, aux, metrics
@@ -430,8 +441,9 @@ class WorkerTasklet:
                     # the local pull belongs to the PULL stage even though
                     # it is traced inside the compute closure — barrier it
                     # like the model pull
-                    lmodel = _phase_boundary(local_spec.pull_all(local),
-                                             replicate_on=mesh)
+                    with step_scope("table.pull"):
+                        lmodel = _phase_boundary(local_spec.pull_all(local),
+                                                 replicate_on=mesh)
                     state, new_l, metrics = _hash_pull_push(
                         state,
                         batch,
@@ -439,27 +451,28 @@ class WorkerTasklet:
                             rows, lmodel, batch, hyper
                         ),
                     )
-                    return (
-                        state,
-                        local_spec.write_all(local, new_l),
-                    ), sync(metrics, state[1])
+                    with step_scope("table.push"):
+                        new_local = local_spec.write_all(local, new_l)
+                    return (state, new_local), sync(metrics, state[1])
 
                 return _step
 
             def _step(arr, local, batch, hyper):
-                model, lmodel = _phase_boundary(
-                    (spec.pull_all(arr), local_spec.pull_all(local)),
-                    replicate_on=mesh,
-                )                                                  # PULL
-                delta, new_l, metrics = _phase_boundary(
-                    trainer.compute_with_local(model, lmodel, batch, hyper),
-                    replicate_on=mesh,
-                )                                                  # COMP
-                new_arr = spec.push_all(arr, delta)                # PUSH
-                return (
-                    new_arr,
-                    local_spec.write_all(local, new_l),
-                ), sync(metrics, new_arr)
+                with step_scope("table.pull"):
+                    model, lmodel = _phase_boundary(
+                        (spec.pull_all(arr), local_spec.pull_all(local)),
+                        replicate_on=mesh,
+                    )                                              # PULL
+                with step_scope("compute"):
+                    delta, new_l, metrics = _phase_boundary(
+                        trainer.compute_with_local(model, lmodel, batch,
+                                                   hyper),
+                        replicate_on=mesh,
+                    )                                              # COMP
+                with step_scope("table.push"):
+                    new_arr = spec.push_all(arr, delta)            # PUSH
+                    new_local = local_spec.write_all(local, new_l)
+                return (new_arr, new_local), sync(metrics, new_arr)
 
             return _step
 
@@ -485,13 +498,17 @@ class WorkerTasklet:
             push_via = push_route
 
             def _step(arr, batch, hyper):
-                keys = trainer.pull_keys(batch)
-                model = _phase_boundary(spec.pull(arr, keys),
-                                        replicate_on=mesh)         # PULL
-                delta, metrics = _phase_boundary(
-                    trainer.compute(model, batch, hyper),
-                    replicate_on=mesh)                             # COMP
-                new_arr = spec.push(arr, keys, delta, via=push_via)  # PUSH
+                with step_scope("table.pull"):
+                    keys = trainer.pull_keys(batch)
+                    model = _phase_boundary(spec.pull(arr, keys),
+                                            replicate_on=mesh)     # PULL
+                with step_scope("compute"):
+                    delta, metrics = _phase_boundary(
+                        trainer.compute(model, batch, hyper),
+                        replicate_on=mesh)                         # COMP
+                with step_scope("table.push"):
+                    new_arr = spec.push(arr, keys, delta,
+                                        via=push_via)              # PUSH
                 return new_arr, sync(metrics, new_arr)
 
         return _step
@@ -599,7 +616,8 @@ class WorkerTasklet:
         mesh = (tsh[0] if isinstance(tsh, tuple) else tsh).mesh
 
         def build_step():
-            step = traced_on(mesh, self._step_core(push_route, mesh))
+            step = in_cache_key(
+                traced_on(mesh, self._step_core(push_route, mesh)))
             if self.trainer.uses_local_table:
                 return jax.jit(step, out_shardings=((tsh, lsh), None),
                                donate_argnums=(0, 1))
@@ -617,13 +635,15 @@ class WorkerTasklet:
                     (fa, fl), ms = jax.lax.scan(body, (arr, larr), stacked)
                     return (fa, fl), ms
 
-                return jax.jit(_epoch2, out_shardings=((tsh, lsh), None),
+                return jax.jit(in_cache_key(_epoch2),
+                               out_shardings=((tsh, lsh), None),
                                donate_argnums=(0, 1))
 
             def _epoch(arr, stacked, hyper):
                 return jax.lax.scan(lambda a, b: step(a, b, hyper), arr, stacked)
 
-            return jax.jit(_epoch, out_shardings=(tsh, None), donate_argnums=0)
+            return jax.jit(in_cache_key(_epoch), out_shardings=(tsh, None),
+                           donate_argnums=0)
 
         return build_step, build_epoch
 

@@ -49,6 +49,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from harmony_tpu.tracing.stepscopes import step_scope
+
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
@@ -107,32 +109,40 @@ def moe_ffn(
     T, d = x.shape
     E = cfg.num_experts
     C = cfg.capacity(T)
-    dispatch, combine, aux = _dispatch_combine(x, params["router"], E, C)
-    xe = jnp.einsum("tec,td->ecd", dispatch, x.astype(jnp.float32))  # [E,C,d]
+    with step_scope("moe.route"):
+        dispatch, combine, aux = _dispatch_combine(x, params["router"], E, C)
+    with step_scope("moe.dispatch"):
+        xe = jnp.einsum("tec,td->ecd", dispatch,
+                        x.astype(jnp.float32))        # [E, C, d]
 
     if axis_name is None:
-        w1, w2 = params["w1"], params["w2"]           # [E, d, f], [E, f, d]
-        h = jax.nn.gelu(jnp.einsum("ecd,edf->ecf", xe, w1))
-        ye = jnp.einsum("ecf,efd->ecd", h, w2)        # [E, C, d]
+        with step_scope("moe.experts"):
+            w1, w2 = params["w1"], params["w2"]       # [E, d, f], [E, f, d]
+            h = jax.nn.gelu(jnp.einsum("ecd,edf->ecf", xe, w1))
+            ye = jnp.einsum("ecf,efd->ecd", h, w2)    # [E, C, d]
     else:
         S = lax.psum(1, axis_name)
         E_loc = E // S
         # [E, C, d] -> exchange: each device keeps its E_loc experts but
         # receives every shard's buckets for them: [S*E_loc, C, d] ->
         # all_to_all splits the expert axis and concatenates source shards.
-        xe = xe.reshape(S, E_loc, C, d)
-        xe = lax.all_to_all(xe, axis_name, split_axis=0, concat_axis=0,
-                            tiled=False)              # [S, E_loc, C, d] src-major
-        xe = xe.transpose(1, 0, 2, 3).reshape(E_loc, S * C, d)
-        w1, w2 = params["w1"], params["w2"]           # [E_loc, d, f]
-        h = jax.nn.gelu(jnp.einsum("ecd,edf->ecf", xe, w1))
-        ye = jnp.einsum("ecf,efd->ecd", h, w2)        # [E_loc, S*C, d]
-        ye = ye.reshape(E_loc, S, C, d).transpose(1, 0, 2, 3)  # [S, E_loc, C, d]
-        ye = lax.all_to_all(ye, axis_name, split_axis=0, concat_axis=0,
-                            tiled=False)
-        ye = ye.reshape(E, C, d)
+        with step_scope("moe.dispatch"):
+            xe = xe.reshape(S, E_loc, C, d)
+            xe = lax.all_to_all(xe, axis_name, split_axis=0, concat_axis=0,
+                                tiled=False)          # [S, E_loc, C, d] src-major
+            xe = xe.transpose(1, 0, 2, 3).reshape(E_loc, S * C, d)
+        with step_scope("moe.experts"):
+            w1, w2 = params["w1"], params["w2"]       # [E_loc, d, f]
+            h = jax.nn.gelu(jnp.einsum("ecd,edf->ecf", xe, w1))
+            ye = jnp.einsum("ecf,efd->ecd", h, w2)    # [E_loc, S*C, d]
+        with step_scope("moe.combine"):
+            ye = ye.reshape(E_loc, S, C, d).transpose(1, 0, 2, 3)  # [S, E_loc, C, d]
+            ye = lax.all_to_all(ye, axis_name, split_axis=0, concat_axis=0,
+                                tiled=False)
+            ye = ye.reshape(E, C, d)
 
-    out = jnp.einsum("tec,ecd->td", combine, ye).astype(x.dtype)
+    with step_scope("moe.combine"):
+        out = jnp.einsum("tec,ecd->td", combine, ye).astype(x.dtype)
     return out, aux
 
 
@@ -215,42 +225,47 @@ def _route(params, x, cfg: DroplessConfig, seqs: int):
     normalised score) — a mean already, so layers ADD it."""
     T = x.shape[0]
     E, k = cfg.num_experts, cfg.top_k
-    # a tiny matmul deciding discrete routes: full float32 passes on the MXU
-    logits = jnp.dot(x.astype(jnp.float32), params["router"],
-                     precision=lax.Precision.HIGHEST)            # [T, E]
-    if cfg.score == "softmax":
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        probs = jnp.exp(logits - lse[:, None])
-        gate, expert = lax.top_k(probs, k)                       # [T, k]
-    else:
-        score = jax.nn.sigmoid(logits)
-        # the bias moves WHICH experts are chosen; weights are the scores
-        _, expert = lax.top_k(score + lax.stop_gradient(params["bias"]), k)
-        gate = jnp.take_along_axis(score, expert, axis=1)
-        probs = score / score.sum(axis=-1, keepdims=True)
-    if cfg.norm_topk:  # over all k chosen, held here or not
-        gate = gate / (gate.sum(axis=-1, keepdims=True) + 1e-20)
-    if cfg.routed_scale != 1.0:
-        gate = gate * cfg.routed_scale
-    slot_expert = expert.reshape(-1)                             # [T * k]
-    # a compare-and-sum, not a scatter-add (which a TPU serialises)
-    if cfg.seq_aux:
-        by_seq = jnp.sum(slot_expert.reshape(seqs, -1)[:, :, None]
-                         == jnp.arange(E)[None, None, :], axis=1,
-                         dtype=jnp.int32)                        # [seqs, E]
-        tokens = by_seq.sum(axis=0)
-        f = by_seq.astype(jnp.float32) * (E / (k * (T // seqs)))
-        p = probs.reshape(seqs, T // seqs, E).mean(axis=1)
-        seq_lb = jnp.sum(f * p, axis=-1).mean()
-    else:
-        tokens = jnp.sum(slot_expert[:, None] == jnp.arange(E)[None, :],
-                         axis=0, dtype=jnp.int32)
-    stats = {"tokens": tokens.astype(jnp.float32), "n": jnp.float32(T),
-             "prob_sum": probs.sum(axis=0)}
-    if cfg.score == "softmax":
-        stats["z_sum"] = jnp.sum(lse * lse)
-    if cfg.seq_aux:
-        stats["seq_lb"] = seq_lb
+    with step_scope("moe.route"):
+        # a tiny matmul deciding discrete routes: full float32 passes on
+        # the MXU
+        logits = jnp.dot(x.astype(jnp.float32), params["router"],
+                         precision=lax.Precision.HIGHEST)        # [T, E]
+        if cfg.score == "softmax":
+            lse = jax.nn.logsumexp(logits, axis=-1)
+            probs = jnp.exp(logits - lse[:, None])
+            gate, expert = lax.top_k(probs, k)                   # [T, k]
+        else:
+            score = jax.nn.sigmoid(logits)
+            # the bias moves WHICH experts are chosen; weights are the
+            # scores
+            _, expert = lax.top_k(
+                score + lax.stop_gradient(params["bias"]), k)
+            gate = jnp.take_along_axis(score, expert, axis=1)
+            probs = score / score.sum(axis=-1, keepdims=True)
+        if cfg.norm_topk:  # over all k chosen, held here or not
+            gate = gate / (gate.sum(axis=-1, keepdims=True) + 1e-20)
+        if cfg.routed_scale != 1.0:
+            gate = gate * cfg.routed_scale
+        slot_expert = expert.reshape(-1)                         # [T * k]
+    with step_scope("moe.aux"):
+        # a compare-and-sum, not a scatter-add (which a TPU serialises)
+        if cfg.seq_aux:
+            by_seq = jnp.sum(slot_expert.reshape(seqs, -1)[:, :, None]
+                             == jnp.arange(E)[None, None, :], axis=1,
+                             dtype=jnp.int32)                    # [seqs, E]
+            tokens = by_seq.sum(axis=0)
+            f = by_seq.astype(jnp.float32) * (E / (k * (T // seqs)))
+            p = probs.reshape(seqs, T // seqs, E).mean(axis=1)
+            seq_lb = jnp.sum(f * p, axis=-1).mean()
+        else:
+            tokens = jnp.sum(slot_expert[:, None] == jnp.arange(E)[None, :],
+                             axis=0, dtype=jnp.int32)
+        stats = {"tokens": tokens.astype(jnp.float32), "n": jnp.float32(T),
+                 "prob_sum": probs.sum(axis=0)}
+        if cfg.score == "softmax":
+            stats["z_sum"] = jnp.sum(lse * lse)
+        if cfg.seq_aux:
+            stats["seq_lb"] = seq_lb
     return gate, expert, slot_expert, tokens, stats
 
 
@@ -269,21 +284,26 @@ def moe_ffn_dropless(params: Dict[str, jnp.ndarray], x: jnp.ndarray,
     gate, expert, slot_expert, tokens, stats = _route(params, x, cfg, seqs)
     # slots sorted by expert: the held experts' runs come first (they are
     # experts 0 .. H-1), absent experts' slots after them
-    order = jnp.argsort(slot_expert, stable=True)
-    inv = jnp.argsort(order)  # a permutation's inverse, again by sorting
-    sizes = tokens[:H]
-    rows = _slot_rows(x, order, inv, k)                          # [T * k, d]
+    with step_scope("moe.dispatch"):
+        order = jnp.argsort(slot_expert, stable=True)
+        inv = jnp.argsort(order)  # a permutation's inverse, again by sorting
+        sizes = tokens[:H]
+        rows = _slot_rows(x, order, inv, k)                      # [T * k, d]
     dtype = x.dtype
-    h = (jax.nn.silu(grouped_matmul(rows, params["wg"].astype(dtype), sizes))
-         * grouped_matmul(rows, params["wu"].astype(dtype), sizes))
-    y = grouped_matmul(h, params["wd"].astype(dtype), sizes)     # [T * k, d]
-    # back to slot order; an absent expert's slot carries weight 0
-    weight = jnp.where(expert < H, gate, 0.0)                    # [T, k]
-    y = _slot_rows(y, inv, order, 1).reshape(T, k, d)
-    out = jnp.einsum("tkd,tk->td", y.astype(jnp.float32), weight)
-    out = out.astype(dtype)
+    with step_scope("moe.experts"):
+        h = (jax.nn.silu(grouped_matmul(rows, params["wg"].astype(dtype),
+                                        sizes))
+             * grouped_matmul(rows, params["wu"].astype(dtype), sizes))
+        y = grouped_matmul(h, params["wd"].astype(dtype), sizes)  # [T * k, d]
+    with step_scope("moe.combine"):
+        # back to slot order; an absent expert's slot carries weight 0
+        weight = jnp.where(expert < H, gate, 0.0)                # [T, k]
+        y = _slot_rows(y, inv, order, 1).reshape(T, k, d)
+        out = jnp.einsum("tkd,tk->td", y.astype(jnp.float32), weight)
+        out = out.astype(dtype)
     if cfg.shared_experts:  # plain matmuls on every token, beside the sum
-        hs = (jax.nn.silu(x @ params["shared_wg"].astype(dtype))
-              * (x @ params["shared_wu"].astype(dtype)))
-        out = out + hs @ params["shared_wd"].astype(dtype)
+        with step_scope("moe.shared"):
+            hs = (jax.nn.silu(x @ params["shared_wg"].astype(dtype))
+                  * (x @ params["shared_wu"].astype(dtype)))
+            out = out + hs @ params["shared_wd"].astype(dtype)
     return out, stats

@@ -35,6 +35,7 @@ from jax.flatten_util import ravel_pytree
 
 from harmony_tpu.config.params import TILE_ROWS, TableConfig
 from harmony_tpu.dolphin.trainer import Trainer, TrainerContext
+from harmony_tpu.tracing.stepscopes import step_scope
 
 
 class PyTreeTrainer(Trainer):
@@ -211,10 +212,13 @@ class PyTreeTrainer(Trainer):
         """``(g, metrics)``: the gradient as rows ``[stride, row_width]``
         over the parameter section's rows ``p`` (rows -> leaves,
         value_and_grad, leaves -> rows), and what the step reports."""
+        with step_scope("table.pull"):
+            leaves = self._leaves(p)
         (loss, extra), grads = jax.value_and_grad(
             self.loss_and_metrics_on_batch, has_aux=True
-        )(self._leaves(p), batch)
-        g = self._to_rows(ravel_pytree(grads)[0], p.shape[0])
+        )(leaves, batch)
+        with step_scope("table.grad_rows"):
+            g = self._to_rows(ravel_pytree(grads)[0], p.shape[0])
         return g, {"loss": loss, **extra}
 
     def section_deltas(self, stored, g, scalars):

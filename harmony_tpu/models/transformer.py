@@ -38,6 +38,7 @@ from harmony_tpu.ops.attention import blockwise_attention
 from harmony_tpu.ops.ring import ring_attention
 from harmony_tpu.ops.ulysses import a2a_attention
 from harmony_tpu.parallel.mesh import DATA_AXIS, SEQ_AXIS
+from harmony_tpu.tracing.stepscopes import step_scope
 
 
 @dataclasses.dataclass(frozen=True)
@@ -567,18 +568,21 @@ class TransformerLM:
         h, nope, rot = cfg.n_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
         r, vd = cfg.kv_lora_rank, cfg.v_head_dim
         to_heads = lambda t, w: t.reshape(B, S, h, w).transpose(0, 2, 1, 3)
-        q = to_heads(xn @ layer["wq"].astype(cfg.dtype), nope + rot)
-        ckv = xn @ layer["wkv_a"].astype(cfg.dtype)          # [B, S, r + rot]
-        c = _norm(ckv[..., :r], layer["kv_norm"].astype(cfg.dtype),
-                  cfg.norm_eps)
-        kv = to_heads(c @ layer["wkv_b"].astype(cfg.dtype), nope + vd)
+        with step_scope("mixer.qkv"):
+            q = to_heads(xn @ layer["wq"].astype(cfg.dtype), nope + rot)
+            ckv = xn @ layer["wkv_a"].astype(cfg.dtype)      # [B, S, r + rot]
+            c = _norm(ckv[..., :r], layer["kv_norm"].astype(cfg.dtype),
+                      cfg.norm_eps)
+            kv = to_heads(c @ layer["wkv_b"].astype(cfg.dtype), nope + vd)
         turn = ((lambda t: rope(t, cfg.rope_theta, pos_offset))
                 if cfg.pos == "rope" else (lambda t: t))
-        q_pe = turn(q[..., nope:])
-        k_pe = turn(ckv[:, None, :, r:])
-        q = jnp.concatenate([q[..., :nope], q_pe], axis=-1)
-        k = jnp.concatenate(
-            [kv[..., :nope], jnp.broadcast_to(k_pe, (B, h, S, rot))], axis=-1)
+        with step_scope("mixer.rope"):
+            q_pe = turn(q[..., nope:])
+            k_pe = turn(ckv[:, None, :, r:])
+            q = jnp.concatenate([q[..., :nope], q_pe], axis=-1)
+            k = jnp.concatenate(
+                [kv[..., :nope], jnp.broadcast_to(k_pe, (B, h, S, rot))],
+                axis=-1)
         return q, k, kv[..., nope:]
 
     def _kda_mixer(self, xn, p):
@@ -610,20 +614,32 @@ class TransformerLM:
             return t * lax.rsqrt(jnp.sum(t * t, axis=-1, keepdims=True)
                                  + KDA_L2_EPS)
 
-        q = l2(conv(xn @ w("wq"), p["conv_q"])) * dh ** -0.5
-        k = l2(conv(xn @ w("wk"), p["conv_k"]))
-        v = conv(xn @ w("wv"), p["conv_v"])
-        f = heads(((xn @ w("wf_a")) @ w("wf_b")).astype(f32) + p["dt_bias"])
-        g = -jnp.exp(p["a_log"])[None, :, None, None] * jax.nn.softplus(f)
-        beta = jax.nn.sigmoid(
-            jnp.einsum("bsd,hd->bhs", xn, w("wb")).astype(f32))
-        o = kda_attention(q.astype(dt), k.astype(dt), v.astype(dt), g, beta)
-        gate = jax.nn.sigmoid(
-            heads((xn @ w("wg_a")) @ w("wg_b")).astype(f32)).astype(dt)
-        o = _norm(o, w("o_norm"), cfg.norm_eps) * gate
-        y = o.transpose(0, 2, 1, 3).reshape(B, S, H * dh) @ w("wo")
-        return y, {"decay": lax.stop_gradient(jnp.exp(g).mean()),
-                   "beta": lax.stop_gradient(beta.mean())}
+        def proj(name):
+            with step_scope("kda.proj"):
+                return xn @ w(name)
+
+        with step_scope("kda.conv"):
+            q = l2(conv(proj("wq"), p["conv_q"])) * dh ** -0.5
+            k = l2(conv(proj("wk"), p["conv_k"]))
+            v = conv(proj("wv"), p["conv_v"])
+        with step_scope("kda.gate"):
+            f = heads(((xn @ w("wf_a")) @ w("wf_b")).astype(f32)
+                      + p["dt_bias"])
+            g = -jnp.exp(p["a_log"])[None, :, None, None] * jax.nn.softplus(f)
+            beta = jax.nn.sigmoid(
+                jnp.einsum("bsd,hd->bhs", xn, w("wb")).astype(f32))
+        with step_scope("kda.scan"):
+            o = kda_attention(q.astype(dt), k.astype(dt), v.astype(dt), g,
+                              beta)
+        with step_scope("kda.gate"):
+            gate = jax.nn.sigmoid(
+                heads((xn @ w("wg_a")) @ w("wg_b")).astype(f32)).astype(dt)
+        with step_scope("kda.out"):
+            o = _norm(o, w("o_norm"), cfg.norm_eps) * gate
+            y = o.transpose(0, 2, 1, 3).reshape(B, S, H * dh) @ w("wo")
+        with step_scope("kda.gate"):
+            return y, {"decay": lax.stop_gradient(jnp.exp(g).mean()),
+                       "beta": lax.stop_gradient(beta.mean())}
 
     def _block(self, x, layer, axis_name: Optional[str],
                moe_axis: Optional[str] = None, pos_offset: Any = 0):
@@ -638,13 +654,15 @@ class TransformerLM:
         ``wqkv``."""
         cfg = self.config
         eps = cfg.norm_eps
-        xn = _norm(x, layer["ln1"].astype(cfg.dtype), eps)
+        with step_scope("norm"):
+            xn = _norm(x, layer["ln1"].astype(cfg.dtype), eps)
         if "kda" in layer:
             y, mix = self._kda_mixer(xn, layer["kda"])
         else:
             y, mix = self._softmax_mixer(xn, layer, axis_name, pos_offset), None
         x = x + y
-        xn = _norm(x, layer["ln2"].astype(cfg.dtype), eps)
+        with step_scope("norm"):
+            xn = _norm(x, layer["ln2"].astype(cfg.dtype), eps)
         out, aux = ffn_apply(cfg, layer, xn, moe_axis=moe_axis)
         return x + out, aux, mix
 
@@ -657,19 +675,24 @@ class TransformerLM:
         if cfg.attn_kind == "mla":
             q, k, v = self._latent_qkv(xn, layer, pos_offset)
         else:
-            qkv = xn @ layer["wqkv"].astype(cfg.dtype)          # [B, S, 3d]
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-            if cfg.qk_norm:
-                q = _norm(q, layer["q_norm"].astype(cfg.dtype), eps)
-                k = _norm(k, layer["k_norm"].astype(cfg.dtype), eps)
-            to_heads = lambda t: t.reshape(B, S, h, hd).transpose(0, 2, 1, 3)
-            q, k, v = to_heads(q), to_heads(k), to_heads(v)
+            with step_scope("mixer.qkv"):
+                qkv = xn @ layer["wqkv"].astype(cfg.dtype)      # [B, S, 3d]
+                q, k, v = jnp.split(qkv, 3, axis=-1)
+                if cfg.qk_norm:
+                    q = _norm(q, layer["q_norm"].astype(cfg.dtype), eps)
+                    k = _norm(k, layer["k_norm"].astype(cfg.dtype), eps)
+                to_heads = lambda t: t.reshape(B, S, h, hd).transpose(
+                    0, 2, 1, 3)
+                q, k, v = to_heads(q), to_heads(k), to_heads(v)
             if cfg.pos == "rope":
-                q = rope(q, cfg.rope_theta, pos_offset)
-                k = rope(k, cfg.rope_theta, pos_offset)
-        o = self._attention(q, k, v, axis_name)
-        o = o.transpose(0, 2, 1, 3).reshape(B, S, h * v.shape[3])
-        return o @ layer["wo"].astype(cfg.dtype)
+                with step_scope("mixer.rope"):
+                    q = rope(q, cfg.rope_theta, pos_offset)
+                    k = rope(k, cfg.rope_theta, pos_offset)
+        with step_scope("mixer.core"):
+            o = self._attention(q, k, v, axis_name)
+        with step_scope("mixer.out"):
+            o = o.transpose(0, 2, 1, 3).reshape(B, S, h * v.shape[3])
+            return o @ layer["wo"].astype(cfg.dtype)
 
     def apply(
         self,
@@ -695,8 +718,9 @@ class TransformerLM:
         KDA blocks' statistics, ``{"decay", "beta"}`` each ``[kda blocks]``
         (None for a model without such blocks)."""
         cfg = self.config
-        x = _embed_in(cfg, params["embed"], params.get("pos"), tokens,
-                      pos_offset)
+        with step_scope("embed"):
+            x = _embed_in(cfg, params["embed"], params.get("pos"), tokens,
+                          pos_offset)
 
         def block(x, layer):
             return self._block(x, layer, axis_name, moe_axis=moe_axis,
@@ -712,8 +736,9 @@ class TransformerLM:
         aux = jnp.asarray(0.0, jnp.float32)
         routed = []  # dropless layers' statistics
         mixers = []  # KDA blocks' statistics
-        for layer in params["layers"]:
-            x, a, mix = block(x, layer)
+        for i, layer in enumerate(params["layers"]):
+            with step_scope("blk", i):
+                x, a, mix = block(x, layer)
             if mix is not None:
                 mixers.append(mix)
             if isinstance(a, dict):
@@ -723,13 +748,15 @@ class TransformerLM:
         if routed:
             aux = jax.tree.map(lambda *xs: sum(xs), *routed)
             aux["tokens_by_layer"] = jnp.stack([a["tokens"] for a in routed])
-        x = _norm(x, params["ln_f"].astype(cfg.dtype), cfg.norm_eps)
-        # f32 logits for a stable softmax; the readout is the embedding
-        # (weight-tied) unless the model has a head of its own
-        head = params["embed"].T if cfg.tie_embeddings else params["head"]
+        with step_scope("head"):
+            x = _norm(x, params["ln_f"].astype(cfg.dtype), cfg.norm_eps)
+            # f32 logits for a stable softmax; the readout is the embedding
+            # (weight-tied) unless the model has a head of its own
+            head = params["embed"].T if cfg.tie_embeddings else params["head"]
         mixers = (jax.tree.map(lambda *xs: jnp.stack(xs), *mixers)
                   if mixers else None)
-        return x.astype(jnp.float32) @ head, aux, mixers
+        with step_scope("head"):
+            return x.astype(jnp.float32) @ head, aux, mixers
 
     def loss(self, params, tokens, axis_name=None) -> jnp.ndarray:
         """Mean next-token cross-entropy over the (single-device) batch,
@@ -744,22 +771,24 @@ class TransformerLM:
         cfg = self.config
         logits, aux, mixers = self._forward(params, tokens[:, :-1],
                                             axis_name=axis_name)
-        ce = _next_token_ce(logits, tokens[:, 1:])
         kda = ({} if mixers is None else
                {"kda_decay_mean": mixers["decay"],
                 "kda_beta_mean": mixers["beta"]})
-        if cfg.moe_seq_aux:  # each layer's mean over sequences, summed
-            return ce + cfg.moe_aux_weight * aux["seq_lb"], {
-                "ce": ce, "aux_seq": aux["seq_lb"],
-                "moe_expert_tokens": aux["tokens_by_layer"], **kda}
-        if cfg.moe_top_k:
-            lb, z = routing_losses(aux, cfg.moe_experts)
-            loss = ce + cfg.moe_aux_weight * lb + cfg.moe_z_weight * z
-            return loss, {"ce": ce, "aux_lb": lb, "aux_z": z,
-                          "moe_expert_tokens": aux["tokens_by_layer"], **kda}
-        if cfg.moe_experts:
-            return ce + cfg.moe_aux_weight * aux, kda
-        return ce, kda
+        with step_scope("loss"):
+            ce = _next_token_ce(logits, tokens[:, 1:])
+            if cfg.moe_seq_aux:  # each layer's mean over sequences, summed
+                return ce + cfg.moe_aux_weight * aux["seq_lb"], {
+                    "ce": ce, "aux_seq": aux["seq_lb"],
+                    "moe_expert_tokens": aux["tokens_by_layer"], **kda}
+            if cfg.moe_top_k:
+                lb, z = routing_losses(aux, cfg.moe_experts)
+                loss = ce + cfg.moe_aux_weight * lb + cfg.moe_z_weight * z
+                return loss, {"ce": ce, "aux_lb": lb, "aux_z": z,
+                              "moe_expert_tokens": aux["tokens_by_layer"],
+                              **kda}
+            if cfg.moe_experts:
+                return ce + cfg.moe_aux_weight * aux, kda
+            return ce, kda
 
 
 def routing_losses(stats, num_experts: int):
@@ -814,13 +843,15 @@ def ffn_apply(cfg, layer, xn, no_drop: bool = False,
         flat = xn.reshape(-1, cfg.d_model)
         out, aux = moe_ffn(layer["moe"], flat, mcfg, axis_name=moe_axis)
         return out.reshape(xn.shape), aux
-    hidden = xn @ layer["w1"].astype(cfg.dtype)
-    if cfg.ffn == "swiglu":
-        hidden = jax.nn.silu(hidden) * (xn @ layer["w3"].astype(cfg.dtype))
-    else:
-        hidden = jax.nn.gelu(hidden)
-    return (hidden @ layer["w2"].astype(cfg.dtype),
-            jnp.asarray(0.0, jnp.float32))
+    with step_scope("ffn"):
+        hidden = xn @ layer["w1"].astype(cfg.dtype)
+        if cfg.ffn == "swiglu":
+            hidden = (jax.nn.silu(hidden)
+                      * (xn @ layer["w3"].astype(cfg.dtype)))
+        else:
+            hidden = jax.nn.gelu(hidden)
+        return (hidden @ layer["w2"].astype(cfg.dtype),
+                jnp.asarray(0.0, jnp.float32))
 
 
 def _embed_in(cfg, embed, pos, tokens, pos_offset=0) -> jnp.ndarray:

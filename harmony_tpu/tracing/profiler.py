@@ -3,9 +3,14 @@
 SURVEY.md §5.9 maps the reference's HTrace wiring to "native profiler hooks
 (xprof/jax profiler) + spans" on TPU. This module is that bridge:
 
-  * naming a region in a captured profile is ``trace_span``'s own job
-    (tracing/span.py opens the ``jax.profiler.TraceAnnotation``): one call
-    sites both worlds, and there is no second entry point here;
+  * naming a HOST region in a captured profile is ``trace_span``'s own
+    job (tracing/span.py opens the ``jax.profiler.TraceAnnotation``): one
+    call sites both worlds, and there is no second entry point here;
+  * naming what the DEVICE did inside a step is ``step_scope``'s
+    (tracing/stepscopes.py): a capture carries every executed module's HLO
+    with the scopes in its ``/host:metadata`` plane, and
+    ``stepscopes.reduce_file`` / ``harmony-tpu obs scopes <capture>``
+    read the device's time by scope from it — a sampled capture included;
   * ``profile_session(logdir)`` — capture a full device trace
     (jax.profiler.start_trace/stop_trace) around a code region; the
     resulting xplane dump is the TPU analogue of a Zipkin trace for kernels;
