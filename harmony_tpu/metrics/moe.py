@@ -10,7 +10,13 @@ trainer hands here:
     is the block's index in the model, expert layers only;
   * ``harmony_moe_held_slots_total{job}`` — those of them routed to experts
     this device holds (the rows its grouped matmuls computed);
-  * ``harmony_moe_experts_held{job}`` — how many experts (0 .. n-1) it holds.
+  * ``harmony_moe_experts_held{job}`` — how many experts (0 .. n-1) it holds;
+  * ``harmony_moe_layer_calls_total{job}`` / ``harmony_moe_chunks_total{job}``
+    — expert-layer calls (layers x steps), and the chunks of the layer's
+    static capacity they ran (models/moe.py ``chunk_plan``: ``ceil(held /
+    C)`` a call, host arithmetic on the same vector; 0 a call where the
+    plain full-length path runs). Their ratio is 1.0 while every call's held
+    slots fit one chunk.
 
 Under a profiler session the span ``moe.observe`` (light; opened at each
 drain) carries the drained steps' held token-slots one by one
@@ -43,7 +49,28 @@ def _families():
             reg.gauge(
                 "harmony_moe_experts_held",
                 "Experts (0 .. n-1) of each expert layer held on this device",
-                ("job",)))
+                ("job",)),
+            reg.counter(
+                "harmony_moe_layer_calls_total",
+                "Expert-layer calls (expert layers x steps)", ("job",)),
+            reg.counter(
+                "harmony_moe_chunks_total",
+                "Chunks of the static capacity those calls ran (0 a call "
+                "on the plain path)", ("job",)))
+
+
+def chunks_run(expert_tokens: np.ndarray, experts_held: int) -> np.ndarray:
+    """Chunks each layer call ran, ``[steps, layers]``, from its token
+    counts ``[steps, layers, experts]``: the plan is the program's own
+    (``models.moe.chunk_plan`` of the call's slots), the held slots over its
+    capacity rounded up; 0 where the plan is the plain path."""
+    from harmony_tpu.models.moe import chunk_plan
+
+    by_step = np.asarray(expert_tokens, np.float64)
+    slots = int(round(by_step[0, 0].sum())) if by_step.size else 0
+    capacity, chunks = chunk_plan(slots, experts_held, by_step.shape[-1])
+    held = by_step[:, :, :experts_held].sum(axis=2)
+    return np.ceil(held / capacity) if chunks else np.zeros_like(held)
 
 
 #: a job's grid of counter children, row-major over (layer, expert), kept
@@ -76,7 +103,7 @@ def observe(job: str, expert_tokens: np.ndarray, experts_held: int,
                     steps=len(held_by_step),
                     held_slots="/".join(str(int(n)) for n in held_by_step)):
         per = by_step.sum(axis=0)  # [layers, E]
-        tokens, held_slots, held = _families()
+        tokens, held_slots, held, calls, chunks = _families()
         layers = tuple(range(len(per)) if layers is None else layers)
         if len(layers) != len(per):
             raise ValueError(f"moe.observe: {len(layers)} layer labels for "
@@ -86,13 +113,16 @@ def observe(job: str, expert_tokens: np.ndarray, experts_held: int,
             child.inc(n)
         held_slots.labels(job=job).inc(float(held_by_step.sum()))
         held.labels(job=job).set(experts_held)
+        calls.labels(job=job).inc(by_step.shape[0] * by_step.shape[1])
+        chunks.labels(job=job).inc(float(chunks_run(by_step,
+                                                    experts_held).sum()))
 
 
 def stats_by_job() -> Dict[str, Dict[str, float]]:
     """``{job: {held_slot_share, load_max_over_mean}}`` from the counters."""
     out: Dict[str, Dict[str, float]] = {}
     try:
-        tokens, held_slots, held = _families()
+        tokens, held_slots, held, _calls, _chunks = _families()
         n_held = {job: int(c.value) for (job,), c in held.children()}
         by_expert: Dict[str, Dict[int, float]] = {}
         for (job, _layer, expert), c in tokens.children():

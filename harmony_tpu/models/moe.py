@@ -11,7 +11,26 @@ tokens weighted by the gates. A device may hold only the first
 router still scores all of them, slots routed to an absent expert sort last
 and their tiles are never visited, and the layer's output is this device's
 part of the sum. Returns the routing statistics the auxiliary losses and the
-counters need. DeepSeek-V3's router (Moonlight's,
+counters need. **Only the held slots are moved** (PR 35): a chip that holds
+``H`` of ``E`` experts computes about ``H / E`` of the slots, and gathering,
+masking and combining all of them cost seven times the matmuls. The held
+slots are the first ``sum(tokens[:H])`` entries of the sorted order, and the
+layer walks them in chunks of a static capacity ``C`` (:func:`chunk_plan`:
+twice the balanced held share, in 512-row tiles): chunk ``i`` gathers the
+rows of sorted slots ``[i C, (i + 1) C)``, runs the three grouped matmuls
+with the held runs clipped to that interval, and scatter-adds each row times
+its gate into its token's row of a float32 ``[T, d]`` sum. The loop runs
+while a chunk starts inside the held slots — one chunk while the router
+stays inside the headroom, all ``ceil(T k / C)`` if every slot routes here:
+nothing is dropped, there is no capacity factor. **There is ONE body**: a
+``while_loop`` with a trip count the device decides, under a ``custom_vjp``
+whose backward is the same loop written out (autodiff cannot reverse a loop
+of unknown length). A ladder of capacities, or a ``cond`` with a full-length
+fallback, is a second copy of the layer to trace, differentiate and lower
+(each with nine Mosaic kernels): PR 34 measured that at +17 s of ``setup_s``
+in Kimi Linear, and was refused for it. Where a chunk would pass a quarter
+of the slots the layer is one full-length pass, as it always was
+(:func:`_experts_plain`). DeepSeek-V3's router (Moonlight's,
 ``perf/configs/moonlight-16b-a3b.json``) enters BEFORE that body and leaves
 it as it is: ``score="sigmoid"`` scores each expert by a sigmoid, selects
 the top ``k`` of ``score + bias`` (a per-expert selection bias that carries
@@ -269,6 +288,205 @@ def _route(params, x, cfg: DroplessConfig, seqs: int):
     return gate, expert, slot_expert, tokens, stats
 
 
+# -- the held token-slots, in chunks of a static capacity --------------------
+
+#: a chunk holds ``_HEADROOM`` times the slots a balanced router sends to the
+#: held experts, in whole row tiles of the grouped matmul; the slots are
+#: chunked where a chunk is at most ``1 / _LEAST_CUT`` of them (on the chip a
+#: chunk of a quarter took 30% off the layer, a chunk of half ADDED 4% — the
+#: row scatter-adds and the loop's buffers cost more than half the slots'
+#: gathers and masks: PERF.md, PR 35)
+_HEADROOM, _ROW_TILE, _LEAST_CUT = 2, 512, 4
+
+
+def chunk_plan(slots: int, held: int, experts: int) -> Tuple[int, int]:
+    """``(capacity, chunks)`` of the expert layer for ``slots = T * k``
+    token-slots where ``held`` of ``experts`` experts live. The capacity is
+    the balanced held share with headroom, ``round_up(2 slots held /
+    experts, 512)``, and the slot axis is cut into ``ceil(slots /
+    capacity)`` chunks of it — of which a step runs those that hold a held
+    slot, one while the router stays inside the headroom. ``(slots, 0)``
+    where a chunk would pass a quarter of the slots (every expert held, a
+    held share above 1/8, the small test models): the layer is then one
+    full-length pass (:func:`_experts_plain`). A pure function of the three
+    shapes: the program (:func:`moe_ffn_dropless`) and the counters
+    (metrics/moe.py) both ask here and nothing else decides."""
+    need = -(-_HEADROOM * slots * held // experts)
+    C = -(-need // _ROW_TILE) * _ROW_TILE
+    return (slots, 0) if _LEAST_CUT * C > slots else (C, -(-slots // C))
+
+
+def _gated(g, u):
+    return jax.nn.silu(g) * u
+
+
+def _chunk(C: int, i, order, offsets, k: int):
+    """Chunk ``i`` of the sorted slots: ``(lo, slots [C], tokens [C], sizes
+    [H])`` — the held experts' runs clipped to ``[i C, (i + 1) C)``."""
+    lo = i * C
+    slots = lax.dynamic_slice(order, (lo,), (C,))
+    sizes = jnp.diff(jnp.clip(offsets - lo, 0, C))
+    return lo, slots, slots // k, sizes
+
+
+def _run_chunks(C: int, save: bool, x, weight, order, offsets, wg, wu, wd):
+    """``(out [T, d] f32, (g, u))``: the routed sum over the held slots,
+    chunk by chunk while a chunk starts inside them; with ``save`` the
+    gate and up products of every chunk that ran, stacked by sorted row
+    (rows of a chunk that did not run stay 0)."""
+    from harmony_tpu.ops.grouped_matmul import _gmm, _note_plans
+    from harmony_tpu.utils.platform import trace_is_tpu
+
+    (T, d), k, dtype = x.shape, weight.shape[1], x.dtype
+    H, _, f = wg.shape
+    interpret = not trace_is_tpu()
+    _note_plans(("fwd",), C, d, f, H, dtype)
+    _note_plans(("fwd",), C, f, d, H, dtype)
+    n_held = offsets[H]
+    flat_w = weight.reshape(-1)
+
+    def body(carry):
+        i, acc, saved = carry
+        with step_scope("moe.dispatch"):
+            lo, slots, tok, sizes = _chunk(C, i, order, offsets, k)
+            rows = x[tok]                                        # [C, d]
+        with step_scope("moe.experts"):
+            g = _gmm(rows, wg, sizes, False, interpret)
+            u = _gmm(rows, wu, sizes, False, interpret)
+            y = _gmm(_gated(g, u), wd, sizes, False, interpret)  # [C, d]
+            if save:
+                saved = tuple(lax.dynamic_update_slice(s, v, (lo, 0))
+                              for s, v in zip(saved, (g, u)))
+        with step_scope("moe.combine"):
+            # XLA's scatter-add: of the forms tried on the chip (segment
+            # sums, a sort by token, a gather by the inverse permutation, a
+            # one-hot matmul) none summed C rows into T faster (PERF.md)
+            acc = acc.at[tok].add(y.astype(jnp.float32)
+                                  * flat_w[slots][:, None])
+        return i + 1, acc, saved
+
+    saved = ((jnp.zeros((order.shape[0], f), dtype),) * 2) if save else ()
+    _, out, saved = lax.while_loop(
+        lambda carry: carry[0] * C < n_held, body,
+        (jnp.int32(0), jnp.zeros((T, d), jnp.float32), saved))
+    return out, saved
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _experts_chunked(C, x, weight, order, offsets, wg, wu, wd):
+    """``out [T, d]`` float32: per token, ``weight[t, j]`` times the held
+    expert's gated-SiLU MLP of ``x[t]``, summed over the token's slots
+    routed to a held expert. ``order [chunks * C]`` are the slots sorted by
+    expert (held runs first, padding after), ``offsets [H + 1]`` the held
+    runs' bounds in it. Only chunks that start inside the held slots run:
+    ONE body, a dynamic trip count, so idle chunks cost nothing and a second
+    capacity is never traced. The backward is written out, chunk by chunk
+    the same way: autodiff cannot reverse a loop of unknown length, and the
+    forward kernels must not run again in it (the benchmark pairs three
+    forward, three dx and three dw calls a layer and step)."""
+    return _run_chunks(C, False, x, weight, order, offsets, wg, wu, wd)[0]
+
+
+def _experts_chunked_fwd(C, x, weight, order, offsets, wg, wu, wd):
+    out, saved = _run_chunks(C, True, x, weight, order, offsets, wg, wu, wd)
+    return out, (x, weight, order, offsets, wg, wu, wd, saved)
+
+
+def _experts_chunked_bwd(C, res, d_out):
+    from harmony_tpu.ops.grouped_matmul import _gmm, _note_plans, _tgmm
+    from harmony_tpu.utils.platform import trace_is_tpu
+
+    x, weight, order, offsets, wg, wu, wd, (g_all, u_all) = res
+    (T, d), k, dtype = x.shape, weight.shape[1], x.dtype
+    H, _, f = wg.shape
+    interpret = not trace_is_tpu()
+    _note_plans(("dx", "dw"), C, d, f, H, dtype)
+    _note_plans(("dx", "dw"), C, f, d, H, dtype)
+    n_held = offsets[H]
+    flat_w = weight.reshape(-1)
+    f32 = jnp.float32
+
+    def body(carry):
+        i, d_x, d_w, d_wg, d_wu, d_wd = carry
+        with step_scope("moe.dispatch"):
+            lo, slots, tok, sizes = _chunk(C, i, order, offsets, k)
+            rows = x[tok]
+        with step_scope("moe.combine"):
+            w = flat_w[slots][:, None]
+            d_rows_out = d_out[tok]                              # [C, d] f32
+            d_y = (d_rows_out * w).astype(dtype)
+        with step_scope("moe.experts"):
+            g = lax.dynamic_slice(g_all, (lo, 0), (C, f))
+            u = lax.dynamic_slice(u_all, (lo, 0), (C, f))
+            h = _gated(g, u)
+            # the down product's cotangent WITHOUT the gate's weight: the
+            # weight's own cotangent is <h, it>, the hidden's is w times it
+            d_hu = _gmm(d_rows_out.astype(dtype), wd, sizes, True,
+                        interpret).astype(f32)                   # [C, f]
+            d_wd = d_wd + _tgmm(h, d_y, sizes, interpret)
+            d_h = d_hu * w
+            g32, u32 = g.astype(f32), u.astype(f32)
+            sg = jax.nn.sigmoid(g32)
+            d_g = (d_h * u32 * sg * (1.0 + g32 * (1.0 - sg))).astype(dtype)
+            d_u = (d_h * g32 * sg).astype(dtype)
+            d_rows = (_gmm(d_g, wg, sizes, True, interpret)
+                      + _gmm(d_u, wu, sizes, True, interpret))
+            d_wg = d_wg + _tgmm(rows, d_g, sizes, interpret)
+            d_wu = d_wu + _tgmm(rows, d_u, sizes, interpret)
+        with step_scope("moe.combine"):
+            d_w = d_w.at[slots].add(jnp.sum(h.astype(f32) * d_hu, axis=-1))
+        with step_scope("moe.dispatch"):
+            d_x = d_x.at[tok].add(d_rows.astype(f32))
+        return i + 1, d_x, d_w, d_wg, d_wu, d_wd
+
+    _, d_x, d_w, d_wg, d_wu, d_wd = lax.while_loop(
+        lambda carry: carry[0] * C < n_held, body,
+        (jnp.int32(0), jnp.zeros((T, d), f32), jnp.zeros((T * k,), f32),
+         jnp.zeros_like(wg), jnp.zeros_like(wu), jnp.zeros_like(wd)))
+    return (d_x.astype(dtype), d_w.reshape(weight.shape), None, None,
+            d_wg, d_wu, d_wd)
+
+
+_experts_chunked.defvjp(_experts_chunked_fwd, _experts_chunked_bwd)
+
+
+def _experts_plain(x, gate, expert, slot_expert, tokens, wg, wu, wd):
+    """The routed sum over ALL ``T * k`` sorted slots in one pass: where
+    every expert is held, or the held share leaves nothing to cut."""
+    from harmony_tpu.ops.grouped_matmul import grouped_matmul
+
+    (T, d), k, H = x.shape, gate.shape[1], wg.shape[0]
+    with step_scope("moe.dispatch"):
+        order = jnp.argsort(slot_expert, stable=True)
+        inv = jnp.argsort(order)  # a permutation's inverse, again by sorting
+        sizes = tokens[:H]
+        rows = _slot_rows(x, order, inv, k)                      # [T * k, d]
+    dtype = x.dtype
+    with step_scope("moe.experts"):
+        h = (jax.nn.silu(grouped_matmul(rows, wg.astype(dtype), sizes))
+             * grouped_matmul(rows, wu.astype(dtype), sizes))
+        y = grouped_matmul(h, wd.astype(dtype), sizes)           # [T * k, d]
+    with step_scope("moe.combine"):
+        # back to slot order; an absent expert's slot carries weight 0
+        weight = jnp.where(expert < H, gate, 0.0)                # [T, k]
+        y = _slot_rows(y, inv, order, 1).reshape(T, k, d)
+        out = jnp.einsum("tkd,tk->td", y.astype(jnp.float32), weight)
+        return out.astype(dtype)
+
+
+def _note_chunk_plan(C: int, chunks: int, d: int, f: int) -> None:
+    """Trace-time record of the layer's plan (STATUS ``kernel_plans``, row
+    ``moe_held_chunks``): block_q = the capacity, grid_steps = the chunks
+    the slot axis is cut into. Never fails a trace."""
+    try:
+        from harmony_tpu.runtime.progcache import note_kernel_plan
+
+        note_kernel_plan("moe_held_chunks", C, 0, 0, chunks, True,
+                         d=d, dv=f)
+    except Exception:
+        pass
+
+
 def moe_ffn_dropless(params: Dict[str, jnp.ndarray], x: jnp.ndarray,
                      cfg: DroplessConfig, seqs: int = 1
                      ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
@@ -277,30 +495,28 @@ def moe_ffn_dropless(params: Dict[str, jnp.ndarray], x: jnp.ndarray,
     ``w_e * down_e(silu(gate_e x) * up_e x)`` over the token's top-k experts
     that are held here (``w_e``: :func:`_route`), plus the shared MLP where
     the configuration has one. ``stats``: :func:`_route`'s."""
-    from harmony_tpu.ops.grouped_matmul import grouped_matmul
-
     T, d = x.shape
     k, H = cfg.top_k, cfg.experts_held
     gate, expert, slot_expert, tokens, stats = _route(params, x, cfg, seqs)
-    # slots sorted by expert: the held experts' runs come first (they are
-    # experts 0 .. H-1), absent experts' slots after them
-    with step_scope("moe.dispatch"):
-        order = jnp.argsort(slot_expert, stable=True)
-        inv = jnp.argsort(order)  # a permutation's inverse, again by sorting
-        sizes = tokens[:H]
-        rows = _slot_rows(x, order, inv, k)                      # [T * k, d]
     dtype = x.dtype
-    with step_scope("moe.experts"):
-        h = (jax.nn.silu(grouped_matmul(rows, params["wg"].astype(dtype),
-                                        sizes))
-             * grouped_matmul(rows, params["wu"].astype(dtype), sizes))
-        y = grouped_matmul(h, params["wd"].astype(dtype), sizes)  # [T * k, d]
-    with step_scope("moe.combine"):
-        # back to slot order; an absent expert's slot carries weight 0
-        weight = jnp.where(expert < H, gate, 0.0)                # [T, k]
-        y = _slot_rows(y, inv, order, 1).reshape(T, k, d)
-        out = jnp.einsum("tkd,tk->td", y.astype(jnp.float32), weight)
-        out = out.astype(dtype)
+    C, chunks = chunk_plan(T * k, H, cfg.num_experts)
+    if not chunks:
+        out = _experts_plain(x, gate, expert, slot_expert, tokens,
+                             params["wg"], params["wu"], params["wd"])
+    else:
+        _note_chunk_plan(C, chunks, d, cfg.d_ff)
+        # slots sorted by expert: the held experts' runs come first (they
+        # are experts 0 .. H-1) and only they are computed, C at a time
+        with step_scope("moe.dispatch"):
+            order = jnp.argsort(slot_expert, stable=True)
+            order = jnp.pad(order, (0, chunks * C - T * k))
+            offsets = jnp.concatenate([jnp.zeros(1, jnp.int32),
+                                       jnp.cumsum(tokens[:H])])
+        with step_scope("moe.experts"):
+            weights = [params[w].astype(dtype) for w in ("wg", "wu", "wd")]
+        out = _experts_chunked(C, x, gate, order, offsets, *weights)
+        with step_scope("moe.combine"):
+            out = out.astype(dtype)
     if cfg.shared_experts:  # plain matmuls on every token, beside the sum
         with step_scope("moe.shared"):
             hs = (jax.nn.silu(x @ params["shared_wg"].astype(dtype))

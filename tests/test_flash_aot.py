@@ -100,6 +100,51 @@ def test_grouped_matmul_kernels_compile_for_v5e(one_chip, m, k, n, groups,
         "tpu_custom_call") == 1
 
 
+@pytest.mark.parametrize("tokens,cfg,checkpoint", [
+    # kimi-linear-48b-a3b.solo: 65,536 slots in 16 chunks of 4,096, remat
+    (8192, dict(num_experts=256, top_k=8, d_model=2304, d_ff=1024,
+                experts_held=8, score="sigmoid", norm_topk=True,
+                routed_scale=2.446, shared_experts=1), True),
+    # moonlight-16b-a3b.solo: 98,304 slots in 4 chunks of 24,576
+    (16384, dict(num_experts=64, top_k=6, d_model=2048, d_ff=1408,
+                 experts_held=8, score="sigmoid", norm_topk=True,
+                 routed_scale=2.446, shared_experts=2), False),
+], ids=["kimi-linear", "moonlight"])
+def test_chunked_expert_layer_compiles_for_v5e(one_chip, monkeypatch, tokens,
+                                               cfg, checkpoint):
+    """The expert layer's gradient at the cells' shapes, chunked (the plan
+    the shapes give) against full-length (a row tile no shape reaches): as
+    many kernel calls — one body a layer and pass, no second capacity — and
+    no more temporary memory."""
+    from harmony_tpu.models import moe
+    from harmony_tpu.utils import platform
+
+    monkeypatch.setattr(platform, "trace_is_tpu", lambda: True)
+    cfg = moe.DroplessConfig(**cfg)
+    params = jax.eval_shape(
+        lambda: moe.init_dropless_params(jax.random.PRNGKey(0), cfg))
+    sd = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    x = jax.ShapeDtypeStruct((tokens, cfg.d_model), jnp.bfloat16,
+                             sharding=one_chip)
+    got = {}
+    for form, tile in (("chunked", 512), ("full", 1 << 30)):
+        monkeypatch.setattr(moe, "_ROW_TILE", tile)
+        assert bool(moe.chunk_plan(tokens * cfg.top_k, cfg.experts_held,
+                                   cfg.num_experts)[1]) == (form == "chunked")
+
+        def loss(p, x):  # a new function each time: checkpoint caches traces
+            out, stats = moe.moe_ffn_dropless(p, x, cfg)
+            return (out.astype(jnp.float32) ** 2).sum() + stats["prob_sum"].sum()
+
+        compiled = jax.jit(jax.value_and_grad(
+            jax.checkpoint(loss) if checkpoint else loss, argnums=(0, 1))
+        ).lower(jax.tree_util.tree_map(sd, params), x).compile()
+        got[form] = (compiled.as_text().count("custom_call_target=\"tpu_custom_call\""),
+                     compiled.memory_analysis().temp_size_in_bytes)
+    assert got["chunked"][0] == got["full"][0] == (12 if checkpoint else 9)
+    assert got["chunked"][1] <= got["full"][1], got
+
+
 @pytest.mark.parametrize("model,capacity", [
     (1, 1 << 24),   # criteo-fm.solo: 2^24 rows, 8.6 GB, on one chip
     (4, 1 << 26),   # criteo-fm-x4.solo: 2^26 rows, 8.6 GB a chip

@@ -300,3 +300,232 @@ class TestMoELM:
         with pytest.raises(ValueError, match="moe_experts"):
             make_ep_train_step(TransformerLM(self._cfg(moe_experts=0)),
                                build_mesh(devices[:4], data=4, model=1))
+
+
+# -- the dropless layer's held slots, in chunks of a static capacity ----------
+#
+# The reference is the full-length formulation (every T * k slot sorted,
+# gathered, multiplied, masked and combined), written out here: the layer
+# must equal it under ANY routing, at the tolerance of a float32 sum of at
+# most k terms associated another way (fixed before the chip was used).
+
+from harmony_tpu.models import moe as moe_mod  # noqa: E402
+from harmony_tpu.models.moe import (  # noqa: E402
+    DroplessConfig, chunk_plan, init_dropless_params, moe_ffn_dropless)
+
+RTOL = 1e-6          # of the largest magnitude of what is compared
+SIGMOID = dict(score="sigmoid", norm_topk=True, routed_scale=2.446,
+               shared_experts=1)
+
+
+def _full_length(params, x, cfg, seqs=1):
+    """The layer as it stood before it was chunked, line for line."""
+    from harmony_tpu.ops.grouped_matmul import grouped_matmul
+    from harmony_tpu.tracing.stepscopes import step_scope
+
+    T, d = x.shape
+    k, H = cfg.top_k, cfg.experts_held
+    gate, expert, slot_expert, tokens, stats = moe_mod._route(
+        params, x, cfg, seqs)
+    with step_scope("moe.dispatch"):
+        order = jnp.argsort(slot_expert, stable=True)
+        inv = jnp.argsort(order)
+        sizes = tokens[:H]
+        rows = moe_mod._slot_rows(x, order, inv, k)
+    dtype = x.dtype
+    with step_scope("moe.experts"):
+        h = (jax.nn.silu(grouped_matmul(rows, params["wg"].astype(dtype),
+                                        sizes))
+             * grouped_matmul(rows, params["wu"].astype(dtype), sizes))
+        y = grouped_matmul(h, params["wd"].astype(dtype), sizes)
+    with step_scope("moe.combine"):
+        weight = jnp.where(expert < H, gate, 0.0)
+        y = moe_mod._slot_rows(y, inv, order, 1).reshape(T, k, d)
+        out = jnp.einsum("tkd,tk->td", y.astype(jnp.float32), weight)
+        out = out.astype(dtype)
+    if cfg.shared_experts:
+        with step_scope("moe.shared"):
+            hs = (jax.nn.silu(x @ params["shared_wg"].astype(dtype))
+                  * (x @ params["shared_wu"].astype(dtype)))
+            out = out + hs @ params["shared_wd"].astype(dtype)
+    return out, stats
+
+
+def _loss(layer, cfg, checkpoint=False):
+    def loss(params, x):
+        out, stats = layer(params, x, cfg)
+        # the router's gradient through the gates AND the statistics
+        return (out ** 2).sum() + 0.1 * (stats["prob_sum"] ** 2).sum(), out
+    return jax.checkpoint(loss) if checkpoint else loss
+
+
+def _assert_close(got, want, what):
+    got, want = np.asarray(got), np.asarray(want)
+    scale = max(float(np.abs(want).max()), 1e-30)
+    assert float(np.abs(got - want).max()) <= RTOL * scale * 8, what
+
+
+def _compare(cfg, params, x, checkpoint=False):
+    """The layer against the full-length formulation: output and every
+    gradient; returns the layer's held token-slots."""
+    outs = []
+    for layer in (moe_ffn_dropless, _full_length):
+        (_, out), grads = jax.jit(jax.value_and_grad(
+            _loss(layer, cfg, checkpoint), argnums=(0, 1), has_aux=True))(
+                params, x)
+        outs.append((out, grads))
+    (out, (gp, gx)), (want, (wp, wx)) = outs
+    _assert_close(out, want, "out")
+    _assert_close(gx, wx, "d x")
+    assert set(gp) == set(wp)
+    for name in wp:
+        _assert_close(gp[name], wp[name], f"d {name}")
+    assert float(np.abs(np.asarray(gp["router"])).max()) > 0.0
+
+
+@pytest.fixture
+def small_tiles(monkeypatch):
+    """Row tiles of 8, so that shapes a CPU test can afford are chunked."""
+    monkeypatch.setattr(moe_mod, "_ROW_TILE", 8)
+
+
+@pytest.mark.parametrize("checkpoint", [False, True],
+                         ids=["plain", "checkpoint"])
+@pytest.mark.parametrize("router", [{}, SIGMOID], ids=["softmax", "sigmoid"])
+@pytest.mark.parametrize("held", [1, 4, 8])  # of 32: 1/32, 1/8, 1/4
+def test_chunked_layer_equals_the_full_length_formulation(
+        small_tiles, held, router, checkpoint):
+    cfg = DroplessConfig(32, 4, 16, 8, held, **router)
+    T = 64
+    C, chunks = chunk_plan(T * 4, held, 32)
+    # a chunk of half the slots does not pay (PERF.md): 1/4 held runs plain
+    assert (C, chunks) == {1: (16, 16), 4: (64, 4), 8: (256, 0)}[held]
+    params = init_dropless_params(jax.random.PRNGKey(held), cfg)
+    if router:  # a selection bias that is not all zeros
+        params["bias"] = 0.05 * jax.random.normal(jax.random.PRNGKey(9), (32,))
+    x = jax.random.normal(jax.random.PRNGKey(2), (T, 16), jnp.float32)
+    _compare(cfg, params, x, checkpoint)
+
+
+def _routed(choices, E, H, k):
+    """A softmax layer whose token ``t`` chooses exactly ``choices[t]`` (k
+    experts each): the router is the identity and ``x`` holds the logits."""
+    T = len(choices)
+    cfg = DroplessConfig(E, k, E, 8, H)
+    params = init_dropless_params(jax.random.PRNGKey(3), cfg)
+    params["router"] = jnp.eye(E, dtype=jnp.float32)
+    x = 0.1 * np.asarray(jax.random.normal(jax.random.PRNGKey(4), (T, E)))
+    for t, chosen in enumerate(choices):
+        for j, e in enumerate(chosen):
+            x[t, e] = 3.0 + 0.25 * j
+    return cfg, params, jnp.asarray(x, jnp.float32)
+
+
+def _edge(name):
+    """32 tokens x top-2 of 16 experts, 2 held: 64 slots in 4 chunks of
+    16. Returns ``(choices, held slots, chunks that run)``."""
+    T, away = 32, [[8, 9]]
+    both, one, other = [[0, 1]], [[0, 8]], [[1, 9]]
+    return {
+        "no_held_slot": (away * T, 0, 0),
+        "exactly_one_chunk": (one * 10 + other * 6 + away * 16, 16, 1),
+        "one_past_the_chunk": (one * 10 + other * 7 + away * 15, 17, 2),
+        "every_slot_held": (both * T, 64, 4),
+        "a_run_across_a_boundary": (one * 24 + other * 4 + away * 4, 28, 2),
+    }[name]
+
+
+@pytest.mark.parametrize("name", [
+    "no_held_slot", "exactly_one_chunk", "one_past_the_chunk",
+    "every_slot_held", "a_run_across_a_boundary"])
+def test_chunked_layer_is_exact_under_edge_routings(small_tiles, name):
+    from harmony_tpu.metrics.moe import chunks_run
+
+    choices, n_held, runs = _edge(name)
+    cfg, params, x = _routed(choices, 16, 2, 2)
+    assert chunk_plan(64, 2, 16) == (16, 4)
+    out, stats = moe_ffn_dropless(params, x, cfg)
+    tokens = np.asarray(stats["tokens"])
+    assert tokens[:2].sum() == n_held and tokens.sum() == 64
+    # the counter's host arithmetic says how many chunks this call ran
+    assert chunks_run(tokens[None, None, :], 2).tolist() == [[runs]]
+    if not n_held:
+        assert float(np.abs(np.asarray(out)).max()) == 0.0
+    _compare(cfg, params, x)
+
+
+def test_layer_that_holds_every_expert_lowers_as_it_did():
+    """``C >= T * k``: the parent's program, text for text (no knob decides
+    it: the plan is a function of the shapes)."""
+    import hashlib
+
+    for cfg in (DroplessConfig(8, 2, 16, 8, 8), DroplessConfig(8, 2, 16, 8, 3),
+                DroplessConfig(8, 2, 16, 8, 4, **SIGMOID)):
+        assert chunk_plan(64 * 2, cfg.experts_held, 8)[1] == 0
+        params = init_dropless_params(jax.random.PRNGKey(0), cfg)
+        x = jnp.zeros((64, 16), jnp.float32)
+        sha = [hashlib.sha256(jax.jit(jax.grad(
+            lambda p, x, layer=layer: _loss(layer, cfg)(p, x)[0],
+            argnums=(0, 1))).lower(params, x).as_text().encode()).hexdigest()
+            for layer in (moe_ffn_dropless, _full_length)]
+        assert sha[0] == sha[1]
+
+
+def _gmm_call_sites(text):
+    import re
+
+    return (len(re.findall(r"call @_gmm(?:_\d+)?\(", text)),
+            len(re.findall(r"call @_tgmm(?:_\d+)?\(", text)))
+
+
+@pytest.mark.parametrize("checkpoint", [False, True],
+                         ids=["plain", "checkpoint"])
+def test_one_traced_body_a_layer_and_pass(monkeypatch, checkpoint):
+    """The guard no CPU test gave PR 34: the gradient of one expert layer at
+    a held share of 1/8, lowered for a TPU, calls the grouped matmuls as
+    often as the full-length formulation does (3 forward + 3 dx + 3 dw, + 3
+    forward under ``checkpoint``) and holds no conditional at all — a second
+    capacity, or a full-length fallback behind a ``cond``, would be a second
+    copy of the body to trace, differentiate and lower."""
+    from harmony_tpu.utils import platform
+
+    monkeypatch.setattr(platform, "trace_is_tpu", lambda: True)
+    cfg = DroplessConfig(64, 4, 128, 64, 8)
+    assert chunk_plan(1024 * 4, 8, 64) == (1024, 4)
+    params = jax.eval_shape(
+        lambda: init_dropless_params(jax.random.PRNGKey(0), cfg))
+    x = jax.ShapeDtypeStruct((1024, 128), jnp.bfloat16)
+    sites = []
+    for layer in (moe_ffn_dropless, _full_length):
+        text = jax.jit(jax.value_and_grad(
+            _loss(layer, cfg, checkpoint), argnums=(0, 1), has_aux=True)
+        ).trace(params, x).lower(lowering_platforms=("tpu",)).as_text()
+        sites.append(_gmm_call_sites(text))
+        if layer is moe_ffn_dropless:
+            assert "stablehlo.case" not in text and "stablehlo.if" not in text
+            assert text.count("stablehlo.while") == (3 if checkpoint else 2)
+    assert sites[0] == sites[1] == ((9, 3) if checkpoint else (6, 3))
+
+
+def test_the_chunk_plan_reaches_kernel_plans():
+    """What a compiled program runs is on STATUS: the capacity and the
+    chunks the slot axis is cut into, and the kernels' tiles at ``M = C``."""
+    from harmony_tpu.runtime import progcache
+    from harmony_tpu.tracing import trace_span
+
+    cfg = DroplessConfig(64, 4, 128, 64, 8)
+    params = jax.eval_shape(
+        lambda: init_dropless_params(jax.random.PRNGKey(0), cfg))
+    x = jax.ShapeDtypeStruct((1024, 128), jnp.float32)
+    with trace_span("job.build_step", job_id="plan-chunks"):
+        jax.jit(jax.grad(lambda p, x: _loss(moe_ffn_dropless, cfg)(p, x)[0])
+                ).trace(params, x)
+    rows = progcache.kernel_plans()["plan-chunks"]
+    plan, = [r for r in rows if r["kernel"] == "moe_held_chunks"]
+    assert (plan["block_q"], plan["grid_steps"]) == (1024, 4)
+    assert (plan["d"], plan["dv"]) == (128, 64)
+    gmm = [r for r in rows if r["kernel"].startswith("harmony_gmm_")]
+    assert {r["kernel"] for r in gmm} == {
+        "harmony_gmm_fwd", "harmony_gmm_dx", "harmony_gmm_dw"}
+    # tiles planned for the chunk's rows: 1024 / 512 row tiles + 8 groups - 1
+    assert {r["grid_steps"] for r in gmm} == {2 + 8 - 1}

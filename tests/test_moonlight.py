@@ -577,6 +577,14 @@ def test_a_tiny_moonlight_tenant_through_the_jobserver_equals_the_replay():
         get_registry().expose())["harmony_moe_expert_tokens_total"]["samples"]
         if l["job"] == "moonlight-tiny"}
     assert layers == {"1"}  # the dense block 0 shows no idle experts
+    # 4 of 8 experts held: nothing to cut, the plain path, no chunk counted;
+    # one expert layer a step, every drained step a call
+    fams = parse_exposition(get_registry().expose())
+    chunks, calls = (sum(v for _, l, v in fams[name]["samples"]
+                         if l["job"] == "moonlight-tiny")
+                     for name in ("harmony_moe_chunks_total",
+                                  "harmony_moe_layer_calls_total"))
+    assert chunks == 0 and calls >= 4
     plans = {p["kernel"]: p for p in progcache.kernel_plans().get(
         "moonlight-tiny", [])}
     assert {"harmony_gmm_fwd", "harmony_gmm_dx", "harmony_gmm_dw"} <= set(plans)

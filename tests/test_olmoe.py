@@ -347,6 +347,51 @@ def test_counters_and_status_row_from_expert_tokens():
     assert reader.read({}) is None
 
 
+def _chunk_counters(job):
+    from harmony_tpu.metrics.registry import get_registry, parse_exposition
+
+    fams = parse_exposition(get_registry().expose())
+    return [sum(v for _, l, v in fams[name]["samples"] if l["job"] == job)
+            for name in ("harmony_moe_chunks_total",
+                         "harmony_moe_layer_calls_total")]
+
+
+@pytest.mark.parametrize("overflow,ratio", [(0, 1.0), (1, 7 / 6), (2, 8 / 6)])
+def test_chunk_counters_read_past_one_when_a_layer_overflows(overflow, ratio):
+    """A drained window of 3 steps x 2 layers, 8,192 slots a call with 1 of
+    8 experts held: capacity 2,048. ``overflow`` calls of it route 2,049 and
+    more to the held expert and ran a second chunk."""
+    from harmony_tpu.metrics import moe
+    from harmony_tpu.models.moe import chunk_plan
+
+    assert chunk_plan(8192, 1, 8) == (2048, 4)
+    per_step = np.zeros((3, 2, 8))
+    per_step[:, :, 0], per_step[:, :, 5] = 2048, 8192 - 2048
+    for step in range(overflow):
+        per_step[step, 1, 0], per_step[step, 1, 5] = 2049, 8192 - 2049
+    job = f"olmoe-chunks-{overflow}"
+    moe.observe(job, per_step, experts_held=1)
+    chunks, calls = _chunk_counters(job)
+    assert calls == 6 and chunks == 6 + overflow
+    reader = load_by_path("layer_metrics", "moe_chunks_per_call")
+    assert reader.read({"phases": {job: None}}) == pytest.approx(ratio)
+    assert reader.read({}) is None
+    assert reader.read({"phases": {"no-such-job": None}}) is None
+
+
+def test_chunk_counters_read_zero_on_the_plain_path():
+    """Every expert held (or a share that leaves nothing to cut): calls are
+    counted, chunks are not, and the ratio reads 0."""
+    from harmony_tpu.metrics import moe
+
+    per_step = np.zeros((3, 2, 8))
+    per_step[:, :, :] = [4, 2, 2, 0, 8, 0, 0, 0]
+    moe.observe("olmoe-plain", per_step, experts_held=3)
+    assert _chunk_counters("olmoe-plain") == [0, 6]
+    reader = load_by_path("layer_metrics", "moe_chunks_per_call")
+    assert reader.read({"phases": {"olmoe-plain": None}}) == 0.0
+
+
 def test_replay_begins_with_the_programs_logits(monkeypatch, capsys):
     """What the cell's ``correct`` evaluates starts with the program's
     logits against the reference's: it passes as the program stands, it
