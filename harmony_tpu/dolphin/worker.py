@@ -30,6 +30,7 @@ from __future__ import annotations
 
 import contextlib
 import logging
+import os
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -1042,6 +1043,19 @@ class WorkerTasklet:
     # chains and metric latency stay short.
     EPOCH_WINDOW = 8
 
+    def _epoch_window_cap(self) -> int:
+        """``EPOCH_WINDOW``, or the operator's ``HARMONY_EPOCH_WINDOW`` where
+        that is smaller: a job whose step runs for most of a second feeds the
+        ledger (STATUS, the metrics) only every ``window x step`` seconds, and
+        a drain costs it a host turnaround — the operator's trade. A
+        setting, not a figure taken from the epochs' measured time: every
+        process of a pod must cut its windows alike, and their clocks do
+        not agree. Process-wide, like every ``HARMONY_*`` knob."""
+        raw = os.environ.get("HARMONY_EPOCH_WINDOW")
+        if not raw:
+            return self.EPOCH_WINDOW
+        return max(1, min(self.EPOCH_WINDOW, int(raw)))
+
     def _epoch_window_len(self, epoch: int, num_epochs: int) -> int:
         """How many consecutive epochs may dispatch before the next drain.
 
@@ -1069,7 +1083,7 @@ class WorkerTasklet:
             return 1
         if self.epoch_callback is not None and not self.defer_epoch_callback:
             return 1
-        w = min(self.EPOCH_WINDOW, num_epochs - epoch)
+        w = min(self._epoch_window_cap(), num_epochs - epoch)
         if self.pending_plan_epoch is not None:
             due = self.pending_plan_epoch()
             if due is not None and due >= epoch:

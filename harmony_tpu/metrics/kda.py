@@ -12,7 +12,8 @@ and the trainer hands here:
     state saturates), one pinned at 0 remembers one token;
   * ``harmony_kda_beta_mean{job,layer}`` — the mean write strength;
   * ``harmony_model_layers{job,kind}`` — how many blocks of each kind
-    (``TransformerConfig.layer_kinds()``: ``kda`` | ``mha`` | ``mla``) the
+    (``TransformerConfig.layer_kinds()``: ``kda`` | ``mha`` | ``mla`` |
+    ``swa`` | ``full``) the
     job's model has, set when the job initialises its table.
 
 Under a profiler session the light span ``kda.observe`` marks each drain.

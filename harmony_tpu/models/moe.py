@@ -181,6 +181,7 @@ class DroplessConfig:
     routed_scale: float = 1.0
     shared_experts: int = 0   # one MLP of this many expert widths, all tokens
     seq_aux: bool = False     # stats carry the sequence-wise balance term
+    act: str = "silu"         # the routed experts' gate: "silu" | "relu"
 
 
 def init_dropless_params(rng: jax.Array, cfg: DroplessConfig
@@ -316,8 +317,11 @@ def chunk_plan(slots: int, held: int, experts: int) -> Tuple[int, int]:
     return (slots, 0) if _LEAST_CUT * C > slots else (C, -(-slots // C))
 
 
-def _gated(g, u):
-    return jax.nn.silu(g) * u
+_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def _gated(g, u, act="silu"):
+    return _ACTS[act](g) * u
 
 
 def _chunk(C: int, i, order, offsets, k: int):
@@ -329,7 +333,8 @@ def _chunk(C: int, i, order, offsets, k: int):
     return lo, slots, slots // k, sizes
 
 
-def _run_chunks(C: int, save: bool, x, weight, order, offsets, wg, wu, wd):
+def _run_chunks(C: int, act: str, save: bool, x, weight, order, offsets, wg,
+                wu, wd):
     """``(out [T, d] f32, (g, u))``: the routed sum over the held slots,
     chunk by chunk while a chunk starts inside them; with ``save`` the
     gate and up products of every chunk that ran, stacked by sorted row
@@ -353,7 +358,7 @@ def _run_chunks(C: int, save: bool, x, weight, order, offsets, wg, wu, wd):
         with step_scope("moe.experts"):
             g = _gmm(rows, wg, sizes, False, interpret)
             u = _gmm(rows, wu, sizes, False, interpret)
-            y = _gmm(_gated(g, u), wd, sizes, False, interpret)  # [C, d]
+            y = _gmm(_gated(g, u, act), wd, sizes, False, interpret)  # [C, d]
             if save:
                 saved = tuple(lax.dynamic_update_slice(s, v, (lo, 0))
                               for s, v in zip(saved, (g, u)))
@@ -372,10 +377,11 @@ def _run_chunks(C: int, save: bool, x, weight, order, offsets, wg, wu, wd):
     return out, saved
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _experts_chunked(C, x, weight, order, offsets, wg, wu, wd):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _experts_chunked(C, act, x, weight, order, offsets, wg, wu, wd):
     """``out [T, d]`` float32: per token, ``weight[t, j]`` times the held
-    expert's gated-SiLU MLP of ``x[t]``, summed over the token's slots
+    expert's gated MLP (``act``: :func:`_gated`) of ``x[t]``, summed over
+    the token's slots
     routed to a held expert. ``order [chunks * C]`` are the slots sorted by
     expert (held runs first, padding after), ``offsets [H + 1]`` the held
     runs' bounds in it. Only chunks that start inside the held slots run:
@@ -384,15 +390,17 @@ def _experts_chunked(C, x, weight, order, offsets, wg, wu, wd):
     the same way: autodiff cannot reverse a loop of unknown length, and the
     forward kernels must not run again in it (the benchmark pairs three
     forward, three dx and three dw calls a layer and step)."""
-    return _run_chunks(C, False, x, weight, order, offsets, wg, wu, wd)[0]
+    return _run_chunks(C, act, False, x, weight, order, offsets, wg, wu,
+                       wd)[0]
 
 
-def _experts_chunked_fwd(C, x, weight, order, offsets, wg, wu, wd):
-    out, saved = _run_chunks(C, True, x, weight, order, offsets, wg, wu, wd)
+def _experts_chunked_fwd(C, act, x, weight, order, offsets, wg, wu, wd):
+    out, saved = _run_chunks(C, act, True, x, weight, order, offsets, wg, wu,
+                             wd)
     return out, (x, weight, order, offsets, wg, wu, wd, saved)
 
 
-def _experts_chunked_bwd(C, res, d_out):
+def _experts_chunked_bwd(C, act, res, d_out):
     from harmony_tpu.ops.grouped_matmul import _gmm, _note_plans, _tgmm
     from harmony_tpu.utils.platform import trace_is_tpu
 
@@ -418,7 +426,7 @@ def _experts_chunked_bwd(C, res, d_out):
         with step_scope("moe.experts"):
             g = lax.dynamic_slice(g_all, (lo, 0), (C, f))
             u = lax.dynamic_slice(u_all, (lo, 0), (C, f))
-            h = _gated(g, u)
+            h = _gated(g, u, act)
             # the down product's cotangent WITHOUT the gate's weight: the
             # weight's own cotangent is <h, it>, the hidden's is w times it
             d_hu = _gmm(d_rows_out.astype(dtype), wd, sizes, True,
@@ -426,9 +434,13 @@ def _experts_chunked_bwd(C, res, d_out):
             d_wd = d_wd + _tgmm(h, d_y, sizes, interpret)
             d_h = d_hu * w
             g32, u32 = g.astype(f32), u.astype(f32)
-            sg = jax.nn.sigmoid(g32)
-            d_g = (d_h * u32 * sg * (1.0 + g32 * (1.0 - sg))).astype(dtype)
-            d_u = (d_h * g32 * sg).astype(dtype)
+            if act == "silu":
+                sg = jax.nn.sigmoid(g32)
+                d_g = (d_h * u32 * sg * (1.0 + g32 * (1.0 - sg))).astype(dtype)
+                d_u = (d_h * g32 * sg).astype(dtype)
+            else:  # relu: the gate passes where it is positive
+                d_g = jnp.where(g32 > 0, d_h * u32, 0.0).astype(dtype)
+                d_u = jnp.where(g32 > 0, d_h * g32, 0.0).astype(dtype)
             d_rows = (_gmm(d_g, wg, sizes, True, interpret)
                       + _gmm(d_u, wu, sizes, True, interpret))
             d_wg = d_wg + _tgmm(rows, d_g, sizes, interpret)
@@ -450,7 +462,8 @@ def _experts_chunked_bwd(C, res, d_out):
 _experts_chunked.defvjp(_experts_chunked_fwd, _experts_chunked_bwd)
 
 
-def _experts_plain(x, gate, expert, slot_expert, tokens, wg, wu, wd):
+def _experts_plain(x, gate, expert, slot_expert, tokens, wg, wu, wd,
+                   act="silu"):
     """The routed sum over ALL ``T * k`` sorted slots in one pass: where
     every expert is held, or the held share leaves nothing to cut."""
     from harmony_tpu.ops.grouped_matmul import grouped_matmul
@@ -463,7 +476,7 @@ def _experts_plain(x, gate, expert, slot_expert, tokens, wg, wu, wd):
         rows = _slot_rows(x, order, inv, k)                      # [T * k, d]
     dtype = x.dtype
     with step_scope("moe.experts"):
-        h = (jax.nn.silu(grouped_matmul(rows, wg.astype(dtype), sizes))
+        h = (_ACTS[act](grouped_matmul(rows, wg.astype(dtype), sizes))
              * grouped_matmul(rows, wu.astype(dtype), sizes))
         y = grouped_matmul(h, wd.astype(dtype), sizes)           # [T * k, d]
     with step_scope("moe.combine"):
@@ -488,21 +501,26 @@ def _note_chunk_plan(C: int, chunks: int, d: int, f: int) -> None:
 
 
 def moe_ffn_dropless(params: Dict[str, jnp.ndarray], x: jnp.ndarray,
-                     cfg: DroplessConfig, seqs: int = 1
+                     cfg: DroplessConfig, seqs: int = 1,
+                     router_x: Optional[jnp.ndarray] = None
                      ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """``(out [T, d], stats)`` for ``x [T, d]`` (``seqs`` sequences of
     ``T // seqs`` tokens, in order). ``out`` sums, per token,
-    ``w_e * down_e(silu(gate_e x) * up_e x)`` over the token's top-k experts
+    ``w_e * down_e(act(gate_e x) * up_e x)`` over the token's top-k experts
     that are held here (``w_e``: :func:`_route`), plus the shared MLP where
-    the configuration has one. ``stats``: :func:`_route`'s."""
+    the configuration has one. ``stats``: :func:`_route`'s. The router
+    reads ``router_x [T, d]`` where given (a block that routes on its
+    input), else the rows it dispatches."""
     T, d = x.shape
     k, H = cfg.top_k, cfg.experts_held
-    gate, expert, slot_expert, tokens, stats = _route(params, x, cfg, seqs)
+    gate, expert, slot_expert, tokens, stats = _route(
+        params, x if router_x is None else router_x, cfg, seqs)
     dtype = x.dtype
     C, chunks = chunk_plan(T * k, H, cfg.num_experts)
     if not chunks:
         out = _experts_plain(x, gate, expert, slot_expert, tokens,
-                             params["wg"], params["wu"], params["wd"])
+                             params["wg"], params["wu"], params["wd"],
+                             cfg.act)
     else:
         _note_chunk_plan(C, chunks, d, cfg.d_ff)
         # slots sorted by expert: the held experts' runs come first (they
@@ -514,7 +532,7 @@ def moe_ffn_dropless(params: Dict[str, jnp.ndarray], x: jnp.ndarray,
                                        jnp.cumsum(tokens[:H])])
         with step_scope("moe.experts"):
             weights = [params[w].astype(dtype) for w in ("wg", "wu", "wd")]
-        out = _experts_chunked(C, x, gate, order, offsets, *weights)
+        out = _experts_chunked(C, cfg.act, x, gate, order, offsets, *weights)
         with step_scope("moe.combine"):
             out = out.astype(dtype)
     if cfg.shared_experts:  # plain matmuls on every token, beside the sum

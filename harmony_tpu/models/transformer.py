@@ -116,11 +116,37 @@ class TransformerConfig:
     linear_heads: int = 0
     linear_head_dim: int = 0
     short_conv: int = 0
+    # SmallThinker's block (arXiv:2507.20984), ``attn_kind="mha"`` only:
+    # ``n_kv_heads`` key/value heads serve the ``n_heads`` query heads in
+    # groups (0: one each); heads are ``mha_head_dim`` wide whatever
+    # ``d_model / n_heads`` is (0: that quotient); the blocks listed in
+    # ``window_layers`` see the last ``window`` keys (the key itself
+    # included) and carry the rotary positions, every other block sees the
+    # whole causal past and carries NO positions (``pos="rope"`` then means
+    # "in the windowed blocks"); ``moe_route_block_input``: the router of a
+    # block's expert layer reads the block's INPUT, un-normed, not the
+    # normed rows the experts are given; ``moe_act``: the experts' gate
+    # activation, ``"silu"`` | ``"relu"``.
+    n_kv_heads: int = 0
+    mha_head_dim: int = 0
+    window: int = 0
+    window_layers: Tuple[int, ...] = ()
+    moe_route_block_input: bool = False
+    moe_act: str = "silu"
+    # Standard deviation the embedding rows are drawn with. GPT-2's 0.02
+    # leaves a row at a fiftieth of what a block's fan-in projections add to
+    # it, which nothing here divides by depth: attention's average over the
+    # keys then IS the residual stream two blocks in, every token hands the
+    # routers one direction, and a later block's router sends nearly all of
+    # them to the same few experts (tests/test_smallthinker.py; PERF.md
+    # section 6, PR 36). 1.0 is the fan-in rule read for a one-hot input,
+    # and keeps the rows apart.
+    embed_std: float = 0.02
 
     def __post_init__(self):
         from harmony_tpu.models.common import validate_attn
 
-        if self.d_model % self.n_heads:
+        if not self.mha_head_dim and self.d_model % self.n_heads:
             raise ValueError("d_model must divide by n_heads")
         if self.sp_attn not in ("ring", "a2a"):
             raise ValueError(f"unknown sp_attn {self.sp_attn!r}")
@@ -145,6 +171,40 @@ class TransformerConfig:
         if not self.linear_layers and any(kda):
             raise ValueError("linear_heads / linear_head_dim / short_conv "
                              "belong to KDA blocks: set linear_layers")
+        object.__setattr__(self, "window_layers",
+                           tuple(int(i) for i in self.window_layers))
+        grouped = (self.n_kv_heads, self.mha_head_dim, self.window,
+                   self.window_layers)
+        if any(grouped) and self.attn_kind != "mha":
+            raise ValueError("n_kv_heads / mha_head_dim / window / "
+                             "window_layers belong to attn_kind='mha'")
+        if self.n_kv_heads and (self.n_heads % self.n_kv_heads
+                                or self.qk_norm):
+            raise ValueError(
+                f"n_kv_heads {self.n_kv_heads} must divide n_heads "
+                f"{self.n_heads}, and qk_norm (a d_model-wide norm of q and "
+                "k) needs as many key heads as query heads")
+        if bool(self.window) != bool(self.window_layers) or self.window < 0 \
+                or (self.window_layers and (
+                    self.pos != "rope" or set(self.window_layers)
+                    & set(self.linear_layers)
+                    or sorted(set(self.window_layers))
+                    != list(self.window_layers)
+                    or not 0 <= self.window_layers[0]
+                    or self.window_layers[-1] >= self.n_layers)):
+            raise ValueError(
+                "window_layers lists the windowed blocks 0..n_layers-1 in "
+                "order, once each, none of them a KDA block, and needs "
+                "window >= 1 and pos='rope' (rotary in those blocks, no "
+                f"positions in the others); got {self.window_layers}, "
+                f"window {self.window}, pos {self.pos!r}")
+        if self.moe_act not in ("silu", "relu"):
+            raise ValueError(f"unknown moe_act {self.moe_act!r}: 'silu' or "
+                             "'relu'")
+        if not self.moe_top_k and (self.moe_route_block_input
+                                   or self.moe_act != "silu"):
+            raise ValueError("moe_route_block_input / moe_act belong to "
+                             "dropless routing: set moe_top_k")
         if self.pos == "none" and not self.linear_layers:
             raise ValueError(
                 "pos='none' runs only beside KDA blocks (linear_layers): "
@@ -237,12 +297,19 @@ class TransformerConfig:
     def layer_kinds(self) -> Tuple[str, ...]:
         """Each block's token mixer, in order — the ONE answer to "which
         kind of layer is this": ``"kda"`` for the blocks in
-        ``linear_layers``, else ``attn_kind`` (``"mha"`` | ``"mla"``). The
-        block, ``init``, the trainer's vectors (``kda_decay_mean [kda
-        blocks]``), STATUS ``layer_kinds`` and the benchmark's work
-        functions ask here."""
-        return tuple("kda" if i in self.linear_layers else self.attn_kind
-                     for i in range(self.n_layers))
+        ``linear_layers``, else ``attn_kind`` (``"mha"`` | ``"mla"``) — or,
+        in a model with ``window_layers``, ``"swa"`` for those (windowed,
+        rotary) and ``"full"`` for its other softmax blocks (whole causal
+        past, no positions). The block, ``init``, the trainer's vectors
+        (``kda_decay_mean [kda blocks]``), STATUS ``layer_kinds`` and the
+        benchmark's work functions ask here."""
+        def kind(i):
+            if i in self.linear_layers:
+                return "kda"
+            if not self.window_layers:
+                return self.attn_kind
+            return "swa" if i in self.window_layers else "full"
+        return tuple(kind(i) for i in range(self.n_layers))
 
     def ffn_width(self, i: int) -> int:
         """The dense MLP's width in block i (an expert's, in an expert
@@ -269,11 +336,22 @@ class TransformerConfig:
             experts_held=self.moe_experts if held is None else held,
             score=self.moe_score, norm_topk=self.moe_norm_topk,
             routed_scale=self.moe_routed_scale,
-            shared_experts=self.moe_shared_experts, seq_aux=self.moe_seq_aux)
+            shared_experts=self.moe_shared_experts, seq_aux=self.moe_seq_aux,
+            act=self.moe_act)
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.mha_head_dim or self.d_model // self.n_heads
+
+    @property
+    def kv_heads(self) -> int:
+        return self.n_kv_heads or self.n_heads
+
+    @property
+    def qkv_widths(self) -> Tuple[int, int, int]:
+        """The widths of ``wqkv``'s three column blocks (q, k, v)."""
+        hd = self.head_dim
+        return self.n_heads * hd, self.kv_heads * hd, self.kv_heads * hd
 
     def require_classic_block(self, who: str) -> None:
         """The side training steps and the decode path below still assume
@@ -282,12 +360,15 @@ class TransformerConfig:
         if not (self.pos == "learned" and self.ffn == "gelu"
                 and self.tie_embeddings and not self.qk_norm
                 and not self.moe_top_k and self.attn_kind == "mha"
-                and not self.moe_first_dense and not self.linear_layers):
+                and not self.moe_first_dense and not self.linear_layers
+                and not (self.n_kv_heads or self.mha_head_dim
+                         or self.window_layers)):
             raise ValueError(
                 f"{who} runs the GPT-2-era block only (learned positions, "
                 "GELU, tied readout, Switch experts); rotary / no-position / "
                 "QK-norm / SwiGLU / untied / dropless / latent-attention / "
-                "KDA linear-attention / leading-dense configs train through "
+                "KDA linear-attention / grouped-query / windowed / leading-dense "
+                "configs train through "
                 "TransformerLM.loss and TransformerTrainer")
 
 
@@ -396,8 +477,8 @@ class TransformerLM:
             else:
                 layer = {
                     "ln1": jnp.ones((d,), jnp.float32),
-                    "wqkv": dense(ks[0], (d, 3 * d)),
-                    "wo": dense(ks[1], (d, d)),
+                    "wqkv": dense(ks[0], (d, sum(cfg.qkv_widths))),
+                    "wo": dense(ks[1], (cfg.qkv_widths[0], d)),
                     "ln2": jnp.ones((d,), jnp.float32),
                 }
             if cfg.qk_norm:
@@ -417,7 +498,8 @@ class TransformerLM:
                     layer["w3"] = dense(jax.random.fold_in(ks[2], 1), (d, f))
             layers.append(layer)
         params = {
-            "embed": jax.random.normal(k_emb, (cfg.vocab_size, d), jnp.float32) * 0.02,
+            "embed": jax.random.normal(k_emb, (cfg.vocab_size, d), jnp.float32)
+            * cfg.embed_std,
             "ln_f": jnp.ones((d,), jnp.float32),
             "layers": layers,
         }
@@ -488,8 +570,8 @@ class TransformerLM:
             else:
                 layer = {
                     "ln1": np.ones((d,), np.float32),
-                    "wqkv": dense((d, 3 * d)),
-                    "wo": dense((d, d)),
+                    "wqkv": dense((d, sum(cfg.qkv_widths))),
+                    "wo": dense((cfg.qkv_widths[0], d)),
                     "ln2": np.ones((d,), np.float32),
                 }
             if cfg.qk_norm:
@@ -525,7 +607,7 @@ class TransformerLM:
                     layer["w3"] = dense((d, f))
             layers.append(layer)
         params = {
-            "embed": (0.02 * rng.standard_normal(
+            "embed": (cfg.embed_std * rng.standard_normal(
                 (cfg.vocab_size, d))).astype(np.float32),
             "ln_f": np.ones((d,), np.float32),
             "layers": layers,
@@ -539,9 +621,13 @@ class TransformerLM:
 
     # -- forward ---------------------------------------------------------
 
-    def _attention(self, q, k, v, axis_name: Optional[str]):
+    def _attention(self, q, k, v, axis_name: Optional[str],
+                   window: Optional[int] = None):
         cfg = self.config
         if axis_name is not None:
+            if window is not None or k.shape[1] != q.shape[1]:
+                raise ValueError("the sequence-parallel attention tiers run "
+                                 "neither a window nor grouped heads")
             sp = a2a_attention if cfg.sp_attn == "a2a" else ring_attention
             return sp(q, k, v, axis_name=axis_name, causal=True)
         S = q.shape[2]
@@ -549,9 +635,10 @@ class TransformerLM:
 
         attn = resolve_attn(cfg.attn, S, head_dim=q.shape[3],
                             v_head_dim=v.shape[3], dtype=q.dtype)
+        band = {} if window is None else {"window": window}
         if attn == "flash":  # the kernels tile themselves from the shape
-            return flash_on_mesh(q, k, v, causal=True)
-        return blockwise_attention(q, k, v, causal=True)
+            return flash_on_mesh(q, k, v, causal=True, **band)
+        return blockwise_attention(q, k, v, causal=True, **band)
 
     def _latent_qkv(self, xn, layer, pos_offset):
         """DeepSeek-V3's latent attention operands from the normed input
@@ -642,7 +729,8 @@ class TransformerLM:
                        "beta": lax.stop_gradient(beta.mean())}
 
     def _block(self, x, layer, axis_name: Optional[str],
-               moe_axis: Optional[str] = None, pos_offset: Any = 0):
+               moe_axis: Optional[str] = None, pos_offset: Any = 0,
+               kind: Optional[str] = None):
         """One pre-norm decoder block — the shared body of ``apply`` and
         the pipeline-parallel stage fn. Returns ``(x, aux, mix)``: aux is
         the Switch load-balance loss when the block carries a Switch MoE
@@ -651,45 +739,55 @@ class TransformerLM:
         block's mixer statistics (``_kda_mixer``), None for a softmax block.
         ``moe_axis`` = expert-parallel mesh axis (see ffn_apply). The
         published q/k/v projections are the three column blocks of
-        ``wqkv``."""
+        ``wqkv``. ``kind`` (``layer_kinds()``'s) matters in a model with
+        ``window_layers`` only: ``"swa"`` blocks window and turn, ``"full"``
+        ones do neither."""
         cfg = self.config
         eps = cfg.norm_eps
+        x_in = x
         with step_scope("norm"):
             xn = _norm(x, layer["ln1"].astype(cfg.dtype), eps)
         if "kda" in layer:
             y, mix = self._kda_mixer(xn, layer["kda"])
         else:
-            y, mix = self._softmax_mixer(xn, layer, axis_name, pos_offset), None
+            y, mix = self._softmax_mixer(xn, layer, axis_name, pos_offset,
+                                         kind), None
         x = x + y
         with step_scope("norm"):
             xn = _norm(x, layer["ln2"].astype(cfg.dtype), eps)
-        out, aux = ffn_apply(cfg, layer, xn, moe_axis=moe_axis)
+        route = {"router_x": x_in} if cfg.moe_route_block_input else {}
+        out, aux = ffn_apply(cfg, layer, xn, moe_axis=moe_axis, **route)
         return x + out, aux, mix
 
-    def _softmax_mixer(self, xn, layer, axis_name, pos_offset):
+    def _softmax_mixer(self, xn, layer, axis_name, pos_offset, kind=None):
         """Softmax attention (``attn_kind``) on the normed input ``xn [B,
         S, d]`` through its output projection."""
         cfg = self.config
         B, S = xn.shape[0], xn.shape[1]
         h, hd, eps = cfg.n_heads, cfg.head_dim, cfg.norm_eps
+        window = cfg.window if kind == "swa" else None
         if cfg.attn_kind == "mla":
             q, k, v = self._latent_qkv(xn, layer, pos_offset)
         else:
             with step_scope("mixer.qkv"):
                 qkv = xn @ layer["wqkv"].astype(cfg.dtype)      # [B, S, 3d]
-                q, k, v = jnp.split(qkv, 3, axis=-1)
+                if cfg.qkv_widths == (cfg.d_model,) * 3:
+                    q, k, v = jnp.split(qkv, 3, axis=-1)
+                else:  # grouped queries: k and v are kv_heads heads wide
+                    wq, wk, _ = cfg.qkv_widths
+                    q, k, v = jnp.split(qkv, (wq, wq + wk), axis=-1)
                 if cfg.qk_norm:
                     q = _norm(q, layer["q_norm"].astype(cfg.dtype), eps)
                     k = _norm(k, layer["k_norm"].astype(cfg.dtype), eps)
-                to_heads = lambda t: t.reshape(B, S, h, hd).transpose(
+                to_heads = lambda t: t.reshape(B, S, -1, hd).transpose(
                     0, 2, 1, 3)
                 q, k, v = to_heads(q), to_heads(k), to_heads(v)
-            if cfg.pos == "rope":
+            if cfg.pos == "rope" and kind != "full":
                 with step_scope("mixer.rope"):
                     q = rope(q, cfg.rope_theta, pos_offset)
                     k = rope(k, cfg.rope_theta, pos_offset)
         with step_scope("mixer.core"):
-            o = self._attention(q, k, v, axis_name)
+            o = self._attention(q, k, v, axis_name, window)
         with step_scope("mixer.out"):
             o = o.transpose(0, 2, 1, 3).reshape(B, S, h * v.shape[3])
             return o @ layer["wo"].astype(cfg.dtype)
@@ -733,12 +831,20 @@ class TransformerLM:
             # with one extra forward pass of FLOPs (the MXU has headroom;
             # HBM usually doesn't).
             block = jax.checkpoint(block)
+        # a model with window_layers has two kinds of softmax block, each
+        # its own traced body (no other model traces a second one)
+        wrap = jax.checkpoint if cfg.remat else (lambda f: f)
+        by_kind = {kind: wrap(functools.partial(
+            self._block, axis_name=axis_name, moe_axis=moe_axis,
+            pos_offset=pos_offset, kind=kind)) for kind in ("swa", "full")
+        } if cfg.window_layers else {}
+        kinds = cfg.layer_kinds()
         aux = jnp.asarray(0.0, jnp.float32)
         routed = []  # dropless layers' statistics
         mixers = []  # KDA blocks' statistics
         for i, layer in enumerate(params["layers"]):
             with step_scope("blk", i):
-                x, a, mix = block(x, layer)
+                x, a, mix = by_kind.get(kinds[i], block)(x, layer)
             if mix is not None:
                 mixers.append(mix)
             if isinstance(a, dict):
@@ -816,7 +922,7 @@ def _next_token_ce(logits, targets) -> jnp.ndarray:
 
 
 def ffn_apply(cfg, layer, xn, no_drop: bool = False,
-              moe_axis: Optional[str] = None):
+              moe_axis: Optional[str] = None, router_x=None):
     """Dense or MoE FFN on [..., d] activations — the ONE dense/MoE
     dispatch shared by training blocks and the decode path. Returns
     ``(out, aux)``. ``no_drop`` lifts the expert capacity to cover every
@@ -824,13 +930,18 @@ def ffn_apply(cfg, layer, xn, no_drop: bool = False,
     capacity_factor would drop tokens whenever two rows share an expert,
     letting one sequence degrade another's output). ``moe_axis`` is the
     expert-parallel mesh axis: expert params are sharded on their leading
-    dim and token buckets move over ICI via all_to_all (moe_ffn)."""
+    dim and token buckets move over ICI via all_to_all (moe_ffn).
+    ``router_x``: what a dropless router reads where that is not ``xn``
+    (``moe_route_block_input``)."""
     if "moe" in layer and cfg.moe_top_k:
         from harmony_tpu.models.moe import moe_ffn_dropless
 
+        route = {} if router_x is None else {
+            "router_x": router_x.reshape(-1, cfg.d_model)}
         out, stats = moe_ffn_dropless(layer["moe"],
                                       xn.reshape(-1, cfg.d_model),
-                                      cfg.dropless_cfg, seqs=xn.shape[0])
+                                      cfg.dropless_cfg, seqs=xn.shape[0],
+                                      **route)
         return out.reshape(xn.shape), stats
     if "moe" in layer:
         import dataclasses as _dc

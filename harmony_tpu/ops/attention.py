@@ -17,9 +17,9 @@ A grid step of a Pallas kernel costs ~0.35 us on a v5e whatever it
 computes, so the kernels choose how much one step does from the shape
 (:func:`tile_plan`): a step holds one RESIDENT tile (q rows for the forward
 and dQ, kv rows for dK/dV) and one STREAMED tile of the other operand — the
-whole sequence where VMEM allows — which an in-kernel loop walks in
-sub-blocks. Under a causal mask the loop skips the sub-blocks above the
-diagonal and masks only those the diagonal crosses.
+whole sequence where VMEM allows (a windowed dK/dV: the band) — which an
+in-kernel loop walks in sub-blocks. Under a causal mask the loop skips the
+sub-blocks above the diagonal and masks only those the diagonal crosses.
 
 Layout: (batch, heads, seq, head_dim). Any head_dim compiles; VMEM tiles pad
 it to the 128-lane width, so 64 (GPT-2) fills half of each vector register
@@ -27,6 +27,23 @@ and half of the MXU's contraction depth. ``v`` (and with it the output, dO
 and dV) may have a width of its own: latent attention multiplies 192-wide q
 and k and sums 128-wide values, and pays for neither a padded v nor a
 second lowering — equal widths trace the programs they always did.
+
+Two more shapes of the same three kernels, each a branch taken in Python at
+trace time, so a call without them traces the program it always did:
+
+  * **grouped queries**: ``k`` / ``v`` may have fewer heads than ``q``, a
+    divisor of its count; query head ``h`` reads K/V head ``h // (H //
+    Hkv)`` through the BlockSpec index map (nothing is repeated in HBM), and
+    the dK/dV kernel's innermost grid axis walks the group's query heads so
+    one K/V head's gradient sums over them in its accumulator;
+  * **a window** ``W`` (causal only): row ``i`` sees keys ``j`` with ``0 <=
+    i - j < W``. The in-kernel loop gains a lower bound (sub-blocks behind
+    the window are skipped, those its edge crosses masked), and the
+    streamed grid axis is as long as the BAND's widest run of blocks, each
+    resident tile starting at its own first needed block: grid steps
+    outside the band do not exist, and a step past a tile's last needed
+    block repeats that block's index and fetches nothing. Windowed calls
+    carry their own kernel names (``harmony_flash_win_*``).
 """
 from __future__ import annotations
 
@@ -66,6 +83,32 @@ def _apply_causal_mask(s, row0, col0):
     return jnp.where(ahead >= col0 - row0, s, _NEG_INF)
 
 
+def _apply_band_mask(s, row0, col0, window):
+    """:func:`_apply_causal_mask` with the window's edge beside the
+    diagonal: keep ``0 <= row - col < window``."""
+    ahead = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+             - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
+    lo = col0 - row0
+    return jnp.where((ahead >= lo) & (ahead < lo + window), s, _NEG_INF)
+
+
+def _head_group(q, k, v) -> int:
+    """Query heads a K/V head serves (1: as many K/V heads as query
+    heads)."""
+    h, hkv = q.shape[1], k.shape[1]
+    if v.shape[1] != hkv or hkv < 1 or h % hkv:
+        raise ValueError(f"attention: {h} query heads over {hkv} key and "
+                         f"{v.shape[1]} value heads; the K/V head count "
+                         "must divide the query head count")
+    return h // hkv
+
+
+def _check_window(window, causal) -> None:
+    if window is not None and (not causal or window < 1):
+        raise ValueError(f"attention: window {window} needs causal=True and "
+                         "at least the key itself (window >= 1)")
+
+
 def _resolve_scale(q, scale):
     """One source of truth for the scale default used by the primal
     forward, the VJP forward and the VJP backward."""
@@ -83,16 +126,24 @@ def blockwise_attention(
     causal: bool = False,
     block_k: int = DEFAULT_BLOCK_K,
     scale: Optional[float] = None,
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
     """Streaming-softmax attention: scan over KV blocks carrying (acc, m, l).
 
     q [B,H,Sq,D], k [B,H,Sk,D], v [B,H,Sk,Dv] -> [B,H,Sq,Dv] (``Dv`` may
     differ from ``D``: latent attention's 192-wide q.k beside a 128-wide v).
     O(Sq * block_k) live memory instead of O(Sq*Sk); autodiff through the
-    scan gives the memory-efficient backward.
+    scan gives the memory-efficient backward. ``k`` / ``v`` with fewer
+    heads (a divisor of ``H``) are repeated, query head ``h`` reading K/V
+    head ``h // group``; ``window`` (causal only) keeps ``0 <= i - j <
+    window``.
     """
     B, H, Sq, D = q.shape
     Sk, Dv = k.shape[2], v.shape[3]
+    group = _head_group(q, k, v)
+    _check_window(window, causal)
+    if group > 1:
+        k, v = (jnp.repeat(t, group, axis=1) for t in (k, v))
     scale = scale if scale is not None else D ** -0.5
     block_k = min(block_k, Sk)
     nk, rem = divmod(Sk, block_k)
@@ -115,6 +166,8 @@ def blockwise_attention(
         mask = kv_pos < Sk  # padding
         if causal:
             mask = mask & (q_pos >= kv_pos)
+        if window is not None:
+            mask = mask & (q_pos - kv_pos < window)
         s = jnp.where(mask, s, _NEG_INF)
         m_new = jnp.maximum(m, s.max(axis=-1))
         p = jnp.exp(s - m_new[..., None])
@@ -165,6 +218,15 @@ class TilePlan(NamedTuple):
 #: in STATUS ``kernel_plans``
 _KERNEL_NAMES = {"fwd": "harmony_flash_fwd", "dkv": "harmony_flash_bwd_dkv",
                  "dq": "harmony_flash_bwd_dq"}
+#: the same kernels under a window: told from the full-causal calls by name
+_WIN_KERNEL_NAMES = {"fwd": "harmony_flash_win_fwd",
+                     "dkv": "harmony_flash_win_bwd_dkv",
+                     "dq": "harmony_flash_win_bwd_dq"}
+
+
+def kernel_name(kernel: str, window: Optional[int]) -> str:
+    """The trace name of ``"fwd"`` / ``"dkv"`` / ``"dq"``."""
+    return (_KERNEL_NAMES if window is None else _WIN_KERNEL_NAMES)[kernel]
 _ONE_BLOCK = 256              # a whole length up to this is one block as it is
 _RESIDENT = (512, 256, 128)   # resident-block lengths tried, largest first
 _SUB = (1024, 512, 256, 128)  # sub-block lengths tried, largest first
@@ -173,6 +235,8 @@ _TEMPS_MAX = 6 * 2**20        # score-sized temporaries of one sub-block: past
 _VMEM_DEFAULT = 16 * 2**20    # Mosaic's scoped-VMEM default on every TPU so far
 _VMEM_FREE = 12 * 2**20       # estimates up to here run under that default
 _VMEM_CAP = 40 * 2**20        # the most a plan may need (a v5e has 128 MiB)
+_BAND_TILES = 4               # a windowed dK/dV streams q tiles of at most
+                              # window / this many rows (``_plan_kernel``)
 
 
 def _temp_bytes(kernel, resident, sub):
@@ -214,12 +278,25 @@ def _with_limit(kernel, block_q, block_k, sub, d, itemsize, dv):
     return Tiles(block_q, block_k, sub, limit)
 
 
-def _plan_kernel(kernel, sq, sk, d, itemsize, dv):
+def _plan_kernel(kernel, sq, sk, d, itemsize, dv, window=None):
     """Largest tiles that divide the lengths and fit the budget: the
     resident block first, then the widest sub-block whose temporaries stay
     under ``_TEMPS_MAX``, then as much of the streamed length as fits
-    ``_VMEM_CAP`` (the whole of it at every shape a model here runs)."""
+    ``_VMEM_CAP`` (the whole of it at every shape a model here runs) — or,
+    for the dK/dV kernel under a window, as much as STREAMS THE BAND: at
+    most ``window / _BAND_TILES`` rows a tile. That kernel's streamed tile
+    is one query head's q, dO and two lane-replicated statistics (1.5 KB a
+    row), fetched again for every K/V tile and every head of a group, so
+    rows outside the band are worth not fetching; the forward and dQ
+    kernels stream K and V, which every q tile and every query head of a
+    group shares — whole, they are fetched once a K/V head, window or not
+    (on the chip at 28 heads over 4 x 16,384 x 128, window 4,096: dK/dV
+    26.9 ms a call with 8,192-row tiles, 16.4 / 13.9 / 12.9 / 13.1 with
+    4,096 / 2,048 / 1,024 / 512; the forward 8.4 ms whole against 9.1-9.3
+    in tiles of 1,024-4,096: PERF.md, PR 36)."""
     res_len, str_len = (sk, sq) if kernel == "dkv" else (sq, sk)
+    most = str_len if kernel != "dkv" or window is None else \
+        max(window // _BAND_TILES, 1)
 
     def divisors(length, sizes):
         if length <= _ONE_BLOCK:
@@ -230,7 +307,7 @@ def _plan_kernel(kernel, sq, sk, d, itemsize, dv):
         for sub in divisors(str_len, _SUB):
             if sub > _LANES and _temp_bytes(kernel, res, sub) > _TEMPS_MAX:
                 continue
-            for n in range(str_len // sub, 0, -1):
+            for n in range(max(min(str_len, most) // sub, 1), 0, -1):
                 if (str_len // sub) % n:
                     continue
                 bq, bk = (n * sub, res) if kernel == "dkv" else (res, n * sub)
@@ -241,7 +318,7 @@ def _plan_kernel(kernel, sq, sk, d, itemsize, dv):
 
 
 def tile_plan(sq, sk, d, dtype, causal=False, block_q=None, block_k=None,
-              dv=None):
+              dv=None, window=None):
     """The tiles of the three kernels for q [.., sq, d] against k
     [.., sk, d] and v [.., sk, dv] (``dv`` None: ``d``), or None where the
     kernels cannot tile the lengths: a length over ``_ONE_BLOCK`` must
@@ -254,7 +331,9 @@ def tile_plan(sq, sk, d, dtype, causal=False, block_q=None, block_k=None,
     ``block_q`` / ``block_k`` win over the plan and are taken as given (one
     sub-block a grid step: the tiling of the interpreter tests and the
     ring's callers). ``causal`` does not change the tiles: the in-kernel
-    loop bounds carry the causal skip at sub-block grain."""
+    loop bounds carry the causal skip at sub-block grain; a ``window``
+    shortens the dK/dV kernel's streamed tile to the band
+    (``_plan_kernel``) and nothing else."""
     del causal
     itemsize = jnp.dtype(dtype).itemsize
     dv = d if dv is None else dv
@@ -266,18 +345,18 @@ def tile_plan(sq, sk, d, dtype, causal=False, block_q=None, block_k=None,
         return TilePlan(_with_limit("fwd", bq, bk, bk, d, itemsize, dv),
                         _with_limit("dkv", bq, bk, bq, d, itemsize, dv),
                         _with_limit("dq", bq, bk, bk, d, itemsize, dv), False)
-    tiles = [_plan_kernel(kern, sq, sk, d, itemsize, dv)
+    tiles = [_plan_kernel(kern, sq, sk, d, itemsize, dv, window)
              for kern in ("fwd", "dkv", "dq")]
     return None if None in tiles else TilePlan(*tiles, True)
 
 
-def _require_plan(q, k, v, causal, block_q, block_k):
+def _require_plan(q, k, v, causal, block_q, block_k, window=None):
     sq, sk, d = q.shape[2], k.shape[2], q.shape[3]
     if k.shape[3] != d:
         raise ValueError(f"flash attention: q is {d} wide and k "
                          f"{k.shape[3]}; only v may have a width of its own")
     plan = tile_plan(sq, sk, d, q.dtype, causal, block_q, block_k,
-                     dv=v.shape[3])
+                     dv=v.shape[3], window=window)
     if plan is None:
         raise ValueError(
             f"flash attention cannot tile seq lens ({sq},{sk})"
@@ -287,19 +366,34 @@ def _require_plan(q, k, v, causal, block_q, block_k):
     return plan
 
 
-def _note_plan(plan, kernels, bh, sq, sk, d, dv):
+def _note_plan(plan, kernels, q, k, v, causal, window):
     """Trace-time record of the tiling a compiled program runs — the plan is
     static per shape, so it engages always or never; STATUS ``kernel_plans``
-    (beside ``compiles``) says which one a job got. Never fails a trace."""
+    (beside ``compiles``) says which one a job got. A call with grouped
+    heads or a window adds what the BAND needs of the plan
+    (:func:`band_work`, summed over the call's heads) and sets
+    ``harmony_flash_masked_share``. Never fails a trace."""
     try:
         from harmony_tpu.runtime.progcache import note_kernel_plan
 
+        bh, sq, sk = q.shape[0] * q.shape[1], q.shape[2], k.shape[2]
         for kern in kernels:
             t = getattr(plan, kern)
+            band = None
+            steps = (sq // t.block_q) * (sk // t.block_k)
+            if window is not None or k.shape[1] != q.shape[1]:
+                work = band_work(kern, t, sq, sk, causal, window)
+                steps = work["grid_steps"]
+                band = {"window": window or 0, "kv_heads": k.shape[1],
+                        "band_grid_steps": bh * work["with_work"],
+                        "sub_blocks": bh * work["sub_blocks"],
+                        "masked_sub_blocks": bh * work["masked_sub_blocks"],
+                        "computed": bh * work["computed"],
+                        "masked_share": 1.0 - work["kept"] / work["computed"]}
             note_kernel_plan(
-                _KERNEL_NAMES[kern], t.block_q, t.block_k, t.sub,
-                bh * (sq // t.block_q) * (sk // t.block_k), plan.planned,
-                d=d, dv=dv)
+                kernel_name(kern, window), t.block_q, t.block_k, t.sub,
+                bh * steps, plan.planned, d=q.shape[3], dv=v.shape[3],
+                band=band)
     except Exception:
         pass
 
@@ -342,6 +436,108 @@ def _q_sub_ranges(causal, q0, k0, block_k, sub, n_sub):
     return first, full_from
 
 
+def _kv_band_ranges(q0, block_q, k0, sub, n_sub, window, xp=jnp):
+    """:func:`_kv_sub_ranges` under a window, ``(a, b, c, d)``: sub-blocks
+    ``[a, b)`` are crossed by the window's edge (masked), ``[b, c)`` lie
+    inside the band for every row (no mask), ``[c, d)`` are crossed by the
+    diagonal (masked); ``[0, a)`` lie behind the window and ``[d, n_sub)``
+    above the diagonal (skipped). Where the window is narrower than a tile
+    the edge and the diagonal share sub-blocks: ``[b, c)`` is then empty and
+    every masked sub-block takes both tests (:func:`_apply_band_mask`).
+    ``xp``: ``numpy`` for the static count (:func:`band_work`)."""
+    clip = lambda x: xp.clip(x, 0, n_sub)
+    d = clip((q0 + block_q - k0 + sub - 1) // sub)
+    a = xp.minimum(clip((q0 - window + 1 - k0) // sub), d)
+    b = xp.clip(-((k0 + window - q0 - block_q) // sub), a, d)
+    c = xp.clip((q0 - k0 + 1) // sub, b, d)
+    return a, b, c, d
+
+
+def _q_band_ranges(q0, k0, block_k, sub, n_sub, window, xp=jnp):
+    """:func:`_q_sub_ranges` under a window, ``(a, b, c, d)``: sub-blocks
+    ``[a, b)`` are crossed by the diagonal (masked), ``[b, c)`` lie inside
+    the band (no mask), ``[c, d)`` are crossed by the window's edge
+    (masked); the rest are skipped."""
+    clip = lambda x: xp.clip(x, 0, n_sub)
+    a = clip((k0 - q0) // sub)
+    d = xp.maximum(clip(-((q0 - k0 - block_k - window + 1) // sub)), a)
+    b = xp.clip((k0 + block_k - q0 + sub - 2) // sub, a, d)
+    c = xp.clip((k0 + window - q0) // sub, b, d)
+    return a, b, c, d
+
+
+def _kv_blocks_of(i, block_q, block_k, window, nk, xp=jnp):
+    """``(first, last)`` KV block a q tile ``i`` needs under a window."""
+    last = xp.minimum((i * block_q + block_q - 1) // block_k, nk - 1)
+    first = xp.minimum(xp.maximum(i * block_q - window + 1, 0) // block_k,
+                       last)
+    return first, last
+
+
+def _q_blocks_of(j, block_q, block_k, window, nq, xp=jnp):
+    """``(first, last)`` q block a KV tile ``j`` needs under a window."""
+    first = xp.minimum((j * block_k) // block_q, nq - 1)
+    last = xp.minimum((j * block_k + block_k + window - 2) // block_q, nq - 1)
+    return first, last
+
+
+def _band_steps(kernel, tiles, sq, sk, window) -> int:
+    """Length of the streamed grid axis under a window: the most blocks any
+    resident tile needs (static)."""
+    import numpy as np
+
+    nq, nk = sq // tiles.block_q, sk // tiles.block_k
+    if kernel == "dkv":
+        first, last = _q_blocks_of(np.arange(nk), tiles.block_q,
+                                   tiles.block_k, window, nq, np)
+    else:
+        first, last = _kv_blocks_of(np.arange(nq), tiles.block_q,
+                                    tiles.block_k, window, nk, np)
+    return int((last - first + 1).max())
+
+
+def band_work(kernel, tiles, sq, sk, causal, window):
+    """What one (batch, q head) of a call of ``kernel`` under ``tiles`` runs
+    and what the mask needs of it, counted from the same bounds the kernel
+    loops by (static, a few thousand integer operations): ``grid_steps``
+    the streamed axis has and those ``with_work``, the ``sub_blocks`` the
+    in-kernel loops take and the ``masked`` ones among them, the score
+    elements ``computed`` and those the mask ``kept``. A causal call
+    without a window counts as one whose window reaches past every key."""
+    import numpy as np
+
+    bq, bk, sub = tiles[:3]
+    nq, nk = sq // bq, sk // bk
+    dkv = kernel == "dkv"
+    n_res, n_str, n_sub = (nk, nq, bq // sub) if dkv else (nq, nk, bk // sub)
+    if not causal:
+        run = n_res * n_str * n_sub
+        return {"grid_steps": n_res * n_str, "with_work": n_res * n_str,
+                "sub_blocks": run, "masked_sub_blocks": 0,
+                "computed": sq * sk, "kept": sq * sk}
+    reach = sq + sk if window is None else window
+    rows = np.arange(sq)
+    kept = int((np.minimum(rows + 1, sk)
+                - np.clip(rows + 1 - reach, 0, sk)).sum())
+    with_work = run = masked = widest = 0
+    for r in range(n_res):
+        first, last = (_q_blocks_of(r, bq, bk, reach, nq, np) if dkv else
+                       _kv_blocks_of(r, bq, bk, reach, nk, np))
+        widest = max(widest, int(last) - int(first) + 1)  # _band_steps'
+        for s in range(int(first), int(last) + 1):
+            a, b, c, d = (int(x) for x in (
+                _q_band_ranges(s * bq, r * bk, bk, sub, n_sub, reach, np)
+                if dkv else
+                _kv_band_ranges(r * bq, bq, s * bk, sub, n_sub, reach, np)))
+            run += d - a
+            masked += (b - a) + (d - c)
+            with_work += d > a
+    steps = n_str if window is None else widest
+    return {"grid_steps": steps * n_res, "with_work": with_work,
+            "sub_blocks": run, "masked_sub_blocks": masked,
+            "computed": run * (bk if dkv else bq) * sub, "kept": kept}
+
+
 def _sub_slice(i, sub):
     if isinstance(i, int):
         return pl.ds(i * sub, sub)
@@ -362,11 +558,27 @@ def _for_sub_blocks(lo, hi, n_sub, body):
         pl.when(jnp.logical_and(lo <= 0, hi > 0))(lambda: body(0))
 
 
+def _for_band(ranges, has_work, n_sub, step, window):
+    """The three runs of a windowed tile's sub-blocks (``_kv_band_ranges``
+    / ``_q_band_ranges``): masked, plain, masked — none where the grid step
+    lies past the tile's last needed block."""
+    a, b, c, d = (jnp.where(has_work, x, 0) for x in ranges)
+    band = functools.partial(_apply_band_mask, window=window)
+    _for_sub_blocks(a, b, n_sub, step(band))
+    _for_sub_blocks(b, c, n_sub, step(None))
+    _for_sub_blocks(c, d, n_sub, step(band))
+
+
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
-               scale, causal, block_q, block_k, sub):
-    q0 = pl.program_id(1) * block_q
+               scale, causal, block_q, block_k, sub, window=None, nk=None):
+    iq = pl.program_id(1)
+    q0 = iq * block_q
     ik = pl.program_id(2)
-    k0 = ik * block_k
+    if window is None:
+        k0 = ik * block_k
+    else:  # the band's own grid axis: this q tile's ik-th needed KV block
+        first, last = _kv_blocks_of(iq, block_q, block_k, window, nk)
+        k0 = (first + ik) * block_k
 
     @pl.when(ik == 0)
     def _init():
@@ -374,15 +586,19 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    def step(masked):
+    def step(mask):
         def body(j):
             cols = _sub_slice(j, sub)
             # NATIVE-dtype operand feeds: a bf16 q/k/v runs the MXU at bf16
             # throughput with fp32 accumulation (preferred_element_type).
             # The scale applies to the fp32 product, exactly.
             s = _dot_f32(q_ref[0], k_ref[0, cols, :], trans_b=True) * scale
-            if masked:
-                s = _apply_causal_mask(s, q0, k0 + j * sub)
+            if mask is not None:
+                s = mask(s, q0, k0 + j * sub)
+            # (a row whose every column of its FIRST sub-block is masked — a
+            # window's edge — sums p = 1 there; its first real score then
+            # makes alpha exp(-1e30 - m) = 0 and wipes that, and every row
+            # has one: its own key)
             m_prev = m_ref[:, :1]                        # (bq, 1)
             m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
             p = jnp.exp(s - m_new)
@@ -397,10 +613,14 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
         return body
 
     n_sub = block_k // sub
-    n_full, n_need = _kv_sub_ranges(causal, q0, block_q, k0, sub, n_sub)
-    _for_sub_blocks(0, n_full, n_sub, step(False))
-    if causal:
-        _for_sub_blocks(n_full, n_need, n_sub, step(True))
+    if window is None:
+        n_full, n_need = _kv_sub_ranges(causal, q0, block_q, k0, sub, n_sub)
+        _for_sub_blocks(0, n_full, n_sub, step(None))
+        if causal:
+            _for_sub_blocks(n_full, n_need, n_sub, step(_apply_causal_mask))
+    else:
+        _for_band(_kv_band_ranges(q0, block_q, k0, sub, n_sub, window),
+                  first + ik <= last, n_sub, step, window)
 
     @pl.when(ik == pl.num_programs(2) - 1)
     def _write():
@@ -426,23 +646,40 @@ def _out_struct(shape, dtype, *refs):
     return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
-def _last_needed_kv(causal, block_q, block_k):
+def _last_needed_kv(causal, block_q, block_k, window=None, nk=None):
     """index_map clamp for a streamed KV tile: a grid step above the
     diagonal repeats the block index of the last needed one, so Pallas
-    fetches nothing for it (its arithmetic is skipped in the kernel)."""
+    fetches nothing for it (its arithmetic is skipped in the kernel). Under
+    a window grid step ``j`` is the q tile's ``j``-th needed block."""
     if not causal:
         return lambda i, j: j
+    if window is not None:
+        def banded(i, j):
+            first, last = _kv_blocks_of(i, block_q, block_k, window, nk)
+            return jnp.minimum(first + j, last)
+        return banded
     return lambda i, j: jnp.minimum(j, (i * block_q + block_q - 1) // block_k)
 
 
-def _first_needed_q(causal, block_q, block_k, nq):
+def _first_needed_q(causal, block_q, block_k, nq, window=None):
     """The same clamp for the dK/dV kernel's streamed q tile: steps before
     the first q tile that reaches this KV tile's columns fetch that one
     (the last one where none does: more columns than rows)."""
     if not causal:
         return lambda j, i: i
+    if window is not None:
+        def banded(j, i):
+            first, last = _q_blocks_of(j, block_q, block_k, window, nq)
+            return jnp.minimum(first + i, last)
+        return banded
     return lambda j, i: jnp.maximum(
         i, jnp.minimum((j * block_k) // block_q, nq - 1))
+
+
+def _kv_head(group):
+    """index_map of the K/V operand's leading (batch x head) axis: query
+    head ``b`` of ``B * H`` reads K/V head ``b // group`` of ``B * Hkv``."""
+    return (lambda b: b) if group == 1 else (lambda b: b // group)
 
 
 # The kernel-calling functions below are jitted with everything but the
@@ -451,28 +688,35 @@ def _first_needed_q(causal, block_q, block_k, nq):
 # the layers of a step, and every later job's re-trace of it, reuse that
 # (job.build_step: twelve layers' kernels cost one trace, not twelve).
 
-@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
-def _flash_forward(q, k, v, causal, tiles, scale, interpret):
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7))
+def _flash_forward(q, k, v, causal, tiles, scale, interpret, window=None):
     B, H, Sq, D = q.shape
-    Sk, Dv = k.shape[2], v.shape[3]
+    Hkv, Sk, Dv = k.shape[1], k.shape[2], v.shape[3]
     block_q, block_k, sub = tiles[:3]
     qf = q.reshape(B * H, Sq, D)
-    kf = k.reshape(B * H, Sk, D)
-    vf = v.reshape(B * H, Sk, Dv)
-    grid = (B * H, Sq // block_q, Sk // block_k)
+    kf = k.reshape(B * Hkv, Sk, D)
+    vf = v.reshape(B * Hkv, Sk, Dv)
+    nk = Sk // block_k
+    grid = (B * H, Sq // block_q,
+            nk if window is None else _band_steps("fwd", tiles, Sq, Sk,
+                                                  window))
+    band = {} if window is None else {"window": window, "nk": nk}
     kernel = functools.partial(
         _fa_kernel, scale=scale, causal=causal,
-        block_q=block_q, block_k=block_k, sub=sub,
+        block_q=block_q, block_k=block_k, sub=sub, **band,
     )
-    kv_j = _last_needed_kv(causal, block_q, block_k)
+    kv_j = _last_needed_kv(causal, block_q, block_k, **band)
+    kv_b = _kv_head(H // Hkv)
     out, lse = pl.pallas_call(
         kernel,
-        name=_KERNEL_NAMES["fwd"],
+        name=kernel_name("fwd", window),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, kv_j(i, j), 0)),
-            pl.BlockSpec((1, block_k, Dv), lambda b, i, j: (b, kv_j(i, j), 0)),
+            pl.BlockSpec((1, block_k, D),
+                         lambda b, i, j: (kv_b(b), kv_j(i, j), 0)),
+            pl.BlockSpec((1, block_k, Dv),
+                         lambda b, i, j: (kv_b(b), kv_j(i, j), 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, Dv), lambda b, i, j: (b, i, 0)),
@@ -493,14 +737,14 @@ def _flash_forward(q, k, v, causal, tiles, scale, interpret):
     return out.reshape(B, H, Sq, Dv), lse[:, :, 0].reshape(B, H, Sq)
 
 
-def _bwd_p_ds(q, k, v, do, lse, delta, row0, col0, scale, masked):
+def _bwd_p_ds(q, k, v, do, lse, delta, row0, col0, scale, mask):
     """Shared backward math for one score tile: returns (p, ds) with p the
     normalized softmax block. ``lse``/``delta`` arrive as (rows, 1) column
     tiles (lane 0 of the lane-replicated stats)."""
     # native-dtype MXU feeds with fp32 accumulation (see _fa_kernel)
     s = _dot_f32(q, k, trans_b=True) * scale
-    if masked:
-        s = _apply_causal_mask(s, row0, col0)
+    if mask is not None:
+        s = mask(s, row0, col0)
     p = jnp.exp(s - lse)                                      # normalized
     dp = _dot_f32(do, v, trans_b=True)
     ds = p * (dp - delta)
@@ -509,33 +753,48 @@ def _bwd_p_ds(q, k, v, do, lse, delta, row0, col0, scale, masked):
 
 def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                        dk_ref, dv_ref, dk_acc, dv_acc, *,
-                       scale, causal, block_q, block_k, sub):
-    k0 = pl.program_id(1) * block_k   # kv tile (this output tile)
+                       scale, causal, block_q, block_k, sub, window=None,
+                       nq=None, steps=None):
+    jk = pl.program_id(1)             # kv tile (this output tile)
+    k0 = jk * block_k
     iq = pl.program_id(2)             # q tiles stream by
-    q0 = iq * block_q
+    if steps is None:
+        q0 = iq * block_q
+    else:
+        # ``steps`` q tiles of each query head of this K/V head's group in
+        # turn; under a window the tile's own first needed q block onward
+        step_i = iq % steps
+        if window is not None:
+            first, last = _q_blocks_of(jk, block_q, block_k, window, nq)
+            step_i = first + step_i
+        q0 = step_i * block_q
 
     @pl.when(iq == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    def step(masked):
+    def step(mask):
         def body(i):
             rows = _sub_slice(i, sub)
             q, do = q_ref[0, rows, :], do_ref[0, rows, :]
             p, ds = _bwd_p_ds(
                 q, k_ref[0], v_ref[0], do,
                 lse_ref[0, rows, :1], delta_ref[0, rows, :1],
-                q0 + i * sub, k0, scale, masked)
+                q0 + i * sub, k0, scale, mask)
             dv_acc[:] += _dot_f32_trans_a(p.astype(do.dtype), do)   # (bk, d)
             dk_acc[:] += _dot_f32_trans_a(ds.astype(q.dtype), q)    # (bk, d)
         return body
 
     n_sub = block_q // sub
-    first, full_from = _q_sub_ranges(causal, q0, k0, block_k, sub, n_sub)
-    if causal:
-        _for_sub_blocks(first, full_from, n_sub, step(True))
-    _for_sub_blocks(full_from, n_sub, n_sub, step(False))
+    if window is None:
+        first, full_from = _q_sub_ranges(causal, q0, k0, block_k, sub, n_sub)
+        if causal:
+            _for_sub_blocks(first, full_from, n_sub, step(_apply_causal_mask))
+        _for_sub_blocks(full_from, n_sub, n_sub, step(None))
+    else:
+        _for_band(_q_band_ranges(q0, k0, block_k, sub, n_sub, window),
+                  step_i <= last, n_sub, step, window)
 
     @pl.when(iq == pl.num_programs(2) - 1)
     def _write():
@@ -545,31 +804,40 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       dq_ref, dq_acc, *, scale, causal, block_q, block_k,
-                      sub):
-    q0 = pl.program_id(1) * block_q   # q tile (this output tile)
+                      sub, window=None, nk=None):
+    iq = pl.program_id(1)             # q tile (this output tile)
+    q0 = iq * block_q
     ik = pl.program_id(2)             # kv tiles stream by
-    k0 = ik * block_k
+    if window is None:
+        k0 = ik * block_k
+    else:
+        first, last = _kv_blocks_of(iq, block_q, block_k, window, nk)
+        k0 = (first + ik) * block_k
 
     @pl.when(ik == 0)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    def step(masked):
+    def step(mask):
         def body(j):
             cols = _sub_slice(j, sub)
             k = k_ref[0, cols, :]
             _, ds = _bwd_p_ds(
                 q_ref[0], k, v_ref[0, cols, :], do_ref[0],
                 lse_ref[0, :, :1], delta_ref[0, :, :1],
-                q0, k0 + j * sub, scale, masked)
+                q0, k0 + j * sub, scale, mask)
             dq_acc[:] += _dot_f32(ds.astype(k.dtype), k)
         return body
 
     n_sub = block_k // sub
-    n_full, n_need = _kv_sub_ranges(causal, q0, block_q, k0, sub, n_sub)
-    _for_sub_blocks(0, n_full, n_sub, step(False))
-    if causal:
-        _for_sub_blocks(n_full, n_need, n_sub, step(True))
+    if window is None:
+        n_full, n_need = _kv_sub_ranges(causal, q0, block_q, k0, sub, n_sub)
+        _for_sub_blocks(0, n_full, n_sub, step(None))
+        if causal:
+            _for_sub_blocks(n_full, n_need, n_sub, step(_apply_causal_mask))
+    else:
+        _for_band(_kv_band_ranges(q0, block_q, k0, sub, n_sub, window),
+                  first + ik <= last, n_sub, step, window)
 
     @pl.when(ik == pl.num_programs(2) - 1)
     def _write():
@@ -597,32 +865,41 @@ def _bwd_row_stats(out, lse, do, lse_cotangent):
 
 
 def _flash_bwd_dkv(qf, kf, vf, dof, lsef, delta, causal, tiles, scale,
-                   interpret):
-    """dK/dV kernel on [B*H, S, D] (q, k) and [B*H, S, Dv] (v, dO)
-    operands: grid over kv tiles, q tiles stream by; softmax recomputed per
-    tile from the saved LSE."""
+                   interpret, window=None):
+    """dK/dV kernel on [B*H, S, D] (q), [B*Hkv, S, D] (k), [B*Hkv, S, Dv]
+    (v) and [B*H, S, Dv] (dO) operands: grid over kv tiles, q tiles stream
+    by — those of every query head of the K/V head's group, one head after
+    another, into the one accumulator; softmax recomputed per tile from the
+    saved LSE."""
     BH, Sq, D = qf.shape
-    Sk, Dv = kf.shape[1], vf.shape[2]
+    BHkv, Sk, Dv = kf.shape[0], kf.shape[1], vf.shape[2]
+    group = BH // BHkv
     block_q, block_k, sub = tiles[:3]
-    grid = (BH, Sk // block_k, Sq // block_q)
-    q_i = _first_needed_q(causal, block_q, block_k, grid[2])
-    q_spec, do_spec = (pl.BlockSpec((1, block_q, w),
-                                    lambda b, j, i: (b, q_i(j, i), 0))
-                       for w in (D, Dv))
+    nq = Sq // block_q
+    steps = nq if window is None else _band_steps("dkv", tiles, Sq, Sk,
+                                                  window)
+    grid = (BHkv, Sk // block_k, group * steps)
+    q_i = _first_needed_q(causal, block_q, block_k, nq, window)
+    if group == 1 and window is None:
+        q_row = lambda b, j, i: (b, q_i(j, i), 0)
+        band = {}
+    else:
+        q_row = lambda b, j, i: (b * group + i // steps, q_i(j, i % steps), 0)
+        band = {"window": window, "nq": nq, "steps": steps}
+    q_spec, do_spec = (pl.BlockSpec((1, block_q, w), q_row) for w in (D, Dv))
     k_spec, v_spec = (pl.BlockSpec((1, block_k, w), lambda b, j, i: (b, j, 0))
                       for w in (D, Dv))
-    row_spec = pl.BlockSpec((1, block_q, _LANES),
-                            lambda b, j, i: (b, q_i(j, i), 0))
+    row_spec = pl.BlockSpec((1, block_q, _LANES), q_row)
     return pl.pallas_call(
         functools.partial(_fa_bwd_dkv_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, sub=sub),
-        name=_KERNEL_NAMES["dkv"],
+                          block_q=block_q, block_k=block_k, sub=sub, **band),
+        name=kernel_name("dkv", window),
         grid=grid,
         in_specs=[q_spec, k_spec, v_spec, do_spec, row_spec, row_spec],
         out_specs=[k_spec, v_spec],
         out_shape=[
-            _out_struct((BH, Sk, D), kf.dtype, qf, kf, vf, dof),
-            _out_struct((BH, Sk, Dv), vf.dtype, qf, kf, vf, dof),
+            _out_struct((BHkv, Sk, D), kf.dtype, qf, kf, vf, dof),
+            _out_struct((BHkv, Sk, Dv), vf.dtype, qf, kf, vf, dof),
         ],
         scratch_shapes=[_vmem((block_k, D)), _vmem((block_k, Dv))],
         interpret=interpret,
@@ -631,24 +908,29 @@ def _flash_bwd_dkv(qf, kf, vf, dof, lsef, delta, causal, tiles, scale,
 
 
 def _flash_bwd_dq(qf, kf, vf, dof, lsef, delta, causal, tiles, scale,
-                  interpret):
-    """dQ kernel on [B*H, S, D] (q, k) and [B*H, S, Dv] (v, dO) operands:
-    grid over q tiles, kv tiles stream by."""
+                  interpret, window=None):
+    """dQ kernel on the same operands: grid over q tiles, kv tiles stream
+    by."""
     BH, Sq, D = qf.shape
-    Sk, Dv = kf.shape[1], vf.shape[2]
+    BHkv, Sk, Dv = kf.shape[0], kf.shape[1], vf.shape[2]
     block_q, block_k, sub = tiles[:3]
-    grid = (BH, Sq // block_q, Sk // block_k)
-    kv_j = _last_needed_kv(causal, block_q, block_k)
+    nk = Sk // block_k
+    grid = (BH, Sq // block_q,
+            nk if window is None else _band_steps("dq", tiles, Sq, Sk,
+                                                  window))
+    band = {} if window is None else {"window": window, "nk": nk}
+    kv_j = _last_needed_kv(causal, block_q, block_k, **band)
+    kv_b = _kv_head(BH // BHkv)
     q_spec, do_spec = (pl.BlockSpec((1, block_q, w), lambda b, i, j: (b, i, 0))
                        for w in (D, Dv))
     k_spec, v_spec = (pl.BlockSpec((1, block_k, w),
-                                   lambda b, i, j: (b, kv_j(i, j), 0))
+                                   lambda b, i, j: (kv_b(b), kv_j(i, j), 0))
                       for w in (D, Dv))
     row_spec = pl.BlockSpec((1, block_q, _LANES), lambda b, i, j: (b, i, 0))
     return pl.pallas_call(
         functools.partial(_fa_bwd_dq_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, sub=sub),
-        name=_KERNEL_NAMES["dq"],
+                          block_q=block_q, block_k=block_k, sub=sub, **band),
+        name=kernel_name("dq", window),
         grid=grid,
         in_specs=[q_spec, k_spec, v_spec, do_spec, row_spec, row_spec],
         out_specs=q_spec,
@@ -659,25 +941,25 @@ def _flash_bwd_dq(qf, kf, vf, dof, lsef, delta, causal, tiles, scale,
     )(qf, kf, vf, dof, lsef, delta)
 
 
-@functools.partial(jax.jit, static_argnums=(7, 8, 9, 10))
+@functools.partial(jax.jit, static_argnums=(7, 8, 9, 10, 11))
 def _flash_backward(q, k, v, out, lse, do, lse_cotangent, causal, plan,
-                    scale, interpret):
+                    scale, interpret, window=None):
     """Fused flash backward: dK/dV kernel (grid over kv tiles) + dQ kernel
     (grid over q tiles); softmax recomputed per tile from the saved LSE —
     the O(S) memory trade the forward made, carried into the backward."""
     B, H, Sq, D = q.shape
-    Sk, Dv = k.shape[2], v.shape[3]
+    Hkv, Sk, Dv = k.shape[1], k.shape[2], v.shape[3]
     qf = q.reshape(B * H, Sq, D)
-    kf = k.reshape(B * H, Sk, D)
-    vf = v.reshape(B * H, Sk, Dv)
+    kf = k.reshape(B * Hkv, Sk, D)
+    vf = v.reshape(B * Hkv, Sk, Dv)
     dof = do.reshape(B * H, Sq, Dv)
     lsef, delta = _bwd_row_stats(out, lse, do, lse_cotangent)
     dk, dv = _flash_bwd_dkv(qf, kf, vf, dof, lsef, delta, causal, plan.dkv,
-                            scale, interpret)
+                            scale, interpret, window)
     dq = _flash_bwd_dq(qf, kf, vf, dof, lsef, delta, causal, plan.dq,
-                       scale, interpret)
-    return (dq.reshape(B, H, Sq, D), dk.reshape(B, H, Sk, D),
-            dv.reshape(B, H, Sk, Dv))
+                       scale, interpret, window)
+    return (dq.reshape(B, H, Sq, D), dk.reshape(B, Hkv, Sk, D),
+            dv.reshape(B, Hkv, Sk, Dv))
 
 
 def _vmem(shape):
@@ -695,6 +977,7 @@ def flash_attention(
     block_k: Optional[int] = None,
     scale: Optional[float] = None,
     interpret: bool = False,
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
     """Fused attention. Forward AND backward are Pallas TPU kernels
     (``interpret=True`` runs them in the Pallas interpreter, for CPU
@@ -703,17 +986,19 @@ def flash_attention(
     softmax statistics (LSE) and the backward recomputes each softmax tile
     from them — flash attention's memory/FLOPs trade in both directions.
     The kernels tile themselves from the shape (:func:`tile_plan`);
-    ``block_q`` / ``block_k`` override it.
+    ``block_q`` / ``block_k`` override it. ``k`` / ``v`` may have fewer
+    heads than ``q`` (grouped queries) and ``window`` bounds how far back a
+    row sees (module docstring).
 
     Thin wrapper over :func:`flash_attention_lse` (the kernel always writes
     the LSE output; discarding it costs nothing, and a zero LSE cotangent
     folds to the identical backward) — ONE custom_vjp to maintain."""
     out, _ = flash_attention_lse(q, k, v, causal, block_q, block_k, scale,
-                                 interpret)
+                                 interpret, window)
     return out
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def flash_attention_lse(
     q: jnp.ndarray,
     k: jnp.ndarray,
@@ -723,6 +1008,7 @@ def flash_attention_lse(
     block_k: Optional[int] = None,
     scale: Optional[float] = None,
     interpret: bool = False,
+    window: Optional[int] = None,
 ) -> "tuple[jnp.ndarray, jnp.ndarray]":
     """:func:`flash_attention` that ALSO returns the per-row log-sum-exp
     ([B, H, Sq], fp32) — the composable form: outputs of independent KV
@@ -730,32 +1016,33 @@ def flash_attention_lse(
     Differentiable in both outputs; the LSE cotangent folds into the
     backward kernels' delta term (see ``_bwd_row_stats``)."""
     return _fa_lse_fwd(q, k, v, causal, block_q, block_k, scale,
-                       interpret)[0]
+                       interpret, window)[0]
 
 
-def _fa_lse_fwd(q, k, v, causal, block_q, block_k, scale, interpret):
+def _fa_lse_fwd(q, k, v, causal, block_q, block_k, scale, interpret,
+                window=None):
     if not (q.dtype == k.dtype == v.dtype):
         raise TypeError(
             f"flash attention feeds the MXU in the operands' dtype, so "
             f"q/k/v must share one dtype (got {q.dtype}/{k.dtype}/"
             f"{v.dtype}); cast the operands before the call"
         )
-    plan = _require_plan(q, k, v, causal, block_q, block_k)
-    _note_plan(plan, ("fwd",), q.shape[0] * q.shape[1], q.shape[2],
-               k.shape[2], q.shape[3], v.shape[3])
+    _head_group(q, k, v)
+    _check_window(window, causal)
+    plan = _require_plan(q, k, v, causal, block_q, block_k, window)
+    _note_plan(plan, ("fwd",), q, k, v, causal, window)
     out, lse = _flash_forward(q, k, v, causal, plan.fwd,
-                              _resolve_scale(q, scale), interpret)
+                              _resolve_scale(q, scale), interpret, window)
     return (out, lse), (q, k, v, out, lse)
 
 
-def _fa_lse_bwd(causal, block_q, block_k, scale, interpret, res, g):
+def _fa_lse_bwd(causal, block_q, block_k, scale, interpret, window, res, g):
     q, k, v, out, lse = res
     g_out, g_lse = g
-    plan = _require_plan(q, k, v, causal, block_q, block_k)
-    _note_plan(plan, ("dkv", "dq"), q.shape[0] * q.shape[1], q.shape[2],
-               k.shape[2], q.shape[3], v.shape[3])
+    plan = _require_plan(q, k, v, causal, block_q, block_k, window)
+    _note_plan(plan, ("dkv", "dq"), q, k, v, causal, window)
     return _flash_backward(q, k, v, out, lse, g_out, g_lse, causal, plan,
-                           _resolve_scale(q, scale), interpret)
+                           _resolve_scale(q, scale), interpret, window)
 
 
 flash_attention_lse.defvjp(_fa_lse_fwd, _fa_lse_bwd)

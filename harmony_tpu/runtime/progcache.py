@@ -472,33 +472,66 @@ def _kernel_plan_family():
         ("job", "kernel", "block_q", "block_k", "sub", "planned", "d", "dv"))
 
 
+#: (the plan gauge's label values) -> what a flash call with grouped heads
+#: or a window needs of its plan (ops/attention.py ``_note_plan``): columns
+#: of the STATUS row, not labels of the gauge
+_plan_bands: Dict[tuple, Dict[str, Any]] = {}
+
+
+def _masked_share_families():
+    from harmony_tpu.metrics.registry import get_registry
+
+    reg = get_registry()
+    return (reg.gauge(
+        "harmony_flash_masked_share",
+        "Share of the score elements a traced flash kernel computes that its "
+        "mask discards (static: from the tiles and the band), by job",
+        ("job", "kernel")),
+            reg.gauge(
+        "harmony_flash_score_elements",
+        "Score elements one call of a traced flash kernel computes under "
+        "its tiles, masked ones included, by job", ("job", "kernel")))
+
+
 def note_kernel_plan(kernel: str, block_q: int, block_k: int, sub: int,
                      grid_steps: int, planned: bool, *, d: int,
-                     dv: int) -> None:
+                     dv: int, band: Optional[Dict[str, Any]] = None) -> None:
     """``d`` / ``dv``: the two widths the tiles were planned for — a flash
-    kernel's q.k and v head widths, a grouped matmul's k and n."""
+    kernel's q.k and v head widths, a grouped matmul's k and n. ``band``:
+    what a flash call with grouped heads or a window adds to its row
+    (``window``, ``kv_heads``, ``band_grid_steps``, ``sub_blocks``,
+    ``masked_sub_blocks``, ``computed``, ``masked_share``)."""
     from harmony_tpu.tracing.span import current_job
 
-    _kernel_plan_family().labels(
-        job=current_job() or "-", kernel=kernel, block_q=str(block_q),
+    job = current_job() or "-"
+    labels = dict(
+        job=job, kernel=kernel, block_q=str(block_q),
         block_k=str(block_k), sub=str(sub), planned=str(int(planned)),
-        d=str(d), dv=str(dv),
-    ).set(grid_steps)
+        d=str(d), dv=str(dv))
+    _kernel_plan_family().labels(**labels).set(grid_steps)
+    if band is not None:
+        _plan_bands[tuple(labels.values())] = dict(band)
+        share, elements = _masked_share_families()
+        share.labels(job=job, kernel=kernel).set(band["masked_share"])
+        elements.labels(job=job, kernel=kernel).set(band["computed"])
 
 
 def kernel_plans() -> Dict[str, list]:
     """``{job: [{kernel, block_q, block_k, sub, planned, d, dv,
     grid_steps}]}`` of
-    every kernel traced in this process — STATUS ``kernel_plans``."""
+    every kernel traced in this process — STATUS ``kernel_plans``. A flash
+    kernel traced with grouped heads or a window carries ``band``'s columns
+    too (``note_kernel_plan``)."""
     out: Dict[str, list] = {}
     try:
-        for (job, kernel, bq, bk, sub, planned, d, dv), child in \
-                _kernel_plan_family().children():
+        for key, child in _kernel_plan_family().children():
+            job, kernel, bq, bk, sub, planned, d, dv = key
             out.setdefault(job, []).append({
                 "kernel": kernel, "block_q": int(bq), "block_k": int(bk),
                 "sub": int(sub), "planned": planned == "1",
                 "d": int(d), "dv": int(dv),
-                "grid_steps": int(child.value)})
+                "grid_steps": int(child.value),
+                **_plan_bands.get(tuple(key), {})})
     except Exception:
         return {}
     return out

@@ -6,6 +6,7 @@ full app and assert exact values (AddVector/AddInteger) or learning progress
 runtime.
 """
 import numpy as np
+import pytest
 
 from harmony_tpu.apps.addvector import AddIntegerTrainer, AddVectorTrainer, make_marks
 from harmony_tpu.apps.mlr import MLRTrainer, make_synthetic
@@ -192,6 +193,33 @@ class TestEpochWindow:
         w = WorkerTasklet("j2", ctx, trainer_peek,
                           TrainingDataProvider([x, y], 4), mesh8)
         assert w._epoch_window_len(0, 12) == 1
+
+
+    @pytest.mark.parametrize("raw, cap", [
+        (None, 8), ("", 8), ("1", 1), ("3", 3), ("8", 8),
+        ("64", 8),  # never above the class cap
+        ("0", 1),   # never below one epoch
+    ])
+    def test_operator_window_cap(self, mesh8, monkeypatch, raw, cap):
+        """HARMONY_EPOCH_WINDOW lowers the epochs a drain (the ledger feeds
+        once a drain) and lifts nothing; the probe horizon and the job's end
+        still bound a window under it."""
+        x, y = make_synthetic(64, num_features=8, num_classes=2)
+        trainer = MLRTrainer(num_classes=2, num_features=8,
+                             features_per_partition=4)
+        table = DenseTable(TableSpec(trainer.model_table_config()), mesh8)
+        params = TrainerParams(num_epochs=12, num_mini_batches=4,
+                               comm_probe_period=0)
+        w = WorkerTasklet("j", TrainerContext(params=params, model_table=table),
+                          trainer, TrainingDataProvider([x, y], 4), mesh8)
+        if raw is None:
+            monkeypatch.delenv("HARMONY_EPOCH_WINDOW", raising=False)
+        else:
+            monkeypatch.setenv("HARMONY_EPOCH_WINDOW", raw)
+        assert w._epoch_window_len(0, 12) == cap
+        assert w._epoch_window_len(11, 12) == 1
+        w.EPOCH_WINDOW = 2  # the instance's cap still holds over the knob
+        assert w._epoch_window_len(0, 12) == min(cap, 2)
 
 
 class TestCommProbe:

@@ -1,0 +1,481 @@
+"""SmallThinker's block on the normal path (PR 36): grouped-query heads and a
+window through the three flash kernels (block-skipped), window + rotary and
+full + NoPE blocks in one model, the router read from the block's input,
+ReLU-gated experts. ``TransformerLM`` with the architecture fields against
+the plain reference the benchmark ships
+(``perf/reference/smallthinker-21b-a3b.py``: float32, K and V repeated, an
+explicit boolean mask, a loop over the held experts, no kernel).
+
+Small, float32, seeded: d 64, 4 query heads over 2 K/V heads of 16, a window
+of 16 over 80 positions (several windows long), 1 full + 3 windowed blocks, 8
+experts of width 32, top-2. Both sides are float32 on the CPU and differ in
+the order of sums, so 2e-5 relative holds for values and gradients.
+"""
+from __future__ import annotations
+
+import dataclasses
+import functools
+import hashlib
+import json
+import os
+import re
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from harmony_tpu.models import TransformerConfig, TransformerLM  # noqa: E402
+from harmony_tpu.models import moe as moe_mod  # noqa: E402
+from harmony_tpu.ops import attention as A  # noqa: E402
+from perf.run import load_by_path  # noqa: E402
+
+REF = load_by_path("reference", "smallthinker-21b-a3b")
+RTOL = 2e-5
+APP = dict(vocab_size=96, d_model=64, n_heads=4, n_kv_heads=2, mha_head_dim=16,
+           n_layers=4, d_ff=32, max_seq=80, pos="rope", rope_theta=1.5e6,
+           ffn="swiglu", tie_embeddings=False, norm_eps=1e-6, window=16,
+           window_layers=[1, 2, 3], moe_experts=8, moe_top_k=2, moe_every=1,
+           moe_norm_topk=True, moe_route_block_input=True, moe_act="relu",
+           moe_aux_weight=0.001, optimizer="adam", step_size=1e-3, beta2=0.95)
+HELD = [None, 4]  # every expert here; experts 0..3 of the 8
+FIELDS = {f.name for f in dataclasses.fields(TransformerConfig)}
+
+
+def _config(app):
+    return TransformerConfig(**{k: v for k, v in app.items() if k in FIELDS})
+
+
+def _app(held):
+    return APP if held is None else {**APP, "moe_experts_held": held}
+
+
+def _tokens(seed=0, batch=2, app=APP):
+    return jnp.asarray(np.random.default_rng(seed).integers(
+        0, app["vocab_size"], (batch, app["max_seq"] + 1)), jnp.int32)
+
+
+def _both(held, seed=5):
+    app = _app(held)
+    lm = TransformerLM(_config(app))
+    return (lm, lm.init(jax.random.PRNGKey(seed)), REF._Static(app),
+            REF.init_params(app, seed))
+
+
+def _close(got, want, rtol=RTOL):
+    got, want = np.asarray(got), np.asarray(want)
+    scale = max(float(np.abs(want).max()), 1e-12)
+    assert float(np.abs(got - want).max()) <= rtol * scale, (
+        float(np.abs(got - want).max()) / scale)
+
+
+def _as_reference(tree):
+    """The program's parameter (or gradient) tree under the reference's
+    names."""
+    return {"embed": tree["embed"], "head": tree["head"], "ln_f": tree["ln_f"],
+            "layers": [{"g1": l["ln1"], "g2": l["ln2"], "wqkv": l["wqkv"],
+                        "wo": l["wo"], "router": l["moe"]["router"],
+                        "eg": l["moe"]["wg"], "eu": l["moe"]["wu"],
+                        "ed": l["moe"]["wd"]} for l in tree["layers"]]}
+
+
+# -- the kernels against the blockwise scan ---------------------------------
+
+#: (positions, window, query heads, K/V heads, explicit block or None: the
+#: plan's tiles) — several windows long, a window that is no multiple of the
+#: tile, a length that is no multiple of a tile, one K/V head for all, no
+#: window at all with grouped heads, and the plan's own tiles with sub-blocks
+CASES = {
+    "4-windows": (256, 64, 4, 2, 64),
+    "odd-window": (384, 100, 4, 1, 128),
+    "window-under-a-tile": (512, 50, 6, 2, 128),
+    "odd-length": (200, 48, 4, 2, None),
+    "grouped-no-window": (256, None, 4, 2, 64),
+    "planned-tiles": (1024, 300, 2, 1, None),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _kernel_case(name):
+    S, W, H, Hkv, blk = CASES[name]
+    ks = jax.random.split(jax.random.PRNGKey(S + (W or 0)), 4)
+    q = jax.random.normal(ks[0], (1, H, S, 16))
+    k = jax.random.normal(ks[1], (1, Hkv, S, 16))
+    v = jax.random.normal(ks[2], (1, Hkv, S, 16))
+    w = jax.random.normal(ks[3], (1, H, S, 16))
+
+    def both(fn):
+        out = fn(q, k, v)
+        grads = jax.grad(lambda *a: (fn(*a) * w).sum(), argnums=(0, 1, 2))(
+            q, k, v)
+        return (out, *grads)
+
+    got = both(lambda *a: A.flash_attention(
+        *a, causal=True, block_q=blk, block_k=blk, interpret=True, window=W))
+    want = both(lambda *a: A.blockwise_attention(
+        *a, causal=True, block_k=64, window=W))
+    return got, want
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv"])
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_each_kernel_equals_blockwise_with_the_same_window_and_groups(
+        name, kernel):
+    got, want = _kernel_case(name)
+    for i in {"fwd": (0,), "dq": (1,), "dkv": (2, 3)}[kernel]:
+        assert got[i].shape == want[i].shape
+        assert np.isfinite(np.asarray(got[i])).all()
+        _close(got[i], want[i], 1e-5)
+
+
+def test_blockwise_window_and_groups_equal_the_explicit_mask():
+    S, W, H, Hkv = 70, 9, 4, 2
+    ks = jax.random.split(jax.random.PRNGKey(1), 3)
+    q = jax.random.normal(ks[0], (2, H, S, 8))
+    k = jax.random.normal(ks[1], (2, Hkv, S, 8))
+    v = jax.random.normal(ks[2], (2, Hkv, S, 8))
+    ahead = np.arange(S)[:, None] - np.arange(S)[None, :]
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, jnp.repeat(k, 2, axis=1)) * 8 ** -0.5
+    s = jnp.where((ahead >= 0) & (ahead < W), s, -jnp.inf)
+    want = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1),
+                      jnp.repeat(v, 2, axis=1))
+    _close(A.blockwise_attention(q, k, v, causal=True, block_k=32, window=W),
+           want, 1e-5)
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv"])
+@pytest.mark.parametrize("S,W,tiles", [
+    (1024, 256, (128, 256, 128)), (1024, 100, (256, 512, 256)),
+    (512, None, (128, 512, 128)), (2048, 512, (512, 2048, 1024))])
+def test_band_work_counts_what_the_mask_keeps(kernel, S, W, tiles):
+    """The static count: the elements kept are the band's, the sub-blocks
+    run cover every kept element, and a window runs fewer than the
+    triangle."""
+    bq, bk, sub = tiles
+    t = A.Tiles(*((bk, bq, sub) if kernel == "dkv" else tiles))
+    work = A.band_work(kernel, t, S, S, True, W)
+    ahead = np.arange(S)[:, None] - np.arange(S)[None, :]
+    keep = (ahead >= 0) & (ahead < (W or S))
+    assert work["kept"] == int(keep.sum())
+    assert work["kept"] == (S * (S + 1) // 2 if W is None else
+                            W * (W + 1) // 2 + (S - W) * W)
+    assert work["computed"] >= work["kept"]
+    assert work["with_work"] <= work["grid_steps"]
+    if W is not None:
+        whole = A.band_work(kernel, t, S, S, True, None)
+        assert work["sub_blocks"] < whole["sub_blocks"]
+        assert work["grid_steps"] <= whole["grid_steps"]
+
+
+def test_what_no_kernel_computes_is_refused():
+    q = jnp.zeros((1, 4, 64, 8))
+    with pytest.raises(ValueError, match="must divide"):
+        A.flash_attention(q, q[:, :3], q[:, :3], causal=True, interpret=True)
+    with pytest.raises(ValueError, match="window"):
+        A.flash_attention(q, q, q, causal=False, window=8, interpret=True)
+    with pytest.raises(ValueError, match="window"):
+        A.blockwise_attention(q, q, q, causal=True, window=0)
+
+
+@pytest.mark.parametrize("fields,match", [
+    (dict(n_kv_heads=3), "must divide"),
+    (dict(window=16), "window_layers"),
+    (dict(window=16, window_layers=[0], pos="learned"), "window_layers"),
+    (dict(window=16, window_layers=[2, 1], pos="rope"), "window_layers"),
+    (dict(moe_act="gelu", moe_experts=4, moe_top_k=2, ffn="swiglu"), "moe_act"),
+    (dict(moe_route_block_input=True), "dropless"),
+    (dict(n_kv_heads=2, attn_kind="mla", kv_lora_rank=8, qk_nope_head_dim=8,
+          qk_rope_head_dim=8, v_head_dim=8, pos="rope"), "attn_kind='mha'"),
+])
+def test_fields_that_describe_no_model_are_refused(fields, match):
+    with pytest.raises(ValueError, match=match):
+        TransformerConfig(vocab_size=32, d_model=32, n_heads=4, n_layers=3,
+                          **fields)
+
+
+# -- the model against the reference ----------------------------------------
+
+@pytest.mark.parametrize("held", HELD, ids=["all-experts", "4-of-8"])
+def test_logits_equal_the_reference(held):
+    lm, params, app, ref = _both(held)
+    toks = _tokens()[:, :-1]
+    with jax.default_matmul_precision("highest"):
+        _close(lm.apply(params, toks), REF.forward(ref, toks, app)[0])
+
+
+@pytest.mark.parametrize("held", HELD, ids=["all-experts", "4-of-8"])
+def test_loss_and_every_gradient_equal_the_reference(held):
+    lm, params, app, ref = _both(held)
+    toks = _tokens()
+    with jax.default_matmul_precision("highest"):
+        loss, grads = jax.value_and_grad(lm.loss)(params, toks)
+        want, want_g = jax.value_and_grad(REF.loss_fn)(ref, toks, app)
+    _close(loss, want)
+    got = _as_reference(grads)
+    for path, w in jax.tree_util.tree_leaves_with_path(want_g):
+        g = functools.reduce(lambda t, k: t[getattr(k, "key", getattr(
+            k, "idx", None))], path, got)
+        _close(g, w, 1e-4)
+
+
+def test_two_adam_steps_equal_the_replay():
+    """The reference's replay (its own Adam) against the program's loss after
+    the same first update, formed by formula from the program's gradient."""
+    lm, params, app, _ = _both(4)
+    toks = _tokens(3)
+    data = (np.concatenate([np.asarray(toks)] * 1),)
+    with jax.default_matmul_precision("highest"):
+        want = REF.replay(dict(app), data, 2, 2, 5, logits=False)
+        loss0, g = jax.value_and_grad(lm.loss)(params, toks)
+        stepped = jax.tree.map(
+            lambda p, a: p - APP["step_size"] * a / (jnp.abs(a) + 1e-8),
+            params, g)
+        loss1 = lm.loss(stepped, toks)
+    _close(loss0, want[0])
+    _close(loss1, want[1], 1e-4)
+
+
+def test_every_ablation_is_told_apart_and_the_program_is_not():
+    """``check_logits`` as the cell runs it, at the test size in float32:
+    the program holds both limits in both ranges of positions, and each
+    broken piece of the mathematics reads above them."""
+    report = REF.check_logits({**_app(4), "dtype": "float32"},
+                              np.asarray(_tokens()[:, :-1]), 5)
+    assert report["ok"], report
+    assert set(report["detected"]) == set(REF.LOGIT_ABLATIONS)
+    assert all(report["detected"].values())
+    assert set(report["program"]) == {"before_window", "from_window"}
+    # the first window's positions cannot see a window: dropping it moves
+    # nothing there, and everything after
+    moved = report["ablations"]["no_window"]
+    assert moved["before_window"]["q90"] == 0.0
+    assert moved["from_window"]["q90"] > 1e-3
+
+
+@pytest.mark.parametrize("ablate", REF.LOGIT_ABLATIONS)
+def test_each_ablation_moves_the_reference_itself(ablate):
+    _, _, app, ref = _both(4)
+    toks = _tokens()[:, :-1]
+    with jax.default_matmul_precision("highest"):
+        want = REF.forward(ref, toks, app)[0]
+        broken = REF.forward(ref, toks, app, ablate)[0]
+    assert REF.position_errors(broken, want)["q90"] > 1e-3
+
+
+def test_eight_shares_add_up_to_the_uncut_layer():
+    """The guide's share test: a windowed block's output from each of eight
+    chips' shares (2 of 16 experts each; attention, the router and the norms
+    computed alike on every chip and counted once) adds up to what the
+    reference gives for the uncut block."""
+    app = {**APP, "moe_experts": 16, "moe_top_k": 4, "n_layers": 2,
+           "window_layers": [1]}
+    uncut = REF._Static(app)
+    ref = REF.init_params(uncut, 7)["layers"][1]
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, app["max_seq"], 64))
+    share_cfg = _config({**app, "moe_experts_held": 2})
+    lm = TransformerLM(share_cfg)
+    with jax.default_matmul_precision("highest"):
+        want = REF._block(x, ref, uncut, True, None)[0]
+        total, once = 0.0, None
+        for s in range(8):
+            # the share's two experts first; the router's columns follow
+            perm = np.roll(np.arange(16), -2 * s)
+            layer = {"ln1": ref["g1"], "ln2": ref["g2"], "wqkv": ref["wqkv"],
+                     "wo": ref["wo"],
+                     "moe": {"router": ref["router"][:, perm],
+                             "wg": ref["eg"][perm[:2]],
+                             "wu": ref["eu"][perm[:2]],
+                             "wd": ref["ed"][perm[:2]]}}
+            out = lm._block(x, layer, None, kind="swa")[0]
+            if once is None:  # what every chip computes alike: x + attention
+                idle = {**layer, "moe": {**layer["moe"], "wd": jnp.zeros_like(
+                    layer["moe"]["wd"])}}
+                once = lm._block(x, idle, None, kind="swa")[0]
+            total = total + (out - once)
+    _close(once + total, want, 1e-5)
+
+
+def test_chunked_relu_layer_and_its_written_backward_equal_autodiff():
+    """A held share of 1/8 takes the chunked layer, whose backward is
+    written by hand: ReLU's beside SiLU's."""
+    cfg = moe_mod.DroplessConfig(64, 6, 32, 16, 8, norm_topk=True, act="relu")
+    assert moe_mod.chunk_plan(1024 * 6, 8, 64) == (1536, 4)
+    params = moe_mod.init_dropless_params(jax.random.PRNGKey(0), cfg)
+    x, rx = (jax.random.normal(jax.random.PRNGKey(i), (1024, 32))
+             for i in (1, 2))
+
+    def plain(p, x, rx):
+        probs = jax.nn.softmax(rx @ p["router"], axis=-1)
+        top, chosen = jax.lax.top_k(probs, 6)
+        w = jnp.zeros_like(probs).at[jnp.arange(1024)[:, None], chosen].set(
+            top / top.sum(axis=-1, keepdims=True))
+        return sum(w[:, e:e + 1] * ((jax.nn.relu(x @ p["wg"][e])
+                                     * (x @ p["wu"][e])) @ p["wd"][e])
+                   for e in range(8))
+
+    layer = lambda p, x, rx: moe_mod.moe_ffn_dropless(p, x, cfg,
+                                                      router_x=rx)[0]
+    with jax.default_matmul_precision("highest"):
+        _close(layer(params, x, rx), plain(params, x, rx), 1e-5)
+        got = jax.grad(lambda *a: (layer(*a) ** 2).sum(), argnums=(0, 1, 2))(
+            params, x, rx)
+        want = jax.grad(lambda *a: (plain(*a) ** 2).sum(), argnums=(0, 1, 2))(
+            params, x, rx)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        _close(g, w, 1e-4)
+
+
+# -- the embedding's scale, and what it does to the routers ------------------
+
+@pytest.mark.parametrize("std", [None, 0.02, 1.0])
+def test_embed_std_scales_the_rows_in_every_initialiser(std):
+    """``embed_std`` is the embedding rows' standard deviation in ``init``,
+    ``init_numpy`` and the reference's ``init_params`` alike (0.02 where the
+    field is left out), and moves no other parameter."""
+    app = APP if std is None else {**APP, "embed_std": std}
+    want = 0.02 if std is None else std
+    lm = TransformerLM(_config(app))
+    drawn = {"init": lm.init(jax.random.PRNGKey(3)),
+             "init_numpy": lm.init_numpy(3),
+             "reference": REF.init_params(app, 3)}
+    for name, params in drawn.items():
+        got = float(np.std(np.asarray(params["embed"])))
+        assert abs(got - want) < 0.05 * want, (name, got)
+    base = TransformerLM(_config(APP)).init(jax.random.PRNGKey(3))
+    np.testing.assert_allclose(np.asarray(drawn["init"]["embed"]),
+                               np.asarray(base["embed"]) * (want / 0.02),
+                               rtol=1e-6)
+    for a, b in zip(jax.tree_util.tree_leaves(drawn["init"]["layers"]),
+                    jax.tree_util.tree_leaves(base["layers"])):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    np.testing.assert_array_equal(np.asarray(drawn["init"]["head"]),
+                                  np.asarray(base["head"]))
+
+
+@pytest.mark.parametrize("std,collapsed", [(0.02, True), (1.0, False)],
+                         ids=["gpt2-rows", "unit-rows"])
+def test_unit_rows_keep_the_routers_apart_as_initialised(std, collapsed):
+    """Why the configuration sets ``embed_std`` 1.0: with GPT-2's 0.02 a row
+    is a fiftieth of what a block's fan-in projections add to it, the keys'
+    average is the residual stream two blocks in, and a later block's router
+    sends nearly every token to the same six of its 64 experts (the most
+    loaded expert near the 64 / 6 = 10.7 means a total collapse gives it);
+    unit rows keep every block's load within a chunk's headroom of twice the
+    balanced share."""
+    app = dict(APP, vocab_size=1024, d_model=256, mha_head_dim=64, d_ff=64,
+               max_seq=512, window=128, moe_experts=64, moe_top_k=6,
+               moe_experts_held=8, embed_std=std, dtype="float32",
+               attn="blockwise")
+    lm = TransformerLM(_config(app))
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (1, 513), 0, 1024)
+    _, metrics = jax.jit(lm.loss_and_metrics)(
+        lm.init(jax.random.PRNGKey(0)), tokens)
+    load = np.asarray(metrics["moe_expert_tokens"])        # [blocks, experts]
+    worst = float((load.max(axis=1) / load.mean(axis=1)).max())
+    held = float((load[:, :8].sum(axis=1) / load.sum(axis=1)).max())
+    if collapsed:
+        assert worst > 6.0, worst
+    else:
+        assert worst < 3.0 and held < 0.25, (worst, held)
+
+
+# -- tracing ----------------------------------------------------------------
+
+def test_layer_kinds_say_swa_and_full():
+    from harmony_tpu.metrics import kda as kinds
+
+    cfg = _config(APP)
+    assert cfg.layer_kinds() == ("full", "swa", "swa", "swa")
+    kinds.note_layer_kinds("kinds-st", cfg.layer_kinds())
+    assert kinds.kinds_by_job()["kinds-st"] == {"full": 1, "swa": 3}
+
+
+def test_windowed_calls_carry_their_own_names_and_plan_columns(monkeypatch):
+    """Lowered for a TPU, a model with both kinds of block holds both sets
+    of kernel names; STATUS ``kernel_plans`` rows carry the window, the K/V
+    head count and what the band needs of the plan; the masked share is a
+    gauge."""
+    from harmony_tpu.metrics.registry import get_registry, parse_exposition
+    from harmony_tpu.runtime import progcache
+    from harmony_tpu.tracing import trace_span
+    from harmony_tpu.utils import platform
+
+    monkeypatch.setattr(platform, "trace_is_tpu", lambda: True)
+    app = {**APP, "max_seq": 2048, "window": 256, "dtype": jnp.bfloat16,
+           "moe_experts_held": 4}
+    lm = TransformerLM(_config(app))
+    params = jax.eval_shape(lambda: lm.init(jax.random.PRNGKey(0)))
+    toks = jax.ShapeDtypeStruct((1, 2049), jnp.int32)
+    with trace_span("job.build_step", job_id="plan-swa"):
+        text = jax.jit(jax.grad(lm.loss)).trace(params, toks).lower(
+            lowering_platforms=("tpu",)).as_text()
+    for kern in ("fwd", "dkv", "dq"):
+        assert A._KERNEL_NAMES[kern] in text
+        assert A._WIN_KERNEL_NAMES[kern] in text
+    rows = {r["kernel"]: r for r in progcache.kernel_plans()["plan-swa"]}
+    win, full = rows["harmony_flash_win_fwd"], rows["harmony_flash_fwd"]
+    assert (win["window"], win["kv_heads"]) == (256, 2)
+    assert (full["window"], full["kv_heads"]) == (0, 2)
+    assert win["sub_blocks"] < full["sub_blocks"]
+    assert win["band_grid_steps"] <= win["grid_steps"]
+    assert 0.0 < win["masked_share"] < 1.0
+    fams = parse_exposition(get_registry().expose())
+    shares = {labels["kernel"]: float(v) for _, labels, v in
+              fams["harmony_flash_masked_share"]["samples"]
+              if labels["job"] == "plan-swa"}
+    assert set(shares) == set(A._KERNEL_NAMES.values()) | set(
+        A._WIN_KERNEL_NAMES.values())
+    assert shares["harmony_flash_win_fwd"] == pytest.approx(
+        win["masked_share"])
+
+
+# -- the other models' programs ----------------------------------------------
+
+#: sha256 (first 16 hex digits) of each accepted LM configuration's
+#: loss-and-gradient program at its rehearse preset over 1,024 positions in
+#: bfloat16, traced for a TPU, RECORDED ON THE PARENT of this PR (commit
+#: 04416a6): the jaxpr's text (kernel bodies and index maps included), and
+#: the lowered StableHLO with each Mosaic kernel's serialized body cut out
+#: (it carries source lines). The new fields at their defaults must trace
+#: these programs; a PR that means to change one records it again here.
+PARENT_PROGRAMS = {
+    "gpt2-124m": ("39d757c8d62c52d0", "f24e521c6f1b759e"),
+    "olmoe-1b-7b": ("99ea9dafb2bff55d", "4c3e6855a920bbd3"),
+    "moonlight-16b-a3b": ("64405e693a7be5ee", "dc4d831f97c232bb"),
+    "kimi-linear-48b-a3b": ("4237db3deb745649", "f6de1660c093e2b3"),
+    # the same two with 8 of 64 experts held, top-4: the chunked expert
+    # layer and its hand-written backward (the rehearse presets hold half
+    # their experts and take the full-length pass)
+    "moonlight-16b-a3b+chunked": ("8ac21d0754a963b9", "9235a672c903a8a1"),
+    "kimi-linear-48b-a3b+chunked": ("7c4a94c76275e189", "a212a063412dcaa1"),
+}
+CHUNKED = {"moe_experts": 64, "moe_top_k": 4, "moe_experts_held": 8}
+
+
+@pytest.mark.parametrize("config", sorted(PARENT_PROGRAMS))
+def test_other_models_step_programs_are_the_parents(monkeypatch, config):
+    from harmony_tpu.utils import platform
+
+    monkeypatch.setattr(platform, "trace_is_tpu", lambda: True)
+    name, _, chunked = config.partition("+")
+    with open(os.path.join(ROOT, "perf", "configs", name + ".json")) as f:
+        conf = json.load(f)
+    app = {**conf["job"]["app_params"], **conf["rehearse"]["app_params"],
+           "max_seq": 1024, "dtype": jnp.bfloat16,
+           **(CHUNKED if chunked else {})}
+    lm = TransformerLM(_config(app))
+    params = jax.eval_shape(lambda: lm.init(jax.random.PRNGKey(0)))
+    toks = jax.ShapeDtypeStruct((2, 1025), jnp.int32)
+    fn = jax.value_and_grad(lm.loss_and_metrics, has_aux=True)
+    jaxpr = str(jax.make_jaxpr(fn)(params, toks))
+    text = jax.jit(fn).trace(params, toks).lower(
+        lowering_platforms=("tpu",)).as_text()
+    text = re.sub(r'\\22body\\22: \\22[^\\]*\\22', "BODY", text)
+    got = tuple(hashlib.sha256(t.encode()).hexdigest()[:16]
+                for t in (jaxpr, text))
+    assert got == PARENT_PROGRAMS[config]
