@@ -18,8 +18,10 @@ slots are the first ``sum(tokens[:H])`` entries of the sorted order, and the
 layer walks them in chunks of a static capacity ``C`` (:func:`chunk_plan`:
 twice the balanced held share, in 512-row tiles): chunk ``i`` gathers the
 rows of sorted slots ``[i C, (i + 1) C)``, runs the three grouped matmuls
-with the held runs clipped to that interval, and scatter-adds each row times
-its gate into its token's row of a float32 ``[T, d]`` sum. The loop runs
+with the held runs clipped to that interval, and adds each held row times
+its gate onto its token's row of a float32 ``[T, d]`` sum (PR 37: folded in
+VMEM a token tile at a time, ops/sum_rows.py, where XLA's scatter-add took
+the rows one after another). The loop runs
 while a chunk starts inside the held slots — one chunk while the router
 stays inside the headroom, all ``ceil(T k / C)`` if every slot routes here:
 nothing is dropped, there is no capacity factor. **There is ONE body**: a
@@ -294,9 +296,11 @@ def _route(params, x, cfg: DroplessConfig, seqs: int):
 #: a chunk holds ``_HEADROOM`` times the slots a balanced router sends to the
 #: held experts, in whole row tiles of the grouped matmul; the slots are
 #: chunked where a chunk is at most ``1 / _LEAST_CUT`` of them (on the chip a
-#: chunk of a quarter took 30% off the layer, a chunk of half ADDED 4% — the
-#: row scatter-adds and the loop's buffers cost more than half the slots'
-#: gathers and masks: PERF.md, PR 35)
+#: chunk of a quarter took 30% off the layer, a chunk of half ADDED 4% while
+#: the rows were summed by XLA's scatter-add: PERF.md, PR 35. With the row-sum
+#: kernel a chunk of half takes 15% OFF OLMoE's layer, 20.6 -> 17.5 ms: PR 37
+#: read it and left the cut where it was, for an issue with that cell's own
+#: measurement to move)
 _HEADROOM, _ROW_TILE, _LEAST_CUT = 2, 512, 4
 
 
@@ -324,22 +328,40 @@ def _gated(g, u, act="silu"):
     return _ACTS[act](g) * u
 
 
-def _chunk(C: int, i, order, offsets, k: int):
+def _chunk(C: int, i, order, offsets, starts, k: int):
     """Chunk ``i`` of the sorted slots: ``(lo, slots [C], tokens [C], sizes
-    [H])`` — the held experts' runs clipped to ``[i C, (i + 1) C)``."""
+    [H], bounds)`` — the held experts' runs, and their token tiles' ranges
+    (``starts``: :func:`_tile_starts`), clipped to ``[i C, (i + 1) C)``."""
     lo = i * C
     slots = lax.dynamic_slice(order, (lo,), (C,))
     sizes = jnp.diff(jnp.clip(offsets - lo, 0, C))
-    return lo, slots, slots // k, sizes
+    return lo, slots, slots // k, sizes, jnp.clip(starts - lo, 0, C)
 
 
-def _run_chunks(C: int, act: str, save: bool, x, weight, order, offsets, wg,
-                wu, wd):
+def _tile_starts(slot_expert, offsets, k: int, tile: int):
+    """``[T / tile + 1, H]`` int32: where, in the sorted slots, each held
+    run's rows of each tile of ``tile`` consecutive tokens start (the last
+    row: where the run ends). The sort by expert is stable and a token picks
+    an expert once, so inside a run the tokens strictly ascend and a tile's
+    rows are one contiguous range — what :func:`ops.sum_rows.sum_rows` folds
+    (a chunk clips these to its interval). A compare-and-sum over the
+    routing, not a scatter, with the slots on the lanes (experts there
+    would fill 8 lanes of 128)."""
+    H = offsets.shape[0] - 1
+    held = slot_expert[None, :] == jnp.arange(H)[:, None]        # [H, T k]
+    count = jnp.sum(held.reshape(H, -1, tile * k), axis=2, dtype=jnp.int32)
+    return (offsets[:H, None] + jnp.concatenate(
+        [jnp.zeros((H, 1), jnp.int32), jnp.cumsum(count, axis=1)], axis=1)).T
+
+
+def _run_chunks(C: int, act: str, save: bool, x, weight, order, offsets,
+                starts, wg, wu, wd):
     """``(out [T, d] f32, (g, u))``: the routed sum over the held slots,
     chunk by chunk while a chunk starts inside them; with ``save`` the
     gate and up products of every chunk that ran, stacked by sorted row
     (rows of a chunk that did not run stay 0)."""
     from harmony_tpu.ops.grouped_matmul import _gmm, _note_plans
+    from harmony_tpu.ops.sum_rows import note_plan, sum_rows
     from harmony_tpu.utils.platform import trace_is_tpu
 
     (T, d), k, dtype = x.shape, weight.shape[1], x.dtype
@@ -347,13 +369,15 @@ def _run_chunks(C: int, act: str, save: bool, x, weight, order, offsets, wg,
     interpret = not trace_is_tpu()
     _note_plans(("fwd",), C, d, f, H, dtype)
     _note_plans(("fwd",), C, f, d, H, dtype)
+    note_plan(T, C, d, H, dtype)
     n_held = offsets[H]
     flat_w = weight.reshape(-1)
 
     def body(carry):
         i, acc, saved = carry
         with step_scope("moe.dispatch"):
-            lo, slots, tok, sizes = _chunk(C, i, order, offsets, k)
+            lo, slots, tok, sizes, bounds = _chunk(C, i, order, offsets,
+                                                   starts, k)
             rows = x[tok]                                        # [C, d]
         with step_scope("moe.experts"):
             g = _gmm(rows, wg, sizes, False, interpret)
@@ -363,11 +387,12 @@ def _run_chunks(C: int, act: str, save: bool, x, weight, order, offsets, wg,
                 saved = tuple(lax.dynamic_update_slice(s, v, (lo, 0))
                               for s, v in zip(saved, (g, u)))
         with step_scope("moe.combine"):
-            # XLA's scatter-add: of the forms tried on the chip (segment
-            # sums, a sort by token, a gather by the inverse permutation, a
-            # one-hot matmul) none summed C rows into T faster (PERF.md)
-            acc = acc.at[tok].add(y.astype(jnp.float32)
-                                  * flat_w[slots][:, None])
+            # each held row times its gate onto its token's row, folded in
+            # VMEM a token tile at a time: XLA's scatter-add takes the C
+            # rows one after another, and no XLA form of the sum tried on
+            # the chip beat it (PERF.md, PR 35 / PR 37)
+            acc = sum_rows(acc, y, tok, bounds, flat_w[slots], fresh=i == 0,
+                           interpret=interpret)
         return i + 1, acc, saved
 
     saved = ((jnp.zeros((order.shape[0], f), dtype),) * 2) if save else ()
@@ -378,38 +403,43 @@ def _run_chunks(C: int, act: str, save: bool, x, weight, order, offsets, wg,
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _experts_chunked(C, act, x, weight, order, offsets, wg, wu, wd):
+def _experts_chunked(C, act, x, weight, order, offsets, starts, wg, wu, wd):
     """``out [T, d]`` float32: per token, ``weight[t, j]`` times the held
     expert's gated MLP (``act``: :func:`_gated`) of ``x[t]``, summed over
     the token's slots
     routed to a held expert. ``order [chunks * C]`` are the slots sorted by
     expert (held runs first, padding after), ``offsets [H + 1]`` the held
-    runs' bounds in it. Only chunks that start inside the held slots run:
+    runs' bounds in it, ``starts`` their token tiles' (:func:`_tile_starts`,
+    at the tile :func:`ops.sum_rows.tile_plan` gives). Only chunks that
+    start inside the held slots run:
     ONE body, a dynamic trip count, so idle chunks cost nothing and a second
     capacity is never traced. The backward is written out, chunk by chunk
     the same way: autodiff cannot reverse a loop of unknown length, and the
     forward kernels must not run again in it (the benchmark pairs three
     forward, three dx and three dw calls a layer and step)."""
-    return _run_chunks(C, act, False, x, weight, order, offsets, wg, wu,
-                       wd)[0]
+    return _run_chunks(C, act, False, x, weight, order, offsets, starts, wg,
+                       wu, wd)[0]
 
 
-def _experts_chunked_fwd(C, act, x, weight, order, offsets, wg, wu, wd):
-    out, saved = _run_chunks(C, act, True, x, weight, order, offsets, wg, wu,
-                             wd)
-    return out, (x, weight, order, offsets, wg, wu, wd, saved)
+def _experts_chunked_fwd(C, act, x, weight, order, offsets, starts, wg, wu,
+                         wd):
+    out, saved = _run_chunks(C, act, True, x, weight, order, offsets, starts,
+                             wg, wu, wd)
+    return out, (x, weight, order, offsets, starts, wg, wu, wd, saved)
 
 
 def _experts_chunked_bwd(C, act, res, d_out):
     from harmony_tpu.ops.grouped_matmul import _gmm, _note_plans, _tgmm
+    from harmony_tpu.ops.sum_rows import note_plan, sum_rows
     from harmony_tpu.utils.platform import trace_is_tpu
 
-    x, weight, order, offsets, wg, wu, wd, (g_all, u_all) = res
+    x, weight, order, offsets, starts, wg, wu, wd, (g_all, u_all) = res
     (T, d), k, dtype = x.shape, weight.shape[1], x.dtype
     H, _, f = wg.shape
     interpret = not trace_is_tpu()
     _note_plans(("dx", "dw"), C, d, f, H, dtype)
     _note_plans(("dx", "dw"), C, f, d, H, dtype)
+    note_plan(T, C, d, H, dtype)
     n_held = offsets[H]
     flat_w = weight.reshape(-1)
     f32 = jnp.float32
@@ -417,7 +447,8 @@ def _experts_chunked_bwd(C, act, res, d_out):
     def body(carry):
         i, d_x, d_w, d_wg, d_wu, d_wd = carry
         with step_scope("moe.dispatch"):
-            lo, slots, tok, sizes = _chunk(C, i, order, offsets, k)
+            lo, slots, tok, sizes, bounds = _chunk(C, i, order, offsets,
+                                                   starts, k)
             rows = x[tok]
         with step_scope("moe.combine"):
             w = flat_w[slots][:, None]
@@ -448,14 +479,15 @@ def _experts_chunked_bwd(C, act, res, d_out):
         with step_scope("moe.combine"):
             d_w = d_w.at[slots].add(jnp.sum(h.astype(f32) * d_hu, axis=-1))
         with step_scope("moe.dispatch"):
-            d_x = d_x.at[tok].add(d_rows.astype(f32))
+            d_x = sum_rows(d_x, d_rows, tok, bounds, fresh=i == 0,
+                           interpret=interpret)
         return i + 1, d_x, d_w, d_wg, d_wu, d_wd
 
     _, d_x, d_w, d_wg, d_wu, d_wd = lax.while_loop(
         lambda carry: carry[0] * C < n_held, body,
         (jnp.int32(0), jnp.zeros((T, d), f32), jnp.zeros((T * k,), f32),
          jnp.zeros_like(wg), jnp.zeros_like(wu), jnp.zeros_like(wd)))
-    return (d_x.astype(dtype), d_w.reshape(weight.shape), None, None,
+    return (d_x.astype(dtype), d_w.reshape(weight.shape), None, None, None,
             d_wg, d_wu, d_wd)
 
 
@@ -511,6 +543,8 @@ def moe_ffn_dropless(params: Dict[str, jnp.ndarray], x: jnp.ndarray,
     the configuration has one. ``stats``: :func:`_route`'s. The router
     reads ``router_x [T, d]`` where given (a block that routes on its
     input), else the rows it dispatches."""
+    from harmony_tpu.ops.sum_rows import tile_plan
+
     T, d = x.shape
     k, H = cfg.top_k, cfg.experts_held
     gate, expert, slot_expert, tokens, stats = _route(
@@ -530,9 +564,12 @@ def moe_ffn_dropless(params: Dict[str, jnp.ndarray], x: jnp.ndarray,
             order = jnp.pad(order, (0, chunks * C - T * k))
             offsets = jnp.concatenate([jnp.zeros(1, jnp.int32),
                                        jnp.cumsum(tokens[:H])])
+            starts = _tile_starts(slot_expert, offsets, k,
+                                  tile_plan(T, d, dtype))
         with step_scope("moe.experts"):
             weights = [params[w].astype(dtype) for w in ("wg", "wu", "wd")]
-        out = _experts_chunked(C, cfg.act, x, gate, order, offsets, *weights)
+        out = _experts_chunked(C, cfg.act, x, gate, order, offsets, starts,
+                               *weights)
         with step_scope("moe.combine"):
             out = out.astype(dtype)
     if cfg.shared_experts:  # plain matmuls on every token, beside the sum

@@ -109,13 +109,21 @@ def test_grouped_matmul_kernels_compile_for_v5e(one_chip, m, k, n, groups,
     (16384, dict(num_experts=64, top_k=6, d_model=2048, d_ff=1408,
                  experts_held=8, score="sigmoid", norm_topk=True,
                  routed_scale=2.446, shared_experts=2), False),
-], ids=["kimi-linear", "moonlight"])
+    # smallthinker-21b-a3b.solo: the same cut at 2,560 lanes, remat
+    (16384, dict(num_experts=64, top_k=6, d_model=2560, d_ff=768,
+                 experts_held=8, norm_topk=True, act="relu"), True),
+], ids=["kimi-linear", "moonlight", "smallthinker"])
 def test_chunked_expert_layer_compiles_for_v5e(one_chip, monkeypatch, tokens,
                                                cfg, checkpoint):
     """The expert layer's gradient at the cells' shapes, chunked (the plan
     the shapes give) against full-length (a row tile no shape reaches): as
-    many kernel calls — one body a layer and pass, no second capacity — and
-    no more temporary memory."""
+    many grouped-matmul calls — one body a layer and pass, no second
+    capacity — and no more temporary memory. The chunked form's row sums are
+    ``harmony_sum_rows`` (one call a pass, at the tile its plan gives for
+    2,048 / 2,304 / 2,560 lanes under its own VMEM limit), and no XLA
+    scatter of ``[C, d]`` rows is left beside them."""
+    import re
+
     from harmony_tpu.models import moe
     from harmony_tpu.utils import platform
 
@@ -139,9 +147,19 @@ def test_chunked_expert_layer_compiles_for_v5e(one_chip, monkeypatch, tokens,
         compiled = jax.jit(jax.value_and_grad(
             jax.checkpoint(loss) if checkpoint else loss, argnums=(0, 1))
         ).lower(jax.tree_util.tree_map(sd, params), x).compile()
-        got[form] = (compiled.as_text().count("custom_call_target=\"tpu_custom_call\""),
+        got[form] = (compiled.as_text().count(
+            "custom_call_target=\"tpu_custom_call\""),
                      compiled.memory_analysis().temp_size_in_bytes)
-    assert got["chunked"][0] == got["full"][0] == (12 if checkpoint else 9)
+        if form == "chunked":
+            text = compiled.as_text()
+    sums = 3 if checkpoint else 2
+    assert got["full"][0] == (12 if checkpoint else 9)
+    assert got["chunked"][0] == got["full"][0] + sums
+    assert sum("harmony_sum_rows" in line for line in text.splitlines()
+               if "custom_call_target=\"tpu_custom_call\"" in line) == sums
+    wide = [line.strip()[:160] for line in text.splitlines()
+            if re.search(rf"= \w+\[\d+,{cfg.d_model}\]\S* scatter\(", line)]
+    assert not wide, wide
     assert got["chunked"][1] <= got["full"][1], got
 
 

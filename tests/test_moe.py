@@ -385,8 +385,12 @@ def _compare(cfg, params, x, checkpoint=False):
 
 @pytest.fixture
 def small_tiles(monkeypatch):
-    """Row tiles of 8, so that shapes a CPU test can afford are chunked."""
+    """Row tiles of 8, so that shapes a CPU test can afford are chunked —
+    and token tiles of 8, so that their row sums cross tiles."""
+    from harmony_tpu.ops import sum_rows
+
     monkeypatch.setattr(moe_mod, "_ROW_TILE", 8)
+    monkeypatch.setattr(sum_rows, "_TB", (8,))
 
 
 @pytest.mark.parametrize("checkpoint", [False, True],
@@ -478,6 +482,19 @@ def _gmm_call_sites(text):
             len(re.findall(r"call @_tgmm(?:_\d+)?\(", text)))
 
 
+def _row_sums(text, d):
+    """``(call sites of the row-sum kernel, XLA scatters whose updates are
+    rows of ``d`` lanes)`` in a lowered program's text."""
+    import re
+
+    updates = [m.group(1) for m in re.finditer(
+        r'"stablehlo\.scatter"\(.*?\}\) : \([^)]*, (tensor<[^>]*>)\) -> ',
+        text, re.S)]
+    assert updates  # the pattern still reads this jax's scatters
+    return (len(re.findall(r"call @sum_rows(?:_\d+)?\(", text)),
+            [u for u in updates if re.fullmatch(rf"tensor<\d+x{d}x\w+>", u)])
+
+
 @pytest.mark.parametrize("checkpoint", [False, True],
                          ids=["plain", "checkpoint"])
 def test_one_traced_body_a_layer_and_pass(monkeypatch, checkpoint):
@@ -486,7 +503,11 @@ def test_one_traced_body_a_layer_and_pass(monkeypatch, checkpoint):
     often as the full-length formulation does (3 forward + 3 dx + 3 dw, + 3
     forward under ``checkpoint``) and holds no conditional at all — a second
     capacity, or a full-length fallback behind a ``cond``, would be a second
-    copy of the body to trace, differentiate and lower."""
+    copy of the body to trace, differentiate and lower. The rows return to
+    their tokens through ``harmony_sum_rows`` (ops/sum_rows.py, PR 37): one
+    call site a pass, the same kernel at both, and no XLA scatter of
+    ``[C, d]`` rows beside it (the scalar ``d_w.at[slots].add`` stays
+    XLA's)."""
     from harmony_tpu.utils import platform
 
     monkeypatch.setattr(platform, "trace_is_tpu", lambda: True)
@@ -504,7 +525,14 @@ def test_one_traced_body_a_layer_and_pass(monkeypatch, checkpoint):
         if layer is moe_ffn_dropless:
             assert "stablehlo.case" not in text and "stablehlo.if" not in text
             assert text.count("stablehlo.while") == (3 if checkpoint else 2)
+            assert _row_sums(text, 128) == (3 if checkpoint else 2, [])
     assert sites[0] == sites[1] == ((9, 3) if checkpoint else (6, 3))
+    # the guard sees a row scatter where there is one: the parent's line
+    rows = jax.ShapeDtypeStruct((1024, 128), jnp.float32)
+    parent = jax.jit(lambda acc, tok, y: acc.at[tok].add(y)).trace(
+        rows, jax.ShapeDtypeStruct((1024,), jnp.int32), rows).lower(
+            lowering_platforms=("tpu",)).as_text()
+    assert _row_sums(parent, 128) == (0, ["tensor<1024x128xf32>"])
 
 
 def test_the_chunk_plan_reaches_kernel_plans():
@@ -529,3 +557,31 @@ def test_the_chunk_plan_reaches_kernel_plans():
         "harmony_gmm_fwd", "harmony_gmm_dx", "harmony_gmm_dw"}
     # tiles planned for the chunk's rows: 1024 / 512 row tiles + 8 groups - 1
     assert {r["grid_steps"] for r in gmm} == {2 + 8 - 1}
+
+
+def test_the_row_sum_plan_reaches_kernel_plans():
+    """... and the row-sum kernel's: the token tile its plan gives, the
+    chunk's rows, the runs, and the tiles a call walks — noted by the
+    forward and by the hand-written backward alike (one row: one plan)."""
+    from harmony_tpu.ops.sum_rows import KERNEL_NAME, tile_plan
+    from harmony_tpu.runtime import progcache
+    from harmony_tpu.tracing import trace_span
+
+    cfg = DroplessConfig(64, 4, 128, 64, 8)
+    params = jax.eval_shape(
+        lambda: init_dropless_params(jax.random.PRNGKey(0), cfg))
+    x = jax.ShapeDtypeStruct((1024, 128), jnp.bfloat16)
+    with trace_span("job.build_step", job_id="plan-row-sums"):
+        jax.jit(jax.grad(lambda p, x: _loss(moe_ffn_dropless, cfg)(p, x)[0])
+                ).trace(params, x)
+    plan, = [r for r in progcache.kernel_plans()["plan-row-sums"]
+             if r["kernel"] == KERNEL_NAME]
+    assert KERNEL_NAME == "harmony_sum_rows"  # no grouped-matmul family's
+    assert tile_plan(1024, 128, jnp.bfloat16) == 256
+    assert (plan["block_q"], plan["block_k"], plan["sub"]) == (256, 1024, 8)
+    assert (plan["grid_steps"], plan["d"], plan["planned"]) == (4, 128, True)
+    # the cells' plans: 256 tokens a tile under the kernel's own VMEM limit
+    for tokens, d in ((8192, 2304), (16384, 2048), (16384, 2560)):
+        assert tile_plan(tokens, d, jnp.bfloat16) == 256
+    assert tile_plan(16384, 8192, jnp.bfloat16) == 64  # wider rows: smaller
+    assert tile_plan(60, 128, jnp.float32) == 60       # no divisor: one tile

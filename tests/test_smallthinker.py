@@ -450,9 +450,13 @@ PARENT_PROGRAMS = {
     "kimi-linear-48b-a3b": ("4237db3deb745649", "f6de1660c093e2b3"),
     # the same two with 8 of 64 experts held, top-4: the chunked expert
     # layer and its hand-written backward (the rehearse presets hold half
-    # their experts and take the full-length pass)
-    "moonlight-16b-a3b+chunked": ("8ac21d0754a963b9", "9235a672c903a8a1"),
-    "kimi-linear-48b-a3b+chunked": ("7c4a94c76275e189", "a212a063412dcaa1"),
+    # their experts and take the full-length pass). RECORDED AGAIN BY PR 37,
+    # which meant to change these two and no other: the layer's row sums
+    # are the kernel of ops/sum_rows.py where XLA's scatter-adds stood (the
+    # parent's: 8ac21d0754a963b9 / 9235a672c903a8a1 and 7c4a94c76275e189 /
+    # a212a063412dcaa1)
+    "moonlight-16b-a3b+chunked": ("a4926d2815adc9a1", "b95e5090d1b8c013"),
+    "kimi-linear-48b-a3b+chunked": ("5e16810afaf161cd", "a87befccf5a25f54"),
 }
 CHUNKED = {"moe_experts": 64, "moe_top_k": 4, "moe_experts_held": 8}
 

@@ -186,6 +186,100 @@ def test_scatter_add_rows_default_tile_takes_a_whole_batch():
         got, _sequential_scatter_add(table, idx, deltas))
 
 
+# -- ops/sum_rows.py: a chunk's rows summed into token rows -------------------
+
+def _picks(T, k, choose):
+    """``[T, k]`` expert choices: ``choose(t)`` gives token ``t``'s."""
+    return np.array([choose(t) for t in range(T)], np.int32).reshape(T, k)
+
+
+_AWAY = (6, 7)  # experts nobody holds (H <= 4 below)
+_SUM_ROWS_CASES = {
+    # name: (T, k, H, C, chunk, d, dtype, gated, choices(t))
+    "a_token_in_several_runs": (
+        32, 3, 4, 48, 0, 128, jnp.float32, True, lambda t: (0, 2, 3)),
+    "an_empty_run": (
+        32, 2, 4, 32, 0, 128, jnp.float32, True,
+        lambda t: (0, 2) if t % 3 else (3, 6)),
+    "a_run_cut_by_the_chunk_on_both_sides": (  # run 1 is slots 24 .. 71
+        48, 2, 2, 16, 2, 128, jnp.float32, True,
+        lambda t: (0, 1) if t < 24 else (1, 6)),
+    "a_run_cut_by_the_chunks_end": (
+        48, 2, 2, 32, 0, 128, jnp.bfloat16, True,
+        lambda t: (0, 1) if t < 24 else (1, 6)),
+    "no_held_row": (32, 2, 4, 32, 0, 128, jnp.float32, True, lambda t: _AWAY),
+    "every_row_held": (
+        32, 2, 2, 32, 1, 128, jnp.bfloat16, True, lambda t: (0, 1)),
+    "tokens_on_both_edges_of_a_tile": (
+        32, 2, 4, 32, 0, 128, jnp.float32, True,
+        lambda t: (1, 3) if t in (0, 7, 8, 15, 16, 31) else _AWAY),
+    "bfloat16_rows_no_gate": (
+        40, 2, 4, 48, 0, 256, jnp.bfloat16, False,
+        lambda t: ((t * 7) % 4, 4 + t % 3)),
+    "float32_rows_no_gate": (
+        40, 2, 4, 40, 1, 256, jnp.float32, False,
+        lambda t: (t % 2, 2 + (t // 2) % 2)),
+    "rows_not_a_multiple_of_a_sublane_group": (
+        24, 3, 4, 24, 0, 128, jnp.bfloat16, True,
+        lambda t: (t % 4, (t + 1) % 4, 5)),
+    "moonlight_lanes": (16, 2, 4, 32, 0, 2048, jnp.bfloat16, False,
+                        lambda t: (t % 4, 4 + t % 2)),
+    "kimi_linear_lanes": (16, 2, 4, 32, 0, 2304, jnp.bfloat16, True,
+                          lambda t: (t % 3, 3 + t % 4)),
+    "smallthinker_lanes": (16, 2, 4, 32, 0, 2560, jnp.bfloat16, True,
+                           lambda t: ((t // 2) % 4, 5)),
+}
+
+
+@pytest.mark.parametrize("case", list(_SUM_ROWS_CASES))
+def test_sum_rows_kernel_is_the_serial_sum_in_chunk_order(case, monkeypatch):
+    """One chunk of the expert layer's sorted slots, cut as the layer cuts
+    it (``models/moe.py`` ``_tile_starts`` / ``_chunk``), folded by the
+    kernel in token tiles of 8: equal BIT FOR BIT to a serial float32 loop
+    over the chunk's held rows in order — each term rounded before it is
+    added — and to XLA's scatter-add of the same terms."""
+    from harmony_tpu.models import moe
+    from harmony_tpu.ops import sum_rows as sr
+
+    monkeypatch.setattr(sr, "_TB", (8,))
+    T, k, H, C, chunk, d, dtype, gated, choose = _SUM_ROWS_CASES[case]
+    expert = _picks(T, k, choose)
+    rng = np.random.default_rng(sum(map(ord, case)))
+    order = np.argsort(expert.reshape(-1), kind="stable")
+    order = jnp.asarray(np.pad(order, (0, -order.size % C)), jnp.int32)
+    offsets = jnp.asarray(np.concatenate(
+        [[0], np.cumsum(np.bincount(expert.reshape(-1), minlength=8)[:H])]),
+        jnp.int32)
+    assert sr.tile_plan(T, d, dtype) == 8
+    starts = moe._tile_starts(jnp.asarray(expert.reshape(-1)), offsets, k, 8)
+    _, _, tok, sizes, bounds = moe._chunk(C, chunk, order, offsets, starts, k)
+    held = int(sizes.sum())
+    src = rng.standard_normal((C, d)).astype(np.float32)
+    src[held:] = 0.0  # the grouped matmul's contract; nobody moves them
+    src = jnp.asarray(src, dtype)
+    gate = jnp.asarray(rng.random(C), jnp.float32) if gated else None
+    acc = rng.standard_normal((T, d)).astype(np.float32)
+
+    got = np.asarray(sr.sum_rows(jnp.asarray(acc), src, tok, bounds, gate,
+                                 interpret=True))
+    want, rows = acc.copy(), np.asarray(src.astype(jnp.float32))
+    for i in range(held):
+        term = rows[i] * np.asarray(gate)[i] if gated else rows[i]
+        want[int(tok[i])] += term
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(got, np.asarray(sr.sum_rows_ref(
+        jnp.asarray(acc), src, tok, held, gate)))
+    # a sum the caller calls fresh is not read: zeros stand in for it
+    fresh = np.asarray(sr.sum_rows(jnp.asarray(acc), src, tok, bounds, gate,
+                                   True, interpret=True))
+    np.testing.assert_array_equal(fresh, np.asarray(sr.sum_rows(
+        jnp.zeros_like(acc), src, tok, bounds, gate, interpret=True)))
+    if case == "no_held_row":
+        assert held == 0 and not fresh.any()
+    if case == "every_row_held":
+        assert held == C
+
+
 def test_scatter_kernel_refuses_shapes_it_cannot_tile():
     ids = jnp.zeros((4,), jnp.int32)
     for table, deltas in (
