@@ -359,6 +359,9 @@ class WorkerTasklet:
         self._phase_ctl = {"grant_wait": 0.0, "probe": 0.0,
                            "bookkeeping": 0.0}
         self._budget_mark: Optional[float] = None
+        # drained step VECTORS (tokens per expert ...) waiting for the
+        # trainer's counters: see _observe_vector_backlog
+        self._vector_backlog: List[Dict[str, np.ndarray]] = []
         # the step program whose first dispatch (trace + lower + compile
         # or cache load, all lazy) has been made: job.build_step covers
         # the first dispatch of every NEW program
@@ -1698,6 +1701,7 @@ class WorkerTasklet:
                                    budget_wall=wall, budget_ctl=ctl)
             first_window.close()
             epoch += 1
+        self._observe_vector_backlog()  # the last window's
         self.trainer.cleanup(ctx)
         return {
             "job_id": self.job_id,
@@ -1758,6 +1762,7 @@ class WorkerTasklet:
             # at a time across tenants, by design; the gather/shuffle work
             # overlaps regardless)
             self._spawn_next_pipeline(epoch + 1)
+        self._observe_vector_backlog()  # the device has this epoch's steps
         last_metrics: Dict[str, float] = {}
         if pending:
             with trace_span("dolphin.metric_drain", job_id=self.job_id,
@@ -1983,8 +1988,13 @@ class WorkerTasklet:
                         for dt, ks in groups.items()
                     }
         # the copies wait for every step still in flight: this is where
-        # the host stands while the device finishes the window
+        # the host stands while the device finishes the window. Every
+        # group's transfer is asked for NOW, so each starts as its data
+        # lands: asked for one after another once the window is done, three
+        # groups were three round trips of device idle (4.5 ms a drain)
         with trace_span("drain.d2h"):
+            for _, arr in (combined or {}).values():
+                arr.copy_to_host_async()
             if combined is not None:
                 host = {}
                 for ks, arr in combined.values():
@@ -2022,6 +2032,7 @@ class WorkerTasklet:
                 self._spawn_next_pipeline(first_epoch + j + 1)
                 if j + 1 < k:
                     self.trainer.on_epoch_finished(self.ctx, first_epoch + j)
+        self._observe_vector_backlog()  # the device has this window's steps
         all_pending = [m for p, _, _, _, _ in per_epoch for m in p]
         drain_t = 0.0
         host: Dict[str, np.ndarray] = {}
@@ -2094,6 +2105,19 @@ class WorkerTasklet:
         v, self._phase_dispatch_acc = self._phase_dispatch_acc, 0.0
         return v
 
+    def _observe_vector_backlog(self) -> None:
+        """Hand the drained steps' vectors to the trainer's counters. They
+        decide nothing, and a 512-expert router's grid is a millisecond of
+        counter updates an epoch — so not inside the turnover, where the
+        device stands idle until the next dispatch: the dispatch paths call
+        this once the NEXT window is enqueued (before its drain), and the
+        epoch loop once more when it ends. Still the ``bookkeeping``
+        phase."""
+        backlog, self._vector_backlog = self._vector_backlog, []
+        for vectors in backlog:
+            with trace_span("drain.vectors", acc=self._add_bookkeeping):
+                self.trainer.observe_step_vectors(self.job_id, vectors)
+
     def _emit_batch_metrics(
         self,
         epoch: int,
@@ -2120,8 +2144,7 @@ class WorkerTasklet:
         # trainer's counters; the series below are per-step scalars
         vectors = {k: v for k, v in host.items() if v.ndim > 1}
         if vectors:
-            with trace_span("drain.vectors"):
-                self.trainer.observe_step_vectors(self.job_id, vectors)
+            self._vector_backlog.append(vectors)
             host = {k: v for k, v in host.items() if k not in vectors}
         # one shared fallback rule (_primary_key) for the per-batch series
         lkey = self._primary_key(host)
@@ -2395,6 +2418,7 @@ class WorkerTasklet:
                 # windowable by declaration: depends only on the epoch
                 # index, so it may run before the epoch's results drain
                 self.trainer.on_epoch_finished(self.ctx, first_epoch + j)
+        self._observe_vector_backlog()  # the device has this window's steps
         # ONE drain for the whole window, counted as work: the per-batch
         # times fed to the optimizer must include device execution
         t_sync = time.perf_counter()

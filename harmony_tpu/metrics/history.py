@@ -201,10 +201,16 @@ class HistoryStore:
                 self._prune_locked(ts)
             s = self._series.get(key)
             if s is None:
-                if len(self._series) >= _MAX_SERIES:
+                if len(self._series) >= _MAX_SERIES and ts > self._last_prune:
                     # cap pressure: evict window-expired series first —
                     # tenant churn must not permanently blind the store
-                    # to NEW tenants while dead ones hold the cap
+                    # to NEW tenants while dead ones hold the cap. ONE
+                    # scan a timestamp (a scrape's samples share theirs,
+                    # and nothing more can expire at the same instant): a
+                    # saturated store otherwise rescans every series for
+                    # every sample past the cap — two jobs' 5 x 512 expert
+                    # counters are a 5,146-line exposition, 1,034 scans and
+                    # 0.6 s under the GIL every scrape period
                     self._prune_locked(ts)
                 if len(self._series) >= _MAX_SERIES:
                     self._dropped_series += 1

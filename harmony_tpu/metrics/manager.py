@@ -187,17 +187,20 @@ class MetricManager:
         from harmony_tpu.metrics import kda, moe
 
         routing = moe.stats_by_job()
-        kinds, mixers = kda.kinds_by_job(), kda.stats_by_job()
+        kinds = kda.kinds_by_job()
+        mixers = {kind: kda.stats_by_job(kind) for kind in kda.STATS}
         for jid, row in rows.items():
             rep = stragglers.get(jid)
             row["straggler_ratio"] = rep["ratio"] if rep else None
             # dropless expert tenants: share of token-slots computed here,
             # most loaded held expert over the mean (metrics/moe.py)
             row["moe"] = routing.get(jid)
-            # blocks by token-mixer kind, and the KDA blocks' mean decay
-            # and beta (metrics/kda.py)
+            # blocks by token-mixer kind, and the recurrent layers' two
+            # statistics: KDA blocks' mean decay and beta, state-space
+            # layers' mean decay and step (metrics/kda.py)
             row["layer_kinds"] = kinds.get(jid)
-            row["kda"] = mixers.get(jid)
+            for kind, by_job in mixers.items():
+                row[kind] = by_job.get(jid)
             b = budgets.get(jid)
             if b:
                 from harmony_tpu.metrics import critpath

@@ -75,7 +75,10 @@ def chunks_run(expert_tokens: np.ndarray, experts_held: int) -> np.ndarray:
 
 #: a job's grid of counter children, row-major over (layer, expert), kept
 #: with the family it belongs to: ``labels()`` costs ~20 us a call, and a
-#: drain of Kimi Linear's 4 x 256 grid paid it 1,024 times
+#: drain of Kimi Linear's 4 x 256 grid paid it 1,024 times. Third: the sum
+#: of everything added to the grid, so that STATUS (:func:`stats_by_job`,
+#: five times a second under the benchmark) reads the held experts' cells
+#: and this one number instead of walking 5 x 512 children a job
 _grids: Dict[tuple, tuple] = {}
 
 
@@ -85,8 +88,8 @@ def _grid(tokens, job: str, layers: Sequence[int], experts: int):
     if found is None or found[0] is not tokens:
         found = _grids[key] = (tokens, [
             tokens.labels(job=job, layer=str(layer), expert=str(expert))
-            for layer in layers for expert in range(experts)])
-    return found[1]
+            for layer in layers for expert in range(experts)], [0.0])
+    return found
 
 
 def observe(job: str, expert_tokens: np.ndarray, experts_held: int,
@@ -108,9 +111,10 @@ def observe(job: str, expert_tokens: np.ndarray, experts_held: int,
         if len(layers) != len(per):
             raise ValueError(f"moe.observe: {len(layers)} layer labels for "
                              f"{len(per)} expert layers")
-        for child, n in zip(_grid(tokens, job, layers, per.shape[1]),
-                            per.ravel().tolist()):
+        _, children, seen = _grid(tokens, job, layers, per.shape[1])
+        for child, n in zip(children, per.ravel().tolist()):
             child.inc(n)
+        seen[0] += float(per.sum())
         held_slots.labels(job=job).inc(float(held_by_step.sum()))
         held.labels(job=job).set(experts_held)
         calls.labels(job=job).inc(by_step.shape[0] * by_step.shape[1])
@@ -124,14 +128,18 @@ def stats_by_job() -> Dict[str, Dict[str, float]]:
     try:
         tokens, held_slots, held, _calls, _chunks = _families()
         n_held = {job: int(c.value) for (job,), c in held.children()}
-        by_expert: Dict[str, Dict[int, float]] = {}
-        for (job, _layer, expert), c in tokens.children():
-            row = by_expert.setdefault(job, {})
-            row[int(expert)] = row.get(int(expert), 0.0) + c.value
+        by_job: Dict[str, list] = {}  # job -> [all slots, held experts']
+        for (job, layers, experts), (family, children, seen) in list(
+                _grids.items()):
+            if family is not tokens:
+                continue  # a registry since replaced
+            row = by_job.setdefault(job, [0.0, [0.0] * n_held.get(job, 0)])
+            row[0] += seen[0]
+            for at in range(len(layers)):
+                for e in range(min(len(row[1]), experts)):
+                    row[1][e] += children[at * experts + e].value
         slots = {job: c.value for (job,), c in held_slots.children()}
-        for job, row in by_expert.items():
-            total = sum(row.values())
-            mine = [row.get(e, 0.0) for e in range(n_held.get(job, 0))]
+        for job, (total, mine) in by_job.items():
             if total <= 0 or not mine or sum(mine) <= 0:
                 continue
             out[job] = {

@@ -183,7 +183,26 @@ class DroplessConfig:
     routed_scale: float = 1.0
     shared_experts: int = 0   # one MLP of this many expert widths, all tokens
     seq_aux: bool = False     # stats carry the sequence-wise balance term
-    act: str = "silu"         # the routed experts' gate: "silu" | "relu"
+    act: str = "silu"         # the experts' activation (``_ACTS``)
+    # Nemotron-H's LatentMoE: ``gated`` False drops the gate (down(act(up
+    # x)): two grouped matmuls, not three; the shared MLP likewise);
+    # ``latent`` > 0 is the width the ROUTED experts read and write — every
+    # token through ``latent_down [d, latent]`` before the dispatch and
+    # ``latent_up [latent, d]`` after the combine, the router and the shared
+    # MLP on the full-width rows; ``shared_d_ff`` > 0 is the shared MLP's
+    # width where that is no multiple of an expert's (the columns held here)
+    gated: bool = True
+    latent: int = 0
+    shared_d_ff: int = 0
+
+    @property
+    def expert_d(self) -> int:
+        """The routed experts' input and output width."""
+        return self.latent or self.d_model
+
+    @property
+    def shared_width(self) -> int:
+        return self.shared_d_ff or self.shared_experts * self.d_ff
 
 
 def init_dropless_params(rng: jax.Array, cfg: DroplessConfig
@@ -191,23 +210,32 @@ def init_dropless_params(rng: jax.Array, cfg: DroplessConfig
     """Router over ALL experts; gate/up/down weights of the HELD experts,
     expert-stacked on the leading dim. A sigmoid router has its selection
     ``bias [E]`` (zeros: the optimizer leaves it there, its gradient is 0);
-    shared experts are ``shared_wg`` / ``_wu`` / ``_wd``."""
+    shared experts are ``shared_wg`` / ``_wu`` / ``_wd``. Ungated experts
+    have no ``wg`` / ``shared_wg``; latent ones are ``expert_d`` wide
+    between ``latent_down`` and ``latent_up``."""
     kr, kg, ku, kd = jax.random.split(rng, 4)
-    E, H, d, f = cfg.num_experts, cfg.experts_held, cfg.d_model, cfg.d_ff
+    E, H, f = cfg.num_experts, cfg.experts_held, cfg.d_ff
+    d, r = cfg.d_model, cfg.expert_d
     params = {
         "router": jax.random.normal(kr, (d, E), jnp.float32) * (d ** -0.5),
-        "wg": jax.random.normal(kg, (H, d, f), jnp.float32) * (d ** -0.5),
-        "wu": jax.random.normal(ku, (H, d, f), jnp.float32) * (d ** -0.5),
-        "wd": jax.random.normal(kd, (H, f, d), jnp.float32) * (f ** -0.5),
+        "wu": jax.random.normal(ku, (H, r, f), jnp.float32) * (r ** -0.5),
+        "wd": jax.random.normal(kd, (H, f, r), jnp.float32) * (f ** -0.5),
     }
+    if cfg.gated:
+        params["wg"] = jax.random.normal(kg, (H, r, f), jnp.float32) * (r ** -0.5)
     if cfg.score == "sigmoid":
         params["bias"] = jnp.zeros((E,), jnp.float32)
     if cfg.shared_experts:
-        fs = cfg.shared_experts * f
+        fs = cfg.shared_width
         ksg, ksu, ksd = jax.random.split(jax.random.fold_in(rng, 1), 3)
-        params["shared_wg"] = jax.random.normal(ksg, (d, fs), jnp.float32) * (d ** -0.5)
+        if cfg.gated:
+            params["shared_wg"] = jax.random.normal(ksg, (d, fs), jnp.float32) * (d ** -0.5)
         params["shared_wu"] = jax.random.normal(ksu, (d, fs), jnp.float32) * (d ** -0.5)
         params["shared_wd"] = jax.random.normal(ksd, (fs, d), jnp.float32) * (fs ** -0.5)
+    if cfg.latent:
+        kld, klu = jax.random.split(jax.random.fold_in(rng, 2))
+        params["latent_down"] = jax.random.normal(kld, (d, r), jnp.float32) * (d ** -0.5)
+        params["latent_up"] = jax.random.normal(klu, (r, d), jnp.float32) * (r ** -0.5)
     return params
 
 
@@ -321,11 +349,22 @@ def chunk_plan(slots: int, held: int, experts: int) -> Tuple[int, int]:
     return (slots, 0) if _LEAST_CUT * C > slots else (C, -(-slots // C))
 
 
-_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu,
+         "relu2": lambda t: jnp.square(jax.nn.relu(t))}
 
 
 def _gated(g, u, act="silu"):
-    return _ACTS[act](g) * u
+    """The experts' hidden rows: ``act(g) * u``, or ``act(u)`` where the
+    experts are not gated (``g`` None)."""
+    return _ACTS[act](u) if g is None else _ACTS[act](g) * u
+
+
+def _d_act(t, act):
+    """``act``'s derivative at ``t`` (float32)."""
+    if act == "silu":
+        s = jax.nn.sigmoid(t)
+        return s * (1.0 + t * (1.0 - s))
+    return jnp.where(t > 0, 2.0 * t if act == "relu2" else 1.0, 0.0)
 
 
 def _chunk(C: int, i, order, offsets, starts, k: int):
@@ -359,13 +398,14 @@ def _run_chunks(C: int, act: str, save: bool, x, weight, order, offsets,
     """``(out [T, d] f32, (g, u))``: the routed sum over the held slots,
     chunk by chunk while a chunk starts inside them; with ``save`` the
     gate and up products of every chunk that ran, stacked by sorted row
-    (rows of a chunk that did not run stay 0)."""
+    (rows of a chunk that did not run stay 0). ``wg`` None: experts that
+    are not gated — no gate product is formed or saved."""
     from harmony_tpu.ops.grouped_matmul import _gmm, _note_plans
     from harmony_tpu.ops.sum_rows import note_plan, sum_rows
     from harmony_tpu.utils.platform import trace_is_tpu
 
     (T, d), k, dtype = x.shape, weight.shape[1], x.dtype
-    H, _, f = wg.shape
+    H, _, f = wu.shape
     interpret = not trace_is_tpu()
     _note_plans(("fwd",), C, d, f, H, dtype)
     _note_plans(("fwd",), C, f, d, H, dtype)
@@ -380,12 +420,14 @@ def _run_chunks(C: int, act: str, save: bool, x, weight, order, offsets,
                                                    starts, k)
             rows = x[tok]                                        # [C, d]
         with step_scope("moe.experts"):
-            g = _gmm(rows, wg, sizes, False, interpret)
+            g = None if wg is None else _gmm(rows, wg, sizes, False,
+                                             interpret)
             u = _gmm(rows, wu, sizes, False, interpret)
             y = _gmm(_gated(g, u, act), wd, sizes, False, interpret)  # [C, d]
             if save:
                 saved = tuple(lax.dynamic_update_slice(s, v, (lo, 0))
-                              for s, v in zip(saved, (g, u)))
+                              for s, v in zip(saved, (u,) if g is None
+                                              else (g, u)))
         with step_scope("moe.combine"):
             # each held row times its gate onto its token's row, folded in
             # VMEM a token tile at a time: XLA's scatter-add takes the C
@@ -395,7 +437,8 @@ def _run_chunks(C: int, act: str, save: bool, x, weight, order, offsets,
                            interpret=interpret)
         return i + 1, acc, saved
 
-    saved = ((jnp.zeros((order.shape[0], f), dtype),) * 2) if save else ()
+    saved = ((jnp.zeros((order.shape[0], f), dtype),)
+             * (2 - (wg is None))) if save else ()
     _, out, saved = lax.while_loop(
         lambda carry: carry[0] * C < n_held, body,
         (jnp.int32(0), jnp.zeros((T, d), jnp.float32), saved))
@@ -433,9 +476,10 @@ def _experts_chunked_bwd(C, act, res, d_out):
     from harmony_tpu.ops.sum_rows import note_plan, sum_rows
     from harmony_tpu.utils.platform import trace_is_tpu
 
-    x, weight, order, offsets, starts, wg, wu, wd, (g_all, u_all) = res
+    x, weight, order, offsets, starts, wg, wu, wd, saved = res
+    gated = wg is not None  # else down(act(up x)): a dx and a dw call fewer
     (T, d), k, dtype = x.shape, weight.shape[1], x.dtype
-    H, _, f = wg.shape
+    H, _, f = wu.shape
     interpret = not trace_is_tpu()
     _note_plans(("dx", "dw"), C, d, f, H, dtype)
     _note_plans(("dx", "dw"), C, f, d, H, dtype)
@@ -445,7 +489,8 @@ def _experts_chunked_bwd(C, act, res, d_out):
     f32 = jnp.float32
 
     def body(carry):
-        i, d_x, d_w, d_wg, d_wu, d_wd = carry
+        i, d_x, d_w, *d_ws = carry
+        d_wu, d_wd = d_ws[-2:]
         with step_scope("moe.dispatch"):
             lo, slots, tok, sizes, bounds = _chunk(C, i, order, offsets,
                                                    starts, k)
@@ -455,8 +500,8 @@ def _experts_chunked_bwd(C, act, res, d_out):
             d_rows_out = d_out[tok]                              # [C, d] f32
             d_y = (d_rows_out * w).astype(dtype)
         with step_scope("moe.experts"):
-            g = lax.dynamic_slice(g_all, (lo, 0), (C, f))
-            u = lax.dynamic_slice(u_all, (lo, 0), (C, f))
+            g, u = ((None,) * (not gated) + tuple(
+                lax.dynamic_slice(s, (lo, 0), (C, f)) for s in saved))
             h = _gated(g, u, act)
             # the down product's cotangent WITHOUT the gate's weight: the
             # weight's own cotangent is <h, it>, the hidden's is w times it
@@ -464,31 +509,40 @@ def _experts_chunked_bwd(C, act, res, d_out):
                         interpret).astype(f32)                   # [C, f]
             d_wd = d_wd + _tgmm(h, d_y, sizes, interpret)
             d_h = d_hu * w
-            g32, u32 = g.astype(f32), u.astype(f32)
-            if act == "silu":
-                sg = jax.nn.sigmoid(g32)
-                d_g = (d_h * u32 * sg * (1.0 + g32 * (1.0 - sg))).astype(dtype)
-                d_u = (d_h * g32 * sg).astype(dtype)
-            else:  # relu: the gate passes where it is positive
-                d_g = jnp.where(g32 > 0, d_h * u32, 0.0).astype(dtype)
-                d_u = jnp.where(g32 > 0, d_h * g32, 0.0).astype(dtype)
-            d_rows = (_gmm(d_g, wg, sizes, True, interpret)
-                      + _gmm(d_u, wu, sizes, True, interpret))
-            d_wg = d_wg + _tgmm(rows, d_g, sizes, interpret)
-            d_wu = d_wu + _tgmm(rows, d_u, sizes, interpret)
+            if not gated:
+                d_u = (d_h * _d_act(u.astype(f32), act)).astype(dtype)
+                d_rows = _gmm(d_u, wu, sizes, True, interpret)
+                d_wu = d_wu + _tgmm(rows, d_u, sizes, interpret)
+                d_ws = (d_wu, d_wd)
+            else:
+                g32, u32 = g.astype(f32), u.astype(f32)
+                if act == "silu":
+                    sg = jax.nn.sigmoid(g32)
+                    d_g = (d_h * u32 * sg * (1.0 + g32 * (1.0 - sg))
+                           ).astype(dtype)
+                    d_u = (d_h * g32 * sg).astype(dtype)
+                else:  # relu: the gate passes where it is positive
+                    d_g = jnp.where(g32 > 0, d_h * u32, 0.0).astype(dtype)
+                    d_u = jnp.where(g32 > 0, d_h * g32, 0.0).astype(dtype)
+                d_rows = (_gmm(d_g, wg, sizes, True, interpret)
+                          + _gmm(d_u, wu, sizes, True, interpret))
+                d_wg = d_ws[0] + _tgmm(rows, d_g, sizes, interpret)
+                d_wu = d_wu + _tgmm(rows, d_u, sizes, interpret)
+                d_ws = (d_wg, d_wu, d_wd)
         with step_scope("moe.combine"):
             d_w = d_w.at[slots].add(jnp.sum(h.astype(f32) * d_hu, axis=-1))
         with step_scope("moe.dispatch"):
             d_x = sum_rows(d_x, d_rows, tok, bounds, fresh=i == 0,
                            interpret=interpret)
-        return i + 1, d_x, d_w, d_wg, d_wu, d_wd
+        return (i + 1, d_x, d_w, *d_ws)
 
-    _, d_x, d_w, d_wg, d_wu, d_wd = lax.while_loop(
+    held = (wg, wu, wd) if gated else (wu, wd)
+    _, d_x, d_w, *d_ws = lax.while_loop(
         lambda carry: carry[0] * C < n_held, body,
         (jnp.int32(0), jnp.zeros((T, d), f32), jnp.zeros((T * k,), f32),
-         jnp.zeros_like(wg), jnp.zeros_like(wu), jnp.zeros_like(wd)))
+         *(jnp.zeros_like(t) for t in held)))
     return (d_x.astype(dtype), d_w.reshape(weight.shape), None, None, None,
-            d_wg, d_wu, d_wd)
+            *(None,) * (not gated), *d_ws)
 
 
 _experts_chunked.defvjp(_experts_chunked_fwd, _experts_chunked_bwd)
@@ -500,7 +554,7 @@ def _experts_plain(x, gate, expert, slot_expert, tokens, wg, wu, wd,
     every expert is held, or the held share leaves nothing to cut."""
     from harmony_tpu.ops.grouped_matmul import grouped_matmul
 
-    (T, d), k, H = x.shape, gate.shape[1], wg.shape[0]
+    (T, d), k, H = x.shape, gate.shape[1], wu.shape[0]
     with step_scope("moe.dispatch"):
         order = jnp.argsort(slot_expert, stable=True)
         inv = jnp.argsort(order)  # a permutation's inverse, again by sorting
@@ -508,8 +562,11 @@ def _experts_plain(x, gate, expert, slot_expert, tokens, wg, wu, wd,
         rows = _slot_rows(x, order, inv, k)                      # [T * k, d]
     dtype = x.dtype
     with step_scope("moe.experts"):
-        h = (_ACTS[act](grouped_matmul(rows, wg.astype(dtype), sizes))
-             * grouped_matmul(rows, wu.astype(dtype), sizes))
+        if wg is None:  # experts that are not gated
+            h = _ACTS[act](grouped_matmul(rows, wu.astype(dtype), sizes))
+        else:
+            h = (_ACTS[act](grouped_matmul(rows, wg.astype(dtype), sizes))
+                 * grouped_matmul(rows, wu.astype(dtype), sizes))
         y = grouped_matmul(h, wd.astype(dtype), sizes)           # [T * k, d]
     with step_scope("moe.combine"):
         # back to slot order; an absent expert's slot carries weight 0
@@ -542,7 +599,9 @@ def moe_ffn_dropless(params: Dict[str, jnp.ndarray], x: jnp.ndarray,
     that are held here (``w_e``: :func:`_route`), plus the shared MLP where
     the configuration has one. ``stats``: :func:`_route`'s. The router
     reads ``router_x [T, d]`` where given (a block that routes on its
-    input), else the rows it dispatches."""
+    input), else the rows it dispatches. Latent experts (``cfg.latent``)
+    read ``x latent_down`` and their sum passes ``latent_up``; the router
+    and the shared MLP keep the full-width rows."""
     from harmony_tpu.ops.sum_rows import tile_plan
 
     T, d = x.shape
@@ -550,10 +609,15 @@ def moe_ffn_dropless(params: Dict[str, jnp.ndarray], x: jnp.ndarray,
     gate, expert, slot_expert, tokens, stats = _route(
         params, x if router_x is None else router_x, cfg, seqs)
     dtype = x.dtype
+    full = x
+    if cfg.latent:
+        with step_scope("moe.latent"):
+            x = x @ params["latent_down"].astype(dtype)
+            d = cfg.latent
     C, chunks = chunk_plan(T * k, H, cfg.num_experts)
     if not chunks:
         out = _experts_plain(x, gate, expert, slot_expert, tokens,
-                             params["wg"], params["wu"], params["wd"],
+                             *(params.get(w) for w in ("wg", "wu", "wd")),
                              cfg.act)
     else:
         _note_chunk_plan(C, chunks, d, cfg.d_ff)
@@ -567,14 +631,21 @@ def moe_ffn_dropless(params: Dict[str, jnp.ndarray], x: jnp.ndarray,
             starts = _tile_starts(slot_expert, offsets, k,
                                   tile_plan(T, d, dtype))
         with step_scope("moe.experts"):
-            weights = [params[w].astype(dtype) for w in ("wg", "wu", "wd")]
+            weights = [params[w].astype(dtype) if w in params else None
+                       for w in ("wg", "wu", "wd")]  # ungated: no wg
         out = _experts_chunked(C, cfg.act, x, gate, order, offsets, starts,
                                *weights)
         with step_scope("moe.combine"):
             out = out.astype(dtype)
+    if cfg.latent:
+        with step_scope("moe.latent"):
+            out = out @ params["latent_up"].astype(dtype)
     if cfg.shared_experts:  # plain matmuls on every token, beside the sum
         with step_scope("moe.shared"):
-            hs = (jax.nn.silu(x @ params["shared_wg"].astype(dtype))
-                  * (x @ params["shared_wu"].astype(dtype)))
+            if cfg.gated:
+                hs = (jax.nn.silu(full @ params["shared_wg"].astype(dtype))
+                      * (full @ params["shared_wu"].astype(dtype)))
+            else:
+                hs = _ACTS[cfg.act](full @ params["shared_wu"].astype(dtype))
             out = out + hs @ params["shared_wd"].astype(dtype)
     return out, stats

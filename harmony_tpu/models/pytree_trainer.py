@@ -168,10 +168,13 @@ class PyTreeTrainer(Trainer):
     def init_global_settings(self, ctx: TrainerContext) -> None:
         params = self.model.init(jax.random.PRNGKey(self.seed))
         flat, _ = ravel_pytree(params)
-        ctx.model_table.multi_put(
-            list(range(self.num_rows)),
-            np.asarray(self._to_rows(flat, self.num_rows)),
-        )
+        rows = np.asarray(self._to_rows(flat, self.num_rows))
+        # the put holds the table twice (nothing is donated) and the rows
+        # once: the leaves and their flat copy, two more thirds of a
+        # [params | m | v] table, go first — with them a 6.1 GB table did not
+        # initialise on a 16 GB chip (PERF.md, PR 38)
+        del params, flat
+        ctx.model_table.multi_put(list(range(self.num_rows)), rows)
         # pad rows, m/v sections and the counter block start (and stay,
         # until the first push) at the table's init value 0.
 

@@ -133,6 +133,32 @@ class TransformerConfig:
     window_layers: Tuple[int, ...] = ()
     moe_route_block_input: bool = False
     moe_act: str = "silu"
+    # Nemotron-H's hybrid (``nemotron_h``; its Mamba-2 layers:
+    # arXiv:2405.21060): ``layer_pattern`` has one letter a layer, and a
+    # layer is ONE pre-norm sublayer — ``x + f(norm(x))`` — where every other
+    # model's block is a mixer AND a feed-forward part: ``"M"`` a Mamba-2
+    # state-space mixer (ops/ssd.py) of ``ssd_heads`` heads of
+    # ``ssd_head_dim``, their ``ssd_groups`` groups of heads sharing one B
+    # and one C of ``ssd_state``, a ``short_conv``-tap depthwise causal
+    # convolution with a bias, scanned in chunks of ``ssd_chunk``; ``"*"``
+    # softmax attention over the whole causal past (``attn_kind="mha"``,
+    # grouped heads as above); ``"E"`` a dropless expert layer, which may be
+    # ``moe_gated`` False (``down(act(up x))``, ``moe_act="relu2"``: the
+    # squared ReLU), work in a ``moe_latent``-wide latent (every token
+    # projected down before the dispatch and up after the combine, the
+    # router and the shared MLP on the full-width rows) and hold
+    # ``moe_shared_d_ff`` columns of its shared MLP. ``pos="none"``: the
+    # family turns nothing. ``moe_every`` / ``moe_first_dense`` play no
+    # part: the pattern says which layers route.
+    layer_pattern: str = ""
+    ssd_heads: int = 0
+    ssd_head_dim: int = 0
+    ssd_groups: int = 0
+    ssd_state: int = 0
+    ssd_chunk: int = 0
+    moe_gated: bool = True
+    moe_latent: int = 0
+    moe_shared_d_ff: int = 0
     # Standard deviation the embedding rows are drawn with. GPT-2's 0.02
     # leaves a row at a fiftieth of what a block's fan-in projections add to
     # it, which nothing here divides by depth: attention's average over the
@@ -168,9 +194,44 @@ class TransformerConfig:
                 "linear_layers lists blocks 0..n_layers-1 in order, once "
                 "each, and needs linear_heads, linear_head_dim and "
                 f"short_conv (got {self.linear_layers}, {kda})")
-        if not self.linear_layers and any(kda):
+        ssd = (self.ssd_heads, self.ssd_head_dim, self.ssd_groups,
+               self.ssd_state, self.ssd_chunk)
+        scanned = "M" in self.layer_pattern
+        if not self.linear_layers and (any(kda[:2]) or (
+                self.short_conv and not scanned)):
             raise ValueError("linear_heads / linear_head_dim / short_conv "
                              "belong to KDA blocks: set linear_layers")
+        if self.layer_pattern and (
+                set(self.layer_pattern) - set("ME*")
+                or len(self.layer_pattern) != self.n_layers
+                or self.linear_layers or self.window_layers
+                or self.attn_kind != "mha" or self.moe_first_dense
+                or self.pos == "learned" or self.moe_route_block_input
+                or ("E" in self.layer_pattern) != bool(self.moe_top_k)):
+            raise ValueError(
+                f"layer_pattern {self.layer_pattern!r}: one letter a layer "
+                f"(n_layers {self.n_layers}), 'M' a Mamba-2 mixer, '*' "
+                "softmax attention, 'E' dropless experts (moe_top_k, set if "
+                "and only if an 'E' stands in it); no KDA, windowed, latent-"
+                "attention or leading dense layers, no learned positions and "
+                "no router on a block's input beside it")
+        if scanned and (min(ssd) < 1 or self.short_conv < 1
+                        or self.ssd_heads % self.ssd_groups):
+            raise ValueError(
+                "an 'M' layer needs ssd_heads, ssd_head_dim, ssd_groups, "
+                "ssd_state, ssd_chunk and short_conv, and ssd_groups must "
+                f"divide ssd_heads (got {ssd}, short_conv {self.short_conv})")
+        if any(ssd) and not scanned:
+            raise ValueError("ssd_heads / ssd_head_dim / ssd_groups / "
+                             "ssd_state / ssd_chunk belong to the 'M' layers "
+                             "of a layer_pattern")
+        if (not self.moe_gated or self.moe_latent or self.moe_shared_d_ff) \
+                and "E" not in self.layer_pattern:
+            raise ValueError("moe_gated / moe_latent / moe_shared_d_ff "
+                             "belong to the 'E' layers of a layer_pattern")
+        if self.moe_shared_d_ff and self.moe_shared_experts != 1:
+            raise ValueError("moe_shared_d_ff is the held width of ONE "
+                             "shared MLP: set moe_shared_experts=1")
         object.__setattr__(self, "window_layers",
                            tuple(int(i) for i in self.window_layers))
         grouped = (self.n_kv_heads, self.mha_head_dim, self.window,
@@ -198,16 +259,20 @@ class TransformerConfig:
                 "window >= 1 and pos='rope' (rotary in those blocks, no "
                 f"positions in the others); got {self.window_layers}, "
                 f"window {self.window}, pos {self.pos!r}")
-        if self.moe_act not in ("silu", "relu"):
-            raise ValueError(f"unknown moe_act {self.moe_act!r}: 'silu' or "
-                             "'relu'")
+        if self.moe_act not in ("silu", "relu", "relu2"):
+            raise ValueError(f"unknown moe_act {self.moe_act!r}: 'silu', "
+                             "'relu' or 'relu2'")
+        if self.moe_gated and self.moe_act == "relu2":
+            raise ValueError("moe_act='relu2' is the activation of experts "
+                             "that are not gated: set moe_gated=False")
         if not self.moe_top_k and (self.moe_route_block_input
                                    or self.moe_act != "silu"):
             raise ValueError("moe_route_block_input / moe_act belong to "
                              "dropless routing: set moe_top_k")
-        if self.pos == "none" and not self.linear_layers:
+        if self.pos == "none" and not (self.linear_layers or scanned):
             raise ValueError(
-                "pos='none' runs only beside KDA blocks (linear_layers): "
+                "pos='none' runs only beside KDA blocks (linear_layers) or "
+                "state-space layers ('M' in layer_pattern): "
                 "their convolutions and decay carry the order, and a latent "
                 "(attn_kind='mla') block among them may then go without "
                 "rotary; a model of softmax blocks alone cannot tell "
@@ -239,7 +304,7 @@ class TransformerConfig:
         if not 0 <= self.moe_top_k <= self.moe_experts:
             raise ValueError(f"moe_top_k {self.moe_top_k} must lie in 0.."
                              f"moe_experts ({self.moe_experts})")
-        if self.moe_top_k and self.ffn != "swiglu":
+        if self.moe_top_k and self.moe_gated and self.ffn != "swiglu":
             raise ValueError("dropless experts (moe_top_k > 0) are gated-SiLU:"
                              " set ffn='swiglu'")
         if not self.moe_top_k and (self.moe_experts_held is not None
@@ -282,7 +347,10 @@ class TransformerConfig:
     def is_moe_layer(self, i: int) -> bool:
         """Block i uses the MoE FFN: past the ``moe_first_dense`` leading
         dense layers, the last of every ``moe_every`` group (Switch
-        interleaves dense and expert blocks)."""
+        interleaves dense and expert blocks); in a ``layer_pattern`` model
+        the layers lettered ``E``."""
+        if self.layer_pattern:
+            return self.layer_pattern[i] == "E"
         return (bool(self.moe_experts) and i >= self.moe_first_dense
                 and i % self.moe_every == self.moe_every - 1)
 
@@ -300,9 +368,16 @@ class TransformerConfig:
         ``linear_layers``, else ``attn_kind`` (``"mha"`` | ``"mla"``) — or,
         in a model with ``window_layers``, ``"swa"`` for those (windowed,
         rotary) and ``"full"`` for its other softmax blocks (whole causal
-        past, no positions). The block, ``init``, the trainer's vectors
-        (``kda_decay_mean [kda blocks]``), STATUS ``layer_kinds`` and the
-        benchmark's work functions ask here."""
+        past, no positions). A ``layer_pattern`` model's layers are one
+        sublayer each: ``"ssd"`` (``M``), ``"attn"`` (``*``: attention and
+        nothing after it), ``"moe"`` (``E``: experts and no mixer). The
+        block, ``init``, the trainer's vectors (``kda_decay_mean [kda
+        blocks]``, ``ssd_decay_mean [ssd layers]``), STATUS ``layer_kinds``
+        and the benchmark's work functions ask here."""
+        if self.layer_pattern:
+            return tuple({"M": "ssd", "*": "attn", "E": "moe"}[c]
+                         for c in self.layer_pattern)
+
         def kind(i):
             if i in self.linear_layers:
                 return "kda"
@@ -337,7 +412,8 @@ class TransformerConfig:
             score=self.moe_score, norm_topk=self.moe_norm_topk,
             routed_scale=self.moe_routed_scale,
             shared_experts=self.moe_shared_experts, seq_aux=self.moe_seq_aux,
-            act=self.moe_act)
+            act=self.moe_act, gated=self.moe_gated, latent=self.moe_latent,
+            shared_d_ff=self.moe_shared_d_ff)
 
     @property
     def head_dim(self) -> int:
@@ -353,6 +429,15 @@ class TransformerConfig:
         hd = self.head_dim
         return self.n_heads * hd, self.kv_heads * hd, self.kv_heads * hd
 
+    @property
+    def ssd_widths(self) -> Tuple[int, int, int]:
+        """An ``M`` layer's widths: its heads' channels ``H P``, what the
+        convolution runs over (``x | B | C``: ``H P + 2 G N``) and its input
+        projection's columns (``z | x | B | C | dt``)."""
+        inner = self.ssd_heads * self.ssd_head_dim
+        conv = inner + 2 * self.ssd_groups * self.ssd_state
+        return inner, conv, inner + conv + self.ssd_heads
+
     def require_classic_block(self, who: str) -> None:
         """The side training steps and the decode path below still assume
         the GPT-2-era block (learned positions, GELU, tied readout, Switch
@@ -361,6 +446,7 @@ class TransformerConfig:
                 and self.tie_embeddings and not self.qk_norm
                 and not self.moe_top_k and self.attn_kind == "mha"
                 and not self.moe_first_dense and not self.linear_layers
+                and not self.layer_pattern
                 and not (self.n_kv_heads or self.mha_head_dim
                          or self.window_layers)):
             raise ValueError(
@@ -368,7 +454,7 @@ class TransformerConfig:
                 "GELU, tied readout, Switch experts); rotary / no-position / "
                 "QK-norm / SwiGLU / untied / dropless / latent-attention / "
                 "KDA linear-attention / grouped-query / windowed / leading-dense "
-                "configs train through "
+                "/ layer-pattern configs train through "
                 "TransformerLM.loss and TransformerTrainer")
 
 
@@ -434,6 +520,42 @@ def init_kda_params(k_in: jax.Array, k_out: jax.Array,
     }
 
 
+def init_ssd_params(k_in: jax.Array, k_out: jax.Array,
+                    cfg: TransformerConfig) -> Dict[str, jnp.ndarray]:
+    """A Mamba-2 mixer's parameters (``TransformerLM._ssd_mixer``): the
+    input projection ``w_in [d, z | x | B | C | dt]``, the convolution's taps
+    ``conv [K, x | B | C]`` (uniform in ``+-K^-1/2``) and bias ``conv_b``,
+    ``a_log`` / ``dt_bias`` / ``skip`` (the paper's ``D``) a head — drawn as
+    the KDA block's decay is (``KDA_A``, ``KDA_DT``: Mamba-2's own ranges) —
+    the gated norm's weight ``o_norm [H P]`` and ``w_out [H P, d]``."""
+    from harmony_tpu.models.common import dense_init as dense
+
+    H, K = cfg.ssd_heads, cfg.short_conv
+    inner, conv, proj = cfg.ssd_widths
+    ki, kc, ka, kdt = jax.random.split(k_in, 4)
+    dt = jnp.exp(jax.random.uniform(kdt, (H,), jnp.float32,
+                                    np.log(KDA_DT[0]), np.log(KDA_DT[1])))
+    return {
+        "w_in": dense(ki, (cfg.d_model, proj)),
+        "conv": jax.random.uniform(kc, (K, conv), jnp.float32,
+                                   -K ** -0.5, K ** -0.5),
+        "conv_b": jnp.zeros((conv,), jnp.float32),
+        "a_log": jnp.log(jax.random.uniform(ka, (H,), jnp.float32, *KDA_A)),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+        "skip": jnp.ones((H,), jnp.float32),
+        "o_norm": jnp.ones((inner,), jnp.float32),
+        "w_out": dense(k_out, (inner, cfg.d_model)),
+    }
+
+
+def _causal_conv(t, taps):
+    """The depthwise causal convolution of ``t [B, S, c]`` by ``taps [K,
+    c]``, in float32: ``y_t = sum_j taps[j] t_{t - (K-1) + j}``."""
+    K, S = taps.shape[0], t.shape[1]
+    tp = jnp.pad(t.astype(jnp.float32), ((0, 0), (K - 1, 0), (0, 0)))
+    return sum(tp[:, j:j + S] * taps[j] for j in range(K))
+
+
 class TransformerLM:
     """Pure-functional decoder-only LM: ``init`` -> params, ``apply`` ->
     logits, ``loss`` -> mean next-token cross-entropy."""
@@ -455,6 +577,20 @@ class TransformerLM:
         for i, kl in enumerate(k_layers):
             ks = jax.random.split(kl, 4)
             f = cfg.ffn_width(i)
+            if cfg.layer_pattern:  # one sublayer under one norm
+                from harmony_tpu.models import moe
+
+                layer = {"ln": jnp.ones((d,), jnp.float32)}
+                if kinds[i] == "ssd":
+                    layer["ssd"] = init_ssd_params(ks[0], ks[1], cfg)
+                elif kinds[i] == "attn":
+                    layer["wqkv"] = dense(ks[0], (d, sum(cfg.qkv_widths)))
+                    layer["wo"] = dense(ks[1], (cfg.qkv_widths[0], d))
+                else:
+                    layer["moe"] = moe.init_dropless_params(
+                        ks[2], cfg.dropless_cfg)
+                layers.append(layer)
+                continue
             if kinds[i] == "kda":
                 layer = {
                     "ln1": jnp.ones((d,), jnp.float32),
@@ -525,10 +661,57 @@ class TransformerLM:
             return (rng.standard_normal(shape)
                     * shape[0] ** -0.5).astype(np.float32)
 
+        def stacked(n, a, b):  # n experts of [a, b], fan-in a
+            return (rng.standard_normal((n, a, b)) * a ** -0.5
+                    ).astype(np.float32)
+
         layers = []
         kinds = cfg.layer_kinds()
         for i in range(cfg.n_layers):
             f = cfg.ffn_width(i)
+            if cfg.layer_pattern:
+                layer = {"ln": np.ones((d,), np.float32)}
+                if kinds[i] == "ssd":
+                    H, K = cfg.ssd_heads, cfg.short_conv
+                    inner, conv, proj = cfg.ssd_widths
+                    dt = np.exp(rng.uniform(np.log(KDA_DT[0]),
+                                            np.log(KDA_DT[1]), H))
+                    layer["ssd"] = {
+                        "w_in": dense((d, proj)),
+                        "conv": rng.uniform(-K ** -0.5, K ** -0.5, (K, conv)
+                                            ).astype(np.float32),
+                        "conv_b": np.zeros((conv,), np.float32),
+                        "a_log": np.log(rng.uniform(*KDA_A, H)
+                                        ).astype(np.float32),
+                        "dt_bias": (dt + np.log(-np.expm1(-dt))
+                                    ).astype(np.float32),
+                        "skip": np.ones((H,), np.float32),
+                        "o_norm": np.ones((inner,), np.float32),
+                        "w_out": dense((inner, d))}
+                elif kinds[i] == "attn":
+                    layer["wqkv"] = dense((d, sum(cfg.qkv_widths)))
+                    layer["wo"] = dense((cfg.qkv_widths[0], d))
+                else:
+                    mc = cfg.dropless_cfg
+                    r, fs = mc.expert_d, mc.shared_width
+                    layer["moe"] = m = {
+                        "router": dense((d, mc.num_experts)),
+                        "wu": stacked(mc.experts_held, r, f),
+                        "wd": stacked(mc.experts_held, f, r)}
+                    if mc.gated:
+                        m["wg"] = stacked(mc.experts_held, r, f)
+                    if mc.score == "sigmoid":
+                        m["bias"] = np.zeros((mc.num_experts,), np.float32)
+                    if mc.shared_experts:
+                        m.update(shared_wu=dense((d, fs)),
+                                 shared_wd=dense((fs, d)))
+                        if mc.gated:
+                            m["shared_wg"] = dense((d, fs))
+                    if mc.latent:
+                        m.update(latent_down=dense((d, r)),
+                                 latent_up=dense((r, d)))
+                layers.append(layer)
+                continue
             if kinds[i] == "kda":
                 H, dh, K = (cfg.linear_heads, cfg.linear_head_dim,
                             cfg.short_conv)
@@ -579,11 +762,6 @@ class TransformerLM:
                 layer["k_norm"] = np.ones((d,), np.float32)
             if cfg.is_moe_layer(i):
                 E = cfg.moe_experts
-
-                def stacked(n, a, b):  # n experts of [a, b], fan-in a
-                    return (rng.standard_normal((n, a, b)) * a ** -0.5
-                            ).astype(np.float32)
-
                 if cfg.moe_top_k:
                     H = cfg.dropless_cfg.experts_held
                     layer["moe"] = {
@@ -691,11 +869,8 @@ class TransformerLM:
         w = lambda name: p[name].astype(dt)
         heads = lambda t: t.reshape(B, S, H, dh).transpose(0, 2, 1, 3)
 
-        def conv(t, taps):  # y_t = sum_j taps[j] t_{t - (K-1) + j}, no bias
-            K = taps.shape[0]
-            tp = jnp.pad(t.astype(f32), ((0, 0), (K - 1, 0), (0, 0)))
-            return heads(jax.nn.silu(
-                sum(tp[:, j:j + S] * taps[j] for j in range(K))))
+        def conv(t, taps):  # no bias
+            return heads(jax.nn.silu(_causal_conv(t, taps)))
 
         def l2(t):
             return t * lax.rsqrt(jnp.sum(t * t, axis=-1, keepdims=True)
@@ -727,6 +902,71 @@ class TransformerLM:
         with step_scope("kda.gate"):
             return y, {"decay": lax.stop_gradient(jnp.exp(g).mean()),
                        "beta": lax.stop_gradient(beta.mean())}
+
+    def _ssd_mixer(self, xn, p):
+        """Nemotron-H's Mamba-2 token mixer on the normed input ``xn [B, S,
+        d]``: ``(y [B, S, d], {"decay", "dt"})``. ``[z | xBC | dt] = xn
+        W_in``; ``xBC`` through a depthwise causal convolution with a bias
+        and a SiLU, split into ``x [H, P]``, ``B`` and ``C [G, N]``; per head
+        the step ``dt = softplus(dt + dt_bias)`` and the decay ``exp(-dt
+        exp(a_log))``; the recurrence ``S_t = a_t S_{t-1} + dt_t x_t B_t^T``,
+        ``y_t = S_t C_t + skip x_t`` (ops/ssd.py; head ``h`` reads group ``h
+        // (H / G)``); the output ``W_out [rmsnorm per group (y * silu(z))]``,
+        a weight a channel. The statistics are the layer's mean decay and
+        mean step (no gradient)."""
+        from harmony_tpu.ops.ssd import ssd_scan
+
+        cfg = self.config
+        B, S = xn.shape[0], xn.shape[1]
+        H, P, G, N = (cfg.ssd_heads, cfg.ssd_head_dim, cfg.ssd_groups,
+                      cfg.ssd_state)
+        inner, conv, _ = cfg.ssd_widths
+        dt_, f32 = cfg.dtype, jnp.float32
+        heads = lambda t, h: t.reshape(B, S, h, -1).transpose(0, 2, 1, 3)
+        with step_scope("ssd.proj"):
+            zxbcdt = xn @ p["w_in"].astype(dt_)
+            z, xbc, dt = jnp.split(zxbcdt, (inner, inner + conv), axis=-1)
+        with step_scope("ssd.conv"):
+            xbc = jax.nn.silu(_causal_conv(xbc, p["conv"]) + p["conv_b"])
+            x, b, c = jnp.split(xbc, (inner, inner + G * N), axis=-1)
+            x = heads(x, H)                                  # [B, H, S, P] f32
+        with step_scope("ssd.gate"):
+            dt = jax.nn.softplus(dt.astype(f32) + p["dt_bias"]
+                                 ).transpose(0, 2, 1)        # [B, H, S]
+            g = -jnp.exp(p["a_log"])[None, :, None] * dt
+        with step_scope("ssd.scan"):
+            y = ssd_scan((x * dt[..., None]).astype(dt_),
+                         heads(b, G).astype(dt_), heads(c, G).astype(dt_), g,
+                         chunk=cfg.ssd_chunk)
+        with step_scope("ssd.gate"):
+            y = y.astype(f32) + p["skip"][None, :, None, None] * x
+            y = y.transpose(0, 2, 1, 3).reshape(B, S, G, inner // G)
+            y = (y * jax.nn.silu(z.astype(f32)).reshape(y.shape)).astype(dt_)
+        with step_scope("ssd.out"):
+            y = _norm(y, p["o_norm"].astype(dt_).reshape(G, -1),
+                      cfg.norm_eps).reshape(B, S, inner)
+            y = y @ p["w_out"].astype(dt_)
+        with step_scope("ssd.gate"):
+            return y, {"decay": lax.stop_gradient(jnp.exp(g).mean()),
+                       "dt": lax.stop_gradient(dt.mean())}
+
+    def _layer(self, x, layer, kind: str, axis_name: Optional[str] = None,
+               pos_offset: Any = 0):
+        """One layer of a ``layer_pattern`` model — ONE pre-norm sublayer,
+        ``x + f(norm(x))`` with ``f`` by ``kind`` (``layer_kinds()``: the
+        state-space mixer, softmax attention, or the expert layer).
+        ``_block``'s triple: ``(x, aux, mix)``."""
+        cfg = self.config
+        aux, mix = jnp.asarray(0.0, jnp.float32), None
+        with step_scope("norm"):
+            xn = _norm(x, layer["ln"].astype(cfg.dtype), cfg.norm_eps)
+        if kind == "ssd":
+            y, mix = self._ssd_mixer(xn, layer["ssd"])
+        elif kind == "attn":
+            y = self._softmax_mixer(xn, layer, axis_name, pos_offset, kind)
+        else:
+            y, aux = ffn_apply(cfg, layer, xn)
+        return x + y, aux, mix
 
     def _block(self, x, layer, axis_name: Optional[str],
                moe_axis: Optional[str] = None, pos_offset: Any = 0,
@@ -813,8 +1053,9 @@ class TransformerLM:
     def _forward(self, params, tokens, axis_name=None, pos_offset=0,
                  moe_axis=None):
         """``(logits, aux, mixers)``: ``_apply_with_aux``'s pair and the
-        KDA blocks' statistics, ``{"decay", "beta"}`` each ``[kda blocks]``
-        (None for a model without such blocks)."""
+        recurrent layers' statistics — KDA blocks' ``{"decay", "beta"}``
+        each ``[kda blocks]``, state-space layers' ``{"decay", "dt"}`` each
+        ``[ssd layers]`` (None for a model without such layers)."""
         cfg = self.config
         with step_scope("embed"):
             x = _embed_in(cfg, params["embed"], params.get("pos"), tokens,
@@ -832,13 +1073,18 @@ class TransformerLM:
             # HBM usually doesn't).
             block = jax.checkpoint(block)
         # a model with window_layers has two kinds of softmax block, each
-        # its own traced body (no other model traces a second one)
+        # its own traced body (no other model traces a second one); a
+        # layer_pattern model a body a kind of layer
         wrap = jax.checkpoint if cfg.remat else (lambda f: f)
         by_kind = {kind: wrap(functools.partial(
             self._block, axis_name=axis_name, moe_axis=moe_axis,
             pos_offset=pos_offset, kind=kind)) for kind in ("swa", "full")
         } if cfg.window_layers else {}
         kinds = cfg.layer_kinds()
+        if cfg.layer_pattern:
+            by_kind = {kind: wrap(functools.partial(
+                self._layer, kind=kind, axis_name=axis_name,
+                pos_offset=pos_offset)) for kind in set(kinds)}
         aux = jnp.asarray(0.0, jnp.float32)
         routed = []  # dropless layers' statistics
         mixers = []  # KDA blocks' statistics
@@ -877,9 +1123,10 @@ class TransformerLM:
         cfg = self.config
         logits, aux, mixers = self._forward(params, tokens[:, :-1],
                                             axis_name=axis_name)
-        kda = ({} if mixers is None else
-               {"kda_decay_mean": mixers["decay"],
-                "kda_beta_mean": mixers["beta"]})
+        kda = {}
+        if mixers is not None:  # one recurrent kind a model
+            kind = "ssd" if cfg.layer_pattern else "kda"
+            kda = {f"{kind}_{stat}_mean": v for stat, v in mixers.items()}
         with step_scope("loss"):
             ce = _next_token_ce(logits, tokens[:, 1:])
             if cfg.moe_seq_aux:  # each layer's mean over sequences, summed
@@ -1463,8 +1710,12 @@ class TransformerTrainer(PyTreeTrainer):
             moe.observe(job_id, vectors["moe_expert_tokens"],
                         self.config.dropless_cfg.experts_held,
                         self.config.moe_layers())
-        if "kda_decay_mean" in vectors:
-            from harmony_tpu.metrics import kda
+        from harmony_tpu.metrics import kda
 
-            kda.observe(job_id, vectors["kda_decay_mean"],
-                        vectors["kda_beta_mean"], self.config.linear_layers)
+        for kind, stats in kda.STATS.items():  # the recurrent layers' pairs
+            first, second = (f"{kind}_{stat}_mean" for stat in stats)
+            if first in vectors:
+                kinds = self.config.layer_kinds()
+                kda.observe(job_id, vectors[first], vectors[second],
+                            [i for i, k in enumerate(kinds) if k == kind],
+                            kind=kind)

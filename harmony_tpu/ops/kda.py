@@ -75,14 +75,15 @@ class Plan(NamedTuple):
     grid_steps: int
 
 
-def tile_plan(bh: int, seq: int) -> Plan:
+def tile_plan(bh: int, seq: int, chunk: int = CHUNK) -> Plan:
     """The kernels' grid for ``bh`` heads (x sequences) of ``seq``
-    positions: one head's chunk of ``CHUNK`` a step (the sequence is padded
-    to whole chunks with positions that change nothing). Several heads a
+    positions: one head's chunk of ``chunk`` a step (padded to whole chunks
+    with positions that change nothing; ops/ssd.py walks the same grid at
+    its own chunk). Several heads a
     step buy nothing: 8 heads x 8,192 positions read 3.10 / 2.95 / 2.84 /
     2.80 ms forward at 1 / 2 / 4 / 8 heads a step (my chip run, PR 31) for
     8 times the code."""
-    return Plan(CHUNK, bh * -(-seq // CHUNK))
+    return Plan(chunk, bh * -(-seq // chunk))
 
 
 def _note_plans(kernels, bh: int, seq: int, dk: int, dv: int) -> None:

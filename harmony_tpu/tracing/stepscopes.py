@@ -54,8 +54,9 @@ VOCABULARY = (
     "embed", "blk", "norm",
     "mixer.qkv", "mixer.rope", "mixer.core", "mixer.out",
     "kda.proj", "kda.conv", "kda.gate", "kda.scan", "kda.out",
+    "ssd.proj", "ssd.conv", "ssd.gate", "ssd.scan", "ssd.out",
     "ffn",
-    "moe.route", "moe.dispatch", "moe.experts", "moe.shared",
+    "moe.route", "moe.latent", "moe.dispatch", "moe.experts", "moe.shared",
     "moe.combine", "moe.aux",
     "head", "loss",
     "fm.interact", "fm.loss",

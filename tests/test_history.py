@@ -75,6 +75,28 @@ class TestStoreRings:
         assert st["evicted_series"] == 2
         assert st["dropped_series"] == 0
 
+    def test_a_saturated_store_scans_once_a_timestamp(self, monkeypatch):
+        """Past the cap every new sample used to rescan all series: a
+        5,146-line exposition (two jobs' 5 x 512 expert counters) cost
+        1,034 scans and 0.6 s under the GIL every scrape period. A
+        scrape's samples share one timestamp, and nothing more can expire
+        at the same instant: one scan, the same drops."""
+        monkeypatch.setattr(hist, "_MAX_SERIES", 4)
+        s = HistoryStore(window_sec=100, resolution_sec=1)
+        scans = []
+        prune = s._prune_locked
+        monkeypatch.setattr(
+            s, "_prune_locked", lambda now: (scans.append(now), prune(now)))
+        t = time.time()
+        for i in range(10):
+            s.ingest("g", {"k": str(i)}, 1.0, ts=t)
+        assert s.stats()["series"] == 4
+        assert s.stats()["dropped_series"] == 6
+        assert len(scans) <= 2  # the periodic one, and one at the cap
+        for i in range(10, 14):  # a later scrape scans again, once
+            s.ingest("g", {"k": str(i)}, 1.0, ts=t + 5)
+        assert len(scans) <= 3 and s.stats()["dropped_series"] == 10
+
 
 class TestQueries:
     def test_label_filtered_range_and_latest(self):

@@ -457,6 +457,11 @@ PARENT_PROGRAMS = {
     # a212a063412dcaa1)
     "moonlight-16b-a3b+chunked": ("a4926d2815adc9a1", "b95e5090d1b8c013"),
     "kimi-linear-48b-a3b+chunked": ("5e16810afaf161cd", "a87befccf5a25f54"),
+    # SmallThinker's own preset, RECORDED ON THE PARENT OF PR 38 (commit
+    # f66bdc2), which added layers of one sublayer, a state-space mixer and
+    # latent ungated experts beside these five tenants' programs
+    "smallthinker-21b-a3b": ("ca2c218290bfadc4", "f29d583db0040dd7"),
+    "smallthinker-21b-a3b+chunked": ("70aaf04974a21872", "cb19e362794a5553"),
 }
 CHUNKED = {"moe_experts": 64, "moe_top_k": 4, "moe_experts_held": 8}
 
