@@ -61,6 +61,14 @@ from perf import rates  # noqa: E402
 #: STATUS walks the ledger under the process's GIL, beside the dispatching
 #: thread: no more than 5 Hz
 POLL_PERIOD_S = 0.2
+#: ... except just where a watched job's next feed is due, from this long
+#: before it (and twice the last stamp's own width: perf/rates.py ``feeds``)
+#: until it is seen — the worker is waiting for the device then —, or until
+#: it is this late: a stall, and the grid again. Three to five more polls a
+#: feed, and a feed's time known to 25 ms where the grid knew it to 200
+FINE_PERIOD_S = 0.025
+FINE_LEAD_S = 0.03
+FINE_GIVE_UP_S = 0.5
 #: the worker drains (and feeds the ledger) once per window of up to 8
 #: epochs (dolphin/worker.py EPOCH_WINDOW); jobs are sized in whole windows
 #: so that every drain stacks the same number of steps — the shape the
@@ -176,8 +184,9 @@ class Cell:
 # ---------------------------------------------------------------------------
 
 class Poller:
-    """STATUS at ``POLL_PERIOD_S``: per job the cumulative counters and
-    phase seconds, each poll stamped on the harness's monotonic clock."""
+    """STATUS at ``POLL_PERIOD_S``, and at ``FINE_PERIOD_S`` where a feed of
+    a job in ``watched`` is due: per job the cumulative counters and phase
+    seconds, each poll stamped on the harness's monotonic clock."""
 
     def __init__(self, server) -> None:
         self.server = server
@@ -189,22 +198,48 @@ class Poller:
         #: job -> {epoch: {worker: wall seconds}}, as last reported
         self.epoch_walls: Dict[str, Dict[str, Dict[str, float]]] = {}
         self.status_seconds: List[float] = []
-        self._next = time.monotonic()
+        #: the jobs whose feeds are met with fine polls
+        self.watched: List[str] = []
+        #: job -> its feeds so far, [(stamp, value, width)] (perf/rates.py)
+        self.feeds: Dict[str, List[Tuple[float, float, float]]] = {}
+        self._next = self._last = time.monotonic()
+
+    def _wake(self) -> float:
+        """When to ask next: at the grid's tick, or sooner where a watched
+        job's feed is due. Its last feed happened no earlier than its stamp
+        less half its width, and the period is no shorter than the least of
+        the last three gaps less the two stamps' half widths."""
+        wake, now = self._next, time.monotonic()
+        for job in self.watched:
+            last = self.feeds.get(job, [])[-4:]
+            if len(last) < 2:
+                continue
+            gap = min(b[0] - a[0] for a, b in zip(last, last[1:]))
+            due = last[-1][0] + gap
+            # feeds as fast as the grid are many, and stay on it
+            if gap < 2.0 * POLL_PERIOD_S or now > due + FINE_GIVE_UP_S:
+                continue
+            start = due - 2.0 * max(last[-1][2], last[-2][2]) - FINE_LEAD_S
+            wake = min(wake, start)
+        return max(wake, self._last + FINE_PERIOD_S)
 
     def poll(self) -> float:
         """Sleep to the next tick, poll once, return the poll's time."""
-        delay = self._next - time.monotonic()
+        delay = self._wake() - time.monotonic()
         if delay > 0:
             time.sleep(delay)
-        t0 = time.monotonic()
+        t0 = self._last = time.monotonic()
         reply = self.server.status(self.client)
         t1 = time.monotonic()
-        self._next = max(self._next + POLL_PERIOD_S, t1)
+        if t0 >= self._next:
+            self._next += POLL_PERIOD_S
+        self._next = max(self._next, t1)
         self.status_seconds.append(t1 - t0)
         t = 0.5 * (t0 + t1)
         for job, row in reply["tenants"].items():
-            self.counters.setdefault(job, []).append(
-                (t, float(row["examples_total"])))
+            polls = self.counters.setdefault(job, [])
+            polls.append((t, float(row["examples_total"])))
+            self.feeds.setdefault(job, []).extend(rates.feeds(polls[-2:]))
         for job, row in reply["phase_budget"].items():
             self.phases.setdefault(job, []).append(
                 (t, float(row["wall_sec"]), dict(row["phases"])))
@@ -344,6 +379,31 @@ def reference_check(cell: Cell, seed: int, warm: Dict[str, Any]
     return ok, rows
 
 
+def work_model_shares(cell: Cell, peaks: Dict[str, float], aggregate: float
+                      ) -> Dict[str, float]:
+    """What the configuration's own arithmetic (perf/work_models.py, by the
+    names under ``job.flops_fn`` / ``job.bytes_fn``) amounts to at the run's
+    ``aggregate`` rate, as shares of the chips' peaks: a line each, traced
+    run or not, and the shares by the lines' names."""
+    from perf import work_models
+
+    app, shares = cell.job["app_params"], {}
+    if cell.job.get("flops_fn"):
+        per_unit = getattr(work_models, cell.job["flops_fn"])(app)
+        shares["model_flops_utilisation"] = (
+            aggregate * per_unit / (peaks["bf16_flops"] * cell.chips))
+        say("model_flops_utilisation", flops_per_unit=per_unit,
+            share_of_peak=shares["model_flops_utilisation"])
+    if cell.job.get("bytes_fn"):
+        per_ex = getattr(work_models, cell.job["bytes_fn"])(app)
+        shares["table_bandwidth"] = (
+            aggregate / float(cell.job["units_per_example"]) * per_ex
+            / (peaks["hbm_bytes_per_s"] * cell.chips))
+        say("table_bandwidth", bytes_per_example=per_ex,
+            share_of_peak=shares["table_bandwidth"])
+    return shares
+
+
 # ---------------------------------------------------------------------------
 # the run
 # ---------------------------------------------------------------------------
@@ -431,7 +491,7 @@ def main(argv=None) -> int:
         # 3. the measured job
         cfgs = [cell.job_config(args.seed, t, "run", sizes[t["name"]]["num_epochs"])
                 for t in cell.tenants]
-        jobs = [c.job_id for c in cfgs]
+        jobs = poller.watched = [c.job_id for c in cfgs]
         window: Dict[str, Any] = {}
         # one trace per cell is kept, inside the checkout (git-ignored)
         trace_dir = os.path.join(ROOT, "chiprun_out", "trace", cell.name)
@@ -500,9 +560,13 @@ def main(argv=None) -> int:
     t0, t1 = window["t0"], window["t1"]
     per_tenant: Dict[str, Dict[str, float]] = {}
     for t, job in zip(cell.tenants, jobs):
-        pts = [p for p in poller.changes(job) if t0 - 1e-9 <= p[0] <= t1]
-        fit = rates.steady(pts, POLL_PERIOD_S)
+        # the feeds seen by a poll inside the window (stamp + half width)
+        seen = [f for f in poller.feeds.get(job, [])
+                if t0 - 1e-9 <= f[0] + 0.5 * f[2] <= t1]
+        fit = rates.steady([f[:2] for f in seen], POLL_PERIOD_S,
+                           [f[2] for f in seen])
         if fit is not None:
+            fit["fine"] = sum(f[2] <= 2.0 * FINE_PERIOD_S for f in seen)
             per_tenant[t["name"]] = fit
     # a measured job that ended inside the window was sized too short
     ended_early = [j for j in jobs if done_at[j] < t1]
@@ -545,21 +609,8 @@ def main(argv=None) -> int:
         losses={j: (None if worker_result(r) is None else
                     [worker_result(r)["losses"][0], worker_result(r)["losses"][-1]])
                 for j, r in results.items()})
-    if peaks is not None and tenant_rates:
-        from perf import work_models
-
-        app = cell.job["app_params"]
-        if cell.job.get("flops_fn"):
-            per_unit = getattr(work_models, cell.job["flops_fn"])(app)
-            say("model_flops_utilisation",
-                flops_per_unit=per_unit,
-                share_of_peak=(aggregate * per_unit
-                               / (peaks["bf16_flops"] * cell.chips)))
-        if cell.job.get("bytes_fn"):
-            per_ex = getattr(work_models, cell.job["bytes_fn"])(app)
-            say("table_bandwidth", bytes_per_example=per_ex,
-                share_of_peak=(aggregate / units * per_ex
-                               / (peaks["hbm_bytes_per_s"] * cell.chips)))
+    shares = (work_model_shares(cell, peaks, aggregate)
+              if peaks is not None and tenant_rates else {})
 
     device: Dict[str, Any] = {"platform": platform, "kind": kind,
                               "count": len(devices),
@@ -581,6 +632,7 @@ def main(argv=None) -> int:
             "trace": reduction,
             "memory_peak_bytes": peak_bytes,
             "hbm_bytes": None if peaks is None else peaks["hbm_bytes"],
+            "model_flops_share": shares.get("model_flops_utilisation"),
         }
         say("progcache", costs_s=sum(c.get("compile_seconds") or 0.0
                                      for c in progcache.program_costs()),

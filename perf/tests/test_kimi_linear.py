@@ -175,15 +175,17 @@ def test_the_cell_and_its_metrics_are_in_the_benchmark():
             "peak_hbm_share"} <= mine
     for name in ("kda_time_share", "kda_roofline_share"):
         entry = next(m for m in BENCH["per_layer"] if m["name"] == name)
-        assert entry["workloads"] == [CELL]
+        assert CELL in entry["workloads"]
         assert (entry["moves"], entry["layer"], entry["unit"]) == (
             "lm_tokens_per_s", "kernels", "%")
         assert os.path.exists(os.path.join(PERF, "layer_metrics", name + ".py"))
     rate = next(m for m in BENCH["end_to_end"] if m["name"] == "lm_tokens_per_s")
-    assert rate["workloads"][-1] == CELL
-    # one of seven cells takes four chips: the share stays under a quarter
+    # appended after Moonlight's; a later PR appends after it, so no "last"
+    assert rate["workloads"].index(CELL) \
+        > rate["workloads"].index("moonlight-16b-a3b.solo")
+    # of seven cells or more, those on four chips stay under a quarter
     four = sum(w["chips"] == 4 for w in BENCH["workloads"])
-    assert len(BENCH["workloads"]) == 7 and four == 1
+    assert len(BENCH["workloads"]) >= 7 and four >= 1
     assert four <= max(1, len(BENCH["workloads"]) // 4)
     assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) < 64 * 1024
 

@@ -135,10 +135,12 @@ def test_the_cell_and_its_metrics_are_in_the_benchmark():
     for name in ("flash_time_share", "flash_roofline_share",
                  "routed_gmm_roofline_share"):
         entry = next(m for m in BENCH["per_layer"] if m["name"] == name)
-        assert entry["workloads"] == [CELL]
+        # first of its cells; later configurations append theirs
+        assert entry["workloads"][0] == CELL
         assert entry["moves"] == "lm_tokens_per_s"
-    assert sum(w["chips"] == 4 for w in BENCH["workloads"]) == 1
-    assert len(BENCH["workloads"]) == 6
+    four = sum(w["chips"] == 4 for w in BENCH["workloads"])
+    assert len(BENCH["workloads"]) >= 6
+    assert 1 <= four <= max(1, len(BENCH["workloads"]) // 4)
 
 
 def test_flash_readers_fold_events_by_kernel_name():

@@ -228,6 +228,70 @@ def test_rate_over_the_regular_stretches(late_feed):
     assert rates.steady(fast)["stalls"] > 0
 
 
+def test_feeds_carry_their_widths_and_a_coarse_end_point_weighs_little():
+    """A feed's stamp is the middle of the two polls it fell between; with
+    the widths given, one feed caught late on the coarse grid (a job's
+    first) no longer tilts the line."""
+    polls = [(0.0, 0.0), (0.2, 0.0), (0.4, 5.0), (0.425, 5.0), (0.45, 9.0)]
+    got = rates.feeds(polls)
+    assert [(round(t, 4), v, round(w, 4)) for t, v, w in got] == [
+        (0.3, 5.0, 0.2), (0.4375, 9.0, 0.025)]
+    feed, rate = 0.809, 39.5
+    # every feed stamped to 25 ms, the first seen 0.2 s late
+    pts = [(feed * i + (0.19 if i == 0 else 0.0), rate * feed * i)
+           for i in range(25)]
+    widths = [0.2] + [0.025] * 24
+    plain = rates.steady(pts, 0.2)
+    weighed = rates.steady(pts, 0.2, widths)
+    assert abs(plain["rate"] / rate - 1) > 1e-3
+    assert abs(weighed["rate"] / rate - 1) < 5e-5
+    assert weighed["n"] == 25 and weighed["stalls"] == 0
+    # equal widths are no weights at all
+    even = rates.steady(pts, 0.2, [0.05] * 25)
+    assert abs(even["rate"] / plain["rate"] - 1) < 1e-12
+
+
+@pytest.mark.parametrize("feed", [0.809, 1.0])
+def test_poller_asks_often_where_a_feed_is_due(feed):
+    """gpt2-124m.pair's 0.809 s and OLMoE's 1.0 s between feeds, the two on
+    which the 0.2 s grid read two levels 1% apart (PR 41): after a job's
+    first two feeds every one is caught between polls 25 ms apart, with a
+    few polls a feed more, and the rate over 5 s is within 0.3% where a
+    span of whole polls is off by up to 4%."""
+    import time
+
+    from perf import run
+
+    class Fake:
+        def __init__(self):
+            self.t0, self.asked = time.monotonic() + 0.13, 0
+
+        def client(self):
+            return None
+
+        def status(self, _client):
+            self.asked += 1
+            k = max(0, int((time.monotonic() - self.t0) / feed))
+            return {"tenants": {"j": {"examples_total": 32.0 * k}},
+                    "phase_budget": {}}
+
+    server = Fake()
+    poller = run.Poller(server)
+    poller.watched = ["j"]
+    start = time.monotonic()
+    while time.monotonic() < start + 0.13 + 7.6 * feed:
+        poller.poll()
+    feeds = poller.feeds["j"]
+    assert len(feeds) == 7
+    assert [w > 0.1 for _, _, w in feeds[:2]] == [True, True]
+    assert all(w < 2 * run.FINE_PERIOD_S for _, _, w in feeds[2:])
+    grid = (time.monotonic() - start) / run.POLL_PERIOD_S
+    assert grid - 2 < server.asked < grid + 10 * len(feeds)
+    fit = rates.steady([f[:2] for f in feeds], run.POLL_PERIOD_S,
+                       [f[2] for f in feeds])
+    assert abs(fit["rate"] * feed / 32.0 - 1) < 0.003
+
+
 # -- trace reduction ------------------------------------------------------------
 
 def test_union_and_classification():

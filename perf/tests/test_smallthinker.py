@@ -188,9 +188,11 @@ def test_the_cell_and_its_metrics_are_in_the_benchmark():
         assert (reader.LAYER, reader.UNIT, reader.SOURCE) == (
             "kernels", "%", source)
     rate = next(m for m in BENCH["end_to_end"] if m["name"] == "lm_tokens_per_s")
-    assert rate["workloads"][-1] == CELL
+    # appended after Kimi Linear's; a later PR appends after it, so no "last"
+    assert rate["workloads"].index(CELL) \
+        > rate["workloads"].index("kimi-linear-48b-a3b.solo")
     four = sum(w["chips"] == 4 for w in BENCH["workloads"])
-    assert len(BENCH["workloads"]) >= 8 and four == 1
+    assert len(BENCH["workloads"]) >= 8 and four >= 1
     assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) < 64 * 1024
 
 

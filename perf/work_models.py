@@ -1,25 +1,232 @@
 """Operations and bytes a step needs, computed from the configuration's
 shapes — the yardstick's own arithmetic, found by the name a configuration
-file gives under ``job.flops_fn`` / ``job.bytes_fn``."""
+file gives under ``job.flops_fn`` / ``job.bytes_fn``. ``perf/run.py`` prints
+each at the run's rate as a share of the chips' peak (``model_flops_
+utilisation``, ``table_bandwidth``); ``step_mfu_share`` is the first, x 100.
+
+``lm_train_flops_per_token``: operations one token of a WHOLE training step
+of the LM tenants NEEDS, from ``job.app_params`` alone. All LM configurations
+are one model class told apart by keys of ``app_params``; this file reads
+those keys and nothing of the program: no ``TransformerConfig``, no span, no
+counter. A key it does not know (another ``attn_kind`` or ``ffn``, a new
+``*_layers`` list, another letter of ``layer_pattern``) raises: a model it
+cannot count reports no share, never a plausible one.
+
+The rules (the ``choosing-metrics`` guide: "the operations the forward and
+backward passes require, not counting recomputed operations"):
+
+**6 x the matmul parameters a token passes through** (a multiply-add as 2
+FLOPs; forward 2, backward 4), from the shapes:
+
+  softmax attention   ``d (H hd + 2 Hkv hd) + H hd d`` — ``hd`` =
+    ``mha_head_dim`` or ``d / n_heads``, ``Hkv`` = ``n_kv_heads`` or ``H``
+  latent attention    ``d H (nope + rope) + d (r + rope) + r H (nope + v)
+                      + H v d``
+  KDA                 q, k, v ``3 d H dh``, the decay's and the output gate's
+    low-rank pairs ``2 (d dh + dh H dh)``, beta ``d H``, out ``H dh d``
+  Mamba-2             in ``d (2 H P + 2 G N + H)``, out ``H P d``
+  dense MLP           ``3 d f`` gated (``ffn="swiglu"``), ``2 d f`` not; ``f``
+    = ``dense_d_ff`` in the ``moe_first_dense`` leading layers
+  expert layer        the router ``d E`` (its full width, every token), the
+    latent's pair ``2 d r`` (``moe_latent``), the shared MLP at
+    ``moe_shared_d_ff`` or ``moe_shared_experts`` x ``d_ff`` columns, and the
+    ROUTED experts (below); ungated (``moe_gated`` false) 2 matrices for 3
+  readout             ``d V``, the rows held (tied or not, the matmul is done)
+
+Embedding lookups, norms, biases, the short convolutions, the optimizer and
+the table path count nothing: they are no matmul, and the last two are not
+the model.
+
+**Routed experts at what this chip holds under UNIFORM routing**:
+``moe_top_k x moe_experts_held / moe_experts`` expert passes a token — from the
+configuration, not from the program's counters: the metric has to be there
+whatever a PR does to the program's spans and counters. It is NOMINAL in the
+expert cells by ``held token-slots counted / expected`` (the router as
+initialised leans; ``expert_load_max_over_mean`` and the ``gmm`` rooflines
+read the rows that were really held).
+
+**Attention pairs a token really needs**: a causal layer ``s^2 / 2`` pairs a
+head and sequence (the diagonal at half, the convention the GPT-2 count has
+always had: 6 L s d), a windowed one the band ``0 <= i - j < W``
+(``perf/work/smallthinker.py`` ``band_pairs``, asked there) with the diagonal
+at half too, so ``W >= s`` counts what a full layer does. A pair costs
+``2 (d_qk + d_v)`` forward and twice that backward: flash attention's
+recomputed scores are the kernel's cost, not the need.
+
+**The chunked scans** (KDA, Mamba-2) at the FORWARD call's FLOPs of
+``perf/work/kimi_linear.py`` ``kda_flops_per_call`` / ``perf/work/
+nemotron_h.py`` ``ssd_flops_per_call`` (asked there, not copied), x 3: two
+backward products for each forward one. Those files credit a backward CALL
+3 x the forward because the kernel recomputes its chunk; recomputation counts
+nothing here, as ``remat`` counts nothing.
+"""
 from __future__ import annotations
 
-from typing import Any, Dict
+import functools
+from typing import Any, Dict, List
+
+#: the parts ``lm_train_flops_split`` returns, FLOPs a token forward + backward
+PARTS = ("dense", "routed", "attention_pairs", "scans", "readout")
+#: what tells the LM configurations' layers apart, as far as this file knows
+LAYER_LISTS = ("n_layers", "linear_layers", "window_layers")
+ATTN_KINDS, FFNS = ("mha", "mla"), ("gelu", "swiglu")
+
+
+@functools.lru_cache(maxsize=None)
+def sibling(name: str):
+    """``perf/work/<name>.py``, loaded as the harness loads it (once)."""
+    from perf.run import load_by_path
+
+    return load_by_path("work", name)
+
+
+def layer_kinds(app: Dict[str, Any]) -> List[Dict[str, str]]:
+    """Each layer as ``{"mixer": kind or "", "ffn": kind or ""}`` from the
+    configuration's keys: a ``layer_pattern`` layer is ONE sublayer (``M``
+    ssd, ``*`` full attention, ``E`` experts); every other model's block is a
+    mixer (``kda`` in ``linear_layers``, else ``mla`` / ``swa`` in
+    ``window_layers`` / ``full``) and a feed-forward part (``moe`` past the
+    ``moe_first_dense`` leading layers, the last of every ``moe_every``;
+    else ``dense``)."""
+    unknown = [k for k in app if k.endswith("_layers") and k not in LAYER_LISTS]
+    attn, ffn = str(app.get("attn_kind", "mha")), str(app.get("ffn", "gelu"))
+    if unknown or attn not in ATTN_KINDS or ffn not in FFNS:
+        raise ValueError(f"not counted here: {unknown} attn_kind {attn!r} "
+                         f"ffn {ffn!r}")
+    n = int(app["n_layers"])
+    pattern = str(app.get("layer_pattern") or "")
+    if pattern:
+        if len(pattern) != n or set(pattern) - set("ME*"):
+            raise ValueError(f"layer_pattern {pattern!r} for {n} layers")
+        return [{"M": {"mixer": "ssd", "ffn": ""},
+                 "*": {"mixer": "full", "ffn": ""},
+                 "E": {"mixer": "", "ffn": "moe"}}[c] for c in pattern]
+    linear = {int(i) for i in app.get("linear_layers") or ()}
+    window = {int(i) for i in app.get("window_layers") or ()}
+    every = int(app.get("moe_every", 2))
+    first = int(app.get("moe_first_dense", 0))
+    out = []
+    for i in range(n):
+        if i in linear:
+            mixer = "kda"
+        elif attn == "mla":
+            mixer = "mla"
+        else:
+            mixer = "swa" if i in window else "full"
+        moe = (int(app.get("moe_experts", 0)) > 0 and i >= first
+               and i % every == every - 1)
+        out.append({"mixer": mixer, "ffn": "moe" if moe else "dense"})
+    return out
+
+
+def _heads(app: Dict[str, Any]):
+    h = int(app["n_heads"])
+    return (h, int(app.get("n_kv_heads") or h),
+            int(app.get("mha_head_dim") or int(app["d_model"]) // h))
+
+
+def mixer_params(app: Dict[str, Any], kind: str) -> int:
+    """Matmul parameters a token passes through in one mixer of ``kind``."""
+    d = int(app["d_model"])
+    if kind in ("full", "swa"):
+        h, hkv, hd = _heads(app)
+        return d * (h * hd + 2 * hkv * hd) + h * hd * d
+    if kind == "mla":
+        h, r = int(app["n_heads"]), int(app["kv_lora_rank"])
+        nope, rope = int(app["qk_nope_head_dim"]), int(app["qk_rope_head_dim"])
+        v = int(app["v_head_dim"])
+        return d * h * (nope + rope) + d * (r + rope) + r * h * (nope + v) \
+            + h * v * d
+    if kind == "kda":
+        h, dh = int(app["linear_heads"]), int(app["linear_head_dim"])
+        return 3 * d * h * dh + 2 * (d * dh + dh * h * dh) + d * h + h * dh * d
+    if kind == "ssd":
+        inner = int(app["ssd_heads"]) * int(app["ssd_head_dim"])
+        proj = 2 * inner + 2 * int(app["ssd_groups"]) * int(app["ssd_state"]) \
+            + int(app["ssd_heads"])
+        return d * proj + inner * d
+    if kind:
+        raise ValueError(f"no mixer {kind!r}")
+    return 0
+
+
+def ffn_params(app: Dict[str, Any], kind: str, layer: int) -> Dict[str, float]:
+    """``{"dense", "routed"}`` matmul parameters a token passes through in
+    one feed-forward part: the routed experts at the held share of uniform
+    routing, everything else of the part under ``dense``."""
+    d, f = int(app["d_model"]), int(app["d_ff"])
+    if kind == "dense":
+        if layer < int(app.get("moe_first_dense", 0)):
+            f = int(app.get("dense_d_ff") or f)
+        gated = str(app.get("ffn", "gelu")) == "swiglu"
+        return {"dense": (3 if gated else 2) * d * f, "routed": 0.0}
+    if kind != "moe":
+        if kind:
+            raise ValueError(f"no feed-forward part {kind!r}")
+        return {"dense": 0, "routed": 0.0}
+    experts, top_k = int(app["moe_experts"]), int(app.get("moe_top_k", 0))
+    if not top_k:  # the program's Switch path: no cell runs it
+        raise ValueError("experts without moe_top_k are not counted here")
+    mats = 3 if app.get("moe_gated", True) else 2
+    r = int(app.get("moe_latent") or d)
+    held = app.get("moe_experts_held")
+    held = experts if held is None else int(held)
+    dense = d * experts + (2 * d * r if app.get("moe_latent") else 0)
+    if app.get("moe_shared_experts"):
+        fs = int(app.get("moe_shared_d_ff")
+                 or int(app["moe_shared_experts"]) * f)
+        dense += mats * d * fs
+    return {"dense": dense, "routed": top_k * held / experts * mats * r * f}
+
+
+def attention_flops(app: Dict[str, Any], kind: str) -> float:
+    """Forward FLOPs a token of one softmax-attention layer's pairs."""
+    s = int(app["max_seq"])
+    if kind == "mla":
+        h = int(app["n_heads"])
+        dqk = int(app["qk_nope_head_dim"]) + int(app["qk_rope_head_dim"])
+        dv = int(app["v_head_dim"])
+    elif kind in ("full", "swa"):
+        h, _, dqk = _heads(app)
+        dv = dqk
+    else:
+        return 0.0
+    # twice the pairs a head and sequence, the diagonal at half
+    band = sibling("smallthinker").band_pairs
+    pairs2 = 2 * band(s, app["window"] if kind == "swa" else s) - s
+    return (dqk + dv) * h * pairs2 / s
+
+
+def scan_flops(app: Dict[str, Any], kind: str) -> float:
+    """Forward FLOPs a token of one chunked-scan layer's recurrence: one
+    sequence's forward call over its positions."""
+    if kind == "kda":
+        call = sibling("kimi_linear").kda_flops_per_call(app, 1, "harmony_kda_fwd")
+    elif kind == "ssd":
+        call = sibling("nemotron_h").ssd_flops_per_call(app, 1, "harmony_ssd_fwd")
+    else:
+        return 0.0
+    return call / int(app["max_seq"])
+
+
+def lm_train_flops_split(app: Dict[str, Any]) -> Dict[str, float]:
+    """FLOPs one token needs forward + backward, by ``PARTS``."""
+    out = dict.fromkeys(PARTS, 0.0)
+    for i, layer in enumerate(layer_kinds(app)):
+        ffn = ffn_params(app, layer["ffn"], i)
+        out["dense"] += 6.0 * (mixer_params(app, layer["mixer"]) + ffn["dense"])
+        out["routed"] += 6.0 * ffn["routed"]
+        out["attention_pairs"] += 3.0 * attention_flops(app, layer["mixer"])
+        out["scans"] += 3.0 * scan_flops(app, layer["mixer"])
+    out["readout"] = 6.0 * int(app["d_model"]) * int(app["vocab_size"])
+    return out
 
 
 def lm_train_flops_per_token(app: Dict[str, Any]) -> float:
-    """Forward + backward FLOPs one token of a dense decoder needs:
-    6 x (matmul parameters) + the causal attention term. Matmul parameters
-    are the 12 d^2 of each block (qkv 3, out 1, ffn 8 at d_ff = 4 d — taken
-    from the shapes, not assumed) and the d x V readout (tied, but the
-    readout matmul is still done); the embedding lookup is not a matmul.
-    Attention scores and values cost 4 s d a token forward over all s keys;
-    a causal mask needs half of them, so forward + backward need 6 L s d,
-    not the 12 L s d of the unmasked convention. Recomputation counts
-    nothing."""
-    d, L, s = app["d_model"], app["n_layers"], app["max_seq"]
-    block = 4 * d * d + 2 * d * app["d_ff"]
-    matmul_params = L * block + d * app["vocab_size"]
-    return 6.0 * matmul_params + 6.0 * L * s * d
+    """Forward + backward FLOPs one token of an LM configuration needs (module
+    docstring; 797,815,296.0 for ``gpt2-124m``, as the count of its block
+    alone always gave)."""
+    return float(sum(lm_train_flops_split(app).values()))
 
 
 def keyed_table_bytes_per_example(app: Dict[str, Any]) -> float:
