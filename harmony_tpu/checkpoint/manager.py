@@ -460,10 +460,11 @@ class CheckpointInfo:
     sampling_ratio: float
     committed: bool
     created_at: float
-    #: application-level tag (e.g. the chain's {"epoch": N}) — optional,
+    #: application-level tag (e.g. the chain's {"epoch": N, "layout":
+    #: name}: numbers and short strings, whatever JSON holds) — optional,
     #: absent in older manifests; the resume path derives the restart
     #: epoch from it instead of guessing from id counters
-    app_meta: Optional[Dict[str, float]] = None
+    app_meta: Optional[Dict[str, Any]] = None
     #: per-block content checksums (str(block_id) -> CRC32 of the block's
     #: logical bytes — JSON keys are strings). Optional: absent in older
     #: manifests; restore verifies blocks only when present
@@ -609,7 +610,7 @@ class CheckpointManager:
     # -- write path ------------------------------------------------------
 
     def _snapshot(self, handle: TableHandle, sampling_ratio: float,
-                  app_meta: Optional[Dict[str, float]] = None):
+                  app_meta: Optional[Dict[str, Any]] = None):
         """The synchronous prefix shared by sync and async checkpointing:
         id allocation + an atomic device-side snapshot (O(dispatch); the
         table lock is held for microseconds)."""
@@ -701,7 +702,7 @@ class CheckpointManager:
         handle: TableHandle,
         sampling_ratio: float = 1.0,
         commit: bool = False,
-        app_meta: Optional[Dict[str, float]] = None,
+        app_meta: Optional[Dict[str, Any]] = None,
     ) -> str:
         """Stage blocks to temp storage; optionally commit immediately.
         Returns the checkpoint id (``tableId-seq-timestamp``, mirroring the
@@ -733,7 +734,7 @@ class CheckpointManager:
 
     def _pod_checkpoint(
         self, handle: TableHandle, sampling_ratio: float, commit: bool,
-        app_meta: Optional[Dict[str, float]] = None,
+        app_meta: Optional[Dict[str, Any]] = None,
     ) -> str:
         """Pod-mode two-stage checkpoint (ref: ChkpManagerSlave.java:50-63
         staging per-executor local files + ChkpManagerMaster.java:49-61
@@ -904,7 +905,7 @@ class CheckpointManager:
         handle: TableHandle,
         sampling_ratio: float = 1.0,
         commit: bool = False,
-        app_meta: Optional[Dict[str, float]] = None,
+        app_meta: Optional[Dict[str, Any]] = None,
     ) -> "PendingCheckpoint":
         """Non-blocking checkpoint: the device-side snapshot is taken NOW
         (atomic w.r.t. training steps), the D2H transfer and file IO run on

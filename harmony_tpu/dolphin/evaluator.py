@@ -44,11 +44,16 @@ class ModelChkpManager:
         handle: TableHandle,
         period: int = 1,
         commit: bool = True,
+        layout: Optional[str] = None,
     ) -> None:
         self._mgr = chkp_manager
         self._handle = handle
         self._period = max(1, period)
         self._commit = commit
+        #: how the trainer lays its model out in the table's rows, by name
+        #: (``PyTreeTrainer.table_layout``): every entry records it, and a
+        #: restore converts or refuses a chain that names another
+        self._layout = layout
         self.chkp_ids: List[str] = []
         self._pending: List[PendingCheckpoint] = []
 
@@ -60,6 +65,8 @@ class ModelChkpManager:
         from harmony_tpu.parallel.mesh import mesh_spans_processes
 
         meta = {"epoch": float(epoch_idx)}  # the resume path's restart key
+        if self._layout is not None:
+            meta["layout"] = self._layout
         if mesh_spans_processes(self._handle.table.mesh):
             # Pod: the checkpoint is a synchronous mesh collective (every
             # process's chief worker reaches this hook at the same point in

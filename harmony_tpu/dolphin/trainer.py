@@ -206,9 +206,10 @@ class Trainer:
         for a model table of ``capacity`` rows: ``(rows, sections,
         gradient, push_update)``. The model's first ``sections * rows``
         rows are ``sections`` sections of state that update together, row
-        by row, the parameters first. ``gradient(model[:rows], batch) ->
-        (g, metrics)`` is the step's COMP: it needs the parameter section
-        alone. ``push_update(spec, arr, model, g, hyper) -> new_arr`` is
+        by row, the parameters first. ``gradient(model, batch) -> (g,
+        metrics)`` is the step's COMP: of the pulled table it reads the
+        parameter section alone, and ``g`` is ``rows`` rows.
+        ``push_update(spec, arr, model, g, hyper) -> new_arr`` is
         the step's PUSH: the rule folded into the table ``arr`` where the
         rows lie (``TableSpec.fold_row_sections`` / ``push_row_ranges``;
         ``model`` is the pulled table). ``compute`` stays, the same

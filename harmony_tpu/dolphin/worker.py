@@ -144,16 +144,15 @@ def pull_all_step(spec, trainer, mesh: Mesh):
                 return spec.push_all(arr, delta), metrics          # PUSH
 
         return _step
-    rows, _, gradient, push_update = trainer.row_update_parts(
+    _, _, gradient, push_update = trainer.row_update_parts(
         spec.config.capacity)
 
     def _step(arr, batch, hyper):
         with step_scope("table.pull"):
             model = _phase_boundary(spec.pull_all(arr),
                                     replicate_on=mesh)             # PULL
-            params = model[:rows]
         with step_scope("compute"):
-            g, metrics = _phase_boundary(gradient(params, batch),
+            g, metrics = _phase_boundary(gradient(model, batch),
                                          replicate_on=mesh)        # COMP
         with step_scope("table.push"):
             return push_update(spec, arr, model, g, hyper), metrics  # PUSH

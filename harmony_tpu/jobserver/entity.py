@@ -115,6 +115,10 @@ class DolphinJobEntity(JobEntity):
         #: elastic recovery accounting (restore stats + shrink plan) —
         #: set by _restore_elastic, surfaced in the job result
         self._elastic_restore: Optional[Dict[str, Any]] = None
+        #: the manifest of the chain entry the model table was restored
+        #: from (``_restore_chain`` / ``_restore_elastic``); it names the
+        #: layout the entry holds (``_in_trainer_layout``)
+        self._restored_entry = None
 
     # -- setup -----------------------------------------------------------
 
@@ -229,6 +233,12 @@ class DolphinJobEntity(JobEntity):
         cfg = self.config
         data_axis = max(1, cfg.user.get("data_axis", 1))
         probe = self._make_trainer()  # one probe serves all schema queries
+        #: the name of the layout the trainer keeps its model in, which
+        #: every chain entry of this job records beside its epoch and a
+        #: restore compares (``PyTreeTrainer.table_layout``; None: the
+        #: table's rows are keys, there is nothing to lay out)
+        self._table_layout = getattr(probe, "table_layout", None)
+        self._restored_entry = None
         if cfg.tables:
             # Explicit table id => shared-table semantics: reuse if it exists
             # (the reference reuses same-id tables across jobs,
@@ -276,6 +286,9 @@ class DolphinJobEntity(JobEntity):
                 table_id=f"{cfg.job_id}:{table_cfg.table_id}"
             )
             self._handle = master.create_table(table_cfg, executor_ids, data_axis)
+        if self._restored_entry is not None:  # either restore above
+            self._handle = self._in_trainer_layout(
+                master, self._handle, probe, executor_ids, data_axis)
         self._note_table_layout(probe)
         self._trainer_factory = lambda: (
             resolve_symbol(cfg.trainer)(**cfg.params.app_params)
@@ -300,11 +313,63 @@ class DolphinJobEntity(JobEntity):
         spec = getattr(self._handle.table, "spec", None)
         if isinstance(spec, TableSpec):  # hash tables have no block rows
             stride_of = getattr(probe, "section_stride", None)
+            leaf_rows = getattr(probe, "leaf_rows", None)
             table_layout.note(
                 self.config.job_id, spec,
-                stride_of(spec.config.capacity) if stride_of else None)
+                stride_of(spec.config.capacity) if stride_of else None,
+                leaf_rows.record() if leaf_rows is not None else None)
 
     # -- run (the DolphinMaster.start analogue) --------------------------
+
+    def _in_trainer_layout(self, master: ETMaster, handle: TableHandle,
+                           probe, executor_ids: List[str],
+                           data_axis: int) -> TableHandle:
+        """The table just restored from a chain entry
+        (``self._restored_entry``, its manifest), in the layout ``probe``
+        trains on. An entry records its layout's name beside its
+        epoch (``app_meta["layout"]``); one written before the leaves were
+        row ranges records none and holds them raveled end to end — row
+        counts can coincide while offsets differ, so the name decides,
+        never the capacity. Such a table is converted once, here, on the
+        host (``PyTreeTrainer.rows_from_flat_chain``: p, m and v alike)
+        into a fresh table of the trainer's own schema; a name the
+        trainer does not know is refused with both named."""
+        info, mine = self._restored_entry, self._table_layout
+        theirs = (info.app_meta or {}).get("layout")
+        if mine is None or theirs == mine:
+            return handle
+        from harmony_tpu.models.pytree_trainer import FLAT
+        from harmony_tpu.parallel.mesh import mesh_spans_processes
+
+        why = None
+        if theirs not in (None, FLAT):
+            why = "the trainer does not know that layout"
+        elif mesh_spans_processes(handle.table.mesh):
+            why = ("the conversion from " + repr(FLAT) + " runs on one "
+                   "host: resume the chain on a single process once")
+        if why:
+            raise ValueError(
+                f"job {self.config.job_id}: chain entry {info.chkp_id} holds "
+                f"its model table in layout {theirs or FLAT!r}, the trainer "
+                f"{type(probe).__name__} reads {mine!r}: {why}")
+        from harmony_tpu.jobserver.joblog import job_logger
+
+        rows = probe.rows_from_flat_chain(
+            np.asarray(handle.table.pull_array()))
+        cfg = probe.model_table_config(table_id=handle.table_id)
+        handle.drop()
+        handle = master.create_table(cfg, executor_ids, data_axis)
+        stride = probe.section_stride(cfg.capacity)
+        for first in range(0, rows.shape[0], stride):  # a section a put:
+            # the put holds the table twice and its rows once
+            part = rows[first:first + stride]
+            handle.table.multi_put(
+                list(range(first, first + part.shape[0])), part)
+        job_logger(self.config.job_id).info(
+            "chain entry %s converted from layout %r to %r (%d -> %d rows)",
+            info.chkp_id, FLAT, mine, info.table_config.capacity,
+            cfg.capacity)
+        return handle
 
     def _restore_chain(self, master: ETMaster, executor_ids: List[str],
                        data_axis: int):
@@ -345,6 +410,7 @@ class DolphinJobEntity(JobEntity):
                 failures.append((info.chkp_id, f"{type(e).__name__}: {e}"))
                 mgr.quarantine(info.chkp_id)
                 continue
+            self._restored_entry = info
             return handle, int(info.app_meta["epoch"]) + 1, base
         raise ValueError(
             f"job {cfg.job_id}: every chain checkpoint failed integrity "
@@ -480,6 +546,7 @@ class DolphinJobEntity(JobEntity):
                 recovery=self._elastic_restore["kind"],
                 **{k: v for k, v in self._elastic_restore.items()
                    if k not in ("executors", "kind")})
+            self._restored_entry = info
             return handle, int(info.app_meta["epoch"]) + 1, base
         raise ValueError(
             f"job {cfg.job_id}: every chain checkpoint failed integrity "
@@ -581,7 +648,8 @@ class DolphinJobEntity(JobEntity):
                 # monotonic across the restart
                 self._chkp_mgr.advance_counter(self._chkp_counter_base)
             self._chkp_chain = ModelChkpManager(
-                self._chkp_mgr, self._handle, period=params.model_chkp_period
+                self._chkp_mgr, self._handle, period=params.model_chkp_period,
+                layout=self._table_layout,
             )
             epoch_hook = self._chkp_chain.on_epoch
         tm_hook = self._make_table_metrics_hook()

@@ -19,7 +19,16 @@ created or restored (jobserver/entity.py):
     tail_rows, section_stride, rows, tile_exact}`` (a row of the tenant
     ledger, metrics/accounting.py ``set_table_layout``); ``section_stride``
     is the trainer's where it has one (``PyTreeTrainer.section_stride``:
-    rows between the ``[params | m | v]`` sections), else ``None``.
+    rows between the ``[params | m | v]`` sections), else ``None``;
+  * STATUS ``tenants.<job>.table_layout.leaf_layout`` = ``{leaves,
+    leaf_copies, leaf_bitcasts, pad_rows, rows}`` and the gauge
+    ``harmony_table_leaf_pad_share{job,table}`` (``pad_rows / rows``), for
+    a trainer whose model lies in its table leaf by leaf
+    (``PyTreeTrainer.leaf_rows``: every leaf a range of whole 8-row tiles
+    of a section): the leaves, how many of them a step relays out — one
+    copy each a direction — and how many ARE their rows (a last dimension
+    of ``row_width``), and the rows of a section that hold no parameter,
+    the price of leaf-aligned rows (PERF.md §6, PR 42).
 
 And what a keyed tenant's push lowers to, recorded where its step program is
 built (dolphin/worker.py ``_build_step``; ``TableSpec.push_lowering``):
@@ -63,10 +72,12 @@ def _family():
         ("job", "table"))
 
 
-def note(job: str, spec, section_stride: Optional[int] = None
-         ) -> Dict[str, Any]:
+def note(job: str, spec, section_stride: Optional[int] = None,
+         leaf_layout: Optional[Dict[str, int]] = None) -> Dict[str, Any]:
     """Record the storage layout of ``spec`` (a dense ``TableSpec``) as
-    ``job``'s model table; returns the STATUS row."""
+    ``job``'s model table — and, where the trainer has one, where its
+    leaves lie in a section (``LeafRows.record()``); returns the STATUS
+    row."""
     from harmony_tpu.metrics.accounting import ledger
     from harmony_tpu.metrics.registry import get_registry
 
@@ -83,6 +94,15 @@ def note(job: str, spec, section_stride: Optional[int] = None
         "ownership map grows with it)",
         ("job", "table")).labels(
             job=job, table=spec.config.table_id).set(spec.num_blocks)
+    if leaf_layout is not None:
+        row["leaf_layout"] = dict(leaf_layout)
+        get_registry().gauge(
+            "harmony_table_leaf_pad_share",
+            "Rows of a model table's section that hold no parameter / its "
+            "rows: what giving every leaf whole row tiles costs",
+            ("job", "table")).labels(
+                job=job, table=spec.config.table_id).set(
+                    leaf_layout["pad_rows"] / max(leaf_layout["rows"], 1))
     ledger().set_table_layout(job, row)
     return row
 

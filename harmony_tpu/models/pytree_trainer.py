@@ -2,7 +2,7 @@
 
 Any model exposing ``init(key) -> params`` and a pure loss over a batch
 trains through the framework's elastic-table substrate with this one
-Trainer: the flattened params pytree lives in a range-partitioned
+Trainer: the params pytree lives, leaf by leaf, in a range-partitioned
 DenseTable (rows of ``row_width`` f32), pull="all" re-assembles it each
 batch, and the push folds the update through the table's additive fold —
 so checkpointing, live migration and multi-tenancy apply to ANY model
@@ -19,23 +19,160 @@ mechanism at all; its trainers are plain SGD).
 The table's storage IS the row matrix: every section starts on a multiple
 of 8 rows, the counter has an 8-row block of its own, and blocks are whole
 (8, 128) tiles with no tail block, so ``pull_all``'s reshape is a bitcast
-and ``push_all`` pads nothing. A step changes layout twice, where the model
-needs it (parameters rows -> leaves, gradients leaves -> rows); the
-optimizer runs on row sections. Pad rows and pad lanes hold zeros and stay
-zero under every optimizer (g = m = v = 0 -> update 0).
+and ``push_all`` pads nothing.
+
+Inside a section a leaf IS a row range (``LeafRows``, the layout
+``"leaf_rows"``): leaf i, raveled, starts at row ``first_i`` — the sum of
+the rows of the leaves before it in ``jax.tree.flatten`` order, always a
+multiple of ``TILE_ROWS`` — and holds ``ceil(n_i / (TILE_ROWS *
+row_width)) * TILE_ROWS`` rows, its tail zero. The offsets are static, from
+the template's shapes. So a step changes layout twice, where the model
+needs it, one copy a leaf each way: parameters ``p[first_i : first_i +
+k]`` reshaped to the leaf, and each gradient leaf reshaped to its rows, one
+concatenate of tile-aligned pieces — no ``[num_params]`` vector exists.
+The optimizer runs on row sections (m and v lie at the same offsets). Pad
+rows and pad lanes hold zeros and stay zero under every optimizer (g = m =
+v = 0 -> update 0). Chains written before this layout (leaves raveled end
+to end) are converted once, on the host, at restore
+(``rows_from_flat_chain``).
 """
 from __future__ import annotations
 
-from typing import Any, Dict
+import math
+from typing import Any, Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.flatten_util import ravel_pytree
 
 from harmony_tpu.config.params import TILE_ROWS, TableConfig
 from harmony_tpu.dolphin.trainer import Trainer, TrainerContext
 from harmony_tpu.tracing.stepscopes import step_scope
+
+#: this layout's name, as a chain's manifest records it
+#: (``app_meta["layout"]``; jobserver/entity.py)
+LEAF_ROWS = "leaf_rows"
+#: what a chain without the key holds: all leaves raveled end to end
+FLAT = "flat"
+
+
+class _Leaf(NamedTuple):
+    first: int                # its first row in a section, a tile's first
+    rows: int                 # whole tiles
+    shape: Tuple[int, ...]
+    dtype: Any
+    #: the shape its first ``prod(read) / row_width`` rows are read as, and
+    #: how many entries of that shape's leading dimension are the leaf
+    read: Tuple[int, ...]
+    lead: int
+
+    @property
+    def size(self) -> int:
+        return math.prod(self.shape)
+
+
+def _read_shape(shape: Tuple[int, ...], rows: int, row_width: int):
+    """``(read, lead)`` of a leaf of ``shape`` raveled into ``rows`` rows:
+    its own shape with the leading dimension grown to a whole number of
+    rows (gpt2's ``[50257, 768]`` reads ``[50260, 768]``, 37,695 rows;
+    a ``[768]`` bias reads ``[1024]``), so that rows -> leaf is one reshape
+    and a slice of the leading dimension — or, where that would not fit
+    the leaf's rows (``[9, 1025]``), the leaf as a vector."""
+    lead, *rest = shape or (1,)
+    inner = math.prod(rest)
+    whole = row_width // math.gcd(inner, row_width)
+    grown = -(-lead // whole) * whole
+    if grown * inner <= rows * row_width:
+        return (grown, *rest), lead
+    n = lead * inner
+    return (-(-n // row_width) * row_width,), n
+
+
+class LeafRows:
+    """Where each leaf of a parameter pytree lies in a section's rows
+    (the module's docstring has the rule), and the two relayouts."""
+
+    def __init__(self, template: Any, row_width: int) -> None:
+        shapes, self.treedef = jax.tree.flatten(template)
+        self.row_width = row_width
+        self.leaves = []
+        first = 0
+        for s in shapes:
+            n = math.prod(s.shape)
+            rows = -(-n // (TILE_ROWS * row_width)) * TILE_ROWS
+            self.leaves.append(_Leaf(
+                first, rows, tuple(s.shape), s.dtype,
+                *_read_shape(tuple(s.shape), rows, row_width)))
+            first += rows
+        #: rows of a section: every leaf's tiles
+        self.rows = first
+
+    def record(self) -> Dict[str, int]:
+        """STATUS ``table_layout.leaf_layout``: ``leaf_bitcasts`` counts
+        the leaves whose rows ARE the leaf (whole tiles of ``row_width``
+        lanes: merging leading dimensions moves nothing), ``leaf_copies``
+        the others — one relayout copy each a direction; ``pad_rows`` the
+        rows that hold no parameter."""
+        w = self.row_width
+        bitcasts = sum(
+            1 for f in self.leaves
+            if len(f.shape) >= 2 and f.shape[-1] == w
+            and f.shape[-2] % TILE_ROWS == 0)
+        return {"leaves": len(self.leaves),
+                "leaf_copies": len(self.leaves) - bitcasts,
+                "leaf_bitcasts": bitcasts,
+                "pad_rows": sum(f.rows - -(-f.size // w)
+                                for f in self.leaves),
+                "rows": self.rows}
+
+    def to_leaves(self, rows: jnp.ndarray) -> Any:
+        """Rows ``[>= self.rows, row_width]`` whose first rows are a
+        section (a pulled model, or one section of it) -> the pytree: each
+        leaf its whole tiles, one reshape a leaf.
+
+        The tiles are read with a DYNAMIC slice whose offset crosses an
+        optimization barrier, although it is a constant: a static slice
+        commutes with the model's casts, and the compiler then hoists every
+        leaf's bf16 cast above its slice and casts the whole pulled table
+        once, m and v with it (3.9 - 4.6 ms of gpt2's step; PERF.md PR 42);
+        a dynamic slice of whole tiles is an address offset that fuses
+        into the leaf's consumer."""
+        tiles = rows.reshape(-1, TILE_ROWS, self.row_width)
+        firsts = jax.lax.optimization_barrier(jnp.asarray(
+            [f.first // TILE_ROWS for f in self.leaves], jnp.int32))
+        out = []
+        for f, first in zip(self.leaves, firsts):
+            x = jax.lax.dynamic_slice_in_dim(tiles, first,
+                                             f.rows // TILE_ROWS)
+            k = math.prod(f.read) // self.row_width
+            x = x.reshape(f.rows, self.row_width)[:k].reshape(f.read)
+            out.append(x[:f.lead].reshape(f.shape).astype(f.dtype))
+        return jax.tree.unflatten(self.treedef, out)
+
+    def to_rows(self, tree: Any) -> jnp.ndarray:
+        """The pytree -> rows ``[self.rows, row_width]`` float32: each leaf
+        reshaped, its tail zero, to its own rows, and one concatenate of
+        tile-aligned pieces."""
+        pieces = []
+        for f, x in zip(self.leaves, self.treedef.flatten_up_to(tree)):
+            x = x.astype(jnp.float32).reshape(f.lead, *f.read[1:])
+            x = _grow(x, f.read[0]).reshape(-1, self.row_width)
+            pieces.append(_grow(x, f.rows))
+        return jnp.concatenate(pieces)
+
+    def fill_rows(self, section: np.ndarray, leaves) -> None:
+        """Host: write ``leaves`` (arrays, or flat vectors of each leaf's
+        size, in ``jax.tree.flatten`` order) into a zeroed ``section``."""
+        for f, x in zip(self.leaves, leaves):
+            section[f.first:f.first + f.rows].reshape(-1)[:f.size] = (
+                np.asarray(x).reshape(-1))
+
+
+def _grow(x: jnp.ndarray, lead: int) -> jnp.ndarray:
+    """``x`` with zeros after it along its leading dimension, to ``lead``."""
+    if x.shape[0] == lead:
+        return x
+    return jnp.pad(x, [(0, lead - x.shape[0])] + [(0, 0)] * (x.ndim - 1))
 
 
 class PyTreeTrainer(Trainer):
@@ -80,14 +217,11 @@ class PyTreeTrainer(Trainer):
         template = jax.eval_shape(
             lambda: self.model.init(jax.random.PRNGKey(0))
         )
-        flat, self._unravel = ravel_pytree(
-            jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), template)
-        )
-        self.num_params = flat.shape[0]
-        self.num_rows = -(-self.num_params // row_width)
-        #: rows from one section's start to the next (num_rows rounded up
-        #: to whole tiles)
-        self.section_rows = -(-self.num_rows // TILE_ROWS) * TILE_ROWS
+        #: where each leaf lies in a section: static, from the shapes
+        self.leaf_rows = LeafRows(template, row_width)
+        self.num_params = sum(f.size for f in self.leaf_rows.leaves)
+        #: rows from one section's start to the next
+        self.section_rows = self.leaf_rows.rows
 
     # -- model binding (subclass hooks) -----------------------------------
 
@@ -132,24 +266,58 @@ class PyTreeTrainer(Trainer):
             update_fn="add",
         )
 
+    #: the layout's name, recorded with every chain entry this trainer's
+    #: table writes and compared on restore (jobserver/entity.py)
+    table_layout = LEAF_ROWS
+
     def section_stride(self, capacity: int) -> int:
         """Rows from one section's start to the next in a table of
-        ``capacity`` rows. A table restored from a chain written before
-        sections were tile-aligned (``[params | m | v | counter row]``,
-        ``(1 + slots) * num_rows + 1`` rows) keeps its stride of
-        ``num_rows``; any other row count is refused, never misread."""
-        slots = self.num_state_slots
+        ``capacity`` rows; a table too small for the sections is refused,
+        never misread."""
         if capacity >= self.capacity:
             return self.section_rows
-        legacy = (1 + slots) * self.num_rows + (1 if slots else 0)
-        if capacity == legacy:
-            return self.num_rows
+        slots = self.num_state_slots
         raise ValueError(
-            f"{type(self).__name__}: a model table of {capacity} rows fits "
-            f"neither this trainer's layout (capacity {self.capacity}: "
+            f"{type(self).__name__}: a model table of {capacity} rows does "
+            f"not hold this trainer's layout (capacity {self.capacity}: "
             f"{1 + slots} sections of {self.section_rows} rows"
-            f"{' + the counter block' if slots else ''}) nor the unaligned "
-            f"one of older chains (capacity {legacy})")
+            f"{' + the counter block' if slots else ''})")
+
+    def rows_from_flat_chain(self, old: np.ndarray) -> np.ndarray:
+        """Host: the rows of this trainer's table from the rows ``old`` of
+        one restored from a chain in the ``"flat"`` layout — every section
+        all leaves raveled end to end in ``ceil(num_params / row_width)``
+        rows, sections a whole number of tiles apart with the counter in a
+        block of its own (PRs 26-41) or back to back with the counter in
+        the last row (before). The frozen flat rule lives here alone; a
+        row count that is neither is refused, never misread."""
+        slots, w = self.num_state_slots, self.row_width
+        flat_rows = -(-self.num_params // w)
+        tiled = -(-flat_rows // TILE_ROWS) * TILE_ROWS
+        aligned = (1 + slots) * tiled + (TILE_ROWS if slots else 0)
+        unaligned = (1 + slots) * flat_rows + (1 if slots else 0)
+        if old.shape[0] >= aligned:
+            stride = tiled
+        elif old.shape[0] == unaligned:
+            stride = flat_rows
+        else:
+            raise ValueError(
+                f"{type(self).__name__}: a model table of {old.shape[0]} "
+                f"rows is neither flat layout of this model ({1 + slots} "
+                f"sections of {self.num_params} parameters in rows of {w}: "
+                f"capacity {aligned} tile-aligned, {unaligned} unaligned); "
+                f"its own layout {LEAF_ROWS!r} has capacity {self.capacity}")
+        new = np.zeros((self.capacity, w), np.float32)
+        bounds = np.cumsum([0] + [f.size for f in self.leaf_rows.leaves])
+        for i in range(1 + slots):
+            flat = np.asarray(old[i * stride:(i + 1) * stride]).reshape(-1)
+            self.leaf_rows.fill_rows(
+                self.section(new, i),
+                [flat[a:b] for a, b in zip(bounds[:-1], bounds[1:])])
+        if slots:
+            new[(1 + slots) * self.section_rows, 0] = old[
+                (1 + slots) * stride, 0]
+        return new
 
     def section(self, model, i: int):
         """Rows ``[stride, row_width]`` of section i (0=params, 1=m, 2=v)
@@ -167,32 +335,26 @@ class PyTreeTrainer(Trainer):
 
     def init_global_settings(self, ctx: TrainerContext) -> None:
         params = self.model.init(jax.random.PRNGKey(self.seed))
-        flat, _ = ravel_pytree(params)
-        rows = np.asarray(self._to_rows(flat, self.num_rows))
+        # op by op, not one jit: a trainer is made anew for every job, and
+        # the ops' executables are shared by shape where a jitted
+        # closure's is not (2.5 s a job to trace and load it again;
+        # PERF.md, PR 42)
+        rows = np.asarray(self.leaf_rows.to_rows(params))
         # the put holds the table twice (nothing is donated) and the rows
-        # once: the leaves and their flat copy, two more thirds of a
-        # [params | m | v] table, go first — with them a 6.1 GB table did not
+        # once: the leaves, one more third of a [params | m | v] table, go
+        # first — with them and a flat copy a 6.1 GB table did not
         # initialise on a 16 GB chip (PERF.md, PR 38)
-        del params, flat
-        ctx.model_table.multi_put(list(range(self.num_rows)), rows)
+        del params
+        ctx.model_table.multi_put(list(range(self.section_rows)), rows)
         # pad rows, m/v sections and the counter block start (and stay,
         # until the first push) at the table's init value 0.
 
     # -- pure parts -------------------------------------------------------
 
-    def _to_rows(self, flat: jnp.ndarray, rows: int) -> jnp.ndarray:
-        pad = rows * self.row_width - self.num_params
-        return jnp.concatenate(
-            [flat, jnp.zeros((pad,), flat.dtype)]
-        ).reshape(rows, self.row_width)
-
-    def _leaves(self, p: jnp.ndarray) -> Any:
-        """The parameter pytree of the parameter section's rows."""
-        return self._unravel(p.reshape(-1)[: self.num_params])
-
     def _params(self, model: jnp.ndarray) -> Any:
         """The parameter pytree of a pulled model: rows -> leaves."""
-        return self._leaves(self.section(model, 0))
+        self.section_stride(model.shape[0])  # refuses what it cannot hold
+        return self.leaf_rows.to_leaves(model)
 
     def hyperparams(self) -> Dict[str, float]:
         if self.beta2 is None:
@@ -206,22 +368,22 @@ class PyTreeTrainer(Trainer):
     # arithmetic as one whole-table delta.
 
     def row_update_parts(self, capacity: int):
-        if self.section_stride(capacity) != self.section_rows:
-            return None  # an older chain's sections start off a tile
-        return (self.section_rows, 1 + self.num_state_slots, self.gradient,
-                self.push_update)
+        return (self.section_stride(capacity), 1 + self.num_state_slots,
+                self.gradient, self.push_update)
 
-    def gradient(self, p: jnp.ndarray, batch):
+    def gradient(self, model: jnp.ndarray, batch):
         """``(g, metrics)``: the gradient as rows ``[stride, row_width]``
-        over the parameter section's rows ``p`` (rows -> leaves,
-        value_and_grad, leaves -> rows), and what the step reports."""
+        over the parameter section of the pulled ``model`` (rows ->
+        leaves, value_and_grad, leaves -> rows), and what the step
+        reports. The leaves are read where they lie in ``model``: a slice
+        of its parameter section first would be a copy of the section."""
         with step_scope("table.pull"):
-            leaves = self._leaves(p)
+            leaves = self._params(model)
         (loss, extra), grads = jax.value_and_grad(
             self.loss_and_metrics_on_batch, has_aux=True
         )(leaves, batch)
         with step_scope("table.grad_rows"):
-            g = self._to_rows(ravel_pytree(grads)[0], p.shape[0])
+            g = self.leaf_rows.to_rows(grads)
         return g, {"loss": loss, **extra}
 
     def section_deltas(self, stored, g, scalars):
@@ -261,7 +423,7 @@ class PyTreeTrainer(Trainer):
     def compute(self, model, batch, hyper):
         slots = self.num_state_slots
         stored = tuple(self.section(model, i) for i in range(1 + slots))
-        g, metrics = self.gradient(stored[0], batch)
+        g, metrics = self.gradient(model, batch)
         t = self.counter(model) + 1.0 if slots else jnp.asarray(1.0)
         sections = list(self.section_deltas(stored, g, {"t": t, **hyper}))
         tail = model.shape[0] - len(sections) * g.shape[0]

@@ -402,8 +402,10 @@ class TestTwoTenantAcceptance:
 
         # the weight gap must dominate fixed costs (compile, dispatch)
         # on CPU, or the device-second separation drowns in noise:
-        # heavy's per-epoch matmuls are ~3 GFLOP vs light's ~100 KFLOP
-        heavy = cfg("tenant-heavy", features=2048, classes=64, n=2048)
+        # heavy's per-epoch matmuls are ~25 GFLOP vs light's ~100 KFLOP
+        # (at ~3 GFLOP heavy read 0.34 s and light, eight steps under five
+        # other test workers' load, 0.64-0.71 s: PR 40's full runs of the whole suite)
+        heavy = cfg("tenant-heavy", features=4096, classes=64, n=8192)
         light = cfg("tenant-light", features=32, classes=4, n=32,
                     target=1e15)  # deliberately unattainable SLO
         server = JobServer(num_executors=2,

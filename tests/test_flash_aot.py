@@ -214,7 +214,7 @@ def test_keyed_push_compiles_in_place_for_v5e(one_chip, model, capacity):
     assert not moved, moved
 
 
-def _tiny_lm_step(mesh, optimizer):
+def _tiny_lm_step(mesh, optimizer, dtype=jnp.float32):
     """The small LM's step as the worker builds it (``pull_all_step``,
     ``_step_core``'s own body), compiled for ``mesh``: ``(trainer, spec,
     lowering, compiled)``."""
@@ -227,7 +227,7 @@ def _tiny_lm_step(mesh, optimizer):
 
     trainer = TransformerTrainer(
         TransformerConfig(vocab_size=64, d_model=32, n_heads=2, n_layers=2,
-                          d_ff=64, max_seq=64, attn="blockwise"),
+                          d_ff=64, max_seq=64, attn="blockwise", dtype=dtype),
         row_width=128, optimizer=optimizer)
     spec = TableSpec(trainer.model_table_config())
     tsh = block_sharding(mesh, spec.num_blocks)
@@ -258,8 +258,14 @@ def _entry_results(text, sizes, ops):
     return hit
 
 
-@pytest.mark.parametrize("optimizer", ["adam", "momentum"])
-def test_lm_step_leaves_the_table_in_its_stored_layout(one_chip, optimizer):
+@pytest.mark.parametrize("optimizer,dtype", [
+    ("adam", jnp.float32), ("momentum", jnp.float32),
+    # bf16 activations: every weight is cast, and a cast commutes with a
+    # STATIC slice of the pulled table — read that way the leaves cost one
+    # cast of the whole [p | m | v] table a step (PERF.md, PR 42)
+    ("adam", jnp.bfloat16)])
+def test_lm_step_leaves_the_table_in_its_stored_layout(one_chip, optimizer,
+                                                       dtype):
     """The small LM's fused PULL -> COMP -> PUSH step, from the worker's own
     function: the table enters in the default layout and no whole-table
     copy, reshape, pad or slice is among the ops the device runs — under
@@ -277,8 +283,8 @@ def test_lm_step_leaves_the_table_in_its_stored_layout(one_chip, optimizer):
     from harmony_tpu.parallel.mesh import build_mesh
 
     mesh = build_mesh(list(one_chip.device_set), data=1)
-    trainer, spec, lowering, compiled = _tiny_lm_step(mesh, optimizer)
-    assert trainer.num_rows % 8  # sections had to be rounded up
+    trainer, spec, lowering, compiled = _tiny_lm_step(mesh, optimizer, dtype)
+    assert trainer.leaf_rows.record()["pad_rows"]  # leaves rounded up to tiles
     assert lowering == "row_ranges"
     text = compiled.as_text()
 

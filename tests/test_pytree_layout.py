@@ -30,7 +30,7 @@ from harmony_tpu.models import (
     TransformerTrainer,
     make_lm_data,
 )
-from harmony_tpu.models.pytree_trainer import PyTreeTrainer
+from harmony_tpu.models.pytree_trainer import PyTreeTrainer, _read_shape
 from harmony_tpu.parallel import build_mesh
 from harmony_tpu.table import DenseTable, TableSpec
 from harmony_tpu.table.table import block_sharding, row_shards
@@ -61,26 +61,65 @@ class VectorTrainer(PyTreeTrainer):
         return jnp.mean((params["w"][: batch.shape[-1]] - batch) ** 2)
 
 
-# -- the flat compute this PR replaced, on the layout it ran on -------------
+# -- the flat layout and the flat compute, frozen (PRs 25-41) ----------------
+#
+# All leaves raveled end to end into ``num_params`` floats, cut into rows;
+# sections ``flat_rows`` apart with the counter in the last row (before PR
+# 26) or a whole number of tiles apart with a counter block (PRs 26-41).
+# The program keeps this rule in ``rows_from_flat_chain`` alone.
+
+def flat_rows(tr):
+    return -(-tr.num_params // tr.row_width)
+
+
+def flat_stride(tr):
+    return -(-flat_rows(tr) // 8) * 8
+
 
 def legacy_capacity(tr):
-    return tr.num_rows * (1 + tr.num_state_slots) + bool(tr.num_state_slots)
+    return flat_rows(tr) * (1 + tr.num_state_slots) + bool(tr.num_state_slots)
+
+
+def flat_capacity(tr):
+    slots = tr.num_state_slots
+    return (1 + slots) * flat_stride(tr) + (8 if slots else 0)
+
+
+def flat_to_rows(tr, flat, rows):
+    pad = rows * tr.row_width - tr.num_params
+    return jnp.concatenate(
+        [flat, jnp.zeros((pad,), flat.dtype)]).reshape(rows, tr.row_width)
+
+
+def flat_unravel(tr):
+    template = jax.eval_shape(lambda: tr.model.init(jax.random.PRNGKey(0)))
+    return ravel_pytree(
+        jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), template))[1]
+
+
+def flat_leaves(tr, table, i, stride):
+    """Section ``i`` of a table in the flat layout, as the pytree."""
+    rows = jnp.asarray(table)[i * stride:(i + 1) * stride]
+    return flat_unravel(tr)(rows.reshape(-1)[: tr.num_params])
 
 
 def legacy_compute(tr, model, batch, hyper):
-    """PyTreeTrainer.compute as of PR 25: sections of ``num_rows`` rows
+    """PyTreeTrainer.compute as of PR 25: sections of ``flat_rows`` rows
     flattened to ``[num_params]``, the optimizer on flat vectors, every
     delta padded and reshaped back, the counter in the last row."""
+    n_rows = flat_rows(tr)
+
     def section(i):
-        rows = model[i * tr.num_rows:(i + 1) * tr.num_rows]
+        rows = model[i * n_rows:(i + 1) * n_rows]
         return rows.reshape(-1)[: tr.num_params]
 
     def to_rows(flat):
-        return tr._to_rows(flat, tr.num_rows)
+        return flat_to_rows(tr, flat, n_rows)
 
     pflat = section(0)
     (loss, extra), grads = jax.value_and_grad(
-        tr.loss_and_metrics_on_batch, has_aux=True)(tr._unravel(pflat), batch)
+        tr.loss_and_metrics_on_batch, has_aux=True)(
+            flat_unravel(tr)(pflat), batch)
     gflat, _ = ravel_pytree(grads)
     slots = tr.num_state_slots
     m = section(1) if slots >= 1 else jnp.zeros_like(pflat)
@@ -106,22 +145,45 @@ def _lm(optimizer, row_width=256):
 
 
 def _start(tr, rows):
+    """The initial model in the trainer's own layout."""
+    params = tr.model.init(jax.random.PRNGKey(tr.seed))
+    model = jnp.zeros((rows, tr.row_width), jnp.float32)
+    return model.at[: tr.section_rows].set(tr.leaf_rows.to_rows(params))
+
+
+def _flat_start(tr, rows):
+    """The same model in the flat layout."""
     flat, _ = ravel_pytree(tr.model.init(jax.random.PRNGKey(tr.seed)))
     model = jnp.zeros((rows, tr.row_width), jnp.float32)
-    return model.at[: tr.num_rows].set(tr._to_rows(flat, tr.num_rows))
+    return model.at[: flat_rows(tr)].set(
+        flat_to_rows(tr, flat, flat_rows(tr)))
 
 
-def _train(compute, tr, rows, steps):
+def _train(compute, tr, model, steps):
     batch = (jnp.asarray(make_lm_data(4, 33, CFG.vocab_size, seed=7)),)
     hyper = {k: jnp.asarray(v, jnp.float32)
              for k, v in tr.hyperparams().items()}
     step = jax.jit(lambda model: compute(model, batch, hyper))
-    model, losses = _start(tr, rows), []
+    losses = []
     for _ in range(steps):
         delta, metrics = step(model)
         model = model + delta
         losses.append(float(metrics["loss"]))
     return np.asarray(model), losses
+
+
+def _assert_same_leaves(tr, new, old, old_stride):
+    """Sections p, m, v of ``new`` (the trainer's layout) hold, leaf by
+    leaf, the bits of ``old`` (flat, sections ``old_stride`` apart); pad
+    rows and pad lanes of ``new`` are zero; every section moved."""
+    for i in range(1 + tr.num_state_slots):
+        section = jnp.asarray(tr.section(new, i))
+        got = tr.leaf_rows.to_leaves(section)
+        want = flat_leaves(tr, old, i, old_stride)
+        jax.tree.map(np.testing.assert_array_equal, got, want)
+        # the leaves are all a section holds: its pads are zero
+        np.testing.assert_array_equal(tr.leaf_rows.to_rows(got), section)
+        assert sum(float(jnp.abs(x).sum()) for x in jax.tree.leaves(got))
 
 
 # -- (a) the schema ----------------------------------------------------------
@@ -131,7 +193,8 @@ def _train(compute, tr, rows, steps):
 @pytest.mark.parametrize("slots", [0, 1, 2])
 def test_storage_is_the_row_matrix(slots, num_params, num_blocks):
     tr = VectorTrainer(num_params, row_width=128, optimizer=BY_SLOTS[slots])
-    assert tr.num_rows % 8 == {2048: 0, 1157: 2, 1793: 7}[num_params]
+    assert flat_rows(tr) % 8 == {2048: 0, 1157: 2, 1793: 7}[num_params]
+    assert tr.section_rows == 16  # one leaf: whole tiles of 8 x 128
     spec = TableSpec(tr.model_table_config(num_blocks=num_blocks))
     capacity = spec.config.capacity
     assert spec.block_size % 8 == 0
@@ -142,7 +205,7 @@ def test_storage_is_the_row_matrix(slots, num_params, num_blocks):
     else:
         assert spec.block_size == 8 and capacity == tr.capacity
     stride = tr.section_stride(capacity)
-    assert stride % 8 == 0 and stride >= tr.num_rows
+    assert stride % 8 == 0 and stride >= flat_rows(tr)
     assert capacity >= (1 + slots) * stride + (8 if slots else 0)
     # sections and the counter through the accessors only
     model = np.arange(capacity * 128, dtype=np.float32).reshape(capacity, 128)
@@ -166,21 +229,14 @@ def test_storage_is_the_row_matrix(slots, num_params, num_blocks):
 @pytest.mark.parametrize("optimizer,steps", [
     ("adam", 3), ("sgd", 1), ("momentum", 1), ("adagrad", 1), ("rmsprop", 1)])
 def test_row_compute_equals_flat_compute(optimizer, steps):
-    tr = _lm(optimizer, row_width=128)  # 84 rows: sections of 88
-    assert tr.num_rows % 8 and tr.num_params % tr.row_width
-    new, new_losses = _train(tr.compute, tr, tr.capacity, steps)
+    tr = _lm(optimizer, row_width=128)  # 162 flat rows, 15 leaves in 200
+    assert flat_rows(tr) % 8 and tr.num_params % tr.row_width
+    new, new_losses = _train(tr.compute, tr, _start(tr, tr.capacity), steps)
     old, old_losses = _train(lambda *a: legacy_compute(tr, *a), tr,
-                             legacy_capacity(tr), steps)
+                             _flat_start(tr, legacy_capacity(tr)), steps)
     assert new_losses == old_losses
-    n, slots = tr.num_params, tr.num_state_slots
-    moved = 0
-    for i in range(1 + slots):
-        got = tr.section(new, i).reshape(-1)
-        want = old[i * tr.num_rows:(i + 1) * tr.num_rows].reshape(-1)[:n]
-        np.testing.assert_array_equal(got[:n], want)
-        assert not got[n:].any()  # pad lanes and pad rows stay zero
-        moved += bool(np.abs(want).sum())
-    assert moved == 1 + slots
+    _assert_same_leaves(tr, new, old, flat_rows(tr))
+    slots = tr.num_state_slots
     if slots:
         assert tr.counter(new) == old[-1, 0] == steps
         block = new[(1 + slots) * tr.section_rows:]
@@ -200,7 +256,9 @@ def test_init_and_evaluate_round_trip_the_parameters(mesh8, optimizer):
     assert model.shape == (tr.capacity, 128)
     params = tr.model.init(jax.random.PRNGKey(tr.seed))
     jax.tree.map(np.testing.assert_array_equal, tr._params(model), params)
-    assert not np.asarray(model)[tr.num_rows:].any()
+    assert not np.asarray(model)[tr.section_rows:].any()
+    np.testing.assert_array_equal(  # pad rows and lanes start at zero
+        tr.leaf_rows.to_rows(params), tr.section(model, 0))
     batch = (jnp.asarray(make_lm_data(4, 33, CFG.vocab_size, seed=3)),)
     np.testing.assert_array_equal(
         tr.evaluate(model, batch)["loss"], tr.loss_on_batch(params, batch))
@@ -208,19 +266,32 @@ def test_init_and_evaluate_round_trip_the_parameters(mesh8, optimizer):
 
 # -- (d) a table from an older chain -----------------------------------------
 
+_FLAT_CHAINS = {
+    # name: (capacity, rows between its sections)
+    "unaligned": (legacy_capacity, flat_rows),   # before PR 26
+    "tile_aligned": (flat_capacity, flat_stride),  # PRs 26-41
+}
+
+
 @pytest.mark.parametrize("optimizer", ["sgd", "momentum", "adam"])
 def test_older_layout_trains_to_the_same_bits(optimizer):
-    """``[params | m | v | counter row]`` at a stride of ``num_rows``: the
-    layout is read off the pulled model's row count."""
+    """``[params | m | v | counter row]`` at a stride of ``flat_rows``,
+    trained there by the frozen flat compute: converted once
+    (``rows_from_flat_chain``) it holds the same leaves and the same
+    count, and two more steps on each side end on the same bits."""
     tr = _lm(optimizer, row_width=128)
-    rows = legacy_capacity(tr)
-    assert tr.section_stride(rows) == tr.num_rows != tr.section_rows
-    new, new_losses = _train(tr.compute, tr, rows, 2)
-    old, old_losses = _train(lambda *a: legacy_compute(tr, *a), tr, rows, 2)
+    old, _ = _train(lambda *a: legacy_compute(tr, *a), tr,
+                    _flat_start(tr, legacy_capacity(tr)), 2)
+    new = tr.rows_from_flat_chain(old)
+    assert new.shape == (tr.capacity, 128)
+    _assert_same_leaves(tr, new, old, flat_rows(tr))
+    new, new_losses = _train(tr.compute, tr, jnp.asarray(new), 2)
+    old, old_losses = _train(lambda *a: legacy_compute(tr, *a), tr,
+                             jnp.asarray(old), 2)
     assert new_losses == old_losses
-    np.testing.assert_array_equal(new, old)
+    _assert_same_leaves(tr, new, old, flat_rows(tr))
     if tr.num_state_slots:
-        assert tr.counter(new) == 2
+        assert tr.counter(new) == 4
 
 
 @pytest.mark.parametrize("off", [-1, 1, 7])
@@ -228,9 +299,13 @@ def test_unknown_row_count_is_refused_by_name(off):
     tr = _lm("adam", row_width=128)
     rows = legacy_capacity(tr) + off
     with pytest.raises(ValueError) as e:
-        tr.section(np.zeros((rows, 128), np.float32), 1)
-    for n in (rows, tr.capacity, legacy_capacity(tr)):
+        tr.rows_from_flat_chain(np.zeros((rows, 128), np.float32))
+    for n in (rows, tr.capacity, legacy_capacity(tr), flat_capacity(tr)):
         assert str(n) in str(e.value)
+    assert "leaf_rows" in str(e.value) and "flat" in str(e.value)
+    with pytest.raises(ValueError) as e:  # and the step's own accessors
+        tr.section(np.zeros((rows, 128), np.float32), 1)
+    assert str(rows) in str(e.value) and str(tr.capacity) in str(e.value)
 
 
 # -- (e) the step in two parts: gradient | update rule + fold ----------------
@@ -252,8 +327,8 @@ def _run_step(body, tr, spec, mesh, steps):
                    donate_argnums=0)
     start = np.zeros((spec.num_blocks * spec.block_size, tr.row_width),
                      np.float32)
-    flat, _ = ravel_pytree(tr.model.init(jax.random.PRNGKey(tr.seed)))
-    start[: tr.num_rows] = np.asarray(tr._to_rows(flat, tr.num_rows))
+    start[: tr.section_rows] = np.asarray(tr.leaf_rows.to_rows(
+        tr.model.init(jax.random.PRNGKey(tr.seed))))
     arr = jax.device_put(start.reshape(spec.storage_shape), tsh)
     batch = (jax.device_put(make_lm_data(4, 33, CFG.vocab_size, seed=7),
                             NamedSharding(mesh, P("data"))),)
@@ -301,14 +376,8 @@ class _OnePart(TransformerTrainer):
         return None
 
 
-def _legacy_table(tr):
-    return dataclasses.replace(tr.model_table_config(),
-                               capacity=legacy_capacity(tr), num_blocks=5)
-
-
 _WHOLE_DELTA_CASES = {
     # name: (trainer class, table config of the trainer, mesh)
-    "legacy_stride": (TransformerTrainer, _legacy_table, "one-device"),
     "post_hook": (TransformerTrainer, lambda tr: dataclasses.replace(
         tr.model_table_config(), update_fn="add_nonneg"), "one-device"),
     "non_additive_fold": (TransformerTrainer, lambda tr: dataclasses.replace(
@@ -579,6 +648,345 @@ def test_worker_records_how_its_step_folds(as_tpu, devices):
     assert layout["fold_lowering"] == "pallas_sections"
 
 
+# -- (g) a leaf is a row range: models/pytree_trainer.py LeafRows -------------
+
+class _Leaves:
+    """A model of the given leaves."""
+
+    def __init__(self, shapes):
+        self.shapes = dict(shapes)
+
+    def init(self, key):
+        return {name: jax.random.normal(jax.random.fold_in(key, i), shape,
+                                        jnp.float32)
+                for i, (name, shape) in enumerate(self.shapes.items())}
+
+
+class LeavesTrainer(PyTreeTrainer):
+    config_cls = dict
+
+    def build_model(self, config):
+        return _Leaves(config)
+
+    def loss_on_batch(self, params, batch):
+        return sum(jnp.mean((x - batch[0]) ** 2)
+                   for x in jax.tree.leaves(params))
+
+
+_LAYOUTS = {
+    # name: (trainer, row width)
+    "lm": (lambda: _lm("adam", row_width=128), 128),
+    "vector_1": (lambda: VectorTrainer(1, row_width=128), 128),
+    "vector_8191": (lambda: VectorTrainer(8191, row_width=1024), 1024),
+    "vector_8192": (lambda: VectorTrainer(8192, row_width=1024), 1024),
+    "vector_8193": (lambda: VectorTrainer(8193, row_width=1024), 1024),
+    # gpt2's [50257, 768] embedding cut down: no whole number of rows, read
+    # with its leading dimension grown to one ([1008, 24]: 189 rows)
+    "embedding": (lambda: LeavesTrainer(
+        {"emb": (1003, 24), "w": (16, 128), "b": (24,)}, row_width=128,
+        optimizer="adam"), 128),
+    # every form of a leaf: a scalar, whole tiles of row_width lanes (its
+    # rows ARE the leaf), a grown leading dimension that would not fit
+    # ([9, 129]: read as a vector), three dimensions, and one of no elements
+    "forms": (lambda: LeavesTrainer(
+        {"s": (), "stack": (3, 16, 128), "odd": (9, 129), "t": (5, 7, 48),
+         "none": (0, 4)}, row_width=128, optimizer="momentum"), 128),
+}
+
+
+@pytest.mark.parametrize("name", list(_LAYOUTS))
+def test_every_leaf_owns_whole_tiles(name):
+    """Each leaf's first row is a multiple of TILE_ROWS, the ranges tile the
+    section in flatten order with no gap and no overlap, and each holds its
+    leaf with less than a tile to spare."""
+    make, width = _LAYOUTS[name]
+    tr = make()
+    shapes = jax.tree.leaves(jax.eval_shape(
+        lambda: tr.model.init(jax.random.PRNGKey(0))))
+    at = 0
+    assert len(tr.leaf_rows.leaves) == len(shapes)
+    for f, leaf in zip(tr.leaf_rows.leaves, shapes):
+        n = int(np.prod(leaf.shape))
+        assert f.first == at and f.first % 8 == 0 and f.rows % 8 == 0
+        assert f.shape == leaf.shape and f.size == n
+        assert 0 <= f.rows * width - n < 8 * width
+        # the shape its rows are read as: whole rows, inside its own tiles
+        read = int(np.prod(f.read))
+        assert read % width == 0 and n <= read <= f.rows * width
+        at += f.rows
+    assert tr.section_rows == tr.leaf_rows.rows == at
+    slots = tr.num_state_slots
+    assert tr.capacity == (1 + slots) * at + (8 if slots else 0)
+    assert tr.num_params == sum(int(np.prod(x.shape)) for x in shapes)
+    record = tr.leaf_rows.record()
+    assert record["rows"] == at and record["leaves"] == len(shapes)
+    assert record["pad_rows"] == at - sum(
+        -(-int(np.prod(x.shape)) // width) for x in shapes)
+    assert record["leaf_copies"] + record["leaf_bitcasts"] == len(shapes)
+    assert record["leaf_bitcasts"] == {"forms": 1, "embedding": 1}.get(name, 0)
+
+
+@pytest.mark.parametrize("shape,width,read,lead", [
+    # gpt2's embedding: 37,692.75 rows of 1024; four rows of 768 are three
+    ((50257, 768), 1024, (50260, 768), 50257),
+    ((768,), 1024, (1024,), 768),               # a bias: one row of a tile
+    ((), 128, (128,), 1),                       # a scalar
+    ((16, 2048, 1024), 1024, (16, 2048, 1024), 16),  # its rows ARE the leaf
+    # 1024 rows of 1025 would not fit 9,225 elements' 16 rows: a vector
+    ((9, 1025), 1024, (10 * 1024,), 9 * 1025),
+    ((0, 4), 128, (0, 4), 0),                   # no element, no row
+])
+def test_a_leaf_is_read_in_its_own_shape_where_that_fits(shape, width, read,
+                                                         lead):
+    """``_read_shape`` from the shape alone: the leading dimension grown to
+    whole rows inside the leaf's own tiles, else the leaf as a vector."""
+    n = int(np.prod(shape))
+    rows = -(-n // (8 * width)) * 8
+    assert _read_shape(shape, rows, width) == (read, lead)
+    assert int(np.prod(read)) % width == 0
+    assert n <= int(np.prod(read)) <= rows * width
+
+
+@pytest.mark.parametrize("name", list(_LAYOUTS))
+def test_rows_and_leaves_round_trip_exactly(name):
+    """leaves -> rows -> leaves and rows -> leaves -> rows, on the device
+    and (``fill_rows``) on the host; the tails are zero."""
+    tr = _LAYOUTS[name][0]()
+    params = tr.model.init(jax.random.PRNGKey(3))
+    rows = jax.jit(tr.leaf_rows.to_rows)(params)
+    assert rows.shape == (tr.section_rows, tr.row_width)
+    assert rows.dtype == jnp.float32
+    back = jax.jit(tr.leaf_rows.to_leaves)(rows)
+    jax.tree.map(np.testing.assert_array_equal, back, params)
+    np.testing.assert_array_equal(tr.leaf_rows.to_rows(back), rows)
+    host = np.zeros(rows.shape, np.float32)
+    tr.leaf_rows.fill_rows(host, jax.tree.leaves(params))
+    np.testing.assert_array_equal(host, rows)
+    for f in tr.leaf_rows.leaves:  # what is no parameter is zero
+        flat = host[f.first:f.first + f.rows].reshape(-1)
+        assert not flat[f.size:].any()
+        np.testing.assert_array_equal(
+            flat[:f.size], np.asarray(
+                jax.tree.leaves(params)[tr.leaf_rows.leaves.index(f)]
+            ).reshape(-1))
+    # a pulled model is read where its leaves lie, with no slice first
+    model = jnp.concatenate([rows, jnp.ones(
+        (tr.capacity - tr.section_rows + 8, tr.row_width))])
+    jax.tree.map(np.testing.assert_array_equal, tr._params(model), params)
+
+
+@pytest.mark.parametrize("name,optimizer", [
+    ("lm", "adam"), ("lm", "momentum"), ("lm", "sgd"), ("embedding", "adam")])
+def test_shipped_step_holds_the_bits_of_the_flat_layout(name, optimizer,
+                                                        devices):
+    """Four steps through ``pull_all_step`` on the leaf rows against the
+    same steps of the frozen flat compute on the flat layout: every loss,
+    and p, m and v leaf by leaf, to the last bit; pads still zero."""
+    tr = (_lm(optimizer, row_width=128) if name == "lm"
+          else _LAYOUTS[name][0]())
+    assert tr.optimizer == optimizer
+    mesh = build_mesh(devices[:1], data=1)
+    spec = TableSpec(tr.model_table_config())
+    assert update_lowering(spec, tr, mesh) == "row_ranges"
+    if name == "lm":
+        batch = (jnp.asarray(make_lm_data(4, 33, CFG.vocab_size, seed=7)),)
+    else:
+        batch = (jnp.float32(0.25),)
+    hyper = {k: jnp.asarray(v, jnp.float32)
+             for k, v in tr.hyperparams().items()}
+    step = jax.jit(traced_on(mesh, pull_all_step(spec, tr, mesh)),
+                   donate_argnums=0)
+
+    def flat_body(table, batch, hyper):  # the same step, PR 25's layout
+        model = _phase_boundary(table, replicate_on=mesh)
+        delta, metrics = _phase_boundary(
+            legacy_compute(tr, model, batch, hyper), replicate_on=mesh)
+        return table + delta, metrics
+
+    flat_step = jax.jit(traced_on(mesh, flat_body), donate_argnums=0)
+    arr = jnp.asarray(_start(tr, tr.capacity)).reshape(spec.storage_shape)
+    old = _flat_start(tr, legacy_capacity(tr))
+    for _ in range(4):
+        arr, metrics = step(arr, batch, hyper)
+        old, old_metrics = flat_step(old, batch, hyper)
+        assert float(metrics["loss"]) == float(old_metrics["loss"])
+    new = np.asarray(arr).reshape(-1, tr.row_width)
+    _assert_same_leaves(tr, new, old, flat_rows(tr))
+    if tr.num_state_slots:
+        assert tr.counter(new) == old[-1, 0] == 4
+
+
+@pytest.mark.parametrize("optimizer,dtype", [
+    ("sgd", jnp.float32), ("adam", jnp.float32), ("adam", jnp.bfloat16)])
+def test_lowered_step_holds_no_flat_parameter_vector(optimizer, dtype,
+                                                     devices):
+    """The structural guard: in the step as lowered, no rank-1 array has
+    ``num_params`` or a section's elements (nor any leaf's whole tiles) —
+    rows go to leaves and back leaf by leaf, under bf16 activations too;
+    and the parameter section is not sliced out of the pulled table as one
+    array."""
+    import re
+
+    tr = TransformerTrainer(dataclasses.replace(CFG, dtype=dtype),
+                            row_width=128, step_size=3e-3,
+                            optimizer=optimizer)
+    mesh = build_mesh(devices[:1], data=1)
+    spec = TableSpec(tr.model_table_config())
+    arr = jax.ShapeDtypeStruct(spec.storage_shape, jnp.float32)
+    batch = (jax.ShapeDtypeStruct((4, 33), jnp.int32),)
+    hyper = {k: jax.ShapeDtypeStruct((), jnp.float32)
+             for k in tr.hyperparams()}
+    text = jax.jit(traced_on(mesh, pull_all_step(spec, tr, mesh))).lower(
+        arr, batch, hyper).as_text()
+    rank1 = {int(n) for n in re.findall(r"tensor<(\d+)x(?:f32|bf16)>", text)}
+    section = tr.section_rows * tr.row_width
+    assert rank1, "the lowering names its vectors tensor<Nxf32>"
+    assert not rank1 & {tr.num_params, section}, rank1
+    assert max(rank1) < max(f.size for f in tr.leaf_rows.leaves), rank1
+    if tr.num_state_slots:  # (sgd's table IS the section)
+        assert f"tensor<{tr.section_rows}x{tr.row_width}xf32>" not in (
+            text.split("stablehlo.optimization_barrier")[1])
+
+
+# -- (h) a chain written before the leaves were row ranges -------------------
+
+def _flat_chain_table(tr, which, steps=2):
+    """A ``[p | m | v | counter]`` table in the flat layout ``which``
+    after ``steps`` steps of the frozen flat compute."""
+    capacity, stride = (fn(tr) for fn in _FLAT_CHAINS[which])
+    trained, losses = _train(lambda *a: legacy_compute(tr, *a), tr,
+                             _flat_start(tr, legacy_capacity(tr)), steps)
+    n, slots = flat_rows(tr), tr.num_state_slots
+    table = np.zeros((capacity, tr.row_width), np.float32)
+    for i in range(1 + slots):
+        table[i * stride:i * stride + n] = trained[i * n:(i + 1) * n]
+    if slots:
+        table[(1 + slots) * stride, 0] = trained[-1, 0]
+    return table, stride, losses
+
+
+@pytest.mark.parametrize("which", list(_FLAT_CHAINS))
+@pytest.mark.parametrize("optimizer", ["sgd", "adam"])
+def test_flat_chain_rows_convert_to_the_same_leaves(optimizer, which):
+    tr = _lm(optimizer, row_width=128)
+    table, stride, _ = _flat_chain_table(tr, which)
+    new = tr.rows_from_flat_chain(table)
+    assert new.shape == (tr.capacity, tr.row_width)
+    _assert_same_leaves(tr, new, table, stride)
+    if tr.num_state_slots:
+        assert tr.counter(new) == 2
+        assert new[(1 + tr.num_state_slots) * tr.section_rows:].sum() == 2
+
+
+def test_capacity_cannot_tell_the_layouts_apart():
+    """Two leaves of 1,000 elements in rows of 128: 16 rows either way,
+    and the second leaf at element 1,000 or at row 8 — a flat table read
+    as leaf rows (or the reverse) would be misread in silence, which is
+    why a chain names its layout."""
+    tr = LeavesTrainer({"a": (10, 100), "b": (1000,)}, row_width=128,
+                       optimizer="sgd")
+    assert tr.capacity == flat_capacity(tr) == 16
+    params = tr.model.init(jax.random.PRNGKey(0))
+    flat = np.asarray(flat_to_rows(tr, ravel_pytree(params)[0], 16))
+    mine = np.asarray(tr.leaf_rows.to_rows(params))
+    assert flat.shape == mine.shape and (flat != mine).any()
+    np.testing.assert_array_equal(tr.rows_from_flat_chain(flat), mine)
+
+
+def _write_chain(chkp_root, job, tr, table, epoch, layout=None):
+    """One committed chain entry of ``job`` holding ``table``, as a job
+    of the tree that wrote such a layout left it."""
+    from harmony_tpu.checkpoint.manager import CheckpointManager
+    from harmony_tpu.parallel import DevicePool
+    from harmony_tpu.runtime import ETMaster
+
+    master = ETMaster(DevicePool(jax.devices()[:1]))
+    executors = [e.id for e in master.add_executors(1)]
+    cfg = dataclasses.replace(
+        tr.model_table_config(table_id=f"{job}:{tr.default_table_id}"),
+        capacity=table.shape[0], num_blocks=table.shape[0] // 8
+        if table.shape[0] % 8 == 0 else 1)
+    handle = master.create_table(cfg, executors)
+    handle.table.multi_put(list(range(table.shape[0])), table)
+    meta = {"epoch": float(epoch)}
+    if layout is not None:
+        meta["layout"] = layout
+    CheckpointManager.for_job(chkp_root, job).checkpoint(
+        handle, commit=True, app_meta=meta)
+    handle.drop()
+
+
+_LM_APP = {"vocab_size": 64, "d_model": 32, "n_heads": 2, "n_layers": 2,
+           "d_ff": 64, "max_seq": 64, "attn": "blockwise", "row_width": 128,
+           "optimizer": "adam", "step_size": 3e-3}
+
+
+def _resume(chkp_root, job, epochs):
+    from harmony_tpu.config.params import JobConfig
+    from harmony_tpu.jobserver.server import JobServer
+    from harmony_tpu.parallel import DevicePool
+
+    server = JobServer(1, device_pool=DevicePool(jax.devices()[:1]),
+                       chkp_root=chkp_root)
+    server.start()
+    try:
+        cfg = JobConfig(
+            job_id=job, app_type="dolphin",
+            trainer="harmony_tpu.models.transformer:TransformerTrainer",
+            params=TrainerParams(num_epochs=epochs, num_mini_batches=1,
+                                 comm_probe_period=0, model_chkp_period=1,
+                                 app_params=_LM_APP),
+            num_workers=1,
+            user={"data_fn": "harmony_tpu.models.transformer:make_lm_data",
+                  "data_args": {"num_seqs": 4, "seq_len": 33,
+                                "vocab_size": 64, "seed": 7},
+                  "resume_from_chain": True})
+        result = server.submit(cfg).result(timeout=300)
+        layout = server._status()["tenants"][job]["table_layout"]
+    finally:
+        server.shutdown(timeout=60)
+    return result, layout
+
+
+@pytest.mark.parametrize("which", list(_FLAT_CHAINS))
+def test_job_resumes_a_flat_chain_on_the_same_leaves(which, tmp_path):
+    """A chain entry with no layout name (epoch 1, two flat steps in):
+    the resumed job converts it, trains epochs 2 and 3 to the losses the
+    frozen flat compute reads from the same state, records its own
+    layout's name with the entries it writes — and those resume as they
+    are."""
+    from harmony_tpu.checkpoint.manager import CheckpointManager
+
+    tr = TransformerTrainer(**_LM_APP)
+    table, _, losses = _flat_chain_table(tr, which, steps=4)
+    two_in, _, _ = _flat_chain_table(tr, which, steps=2)
+    job = f"flat-{which}"
+    _write_chain(str(tmp_path), job, tr, two_in, epoch=1)
+    result, layout = _resume(str(tmp_path), job, epochs=4)
+    got = next(iter(result["workers"].values()))["losses"]
+    np.testing.assert_allclose(got, losses[2:], rtol=2e-6)
+    assert layout["rows"] == tr.capacity and layout["tile_exact"] == 1
+    assert layout["leaf_layout"] == tr.leaf_rows.record()
+    mgr = CheckpointManager.for_job(str(tmp_path), job)
+    metas = [mgr.info(cid).app_meta for cid in mgr.list_checkpoints()]
+    assert sorted(m["epoch"] for m in metas) == [1.0, 2.0, 3.0]
+    assert [m.get("layout") for m in sorted(
+        metas, key=lambda m: m["epoch"])] == [None, "leaf_rows", "leaf_rows"]
+    # the newest entry names this layout: restored as it is, one more epoch
+    result, _ = _resume(str(tmp_path), job, epochs=5)
+    assert len(next(iter(result["workers"].values()))["losses"]) == 1
+
+
+def test_chain_in_an_unknown_layout_is_refused_with_both_named(tmp_path):
+    tr = TransformerTrainer(**_LM_APP)
+    _write_chain(str(tmp_path), "odd", tr,
+                 np.zeros((tr.capacity, 128), np.float32), epoch=0,
+                 layout="column_major")
+    with pytest.raises(Exception) as e:
+        _resume(str(tmp_path), "odd", epochs=2)
+    assert "column_major" in str(e.value) and "leaf_rows" in str(e.value)
+
+
 # -- the record that says it engaged -----------------------------------------
 
 def test_table_layout_record():
@@ -588,10 +996,12 @@ def test_table_layout_record():
     tr = _lm("adam")
     lm = TableSpec(tr.model_table_config(table_id="lm"))
     row = table_layout.note("layout-lm", lm,
-                            tr.section_stride(lm.config.capacity))
+                            tr.section_stride(lm.config.capacity),
+                            tr.leaf_rows.record())
     assert row == {"block_size": 8, "blocks": tr.capacity // 8,
                    "tail_rows": 0, "tile_exact": 1,
-                   "section_stride": tr.section_rows, "rows": tr.capacity}
+                   "section_stride": tr.section_rows, "rows": tr.capacity,
+                   "leaf_layout": tr.leaf_rows.record()}
     nine = TableSpec(TableConfig(table_id="nine", capacity=65, num_blocks=8,
                                  value_shape=(128,)))
     assert table_layout.note("layout-nine", nine) == {
@@ -663,4 +1073,18 @@ def test_status_carries_the_layout_of_a_submitted_lm_tenant():
     assert row == {"block_size": 8, "blocks": tr.capacity // 8,
                    "tail_rows": 0, "tile_exact": 1,
                    "section_stride": tr.section_rows, "rows": tr.capacity,
-                   "update_lowering": "row_ranges", "fold_lowering": "xla"}
+                   "update_lowering": "row_ranges", "fold_lowering": "xla",
+                   "leaf_layout": tr.leaf_rows.record()}
+    # where the leaves lie: 9 of them, none 128 wide, and what the tiles cost
+    leaves = jax.tree.leaves(jax.eval_shape(
+        lambda: tr.model.init(jax.random.PRNGKey(0))))
+    used = sum(-(-int(np.prod(x.shape)) // 128) for x in leaves)
+    assert row["leaf_layout"] == {
+        "leaves": 9, "leaf_copies": 9, "leaf_bitcasts": 0,
+        "pad_rows": tr.section_rows - used, "rows": tr.section_rows}
+    from harmony_tpu.metrics.registry import get_registry
+
+    share = [line for line in get_registry().expose().splitlines()
+             if line.startswith('harmony_table_leaf_pad_share{job="layout-job"')]
+    assert len(share) == 1 and float(share[0].rsplit(" ", 1)[1]) == (
+        (tr.section_rows - used) / tr.section_rows)
