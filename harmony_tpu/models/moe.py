@@ -21,7 +21,11 @@ rows of sorted slots ``[i C, (i + 1) C)``, runs the three grouped matmuls
 with the held runs clipped to that interval, and adds each held row times
 its gate onto its token's row of a float32 ``[T, d]`` sum (PR 37: folded in
 VMEM a token tile at a time, ops/sum_rows.py, where XLA's scatter-add took
-the rows one after another). The loop runs
+the rows one after another). The router's selection itself — which ``k`` of
+the ``E`` scores, and each one's weight — is one op too (PR 43,
+ops/top_k_rows.py: ``lax.top_k``'s experts in its order, bit for bit, with a
+compare-and-sum backward, where XLA sorted every row, gathered ``T k``
+scalars and scatter-added them back one after another). The loop runs
 while a chunk starts inside the held slots — one chunk while the router
 stays inside the headroom, all ``ceil(T k / C)`` if every slot routes here:
 nothing is dropped, there is no capacity factor. **There is ONE body**: a
@@ -273,24 +277,31 @@ def _route(params, x, cfg: DroplessConfig, seqs: int):
     17-20: ``f_e = E / (k S) x`` the sequence's slots that chose ``e``, bias
     included, a count with no gradient; ``P_e`` the sequence's mean
     normalised score) — a mean already, so layers ADD it."""
+    from harmony_tpu.ops.top_k_rows import top_k_rows
+    from harmony_tpu.utils.platform import trace_is_tpu
+
     T = x.shape[0]
     E, k = cfg.num_experts, cfg.top_k
+    interpret = not trace_is_tpu()
     with step_scope("moe.route"):
         # a tiny matmul deciding discrete routes: full float32 passes on
         # the MXU
         logits = jnp.dot(x.astype(jnp.float32), params["router"],
                          precision=lax.Precision.HIGHEST)        # [T, E]
+        # the selection is one op (ops/top_k_rows.py): lax.top_k's experts
+        # in its order, no sort, no scalar gather, no scatter-add behind it
         if cfg.score == "softmax":
             lse = jax.nn.logsumexp(logits, axis=-1)
             probs = jnp.exp(logits - lse[:, None])
-            gate, expert = lax.top_k(probs, k)                   # [T, k]
+            gate, expert = top_k_rows(probs, None, k,
+                                      interpret=interpret)       # [T, k]
         else:
             score = jax.nn.sigmoid(logits)
             # the bias moves WHICH experts are chosen; weights are the
             # scores
-            _, expert = lax.top_k(
-                score + lax.stop_gradient(params["bias"]), k)
-            gate = jnp.take_along_axis(score, expert, axis=1)
+            gate, expert = top_k_rows(
+                score + lax.stop_gradient(params["bias"]), score, k,
+                interpret=interpret)
             probs = score / score.sum(axis=-1, keepdims=True)
         if cfg.norm_topk:  # over all k chosen, held here or not
             gate = gate / (gate.sum(axis=-1, keepdims=True) + 1e-20)

@@ -100,6 +100,37 @@ def test_grouped_matmul_kernels_compile_for_v5e(one_chip, m, k, n, groups,
         "tpu_custom_call") == 1
 
 
+@pytest.mark.parametrize("tokens,experts,k,weighed", [
+    (8192, 512, 22, True),    # nemotron-3-super-120b-a12b.solo
+    (8192, 256, 8, True),     # kimi-linear-48b-a3b.solo
+    (16384, 64, 6, True),     # moonlight-16b-a3b.solo, smallthinker's width
+    (16384, 64, 8, False),    # olmoe-1b-7b.solo: softmax, no second operand
+    (4096, 2048, 8, True),    # a width whose plan must shrink the tile
+    (60, 8, 2, True),         # a token count no tile divides: one block
+])
+def test_router_selection_compiles_for_v5e(one_chip, tokens, experts, k,
+                                           weighed):
+    """``harmony_top_k_rows`` and its compare-and-sum backward at the four
+    routers' shapes: two in-kernel transposes and a dynamic row store under
+    the kernel's own VMEM limit; the backward holds no scatter and no second
+    kernel."""
+    from harmony_tpu.ops.top_k_rows import tile_plan, top_k_rows
+
+    sd = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32,
+                                             sharding=one_chip)
+
+    def loss(sel, val, g):
+        return (top_k_rows(sel, val if weighed else None, k)[0] * g).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        sd(tokens, experts), sd(tokens, experts), sd(tokens, k)
+    ).compile().as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+    assert "harmony_top_k_rows" in text and " scatter(" not in text
+    assert tile_plan(tokens, experts, weighed) == {
+        2048: 256 if weighed else 512, 8: 60}.get(experts, 512)
+
+
 @pytest.mark.parametrize("tokens,cfg,checkpoint", [
     # kimi-linear-48b-a3b.solo: 65,536 slots in 16 chunks of 4,096, remat
     (8192, dict(num_experts=256, top_k=8, d_model=2304, d_ff=1024,
@@ -153,8 +184,15 @@ def test_chunked_expert_layer_compiles_for_v5e(one_chip, monkeypatch, tokens,
         if form == "chunked":
             text = compiled.as_text()
     sums = 3 if checkpoint else 2
-    assert got["full"][0] == (12 if checkpoint else 9)
+    # nine grouped matmuls and the router's selection (PR 43), the forward
+    # ones again under remat
+    assert got["full"][0] == (14 if checkpoint else 10)
     assert got["chunked"][0] == got["full"][0] + sums
+    assert sum("harmony_top_k_rows" in line for line in text.splitlines()
+               if "custom_call_target=\"tpu_custom_call\"" in line) == (
+        2 if checkpoint else 1)
+    sorts = [line for line in text.splitlines() if " sort(" in line]
+    assert sorts and not any("moe.route" in line for line in sorts)
     assert sum("harmony_sum_rows" in line for line in text.splitlines()
                if "custom_call_target=\"tpu_custom_call\"" in line) == sums
     wide = [line.strip()[:160] for line in text.splitlines()

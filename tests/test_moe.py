@@ -585,3 +585,201 @@ def test_the_row_sum_plan_reaches_kernel_plans():
         assert tile_plan(tokens, d, jnp.bfloat16) == 256
     assert tile_plan(16384, 8192, jnp.bfloat16) == 64  # wider rows: smaller
     assert tile_plan(60, 128, jnp.float32) == 60       # no divisor: one tile
+
+
+# -- the router's selection is one op (ops/top_k_rows.py, PR 43) --------------
+
+def _route_parent(params, x, cfg, seqs):
+    """``_route`` as it stood before PR 43, line for line: ``lax.top_k``,
+    ``take_along_axis``, and autodiff's scatter-add behind them."""
+    from jax import lax
+
+    T = x.shape[0]
+    E, k = cfg.num_experts, cfg.top_k
+    logits = jnp.dot(x.astype(jnp.float32), params["router"],
+                     precision=lax.Precision.HIGHEST)
+    if cfg.score == "softmax":
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        probs = jnp.exp(logits - lse[:, None])
+        gate, expert = lax.top_k(probs, k)
+    else:
+        score = jax.nn.sigmoid(logits)
+        _, expert = lax.top_k(score + lax.stop_gradient(params["bias"]), k)
+        gate = jnp.take_along_axis(score, expert, axis=1)
+        probs = score / score.sum(axis=-1, keepdims=True)
+    if cfg.norm_topk:
+        gate = gate / (gate.sum(axis=-1, keepdims=True) + 1e-20)
+    if cfg.routed_scale != 1.0:
+        gate = gate * cfg.routed_scale
+    slot_expert = expert.reshape(-1)
+    if cfg.seq_aux:
+        by_seq = jnp.sum(slot_expert.reshape(seqs, -1)[:, :, None]
+                         == jnp.arange(E)[None, None, :], axis=1,
+                         dtype=jnp.int32)
+        tokens = by_seq.sum(axis=0)
+        f = by_seq.astype(jnp.float32) * (E / (k * (T // seqs)))
+        p = probs.reshape(seqs, T // seqs, E).mean(axis=1)
+        seq_lb = jnp.sum(f * p, axis=-1).mean()
+    else:
+        tokens = jnp.sum(slot_expert[:, None] == jnp.arange(E)[None, :],
+                         axis=0, dtype=jnp.int32)
+    stats = {"tokens": tokens.astype(jnp.float32), "n": jnp.float32(T),
+             "prob_sum": probs.sum(axis=0)}
+    if cfg.score == "softmax":
+        stats["z_sum"] = jnp.sum(lse * lse)
+    if cfg.seq_aux:
+        stats["seq_lb"] = seq_lb
+    return gate, expert, slot_expert, tokens, stats
+
+
+_ROUTERS = {
+    "softmax": dict(),
+    "sigmoid": dict(SIGMOID, seq_aux=True),
+    # every third expert scores exactly 0.5 (a zero router column) and a
+    # bias lifts those eleven above the rest: each row's top 6 is a tie
+    "sigmoid-ties": dict(score="sigmoid", norm_topk=True),
+}
+
+
+@pytest.mark.parametrize("checkpoint", [False, True],
+                         ids=["plain", "checkpoint"])
+@pytest.mark.parametrize("router", list(_ROUTERS))
+def test_route_equals_the_parents_formulation(router, checkpoint):
+    """``_route`` — its selection one op with a compare-and-sum behind it —
+    against ``lax.top_k`` + ``take_along_axis`` + their scatter-add, kept
+    here: the gates, the experts slot by slot, the token counts, every
+    statistic, and the gradients that reach the router and the tokens,
+    all equal to the last bit."""
+    cfg = DroplessConfig(32, 6, 16, 8, 4, **_ROUTERS[router])
+    params = init_dropless_params(jax.random.PRNGKey(3), cfg)
+    if cfg.score == "sigmoid":
+        params["bias"] = 0.3 * jax.random.normal(jax.random.PRNGKey(4), (32,))
+    if router == "sigmoid-ties":
+        params["router"] = params["router"].at[:, ::3].set(0.0)
+        params["bias"] = jnp.zeros((32,)).at[::3].set(1.0)
+    seqs = 4
+    x = jax.random.normal(jax.random.PRNGKey(5), (seqs * 24, 16))
+    w = jax.random.normal(jax.random.PRNGKey(6), (seqs * 24, 6))
+
+    def run(route):
+        def loss(p, x):
+            gate, expert, slot_expert, tokens, stats = route(p, x, cfg, seqs)
+            total = (gate * w).sum() + (gate ** 2).sum() + sum(
+                (v ** 2).sum() for name, v in stats.items()
+                if name != "tokens")
+            return total, (gate, expert, slot_expert, tokens, stats)
+        if checkpoint:
+            loss = jax.checkpoint(loss)
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1),
+                                          has_aux=True))(params, x)
+
+    (got_loss, got), (got_p, got_x) = run(moe_mod._route)
+    (want_loss, want), (want_p, want_x) = run(_route_parent)
+    assert got[1].dtype == want[1].dtype == jnp.int32
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert set(got[4]) == set(want[4])
+    np.testing.assert_array_equal(np.asarray(got_loss), np.asarray(want_loss))
+    np.testing.assert_array_equal(np.asarray(got_x), np.asarray(want_x))
+    np.testing.assert_array_equal(np.asarray(got_p["router"]),
+                                  np.asarray(want_p["router"]))
+    assert float(np.abs(np.asarray(got_p["router"])).max()) > 0.0
+    if cfg.score == "sigmoid":  # the bias selects and carries no gradient
+        assert not np.asarray(got_p["bias"]).any()
+    if router == "sigmoid-ties":  # the lower lanes of the tie, in order
+        assert (np.asarray(got[1]) == np.arange(0, 18, 3)).all()
+
+
+@pytest.mark.parametrize("checkpoint", [False, True],
+                         ids=["plain", "checkpoint"])
+@pytest.mark.parametrize("router", [{}, SIGMOID], ids=["softmax", "sigmoid"])
+def test_route_lowers_without_sort_gather_or_scalar_scatter(
+        monkeypatch, router, checkpoint):
+    """The gradient of one expert layer, lowered for a TPU: ``_route`` leaves
+    no sort (the one sort left is ``moe.dispatch``'s argsort by expert), no
+    gather and no scatter under ``moe.route``, and one ``top_k_rows`` call a
+    layer and pass (+ 1 under ``checkpoint``); the only scatter with scalar
+    updates is ``d_w.at[slots].add`` of the chunked backward, as before."""
+    import re
+
+    from harmony_tpu.utils import platform
+
+    monkeypatch.setattr(platform, "trace_is_tpu", lambda: True)
+    cfg = DroplessConfig(64, 4, 128, 64, 8, **router)
+    params = jax.eval_shape(
+        lambda: init_dropless_params(jax.random.PRNGKey(0), cfg))
+    x = jax.ShapeDtypeStruct((1024, 128), jnp.bfloat16)
+    texts = {}
+    for layer in (moe_ffn_dropless, _full_length):
+        texts[layer] = jax.jit(jax.value_and_grad(
+            _loss(layer, cfg, checkpoint), argnums=(0, 1), has_aux=True)
+        ).trace(params, x).lower(lowering_platforms=("tpu",)).as_text(
+            debug_info=True)
+    text = texts[moe_ffn_dropless]
+    calls = 2 if checkpoint else 1
+    assert len(re.findall(r'kernel_name = "harmony_top_k_rows"', text)) \
+        == calls, re.findall(r"kernel_name = \S+", text)
+    named = dict(re.findall(r'(#loc\d+) = loc\("([^"]*)"', text))
+
+    def under_route(op):
+        """Uses of ``op`` whose location names the ``moe.route`` scope."""
+        out = []
+        for m in re.finditer(rf'"?{re.escape(op)}"?[ (].*?loc\((#loc\d+)\)',
+                             text):
+            where = named.get(m.group(1), "")
+            if "moe.route" in where:
+                out.append(where)
+        return out
+
+    assert "moe.route" in " ".join(named.values())
+    assert under_route("stablehlo.dot_general")  # the reader finds the scope
+    for op in ("stablehlo.sort", "stablehlo.gather", "stablehlo.scatter",
+               "chlo.top_k"):
+        assert not under_route(op), (op, under_route(op))
+    assert "chlo.top_k" not in text
+    # scatters with scalar updates: the chunked backward's one, and none in
+    # the full-length formulation, which has no other
+    def scalar(t):
+        """Scatters whose updates are scalars: one a row of the indices."""
+        found = re.findall(
+            r'"stablehlo\.scatter"\(.*?\}\) : \([^)]*, tensor<([\dx]+)xi32>, '
+            r'tensor<([\dx]+)xf32>\) -> ', t, re.S)
+        return sum(at.rsplit("x", 1)[0] == updates for at, updates in found)
+
+    assert (scalar(text), scalar(texts[_full_length])) == (1, 0)
+    # the guard sees the parent's router where it stands
+    parent = jax.jit(jax.grad(
+        lambda p, x: (_route_parent(p, x, cfg, 1)[0] ** 2).sum())
+    ).trace(params, x).lower(lowering_platforms=("tpu",)).as_text()
+    assert scalar(parent) == 1 and "chlo.top_k" in parent
+
+
+def test_the_top_k_plan_reaches_kernel_plans():
+    """... and the selection kernel's: the token tile its plan gives, the
+    experts, ``k``, and the tiles a call walks — one row a shape, whichever
+    pass traced it."""
+    from harmony_tpu.ops.top_k_rows import KERNEL_NAME, tile_plan
+    from harmony_tpu.runtime import progcache
+    from harmony_tpu.tracing import trace_span
+
+    cfg = DroplessConfig(64, 4, 128, 64, 8, **SIGMOID)
+    params = jax.eval_shape(
+        lambda: init_dropless_params(jax.random.PRNGKey(0), cfg))
+    x = jax.ShapeDtypeStruct((1024, 128), jnp.bfloat16)
+    with trace_span("job.build_step", job_id="plan-top-k"):
+        jax.jit(jax.grad(lambda p, x: _loss(moe_ffn_dropless, cfg)(p, x)[0])
+                ).trace(params, x)
+    plan, = [r for r in progcache.kernel_plans()["plan-top-k"]
+             if r["kernel"] == KERNEL_NAME]
+    assert KERNEL_NAME == "harmony_top_k_rows"  # no grouped-matmul family's
+    assert (plan["block_q"], plan["block_k"], plan["sub"]) == (512, 64, 4)
+    assert (plan["grid_steps"], plan["d"], plan["dv"]) == (2, 64, 4)
+    assert plan["planned"] is True
+    # the cells' plans: 512 tokens a tile at every router's width
+    for tokens, experts, weighed in ((8192, 512, True), (8192, 256, True),
+                                     (16384, 64, True), (16384, 64, False)):
+        assert tile_plan(tokens, experts, weighed) == 512
+    assert tile_plan(8192, 2048, True) == 256  # wider rows: a smaller tile
+    assert tile_plan(60, 64, True) == 60       # no divisor: one tile

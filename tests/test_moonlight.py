@@ -588,7 +588,11 @@ def test_a_tiny_moonlight_tenant_through_the_jobserver_equals_the_replay():
     plans = {p["kernel"]: p for p in progcache.kernel_plans().get(
         "moonlight-tiny", [])}
     assert {"harmony_gmm_fwd", "harmony_gmm_dx", "harmony_gmm_dw"} <= set(plans)
-    assert {(p["d"], p["dv"]) for p in plans.values()} == {(64, 32), (32, 64)}
+    assert {(p["d"], p["dv"]) for name, p in plans.items()
+            if name.startswith("harmony_gmm_")} == {(64, 32), (32, 64)}
+    # the router's selection (PR 43): top-2 of 8, sigmoid scores + bias
+    assert (plans["harmony_top_k_rows"]["block_k"],
+            plans["harmony_top_k_rows"]["sub"]) == (8, 2)
 
 
 def test_the_bias_row_is_bit_equal_after_adam_steps_through_the_jobserver():

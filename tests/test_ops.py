@@ -554,3 +554,123 @@ def test_flash_plan_is_recorded_at_trace_time():
         "sub": 1024, "planned": True, "d": 64, "dv": 64, "grid_steps": 192}
     assert rows["harmony_flash_bwd_dkv"]["grid_steps"] == 192
     assert rows["harmony_flash_bwd_dq"]["sub"] == 512
+
+
+# -- the router's selection: harmony_top_k_rows (ops/top_k_rows.py) ----------
+
+def _scores(tokens, experts, ties, seed=0):
+    """``[tokens, experts]`` float32 scores in (0, 1]: ``"none"`` distinct
+    sigmoid scores; ``"ties"`` scores rounded to one decimal (every row
+    repeats its values), rows saturated at exactly 1.0 in several lanes, and
+    constant rows."""
+    rng = np.random.default_rng(seed)
+    s = 1.0 / (1.0 + np.exp(-3.0 * rng.normal(size=(tokens, experts))))
+    if ties == "ties":
+        s = np.round(s, 1)
+        s[1::5, ::3] = 1.0
+        s[2::5] = 0.5
+        s[3::5] = 1.0
+    return jnp.asarray(s.astype(np.float32))
+
+
+@pytest.mark.parametrize("ties", ["none", "ties"])
+@pytest.mark.parametrize("weighed", [False, True], ids=["sel", "sel+val"])
+@pytest.mark.parametrize("tokens,experts,k", [
+    (128, 64, 6), (96, 64, 8), (64, 256, 8), (32, 512, 22), (40, 8, 2),
+    (60, 8, 2),   # a token count no tile divides: one block
+])
+def test_top_k_rows_equals_lax_top_k(tokens, experts, k, weighed, ties):
+    """The kernel (interpret mode) against ``lax.top_k`` +
+    ``take_along_axis``, bit for bit: the same experts in the same order —
+    ties to the LOWER index — and the weights at those lanes."""
+    from harmony_tpu.ops.top_k_rows import (tile_plan, top_k_rows,
+                                            top_k_rows_ref)
+
+    sel = _scores(tokens, experts, ties)
+    val = _scores(tokens, experts, "none", seed=1) if weighed else None
+    assert tile_plan(tokens, experts, weighed) == {
+        128: 128, 96: 32, 64: 64, 32: 32, 40: 8, 60: 60}[tokens]
+    weight, expert = jax.jit(
+        lambda s, v: top_k_rows(s, v, k, interpret=True))(sel, val)
+    want_w, want_e = top_k_rows_ref(sel, val, k)
+    assert expert.dtype == jnp.int32 and weight.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(expert), np.asarray(want_e))
+    np.testing.assert_array_equal(np.asarray(weight), np.asarray(want_w))
+    if ties == "ties":  # the constant rows: lanes 0 .. k-1, in order
+        np.testing.assert_array_equal(np.asarray(expert)[2], np.arange(k))
+
+
+def test_top_k_rows_orders_as_lax_top_k_orders():
+    """The issue's row, and the total order of the bits behind it: ``+0.0``
+    before ``-0.0`` (XLA's sort comparator), negative scores, an infinity."""
+    from harmony_tpu.ops.top_k_rows import top_k_rows
+
+    rows = jnp.asarray([[1, 3, 3, 2, 3, 1, 0, 0],
+                        [0.0, -0.0, 0.0, -0.0, 1.0, -1.0, 0.0, -0.0],
+                        [-3, -1, -2, -1, -5, -1, -4, -np.inf],
+                        [np.inf, 2, np.inf, -2, 0, 1, -np.inf, 2]],
+                       jnp.float32)
+    _, expert = top_k_rows(rows, None, 4, interpret=True)
+    assert np.asarray(expert)[0].tolist() == [1, 2, 4, 3]
+    np.testing.assert_array_equal(np.asarray(expert),
+                                  np.asarray(jax.lax.top_k(rows, 4)[1]))
+    with pytest.raises(ValueError, match="top_k_rows"):
+        top_k_rows(rows.astype(jnp.bfloat16), None, 4, interpret=True)
+    with pytest.raises(ValueError, match="top_k_rows"):
+        top_k_rows(rows, None, 9, interpret=True)
+
+
+@pytest.mark.parametrize("checkpoint", [False, True],
+                         ids=["plain", "checkpoint"])
+@pytest.mark.parametrize("weighed", [False, True], ids=["sel", "sel+val"])
+@pytest.mark.parametrize("tokens,experts,k", [
+    (96, 64, 6), (64, 256, 8), (32, 512, 22), (60, 8, 2)])
+def test_top_k_rows_gradient_equals_the_scatter_adds(tokens, experts, k,
+                                                     weighed, checkpoint):
+    """``jax.grad`` through the op — a compare-and-sum — equals ``jax.grad``
+    through the XLA formulation — a scatter-add — bit for bit; where ``val``
+    is given the selecting scores get exactly zero."""
+    from harmony_tpu.ops.top_k_rows import top_k_rows, top_k_rows_ref
+
+    sel = _scores(tokens, experts, "ties")
+    val = _scores(tokens, experts, "none", seed=1) if weighed else None
+    g = jnp.asarray(np.random.default_rng(2).normal(size=(tokens, k)),
+                    jnp.float32)
+    grads = []
+    for op in (lambda s, v: top_k_rows(s, v, k, interpret=True),
+               lambda s, v: top_k_rows_ref(s, v, k)):
+        def loss(s, v, op=op):
+            weight, _ = op(s, v)
+            return (weight * g).sum() + (weight ** 2).sum()
+        if checkpoint:
+            loss = jax.checkpoint(loss)
+        grads.append(jax.jit(jax.grad(
+            loss, argnums=(0, 1) if weighed else 0))(sel, val))
+    got, want = (jax.tree_util.tree_leaves(t) for t in grads)
+    assert len(got) == len(want) == 1 + weighed
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert float(np.abs(np.asarray(got[-1])).max()) > 0.0
+    if weighed:
+        assert not np.asarray(got[0]).any()
+
+
+def test_top_k_rows_lowers_for_tpu_without_sort_gather_or_scatter():
+    """Forward and backward at Nemotron-H's router cross-lower through the
+    Pallas TPU front end as ONE kernel — whose name no expert-matmul pattern
+    matches (``perf/layer_metrics/_moe_kernels.py``) — and plain elementwise
+    work."""
+    import re
+
+    from harmony_tpu.ops.top_k_rows import KERNEL_NAME, top_k_rows
+
+    x = jax.ShapeDtypeStruct((8192, 512), jnp.float32)
+    text = jax.jit(jax.grad(
+        lambda s, v: top_k_rows(s, v, 22)[0].sum(), argnums=(0, 1))
+    ).trace(x, x).lower(lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") == 1 and KERNEL_NAME in text
+    assert KERNEL_NAME == "harmony_top_k_rows"
+    assert not re.match(r"harmony_(gmm|moe)_", KERNEL_NAME)
+    for op in ("stablehlo.sort", "stablehlo.scatter", "stablehlo.gather",
+               "chlo.top_k"):
+        assert op not in text, op

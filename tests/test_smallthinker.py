@@ -438,30 +438,35 @@ def test_windowed_calls_carry_their_own_names_and_plan_columns(monkeypatch):
 
 #: sha256 (first 16 hex digits) of each accepted LM configuration's
 #: loss-and-gradient program at its rehearse preset over 1,024 positions in
-#: bfloat16, traced for a TPU, RECORDED ON THE PARENT of this PR (commit
-#: 04416a6): the jaxpr's text (kernel bodies and index maps included), and
-#: the lowered StableHLO with each Mosaic kernel's serialized body cut out
+#: bfloat16, traced for a TPU, RECORDED ON THE PARENT of PR 36 (commit
+#: 04416a6) unless said otherwise below: the jaxpr's text (kernel bodies
+#: and index maps included), and the lowered StableHLO with each Mosaic kernel's serialized body cut out
 #: (it carries source lines). The new fields at their defaults must trace
 #: these programs; a PR that means to change one records it again here.
 PARENT_PROGRAMS = {
     "gpt2-124m": ("39d757c8d62c52d0", "f24e521c6f1b759e"),
-    "olmoe-1b-7b": ("99ea9dafb2bff55d", "4c3e6855a920bbd3"),
-    "moonlight-16b-a3b": ("64405e693a7be5ee", "dc4d831f97c232bb"),
-    "kimi-linear-48b-a3b": ("4237db3deb745649", "f6de1660c093e2b3"),
+    # every expert model RECORDED AGAIN BY PR 43, which meant to change these
+    # seven and not gpt2's: the router's selection is ``harmony_top_k_rows``
+    # (ops/top_k_rows.py) where ``lax.top_k`` + ``take_along_axis`` stood.
+    # The parent's (commit f8cd6be), in this order: 99ea9dafb2bff55d /
+    # 4c3e6855a920bbd3, 64405e693a7be5ee / dc4d831f97c232bb, 4237db3deb745649
+    # / f6de1660c093e2b3
+    "olmoe-1b-7b": ("2072f548af7248f4", "4b53909c4639758e"),
+    "moonlight-16b-a3b": ("c5d9c8027f4acd6d", "83dc38593d13b721"),
+    "kimi-linear-48b-a3b": ("613a652ead1e7c25", "822ef39f72cedb41"),
     # the same two with 8 of 64 experts held, top-4: the chunked expert
     # layer and its hand-written backward (the rehearse presets hold half
-    # their experts and take the full-length pass). RECORDED AGAIN BY PR 37,
-    # which meant to change these two and no other: the layer's row sums
-    # are the kernel of ops/sum_rows.py where XLA's scatter-adds stood (the
-    # parent's: 8ac21d0754a963b9 / 9235a672c903a8a1 and 7c4a94c76275e189 /
-    # a212a063412dcaa1)
-    "moonlight-16b-a3b+chunked": ("a4926d2815adc9a1", "b95e5090d1b8c013"),
-    "kimi-linear-48b-a3b+chunked": ("5e16810afaf161cd", "a87befccf5a25f54"),
-    # SmallThinker's own preset, RECORDED ON THE PARENT OF PR 38 (commit
-    # f66bdc2), which added layers of one sublayer, a state-space mixer and
-    # latent ungated experts beside these five tenants' programs
-    "smallthinker-21b-a3b": ("ca2c218290bfadc4", "f29d583db0040dd7"),
-    "smallthinker-21b-a3b+chunked": ("70aaf04974a21872", "cb19e362794a5553"),
+    # their experts and take the full-length pass); PR 37 recorded these two
+    # when the layer's row sums became the kernel of ops/sum_rows.py (the
+    # parent's of PR 43: a4926d2815adc9a1 / b95e5090d1b8c013 and
+    # 5e16810afaf161cd / a87befccf5a25f54)
+    "moonlight-16b-a3b+chunked": ("35221c1eb04af432", "715a9f21cc4f8472"),
+    "kimi-linear-48b-a3b+chunked": ("900b195c6eb54df7", "76c2b3e32f6b2c41"),
+    # SmallThinker's own preset (the parent's of PR 43: ca2c218290bfadc4 /
+    # f29d583db0040dd7 and 70aaf04974a21872 / cb19e362794a5553, recorded on
+    # the parent of PR 38)
+    "smallthinker-21b-a3b": ("bf626cc9ea895962", "be5b78b8fdfb037f"),
+    "smallthinker-21b-a3b+chunked": ("a8edc618dcb24dcd", "f1350c827e6a9a11"),
 }
 CHUNKED = {"moe_experts": 64, "moe_top_k": 4, "moe_experts_held": 8}
 
