@@ -22,9 +22,6 @@ measures (b) plus the other primitives a capacity-planning reader needs:
              chip's peak) — the ceiling every MXU-shaped op is judged
              against (BASELINE.md measurement plan; per-batch analogue of
              the reference's metrics.avsc:164-201 compute records).
-  mxupush    the size-gated MXU duplicate-fold push route (one-hot matmul
-             fold, table/table.py) vs the scatter route — GB/s both ways
-             plus the fold's achieved FLOP/s.
   ringflash  the ring-attention flash inner compiled under shard_map —
              correctness + speed vs the einsum inner (gates flipping
              ring_attention's inner='auto' to flash-on-TPU).
@@ -43,7 +40,7 @@ meaningful peak). Run on the real chip and commit the JSON.
              binding resource, expected-MFU range with stated basis. No
              device needed.
 
-Run:  python benchmarks/micro.py [table|reshard|attention|multiget|sparse|mxu|mxupush|ringflash|stall|chkp|roofline|all]
+Run:  python benchmarks/micro.py [table|reshard|attention|multiget|sparse|mxu|ringflash|stall|chkp|roofline|all]
 
 Each section prints one JSON line so results diff cleanly across rounds.
 Uses whatever backend JAX is pointed at (set
@@ -423,74 +420,6 @@ def bench_roofline() -> dict:
                      "replace this as captures land")}
 
 
-def bench_mxupush() -> dict:
-    """The keyed-push routes: XLA scatter vs the MXU duplicate-fold
-    (one-hot matmul, table/table.py push via='mxu') ACROSS shapes, plus
-    the AUTOTUNED choice (table/autotune.choose_push_route) — the round-3
-    acceptance is chosen == best-of-both per shape (the old static
-    capacity//256 gate picked the measured-slower route on chip)."""
-    from harmony_tpu.table import autotune
-
-    mesh = _mesh()
-    # (capacity, width, nkeys): duplicate-heavy, sparse-into-huge, medium
-    shapes = [(4096, 256, 8192), (65536, 64, 4096), (16384, 128, 16384)]
-    rng = np.random.default_rng(0)
-    out = {"metric": "mxu push route (measured choice vs best-of-both)",
-           "unit": "GB/s", "devices": len(mesh.devices.flat), "shapes": []}
-    mischosen = 0
-    for capacity, width, nkeys in shapes:
-        spec = TableSpec(TableConfig(
-            table_id=f"bench-mp-{capacity}-{width}", capacity=capacity,
-            value_shape=(width,), num_blocks=64, update_fn="add",
-        ))
-        table = DenseTable(spec, mesh)
-        keys = jnp.asarray(rng.integers(0, capacity, nkeys), jnp.int32)
-        deltas = jnp.asarray(
-            rng.standard_normal((nkeys, width)), np.float32)
-        push_bytes = nkeys * width * 4
-        # deltas gain a zero-weight dependency on the loop-carried array
-        # so the fold/scatter operand is NOT loop-invariant inside
-        # timed_inner's fori_loop — XLA would hoist the one-hot fold out
-        # of the loop and the section would time a dense add
-        t_scatter = _time_inner(
-            lambda a: spec.push(a, keys, deltas + 0.0 * a[0, 0],
-                                via="scatter"),
-            table.array)
-        t_mxu = _time_inner(
-            lambda a: spec.push(a, keys, deltas + 0.0 * a[0, 0], via="mxu"),
-            table.array)
-        chosen = autotune.choose_push_route(spec, mesh, nkeys, table=table)
-        best = "mxu" if t_mxu < t_scatter else "scatter"
-        # a mischoice only counts when the routes differ beyond noise
-        # (autotune and this bench time with different harnesses; at a
-        # near-tie shape either answer is right)
-        if chosen != best and abs(t_mxu - t_scatter) > 0.1 * max(t_mxu,
-                                                                 t_scatter):
-            mischosen += 1
-        row = {
-            "capacity": capacity, "width": width, "keys": nkeys,
-            "scatter_gbps": round(push_bytes / t_scatter / 1e9, 2),
-            "mxu_gbps": round(push_bytes / t_mxu / 1e9, 2),
-            "chosen": chosen, "best": best,
-            "chosen_gbps": round(
-                push_bytes / (t_mxu if chosen == "mxu" else t_scatter)
-                / 1e9, 2),
-        }
-        # the fold is a [capacity, nkeys] x [nkeys, width] one-hot matmul
-        fold_flops = 2 * capacity * nkeys * width
-        row["fold_mfu"] = _mfu(fold_flops / t_mxu)
-        out["shapes"].append(row)
-        table.drop()
-    # headline: the chosen-route bandwidth at the duplicate-heavy shape
-    out["value"] = out["shapes"][0]["chosen_gbps"]
-    out["mischosen_shapes"] = mischosen
-    out["old_static_gate_note"] = (
-        "static capacity//256 routed shape 0 to mxu; the measurement now "
-        "decides per shape"
-    )
-    return out
-
-
 def bench_multiget() -> dict:
     """Host-path random-key access (sparse/irregular pulls)."""
     mesh = _mesh()
@@ -722,7 +651,6 @@ SECTIONS = {
     "multiget": bench_multiget,
     "sparse": bench_sparse,
     "mxu": bench_mxu,
-    "mxupush": bench_mxupush,
     "ringflash": bench_ringflash,
     "stall": bench_stall,
     "chkp": bench_chkp,
@@ -739,7 +667,6 @@ SECTION_METRICS = {
     "multiget": ("host multi_get+multi_update", "keys/sec"),
     "sparse": ("sparse table fused pull+push", "keys/sec"),
     "mxu": ("mxu_dot bf16 achieved", "TFLOP/s"),
-    "mxupush": ("mxu push route", "GB/s"),
     "stall": ("live migration stall", "sec"),
     "chkp": ("checkpoint save/restore", "MB/s stage"),
     "roofline": ("analytic roofline (v5e model)", "min expected flash fwd MFU"),

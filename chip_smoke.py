@@ -14,7 +14,6 @@ What it does, in ONE process (the process that owns the chips):
   3. kernels    every Pallas kernel COMPILED on the device (never
                 ``interpret=True``) against its reference: ``gather_rows`` vs
                 ``table[idx]``, ``scatter_add_rows`` vs ``.at[idx].add``,
-                ``segment_sum_rows`` at its VMEM bound,
                 ``weighted_histogram`` vs ``xla_histogram``, flash attention
                 forward and gradients vs ``blockwise_attention`` at the LM's
                 shapes in bf16;
@@ -25,7 +24,7 @@ What it does, in ONE process (the process that owns the chips):
                 then the transformer LM (benchmarks/lm.py's widths,
                 ``attn="auto"`` — which must trace the flash kernels) beside
                 one keyed tenant whose 128-wide rows take the Pallas gather
-                and the measured push route; WAIT, STATUS, SHUTDOWN. A few
+                and the Pallas row scatter-add; WAIT, STATUS, SHUTDOWN. A few
                 steps each; every tenant must step, stay finite and improve.
 
 and, when the machine has four chips:
@@ -148,12 +147,14 @@ def lm_job(job_id: str = "smoke-lm", *, widths: Dict[str, int] = LM_WIDTHS,
         epochs=epochs, batches=batches, user=user)
 
 
-def fm_job(job_id: str = "smoke-fm", *, vocab: int = 16384, slots: int = 8,
+def fm_job(job_id: str = "smoke-fm", *, vocab: int = 16383, slots: int = 8,
            emb_dim: int = 127, n: int = 8192, epochs: int = 3,
            batches: int = 4):
     """The keyed tenant: a factorization machine whose rows are
-    1 + emb_dim = 128 floats wide — the width the Pallas gather takes —
-    pulled and pushed by key every step."""
+    1 + emb_dim = 128 floats wide — the width the Pallas gather and
+    scatter-add take — pulled and pushed by key every step. With the bias
+    row the table is 2^14 rows in 256 blocks of 64: whole 8-row tiles, which
+    the in-place scatter-add needs (``TableSpec.push_lowering``)."""
     return _job(
         job_id, "harmony_tpu.apps.widedeep:FMTrainer",
         {"vocab_size": vocab, "num_slots": slots, "emb_dim": emb_dim,
@@ -344,7 +345,7 @@ def phase_kernels() -> Dict[str, Any]:
     rng = np.random.default_rng(0)
 
     # -- gather_rows vs table[idx] (the FM tenant's table and key count) --
-    rows, width, nkeys = 16640, 128, 16385
+    rows, width, nkeys = 16384, 128, 16385
     table = jnp.asarray(rng.standard_normal((rows, width), dtype=np.float32))
     idx = rng.integers(0, rows, nkeys).astype(np.int32)
     got = np.asarray(jax.jit(sparse.gather_rows)(table, jnp.asarray(idx)))
@@ -372,27 +373,6 @@ def phase_kernels() -> Dict[str, Any]:
         "scatter_add_rows != .at[idx].add(deltas)")
     out["scatter_add_rows"] = {"table": [rows, width], "keys": nkeys,
                                "max_abs_err": 0.0}
-
-    # -- segment_sum_rows at the accumulator's VMEM bound ------------------
-    acc_rows = sparse._ACC_VMEM_BYTES // (width * 4)
-    _require(sparse.segment_sum_kernel_ok((4096, width), jnp.float32, acc_rows),
-             "fold kernel refuses its own bound")
-    seg = rng.integers(-4, acc_rows + 4, 4096).astype(np.int32)  # dups + OOB
-    counts = rng.integers(-3, 4, (4096, width)).astype(np.float32)
-    fold = jax.jit(lambda d, i: sparse.segment_sum_rows(d, i, acc_rows))
-    ref = jax.jit(lambda d, i: sparse.segment_sum_rows_ref(d, i, acc_rows))
-    _require(np.array_equal(
-        np.asarray(fold(jnp.asarray(counts), jnp.asarray(seg))),
-        np.asarray(ref(jnp.asarray(counts), jnp.asarray(seg)))),
-        "segment_sum_rows != reference on integer-valued deltas")
-    reals = rng.standard_normal((4096, width), dtype=np.float32)
-    err = float(np.abs(
-        np.asarray(fold(jnp.asarray(reals), jnp.asarray(seg)))
-        - np.asarray(ref(jnp.asarray(reals), jnp.asarray(seg)))).max())
-    _require(err <= 1e-4, f"segment_sum_rows float error {err}")
-    out["segment_sum_rows"] = {"acc": [acc_rows, width],
-                               "acc_bytes": acc_rows * width * 4,
-                               "max_abs_err": err}
 
     # -- weighted_histogram vs xla_histogram -------------------------------
     # integer-valued weights: both routes are exact whatever precision the
@@ -490,7 +470,6 @@ def phase_jobs(srv: Server, ndev: int) -> Dict[str, Any]:
     import jax
 
     from harmony_tpu.parallel.mesh import build_mesh
-    from harmony_tpu.table import autotune
 
     t0 = time.perf_counter()
     trio = srv.run([mlr_job(), nmf_job(), lda_job()])
@@ -512,14 +491,14 @@ def phase_jobs(srv: Server, ndev: int) -> Dict[str, Any]:
     _require(kernels >= 3 * LM_WIDTHS["n_layers"],
              f"LM attn='auto' traced {kernels} Pallas calls, want "
              f">= {3 * LM_WIDTHS['n_layers']} (flash fwd + 2 bwd a layer)")
-    routes = [dict(nkeys=sig[4], **meas)
-              for sig, meas in autotune.measurements().items()]
-    _require(routes and all("mxu_sec" in r and "scatter_sec" in r
-                            for r in routes),
-             f"keyed tenant's push route was not measured: {routes}")
+    fm_layout = status["tenants"]["smoke-fm"]["table_layout"]
+    _require(fm_layout["push_lowering"] == "pallas_rows",
+             f"keyed tenant's push is not the Pallas row scatter-add: "
+             f"{fm_layout}")
     return {"trio_wall_s": round(t_trio, 1),
             "lm_fm_wall_s": round(t_pair, 1),
-            "lm_pallas_calls": kernels, "push_route_measurements": routes,
+            "lm_pallas_calls": kernels,
+            "fm_push_lowering": fm_layout["push_lowering"],
             "status_ledger_tenants": sorted(status["tenants"])}
 
 

@@ -10,8 +10,7 @@ program retraces (and recompiles) every time. Two rules:
 1. no construct-and-call — ``jax.jit(...)(...)`` / ``pjit(...)(...)``
    in one expression builds a wrapper and throws it away after one
    call. Hoist the wrapper (module scope, a table's ``_jitted`` cache,
-   or runtime/progcache). The one vouched-for one-shot site
-   (table/autotune.py) carries an inline allow pragma.
+   or runtime/progcache).
 2. step-shaped jits declare donation intent — any ``jax.jit(fn)`` whose
    traced function is named like a training step (``*step*``,
    ``*epoch*``, ``*superstep*``) must pass ``donate_argnums``

@@ -379,12 +379,11 @@ class WorkerTasklet:
             return metrics
         return {"_sync": jnp.ravel(arr)[0]}
 
-    def _step_core(self, push_route: str, mesh: Mesh):
+    def _step_core(self, mesh: Mesh):
         """The fused PULL/COMP/PUSH body shared by per-batch and per-epoch
         compilation. ``hyper`` is a dict of scalars (lr etc.) passed fresh
-        each dispatch so host-side decay is honored. ``push_route`` is the
-        RESOLVED keyed-push lowering and ``mesh`` the LAYOUT SNAPSHOT's
-        mesh — both threaded from the caller so the traced program is
+        each dispatch so host-side decay is honored. ``mesh`` is the LAYOUT
+        SNAPSHOT's mesh, threaded from the caller so the traced program is
         fully determined by its program-cache key (reading the live
         table's mesh here let a prewarm cache a target-key program whose
         sharding constraints pinned the OLD mesh)."""
@@ -498,8 +497,6 @@ class WorkerTasklet:
                 return new_arr, sync(metrics, new_arr)
 
         else:
-            push_via = push_route
-
             def _step(arr, batch, hyper):
                 with step_scope("table.pull"):
                     keys = trainer.pull_keys(batch)
@@ -510,8 +507,7 @@ class WorkerTasklet:
                         trainer.compute(model, batch, hyper),
                         replicate_on=mesh)                         # COMP
                 with step_scope("table.push"):
-                    new_arr = spec.push(arr, keys, delta,
-                                        via=push_via)              # PUSH
+                    new_arr = spec.push(arr, keys, delta)          # PUSH
                 return new_arr, sync(metrics, new_arr)
 
         return _step
@@ -526,11 +522,9 @@ class WorkerTasklet:
         spec = getattr(self.ctx.model_table, "spec", None)
         if not isinstance(spec, TableSpec):  # hash tables: no block rows
             return
-        route = self._push_route
-        if route in ("auto", "scatter"):
-            with on_mesh(mesh):
-                route = spec.push_lowering(self._pull_rows)
-        table_layout.note_push(self.job_id, spec.table_id, route)
+        with on_mesh(mesh):
+            lowering = spec.push_lowering(self._pull_rows)
+        table_layout.note_push(self.job_id, spec.table_id, lowering)
 
     def _note_update_lowering(self, mesh: Mesh) -> None:
         """STATUS ``tenants.<job>.table_layout.update_lowering`` /
@@ -549,37 +543,7 @@ class WorkerTasklet:
                 fold = spec.fold_lowering(rows, sections)
             table_layout.note_fold(self.job_id, spec.table_id, fold)
 
-    def _resolve_push_route(self) -> str:
-        """The table's keyed-push route with "mxu_auto" resolved by a
-        one-time MEASUREMENT at this job's actual push shape (the static
-        capacity//256 gate picked the measured-slower route on chip —
-        table/autotune.py). Cached process-wide per shape signature.
-
-        The measurement is an ad-hoc device dispatch, so the same guards
-        as _prewarm_layout apply: turnstiled or multi-process meshes keep
-        the static gate (shape-derived, deterministic on every process —
-        a noisy local timing could bake DIFFERENT lowerings into the same
-        SPMD step across processes)."""
-        table = self.ctx.model_table
-        via = getattr(table, "push_via", None)
-        if via != "mxu_auto" or self.trainer.pull_mode != "keys":
-            return via
-        if (self.dispatch_turn is not None
-                or self._mesh_spans_processes(table.mesh)):
-            return via  # static gate resolves deterministically in-trace
-        sample = tuple(
-            jax.ShapeDtypeStruct((self.data.batch_size, *tail), dt)
-            for tail, dt in self.data.array_specs()
-        )
-        nkeys = int(jax.eval_shape(self.trainer.pull_keys, sample).shape[0])
-        from harmony_tpu.table.autotune import choose_push_route
-
-        # a measurement that raises (a route that does not compile
-        # included) fails the build: it is never traded for the gate
-        return choose_push_route(table.spec, table.mesh, nkeys, table=table)
-
-    def _program_key(self, table_sharding, local_sharding,
-                     push_route) -> "tuple | None":
+    def _program_key(self, table_sharding, local_sharding) -> "tuple | None":
         """Structural signature of everything the jitted step traces, for the
         process-level program cache (runtime/progcache) — None opts out.
         Components: trainer behavior, table schema + layout SNAPSHOT (the
@@ -609,10 +573,9 @@ class WorkerTasklet:
         )
         hyper_sig = tuple(sorted(self.trainer.hyperparams().keys()))
         return (tsig, table_sig, local_sig, batch_sig, hyper_sig,
-                push_route,  # the BAKED lowering (measured; see caller)
                 self.data.num_mini_batches if self._use_fused_epoch() else None)
 
-    def _program_builders(self, tsh, lsh, push_route):
+    def _program_builders(self, tsh, lsh):
         """The step/epoch jit-wrapper constructors for a GIVEN layout
         snapshot — shared by _build_step (live layout) and _prewarm_layout
         (announced target layout)."""
@@ -620,14 +583,14 @@ class WorkerTasklet:
 
         def build_step():
             step = in_cache_key(
-                traced_on(mesh, self._step_core(push_route, mesh)))
+                traced_on(mesh, self._step_core(mesh)))
             if self.trainer.uses_local_table:
                 return jax.jit(step, out_shardings=((tsh, lsh), None),
                                donate_argnums=(0, 1))
             return jax.jit(step, out_shardings=(tsh, None), donate_argnums=0)
 
         def build_epoch():
-            step = traced_on(mesh, self._step_core(push_route, mesh))
+            step = traced_on(mesh, self._step_core(mesh))
             if self.trainer.uses_local_table:
 
                 def _epoch2(arr, larr, stacked, hyper):
@@ -681,8 +644,7 @@ class WorkerTasklet:
                        else table._make_sharding(new_mesh))
             if tsh_new == self._step_sharding:
                 return  # announced layout == live layout: nothing to warm
-            route = self._resolve_push_route()
-            key = self._program_key(tsh_new, None, route)
+            key = self._program_key(tsh_new, None)
             if key is None:
                 return  # uncacheable trainer: a throwaway warm helps nobody
             fused = self._use_fused_epoch()
@@ -709,8 +671,7 @@ class WorkerTasklet:
                 return  # program warm is chief-only: progcache is shared,
                 # so one worker's warm serves the whole job (N duplicate
                 # zero-table epochs would tax the very devices training on)
-            build_step, build_epoch = self._program_builders(
-                tsh_new, None, route)
+            build_step, build_epoch = self._program_builders(tsh_new, None)
             step = progcache.get_or_build((key, "step"), build_step)
             epoch_fn = (progcache.get_or_build((key, "epoch"), build_epoch)
                         if fused else None)
@@ -760,15 +721,10 @@ class WorkerTasklet:
         tsh = table.sharding
         lsh = self.ctx.local_table.sharding if self.trainer.uses_local_table else None
         prev_key = self._program_cache_key if self._built_once else None
-        # ONE route resolution per build, shared by the key and the traced
-        # body (two resolutions could drift across a transient failure and
-        # cache an executable under a key claiming a different lowering)
-        self._push_route = self._resolve_push_route()
-        self._program_cache_key = self._program_key(tsh, lsh, self._push_route)
+        self._program_cache_key = self._program_key(tsh, lsh)
         key = self._program_cache_key
 
-        build_step, build_epoch = self._program_builders(
-            tsh, lsh, self._push_route)
+        build_step, build_epoch = self._program_builders(tsh, lsh)
         self._step = progcache.get_or_build(
             None if key is None else (key, "step"), build_step
         )
@@ -906,15 +862,13 @@ class WorkerTasklet:
                 return spec.push_all(arr, jnp.zeros_like(model))
 
         else:
-            push_via = self._push_route  # resolved by _build_step
-
             def pull_fn(arr, batch):
                 return spec.pull(arr, trainer.pull_keys(batch))
 
             def pp_fn(arr, batch):
                 keys = trainer.pull_keys(batch)
                 rows = spec.pull(arr, keys)
-                return spec.push(arr, keys, jnp.zeros_like(rows), via=push_via)
+                return spec.push(arr, keys, jnp.zeros_like(rows))
 
         key = self._program_cache_key
         mesh = self.mesh  # the built step's layout snapshot (_build_step)
@@ -2679,7 +2633,6 @@ class FusedSparseStep:
         *,
         signature: Optional[Any] = None,
         donate: bool = True,
-        push_via: Optional[str] = None,
     ) -> None:
         from harmony_tpu.metrics.tracer import Tracer
         from harmony_tpu.table.hashtable import DeviceHashTable
@@ -2694,14 +2647,12 @@ class FusedSparseStep:
             raise TypeError(f"need a DenseTable, got {type(table).__name__}")
         self.table = table
         spec = table.spec
-        route = push_via if push_via is not None else table.push_via
-        self.push_route = route
         self.donate = bool(donate)
 
         def _step(arr, keys, *extra):
             rows = spec.pull(arr, keys)                    # PULL
             delta, aux = compute_fn(rows, *extra)          # COMP
-            new_arr = spec.push(arr, keys, delta, via=route)  # PUSH
+            new_arr = spec.push(arr, keys, delta)          # PUSH
             return new_arr, aux
 
         dn = (0,) if donate else ()
@@ -2711,7 +2662,7 @@ class FusedSparseStep:
 
             tsig = _pc.table_signature(table)
             if tsig is not None:
-                key = (tsig, "fused_sparse", signature, route, bool(donate))
+                key = (tsig, "fused_sparse", signature, bool(donate))
         mesh = table.mesh
         self._fn = progcache.get_or_build(
             key, lambda: jax.jit(traced_on(mesh, _step), donate_argnums=dn)

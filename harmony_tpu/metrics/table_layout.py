@@ -37,8 +37,7 @@ built (dolphin/worker.py ``_build_step``; ``TableSpec.push_lowering``):
     is the in-place Pallas row scatter-add (ops.sparse.scatter_add_rows),
     0 for any other lowering;
   * STATUS ``tenants.<job>.table_layout.push_lowering`` = ``"pallas_rows"``
-    / ``"xla"``, or the route's name where the route is not ``scatter``
-    (``"mxu"``, ``"sparse"``, ``"mxu_auto"``).
+    / ``"xla"``.
 
 And how a pull-all tenant's step applies its update, recorded at the same
 place (dolphin/worker.py ``update_lowering``; ``TableSpec.fold_lowering``):
@@ -122,7 +121,7 @@ def note_push(job: str, table_id: str, lowering: str) -> None:
     _note_lowering(get_registry().gauge(
         "harmony_table_push_pallas_rows",
         "1 when a tenant's keyed push is the in-place Pallas row "
-        "scatter-add, 0 for XLA's scatter or a fold route",
+        "scatter-add, 0 for XLA's scatter",
         ("job", "table")), job, table_id, "push_lowering", lowering,
         "pallas_rows")
 

@@ -1,4 +1,4 @@
-"""MXU one-hot histogram / segment reduction kernels.
+"""MXU one-hot histogram kernel.
 
 The GBT trainer's hot op is building per-(node, feature, bin) gradient /
 hessian / count histograms (ref: mlapps/gbt/GBTTrainer.java — the reference
@@ -12,8 +12,6 @@ tiles, N tiles); each step builds its tile's one-hot on the fly in VMEM
 transposed operand copy — and accumulates the (bins, W) product into the
 revisited output block. Tile sizes are clamped against a VMEM word budget
 so the kernel fits the scoped-VMEM limit (16 MB on v5e) at any input size.
-:func:`segment_sum` is the same op named for its other use — aggregating
-per-key push deltas by destination key (the table push path).
 
 :func:`xla_histogram` is the pure-XLA one-hot matmul reference; off-TPU
 callers take it by name (``interpret=True`` runs the kernel body in the
@@ -144,21 +142,3 @@ def weighted_histogram(
         name="harmony_weighted_histogram",
     )(ids.astype(jnp.int32)[None, :], weights)
     return out[:num_bins, :W]
-
-
-def segment_sum(
-    data: jnp.ndarray,
-    segment_ids: jnp.ndarray,
-    num_segments: int,
-    **kw,
-) -> jnp.ndarray:
-    """Sum rows of ``data`` [N, W] by ``segment_ids`` [N] -> [num_segments, W].
-
-    The push-aggregation primitive: fold duplicate-key deltas before the
-    table scatter (ref semantics: server-side UpdateFunction applies each
-    delta; pre-reducing on the worker is the TPU-friendly equivalent)."""
-    squeeze = data.ndim == 1
-    if squeeze:
-        data = data[:, None]
-    out = weighted_histogram(segment_ids, data, num_segments, **kw)
-    return out[:, 0] if squeeze else out

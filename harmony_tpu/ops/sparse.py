@@ -1,10 +1,9 @@
-"""Sparse table kernels — batched row gather, row scatter-add, and the
-row-granular segment-sum.
+"""Sparse table kernels — batched row gather and row scatter-add.
 
 The device ops that dominate keyed sparse workloads (FM / Wide&Deep on
 Criteo-shaped ids, NMF, LDA) are the table's keyed pull (multi_get: a
 batched embedding gather) and the keyed push (multi_update: every delta row
-added into its destination row, duplicate keys folding). Three Pallas
+added into its destination row, duplicate keys folding). Two Pallas
 kernels move rows without XLA's generic gather/scatter:
 
   * ``gather_rows`` leaves the table in HBM and issues one row DMA per
@@ -16,24 +15,18 @@ kernels move rows without XLA's generic gather/scatter:
     run of equal ids in VMEM — in occurrence order, on top of the table
     row — and moves each of a tile's distinct rows by one DMA each way.
     Its index operands come a tile at a time, so it takes any N.
-  * ``segment_sum_rows`` folds ALL duplicates into a ``[num_rows, W]``
-    accumulator resident in VMEM across the grid, for callers that then
-    apply one dense add over the table (the "sparse" push route).
 
 The kernels are TPU programs and this module never asks which platform
-it is on: ``gather_rows`` / ``scatter_add_rows`` / ``segment_sum_rows``
-ARE the kernels (``interpret=True`` runs their bodies in the Pallas
-interpreter, for CPU tests), ``*_ref`` are the jnp references, and
+it is on: ``gather_rows`` / ``scatter_add_rows`` ARE the kernels
+(``interpret=True`` runs their bodies in the Pallas interpreter, for CPU
+tests), ``*_ref`` are the jnp references, and
 callers that know their mesh pick by name (``TableSpec.pull`` /
 ``TableSpec.push``). ``*_kernel_ok`` say which shapes the kernels take.
 
 Numerical contract: the gather reference is value-identical to the kernel
 (a gather copies bytes); the scatter-add folds a key's deltas in the
 order they occur, the association of a serial scatter-add, and is
-deterministic; the segment-sum routes agree exactly when the folded
-values are addition-order-insensitive (integer-valued counts, no
-duplicate keys) and to float tolerance otherwise (duplicate folds may
-associate differently). On any ONE route the result is deterministic.
+deterministic.
 """
 from __future__ import annotations
 
@@ -51,13 +44,6 @@ _LANES = 128
 _SUBLANES = 8
 # Rows gathered per grid step (row DMAs in flight at once).
 _GATHER_TILE = 128
-# Accumulator-residency budget for the segment-sum kernel (bytes). The
-# whole [num_rows, W] accumulator block stays in VMEM across the grid
-# (same output block every step => consecutive-revisit residency); bigger
-# tables take the reference rather than thrash HBM per step.
-_ACC_VMEM_BYTES = 8 << 20
-# Delta rows folded per grid step (the scalar fold loop's span).
-_FOLD_TILE = 256
 
 
 def _clamp_rows(idx: jnp.ndarray, num_rows: int) -> jnp.ndarray:
@@ -359,97 +345,6 @@ def scatter_add_rows(
         interpret=interpret,
         name="harmony_scatter_add_rows",
     )(held, sid.reshape(-1), pos.reshape(-1), deltas, table)
-
-
-def _make_fold_kernel(num_rows: int, tile: int):
-    def _fold_kernel(idx_ref, delta_ref, acc_ref):
-        """Grid over delta tiles; the [num_rows, W] accumulator block is
-        the SAME output block every step, so it stays VMEM-resident and
-        the per-row folds are VMEM read-modify-writes. Rows fold in index
-        order (a sequential scalar loop), matching the reference's
-        scatter-add fold order for duplicate keys."""
-        i = pl.program_id(0)
-
-        @pl.when(i == 0)
-        def _init():
-            acc_ref[:] = jnp.zeros_like(acc_ref)
-
-        def body(j, _):
-            k = idx_ref[i * tile + j]
-            ok = (k >= 0) & (k < num_rows)
-            kc = jnp.clip(k, 0, num_rows - 1)
-            row = delta_ref[pl.ds(j, 1), :]
-            acc_ref[pl.ds(kc, 1), :] += jnp.where(ok, row, jnp.zeros_like(row))
-            return 0
-
-        jax.lax.fori_loop(0, tile, body, 0)
-
-    return _fold_kernel
-
-
-def segment_sum_kernel_ok(deltas_shape, dtype, num_rows: int) -> bool:
-    """Shapes the fold kernel takes: lane-tiled float32 rows and an
-    accumulator inside the VMEM residency budget."""
-    N, W = deltas_shape
-    return (N > 0 and W % _LANES == 0 and jnp.dtype(dtype) == jnp.float32
-            and num_rows * W * 4 <= _ACC_VMEM_BYTES)
-
-
-def segment_sum_rows_ref(
-    deltas: jnp.ndarray, idx: jnp.ndarray, num_rows: int
-) -> jnp.ndarray:
-    """The fold as one XLA scatter-add; out-of-range ids contribute
-    nothing."""
-    ok = (idx >= 0) & (idx < num_rows)
-    safe = jnp.where(ok, idx, 0)
-    masked = jnp.where(ok[:, None], deltas, jnp.zeros_like(deltas))
-    return jnp.zeros((num_rows, deltas.shape[1]), deltas.dtype).at[safe].add(masked)
-
-
-def segment_sum_rows(
-    deltas: jnp.ndarray,
-    idx: jnp.ndarray,
-    num_rows: int,
-    *,
-    interpret: bool = False,
-) -> jnp.ndarray:
-    """``out[k] = sum over i with idx[i]==k of deltas[i]`` — deltas [N, W],
-    idx [N] int32 -> [num_rows, W], as the Pallas kernel. Out-of-range ids
-    contribute nothing. The multi_update duplicate fold: the result is
-    applied to the table with ONE dense add (``TableSpec.push``
-    via="sparse"), like the mxu route but with a row-granular fold instead
-    of the one-hot matmul (ops/histogram.py) — cheaper when W is wide and
-    the key set is a small fraction of the table."""
-    if deltas.ndim != 2 or idx.ndim != 1 or idx.shape[0] != deltas.shape[0]:
-        raise ValueError(f"bad shapes deltas={deltas.shape} idx={idx.shape}")
-    N, W = deltas.shape
-    if not segment_sum_kernel_ok(deltas.shape, deltas.dtype, num_rows):
-        raise ValueError(
-            f"segment_sum_rows kernel takes float32 rows of a multiple of "
-            f"{_LANES} lanes and an accumulator of at most "
-            f"{_ACC_VMEM_BYTES} bytes; got deltas={deltas.shape} "
-            f"{deltas.dtype}, {num_rows} rows (use segment_sum_rows_ref)")
-    tile = min(_FOLD_TILE, -(-N // _SUBLANES) * _SUBLANES)
-    pad = (-N) % tile
-    idx32 = idx.astype(jnp.int32)
-    if pad:
-        # padded rows carry id -1: masked out inside the kernel
-        idx32 = jnp.pad(idx32, (0, pad), constant_values=-1)
-        deltas = jnp.pad(deltas, ((0, pad), (0, 0)))
-        N += pad
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(N // tile,),
-        in_specs=[pl.BlockSpec((tile, W), lambda i, idx_ref: (i, 0))],
-        out_specs=pl.BlockSpec((num_rows, W), lambda i, idx_ref: (0, 0)),
-    )
-    return pl.pallas_call(
-        _make_fold_kernel(num_rows, tile),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((num_rows, W), deltas.dtype),
-        interpret=interpret,
-        name="harmony_segment_sum_rows",
-    )(idx32, deltas)
 
 
 def value_width(value_shape) -> int:

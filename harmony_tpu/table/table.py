@@ -35,7 +35,6 @@ Architecture (deliberately NOT a translation):
 from __future__ import annotations
 
 import threading
-from functools import partial
 from typing import Callable, Dict, Optional, Sequence, Tuple
 
 import jax
@@ -189,25 +188,6 @@ def _scatter_on_mesh(mesh: Mesh, shards: int, flat: jnp.ndarray,
                          out_specs=rows, check_vma=False)(flat, idx, deltas)
 
 
-def _fold_on_mesh(mesh: Optional[Mesh], shards: int, fold,
-                  deltas: jnp.ndarray, idx: jnp.ndarray,
-                  num_rows: int) -> jnp.ndarray:
-    """``fold(deltas, idx, rows) -> [rows, W]`` (ids outside [0, rows)
-    contribute nothing) over the table's row shards: each device folds
-    only the keys that land in the rows it holds."""
-    if mesh is None or mesh.devices.size == 1:
-        return fold(deltas, idx, num_rows)
-    per = num_rows // shards
-
-    def local(d, ids):
-        lo = jax.lax.axis_index(MODEL_AXIS) * per if shards > 1 else 0
-        return fold(d, ids - lo, per)
-
-    rows = P(MODEL_AXIS) if shards > 1 else P()
-    return jax.shard_map(local, mesh=mesh, in_specs=(P(), P()),
-                         out_specs=rows, check_vma=False)(deltas, idx)
-
-
 class LayoutAnnouncerMixin:
     """Reshard announcements, shared by dense AND hash tables: the caller
     (TableHandle._announce_target) announces the TARGET mesh before the
@@ -345,8 +325,8 @@ class TableSpec:
         return mesh, row_shards(mesh, self.num_blocks)
 
     def push_lowering(self, n_keys: int) -> str:
-        """What ``push(via="scatter")`` of ``n_keys`` keys lowers to in
-        the program being traced: ``"pallas_rows"`` —
+        """What a ``push`` of ``n_keys`` keys lowers to in the program
+        being traced: ``"pallas_rows"`` —
         ops.sparse.scatter_add_rows, in place, per row shard — for an
         additive update fn on a TPU mesh over rows the kernel takes,
         stored in whole 8-row tiles (the kernel sees the storage as its
@@ -410,74 +390,25 @@ class TableSpec:
         """multiUpdate: fold ``deltas`` into the table; duplicate keys fold
         per the update fn's scatter_mode.
 
-        ``via`` picks the lowering of additive pushes:
-          * "scatter" — read-modify-write of the touched rows alone, in
-            place. Traced for a TPU mesh over float32 rows 128 wide it is
-            ops.sparse.scatter_add_rows (:meth:`push_lowering` says when):
-            the keys sorted a tile at a time, duplicates folded in VMEM in
-            occurrence order, each of a tile's distinct rows read and
-            written once by row DMA. Everywhere else, and for min / max /
-            set, one XLA scatter (duplicate keys serialise on TPU, 76 ns
-            a key whatever they repeat).
-          * "mxu" — pre-fold duplicates with the one-hot segment-sum matmul
-            (ops.histogram.segment_sum) and apply ONE dense add; the
-            temporary is table-sized (memory is always affordable, but the
-            dense add streams the whole table through HBM).
-          * "mxu_auto" — "mxu" when the push touches a meaningful fraction
-            of the table (>= capacity/256 keys — the dense-add bandwidth
-            amortises over duplicate folds), else "scatter" (a few rows
-            into a huge table: streaming the table would dominate).
-          * "sparse" — pre-fold duplicates with the row-granular
-            segment-sum (ops.sparse.segment_sum_rows) and apply ONE dense
-            add — the mxu route's shape without the table-sized one-hot
-            contraction.
-          * "auto" — "scatter". The spec cannot see which devices the
-            array lives on (the process default backend is NOT it — a CPU
-            table in a TPU-default process is normal in tests/benchmarks),
-            so platform-aware callers resolve DenseTable.push_via and pass
-            it explicitly.
+        An additive push reads, adds to and writes the touched rows alone,
+        in place. Traced for a TPU mesh over float32 rows 128 wide it is
+        ops.sparse.scatter_add_rows (:meth:`push_lowering` says when): the
+        keys sorted a tile at a time, duplicates folded in VMEM in
+        occurrence order, each of a tile's distinct rows read and written
+        once by row DMA, run per row shard. Everywhere else, and for min /
+        max / set, one XLA scatter (duplicate keys serialise on TPU, 76 ns
+        a key whatever they repeat).
 
-        The two folds and the scatter are Pallas kernels when traced for
-        a TPU mesh (utils.platform.on_mesh), run per row shard; their XLA
-        references (histogram.xla_histogram, sparse.segment_sum_rows_ref,
-        ``.at[].add``) everywhere else.
+        ``via`` names the route and there is one, "scatter" ("auto" is its
+        alias); anything else raises.
         """
+        if via not in ("scatter", "auto"):
+            raise ValueError(
+                f"unknown push route {via!r}: a keyed push has one route, "
+                "'scatter'"
+            )
         b, o = self.partitioner.locate(keys)
         mode = self.update_fn.scatter_mode
-        if via == "auto":
-            via = "scatter"
-        elif via == "mxu_auto":
-            dense_enough = keys.shape[0] >= max(32, self.config.capacity // 256)
-            via = "mxu" if mode == "add" and dense_enough else "scatter"
-        if via in ("mxu", "sparse"):
-            # both fold duplicates into a flat-row delta and apply ONE
-            # dense add; they differ only in the fold op (one-hot matmul
-            # vs row-granular Pallas/jnp segment-sum)
-            if mode != "add":
-                raise ValueError(f"via={via!r} requires an additive update fn")
-            from harmony_tpu.ops import histogram, sparse
-
-            n = keys.shape[0]
-            flat_idx = (b * self.block_size + o).astype(jnp.int32).reshape(-1)
-            rows2d = deltas.reshape(n, -1).astype(jnp.float32)
-            num_rows = self.num_blocks * self.block_size
-            mesh, shards = self._kernel_layout()
-            if via == "mxu":
-                fold = (histogram.segment_sum if mesh is not None
-                        else lambda d, i, r: histogram.xla_histogram(i, d, r))
-            elif mesh is not None and sparse.segment_sum_kernel_ok(
-                    rows2d.shape, rows2d.dtype, num_rows // shards):
-                fold = sparse.segment_sum_rows
-            else:
-                fold = sparse.segment_sum_rows_ref
-            folded = _fold_on_mesh(mesh, shards, fold, rows2d, flat_idx,
-                                   num_rows)
-            out = arr + folded.reshape(arr.shape).astype(arr.dtype)
-            if self.update_fn.post is not None:
-                out = out.at[b, o].set(self.update_fn.post(out[b, o]))
-            return out
-        if via != "scatter":
-            raise ValueError(f"unknown push route {via!r}")
         ref = arr.at[b, o]
         if self.push_lowering(keys.shape[0]) == "pallas_rows":
             # what ``.at[b, o].add`` does with an index outside its axis:
@@ -842,35 +773,12 @@ class DenseTable(LayoutAnnouncerMixin):
     get_or_init = get
     multi_get_or_init = multi_get
 
-    @property
-    def push_via(self) -> str:
-        """Platform-resolved keyed-push route: the size-gated MXU
-        duplicate-fold on an all-TPU mesh for additive tables, XLA scatter
-        everywhere else. ``HARMONY_PUSH_VIA`` (scatter|mxu|mxu_auto|sparse)
-        overrides — the operator rollback knob while on-chip measurements
-        of fold-vs-scatter at real shapes are still settling (the first
-        honest capture had scatter ahead at the bench shape); "sparse"
-        opts into the row-granular Pallas fold (ops/sparse.py)."""
-        from harmony_tpu.utils.platform import env_choice, mesh_is_tpu
-
-        forced = env_choice("HARMONY_PUSH_VIA",
-                            ("scatter", "mxu", "mxu_auto", "sparse"))
-        if forced:
-            return forced
-        return (
-            "mxu_auto"
-            if mesh_is_tpu(self._mesh)
-            and self.spec.update_fn.scatter_mode == "add"
-            else "scatter"
-        )
-
     def multi_update(self, keys: Sequence[int], deltas: np.ndarray) -> None:
         k = jnp.asarray(keys, dtype=jnp.int32)
         d = jnp.asarray(deltas)
         with self._lock:
-            self._arr = self._jitted(
-                "push", partial(self.spec.push, via=self.push_via)
-            )(self._arr, k, d)
+            self._arr = self._jitted("push", self.spec.push)(
+                self._arr, k, d)
             self._bump_data_version()
 
     def update(self, key: int, delta: np.ndarray) -> None:

@@ -265,12 +265,17 @@ class TestRound3Surfaces:
         g.report_unit_cost("j", 0.5)
         assert g.num_jobs() == 0
 
-    def test_autotune_surface(self):
-        from harmony_tpu.table import autotune
+    def test_keyed_push_surface(self):
+        """What perf/aot_compile.py calls: ``spec.push(arr, keys, deltas,
+        via="scatter")`` and ``spec.push_lowering(n_keys)``."""
+        import inspect
 
-        assert callable(autotune.choose_push_route)
-        autotune.reset()
-        assert autotune.measurements() == {}
+        from harmony_tpu.table import TableSpec
+
+        via = inspect.signature(TableSpec.push).parameters["via"]
+        assert via.kind is via.KEYWORD_ONLY and via.default == "auto"
+        assert list(inspect.signature(
+            TableSpec.push_lowering).parameters) == ["self", "n_keys"]
 
     def test_table_pod_surfaces(self, mesh8):
         from harmony_tpu.config.params import TableConfig

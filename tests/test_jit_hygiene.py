@@ -3,11 +3,10 @@
 Since PR 7 the two AST rules that lived here are the ``jit-hygiene``
 pass of harmonylint (harmony_tpu/analysis/passes/jit.py — the full
 suite also runs tree-wide in tests/test_analysis.py); these wrappers
-keep the original per-rule failure surface. The old file-level
-allowlist (table/autotune.py's one-shot push-route measurement) is now
-an inline ``# lint: allow(jit-hygiene) <reason>`` pragma at the call
-site, where the justification can't drift away from the code it
-vouches for.
+keep the original per-rule failure surface. There is no file-level
+allowlist: an exception is an inline ``# lint: allow(jit-hygiene)
+<reason>`` pragma at the call site, where the justification can't drift
+away from the code it vouches for (the package has none since PR 44).
 """
 from __future__ import annotations
 

@@ -14,7 +14,6 @@ from harmony_tpu.ops import (
     blockwise_attention,
     flash_attention,
     ring_attention,
-    segment_sum,
     weighted_histogram,
 )
 from harmony_tpu.ops.ring import ring_self_attention
@@ -93,11 +92,11 @@ def test_weighted_histogram_ignores_negative_ids():
     np.testing.assert_allclose(out[:, 0], [1.0, 1.0])
 
 
-def test_segment_sum_1d():
-    data = jnp.asarray([1.0, 2.0, 3.0, 4.0])
-    seg = jnp.asarray([0, 1, 0, 2], jnp.int32)
-    out = segment_sum(data, seg, 3, interpret=True)
-    np.testing.assert_allclose(out, [4.0, 2.0, 4.0])
+def test_weighted_histogram_single_column():
+    data = jnp.asarray([1.0, 2.0, 3.0, 4.0])[:, None]
+    ids = jnp.asarray([0, 1, 0, 2], jnp.int32)
+    out = weighted_histogram(ids, data, 3, interpret=True)
+    np.testing.assert_allclose(out[:, 0], [4.0, 2.0, 4.0])
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -235,9 +234,9 @@ def test_histogram_tile_picker_respects_vmem_budget():
         assert min(bn, bb, bw) >= 8
 
 
-def test_segment_sum_empty_input():
-    out = segment_sum(jnp.zeros((0, 4)), jnp.zeros((0,), jnp.int32), 16,
-                      interpret=True)
+def test_weighted_histogram_empty_input():
+    out = weighted_histogram(jnp.zeros((0,), jnp.int32), jnp.zeros((0, 4)),
+                             16, interpret=True)
     np.testing.assert_allclose(out, np.zeros((16, 4)))
 
 

@@ -30,8 +30,6 @@ from harmony_tpu.ops.sparse import (
     gather_rows_ref,
     scatter_add_rows,
     scatter_add_rows_ref,
-    segment_sum_rows,
-    segment_sum_rows_ref,
 )
 from harmony_tpu.table import DenseTable, TableSpec
 
@@ -64,33 +62,6 @@ def test_gather_rows_oob_clamps_like_jax_gather():
     np.testing.assert_array_equal(np.asarray(fallback[3]), np.asarray(table[7]))
 
 
-def test_segment_sum_rows_kernel_matches_fallback_exact_counts():
-    """Integer-valued folds are addition-order-insensitive: the kernel and
-    the fallback must agree bit for bit (the LDA count-table shape)."""
-    rng = np.random.default_rng(1)
-    deltas = jnp.asarray(
-        rng.integers(-3, 4, (200, 128)).astype(np.float32))
-    idx = jnp.asarray(rng.integers(0, 16, 200), jnp.int32)
-    kernel = segment_sum_rows(deltas, idx, 16, interpret=True)
-    fallback = segment_sum_rows_ref(deltas, idx, 16)
-    np.testing.assert_array_equal(np.asarray(kernel), np.asarray(fallback))
-
-
-def test_segment_sum_rows_kernel_matches_fallback_float():
-    rng = np.random.default_rng(2)
-    deltas = jnp.asarray(rng.normal(size=(100, 128)).astype(np.float32))
-    idx = jnp.asarray(rng.integers(-2, 12, 100), jnp.int32)  # incl. OOB
-    kernel = segment_sum_rows(deltas, idx, 10, interpret=True)
-    fallback = segment_sum_rows_ref(deltas, idx, 10)
-    np.testing.assert_allclose(np.asarray(kernel), np.asarray(fallback),
-                               atol=1e-5, rtol=1e-5)
-    # OOB ids (negative / >= num_rows) contribute nothing on either route
-    ok = (np.asarray(idx) >= 0) & (np.asarray(idx) < 10)
-    expect = np.zeros((10, 128), np.float32)
-    np.add.at(expect, np.asarray(idx)[ok], np.asarray(deltas)[ok])
-    np.testing.assert_allclose(np.asarray(kernel), expect, atol=1e-4)
-
-
 def test_kernels_refuse_shapes_they_cannot_tile():
     """The kernels ARE the kernels: a shape they cannot take is an error
     naming the reference, never a quiet switch of route."""
@@ -103,9 +74,6 @@ def test_kernels_refuse_shapes_they_cannot_tile():
     wide = jnp.zeros((8, 256), jnp.float32)  # tile-interleaved rows
     with pytest.raises(ValueError, match="gather_rows_ref"):
         gather_rows(wide, jnp.zeros((4,), jnp.int32), interpret=True)
-    with pytest.raises(ValueError, match="segment_sum_rows_ref"):
-        segment_sum_rows(jnp.zeros((4, 3), jnp.float32),
-                         jnp.zeros((4,), jnp.int32), 8, interpret=True)
 
 
 def _sequential_scatter_add(table, idx, deltas):
@@ -304,16 +272,11 @@ def _tpu_text(fn, *args):
 def test_sparse_kernels_lower_for_tpu():
     """A block shape or op the Pallas TPU lowering refuses (the old
     one-row gather block; pl.load/pl.store) can never again pass a
-    CPU-only review: both kernels must cross-lower, at a plain shape and
-    at the shapes that force padding."""
+    CPU-only review: the gather must cross-lower, at a plain shape and at
+    the shapes that force padding (the scatter-add's turn is below)."""
     table = jnp.zeros((1000, 128), jnp.float32)
     for n in (1, 8, 100, 4096):
         text = _tpu_text(gather_rows, table, jnp.zeros((n,), jnp.int32))
-        assert "tpu_custom_call" in text
-    for n, rows in ((5, 64), (256, 64), (1000, 16384)):
-        text = _tpu_text(
-            lambda d, i, rows=rows: segment_sum_rows(d, i, rows),
-            jnp.zeros((n, 128), jnp.float32), jnp.zeros((n,), jnp.int32))
         assert "tpu_custom_call" in text
 
 
@@ -459,29 +422,6 @@ def test_spec_pull_matches_direct_gather(mesh8):
         got, np.arange(150, dtype=np.float32).reshape(50, 3)[keys])
 
 
-def test_push_via_sparse_matches_scatter(mesh8):
-    spec = TableSpec(TableConfig(table_id="ps", capacity=40,
-                                 value_shape=(4,), num_blocks=8))
-    arr = jax.jit(spec.init_array)()
-    keys = jnp.asarray([1, 5, 1, 39], jnp.int32)  # duplicate key folds
-    deltas = jnp.asarray(
-        np.random.default_rng(3).normal(size=(4, 4)).astype(np.float32))
-    out_sc = spec.push(arr, keys, deltas, via="scatter")
-    out_sp = spec.push(arr, keys, deltas, via="sparse")
-    np.testing.assert_allclose(np.asarray(out_sc), np.asarray(out_sp),
-                               atol=1e-6)
-
-
-def test_push_via_sparse_requires_additive():
-    spec = TableSpec(TableConfig(table_id="pa", capacity=8,
-                                 value_shape=(2,), num_blocks=4,
-                                 update_fn="assign"))
-    arr = jax.jit(spec.init_array)()
-    with pytest.raises(ValueError, match="additive"):
-        spec.push(arr, jnp.asarray([1], jnp.int32),
-                  jnp.ones((1, 2), jnp.float32), via="sparse")
-
-
 # ---------------------------------------------------------------------------
 # the step program against a per-phase reference over the public accessor
 # ---------------------------------------------------------------------------
@@ -596,7 +536,7 @@ def _lda(sparse=False):
 
 def _fm(sparse=False):
     """The keyed families of the benchmark's criteo cells: a dense keyed
-    table (on the cells' forced scatter route) and a DeviceHashTable."""
+    table and a DeviceHashTable."""
     from harmony_tpu.apps import widedeep
 
     data = (widedeep.make_synthetic_sparse if sparse
@@ -650,8 +590,7 @@ _MESHES = {"one-device": (1, 1), "data2-model4": (2, 4)}
 
 @pytest.mark.parametrize("mesh_name", list(_MESHES))
 @pytest.mark.parametrize("family", list(_FAMILIES))
-def test_step_matches_per_phase_accessor_loop(family, mesh_name, devices,
-                                              monkeypatch):
+def test_step_matches_per_phase_accessor_loop(family, mesh_name, devices):
     """The one step program against the per-phase reference, family by
     family: the classic apps, and the families the benchmark's cells run."""
     from harmony_tpu.parallel import build_mesh
@@ -660,8 +599,6 @@ def test_step_matches_per_phase_accessor_loop(family, mesh_name, devices,
     data_ax, model_ax = _MESHES[mesh_name]
     mesh = build_mesh(devices[:data_ax * model_ax], data=data_ax,
                       model=model_ax)
-    if family == "fm-scatter":
-        monkeypatch.setenv("HARMONY_PUSH_VIA", "scatter")
     trainer, arrays = make()
     step_losses, step_table = _run_worker(trainer, arrays, mesh, epochs,
                                           batches)
@@ -690,8 +627,7 @@ def test_step_matches_per_phase_accessor_loop(family, mesh_name, devices,
 @pytest.mark.parametrize("mesh_name", list(_MESHES))
 def test_worker_step_on_the_kernel_lowering(mesh_name, devices, monkeypatch,
                                             request):
-    """A keyed FM tenant with 128-wide rows on the scatter route, through
-    ``WorkerTasklet``: as a CPU mesh lowers it (XLA's scatter) and as a
+    """A keyed FM tenant with 128-wide rows through ``WorkerTasklet``: as a CPU mesh lowers it (XLA's scatter) and as a
     TPU mesh does (the Pallas row scatter-add, interpreted) the losses and
     the table are equal bit for bit, and each run says which it took in
     the tenant ledger (STATUS ``table_layout.push_lowering``) and the
@@ -704,7 +640,6 @@ def test_worker_step_on_the_kernel_lowering(mesh_name, devices, monkeypatch,
     data_ax, model_ax = _MESHES[mesh_name]
     mesh = build_mesh(devices[:data_ax * model_ax], data=data_ax,
                       model=model_ax)
-    monkeypatch.setenv("HARMONY_PUSH_VIA", "scatter")
 
     def run():
         trainer = widedeep.FMTrainer(vocab_size=2047, num_slots=4,
@@ -722,7 +657,7 @@ def test_worker_step_on_the_kernel_lowering(mesh_name, devices, monkeypatch,
     request.getfixturevalue("as_tpu")
     from harmony_tpu.runtime import progcache
 
-    progcache.clear()  # the step's key names the route, not its lowering
+    progcache.clear()  # the step's key does not name its lowering
     traced, kernel = [], sparse.scatter_add_rows
     monkeypatch.setattr(
         sparse, "scatter_add_rows",

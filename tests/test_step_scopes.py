@@ -86,7 +86,7 @@ def _compile_step(trainer, batch):
     tsh = block_sharding(mesh, spec.num_blocks)
     rep = NamedSharding(mesh, P())
     compiled = jax.jit(
-        traced_on(mesh, tasklet._step_core("scatter", mesh)),
+        traced_on(mesh, tasklet._step_core(mesh)),
         out_shardings=(tsh, None), donate_argnums=0).lower(
         jax.ShapeDtypeStruct(spec.storage_shape, spec.dtype, sharding=tsh),
         tuple(jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep)

@@ -200,54 +200,66 @@ class TestBlockManager:
         assert events and events[0][0] == "t"
 
 
-class TestMxuPushRoute:
+class TestKeyedPush:
+    """``TableSpec.push``: one route, whose duplicates fold like a loop
+    over the keys in the order they occur."""
+
     def _spec(self, update_fn="add"):
         from harmony_tpu.config import TableConfig
         from harmony_tpu.table import TableSpec
 
         return TableSpec(TableConfig(
-            table_id="mxu-push", capacity=100, value_shape=(6,),
+            table_id="keyed-push", capacity=100, value_shape=(6,),
             num_blocks=8, update_fn=update_fn,
         ))
 
-    def test_mxu_matches_scatter_with_duplicates(self):
-        spec = self._spec()
-        arr = spec.init_array()
+    @pytest.mark.parametrize("update_fn,fold", [
+        ("add", np.add), ("min", np.minimum), ("max", np.maximum)])
+    def test_duplicate_keys_fold_like_a_serial_loop(self, update_fn, fold):
+        spec = self._spec(update_fn)
         rng = np.random.default_rng(0)
-        keys = jnp.asarray(rng.integers(0, 100, 64), jnp.int32)  # many dups
-        deltas = jnp.asarray(rng.standard_normal((64, 6), dtype=np.float32))
-        out_scatter = spec.push(arr, keys, deltas, via="scatter")
-        out_mxu = spec.push(arr, keys, deltas, via="mxu")
-        np.testing.assert_allclose(
-            np.asarray(out_mxu), np.asarray(out_scatter), rtol=1e-5, atol=1e-5
-        )
+        arr = jnp.asarray(
+            rng.standard_normal(spec.storage_shape, dtype=np.float32))
+        keys = rng.integers(0, 100, 64).astype(np.int32)  # many repeats
+        assert len(set(keys.tolist())) < 64
+        deltas = rng.standard_normal((64, 6), dtype=np.float32)
+        out = spec.push(arr, jnp.asarray(keys), jnp.asarray(deltas))
+        want = np.array(spec.pull_all(arr))
+        for k, d in zip(keys, deltas):  # float32 all the way
+            want[k] = fold(want[k], d)
+        got = np.asarray(spec.pull_all(out))
+        if update_fn == "add":  # XLA may add a key's deltas in any order
+            np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+        else:
+            np.testing.assert_array_equal(got, want)
 
-    def test_mxu_applies_post_invariant(self):
+    def test_post_hook_after_a_duplicate_heavy_push(self):
         spec = self._spec("add_nonneg")  # post clamps touched entries >= 0
         arr = spec.init_array()
-        keys = jnp.asarray([3, 3, 7], jnp.int32)
-        deltas = jnp.asarray([[-5.0] * 6, [1.0] * 6, [2.0] * 6], jnp.float32)
-        out = spec.push(arr, keys, deltas, via="mxu")
+        keys = jnp.asarray([3, 3, 7, 3], jnp.int32)
+        deltas = jnp.asarray(
+            [[-5.0] * 6, [1.0] * 6, [2.0] * 6, [1.5] * 6], jnp.float32)
+        assert spec.push_lowering(4) == "xla"
+        out = spec.push(arr, keys, deltas)
         got = np.asarray(spec.pull(out, jnp.asarray([3, 7], jnp.int32)))
-        np.testing.assert_allclose(got[0], np.zeros(6))   # clamped
+        # the clamp sees the folded row (-2.5), not each delta in turn
+        np.testing.assert_allclose(got[0], np.zeros(6))
         np.testing.assert_allclose(got[1], np.full(6, 2.0))
 
-    def test_mxu_rejects_non_additive(self):
-        spec = self._spec("assign")
-        arr = spec.init_array()
-        with pytest.raises(ValueError):
-            spec.push(arr, jnp.asarray([1], jnp.int32),
-                      jnp.ones((1, 6), jnp.float32), via="mxu")
-
-    def test_mxu_auto_size_gate(self):
+    @pytest.mark.parametrize("via", ["mxu", "mxu_auto", "sparse"])
+    def test_a_deleted_route_raises_and_names_the_one_there_is(self, via):
         spec = self._spec()
-        arr = spec.init_array()
-        # few keys into the table -> downgrades to scatter (same result)
-        few = spec.push(arr, jnp.asarray([1, 1], jnp.int32),
-                        jnp.ones((2, 6), jnp.float32), via="mxu_auto")
-        ref = spec.push(arr, jnp.asarray([1, 1], jnp.int32),
-                        jnp.ones((2, 6), jnp.float32), via="scatter")
-        np.testing.assert_allclose(np.asarray(few), np.asarray(ref))
+        with pytest.raises(ValueError, match="'scatter'"):
+            spec.push(spec.init_array(), jnp.asarray([1], jnp.int32),
+                      jnp.ones((1, 6), jnp.float32), via=via)
+
+    def test_auto_is_scatter(self):
+        spec = self._spec()
+        args = (spec.init_array(), jnp.asarray([1, 1], jnp.int32),
+                jnp.ones((2, 6), jnp.float32))
+        np.testing.assert_array_equal(
+            np.asarray(spec.push(*args, via="auto")),
+            np.asarray(spec.push(*args, via="scatter")))
 
 
 class TestRandomizedOpEquivalence:
@@ -302,79 +314,38 @@ class TestRandomizedOpEquivalence:
                                        atol=1e-5)
 
 
-class TestPushRouteAutotune:
-    """table/autotune.py: the measured route replaces the static
-    capacity//256 gate (round-2 on-chip capture: the static gate picked
-    the measured-slower route at its own bench shape)."""
+class TestKeyedStepBuild:
+    """A keyed job's start: ``_build_step`` wraps programs and runs none,
+    and nothing in the environment chooses how its push is lowered."""
 
-    def test_chooses_measured_faster_and_caches(self, mesh8):
-        from harmony_tpu.table import autotune
-
-        autotune.reset()
-        spec = TableSpec(TableConfig(
-            table_id="at-t", capacity=512, value_shape=(16,),
-            num_blocks=16, update_fn="add",
-        ))
-        route = autotune.choose_push_route(spec, mesh8, 256)
-        assert route in ("scatter", "mxu")
-        sig, meas = next(iter(autotune.measurements().items()))
-        best = "mxu" if meas["mxu_sec"] < meas["scatter_sec"] else "scatter"
-        assert route == best  # never the measured-slower route
-        # cached: the second call measures nothing new
-        n = len(autotune.measurements())
-        assert autotune.choose_push_route(spec, mesh8, 256) == route
-        assert len(autotune.measurements()) == n
-
-    def test_non_additive_is_always_scatter(self, mesh8):
-        from harmony_tpu.table import autotune
-
-        spec = TableSpec(TableConfig(
-            table_id="at-a", capacity=512, value_shape=(16,),
-            num_blocks=16, update_fn="assign",
-        ))
-        assert autotune.choose_push_route(spec, mesh8, 256) == "scatter"
-
-    def test_worker_bakes_resolved_route(self, mesh8, monkeypatch):
-        """_build_step resolves mxu_auto through the autotune and bakes
-        the choice into both the program and its cache key."""
+    @staticmethod
+    def _worker(mesh, job_id="ks-job"):
         from harmony_tpu.apps.mlr import make_synthetic
         from harmony_tpu.config.params import TrainerParams
         from harmony_tpu.dolphin import (
             TrainerContext, TrainingDataProvider, WorkerTasklet,
         )
         from harmony_tpu.dolphin.trainer import Trainer
-        from harmony_tpu.table import autotune
 
         class KeyedTrainer(Trainer):
             pull_mode = "keys"
 
-            def model_table_config(self, table_id="kt-model"):
+            def model_table_config(self, table_id="ks-model"):
                 return TableConfig(table_id=table_id, capacity=64,
                                    value_shape=(4,), num_blocks=8,
                                    update_fn="add")
 
             def pull_keys(self, batch):
-                import jax.numpy as jnp
-                return jnp.arange(32, dtype=jnp.int32)
+                return jnp.arange(32, dtype=jnp.int32) % 16  # repeats
 
             def compute(self, model, batch, hyper):
-                import jax.numpy as jnp
                 return -0.1 * model, {"loss": jnp.sum(model * model)}
 
         trainer = KeyedTrainer()
-        table = DenseTable(TableSpec(trainer.model_table_config()), mesh8)
-        monkeypatch.setattr(
-            type(table), "push_via", property(lambda self: "mxu_auto"))
-        calls = {}
-
-        def fake_choose(spec, mesh, nkeys, table=None):
-            calls["nkeys"] = nkeys
-            return "mxu"
-
-        monkeypatch.setattr(autotune, "choose_push_route", fake_choose)
-        x, y = make_synthetic(32, num_features=4, num_classes=2)
-        w = WorkerTasklet(
-            "at-job",
+        table = DenseTable(TableSpec(trainer.model_table_config()), mesh)
+        x, _ = make_synthetic(32, num_features=4, num_classes=2)
+        return WorkerTasklet(
+            job_id,
             TrainerContext(
                 params=TrainerParams(num_epochs=1, num_mini_batches=2,
                                      comm_probe_period=0),
@@ -382,9 +353,77 @@ class TestPushRouteAutotune:
             ),
             trainer,
             TrainingDataProvider([x], 2),
-            mesh8,
+            mesh,
         )
-        assert w._resolve_push_route() == "mxu"
-        assert calls["nkeys"] == 32  # measured at the job's real push shape
-        route = w._resolve_push_route()
-        assert w._program_key(table.sharding, None, route)[5] == "mxu"
+
+    @staticmethod
+    def _step_text(w):
+        table = w.ctx.model_table
+        batch = tuple(
+            jax.ShapeDtypeStruct((w.data.batch_size, *tail), dt)
+            for tail, dt in w.data.array_specs())
+        step = w._program_builders(table.sharding, None)[0]()
+        return step.lower(table.array, batch, w._hyper()).as_text()
+
+    def test_build_step_runs_nothing_on_the_device(self, mesh8, monkeypatch):
+        """Also as a TPU mesh would be built for: a job's start may hold a
+        table of half the chip, and any table-sized program beside it is one
+        the chip cannot fit."""
+        from harmony_tpu.dolphin import worker
+        from harmony_tpu.parallel import dispatch
+        from harmony_tpu.table import table as table_module
+        from harmony_tpu.utils import platform
+
+        monkeypatch.setattr(platform, "mesh_is_tpu", lambda mesh: True)
+        w = self._worker(mesh8)
+        version = w.ctx.model_table.data_version
+        compiles, scopes = [], []
+
+        def on_duration(name, *a, **kw):
+            if name.endswith("backend_compile_duration"):
+                compiles.append(name)
+
+        real = dispatch.dispatch_scope
+        for module in (dispatch, worker, table_module):
+            monkeypatch.setattr(
+                module, "dispatch_scope",
+                lambda *a, **kw: scopes.append(a) or real(*a, **kw))
+        jax.monitoring.register_event_duration_secs_listener(on_duration)
+        try:
+            w._build_step()
+        finally:
+            jax.monitoring.unregister_event_duration_listener(on_duration)
+        assert not compiles and not scopes
+        assert w.ctx.model_table.data_version == version
+
+    def test_program_key_has_no_route_slot(self, mesh8, monkeypatch):
+        w = self._worker(mesh8)
+        tsh = w.ctx.model_table.sharding
+        key = w._program_key(tsh, None)
+        assert len(key) == 6
+        assert not {"scatter", "mxu", "mxu_auto", "sparse", "auto"} & {
+            part for part in key if isinstance(part, str)}
+        monkeypatch.setenv("HARMONY_PUSH_VIA", "mxu")
+        assert w._program_key(tsh, None) == key
+
+    @pytest.mark.parametrize("value", ["mxu", "mxu_auto", "sparse"])
+    def test_the_old_knob_is_not_read(self, value, mesh8, monkeypatch):
+        """``HARMONY_PUSH_VIA`` (the frozen benchmark configs still export
+        it) changes neither the step's text nor what STATUS says of its
+        push."""
+        from harmony_tpu.metrics.accounting import ledger
+        from harmony_tpu.runtime import progcache
+
+        def build(job_id):
+            progcache.clear()
+            w = self._worker(mesh8, job_id)
+            w._build_step()
+            layout = ledger().snapshot()[job_id]["table_layout"]
+            return self._step_text(w), layout["push_lowering"]
+
+        monkeypatch.delenv("HARMONY_PUSH_VIA", raising=False)
+        text, lowering = build("ks-unset")
+        monkeypatch.setenv("HARMONY_PUSH_VIA", value)
+        assert build("ks-set") == (text, lowering)
+        assert lowering == "xla" and "scatter" in text
+        progcache.clear()
