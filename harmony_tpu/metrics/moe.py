@@ -10,6 +10,12 @@ trainer hands here:
     is the block's index in the model, expert layers only;
   * ``harmony_moe_held_slots_total{job}`` — those of them routed to experts
     this device holds (the rows its grouped matmuls computed);
+  * ``harmony_moe_absent_slots_total{job}`` — the rest of them: slots whose
+    expert is held on another device; and, of a router with a "no expert"
+    output (``moe_null_expert``), ``harmony_moe_null_slots_total{job}`` —
+    token-slots that chose no expert, which no device computes (a job
+    without that output has no such child). Apart, because the first is the
+    deployment's cut and the second the model's own saving;
   * ``harmony_moe_experts_held{job}`` — how many experts (0 .. n-1) it holds;
   * ``harmony_moe_layer_calls_total{job}`` / ``harmony_moe_chunks_total{job}``
     — expert-layer calls (layers x steps), and the chunks of the layer's
@@ -59,6 +65,20 @@ def _families():
                 "on the plain path)", ("job",)))
 
 
+def _dropped_families():
+    """Slots this device computed nothing for, by why."""
+    from harmony_tpu.metrics.registry import get_registry
+
+    reg = get_registry()
+    return (reg.counter(
+                "harmony_moe_absent_slots_total",
+                "Token-slots routed to experts held on another device",
+                ("job",)),
+            reg.counter(
+                "harmony_moe_null_slots_total",
+                "Token-slots whose router chose no expert", ("job",)))
+
+
 def chunks_run(expert_tokens: np.ndarray, experts_held: int) -> np.ndarray:
     """Chunks each layer call ran, ``[steps, layers]``, from its token
     counts ``[steps, layers, experts]``: the plan is the program's own
@@ -93,11 +113,14 @@ def _grid(tokens, job: str, layers: Sequence[int], experts: int):
 
 
 def observe(job: str, expert_tokens: np.ndarray, experts_held: int,
-            layers: Optional[Sequence[int]] = None) -> None:
+            layers: Optional[Sequence[int]] = None,
+            null_slots: Optional[np.ndarray] = None) -> None:
     """Add ``expert_tokens [steps, expert layers, experts]`` to the counters;
     ``layers`` are those layers' block indices (``TransformerConfig.
     moe_layers()``: the ``layer`` label is the block's index, so a leading
-    dense block has no row at all; None: every block is an expert layer)."""
+    dense block has no row at all; None: every block is an expert layer);
+    ``null_slots [steps, expert layers]``: the slots that chose no expert,
+    of a router that has that output."""
     from harmony_tpu.tracing import trace_span
 
     by_step = np.asarray(expert_tokens, np.float64)
@@ -116,6 +139,10 @@ def observe(job: str, expert_tokens: np.ndarray, experts_held: int,
             child.inc(n)
         seen[0] += float(per.sum())
         held_slots.labels(job=job).inc(float(held_by_step.sum()))
+        absent, null = _dropped_families()
+        absent.labels(job=job).inc(float(per.sum() - held_by_step.sum()))
+        if null_slots is not None:
+            null.labels(job=job).inc(float(np.asarray(null_slots).sum()))
         held.labels(job=job).set(experts_held)
         calls.labels(job=job).inc(by_step.shape[0] * by_step.shape[1])
         chunks.labels(job=job).inc(float(chunks_run(by_step,

@@ -198,6 +198,21 @@ class DroplessConfig:
     gated: bool = True
     latent: int = 0
     shared_d_ff: int = 0
+    # ZAYA1's router (arXiv:2511.17127; :func:`_mlp_logits`):
+    # ``router_hidden`` > 0 is the width of an MLP where the other routers
+    # have one matrix — its pre-norm rows also reach the next expert layer's
+    # router —, selecting by ``softmax + bias`` and weighing by the softmax
+    # alone; ``null_expert`` gives that softmax one more output, "no
+    # expert": a slot that chose it is computed nowhere and adds nothing;
+    # ``norm_eps`` is the router's RMSNorm's
+    router_hidden: int = 0
+    null_expert: bool = False
+    norm_eps: float = 1e-6
+
+    @property
+    def router_out(self) -> int:
+        """The router's outputs: an expert each, and "no expert"."""
+        return self.num_experts + int(self.null_expert)
 
     @property
     def expert_d(self) -> int:
@@ -216,7 +231,9 @@ def init_dropless_params(rng: jax.Array, cfg: DroplessConfig
     ``bias [E]`` (zeros: the optimizer leaves it there, its gradient is 0);
     shared experts are ``shared_wg`` / ``_wu`` / ``_wd``. Ungated experts
     have no ``wg`` / ``shared_wg``; latent ones are ``expert_d`` wide
-    between ``latent_down`` and ``latent_up``."""
+    between ``latent_down`` and ``latent_up``. An MLP router
+    (``router_hidden``) has, where the others have ``router``,
+    :func:`init_mlp_router`'s leaves."""
     kr, kg, ku, kd = jax.random.split(rng, 4)
     E, H, f = cfg.num_experts, cfg.experts_held, cfg.d_ff
     d, r = cfg.d_model, cfg.expert_d
@@ -225,6 +242,9 @@ def init_dropless_params(rng: jax.Array, cfg: DroplessConfig
         "wu": jax.random.normal(ku, (H, r, f), jnp.float32) * (r ** -0.5),
         "wd": jax.random.normal(kd, (H, f, r), jnp.float32) * (f ** -0.5),
     }
+    if cfg.router_hidden:
+        del params["router"]
+        params.update(init_mlp_router(kr, cfg))
     if cfg.gated:
         params["wg"] = jax.random.normal(kg, (H, r, f), jnp.float32) * (r ** -0.5)
     if cfg.score == "sigmoid":
@@ -241,6 +261,59 @@ def init_dropless_params(rng: jax.Array, cfg: DroplessConfig
         params["latent_down"] = jax.random.normal(kld, (d, r), jnp.float32) * (d ** -0.5)
         params["latent_up"] = jax.random.normal(klu, (r, d), jnp.float32) * (r ** -0.5)
     return params
+
+
+def init_mlp_router(rng: jax.Array, cfg: DroplessConfig
+                    ) -> Dict[str, jnp.ndarray]:
+    """An MLP router's leaves (:func:`_mlp_logits`), ``R = router_hidden``:
+    the down-projection ``r_down [d, R]`` + ``r_down_b``, the scale
+    ``r_eda [R]`` on the rows the router before left (ones), the norm's
+    weight ``r_norm [R]``, ``r_w1`` / ``r_w2 [R, R]`` with biases, ``r_w3
+    [R, outputs]`` without one, and the selection ``bias [outputs]``: zeros,
+    -1 on "no expert" — held there by the optimizer (no gradient reaches
+    it), so a router as initialised sends no token to no expert. ``r_w2``
+    and ``r_w3`` are drawn fan-in and then CENTRED down their fan-in axis
+    (each column less its mean): a GELU's outputs have a positive mean
+    (0.28 for a unit normal), which a plain fan-in matrix turns into one
+    constant offset an output — 0.2 against the 0.35 the logits spread
+    over tokens, and the most loaded of 16 experts took 4 to 7 times the
+    mean as initialised at d 512 on the CPU; at ZAYA1's widths on the chip
+    2.4 to 2.9 times against 1.5 to 2.0 centred, the step's rate within
+    half a percent either way (PERF.md section 6, PR 45). A trained router
+    is balanced by its selection bias; this one starts nearer balance."""
+    d, R, out = cfg.d_model, cfg.router_hidden, cfg.router_out
+    kd, k1, k2, k3 = jax.random.split(rng, 4)
+    dense = lambda key, a, b: jax.random.normal(key, (a, b), jnp.float32) \
+        * (a ** -0.5)
+    centred = lambda w: w - w.mean(axis=0)
+    zeros, ones = (lambda: jnp.zeros((R,), jnp.float32),
+                   lambda: jnp.ones((R,), jnp.float32))
+    return {
+        "r_down": dense(kd, d, R), "r_down_b": zeros(), "r_eda": ones(),
+        "r_norm": ones(), "r_w1": dense(k1, R, R), "r_b1": zeros(),
+        "r_w2": centred(dense(k2, R, R)), "r_b2": zeros(),
+        "r_w3": centred(dense(k3, R, out)),
+        "bias": jnp.zeros((out,), jnp.float32).at[cfg.num_experts:].set(-1.0),
+    }
+
+
+def _mlp_logits(params, x, cfg: DroplessConfig, state):
+    """``(logits [T, outputs], rows [T, R])`` of ZAYA1's router, all in
+    float32 at full precision: ``z = x W_down + b`` plus, where the expert
+    layer before left its rows (``state``; None in the first), ``r_eda *
+    state``; ``z`` is what this layer leaves for the next; ``logits =
+    gelu(gelu(rmsnorm(z) W1 + b1) W2 + b2) W3`` (the exact GELU)."""
+    f32, hi = jnp.float32, lax.Precision.HIGHEST
+    z = jnp.dot(x.astype(f32), params["r_down"], precision=hi) \
+        + params["r_down_b"]
+    if state is not None:
+        z = z + params["r_eda"] * state
+    u = z * lax.rsqrt(jnp.mean(z * z, axis=-1, keepdims=True)
+                      + cfg.norm_eps) * params["r_norm"]
+    for w, b in (("r_w1", "r_b1"), ("r_w2", "r_b2")):
+        u = jax.nn.gelu(jnp.dot(u, params[w], precision=hi) + params[b],
+                        approximate=False)
+    return jnp.dot(u, params["r_w3"], precision=hi), z
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -263,7 +336,7 @@ _slot_rows.defvjp(lambda x, order, inv, k: (x[order // k], (inv,)),
                   _slot_rows_bwd)
 
 
-def _route(params, x, cfg: DroplessConfig, seqs: int):
+def _route(params, x, cfg: DroplessConfig, seqs: int, state=None):
     """``(gate [T, k], expert [T, k], slot_expert [T * k], tokens [E] int32,
     stats)``: each token's chosen experts (also slot by slot) and their
     weights, and the token-slots each expert was chosen for, by the
@@ -276,7 +349,11 @@ def _route(params, x, cfg: DroplessConfig, seqs: int):
     layer's ``seqs`` sequences of ``sum_e f_e P_e`` (arXiv:2412.19437 eq.
     17-20: ``f_e = E / (k S) x`` the sequence's slots that chose ``e``, bias
     included, a count with no gradient; ``P_e`` the sequence's mean
-    normalised score) — a mean already, so layers ADD it."""
+    normalised score) — a mean already, so layers ADD it. An MLP router
+    (``router_hidden``; ``state``: :func:`_mlp_logits`) adds ``state``, its
+    rows for the next layer's router, and with ``null_expert`` ``skipped``,
+    the slots that chose no expert: those carry the index ``E``, which no
+    device holds, and ``tokens`` / ``prob_sum`` keep to the ``E`` experts."""
     from harmony_tpu.ops.top_k_rows import top_k_rows
     from harmony_tpu.utils.platform import trace_is_tpu
 
@@ -286,15 +363,24 @@ def _route(params, x, cfg: DroplessConfig, seqs: int):
     with step_scope("moe.route"):
         # a tiny matmul deciding discrete routes: full float32 passes on
         # the MXU
-        logits = jnp.dot(x.astype(jnp.float32), params["router"],
-                         precision=lax.Precision.HIGHEST)        # [T, E]
+        if cfg.router_hidden:
+            logits, rows = _mlp_logits(params, x, cfg, state)
+        else:
+            logits = jnp.dot(x.astype(jnp.float32), params["router"],
+                             precision=lax.Precision.HIGHEST)    # [T, E]
         # the selection is one op (ops/top_k_rows.py): lax.top_k's experts
         # in its order, no sort, no scalar gather, no scatter-add behind it
         if cfg.score == "softmax":
             lse = jax.nn.logsumexp(logits, axis=-1)
             probs = jnp.exp(logits - lse[:, None])
-            gate, expert = top_k_rows(probs, None, k,
-                                      interpret=interpret)       # [T, k]
+            if cfg.router_hidden:  # selected with the bias, weighed without
+                gate, expert = top_k_rows(
+                    probs + lax.stop_gradient(params["bias"]), probs, k,
+                    interpret=interpret)
+                probs = probs[:, :E]
+            else:
+                gate, expert = top_k_rows(probs, None, k,
+                                          interpret=interpret)   # [T, k]
         else:
             score = jax.nn.sigmoid(logits)
             # the bias moves WHICH experts are chosen; weights are the
@@ -327,6 +413,10 @@ def _route(params, x, cfg: DroplessConfig, seqs: int):
             stats["z_sum"] = jnp.sum(lse * lse)
         if cfg.seq_aux:
             stats["seq_lb"] = seq_lb
+        if cfg.router_hidden:
+            stats["state"] = rows
+        if cfg.null_expert:
+            stats["skipped"] = jnp.sum(slot_expert == E, dtype=jnp.float32)
     return gate, expert, slot_expert, tokens, stats
 
 
@@ -602,7 +692,8 @@ def _note_chunk_plan(C: int, chunks: int, d: int, f: int) -> None:
 
 def moe_ffn_dropless(params: Dict[str, jnp.ndarray], x: jnp.ndarray,
                      cfg: DroplessConfig, seqs: int = 1,
-                     router_x: Optional[jnp.ndarray] = None
+                     router_x: Optional[jnp.ndarray] = None,
+                     state: Optional[jnp.ndarray] = None
                      ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """``(out [T, d], stats)`` for ``x [T, d]`` (``seqs`` sequences of
     ``T // seqs`` tokens, in order). ``out`` sums, per token,
@@ -612,13 +703,15 @@ def moe_ffn_dropless(params: Dict[str, jnp.ndarray], x: jnp.ndarray,
     reads ``router_x [T, d]`` where given (a block that routes on its
     input), else the rows it dispatches. Latent experts (``cfg.latent``)
     read ``x latent_down`` and their sum passes ``latent_up``; the router
-    and the shared MLP keep the full-width rows."""
+    and the shared MLP keep the full-width rows. ``state``: an MLP router's
+    rows from the expert layer before (``stats["state"]`` of that call)."""
     from harmony_tpu.ops.sum_rows import tile_plan
 
     T, d = x.shape
     k, H = cfg.top_k, cfg.experts_held
+    route = {} if state is None else {"state": state}
     gate, expert, slot_expert, tokens, stats = _route(
-        params, x if router_x is None else router_x, cfg, seqs)
+        params, x if router_x is None else router_x, cfg, seqs, **route)
     dtype = x.dtype
     full = x
     if cfg.latent:

@@ -159,6 +159,30 @@ class TransformerConfig:
     moe_gated: bool = True
     moe_latent: int = 0
     moe_shared_d_ff: int = 0
+    # ZAYA1's block (arXiv:2511.17127; its attention is CCA,
+    # arXiv:2510.04476), ``attn_kind="mha"`` with grouped heads: ``cca``:
+    # attention runs INSIDE the q/k latent — ``wqkv``'s q and k columns pass
+    # a depthwise causal convolution and then one ``head_dim x head_dim``
+    # causal convolution a head (``CCA_TAPS`` taps each, with biases), the
+    # mean of the unconvolved q and k is added back, each head is L2-normed
+    # to ``sqrt(head_dim)`` with a learned temperature a key head, the LAST
+    # half of the key/value heads read the previous position's value, and
+    # ``wo`` projects from ``n_heads x head_dim``; ``rope_fraction``: rotary
+    # turns the first that share of a head's columns, the rest pass;
+    # ``moe_router_hidden`` > 0: the router is an MLP that wide (models/moe.py
+    # ``_mlp_logits``: a down-projection, the router of the block before
+    # added in under a learned scale, an RMSNorm, three matrices under
+    # GELU, a selection bias outside the gradient, the softmax's own
+    # probability as the weight) whose pre-norm rows pass from block to
+    # block beside the residual stream; ``moe_null_expert``: that softmax has
+    # one more output, "no expert", and a slot that chose it adds nothing;
+    # ``merge_scaled``: a block's two residual adds are ``a_x (x + b_x) +
+    # a_y (y + b_y)`` with four learned ``d_model``-wide vectors each.
+    cca: bool = False
+    rope_fraction: float = 1.0
+    moe_router_hidden: int = 0
+    moe_null_expert: bool = False
+    merge_scaled: bool = False
     # Standard deviation the embedding rows are drawn with. GPT-2's 0.02
     # leaves a row at a fiftieth of what a block's fan-in projections add to
     # it, which nothing here divides by depth: attention's average over the
@@ -342,6 +366,50 @@ class TransformerConfig:
         if self.dense_d_ff and not self.moe_first_dense:
             raise ValueError("dense_d_ff is the width of the moe_first_dense "
                              "leading layers: set moe_first_dense")
+        if self.cca and (self.attn_kind != "mha" or self.linear_layers
+                         or self.layer_pattern or self.window_layers
+                         or self.qk_norm):
+            raise ValueError(
+                "cca convolves and L2-norms the q and k columns of an "
+                "attn_kind='mha' block's wqkv: a latent block (mla) has no "
+                "such columns, KDA / layer_pattern layers have mixers of "
+                "their own, qk_norm would norm the same heads twice, and a "
+                "windowed CCA block turns with a theta of its own that no "
+                "field carries (window_layers)")
+        if self.cca and self.kv_heads % 2:
+            raise ValueError(
+                f"cca: the last half of the {self.kv_heads} key/value heads "
+                "reads the previous position's value, so they must be even")
+        rot = self.rope_fraction * self.head_dim
+        if not 0.0 < self.rope_fraction <= 1.0 or rot != int(rot) \
+                or int(rot) % 2:
+            raise ValueError(
+                f"rope_fraction {self.rope_fraction} of a {self.head_dim}-"
+                "wide head must be a whole, even number of columns in "
+                "(0, head]: rotary turns pairs")
+        if self.rope_fraction != 1.0 and (self.attn_kind != "mha"
+                                          or self.pos != "rope"):
+            raise ValueError(
+                "rope_fraction belongs to attn_kind='mha' under pos='rope': "
+                "a latent block's turned width is qk_rope_head_dim")
+        if self.moe_router_hidden and (
+                not self.moe_top_k or self.moe_score != "softmax"
+                or self.layer_pattern or self.moe_seq_aux):
+            raise ValueError(
+                "moe_router_hidden is the width of a dropless router's MLP "
+                "(set moe_top_k), a softmax router (its selection bias is "
+                "its own, not moe_score='sigmoid''s, and it has no "
+                "sequence-wise balance term), and its state passes from "
+                "block to block: a layer_pattern layer hands nothing on")
+        if self.moe_null_expert and not self.moe_router_hidden:
+            raise ValueError(
+                "moe_null_expert is the MLP router's last output (set "
+                "moe_router_hidden): the one-matrix routers have a column "
+                "an expert and no more")
+        if self.merge_scaled and self.layer_pattern:
+            raise ValueError(
+                "merge_scaled names the two residual merges of a block; a "
+                "layer_pattern layer is one sublayer under one norm")
         validate_attn(self.attn)
 
     def is_moe_layer(self, i: int) -> bool:
@@ -413,7 +481,9 @@ class TransformerConfig:
             routed_scale=self.moe_routed_scale,
             shared_experts=self.moe_shared_experts, seq_aux=self.moe_seq_aux,
             act=self.moe_act, gated=self.moe_gated, latent=self.moe_latent,
-            shared_d_ff=self.moe_shared_d_ff)
+            shared_d_ff=self.moe_shared_d_ff,
+            router_hidden=self.moe_router_hidden,
+            null_expert=self.moe_null_expert, norm_eps=self.norm_eps)
 
     @property
     def head_dim(self) -> int:
@@ -448,22 +518,30 @@ class TransformerConfig:
                 and not self.moe_first_dense and not self.linear_layers
                 and not self.layer_pattern
                 and not (self.n_kv_heads or self.mha_head_dim
-                         or self.window_layers)):
+                         or self.window_layers)
+                and not (self.cca or self.merge_scaled
+                         or self.rope_fraction != 1.0)):
             raise ValueError(
                 f"{who} runs the GPT-2-era block only (learned positions, "
                 "GELU, tied readout, Switch experts); rotary / no-position / "
                 "QK-norm / SwiGLU / untied / dropless / latent-attention / "
                 "KDA linear-attention / grouped-query / windowed / leading-dense "
-                "/ layer-pattern configs train through "
+                "/ layer-pattern / CCA / partial-rotary / scaled-merge configs "
+                "train through "
                 "TransformerLM.loss and TransformerTrainer")
 
 
 from harmony_tpu.models.common import rms_norm as _norm  # noqa: E402
 
 
-def rope(x, theta: float, pos_offset=0):
+def rope(x, theta: float, pos_offset=0, width: Optional[int] = None):
     """Rotate-half rotary positions on ``x [B, H, S, hd]`` (positions
-    ``pos_offset .. pos_offset+S-1``): float32 angles, result in x's dtype."""
+    ``pos_offset .. pos_offset+S-1``): float32 angles, result in x's dtype.
+    ``width``: only the first that many columns of a head are turned, as a
+    head that wide would be (``rope_fraction``); the others pass."""
+    if width is not None and width != x.shape[-1]:
+        return jnp.concatenate(
+            [rope(x[..., :width], theta, pos_offset), x[..., width:]], axis=-1)
     hd = x.shape[-1]
     inv_freq = theta ** (-jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
     ang = (pos_offset + jnp.arange(x.shape[2], dtype=jnp.float32)
@@ -548,6 +626,55 @@ def init_ssd_params(k_in: jax.Array, k_out: jax.Array,
     }
 
 
+#: taps of CCA's two causal convolutions (``cca_time0`` / ``cca_time1`` of
+#: ZAYA1's config: position t sees t - 1 and t), and the epsilon beside the
+#: sum of squares under its L2 norm's root
+CCA_TAPS = 2
+CCA_L2_EPS = 1e-12
+
+
+def init_cca_params(key: jax.Array, cfg: TransformerConfig
+                    ) -> Dict[str, jnp.ndarray]:
+    """What CCA adds to an ``mha`` block's ``wqkv`` / ``wo``
+    (``TransformerLM._cca_latent``), over the ``c = (n_heads + kv_heads)
+    head_dim`` channels of ``[q | k]``: the depthwise taps ``conv0 [K, c]``
+    (uniform in ``+-K^-1/2``, as KDA's) and bias ``conv0_b [c]``; the
+    per-head taps ``conv1 [K, heads, hd, hd]`` (fan-in ``K hd``) and bias
+    ``conv1_b [c]``; the keys' temperature ``temp [kv_heads]``, stored as
+    itself, 1 at the start."""
+    K, hd = CCA_TAPS, cfg.head_dim
+    heads = cfg.n_heads + cfg.kv_heads
+    k0, k1 = jax.random.split(key)
+    return {
+        "conv0": jax.random.uniform(k0, (K, heads * hd), jnp.float32,
+                                    -K ** -0.5, K ** -0.5),
+        "conv0_b": jnp.zeros((heads * hd,), jnp.float32),
+        "conv1": jax.random.normal(k1, (K, heads, hd, hd), jnp.float32)
+        * (K * hd) ** -0.5,
+        "conv1_b": jnp.zeros((heads * hd,), jnp.float32),
+        "temp": jnp.ones((cfg.kv_heads,), jnp.float32),
+    }
+
+
+def merge_init(d: int, xp=jnp):
+    """A scaled residual merge's ``[a_x, b_x, a_y, b_y]`` as rows of
+    ``[4, d]``: ones and zeros, a plain sum at the start."""
+    return xp.stack([xp.ones((d,), xp.float32), xp.zeros((d,), xp.float32)]
+                    * 2)
+
+
+def _merge(x, y, m):
+    """The residual merge of stream ``x`` and a sublayer's ``y``: their sum,
+    or under ``merge_scaled`` (``m [4, d]``: :func:`merge_init`) ``a_x (x +
+    b_x) + a_y (y + b_y)`` in float32."""
+    if m is None:
+        return x + y
+    with step_scope("merge"):
+        f32 = jnp.float32
+        return (m[0] * (x.astype(f32) + m[1])
+                + m[2] * (y.astype(f32) + m[3])).astype(x.dtype)
+
+
 def _causal_conv(t, taps):
     """The depthwise causal convolution of ``t [B, S, c]`` by ``taps [K,
     c]``, in float32: ``y_t = sum_j taps[j] t_{t - (K-1) + j}``."""
@@ -620,6 +747,11 @@ class TransformerLM:
             if cfg.qk_norm:
                 layer["q_norm"] = jnp.ones((d,), jnp.float32)
                 layer["k_norm"] = jnp.ones((d,), jnp.float32)
+            if cfg.cca:
+                layer["cca"] = init_cca_params(
+                    jax.random.fold_in(ks[0], 1), cfg)
+            if cfg.merge_scaled:
+                layer["merge1"], layer["merge2"] = merge_init(d), merge_init(d)
             if cfg.is_moe_layer(i):
                 from harmony_tpu.models import moe
 
@@ -760,6 +892,20 @@ class TransformerLM:
             if cfg.qk_norm:
                 layer["q_norm"] = np.ones((d,), np.float32)
                 layer["k_norm"] = np.ones((d,), np.float32)
+            if cfg.cca:
+                K, hd = CCA_TAPS, cfg.head_dim
+                heads = cfg.n_heads + cfg.kv_heads
+                layer["cca"] = {
+                    "conv0": rng.uniform(-K ** -0.5, K ** -0.5,
+                                         (K, heads * hd)).astype(np.float32),
+                    "conv0_b": np.zeros((heads * hd,), np.float32),
+                    "conv1": ((K * hd) ** -0.5 * rng.standard_normal(
+                        (K, heads, hd, hd))).astype(np.float32),
+                    "conv1_b": np.zeros((heads * hd,), np.float32),
+                    "temp": np.ones((cfg.kv_heads,), np.float32)}
+            if cfg.merge_scaled:
+                layer["merge1"] = merge_init(d, np)
+                layer["merge2"] = merge_init(d, np)
             if cfg.is_moe_layer(i):
                 E = cfg.moe_experts
                 if cfg.moe_top_k:
@@ -767,6 +913,23 @@ class TransformerLM:
                     layer["moe"] = {
                         "router": dense((d, E)), "wg": stacked(H, d, f),
                         "wu": stacked(H, d, f), "wd": stacked(H, f, d)}
+                    if cfg.moe_router_hidden:
+                        R = cfg.moe_router_hidden
+                        out = E + cfg.moe_null_expert
+                        bias = np.zeros((out,), np.float32)
+                        bias[E:] = -1.0
+                        centred = lambda w: w - w.mean(axis=0)
+                        del layer["moe"]["router"]
+                        layer["moe"].update(
+                            r_down=dense((d, R)),
+                            r_down_b=np.zeros((R,), np.float32),
+                            r_eda=np.ones((R,), np.float32),
+                            r_norm=np.ones((R,), np.float32),
+                            r_w1=dense((R, R)),
+                            r_b1=np.zeros((R,), np.float32),
+                            r_w2=centred(dense((R, R))),
+                            r_b2=np.zeros((R,), np.float32),
+                            r_w3=centred(dense((R, out))), bias=bias)
                     if cfg.moe_score == "sigmoid":
                         layer["moe"]["bias"] = np.zeros((E,), np.float32)
                     if cfg.moe_shared_experts:
@@ -970,7 +1133,7 @@ class TransformerLM:
 
     def _block(self, x, layer, axis_name: Optional[str],
                moe_axis: Optional[str] = None, pos_offset: Any = 0,
-               kind: Optional[str] = None):
+               kind: Optional[str] = None, route_state=None):
         """One pre-norm decoder block — the shared body of ``apply`` and
         the pipeline-parallel stage fn. Returns ``(x, aux, mix)``: aux is
         the Switch load-balance loss when the block carries a Switch MoE
@@ -981,7 +1144,9 @@ class TransformerLM:
         published q/k/v projections are the three column blocks of
         ``wqkv``. ``kind`` (``layer_kinds()``'s) matters in a model with
         ``window_layers`` only: ``"swa"`` blocks window and turn, ``"full"``
-        ones do neither."""
+        ones do neither. ``route_state``: what the MLP router of the block
+        before left for this one's (``moe_router_hidden``; None in the first
+        block); this block's is ``aux["state"]``."""
         cfg = self.config
         eps = cfg.norm_eps
         x_in = x
@@ -992,12 +1157,52 @@ class TransformerLM:
         else:
             y, mix = self._softmax_mixer(xn, layer, axis_name, pos_offset,
                                          kind), None
-        x = x + y
+        x = _merge(x, y, layer.get("merge1"))
         with step_scope("norm"):
             xn = _norm(x, layer["ln2"].astype(cfg.dtype), eps)
         route = {"router_x": x_in} if cfg.moe_route_block_input else {}
+        if route_state is not None:
+            route["route_state"] = route_state
         out, aux = ffn_apply(cfg, layer, xn, moe_axis=moe_axis, **route)
-        return x + out, aux, mix
+        return _merge(x, out, layer.get("merge2")), aux, mix
+
+    def _cca_latent(self, q, k, v, p):
+        """CCA's q, k and v (arXiv:2510.04476) from ``wqkv``'s column blocks
+        ``q [B, S, H hd]``, ``k`` and ``v [B, S, Hkv hd]``, all ``[B, S,
+        heads, hd]``. Over ``c = [q | k]``: a depthwise causal convolution
+        with a bias (float32), then a head at a time one ``[K hd, hd]``
+        causal convolution with a bias (activation dtype operands, float32
+        sums); the mean of the UNCONVOLVED q and k is added back — query
+        head i gets ``(q_i + k_kv(i)) / 2``, key head j ``(mean of its
+        query heads + k_j) / 2``; each head is L2-normed to ``sqrt(hd)``,
+        a key head times its temperature (float32); the last half of the
+        key/value heads read the previous position's value (zero at 0)."""
+        cfg = self.config
+        B, S = q.shape[0], q.shape[1]
+        H, Hkv, hd, dt = cfg.n_heads, cfg.kv_heads, cfg.head_dim, cfg.dtype
+        f32 = jnp.float32
+        shift = lambda t: jnp.pad(t, ((0, 0), (1, 0)) + ((0, 0),) * (
+            t.ndim - 2))[:, :S]
+        c = jnp.concatenate([q, k], axis=-1)                  # [B, S, c]
+        c1 = (_causal_conv(c, p["conv0"]) + p["conv0_b"]).astype(dt)
+        c1 = c1.reshape(B, S, H + Hkv, hd)
+        c2 = jnp.einsum("bsjgc,jgcd->bsgd", jnp.stack([shift(c1), c1], axis=2),
+                        p["conv1"].astype(dt), preferred_element_type=f32)
+        c2 = c2 + p["conv1_b"].reshape(H + Hkv, hd)
+        qg = q.astype(f32).reshape(B, S, Hkv, H // Hkv, hd)
+        k0 = k.astype(f32).reshape(B, S, Hkv, hd)
+        q = c2[:, :, :H] + (0.5 * (qg + k0[:, :, :, None])).reshape(
+            B, S, H, hd)
+        k = c2[:, :, H:] + 0.5 * (qg.mean(axis=3) + k0)
+
+        def l2(t):
+            return t * (hd ** 0.5 * lax.rsqrt(
+                jnp.sum(t * t, axis=-1, keepdims=True) + CCA_L2_EPS))
+
+        v = v.reshape(B, S, Hkv, hd)
+        v = jnp.concatenate([v[:, :, :Hkv // 2], shift(v[:, :, Hkv // 2:])],
+                            axis=2)
+        return (l2(q).astype(dt), (l2(k) * p["temp"][:, None]).astype(dt), v)
 
     def _softmax_mixer(self, xn, layer, axis_name, pos_offset, kind=None):
         """Softmax attention (``attn_kind``) on the normed input ``xn [B,
@@ -1021,11 +1226,18 @@ class TransformerLM:
                     k = _norm(k, layer["k_norm"].astype(cfg.dtype), eps)
                 to_heads = lambda t: t.reshape(B, S, -1, hd).transpose(
                     0, 2, 1, 3)
-                q, k, v = to_heads(q), to_heads(k), to_heads(v)
+                if not cfg.cca:
+                    q, k, v = to_heads(q), to_heads(k), to_heads(v)
+            if cfg.cca:
+                with step_scope("mixer.cca"):
+                    q, k, v = (to_heads(t) for t in self._cca_latent(
+                        q, k, v, layer["cca"]))
             if cfg.pos == "rope" and kind != "full":
+                part = ({} if cfg.rope_fraction == 1.0 else
+                        {"width": int(cfg.rope_fraction * hd)})
                 with step_scope("mixer.rope"):
-                    q = rope(q, cfg.rope_theta, pos_offset)
-                    k = rope(k, cfg.rope_theta, pos_offset)
+                    q = rope(q, cfg.rope_theta, pos_offset, **part)
+                    k = rope(k, cfg.rope_theta, pos_offset, **part)
         with step_scope("mixer.core"):
             o = self._attention(q, k, v, axis_name, window)
         with step_scope("mixer.out"):
@@ -1061,9 +1273,9 @@ class TransformerLM:
             x = _embed_in(cfg, params["embed"], params.get("pos"), tokens,
                           pos_offset)
 
-        def block(x, layer):
+        def block(x, layer, **state):
             return self._block(x, layer, axis_name, moe_axis=moe_axis,
-                               pos_offset=pos_offset)
+                               pos_offset=pos_offset, **state)
 
         if cfg.remat:
             # Per-layer rematerialization: the backward recomputes each
@@ -1088,18 +1300,24 @@ class TransformerLM:
         aux = jnp.asarray(0.0, jnp.float32)
         routed = []  # dropless layers' statistics
         mixers = []  # KDA blocks' statistics
+        state = {}   # an MLP router's rows, for the next block's router
         for i, layer in enumerate(params["layers"]):
             with step_scope("blk", i):
-                x, a, mix = by_kind.get(kinds[i], block)(x, layer)
+                x, a, mix = by_kind.get(kinds[i], block)(x, layer, **state)
             if mix is not None:
                 mixers.append(mix)
             if isinstance(a, dict):
+                if "state" in a:
+                    state = {"route_state": a.pop("state")}
                 routed.append(a)
             else:
                 aux = aux + a
         if routed:
             aux = jax.tree.map(lambda *xs: sum(xs), *routed)
             aux["tokens_by_layer"] = jnp.stack([a["tokens"] for a in routed])
+            if "skipped" in aux:
+                aux["skipped_by_layer"] = jnp.stack(
+                    [a["skipped"] for a in routed])
         with step_scope("head"):
             x = _norm(x, params["ln_f"].astype(cfg.dtype), cfg.norm_eps)
             # f32 logits for a stable softmax; the readout is the embedding
@@ -1136,6 +1354,8 @@ class TransformerLM:
             if cfg.moe_top_k:
                 lb, z = routing_losses(aux, cfg.moe_experts)
                 loss = ce + cfg.moe_aux_weight * lb + cfg.moe_z_weight * z
+                if cfg.moe_null_expert:
+                    kda["moe_null_slots"] = aux["skipped_by_layer"]
                 return loss, {"ce": ce, "aux_lb": lb, "aux_z": z,
                               "moe_expert_tokens": aux["tokens_by_layer"],
                               **kda}
@@ -1169,7 +1389,8 @@ def _next_token_ce(logits, targets) -> jnp.ndarray:
 
 
 def ffn_apply(cfg, layer, xn, no_drop: bool = False,
-              moe_axis: Optional[str] = None, router_x=None):
+              moe_axis: Optional[str] = None, router_x=None,
+              route_state=None):
     """Dense or MoE FFN on [..., d] activations — the ONE dense/MoE
     dispatch shared by training blocks and the decode path. Returns
     ``(out, aux)``. ``no_drop`` lifts the expert capacity to cover every
@@ -1179,12 +1400,15 @@ def ffn_apply(cfg, layer, xn, no_drop: bool = False,
     expert-parallel mesh axis: expert params are sharded on their leading
     dim and token buckets move over ICI via all_to_all (moe_ffn).
     ``router_x``: what a dropless router reads where that is not ``xn``
-    (``moe_route_block_input``)."""
+    (``moe_route_block_input``); ``route_state``: what the MLP router of the
+    block before left for this one's (``moe_router_hidden``)."""
     if "moe" in layer and cfg.moe_top_k:
         from harmony_tpu.models.moe import moe_ffn_dropless
 
         route = {} if router_x is None else {
             "router_x": router_x.reshape(-1, cfg.d_model)}
+        if route_state is not None:
+            route["state"] = route_state
         out, stats = moe_ffn_dropless(layer["moe"],
                                       xn.reshape(-1, cfg.d_model),
                                       cfg.dropless_cfg, seqs=xn.shape[0],
@@ -1709,7 +1933,8 @@ class TransformerTrainer(PyTreeTrainer):
 
             moe.observe(job_id, vectors["moe_expert_tokens"],
                         self.config.dropless_cfg.experts_held,
-                        self.config.moe_layers())
+                        self.config.moe_layers(),
+                        null_slots=vectors.get("moe_null_slots"))
         from harmony_tpu.metrics import kda
 
         for kind, stats in kda.STATS.items():  # the recurrent layers' pairs

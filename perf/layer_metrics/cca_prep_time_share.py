@@ -1,0 +1,25 @@
+"""``cca_prep_time_share`` — device time of CCA's preamble — ``blk*/mixer.cca``:
+the value shift, the two causal convolutions over the concatenated q and k
+latents, the q/k mean and the per-head L2 norm with its temperature, all
+between the ``wqkv`` product and the rotary — over the device seconds of the
+step modules of device 0 in the traced window (``_step_scopes.py``: the
+program's scope table, read from the profiler capture's own HLO; in the
+benchmark's partition these seconds lie in ``mixer``). A program without the
+scope (every configuration without ``cca``, and the parent of the PR that
+added it) reports nothing."""
+from perf.layer_metrics._step_scopes import table
+
+SCOPE = "blk*/mixer.cca"
+LAYER = "model"
+UNIT = "%"
+SOURCE = "device_trace"
+
+
+def read(obs):
+    if not obs.get("trace"):
+        return None
+    found = table()
+    if found is None:
+        return None
+    seconds = sum(r.seconds for r in found["rows"] if r.scope == SCOPE)
+    return 100.0 * seconds / found["seconds"] if seconds > 0 else None
