@@ -28,7 +28,8 @@ difference is split at the later sub-block's first row ``n`` —
 ``exp(G_r - G_n) exp(G_n - G_i)``, both at most 1 — which is a matmul. The
 triangular system is solved in float32 by inverting ``I + A`` block by block
 (1, 2, 4 ... rows: ``X <- X - X (A on the lower-left sub-blocks) X``, the
-exact block recursion, no power series); the other products take the
+exact block recursion, no power series; blocks of one row are 1, so the
+first level is a mask and five levels multiply); the other products take the
 operands' dtype into the MXU (bfloat16 on hardware) and accumulate in float32.
 
 **The kernels** (``harmony_kda_fwd``, ``harmony_kda_bwd``): the grid walks
@@ -36,12 +37,20 @@ operands' dtype into the MXU (bfloat16 on hardware) and accumulate in float32.
 transposed state ``S^T [dv, dk]`` in float32 in VMEM scratch (the decay then
 multiplies along lanes).
 The forward also writes the state each chunk STARTS from (``[N, dv, dk]`` a
-head: 1/C of what keeping every position's state would take); the backward
-walks the chunks in reverse, recomputes a chunk from that boundary state and
-takes the chunk's vector-Jacobian product (``jax.vjp`` of ``_chunk`` traced
-into the kernel body), carrying the state's cotangent in scratch. The chunk's
-running sum of ``g`` and the products with ``b`` are XLA's, outside: their
-derivatives are autodiff's.
+head: 1/C of what keeping every position's state would take) and the chunk's
+solve ``X = (I + A)^-1`` (``[N, C, C]`` float32, a quarter of that again).
+The backward walks the chunks in reverse with the state's cotangent in
+scratch, and takes each chunk's backward AROUND ``X``, never through the
+recursion that made it (``_chunk_bwd``): ``U = X R`` is rebuilt from the
+boundary state in three products, the application's cotangents are its own
+transposes, the solve's are ``dR = X^T dU`` and ``dA = -dR U^T`` (two
+products where differentiating six levels took 24 exact ones after
+recomputing 12), and only the pair matrices — 16 row shifts, three split
+products — go through ``jax.vjp`` (of ``_pair``, traced into the kernel
+body). The chunk's running sum of ``g`` and the products with ``b`` are
+XLA's, outside: their derivatives are autodiff's. The XLA form
+(``_scan_chunks``) keeps autodiff through the whole of ``_chunk``: it is what
+the kernels are tested against.
 
 One predicate chooses (``_kernel_route``: the traced program runs on a
 one-chip TPU mesh), as for the flash kernels; no option and no environment
@@ -124,27 +133,26 @@ def _shift_rows_bwd(s, on_tpu, _, g):
 _shift_rows.defvjp(_shift_rows_fwd, _shift_rows_bwd)
 
 
-def _chunk(q, k, kb, vb, G, St, on_tpu=False):
-    """One chunk of one head: ``(o [C, dv], S'^T [dv, dk])`` from ``q, k
-    [C, dk]``, ``kb = b k``, ``vb = b v [C, dv]``, the chunk's running
-    log-decay ``G [C, dk]`` (float32) and the transposed state ``St
-    [dv, dk]`` (float32) the chunk starts from. Module docstring for the
-    equations; products take ``q``'s dtype into the MXU."""
-    C = q.shape[0]
+def _mm(a, b, dims, mxu):
+    """``a`` x ``b`` with ``mxu`` operands into the MXU, float32 out (exact
+    where the operands are float32 themselves)."""
+    return lax.dot_general(
+        a.astype(mxu), b.astype(mxu), dims,
+        preferred_element_type=jnp.float32,
+        precision=lax.Precision.HIGHEST if mxu == jnp.float32 else None)
+
+
+def _row(G, n):
+    """``G``'s row ``n``, ``[1, dk]`` (a masked sum: no unaligned slice)."""
+    row = lax.broadcasted_iota(jnp.int32, (G.shape[0], 1), 0)
+    return jnp.sum(jnp.where(row == n, G, 0.0), axis=0, keepdims=True)
+
+
+def _pair(qf, kf, kbf, G, mxu, on_tpu=False):
+    """The chunk's pair matrices ``(A, Aqk) [C, C]`` (module docstring) from
+    float32 ``q, k, b k`` and ``G``; products take ``mxu`` operands."""
+    C = qf.shape[0]
     f32 = jnp.float32
-    mxu = q.dtype
-    exact = lax.Precision.HIGHEST
-
-    def mm(a, b, dims):
-        return lax.dot_general(
-            a.astype(mxu), b.astype(mxu), dims, preferred_element_type=f32,
-            precision=exact if mxu == f32 else None)
-
-    def mm32(a, b):
-        return lax.dot_general(a, b, _NN, preferred_element_type=f32,
-                               precision=exact)
-
-    qf, kf, kbf, vbf = (t.astype(f32) for t in (q, k, kb, vb))
     row = lax.broadcasted_iota(jnp.int32, (C, 1), 0)
     ri = lax.broadcasted_iota(jnp.int32, (C, C), 0)
     ci = lax.broadcasted_iota(jnp.int32, (C, C), 1)
@@ -153,12 +161,12 @@ def _chunk(q, k, kb, vb, G, St, on_tpu=False):
     # between sub-blocks: the difference split at the later one's first row
     for b in range(1, C // SUB):
         n = b * SUB
-        gn = jnp.sum(jnp.where(row == n, G, 0.0), axis=0, keepdims=True)
+        gn = _row(G, n)
         rowfac = jnp.exp(jnp.minimum(G - gn, 0.0))      # true for rows >= n
         kc = kf * jnp.where(row < n, jnp.exp(jnp.minimum(gn - G, 0.0)), 0.0)
         mine = jnp.logical_and(ri >= n, ri < n + SUB)
-        A = A + jnp.where(mine, mm(kbf * rowfac, kc, _NT), 0.0)
-        Aqk = Aqk + jnp.where(mine, mm(qf * rowfac, kc, _NT), 0.0)
+        A = A + jnp.where(mine, _mm(kbf * rowfac, kc, _NT, mxu), 0.0)
+        Aqk = Aqk + jnp.where(mine, _mm(qf * rowfac, kc, _NT, mxu), 0.0)
     # inside a sub-block: the pairs (r, r - s), a shift of the rows each
     for s in range(min(SUB, C)):
         ks, Gs = _shift_rows(kf, s, on_tpu), _shift_rows(G, s, on_tpu)
@@ -169,25 +177,98 @@ def _chunk(q, k, kb, vb, G, St, on_tpu=False):
         if s:
             A = A + jnp.where(
                 here, jnp.sum(kbf * e, axis=1, keepdims=True), 0.0)
-    # (I + A)^-1, block by block: X holds the inverses of the diagonal
-    # blocks of ``size`` rows; the lower-left quarter of each block twice
-    # that size is -X22 A21 X11
-    X = (ri == ci).astype(f32)
-    size = 1
-    while size < C:
+    return A, Aqk
+
+
+def _solve(A):
+    """``(I + A)^-1`` for a strictly lower ``A [C, C]``, in float32, block by
+    block: ``X`` holds the inverses of the diagonal blocks of ``size`` rows;
+    the lower-left quarter of each block twice that size is -X22 A21 X11."""
+    C = A.shape[0]
+    ri = lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    ci = lax.broadcasted_iota(jnp.int32, (C, C), 1)
+
+    def low_left(size):
         # size is a power of two: blocks by shifts, halves by one bit
         bit = size.bit_length() - 1
-        low_left = jnp.logical_and(
+        return jnp.logical_and(
             jnp.right_shift(ri, bit + 1) == jnp.right_shift(ci, bit + 1),
             jnp.logical_and(jnp.bitwise_and(ri, size) != 0,
                             jnp.bitwise_and(ci, size) == 0))
-        X = X - mm32(mm32(X, jnp.where(low_left, A, 0.0)), X)
+
+    # blocks of one row are 1: X - X M X with X = I is the mask itself
+    X = (ri == ci).astype(jnp.float32) - jnp.where(low_left(1), A, 0.0)
+    size = 2
+    while size < C:
+        M = jnp.where(low_left(size), A, 0.0)
+        X = X - _mm(_mm(X, M, _NN, jnp.float32), X, _NN, jnp.float32)
         size *= 2
-    U = mm(X, vbf, _NN) - mm(mm(X, kbf * jnp.exp(G), _NN), St, _NT)
-    o = mm(qf * jnp.exp(G), St, _NT) + mm(Aqk, U, _NN)
-    g_end = jnp.sum(jnp.where(row == C - 1, G, 0.0), axis=0, keepdims=True)
+    return X
+
+
+def _apply(qf, kf, kbf, vbf, G, St, Aqk, X, mxu):
+    """``(o, S'^T)`` of the chunk from its pair matrix ``Aqk`` and the solve
+    ``X = (I + A)^-1``: the last three equations of the module docstring."""
+    mm = functools.partial(_mm, mxu=mxu)
+    eG = jnp.exp(G)
+    U = mm(X, vbf, _NN) - mm(mm(X, kbf * eG, _NN), St, _NT)
+    o = mm(qf * eG, St, _NT) + mm(Aqk, U, _NN)
+    g_end = _row(G, G.shape[0] - 1)
     St = St * jnp.exp(g_end) + mm(U, kf * jnp.exp(g_end - G), _TN)
     return o, St
+
+
+def _chunk_keeping_solve(q, k, kb, vb, G, St, on_tpu=False):
+    """``_chunk`` with the solve ``X [C, C]`` (float32) it went through: what
+    the forward kernel hands the backward."""
+    f32 = jnp.float32
+    qf, kf, kbf, vbf = (t.astype(f32) for t in (q, k, kb, vb))
+    A, Aqk = _pair(qf, kf, kbf, G, q.dtype, on_tpu)
+    X = _solve(A)
+    return (*_apply(qf, kf, kbf, vbf, G, St, Aqk, X, q.dtype), X)
+
+
+def _chunk(q, k, kb, vb, G, St, on_tpu=False):
+    """One chunk of one head: ``(o [C, dv], S'^T [dv, dk])`` from ``q, k
+    [C, dk]``, ``kb = b k``, ``vb = b v [C, dv]``, the chunk's running
+    log-decay ``G [C, dk]`` (float32) and the transposed state ``St
+    [dv, dk]`` (float32) the chunk starts from. Module docstring for the
+    equations; products take ``q``'s dtype into the MXU."""
+    return _chunk_keeping_solve(q, k, kb, vb, G, St, on_tpu)[:2]
+
+
+def _chunk_bwd(q, k, kb, vb, G, St, X, do, dSt, on_tpu=False):
+    """The cotangents ``(dq, dk, dkb, dvb, dG, dSt)`` of ``_chunk`` under
+    ``(do [C, dv], dS'^T [dv, dk])``, around the forward's ``X = (I + A)^-1``
+    and never through it: with ``U = X R`` (``R = b v - (b k e^G) S``) the
+    solve's cotangents are ``dR = X^T dU`` and ``dA = -dR U^T`` (its strict
+    lower part: the pair matrices' masks take it). The application's
+    transposes by hand, the pair matrices' by ``jax.vjp`` of ``_pair``."""
+    f32 = jnp.float32
+    mxu = q.dtype
+    mm = functools.partial(_mm, mxu=mxu)
+    qf, kf, kbf, vbf = (t.astype(f32) for t in (q, k, kb, vb))
+    C = q.shape[0]
+    row = lax.broadcasted_iota(jnp.int32, (C, 1), 0)
+    eG = jnp.exp(G)
+    g_end = _row(G, C - 1)
+    e_end, dec = jnp.exp(g_end), jnp.exp(g_end - G)
+    qe, KG, kdec = qf * eG, kbf * eG, kf * dec
+    (_, Aqk), pull = jax.vjp(
+        lambda *a: _pair(*a, mxu, on_tpu), qf, kf, kbf, G)
+    U = mm(X, vbf, _NN) - mm(mm(X, KG, _NN), St, _NT)
+    dU = mm(Aqk, do, _TN) + mm(kdec, dSt, _NT)
+    dR = mm(X, dU, _TN)
+    dqe = mm(do, St, _NN)
+    dKG = -mm(dR, St, _NN)
+    dkdec = mm(U, dSt, _NN)
+    dq, dk, dkb, dG = pull((-mm(dR, U, _NT), mm(do, U, _NT)))
+    gk = dkdec * kdec
+    dg_end = jnp.sum(gk, axis=0, keepdims=True) + e_end * jnp.sum(
+        dSt * St, axis=0, keepdims=True)
+    dG = dG + dqe * qe + dKG * KG - gk + jnp.where(row == C - 1, dg_end, 0.0)
+    dSt = dSt * e_end + mm(do, qe, _TN) - mm(dR, KG, _TN)
+    return dq + dqe * eG, dk + dkdec * dec, dkb + dKG * eG, dR, dG, dSt
 
 
 # ---------------------------------------------------------------------------
@@ -214,30 +295,31 @@ def _scan_chunks(q, k, kb, vb, G):
 # kernels
 # ---------------------------------------------------------------------------
 
-def _fwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, o_ref, h_ref, st_ref, *,
-                on_tpu):
+def _fwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, o_ref, h_ref, x_ref,
+                st_ref, *, on_tpu):
     @pl.when(pl.program_id(1) == 0)
     def _zero():
         st_ref[...] = jnp.zeros_like(st_ref)
 
     h_ref[...] = st_ref[...]
-    o, st = _chunk(q_ref[...], k_ref[...], kb_ref[...], vb_ref[...],
-                   g_ref[...], st_ref[...], on_tpu)
+    o, st, x = _chunk_keeping_solve(
+        q_ref[...], k_ref[...], kb_ref[...], vb_ref[...], g_ref[...],
+        st_ref[...], on_tpu)
     o_ref[...] = o.astype(o_ref.dtype)
+    x_ref[...] = x
     st_ref[...] = st
 
 
-def _bwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, h_ref, do_ref,
+def _bwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, h_ref, x_ref, do_ref,
                 dq_ref, dk_ref, dkb_ref, dvb_ref, dg_ref, dst_ref, *, on_tpu):
     @pl.when(pl.program_id(1) == 0)
     def _zero():
         dst_ref[...] = jnp.zeros_like(dst_ref)
 
-    _, pull = jax.vjp(
-        functools.partial(_chunk, on_tpu=on_tpu), q_ref[...], k_ref[...],
-        kb_ref[...], vb_ref[...], g_ref[...], h_ref[...])
-    dq, dk, dkb, dvb, dg, dst = pull(
-        (do_ref[...].astype(jnp.float32), dst_ref[...]))
+    dq, dk, dkb, dvb, dg, dst = _chunk_bwd(
+        q_ref[...], k_ref[...], kb_ref[...], vb_ref[...], g_ref[...],
+        h_ref[...], x_ref[...], do_ref[...].astype(jnp.float32),
+        dst_ref[...], on_tpu)
     dq_ref[...] = dq.astype(dq_ref.dtype)
     dk_ref[...] = dk.astype(dk_ref.dtype)
     dkb_ref[...] = dkb.astype(dkb_ref.dtype)
@@ -253,8 +335,9 @@ def _params():
 
 @functools.partial(jax.jit, static_argnums=(5,))
 def _kda_fwd_call(q, k, kb, vb, G, interpret):
-    """``(o [BH, N, C, dv], h [BH, N, dv, dk])``: the outputs and the
-    transposed state each chunk starts from."""
+    """``(o [BH, N, C, dv], h [BH, N, dv, dk], X [BH, N, C, C])``: the
+    outputs, the transposed state each chunk starts from and each chunk's
+    solve ``(I + A)^-1`` (float32 both)."""
     BH, N, C, dk = q.shape
     dv = vb.shape[-1]
     at = lambda h, n: (h, n, 0, 0)
@@ -264,20 +347,22 @@ def _kda_fwd_call(q, k, kb, vb, G, interpret):
         functools.partial(_fwd_kernel, on_tpu=not interpret),
         name=KERNEL_NAMES["fwd"],
         out_shape=(jax.ShapeDtypeStruct((BH, N, C, dv), q.dtype),
-                   jax.ShapeDtypeStruct((BH, N, dv, dk), jnp.float32)),
+                   jax.ShapeDtypeStruct((BH, N, dv, dk), jnp.float32),
+                   jax.ShapeDtypeStruct((BH, N, C, C), jnp.float32)),
         grid=(BH, N),
         in_specs=[qk, qk, qk, vo, qk],
-        out_specs=(vo, pl.BlockSpec((None, None, dv, dk), at)),
+        out_specs=(vo, pl.BlockSpec((None, None, dv, dk), at),
+                   pl.BlockSpec((None, None, C, C), at)),
         scratch_shapes=[pltpu.VMEM((dv, dk), jnp.float32)],
         compiler_params=_params(),
         interpret=interpret,
     )(q, k, kb, vb, G)
 
 
-@functools.partial(jax.jit, static_argnums=(7,))
-def _kda_bwd_call(q, k, kb, vb, G, h, do, interpret):
+@functools.partial(jax.jit, static_argnums=(8,))
+def _kda_bwd_call(q, k, kb, vb, G, h, X, do, interpret):
     """The cotangents of ``q, k, kb, vb, G`` under ``do``, the chunks walked
-    last to first."""
+    last to first, each around the solve ``X`` the forward kept."""
     BH, N, C, dk = q.shape
     dv = vb.shape[-1]
     back = lambda h, n: (h, N - 1 - n, 0, 0)
@@ -290,12 +375,13 @@ def _kda_bwd_call(q, k, kb, vb, G, h, do, interpret):
         out_shape=(like(q), like(k), like(kb), like(vb), like(G)),
         grid=(BH, N),
         in_specs=[qk, qk, qk, vo, qk,
-                  pl.BlockSpec((None, None, dv, dk), back), vo],
+                  pl.BlockSpec((None, None, dv, dk), back),
+                  pl.BlockSpec((None, None, C, C), back), vo],
         out_specs=(qk, qk, qk, vo, qk),
         scratch_shapes=[pltpu.VMEM((dv, dk), jnp.float32)],
         compiler_params=_params(),
         interpret=interpret,
-    )(q, k, kb, vb, G, h, do)
+    )(q, k, kb, vb, G, h, X, do)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
@@ -306,8 +392,8 @@ def _kda_kernels(q, k, kb, vb, G, interpret):
 def _kda_kernels_fwd(q, k, kb, vb, G, interpret):
     BH, N, C, dk = q.shape
     _note_plans(("fwd",), BH, N * C, dk, vb.shape[-1])
-    o, h = _kda_fwd_call(q, k, kb, vb, G, interpret)
-    return o, (q, k, kb, vb, G, h)
+    o, h, X = _kda_fwd_call(q, k, kb, vb, G, interpret)
+    return o, (q, k, kb, vb, G, h, X)
 
 
 def _kda_kernels_bwd(interpret, res, do):

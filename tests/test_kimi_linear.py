@@ -138,6 +138,107 @@ def test_identical_keys_and_no_decay_stay_finite_and_exact():
         _close(kda.kda_attention(*args, interpret=True), _recurrence(*args))
 
 
+def _one_chunk(seed, decay, C=64, d=32, same_keys=False):
+    """One head's chunk as ``kda_attention`` hands it to ``_chunk``, float32,
+    with a state to start from and cotangents ``(do, dS')``."""
+    (q, k, v, g, beta), do = _operands(seed, C, d, decay, heads=1)
+    if same_keys:  # I + A all ones below the diagonal
+        k = jnp.broadcast_to(jnp.eye(d)[0], k.shape)
+        g, beta = jnp.zeros_like(g), jnp.ones_like(beta)
+    q, k, v, g, do = (t[0, 0] for t in (q, k, v, g, do))
+    b = beta[0, 0][:, None]
+    ks = jax.random.split(jax.random.PRNGKey(seed + 100), 2)
+    St = jax.random.normal(ks[0], (d, d))
+    return ((q, k, b * k, b * v, jnp.cumsum(g, axis=0), St),
+            (do, jax.random.normal(ks[1], (d, d))))
+
+
+@pytest.mark.parametrize("case", [1e-4, 0.3, 30.0, "same keys"],
+                         ids=["decay~1", "decay~0.8", "decay~0", "same-keys"])
+def test_hand_derived_chunk_backward_equals_autodiff_through_the_solver(case):
+    """``_chunk_bwd`` (the backward kernel's body: around the forward's
+    ``(I + A)^-1``, never through it) against ``jax.vjp`` of ``_chunk``
+    (through all six levels): every one of the six cotangents."""
+    same = case == "same keys"
+    args, cts = _one_chunk(11, 0.0 if same else case, same_keys=same)
+    with jax.default_matmul_precision("highest"):
+        out, pull = jax.vjp(kda._chunk, *args)
+        want = pull(cts)
+        *again, X = kda._chunk_keeping_solve(*args)
+        got = kda._chunk_bwd(*args, X, *cts)
+    for a, b in zip(again, out):   # one body: the same values
+        np.testing.assert_array_equal(a, b)
+    for name, a, b in zip(("q", "k", "kb", "vb", "G", "St"), got, want):
+        assert np.isfinite(np.asarray(a)).all(), name
+        _close(a, b, 1e-5)
+
+
+@pytest.mark.parametrize("matrix", ["all ones", "random"])
+def test_the_solve_with_its_first_level_a_mask_inverts_i_plus_a(matrix):
+    """Six levels, the first (blocks of one row, ``X = I``) written as the
+    mask it is: against float64's inverse, on the identical-keys matrix and
+    a random strictly lower one."""
+    C = kda.CHUNK
+    lower = np.tril(np.ones((C, C)), -1)
+    A = lower if matrix == "all ones" else lower * np.asarray(
+        jax.random.normal(jax.random.PRNGKey(1), (C, C)), np.float64) * 0.3
+    want = np.linalg.inv(np.eye(C) + A.astype(np.float64))
+    _close(kda._solve(jnp.asarray(A, jnp.float32)), want, 1e-5)
+
+
+def _dots(jaxpr):
+    """Every ``dot_general`` equation of a jaxpr, kernel bodies and other
+    nested jaxprs included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            found.append(eqn)
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (list, tuple)) else [value]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found.extend(_dots(sub))
+    return found
+
+
+def _exact_square_products(jaxpr, C=64):
+    exact = jax.lax.Precision.HIGHEST
+    return sum(
+        all(v.aval.shape == (C, C) for v in e.invars)
+        and e.params["precision"] in (exact, (exact, exact))
+        for e in _dots(jaxpr))
+
+
+def test_only_the_forward_solves_and_in_ten_products():
+    """The structural pin: at the cell's shapes the forward kernel's body
+    holds the solve's ten exact ``[64, 64] x [64, 64]`` products (five
+    levels of two; the level of one-row blocks is a mask) and the backward
+    kernel's none — it takes ``(I + A)^-1`` from the forward and is not a
+    ``jax.vjp`` through the recursion (24 more, and the 12 recomputed)."""
+    BH, N, C, d = 8, 128, 64, 128
+    t = jax.ShapeDtypeStruct((BH, N, C, d), jnp.bfloat16)
+    G = jax.ShapeDtypeStruct((BH, N, C, d), jnp.float32)
+    h = jax.ShapeDtypeStruct((BH, N, d, d), jnp.float32)
+    X = jax.ShapeDtypeStruct((BH, N, C, C), jnp.float32)
+    fwd = jax.make_jaxpr(lambda *a: kda._kda_fwd_call(*a, False))(
+        t, t, t, t, G)
+    assert [v.aval.shape for v in fwd.jaxpr.outvars] == [
+        t.shape, h.shape, X.shape]
+    assert _exact_square_products(fwd.jaxpr) == 10
+    bwd = jax.make_jaxpr(lambda *a: kda._kda_bwd_call(*a, False))(
+        t, t, t, t, G, h, X, t)
+    assert _exact_square_products(bwd.jaxpr) == 0
+    # 13 products of the application's transposes and the solve's two
+    # cotangents, 6 + 12 of the pair matrices' vjp
+    assert len(_dots(bwd.jaxpr)) == 31
+    # the guard sees the solver's derivative where there is one
+    through = jax.make_jaxpr(lambda *a: jax.vjp(kda._chunk, *a)[1](
+        (a[3].astype(jnp.float32), a[5])))(*(
+            jax.ShapeDtypeStruct(s.shape[2:], s.dtype)
+            for s in (t, t, t, t, G, h)))
+    assert _exact_square_products(through.jaxpr) == 10 + 20
+
+
 def test_shapes_that_nothing_computes_are_refused():
     args, _ = _operands(0, 8, 16, 0.1)
     with pytest.raises(ValueError, match="kda_attention"):
@@ -172,8 +273,9 @@ def test_kernel_plans_reach_status_with_both_widths():
 
 def test_the_kernels_lower_for_a_tpu():
     """The Pallas TPU front end takes both kernel bodies at the cell's
-    shapes (the vector-Jacobian product traced into the backward's body
-    too); Mosaic's own compile needs the chip or its compiler."""
+    widths (the backward's hand-derived around the forward's solve, with
+    the pair matrices' ``jax.vjp`` traced into it); Mosaic's own compile
+    needs the chip or its compiler."""
     t = jax.ShapeDtypeStruct((1, 4, 256, 128), jnp.bfloat16)
     g = jax.ShapeDtypeStruct((1, 4, 256, 128), jnp.float32)
     b = jax.ShapeDtypeStruct((1, 4, 256), jnp.float32)

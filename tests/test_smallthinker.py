@@ -453,7 +453,12 @@ PARENT_PROGRAMS = {
     # / f6de1660c093e2b3
     "olmoe-1b-7b": ("2072f548af7248f4", "4b53909c4639758e"),
     "moonlight-16b-a3b": ("c5d9c8027f4acd6d", "83dc38593d13b721"),
-    "kimi-linear-48b-a3b": ("613a652ead1e7c25", "822ef39f72cedb41"),
+    # Kimi Linear's two RECORDED AGAIN BY PR 46, which meant to change them
+    # and no other: the KDA forward kernel also writes (I + A)^-1 and the
+    # backward's body is hand-derived around it (ops/kda.py). The parent's
+    # (commit eba0826): 613a652ead1e7c25 / 822ef39f72cedb41 and, chunked,
+    # 900b195c6eb54df7 / 76c2b3e32f6b2c41
+    "kimi-linear-48b-a3b": ("2281e4f1a27b6ac2", "149e47f8d1a77ef0"),
     # the same two with 8 of 64 experts held, top-4: the chunked expert
     # layer and its hand-written backward (the rehearse presets hold half
     # their experts and take the full-length pass); PR 37 recorded these two
@@ -461,7 +466,7 @@ PARENT_PROGRAMS = {
     # parent's of PR 43: a4926d2815adc9a1 / b95e5090d1b8c013 and
     # 5e16810afaf161cd / a87befccf5a25f54)
     "moonlight-16b-a3b+chunked": ("35221c1eb04af432", "715a9f21cc4f8472"),
-    "kimi-linear-48b-a3b+chunked": ("900b195c6eb54df7", "76c2b3e32f6b2c41"),
+    "kimi-linear-48b-a3b+chunked": ("bf4fd3bcc0118c99", "cb972f89dd457a6d"),
     # SmallThinker's own preset (the parent's of PR 43: ca2c218290bfadc4 /
     # f29d583db0040dd7 and 70aaf04974a21872 / cb19e362794a5553, recorded on
     # the parent of PR 38)
