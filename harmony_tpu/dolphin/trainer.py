@@ -208,7 +208,9 @@ class Trainer:
         rows are ``sections`` sections of state that update together, row
         by row, the parameters first. ``gradient(model, batch) -> (g,
         metrics)`` is the step's COMP: of the pulled table it reads the
-        parameter section alone, and ``g`` is ``rows`` rows.
+        parameter section alone, and ``g`` is ``rows`` rows — one array,
+        or a pytree of arrays that ``push_update`` knows how to read (the
+        fence carries either).
         ``push_update(spec, arr, model, g, hyper) -> new_arr`` is
         the step's PUSH: the rule folded into the table ``arr`` where the
         rows lie (``TableSpec.fold_row_sections`` / ``push_row_ranges``;

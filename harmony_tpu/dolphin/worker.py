@@ -125,11 +125,12 @@ def pull_all_step(spec, trainer, mesh: Mesh):
     ``whole_delta``: PULL the table | COMP ``trainer.compute`` | PUSH
     ``push_all`` of its delta. ``row_ranges`` (:func:`update_lowering`):
     PULL the table | COMP the gradient, fenced and pinned replicated as
-    the delta is — one section, not three | PUSH the update rule on the
-    stored rows and its fold, section by section where the rows lie: no
-    delta is concatenated and none is added to the whole table. Per
-    element the arithmetic is the same in both: ``stored + (new -
-    stored)``."""
+    the delta is — one section, not three, and where the trainer hands it
+    over in row pieces a tuple of them, not one array | PUSH the update
+    rule on the stored rows and its fold, section by section where the
+    rows lie: no delta is concatenated and none is added to the whole
+    table. Per element the arithmetic is the same in both: ``stored +
+    (new - stored)``."""
     if update_lowering(spec, trainer, mesh) == "whole_delta":
 
         def _step(arr, batch, hyper):
@@ -529,8 +530,10 @@ class WorkerTasklet:
     def _note_update_lowering(self, mesh: Mesh) -> None:
         """STATUS ``tenants.<job>.table_layout.update_lowering`` /
         ``fold_lowering`` and their gauges: how the pull-all step just
-        built applies its update (:func:`update_lowering`), and what the
-        fold of its row sections lowers to (``TableSpec.fold_lowering``)."""
+        built applies its update (:func:`update_lowering`), what the fold
+        of its row sections lowers to (``TableSpec.fold_lowering``) and how
+        much of the gradient that fold reads where the leaves' relayouts
+        left it (``harmony_table_fold_direct_row_share``)."""
         from harmony_tpu.metrics import table_layout
 
         spec, trainer = self.ctx.model_table.spec, self.trainer
@@ -541,7 +544,10 @@ class WorkerTasklet:
                 spec.config.capacity)[:2]
             with on_mesh(mesh):
                 fold = spec.fold_lowering(rows, sections)
-            table_layout.note_fold(self.job_id, spec.table_id, fold)
+            leaf_rows = getattr(trainer, "leaf_rows", None)
+            table_layout.note_fold(
+                self.job_id, spec.table_id, fold,
+                leaf_rows.record() if leaf_rows is not None else None)
 
     def _program_key(self, table_sharding, local_sharding) -> "tuple | None":
         """Structural signature of everything the jitted step traces, for the

@@ -21,14 +21,18 @@ created or restored (jobserver/entity.py):
     is the trainer's where it has one (``PyTreeTrainer.section_stride``:
     rows between the ``[params | m | v]`` sections), else ``None``;
   * STATUS ``tenants.<job>.table_layout.leaf_layout`` = ``{leaves,
-    leaf_copies, leaf_bitcasts, pad_rows, rows}`` and the gauge
+    leaf_copies, leaf_bitcasts, pad_rows, rows, fold_pieces,
+    direct_rows}`` and the gauge
     ``harmony_table_leaf_pad_share{job,table}`` (``pad_rows / rows``), for
     a trainer whose model lies in its table leaf by leaf
     (``PyTreeTrainer.leaf_rows``: every leaf a range of whole 8-row tiles
     of a section): the leaves, how many of them a step relays out — one
     copy each a direction — and how many ARE their rows (a last dimension
-    of ``row_width``), and the rows of a section that hold no parameter,
-    the price of leaf-aligned rows (PERF.md §6, PR 42).
+    of ``row_width``), the rows of a section that hold no parameter,
+    the price of leaf-aligned rows (PERF.md §6, PR 42), and how the
+    gradient comes back: ``fold_pieces`` operands of the fold, of whose
+    rows ``direct_rows`` are one leaf's own, read where its relayout left
+    them, the others small leaves joined by one concatenate (PR 47).
 
 And what a keyed tenant's push lowers to, recorded where its step program is
 built (dolphin/worker.py ``_build_step``; ``TableSpec.push_lowering``):
@@ -52,7 +56,12 @@ place (dolphin/worker.py ``update_lowering``; ``TableSpec.fold_lowering``):
     ``table_layout.fold_lowering`` (``row_ranges`` tenants only) — 1 /
     ``"pallas_sections"`` when that fold is the one-pass in-place kernel
     (ops.sections.fold_row_sections), 0 / ``"xla"`` when XLA rewrites the
-    sections through fresh buffers.
+    sections through fresh buffers;
+  * ``harmony_table_fold_direct_row_share{job,table}`` — ``direct_rows /
+    rows`` of the trainer's ``leaf_layout`` where the fold is that kernel
+    (it reads each piece where it lies), 0 where XLA's takes the pieces
+    concatenated: the share of the gradient that is written once on its
+    way to the fold.
 """
 from __future__ import annotations
 
@@ -139,10 +148,23 @@ def note_update(job: str, table_id: str, lowering: str) -> None:
         "update_lowering", lowering, "row_ranges")
 
 
-def note_fold(job: str, table_id: str, lowering: str) -> None:
+def note_fold(job: str, table_id: str, lowering: str,
+              leaf_layout: Optional[Dict[str, int]] = None) -> None:
     """Record what the fold of ``job``'s row sections on ``table_id``
-    lowers to (``row_ranges`` tenants)."""
+    lowers to (``row_ranges`` tenants) and, for a trainer with a
+    ``leaf_layout`` (``LeafRows.record()``), the share of its gradient's
+    rows that lowering reads from a leaf's own buffer."""
     from harmony_tpu.metrics.registry import get_registry
+
+    if leaf_layout is not None:
+        get_registry().gauge(
+            "harmony_table_fold_direct_row_share",
+            "Rows of a section's gradient the fold reads from one leaf's "
+            "own buffer / the section's rows (0 where the fold is not the "
+            "in-place kernel and the pieces are concatenated)",
+            ("job", "table")).labels(job=job, table=table_id).set(
+                leaf_layout["direct_rows"] / max(leaf_layout["rows"], 1)
+                if lowering == "pallas_sections" else 0.0)
 
     _note_lowering(get_registry().gauge(
         "harmony_table_fold_pallas_sections",

@@ -28,8 +28,15 @@ multiple of ``TILE_ROWS`` — and holds ``ceil(n_i / (TILE_ROWS *
 row_width)) * TILE_ROWS`` rows, its tail zero. The offsets are static, from
 the template's shapes. So a step changes layout twice, where the model
 needs it, one copy a leaf each way: parameters ``p[first_i : first_i +
-k]`` reshaped to the leaf, and each gradient leaf reshaped to its rows, one
-concatenate of tile-aligned pieces — no ``[num_params]`` vector exists.
+k]`` reshaped to the leaf, and each gradient leaf reshaped to its rows —
+no ``[num_params]`` vector exists. The gradient's rows are never ONE array
+either: they go to the fold in tile-aligned PIECES (``LeafRows.to_pieces``),
+a large leaf's own rows a piece as its relayout left them and each run of
+small leaves between two such joined by one concatenate, and
+``ops.sections.fold_row_sections`` reads every piece where it lies — the
+gradient is written once on its way to the optimizer. (``join`` makes one array of the
+pieces for the whole-delta step; ``to_rows`` is the same rows built leaf by
+leaf, for a job's start.)
 The optimizer runs on row sections (m and v lie at the same offsets). Pad
 rows and pad lanes hold zeros and stay zero under every optimizer (g = m =
 v = 0 -> update 0). Chains written before this layout (leaves raveled end
@@ -47,6 +54,7 @@ import numpy as np
 
 from harmony_tpu.config.params import TILE_ROWS, TableConfig
 from harmony_tpu.dolphin.trainer import Trainer, TrainerContext
+from harmony_tpu.ops.sections import PIECE_ROWS
 from harmony_tpu.tracing.stepscopes import step_scope
 
 #: this layout's name, as a chain's manifest records it
@@ -106,13 +114,34 @@ class LeafRows:
             first += rows
         #: rows of a section: every leaf's tiles
         self.rows = first
+        #: the fold's side operands (``to_pieces``): each a run of
+        #: segments ``(leaf, its first row taken, rows)``. A leaf of
+        #: ``PIECE_ROWS`` rows or more is a piece alone, as far as its
+        #: reshape gives whole tiles (gpt2's embedding reads 37,695 rows:
+        #: 37,688, its last tile goes with what follows); the leaves
+        #: between two such are joined
+        runs, run = [], []
+        for i, f in enumerate(self.leaves):
+            if f.rows >= PIECE_ROWS:
+                whole = (math.prod(f.read) // row_width
+                         // TILE_ROWS * TILE_ROWS)
+                runs += [run, [(i, 0, whole)]]
+                run = [(i, whole, f.rows - whole)] if whole < f.rows else []
+            elif f.rows:
+                run.append((i, 0, f.rows))
+        self.pieces = [r for r in (*runs, run) if r]
+        #: each piece's first row in a section
+        self.piece_firsts = [self.leaves[r[0][0]].first + r[0][1]
+                             for r in self.pieces]
 
     def record(self) -> Dict[str, int]:
         """STATUS ``table_layout.leaf_layout``: ``leaf_bitcasts`` counts
         the leaves whose rows ARE the leaf (whole tiles of ``row_width``
         lanes: merging leading dimensions moves nothing), ``leaf_copies``
         the others — one relayout copy each a direction; ``pad_rows`` the
-        rows that hold no parameter."""
+        rows that hold no parameter; ``fold_pieces`` the operands the
+        fold reads the gradient from and ``direct_rows`` the rows of those
+        that are one leaf's own rows, not a concatenate of several."""
         w = self.row_width
         bitcasts = sum(
             1 for f in self.leaves
@@ -123,7 +152,10 @@ class LeafRows:
                 "leaf_bitcasts": bitcasts,
                 "pad_rows": sum(f.rows - -(-f.size // w)
                                 for f in self.leaves),
-                "rows": self.rows}
+                "rows": self.rows,
+                "fold_pieces": len(self.pieces),
+                "direct_rows": sum(r[0][2] for r in self.pieces
+                                   if len(r) == 1)}
 
     def to_leaves(self, rows: jnp.ndarray) -> Any:
         """Rows ``[>= self.rows, row_width]`` whose first rows are a
@@ -149,16 +181,49 @@ class LeafRows:
             out.append(x[:f.lead].reshape(f.shape).astype(f.dtype))
         return jax.tree.unflatten(self.treedef, out)
 
-    def to_rows(self, tree: Any) -> jnp.ndarray:
-        """The pytree -> rows ``[self.rows, row_width]`` float32: each leaf
-        reshaped, its tail zero, to its own rows, and one concatenate of
-        tile-aligned pieces."""
-        pieces = []
+    def _leaf_rows(self, tree: Any):
+        """Each leaf of the pytree as float32 rows of ``row_width``: its
+        reshape, whole rows (its tail zero) and not yet whole tiles."""
+        rows = []
         for f, x in zip(self.leaves, self.treedef.flatten_up_to(tree)):
             x = x.astype(jnp.float32).reshape(f.lead, *f.read[1:])
-            x = _grow(x, f.read[0]).reshape(-1, self.row_width)
-            pieces.append(_grow(x, f.rows))
-        return jnp.concatenate(pieces)
+            rows.append(_grow(x, f.read[0]).reshape(-1, self.row_width))
+        return rows
+
+    def to_pieces(self, tree: Any) -> Tuple[jnp.ndarray, ...]:
+        """The pytree -> the rows ``[self.rows, row_width]`` float32 as
+        the tile-aligned pieces ``self.pieces``, in order: piece j holds
+        the section's rows from ``piece_firsts[j]`` to the next piece's
+        first (and, where it is a leaf whose reshape ends inside a tile,
+        that tile's rows after them). Each leaf is reshaped to its own
+        rows — the one pass over a large leaf, and none where its rows ARE
+        the leaf — and every run of small leaves is one concatenate, their
+        tails zero. What ``fold_row_sections`` reads, piece by piece: the
+        rows as ONE array are never built."""
+        rows = self._leaf_rows(tree)
+
+        def segment(i, at, n):
+            x = rows[i][at:]
+            return x if x.shape[0] >= n else _grow(x, n)
+
+        return tuple(segment(*r[0]) if len(r) == 1 else
+                     jnp.concatenate([segment(*s) for s in r])
+                     for r in self.pieces)
+
+    def join(self, pieces) -> jnp.ndarray:
+        """``to_pieces``' pieces -> the rows ``[self.rows, row_width]`` as
+        one array (the whole-delta step's)."""
+        ends = [*self.piece_firsts[1:], self.rows]
+        return jnp.concatenate([p[:end - first] for p, first, end in zip(
+            pieces, self.piece_firsts, ends)])
+
+    def to_rows(self, tree: Any) -> jnp.ndarray:
+        """The pytree -> rows ``[self.rows, row_width]`` float32: each leaf
+        reshaped, its tail zero, to its own rows, and one concatenate —
+        ``to_pieces`` joined, built leaf by leaf so that it costs a job's
+        start, which runs it op by op, no copy of a piece."""
+        return jnp.concatenate([_grow(x, f.rows) for f, x in zip(
+            self.leaves, self._leaf_rows(tree))])
 
     def fill_rows(self, section: np.ndarray, leaves) -> None:
         """Host: write ``leaves`` (arrays, or flat vectors of each leaf's
@@ -372,18 +437,19 @@ class PyTreeTrainer(Trainer):
                 self.gradient, self.push_update)
 
     def gradient(self, model: jnp.ndarray, batch):
-        """``(g, metrics)``: the gradient as rows ``[stride, row_width]``
-        over the parameter section of the pulled ``model`` (rows ->
-        leaves, value_and_grad, leaves -> rows), and what the step
-        reports. The leaves are read where they lie in ``model``: a slice
-        of its parameter section first would be a copy of the section."""
+        """``(g, metrics)``: the gradient over the parameter section of
+        the pulled ``model`` as the row pieces ``LeafRows.to_pieces`` makes
+        of it (rows -> leaves, value_and_grad, leaves -> their rows), and
+        what the step reports. The leaves are read where they lie in
+        ``model``: a slice of its parameter section first would be a copy
+        of the section."""
         with step_scope("table.pull"):
             leaves = self._params(model)
         (loss, extra), grads = jax.value_and_grad(
             self.loss_and_metrics_on_batch, has_aux=True
         )(leaves, batch)
         with step_scope("table.grad_rows"):
-            g = self.leaf_rows.to_rows(grads)
+            g = self.leaf_rows.to_pieces(grads)
         return g, {"loss": loss, **extra}
 
     def section_deltas(self, stored, g, scalars):
@@ -406,13 +472,15 @@ class PyTreeTrainer(Trainer):
 
     def push_update(self, spec, arr, model, g, hyper):
         """PUSH: the optimizer on the table's own sections under the
-        gradient rows ``g`` (``model``: the pulled table, for the step
-        count), each stored row read and written where it lies, then the
-        counter's ``+1``. Tile-aligned sections only."""
+        gradient's row pieces ``g`` (``model``: the pulled table, for the
+        step count), each stored row read and written where it lies and
+        each piece read where ``gradient`` left it, then the counter's
+        ``+1``. Tile-aligned sections only."""
         slots = self.num_state_slots
         t = self.counter(model) + 1.0 if slots else jnp.asarray(1.0)
         arr = spec.fold_row_sections(
-            arr, g, {"t": t, **hyper}, self.section_deltas,
+            arr, list(zip(self.leaf_rows.piece_firsts, g)),
+            {"t": t, **hyper}, self.section_deltas,
             rows=self.section_rows, sections=1 + slots)
         if slots:
             arr = spec.push_row_ranges(arr, [(
@@ -424,6 +492,7 @@ class PyTreeTrainer(Trainer):
         slots = self.num_state_slots
         stored = tuple(self.section(model, i) for i in range(1 + slots))
         g, metrics = self.gradient(model, batch)
+        g = self.leaf_rows.join(g)
         t = self.counter(model) + 1.0 if slots else jnp.asarray(1.0)
         sections = list(self.section_deltas(stored, g, {"t": t, **hyper}))
         tail = model.shape[0] - len(sections) * g.shape[0]

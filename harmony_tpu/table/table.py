@@ -545,8 +545,11 @@ class TableSpec:
         state that updates together, row by row — ``stored[k][r] +=
         rule(stored, side, scalars)[k][r]``, where ``rule`` gets the
         ``sections`` stored blocks and ``side`` (``[rows, *value_shape]``,
-        e.g. a gradient) in one shape, ``scalars`` as a dict of values
-        that broadcast against them, and returns one delta per section.
+        e.g. a gradient; or its rows in pieces ``[(first_row, piece),
+        ...]``, which the one-pass lowering reads where they lie and the
+        other concatenates: ops.sections.fold_row_sections) in one shape, ``scalars`` as a dict of
+        values that broadcast against them, and returns one delta per
+        section.
         The update fn's fold of the table, with the delta computed where
         the rows lie: in one in-place pass (:meth:`fold_lowering`), else
         the rule on whole sections and one rewrite of them
@@ -557,18 +560,22 @@ class TableSpec:
         )
 
         flat = self._flat_rows(arr, "fold_row_sections")
+        if isinstance(side, (tuple, list)):
+            side = [(first, p.astype(arr.dtype)) for first, p in side]
+        else:
+            side = side.astype(arr.dtype)
         if self.fold_lowering(rows, sections) == "pallas_sections":
             keys = sorted(scalars)
             consts = jnp.stack([jnp.broadcast_to(
                 jnp.asarray(scalars[k], arr.dtype), flat.shape[1:])
                 for k in keys])
             return fold_row_sections(
-                flat, side.astype(arr.dtype), consts,
+                flat, side, consts,
                 lambda stored, g, c: rule(stored, g, {
                     k: c[i:i + 1] for i, k in enumerate(keys)}),
                 rows=rows, sections=sections).reshape(arr.shape)
         return fold_row_sections_ref(
-            flat, side.astype(arr.dtype), scalars, rule, rows=rows,
+            flat, side, scalars, rule, rows=rows,
             sections=sections).reshape(arr.shape)
 
     def write_all(self, arr: jnp.ndarray, values: jnp.ndarray) -> jnp.ndarray:

@@ -373,6 +373,19 @@ def test_lm_step_on_a_row_sharded_table_keeps_the_whole_delta(one_chip):
     assert "all-gather" not in compiled.as_text()
 
 
+def _optimizer_rule(optimizer):
+    """``optimizer`` as the fold's elementwise rule over its sections."""
+    from harmony_tpu.dolphin import optim
+
+    def rule(stored, g, consts):
+        p, m, v = (*stored, g, g)[:3]
+        new = optim.apply(optimizer, p, g, m, v, consts[0:1],
+                          {"lr": consts[1:2], "beta2": consts[2:3]})
+        return tuple(n - o for n, o in zip(new, stored))
+
+    return rule
+
+
 @pytest.mark.parametrize("rows,optimizer", [
     (121_424, "adam"),       # gpt2-124m: three sections of 0.498 GB
     (279_960, "adam"),       # olmoe-1b-7b
@@ -389,12 +402,7 @@ def test_section_fold_compiles_in_place_for_v5e(one_chip, rows, optimizer):
     from harmony_tpu.ops.sections import fold_row_sections
 
     sections = 1 + optim.num_slots(optimizer)
-
-    def rule(stored, g, consts):
-        p, m, v = (*stored, g, g)[:3]
-        new = optim.apply(optimizer, p, g, m, v, consts[0:1],
-                          {"lr": consts[1:2], "beta2": consts[2:3]})
-        return tuple(n - o for n, o in zip(new, stored))
+    rule = _optimizer_rule(optimizer)
 
     sd = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32,
                                              sharding=one_chip)
@@ -407,3 +415,46 @@ def test_section_fold_compiles_in_place_for_v5e(one_chip, rows, optimizer):
     ma = compiled.memory_analysis()
     assert ma.alias_size_in_bytes == (sections * rows + 8) * 1024 * 4
     assert ma.temp_size_in_bytes == 0
+
+
+@pytest.mark.parametrize("config", [
+    "gpt2-124m",                    # 62 pieces, a dozen of 16 rows
+    "zaya1-8b",                     # 26, four expert stacks of 32,768 rows
+    "nemotron-3-super-120b-a12b",   # 61
+])
+def test_section_fold_reads_the_benchmark_pieces_for_v5e(one_chip, config):
+    """The fold with the gradient in ``LeafRows``' pieces at a benchmark
+    configuration's real shapes — every piece an operand in HBM, the walk
+    in SMEM — compiled by Mosaic for a v5e: still ONE custom call, the
+    table aliased onto the result whole, no temporary beside the few
+    short pieces' blocks."""
+    import json
+    import os
+
+    from harmony_tpu.models import TransformerTrainer
+    from harmony_tpu.ops.sections import _BLOCK_ROWS, fold_row_sections
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "perf", "configs", config + ".json")) as f:
+        trainer = TransformerTrainer(**json.load(f)["job"]["app_params"])
+    lr = trainer.leaf_rows
+    pieces = jax.eval_shape(lr.to_pieces, jax.eval_shape(
+        lambda: trainer.model.init(jax.random.PRNGKey(0))))
+
+    rule = _optimizer_rule("adam")
+    sd = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32,
+                                             sharding=one_chip)
+    rows = lr.rows
+    compiled = jax.jit(
+        lambda t, ps, c: fold_row_sections(
+            t, list(zip(lr.piece_firsts, ps)), c, rule, rows=rows,
+            sections=3),
+        donate_argnums=0).lower(
+            sd(3 * rows + 8, 1024), tuple(sd(*p.shape) for p in pieces),
+            sd(3, 1024)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes == (3 * rows + 8) * 1024 * 4
+    short = sum(end - first < _BLOCK_ROWS for first, end in zip(
+        lr.piece_firsts, [*lr.piece_firsts[1:], rows]))
+    assert ma.temp_size_in_bytes <= short * _BLOCK_ROWS * 1024 * 4

@@ -477,9 +477,9 @@ def _plain_rule(stored, g, consts):
     """Elementwise across the sections, with no product next to a sum:
     XLA's CPU backend contracts ``a * b + c`` where one fusion holds both,
     so only such a rule is bit-equal between two CPU programs."""
-    p, m, v = stored
+    p, m, v = (*stored, g, g)[:3]
     return ((g - m) - v, p - g,
-            jnp.sqrt(jnp.abs(v)) / (consts[0:1] + jnp.abs(g)))
+            jnp.sqrt(jnp.abs(v)) / (consts[0:1] + jnp.abs(g)))[:len(stored)]
 
 
 def _adam_rule(stored, g, consts):
@@ -489,19 +489,29 @@ def _adam_rule(stored, g, consts):
 
 
 _FOLD_SHAPES = {
-    # name: (rows a section, row width, rows after the sections)
-    "one_tile": (8, 128, 8),
-    "one_short_block": (88, 128, 8),
-    "whole_blocks": (512, 128, 16),
-    "last_block_overlaps": (264, 128, 8),
-    "last_block_of_odd_tiles": (600, 256, 0),
+    # name: (rows of each piece of the gradient, row width, rows after the
+    # sections); one piece is the gradient as one array
+    "one_tile": ([8], 128, 8),
+    "one_short_block": ([88], 128, 8),
+    "whole_blocks": ([512], 128, 16),
+    "last_block_overlaps": ([264], 128, 8),
+    "last_block_of_odd_tiles": ([600], 256, 0),
+    # pieces: every one an operand of its own, walked in blocks of 256 rows
+    "ends_off_the_blocks": ([264, 600, 296], 128, 8),
+    "a_piece_of_one_block": ([256, 512, 256], 128, 8),
+    "small_between_two_large": ([1032, 24, 1040], 128, 8),
+    "small_first_and_last": ([8, 520, 16], 128, 8),
+    "all_short_of_a_block": ([8, 16, 8], 128, 0),
+    "many": ([16, 256, 8, 1000, 24, 304, 8], 128, 8),
 }
 
 
-def _fold_operands(rows, width, extra):
+def _fold_operands(rows, width, extra, sections=3):
     rng = np.random.default_rng(rows)
-    table = rng.normal(size=(3 * rows + extra, width)).astype(np.float32)
-    table[2 * rows:3 * rows] = np.abs(table[2 * rows:3 * rows])  # v >= 0
+    table = rng.normal(size=(sections * rows + extra, width)).astype(
+        np.float32)
+    table[(sections - 1) * rows:sections * rows] = np.abs(
+        table[(sections - 1) * rows:sections * rows])  # v >= 0
     consts = np.broadcast_to(
         np.asarray([3.0, 1e-3, 0.95], np.float32)[:, None], (3, width))
     return (jnp.asarray(table),
@@ -509,33 +519,84 @@ def _fold_operands(rows, width, extra):
             jnp.asarray(consts))
 
 
+@pytest.mark.parametrize("sections", [1, 3])   # SGD's table, Adam's
 @pytest.mark.parametrize("shape", list(_FOLD_SHAPES))
-def test_fold_kernel_equals_its_reference(shape):
-    """Every section's every row once, whatever the block count: a last
-    block that overlaps the one before it writes only its new rows, and
-    the rows after the sections are not touched."""
+def test_fold_kernel_equals_its_reference(shape, sections):
+    """Every section's every row once, whatever the pieces and the block
+    count: a piece's last block overlaps the one before it and writes only
+    its new rows, a piece shorter than a block is read inside a block
+    pushed back into the section, and the rows after the sections are not
+    touched. A piece may be longer than the rows it stands for (a leaf
+    whose reshape ends inside a tile): the rest is not read."""
     from harmony_tpu.ops.sections import (
         fold_row_sections,
         fold_row_sections_ref,
     )
 
-    rows, width, extra = _FOLD_SHAPES[shape]
-    table, g, consts = _fold_operands(rows, width, extra)
-    fold = dict(rows=rows, sections=3)
+    pieces, width, extra = _FOLD_SHAPES[shape]
+    rows = sum(pieces)
+    table, g, consts = _fold_operands(rows, width, extra, sections)
+    fold = dict(rows=rows, sections=sections)
+    firsts = [0, *np.cumsum(pieces)[:-1].tolist()]
+    junk = jnp.full((3, width), np.nan, jnp.float32)
+
+    def side(g):
+        if len(pieces) == 1:
+            return g
+        parts = [g[a:a + n] for a, n in zip(firsts, pieces)]
+        # the longest piece handed over with rows after its own
+        longest = int(np.argmax(pieces))
+        parts[longest] = jnp.concatenate([parts[longest], junk])
+        return list(zip(firsts, parts))
+
     want = jax.jit(lambda *a: fold_row_sections_ref(*a, _plain_rule, **fold))(
         table, g, consts)
-    got = jax.jit(lambda *a: fold_row_sections(
-        *a, _plain_rule, interpret=True, **fold))(table, g, consts)
+    got = jax.jit(lambda t, g, c: fold_row_sections(
+        t, side(g), c, _plain_rule, interpret=True, **fold))(table, g, consts)
     np.testing.assert_array_equal(got, want)
-    assert np.abs(np.asarray(want) - np.asarray(table))[:3 * rows].min() > 0
-    np.testing.assert_array_equal(got[3 * rows:], table[3 * rows:])
+    np.testing.assert_array_equal(
+        jax.jit(lambda t, g, c: fold_row_sections_ref(
+            t, side(g), c, _plain_rule, **fold))(table, g, consts), want)
+    assert np.abs(np.asarray(want) - np.asarray(table))[
+        :sections * rows].max(axis=1).min() > 0  # every row moved
+    np.testing.assert_array_equal(got[sections * rows:],
+                                  table[sections * rows:])
+    if sections < 3:
+        return
     # Adam's rule: the same jnp ops, so at most the CPU's contraction of
     # ``b1 * m + (1 - b1) * g`` apart (the chip reads equal: PERF.md PR 30)
     want = jax.jit(lambda *a: fold_row_sections_ref(*a, _adam_rule, **fold))(
         table, g, consts)
-    got = jax.jit(lambda *a: fold_row_sections(
-        *a, _adam_rule, interpret=True, **fold))(table, g, consts)
+    got = jax.jit(lambda t, g, c: fold_row_sections(
+        t, side(g), c, _adam_rule, interpret=True, **fold))(table, g, consts)
     np.testing.assert_allclose(got, want, rtol=0, atol=5e-7)  # an ulp of 4
+
+
+@pytest.mark.parametrize("pieces,rows", [
+    ([16, 16], 32), ([8], 8), ([264, 8, 600], 872), ([8] * 5, 40),
+    ([300 * 8, 8, 256, 264], 2928)])
+def test_fold_schedule_writes_every_row_once(pieces, rows):
+    """The kernel's walk, as numbers: every block lies inside the section
+    and inside its piece, and the new rows of all blocks are the section's
+    rows, each once, in order."""
+    from harmony_tpu.ops import sections
+
+    block = min(sections._BLOCK_ROWS, rows)
+    at, piece, piece_at, new_at, new = sections._schedule(pieces, rows, block)
+    assert (at >= 0).all() and (at + block <= rows).all()
+    assert (at % 8 == 0).all() and (new_at % 8 == 0).all()
+    assert (new > 0).all() and (new_at + new <= block).all()
+    held = np.maximum(np.asarray(pieces)[piece], block)  # short ones padded
+    assert (piece_at >= 0).all() and (piece_at + block <= held).all()
+    starts = at + new_at
+    np.testing.assert_array_equal(starts, np.cumsum([0, *new[:-1]]))
+    assert starts[-1] + new[-1] == rows
+    firsts = np.cumsum([0, *pieces[:-1]])
+    # a block's new rows are its piece's rows, read at the same offset
+    long = np.asarray(pieces)[piece] >= block
+    np.testing.assert_array_equal((starts - firsts[piece])[long],
+                                  (piece_at + new_at)[long])
+    assert (piece_at[~long] == 0).all()
 
 
 @pytest.mark.parametrize("bad,match", [
@@ -543,6 +604,8 @@ def test_fold_kernel_equals_its_reference(shape):
     (dict(width=100), "whole lanes"),
     (dict(dtype=jnp.bfloat16), "float32"),
     (dict(sections=4), "fold_row_sections_ref"),   # more rows than it has
+    (dict(pieces=[0, 12]), "8-row tiles"),         # a piece of half tiles
+    (dict(pieces=[0, 8], short=8), "fold_row_sections_ref"),  # rows missing
 ])
 def test_fold_kernel_refuses_what_it_cannot_tile(bad, match):
     from harmony_tpu.ops.sections import fold_row_sections
@@ -550,8 +613,12 @@ def test_fold_kernel_refuses_what_it_cannot_tile(bad, match):
     rows, width = bad.get("rows", 16), bad.get("width", 128)
     dtype = bad.get("dtype", jnp.float32)
     table = jnp.zeros((3 * rows + 8, width), dtype)
+    side = jnp.zeros((rows - bad.get("short", 0), width), dtype)
+    if "pieces" in bad:
+        side = [(first, side[first:end]) for first, end in zip(
+            bad["pieces"], [*bad["pieces"][1:], rows])]
     with pytest.raises(ValueError, match=match):
-        fold_row_sections(table, jnp.zeros((rows, width), dtype),
+        fold_row_sections(table, side,
                           jnp.zeros((1, width), dtype), _plain_rule,
                           rows=rows, sections=bad.get("sections", 3),
                           interpret=True)
@@ -597,17 +664,24 @@ def test_fold_lowering_is_chosen_from_what_is_traced(as_tpu, devices):
         assert spec.fold_lowering(rows, 3) == "xla"
 
 
-@pytest.mark.parametrize("optimizer", ["sgd", "momentum", "adam"])
-def test_shipped_step_on_the_kernel_lowering(optimizer, as_tpu, devices):
+@pytest.mark.parametrize("optimizer,vocab", [
+    ("sgd", 64), ("momentum", 64), ("adam", 64),
+    ("adam", 4100)])  # the embedding a piece of its own, the rest joined
+def test_shipped_step_on_the_kernel_lowering(optimizer, vocab, as_tpu,
+                                             devices):
     """The step as a one-chip TPU mesh lowers it — the optimizer inside
-    ``harmony_fold_row_sections``, interpreted — against the step as the
-    CPU mesh lowers it: every loss equal, the table equal to the CPU's
-    contraction of a product into a sum (1 ulp of an element; on the chip
-    kernel and XLA read equal to the last bit, PERF.md PR 30)."""
+    ``harmony_fold_row_sections``, interpreted, the gradient handed to it
+    in ``LeafRows``' pieces — against the step as the CPU mesh lowers it:
+    every loss equal, the table equal to the CPU's contraction of a
+    product into a sum (1 ulp of an element; on the chip kernel and XLA
+    read equal to the last bit, PERF.md PR 30)."""
     from harmony_tpu.utils import platform
 
     mesh = build_mesh(devices[:1], data=1)
-    tr = _lm(optimizer, row_width=128)
+    tr = TransformerTrainer(dataclasses.replace(CFG, vocab_size=vocab),
+                            row_width=128, step_size=3e-3,
+                            optimizer=optimizer)
+    assert tr.leaf_rows.record()["fold_pieces"] == (1 if vocab == 64 else 2)
     spec = TableSpec(tr.model_table_config())
     new, new_losses = _run_step(pull_all_step(spec, tr, mesh), tr, spec,
                                 mesh, 4)
@@ -646,6 +720,10 @@ def test_worker_records_how_its_step_folds(as_tpu, devices):
     layout = ledger().snapshot()["j-fold"]["table_layout"]
     assert layout["update_lowering"] == "row_ranges"
     assert layout["fold_lowering"] == "pallas_sections"
+    from harmony_tpu.metrics.registry import get_registry
+
+    assert 'harmony_table_fold_direct_row_share{job="j-fold"' in (
+        get_registry().expose())
 
 
 # -- (g) a leaf is a row range: models/pytree_trainer.py LeafRows -------------
@@ -691,6 +769,15 @@ _LAYOUTS = {
     "forms": (lambda: LeavesTrainer(
         {"s": (), "stack": (3, 16, 128), "odd": (9, 129), "t": (5, 7, 48),
          "none": (0, 4)}, row_width=128, optimizer="momentum"), 128),
+    # the fold's pieces (in flatten order): a5 and b5, leaves of PIECE_ROWS
+    # rows or more, alone — a5 reads [5004, 96] = 3,753 rows of its 3,760,
+    # so its last tile goes with the small leaves after it —, the runs
+    # [a5's tile, a7, a8], [d0, d1] and [e0] each one piece, c5 alone
+    "pieces": (lambda: LeavesTrainer(
+        {"a5": (5003, 96), "a7": (24,), "a8": (40, 128), "b5": (8, 136, 128),
+         "c5": (1024, 128), "d0": (96,), "d1": (3, 128), "d2": (0, 4),
+         "e0": (2048, 128), "f0": (7,)}, row_width=128, optimizer="adam"),
+        128),
 }
 
 
@@ -723,7 +810,90 @@ def test_every_leaf_owns_whole_tiles(name):
     assert record["pad_rows"] == at - sum(
         -(-int(np.prod(x.shape)) // width) for x in shapes)
     assert record["leaf_copies"] + record["leaf_bitcasts"] == len(shapes)
-    assert record["leaf_bitcasts"] == {"forms": 1, "embedding": 1}.get(name, 0)
+    assert record["leaf_bitcasts"] == {"forms": 1, "embedding": 1,
+                                       "pieces": 4}.get(name, 0)
+
+
+@pytest.mark.parametrize("name", list(_LAYOUTS))
+def test_pieces_tile_a_section_in_order(name):
+    """``to_pieces``: whole tiles from ``piece_firsts[j]`` to the next
+    piece's first, in order and with no gap, a large leaf alone and the
+    small ones between two such joined; joined again they are ``to_rows``,
+    which is the host's ``fill_rows``; and the record counts them."""
+    from harmony_tpu.ops.sections import PIECE_ROWS
+
+    tr = _LAYOUTS[name][0]()
+    lr = tr.leaf_rows
+    params = tr.model.init(jax.random.PRNGKey(3))
+    pieces = jax.jit(lr.to_pieces)(params)
+    ends = [*lr.piece_firsts[1:], lr.rows]
+    assert lr.piece_firsts[0] == 0 and len(pieces) == len(lr.pieces)
+    host = np.zeros((lr.rows, tr.row_width), np.float32)
+    lr.fill_rows(host, jax.tree.leaves(params))
+    direct = 0
+    for p, run, first, end in zip(pieces, lr.pieces, lr.piece_firsts, ends):
+        assert first % 8 == 0 and end > first and p.dtype == jnp.float32
+        assert p.shape[0] >= end - first and p.shape[1] == tr.row_width
+        np.testing.assert_array_equal(p[:end - first], host[first:end])
+        assert sum(n for _, _, n in run) == end - first
+        large = [lr.leaves[i].rows >= PIECE_ROWS and not at
+                 for i, at, _ in run]
+        assert large in ([True], [False] * len(run))
+        direct += (end - first) * (len(run) == 1)
+    np.testing.assert_array_equal(lr.join(pieces), host)
+    np.testing.assert_array_equal(jax.jit(lr.to_rows)(params), host)
+    record = lr.record()
+    assert record["fold_pieces"] == len(pieces)
+    assert record["direct_rows"] == direct <= lr.rows
+    if name == "pieces":
+        assert [[i for i, _, _ in run] for run in lr.pieces] == [
+            [0], [0, 1, 2], [3], [4], [5, 6], [8], [9]]
+        assert lr.pieces[0] == [(0, 0, 3752)] and pieces[0].shape[0] == 3753
+        assert lr.pieces[1][0] == (0, 3752, 8)
+
+
+def _benchmark_lm_jobs():
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        files = [c["file"] for c in json.load(f)["configs"]]
+    jobs = {}
+    for file in files:
+        with open(os.path.join(root, file)) as f:
+            job = json.load(f)["job"]
+        if job["trainer"].endswith(":TransformerTrainer"):
+            jobs[os.path.basename(file)[:-len(".json")]] = job["app_params"]
+    return jobs
+
+
+@pytest.mark.parametrize("config", sorted(_benchmark_lm_jobs()))
+def test_benchmark_templates_come_back_in_pieces(config):
+    """Each LM configuration of the benchmark at its real shapes (shapes
+    only): ``to_rows`` is the concatenate of ``to_pieces``, the fold gets
+    tens of operands, not hundreds, and over nine tenths of the gradient's
+    rows are read from a leaf's own buffer."""
+    from harmony_tpu.ops.sections import _BLOCK_ROWS
+
+    tr = TransformerTrainer(**_benchmark_lm_jobs()[config])
+    lr = tr.leaf_rows
+    template = jax.eval_shape(lambda: tr.model.init(jax.random.PRNGKey(0)))
+    pieces = jax.eval_shape(lr.to_pieces, template)
+    ends = [*lr.piece_firsts[1:], lr.rows]
+    assert lr.piece_firsts[0] == 0
+    for p, first, end in zip(pieces, lr.piece_firsts, ends):
+        assert first % 8 == 0 and 0 < end - first <= p.shape[0] < end - first + 8
+        assert p.shape[1] == tr.row_width and p.dtype == jnp.float32
+    rows = jax.eval_shape(lr.to_rows, template)
+    assert rows.shape == jax.eval_shape(lr.join, pieces).shape == (
+        lr.rows, tr.row_width)
+    record = lr.record()
+    assert 1 <= record["fold_pieces"] == len(pieces) <= 128, record
+    assert record["direct_rows"] / record["rows"] > 0.9, record
+    # a piece short of a block costs the fold a block read for its few rows
+    assert sum(end - first < _BLOCK_ROWS for first, end in zip(
+        lr.piece_firsts, ends)) <= len(pieces) // 2
 
 
 @pytest.mark.parametrize("shape,width,read,lead", [
@@ -846,6 +1016,45 @@ def test_lowered_step_holds_no_flat_parameter_vector(optimizer, dtype,
     if tr.num_state_slots:  # (sgd's table IS the section)
         assert f"tensor<{tr.section_rows}x{tr.row_width}xf32>" not in (
             text.split("stablehlo.optimization_barrier")[1])
+
+
+def test_lowered_step_holds_no_section_of_gradient_rows(monkeypatch, devices):
+    """The structural guard of the gradient's way back: the step as a
+    one-chip TPU mesh lowers it (cross-lowered here, the fold a Mosaic
+    custom call) builds no ``[section_rows, row_width]`` array — no
+    concatenate, dynamic-update-slice or pad of that shape — between
+    ``value_and_grad`` and the ONE ``harmony_fold_row_sections``, which
+    takes the pieces as operands of its own."""
+    import re
+
+    from harmony_tpu.utils import platform
+
+    monkeypatch.setattr(platform, "mesh_is_tpu", lambda mesh: True)
+    tr = TransformerTrainer(
+        dataclasses.replace(CFG, vocab_size=4100, dtype=jnp.bfloat16),
+        row_width=128, step_size=3e-3, optimizer="adam")
+    assert 1 < tr.leaf_rows.record()["fold_pieces"] < len(tr.leaf_rows.leaves)
+    assert tr.leaf_rows.record()["direct_rows"]
+    mesh = build_mesh(devices[:1], data=1)
+    spec = TableSpec(tr.model_table_config())
+    arr = jax.ShapeDtypeStruct(spec.storage_shape, jnp.float32)
+    batch = (jax.ShapeDtypeStruct((4, 33), jnp.int32),)
+    hyper = {k: jax.ShapeDtypeStruct((), jnp.float32)
+             for k in tr.hyperparams()}
+    text = jax.jit(traced_on(mesh, pull_all_step(spec, tr, mesh))).trace(
+        arr, batch, hyper).lower(lowering_platforms=("tpu",)).as_text()
+    folds = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and "stablehlo.custom_call" in line]
+    assert len(folds) == 1 and "harmony_fold_row_sections" in folds[0]
+    # its operands: the walk, the rule's scalars, a piece each, the table
+    operands = re.search(r"\((tensor<.*?)\) -> ", folds[0]).group(1)
+    assert operands.count("tensor<") == 3 + tr.leaf_rows.record()[
+        "fold_pieces"], operands
+    section = f"tensor<{tr.section_rows}x{tr.row_width}xf32>"
+    made = [line.strip()[:160] for line in text.splitlines()
+            if f"-> {section}" in line or line.rstrip().endswith(
+                f": {section}")]
+    assert not made, made
 
 
 # -- (h) a chain written before the leaves were row ranges -------------------
@@ -1028,7 +1237,8 @@ def test_table_layout_record():
                         'table="lm"']) == tr.capacity // 8, blocks
     # how the step applies its update: a key of the same row, and a gauge
     table_layout.note_update("layout-lm", "lm", "row_ranges")
-    table_layout.note_fold("layout-lm", "lm", "pallas_sections")
+    record = _LAYOUTS["pieces"][0]().leaf_rows.record()
+    table_layout.note_fold("layout-lm", "lm", "pallas_sections", record)
     table_layout.note_update("layout-nine", "nine", "whole_delta")
     assert ledger().snapshot()["layout-lm"]["table_layout"] == {
         **row, "update_lowering": "row_ranges",
@@ -1037,6 +1247,14 @@ def test_table_layout_record():
             in get_registry().expose())
     assert (ledger().snapshot()["layout-nine"]["table_layout"]
             ["update_lowering"]) == "whole_delta"
+    # how much of the gradient that fold reads where the leaves left it
+    assert 0 < record["direct_rows"] < record["rows"]
+    assert record["fold_pieces"] == 7
+    direct = exposed("harmony_table_fold_direct_row_share")
+    key = 'harmony_table_fold_direct_row_share{job="layout-lm",table="lm"'
+    assert float(direct[key]) == record["direct_rows"] / record["rows"]
+    table_layout.note_fold("layout-lm", "lm", "xla", record)  # concatenated
+    assert float(exposed("harmony_table_fold_direct_row_share")[key]) == 0
     ranges = exposed("harmony_table_update_row_ranges")
     assert ranges['harmony_table_update_row_ranges{job="layout-lm",'
                   'table="lm"'] == "1", ranges
@@ -1081,10 +1299,14 @@ def test_status_carries_the_layout_of_a_submitted_lm_tenant():
     used = sum(-(-int(np.prod(x.shape)) // 128) for x in leaves)
     assert row["leaf_layout"] == {
         "leaves": 9, "leaf_copies": 9, "leaf_bitcasts": 0,
-        "pad_rows": tr.section_rows - used, "rows": tr.section_rows}
+        "pad_rows": tr.section_rows - used, "rows": tr.section_rows,
+        "fold_pieces": 1, "direct_rows": 0}  # all small: one concatenate
     from harmony_tpu.metrics.registry import get_registry
 
     share = [line for line in get_registry().expose().splitlines()
              if line.startswith('harmony_table_leaf_pad_share{job="layout-job"')]
     assert len(share) == 1 and float(share[0].rsplit(" ", 1)[1]) == (
         (tr.section_rows - used) / tr.section_rows)
+    direct = [line for line in get_registry().expose().splitlines() if
+              line.startswith('harmony_table_fold_direct_row_share{job="layout-job"')]
+    assert len(direct) == 1 and float(direct[0].rsplit(" ", 1)[1]) == 0
