@@ -11,14 +11,27 @@ without XLA's ``.<n>`` suffix. A program without the kernels (the parent of
 the PR that added them) has no such event: ``of_this_run`` returns None and
 the readers report nothing.
 
-``traced_steps`` pairs the kernels' events with the rows they computed IN
-THE SAME SPAN: at each drain the program opens the span ``moe.observe``
-(harmony_tpu/metrics/moe.py), whose annotation lists the drained steps' held
-token-slots one by one. The device stands idle while the host drains, so the
-kernel calls between two such events are the later one's steps, in order,
-and the calls before the trace's first are its LAST steps. Calls after the
-trace's last drain (their rows are reported after the trace ended) and a
-step the trace's start cut are left out of both sides of any ratio.
+``traced_steps`` pairs the kernels' events with the rows they computed. The
+DEVICE says which calls are one step's: every executed program is an event of
+the first device's line ``XLA Modules`` (the line ``_step_scopes.py`` reads
+the step modules from, through the program's own reader), and the grouped-
+matmul calls that start inside one such event are that execution's — a step's,
+if they are as many as the configuration's shapes say a step makes
+(``per_step``). The HOST says only the rows, in order: at each drain the
+program hands the drained steps' held token-slots, one by one, to the span
+``moe.observe`` (harmony_tpu/metrics/moe.py), which it opens once the NEXT
+window's steps are enqueued (dolphin/worker.py ``_observe_vector_backlog``) —
+so the span lies somewhere in the next window's first step, and the steps that
+ENDED before it, since the one before, are its rows' steps, in order. (Until PR
+48 the span's time cut the CALLS: with ``table.pull`` at 0.6 ms it came to lie
+among the next step's first calls, 143 / 145 between two spans where 144 were
+due, and the reader refused from PR 42 on.) Steps before the trace's first
+span are its LAST steps. Steps after the trace's last span (their rows are
+reported after the trace ended), and a step the trace's edge cut (fewer calls
+than a step's, or no module event around them), are left out of both sides of
+any ratio. Where a whole execution holds another number of calls than
+``per_step``, or other than a span's number of steps ended between two spans,
+nothing is paired: a guess is worse than nothing.
 
     python perf/layer_metrics/_moe_kernels.py <file.xplane.pb | cell name>
 """
@@ -42,8 +55,10 @@ KERNEL = re.compile(r"^(harmony_(?:gmm|moe)_[a-z_]+?)(?:\.\d+)?$")
 TOKENS = "harmony_moe_expert_tokens_total"
 EXPERTS_HELD = "harmony_moe_experts_held"
 OBSERVE = _host_spans.PREFIX + "moe.observe"
+MODULES_LINE = "XLA Modules"
 
 Call = Tuple[str, float, float]  # kernel, start_ns, end_ns
+Run = Tuple[float, float]  # one executed program: start_ns, end_ns
 
 _cache: Dict[str, Any] = {}
 
@@ -83,6 +98,19 @@ def kernel_seconds(profile) -> Optional[Dict[str, Any]]:
     return {"busy_s": found[0], "kernels": kernels}
 
 
+def module_runs(profile) -> List[Run]:
+    """``[(start_ns, end_ns)]`` of every program the first device executed
+    (the device ``kernel_calls`` reads), by start."""
+    live = [d for d, ops in trace_reduce.device_ops(profile).items() if ops]
+    if not live:
+        return []
+    first = f"/device:TPU:{min(live)}"
+    return sorted((float(e.start_ns), float(e.start_ns) + float(e.duration_ns))
+                  for plane in profile.planes if plane.name == first
+                  for line in plane.lines if line.name == MODULES_LINE
+                  for e in line.events)
+
+
 def drains(profile, jobs: Sequence[str]) -> List[Tuple[float, List[float]]]:
     """``[(start_ns, [held token-slots of each drained step])]`` of the
     ``moe.observe`` spans of ``jobs``, by time."""
@@ -101,19 +129,36 @@ def drains(profile, jobs: Sequence[str]) -> List[Tuple[float, List[float]]]:
     return sorted(out)
 
 
-def pair(calls: Sequence[Call], drained: Sequence[Tuple[float, List[float]]],
+def pair(calls: Sequence[Call], runs: Sequence[Run],
+         drained: Sequence[Tuple[float, List[float]]],
          per_step: int) -> Optional[List[Tuple[str, float, float]]]:
     """``[(kernel, seconds, held token-slots of the call's STEP)]`` for the
-    calls of every whole step whose drain lies in the trace (module
-    docstring). None where the calls between two drains are not the later
-    one's steps (a program that dispatches across its drain, or another
-    count of calls a step): the pairing would be a guess."""
+    calls of every whole step whose rows a span in the trace reports (module
+    docstring): ``runs`` cut the calls into steps, ``drained`` gives the
+    rows in order. None where an execution's calls are not a step's, or the
+    steps that ended between two spans are not the later one's: the pairing
+    would be a guess."""
+    runs = sorted(runs)
+    inside: List[List[Call]] = [[] for _ in runs]
+    at = 0
+    for c in sorted(calls, key=lambda c: c[1]):
+        while at < len(runs) and runs[at][1] <= c[1]:
+            at += 1
+        if at < len(runs) and runs[at][0] <= c[1]:
+            inside[at].append(c)
+    # the executions that called the kernels at all; only the trace's edge
+    # may have cut one short (its event then covers what the trace saw of it)
+    steps = [(r[1], cs) for r, cs in zip(runs, inside) if cs]
+    for n, (_, cs) in enumerate(steps):
+        if len(cs) != per_step and (
+                len(cs) > per_step or 0 < n < len(steps) - 1):
+            return None
+    steps = [st for st in steps if len(st[1]) == per_step]
     # the worker replays a drained window epoch by epoch: its spans come in
-    # a burst with no kernel call between them, and count as ONE drain
+    # a burst with no step ending between them, and count as ONE drain
     bursts: List[List[Any]] = []  # [first start, last start, held slots]
-    for t, held in drained:
-        if bursts and not any(bursts[-1][1] < c[1] and c[2] <= t
-                              for c in calls):
+    for t, held in sorted(drained):
+        if bursts and not any(bursts[-1][1] < end <= t for end, _ in steps):
             bursts[-1][1] = t
             bursts[-1][2] = bursts[-1][2] + list(held)
         else:
@@ -121,14 +166,12 @@ def pair(calls: Sequence[Call], drained: Sequence[Tuple[float, List[float]]],
     out: List[Tuple[str, float, float]] = []
     after = float("-inf")
     for n, (first, last, held) in enumerate(bursts):
-        span = [c for c in calls if c[1] > after and c[2] <= first]
-        if n and len(span) != len(held) * per_step:
+        ended = [cs for end, cs in steps if after < end <= first]
+        if n and len(ended) != len(held):
             return None
-        k = min(len(held), len(span) // per_step)
-        span = span[len(span) - k * per_step:]
-        for i, slots in enumerate(held[len(held) - k:]):
-            for name, s, e in span[i * per_step:(i + 1) * per_step]:
-                out.append((name, (e - s) * 1e-9, slots))
+        k = min(len(held), len(ended))
+        for cs, slots in zip(ended[len(ended) - k:], held[len(held) - k:]):
+            out.extend((name, (e - s) * 1e-9, slots) for name, s, e in cs)
         after = last
     return out
 
@@ -165,12 +208,12 @@ def of_this_run() -> Optional[Dict[str, Any]]:
     return None if profile is None else kernel_seconds(profile)
 
 
-def traced_steps(obs) -> Optional[Dict[str, Any]]:
-    """``{cell, calls: pair(...)}`` for the measured jobs (the keys of
-    ``obs["phases"]``) in the trace this process's cell just wrote: the
-    configuration comes from the jobs' own ids, the calls a step from its
-    shapes through the benchmark's work function. None without the kernels,
-    the spans or a whole step."""
+def traced_steps(obs, work: str = "olmoe") -> Optional[Dict[str, Any]]:
+    """``{cell, work, layers, calls: pair(...)}`` for the measured jobs (the
+    keys of ``obs["phases"]``) in the trace this process's cell just wrote:
+    the configuration comes from the jobs' own ids, the calls a step from its
+    shapes through the benchmark's work file ``perf/work/<work>.py``. None
+    without the kernels, the spans or a whole step."""
     jobs = list(obs.get("phases") or {})
     profile = _load()
     if not jobs or profile is None:
@@ -181,16 +224,67 @@ def traced_steps(obs) -> Optional[Dict[str, Any]]:
         found, cell = kernel_calls(profile), cell_of(jobs)
         if found is None or cell is None:
             return None
-        work = load_by_path("work", "olmoe")
-        app = cell.job["app_params"]
-        per_step = sum(work.CALLS_PER_LAYER.values()) * work.moe_layers(app)
-        gmm = [c for c in found[1] if c[0] in work.CALLS_PER_LAYER]
-        calls = pair(gmm, drains(profile, jobs), per_step)
+        module = load_by_path("work", work)
+        layers = module.moe_layers(cell.job["app_params"])
+        per_step = sum(module.CALLS_PER_LAYER.values()) * layers
+        gmm = [c for c in found[1] if c[0] in module.CALLS_PER_LAYER]
+        calls = pair(gmm, module_runs(profile), drains(profile, jobs),
+                     per_step)
     except Exception:
         return None
     if not calls:
         return None
-    return {"cell": cell, "work": work, "calls": calls}
+    return {"cell": cell, "work": module, "layers": layers, "calls": calls}
+
+
+def roofline_share(obs, work: str, line: str) -> Optional[float]:
+    """FLOPs the grouped matmuls of the traced steps NEEDED over what the
+    chip could have done in the device time they took, percent:
+
+        sum over calls (held rows of the call's step x 2 d f)  /  (seconds x bf16 peak)
+
+    with both sides from the same steps of the same trace
+    (``traced_steps``): a call counts its step's mean expert layer, a whole
+    step counts exactly. Each kernel's own share goes to the printed
+    ``line``. None with nothing to read, never 0."""
+    if not obs.get("trace"):
+        return None
+    found = traced_steps(obs, work)
+    if not found:
+        return None
+    try:
+        import jax
+
+        with open(os.path.join(PERF, "peaks.json")) as f:
+            peak = json.load(f)[str(jax.devices()[0].device_kind)]["bf16_flops"]
+    except Exception:
+        return None
+    cell, module, layers = found["cell"], found["work"], found["layers"]
+    app = cell.job["app_params"]
+    by_kernel: Dict[str, Dict[str, float]] = {}
+    for name, seconds, step_slots in found["calls"]:
+        row = by_kernel.setdefault(name, {"calls": 0, "seconds": 0.0,
+                                          "flops": 0.0, "rows": 0.0})
+        row["calls"] += 1
+        row["seconds"] += seconds
+        row["rows"] += step_slots / layers
+        row["flops"] += module.gmm_flops_per_call(app, step_slots / layers)
+    total = {k: sum(r[k] for r in by_kernel.values())
+             for k in ("calls", "seconds", "flops", "rows")}
+    if total["seconds"] <= 0:
+        return None
+    rows_per_call = total["rows"] / total["calls"]
+    print(json.dumps({
+        "line": line, "bound": "bf16 MXU peak",
+        "expert_layers": layers, "calls_paired": total["calls"],
+        "held_rows_per_call": rows_per_call,
+        "held_slot_share": rows_per_call / module.slots_per_step(app, cell.batch),
+        "kernels": {name: {"calls": r["calls"],
+                           "ms_per_call": 1e3 * r["seconds"] / r["calls"],
+                           "roofline_share": 100.0 * r["flops"]
+                           / (r["seconds"] * peak)}
+                    for name, r in sorted(by_kernel.items())}}), flush=True)
+    return 100.0 * total["flops"] / (total["seconds"] * peak)
 
 
 def load_max_over_mean(obs) -> Optional[float]:

@@ -3,9 +3,12 @@ arithmetic amounts to at the rate this run measured: 100 x the
 ``share_of_peak`` of the line ``model_flops_utilisation`` that ``perf/run.py``
 prints in every run of a cell whose configuration names a ``job.flops_fn``
 (``work_model_shares`` there: the tenants' fitted tokens/s, summed, x the
-FLOPs a token of ``perf/work_models.py`` ``lm_train_flops_per_token`` /
-(``perf/peaks.json`` ``bf16_flops`` x the cell's chips)). One count, one
-quotient: the harness hands it over in ``obs``.
+FLOPs a token of the corpus by the count the configuration names —
+``perf/work_models.py`` ``lm_train_flops_per_token``, or its own
+``"<sibling>:<function>"`` in ``perf/work/<sibling>.py``, found by
+``work_models.resolve`` — / (``perf/peaks.json`` ``bf16_flops`` x the cell's
+chips)). One count a configuration, one quotient: the harness hands it over
+in ``obs``.
 
 A fixed multiple of ``lm_tokens_per_s``: it finds nothing the rate does not.
 It is here to BOUND claims: a kernel's roofline may fall silent (the kernel

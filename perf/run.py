@@ -8,8 +8,12 @@ A cell is an entry of ``workloads`` in ``BENCHMARK.json``: a configuration
 (``perf/traffic/<traffic>.json``). This file has no branch per cell, model
 or metric: tenants, trainer, ``app_params``, data, batch, epoch size and
 the work model all come from those two files; a per-layer metric is a
-reader under ``perf/layer_metrics/`` found by its name. PERF.md sections
-2-4 say what is measured and why.
+reader under ``perf/layer_metrics/`` found by its name. The work model is
+the function the configuration names under ``job.flops_fn`` / ``job.bytes_fn``:
+a bare name lives in ``perf/work_models.py``, ``"<sibling>:<function>"`` in
+``perf/work/<sibling>.py`` beside the configuration's other work functions
+(``work_models.resolve``; that file's docstring says what such a count owes).
+PERF.md sections 2-4 say what is measured and why.
 
 One process: it starts the jobserver here (the one process that holds the
 chips) and drives it only as a user can — ``JobConfig``s sent with the
@@ -381,21 +385,33 @@ def reference_check(cell: Cell, seed: int, warm: Dict[str, Any]
 
 def work_model_shares(cell: Cell, peaks: Dict[str, float], aggregate: float
                       ) -> Dict[str, float]:
-    """What the configuration's own arithmetic (perf/work_models.py, by the
-    names under ``job.flops_fn`` / ``job.bytes_fn``) amounts to at the run's
-    ``aggregate`` rate, as shares of the chips' peaks: a line each, traced
-    run or not, and the shares by the lines' names."""
+    """What the configuration's own arithmetic (the functions it names under
+    ``job.flops_fn`` / ``job.bytes_fn``, found by ``perf/work_models.py``
+    ``resolve``: that file's, or ``perf/work/<sibling>.py``'s) amounts to at
+    the run's ``aggregate`` rate, as shares of the chips' peaks: a line each,
+    traced run or not, and the shares by the lines' names. A name that does
+    not resolve, or a function that counts nothing, prints
+    ``work_model_failed`` and leaves its share out: no other count stands in."""
     from perf import work_models
 
-    app, shares = cell.job["app_params"], {}
-    if cell.job.get("flops_fn"):
-        per_unit = getattr(work_models, cell.job["flops_fn"])(app)
+    def counted(key: str) -> Optional[float]:
+        if not cell.job.get(key):
+            return None
+        try:
+            return work_models.count(cell.job, key)
+        except Exception as e:
+            say("work_model_failed", key=key, name=cell.job[key],
+                error=f"{type(e).__name__}: {e}"[:300])
+            return None
+
+    shares = {}
+    per_unit, per_ex = counted("flops_fn"), counted("bytes_fn")
+    if per_unit is not None:
         shares["model_flops_utilisation"] = (
             aggregate * per_unit / (peaks["bf16_flops"] * cell.chips))
         say("model_flops_utilisation", flops_per_unit=per_unit,
             share_of_peak=shares["model_flops_utilisation"])
-    if cell.job.get("bytes_fn"):
-        per_ex = getattr(work_models, cell.job["bytes_fn"])(app)
+    if per_ex is not None:
         shares["table_bandwidth"] = (
             aggregate / float(cell.job["units_per_example"]) * per_ex
             / (peaks["hbm_bytes_per_s"] * cell.chips))

@@ -6,6 +6,8 @@ import json
 import os
 import sys
 
+import pytest
+
 HERE = os.path.dirname(os.path.abspath(__file__))
 PERF = os.path.dirname(HERE)
 ROOT = os.path.dirname(PERF)
@@ -87,28 +89,65 @@ def test_kernel_readers_fold_events_by_kernel_name():
         assert reader.read({"trace": None}) is None
 
 
+def _steps(starts):
+    """Executions of the step program at ``starts`` (8 long) and their two
+    calls (1 long, at start + 1 and start + 4)."""
+    runs = [(float(t), float(t) + 8.0) for t in starts]
+    calls = [("harmony_gmm_fwd", t + at, t + at + 1.0)
+             for t, _ in runs for at in (1.0, 4.0)]
+    return runs, calls
+
+
 def test_roofline_pairs_each_traced_call_with_its_own_steps_rows():
-    """Two calls a step; drains at t = 100 (steps of 10, 20, 30 held slots)
-    and t = 200 (40, 50). The trace starts inside the first window: one
-    call of a cut step, then the window's last two steps; the second window
-    whole; calls after the last drain belong to no reported step."""
+    """Two calls a step, the device's module events cut them. Window 1 (held
+    slots 10, 20, 30 a step) began before the trace: its first step is a lone
+    call with no module event around it, then the steps at 10 and 20. Window
+    2 (40, 50): the steps at 30 and 40. Window 3's (50, 60) rows are reported
+    after the trace ended. Each window's span opens inside the NEXT window's
+    first step, after that step's first call (at 33 and 52: what the calls'
+    own times could not cut since PR 42)."""
     mk = load_by_path("layer_metrics", "_moe_kernels")
-    call = lambda t, name="harmony_gmm_fwd": (name, float(t), float(t) + 1.0)
-    calls = ([call(5)] + [call(t) for t in (10, 20, 30, 40)]
-             + [call(t, "harmony_gmm_dw") for t in (110, 120, 130, 140)]
-             + [call(210), call(220)])
-    got = mk.pair(calls, [(100.0, [10.0, 20.0, 30.0]), (200.0, [40.0, 50.0])], 2)
+    runs, calls = _steps((10, 20, 30, 40, 50, 60))
+    calls = [("harmony_gmm_fwd", 5.0, 6.0)] + calls
+    idle = [(28.5, 29.5), (48.5, 49.5)]  # the drain's own programs: no call
+    spans = [(33.0, [10.0, 20.0, 30.0]), (52.0, [40.0, 50.0])]
+    got = mk.pair(calls, runs + idle, spans, 2)
+    # the steps at 10, 20, 30 and 40, two calls each, in order
+    assert [slots for _, _, slots in got] == [20.0, 20.0, 30.0, 30.0,
+                                              40.0, 40.0, 50.0, 50.0]
+    assert all(name == "harmony_gmm_fwd" and abs(sec - 1e-9) < 1e-15
+               for name, sec, _ in got)
     # a window replayed epoch by epoch reports its steps in a burst of spans
-    assert got == mk.pair(calls, [(100.0, [10.0]), (101.0, [20.0, 30.0]),
-                                  (200.0, [40.0]), (203.0, [50.0])], 2)
-    assert [(n, slots) for n, _, slots in got] == (
-        [("harmony_gmm_fwd", 20.0)] * 2 + [("harmony_gmm_fwd", 30.0)] * 2
-        + [("harmony_gmm_dw", 40.0)] * 2 + [("harmony_gmm_dw", 50.0)] * 2)
-    assert all(abs(sec - 1e-9) < 1e-15 for _, sec, _ in got)
-    # a window whose calls are not its steps' calls: no pairing, no number
-    assert mk.pair(calls[:-2] + [call(150)], [(100.0, [30.0]),
-                                              (200.0, [40.0, 50.0])], 2) is None
-    assert mk.pair(calls, [], 2) == []
+    assert got == mk.pair(calls, runs, [(33.0, [10.0]), (33.5, [20.0, 30.0]),
+                                        (52.0, [40.0]), (53.0, [50.0])], 2)
+    # the trace's end cut the last execution: left out, not refused
+    assert got == mk.pair(calls[:-1], runs, spans, 2)
+    # an execution with another count of calls than a step's; other than the
+    # span's number of steps between two spans: no pairing, no number
+    extra = [("harmony_gmm_dw", 36.0, 37.0)]
+    assert mk.pair(calls + extra, runs, spans, 2) is None
+    assert mk.pair(calls, runs, [spans[0], (52.0, [40.0, 45.0, 50.0])], 2) is None
+    assert mk.pair(calls, runs, spans, 3) is None
+    # no span, or no module event to cut by: nothing to pair
+    assert mk.pair(calls, runs, [], 2) == []
+    assert mk.pair(calls, [], spans, 2) == []
+
+
+@pytest.mark.parametrize("fixture", ["fixture_1chip", "fixture_4chip",
+                                     "fixture_scopes", "fixture_spans"])
+def test_the_devices_module_events_hold_every_operation(fixture):
+    """What ``pair`` cuts by, on traces recorded on the chip: the first
+    device's executed programs, in order and apart, and every ``XLA Ops``
+    event of that device starts inside one of them."""
+    mk = load_by_path("layer_metrics", "_moe_kernels")
+    profile = mk.trace_reduce.load(os.path.join(PERF, "tests",
+                                                fixture + ".xplane.pb"))
+    runs = mk.module_runs(profile)
+    assert len(runs) >= 5 and runs == sorted(runs)
+    assert all(a[1] <= b[0] for a, b in zip(runs, runs[1:]))
+    per_dev = mk.trace_reduce.device_ops(profile)
+    ops = per_dev[min(d for d, found in per_dev.items() if found)]
+    assert ops and all(any(s <= at < e for s, e in runs) for _, at, _ in ops)
 
 
 def test_the_configuration_comes_from_the_measured_jobs_id():

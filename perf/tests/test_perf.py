@@ -84,9 +84,14 @@ def test_cell_resolves_to_files(cell):
     assert callable(resolve_symbol(job["trainer"]))
     from perf import work_models
 
+    # the name resolves (perf/work_models.py, or "<sibling>:<function>" in
+    # perf/work/<sibling>.py) and counts something; a FLOPs count has its twin
     for key in ("flops_fn", "bytes_fn"):
         if job.get(key):
-            assert getattr(work_models, job[key])(job["app_params"]) > 0
+            assert work_models.count(job, key) > 0
+    if job.get("flops_fn"):
+        assert sum(work_models.split(job).values()) \
+            == work_models.count(job, "flops_fn")
     assert traffic["tenants"] and 0 < traffic["batch_share"] <= 1
     # a mix overrides only fields the configuration's job has, each with a why
     assert set(traffic.get("job", {})) <= set(job)

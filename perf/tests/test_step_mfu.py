@@ -1,12 +1,16 @@
 """Checks of ``step_mfu_share`` (PR 41): the model FLOPs a token of every LM
-configuration (``perf/work_models.py`` ``lm_train_flops_per_token``) against a
-hand count from the published shapes, its rules, the harness's quotient
-(``perf/run.py`` ``work_model_shares``) and the reader on a synthetic ``obs``
-with no trace, and the entry of ``BENCHMARK.json``. CPU only, nothing here is
-a measurement. Tier-1 collects these cases through ``perf/tests/
+configuration — the count its file names under ``job.flops_fn``, found by
+``perf/work_models.py`` ``resolve``: that file's ``lm_train_flops_per_token``
+or a sibling's own (PR 48) — against a hand count from the published shapes,
+the one function's rules, the harness's quotient (``perf/run.py``
+``work_model_shares``) and the reader on a synthetic ``obs`` with no trace,
+the entry of ``BENCHMARK.json``, and the door a configuration's own count
+comes in by, shown open on a sibling written for the test. CPU only, nothing
+here is a measurement. Tier-1 collects these cases through ``perf/tests/
 test_step_scopes.py`` (its last lines say why)."""
 from __future__ import annotations
 
+import functools
 import json
 import os
 import sys
@@ -30,9 +34,13 @@ KEYED_CELLS = next(m for m in BENCH["end_to_end"]
                    if m["name"] == "keyed_samples_per_s")["workloads"]
 
 
-def app_of(config: str):
+def job_of(config: str):
     with open(os.path.join(PERF, "configs", config + ".json")) as f:
-        return json.load(f)["job"]["app_params"]
+        return json.load(f)["job"]
+
+
+def app_of(config: str):
+    return job_of(config)["app_params"]
 
 
 def tri(s, heads, width):
@@ -106,7 +114,7 @@ HAND = {"gpt2-124m": hand_gpt2, "olmoe-1b-7b": hand_olmoe,
 
 @pytest.mark.parametrize("config", sorted(HAND))
 def test_flops_a_token_equal_the_hand_count(config):
-    got = WORK.lm_train_flops_per_token(app_of(config))
+    got = WORK.count(job_of(config), "flops_fn")
     assert got == pytest.approx(HAND[config](), rel=1e-12)
 
 
@@ -127,20 +135,33 @@ def lm_configs():
 
 
 def test_every_lm_configuration_names_the_count_and_is_counted():
+    """Held to a COUNT, not to one function's name: whatever ``flops_fn``
+    names resolves, its twin splits it over ``PARTS``, and a hand count from
+    the published shapes (``HAND``; a later configuration's joins from its own
+    ``perf/tests/test_<config>.py``, conftest.py) equals it."""
     jobs = lm_configs()
-    assert set(HAND) <= set(jobs)
+    assert set(HAND) >= set(jobs), "an LM configuration without a hand count"
     assert {w["config"] for w in BENCH["workloads"]
             if w["name"] in LM_CELLS} == set(jobs)
     for name, job in jobs.items():
-        assert job["flops_fn"] == "lm_train_flops_per_token", name
-        parts = WORK.lm_train_flops_split(job["app_params"])
+        counted = WORK.count(job, "flops_fn")  # the name resolves
+        parts = WORK.split(job)
         assert tuple(parts) == WORK.PARTS and min(parts.values()) >= 0
         assert parts["dense"] > 0 and parts["readout"] > 0, name
-        assert WORK.lm_train_flops_per_token(job["app_params"]) \
-            == float(sum(parts.values())), name
+        assert counted == float(sum(parts.values())), name
+        assert counted == pytest.approx(HAND[name](), rel=1e-12), name
 
 
-@pytest.mark.parametrize("config", sorted(HAND))
+def counted_here(configs):
+    """Those of ``configs`` whose count is ``lm_train_flops_per_token``: the
+    three checks below ask ``work_models.layer_kinds`` and that function's
+    keys. A configuration with its own count (``"<sibling>:<function>"``)
+    brings their like in its own ``perf/tests/test_<config>.py``."""
+    return [c for c in configs
+            if job_of(c)["flops_fn"] == "lm_train_flops_per_token"]
+
+
+@pytest.mark.parametrize("config", counted_here(sorted(HAND)))
 def test_the_layers_are_the_programs(config):
     """The kinds this file derives from the keys against the program's own
     ``TransformerConfig`` (a test may ask it; the metric does not)."""
@@ -176,16 +197,16 @@ def test_a_model_it_cannot_count_raises(change):
         WORK.lm_train_flops_per_token({**app_of("olmoe-1b-7b"), "moe_top_k": 0})
 
 
-@pytest.mark.parametrize("config", ["kimi-linear-48b-a3b",
-                                    "smallthinker-21b-a3b", "gpt2-124m"])
+@pytest.mark.parametrize("config", counted_here([
+    "kimi-linear-48b-a3b", "smallthinker-21b-a3b", "gpt2-124m"]))
 def test_remat_counts_nothing(config):
     app = app_of(config)
     assert WORK.lm_train_flops_per_token({**app, "remat": True}) \
         == WORK.lm_train_flops_per_token({**app, "remat": False})
 
 
-@pytest.mark.parametrize("config", ["olmoe-1b-7b", "moonlight-16b-a3b",
-                                    "nemotron-3-super-120b-a12b"])
+@pytest.mark.parametrize("config", counted_here([
+    "olmoe-1b-7b", "moonlight-16b-a3b", "nemotron-3-super-120b-a12b"]))
 def test_doubling_the_held_experts_adds_the_routed_term(config):
     app = app_of(config)
     one, two = WORK.lm_train_flops_split(app), WORK.lm_train_flops_split(
@@ -228,8 +249,7 @@ def test_the_share_needs_no_trace_no_span_and_no_counter(cell, monkeypatch,
         tokens * HAND[config]() / (PEAK * 1), rel=1e-12)
     line = json.loads(capsys.readouterr().out.splitlines()[-1])
     assert line == {"line": "model_flops_utilisation",
-                    "flops_per_unit": WORK.lm_train_flops_per_token(
-                        c.job["app_params"]),
+                    "flops_per_unit": WORK.count(c.job, "flops_fn"),
                     "share_of_peak": shares["model_flops_utilisation"]}
     obs = {"fits": [{"rate": r} for r in rates],
            "model_flops_share": shares["model_flops_utilisation"]}
@@ -256,6 +276,138 @@ def test_the_reader_reports_nothing_only_where_the_harness_has_no_share():
     assert READER.read({"model_flops_share": 0.25}) == 25.0
     assert READER.read({"model_flops_share": 0.25, "trace": None,
                         "phases": {}}) == 25.0
+
+
+# -- the door: a configuration's own count, in a sibling of its own (PR 48) --
+
+DOOR_SIBLING = '''"""Written by perf/tests/test_step_mfu.py: the count of a step that runs TWO
+streams of every sequence through the layers (s + block attention pairs a
+head and token) and reads out ONE."""
+
+
+def door_flops_split(app):
+    if app.get("attn_kind", "mha") != "mha" or "block" not in app:
+        raise ValueError("not counted here")
+    d, h, s = app["d_model"], app["n_heads"], app["max_seq"]
+    layer = 4 * d * d + 2 * d * app["d_ff"]
+    pairs = 2 * (d // h + d // h) * h * (s + app["block"])
+    return {"dense": 2 * 6.0 * app["n_layers"] * layer, "routed": 0.0,
+            "attention_pairs": 3.0 * app["n_layers"] * pairs, "scans": 0.0,
+            "readout": 6.0 * d * app["vocab_size"]}
+
+
+def door_flops_per_token(app):
+    return float(sum(door_flops_split(app).values()))
+
+
+def nothing_per_token(app):
+    return None
+
+
+def nan_per_token(app):
+    return float("nan")
+
+
+def lonely_per_token(app):
+    return door_flops_per_token(app)
+
+
+nothing_split = nan_split = door_flops_split
+'''
+DOOR_HAND = (2 * 6 * 12 * (4 * 768 * 768 + 2 * 768 * 3072)
+             + 3 * 12 * 2 * 128 * 12 * (1024 + 32) + 6 * 768 * 50257)
+
+
+def door(tmp_path, monkeypatch, flops_fn):
+    """A benchmark of one cell in ``tmp_path`` whose configuration is
+    gpt2-124m's with a ``block`` key and ``flops_fn`` for its count, beside a
+    sibling ``perf/work/door_count.py``; the harness and ``test_perf.py``
+    look there until the test ends. Returns ``(the cell's entry,
+    test_perf)``. (A plain function: tier-1 gathers this file's ``test_*``
+    names only, so a fixture of its own would not be found there.)"""
+    checks = run.load_by_path("tests", "test_perf")  # before PERF moves
+    config = json.load(open(os.path.join(PERF, "configs", "gpt2-124m.json")))
+    config["job"]["app_params"]["block"] = 32
+    config["job"]["flops_fn"] = flops_fn
+    bench = {**BENCH, "configs": [
+        {"name": "door", "source": "https://example.org/door",
+         "file": "perf/configs/door.json", "reduced": config["reduced"],
+         "why": "a configuration that names its own count"}],
+        "workloads": [{"name": "door.solo", "config": "door",
+                       "traffic": "solo", "chips": 1, "why": "the door"}],
+        "end_to_end": [{**m, "workloads": ["door.solo"]}
+                       for m in BENCH["end_to_end"]
+                       if m["name"] in ("setup_s", "lm_tokens_per_s")],
+        "per_layer": [{**m, "workloads": ["door.solo"]}
+                      for m in BENCH["per_layer"]
+                      if m["name"] == "step_mfu_share"]}
+    perf = tmp_path / "perf"
+    for sub in ("configs", "traffic", "work", "reference"):
+        (perf / sub).mkdir(parents=True)
+    (perf / "configs" / "door.json").write_text(json.dumps(config))
+    (perf / "work" / "door_count.py").write_text(DOOR_SIBLING)
+    (perf / "reference" / (config["job"]["reference"] + ".py")).write_text("")
+    (perf / "traffic" / "solo.json").write_text(
+        open(os.path.join(PERF, "traffic", "solo.json")).read())
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    for module in (run, checks):
+        monkeypatch.setattr(module, "ROOT", str(tmp_path))
+        monkeypatch.setattr(module, "PERF", str(perf))
+    monkeypatch.setattr(checks, "BENCH", bench)
+    # siblings are loaded once a process: this test's get a cache of their own
+    monkeypatch.setattr(WORK, "sibling", functools.lru_cache(maxsize=None)(
+        WORK.sibling.__wrapped__))
+    return bench["workloads"][0], checks
+
+
+def test_a_configuration_may_name_its_own_count(tmp_path, monkeypatch, capsys):
+    """``"<sibling>:<function>"`` is what ``model_flops_utilisation`` and
+    ``step_mfu_share`` report — that function's number, not this
+    repository's one function's — and the cell's file check passes."""
+    entry, checks = door(tmp_path, monkeypatch,
+                         "door_count:door_flops_per_token")
+    checks.test_cell_resolves_to_files(entry)
+    c = run.Cell("door.solo", False)
+    # gpt2-124m's shapes: the one function counts them 797,815,296.0
+    assert WORK.count(c.job, "flops_fn") == float(DOOR_HAND) != 797815296.0
+    assert sum(WORK.split(c.job).values()) == DOOR_HAND
+    tokens = 7.25 * float(c.job["units_per_example"])
+    shares = run.work_model_shares(c, PEAKS, tokens)
+    assert shares == {"model_flops_utilisation": pytest.approx(
+        tokens * DOOR_HAND / PEAK, rel=1e-12)}
+    line = json.loads(capsys.readouterr().out.splitlines()[-1])
+    assert line == {"line": "model_flops_utilisation",
+                    "flops_per_unit": float(DOOR_HAND),
+                    "share_of_peak": shares["model_flops_utilisation"]}
+    assert READER.read({"fits": [{"rate": 7.25}], "model_flops_share":
+                        shares["model_flops_utilisation"]}) \
+        == 100.0 * line["share_of_peak"] > 0
+
+
+@pytest.mark.parametrize("flops_fn", [
+    "no_such_function", "no_such_sibling:door_flops_per_token",
+    "door_count:no_such_function", "door_count:", ":lm_train_flops_per_token",
+    "door_count:nothing_per_token", "door_count:nan_per_token",
+    "door_count:lonely_per_token"])
+def test_a_count_that_is_not_there_reports_no_share(flops_fn, tmp_path,
+                                                    monkeypatch, capsys):
+    """A name that does not resolve, a function that returns no number, a
+    count without its twin: the cell fails its file check loudly, and (the
+    twin aside, which a run does not ask) the run prints why and reports NO
+    share, never ``lm_train_flops_per_token``'s."""
+    entry, checks = door(tmp_path, monkeypatch, flops_fn)
+    with pytest.raises(Exception):
+        checks.test_cell_resolves_to_files(entry)
+    if flops_fn.endswith("lonely_per_token"):
+        return
+    c = run.Cell("door.solo", False)
+    shares = run.work_model_shares(c, PEAKS, 1e5)
+    assert shares == {}
+    lines = [json.loads(x) for x in capsys.readouterr().out.splitlines()]
+    assert [x["line"] for x in lines] == ["work_model_failed"]
+    assert lines[0]["key"] == "flops_fn" and lines[0]["name"] == flops_fn
+    assert READER.read({"fits": [{"rate": 1.0}], "model_flops_share":
+                        shares.get("model_flops_utilisation")}) is None
 
 
 def test_the_entry_in_the_benchmark():

@@ -4,6 +4,46 @@ file gives under ``job.flops_fn`` / ``job.bytes_fn``. ``perf/run.py`` prints
 each at the run's rate as a share of the chips' peak (``model_flops_
 utilisation``, ``table_bandwidth``); ``step_mfu_share`` is the first, x 100.
 
+**Where a count may live** (``resolve``, the one place a name is looked up:
+the harness, ``perf/tests/test_perf.py`` and ``perf/tests/test_step_mfu.py``
+all ask it). A bare name is a function of THIS file. ``"<sibling>:<function>"``
+is a function of ``perf/work/<sibling>.py``, loaded as the harness loads every
+sibling (``sibling``): a configuration whose step this file's one function
+cannot count — two streams through every layer, a mask that is neither a
+triangle nor a band, a mixer kind ``layer_kinds`` refuses — brings its own
+count beside its other work functions, in a file a ``model_config`` PR may
+add, and ``step_mfu_share`` stays one metric under one name. No key of a
+configuration file, no flag and no environment variable says where: the name
+does.
+
+**What such a function owes** (``count`` and ``split`` hold every one to it;
+``perf/tests/test_step_mfu.py`` holds every LM configuration to a hand count):
+
+* it takes ``job.app_params`` and nothing of the program: no
+  ``TransformerConfig``, no span, no counter;
+* it returns the FLOPs (``bytes_fn``: the bytes) that ONE UNIT OF THE CELL'S
+  RATE needs, forward and backward. A unit is what ``job.units_per_example``
+  counts: for ``lm_tokens_per_s`` a token of the tenant's CORPUS, not a
+  position the step happens to compute — a step that runs a noised and a clean
+  copy of each sequence through the layers needs twice the layers' FLOPs a
+  token and ONE readout, and says so in its count;
+* recomputation counts nothing (``remat``, flash attention's scores, a chunked
+  scan's chunks);
+* a key it does not know raises: a model it cannot count reports no share,
+  never a plausible one;
+* a ``flops_fn`` comes with a TWIN in the same file that returns the same
+  number split over ``PARTS`` (``dense``, ``routed``, ``attention_pairs``,
+  ``scans``, ``readout``; none negative, ``dense`` and ``readout`` positive,
+  the sum equal to the count). The twin's name is the count's with its last
+  ``_per_<unit>`` replaced by ``_split`` (``lm_train_flops_per_token`` /
+  ``lm_train_flops_split``), so the printed line ``model_flops_utilisation``
+  and ``step_mfu_share`` are computed the same way for every cell.
+
+A name that does not resolve, or a function that returns no positive finite
+number, fails the cell's file check (``test_cell_resolves_to_files``) and
+makes the run print ``work_model_failed`` and report NO share — never this
+file's default in its place.
+
 ``lm_train_flops_per_token``: operations one token of a WHOLE training step
 of the LM tenants NEEDS, from ``job.app_params`` alone. All LM configurations
 are one model class told apart by keys of ``app_params``; this file reads
@@ -63,7 +103,10 @@ nothing here, as ``remat`` counts nothing.
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, List
+import math
+import re
+import sys
+from typing import Any, Callable, Dict, List
 
 #: the parts ``lm_train_flops_split`` returns, FLOPs a token forward + backward
 PARTS = ("dense", "routed", "attention_pairs", "scans", "readout")
@@ -78,6 +121,50 @@ def sibling(name: str):
     from perf.run import load_by_path
 
     return load_by_path("work", name)
+
+
+def resolve(name: str) -> Callable[[Dict[str, Any]], Any]:
+    """The function a configuration names under ``job.flops_fn`` /
+    ``job.bytes_fn``: ``"<function>"`` of this file, or
+    ``"<sibling>:<function>"`` of ``perf/work/<sibling>.py`` (module
+    docstring). Raises where the file or the function is not there."""
+    where, colon, fn = str(name).rpartition(":")
+    module = sibling(where) if colon else sys.modules[__name__]
+    found = getattr(module, fn, None) if fn.isidentifier() else None
+    if not callable(found):
+        raise ValueError(f"work function {name!r}: no callable {fn!r} in "
+                         f"{getattr(module, '__file__', module)}")
+    return found
+
+
+def count(job: Dict[str, Any], key: str = "flops_fn") -> float:
+    """What ``job[key]``'s function counts for ``job["app_params"]``: a
+    positive finite number, or an error (a count of nothing, ``None`` or
+    ``nan`` is no count)."""
+    value = resolve(job[key])(job["app_params"])
+    if not isinstance(value, (int, float)) or not math.isfinite(value) \
+            or value <= 0:
+        raise ValueError(f"work function {job[key]!r} returned {value!r}, "
+                         "not a positive finite number")
+    return float(value)
+
+
+def split(job: Dict[str, Any]) -> Dict[str, float]:
+    """``count(job, "flops_fn")`` by ``PARTS``, from the count's twin (module
+    docstring): exactly ``PARTS``, none negative, ``dense`` and ``readout``
+    positive, summing to the count — or an error."""
+    name = str(job["flops_fn"])
+    twin, n = re.subn(r"_per_[a-z]+$", "_split", name)
+    if not n:
+        raise ValueError(f"work function {name!r} does not end in "
+                         "_per_<unit>: its twin has no name")
+    parts = resolve(twin)(job["app_params"])
+    if tuple(parts) != PARTS or min(parts.values()) < 0 \
+            or not (parts["dense"] > 0 and parts["readout"] > 0) \
+            or float(sum(parts.values())) != count(job, "flops_fn"):
+        raise ValueError(f"{twin!r} is not {name!r} split over {PARTS}: "
+                         f"{parts!r}")
+    return {k: float(v) for k, v in parts.items()}
 
 
 def layer_kinds(app: Dict[str, Any]) -> List[Dict[str, str]]:
