@@ -55,23 +55,25 @@ def resolve_attn(attn: str, seq: int, block: int | None = None,
             else "blockwise")
 
 
-def flash_on_mesh(q, k, v, **kw):
+def flash_on_mesh(q, k, v, with_lse: bool = False, **kw):
     """:func:`harmony_tpu.ops.flash_attention` on [B, H, S, D] operands,
     split over the traced mesh's data axis when there is one: a
     pallas_call is opaque to the GSPMD partitioner, which would otherwise
     all-gather the batch-sharded operands and run the whole attention on
-    every chip."""
+    every chip. ``with_lse``: :func:`flash_attention_lse`'s pair."""
     from jax.sharding import PartitionSpec as P
 
-    from harmony_tpu.ops.attention import flash_attention
+    from harmony_tpu.ops.attention import flash_attention, flash_attention_lse
     from harmony_tpu.parallel.mesh import DATA_AXIS
     from harmony_tpu.utils.platform import trace_mesh
 
-    fn = functools.partial(flash_attention, **kw)
+    fn = functools.partial(
+        flash_attention_lse if with_lse else flash_attention, **kw)
     mesh = trace_mesh()
     if mesh is None or mesh.devices.size == 1:
         return fn(q, k, v)
     data = mesh.shape.get(DATA_AXIS, 1)
     spec = P(DATA_AXIS) if data > 1 and q.shape[0] % data == 0 else P()
     return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                         out_specs=spec, check_vma=False)(q, k, v)
+                         out_specs=(spec, spec) if with_lse else spec,
+                         check_vma=False)(q, k, v)

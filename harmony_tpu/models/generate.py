@@ -128,6 +128,12 @@ def make_generate_fn(model, prompt_len: int, num_new: int,
     at temperature 0). ``prompt_len + num_new`` must fit
     ``config.max_seq``."""
     cfg = model.config
+    if cfg.objective == "block_diffusion":
+        raise ValueError(
+            "make_generate_fn samples left to right, one token a sequence a "
+            "step; a block-diffusion model generates by denoising a whole "
+            "block over several steps against a block-wise cache, a serving "
+            "path this repo does not have (ROADMAP.md Queue 2)")
     cfg.require_classic_block("make_generate_fn")
     total = prompt_len + num_new
     if total > cfg.max_seq:

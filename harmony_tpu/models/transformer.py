@@ -183,6 +183,26 @@ class TransformerConfig:
     moe_router_hidden: int = 0
     moe_null_expert: bool = False
     merge_scaled: bool = False
+    # SDAR's block and step (arXiv:2510.06303; the objective is block
+    # diffusion, BD3-LMs, arXiv:2503.09573), ``attn_kind="mha"`` under
+    # ``pos="rope"``: ``head_norm``: an RMSNorm over each HEAD's ``head_dim``
+    # columns of q and of k (one weight vector each, shared by the heads)
+    # after the head split and before the rotary — the Qwen3 block's, beside
+    # grouped heads where the whole-width ``qk_norm`` cannot stand;
+    # ``objective="block_diffusion"``: a step runs a CLEAN and a NOISED copy
+    # of every sequence (``mask_token``, a row of the held vocabulary, where
+    # the batch says "masked") through every layer, as rows ``n`` and ``N +
+    # n`` of one ``[2N, L]`` batch; with positions in blocks of
+    # ``diffusion_block`` a clean row sees the clean keys of its own and
+    # earlier blocks, a noisy row the clean keys of EARLIER blocks and the
+    # noisy keys of its own block (both directions); the readout takes the
+    # noisy rows, and the loss is the cross-entropy at the masked positions
+    # (no shift), each weighted by one over its block's masking rate, over
+    # ``N L`` (``loss_and_metrics``).
+    head_norm: bool = False
+    objective: str = "next_token"   # "next_token" | "block_diffusion"
+    diffusion_block: int = 0
+    mask_token: int = -1
     # Standard deviation the embedding rows are drawn with. GPT-2's 0.02
     # leaves a row at a fiftieth of what a block's fan-in projections add to
     # it, which nothing here divides by depth: attention's average over the
@@ -410,6 +430,45 @@ class TransformerConfig:
             raise ValueError(
                 "merge_scaled names the two residual merges of a block; a "
                 "layer_pattern layer is one sublayer under one norm")
+        if self.head_norm and (self.qk_norm or self.cca
+                               or self.attn_kind != "mha"
+                               or self.linear_layers or self.layer_pattern):
+            raise ValueError(
+                "head_norm norms each head's columns of an attn_kind='mha' "
+                "block's q and k: qk_norm would norm the same columns over "
+                "the whole width, cca L2-norms them itself, a latent block "
+                "(mla) norms its latent, and KDA / layer_pattern layers "
+                "carry no such leaves")
+        if self.objective not in ("next_token", "block_diffusion"):
+            raise ValueError(f"unknown objective {self.objective!r}: "
+                             "'next_token' or 'block_diffusion'")
+        diffusion = self.objective == "block_diffusion"
+        if not diffusion and (self.diffusion_block or self.mask_token != -1):
+            raise ValueError("diffusion_block / mask_token belong to "
+                             "objective='block_diffusion'")
+        if diffusion and (
+                self.diffusion_block < 1
+                or self.max_seq % self.diffusion_block
+                or not 0 <= self.mask_token < self.vocab_size
+                or self.pos != "rope" or self.attn_kind != "mha"
+                or self.window_layers or self.linear_layers
+                or self.layer_pattern or self.cca
+                or self.moe_seq_aux or self.moe_null_expert
+                or (self.moe_experts and not self.moe_top_k)):
+            raise ValueError(
+                "objective='block_diffusion' needs diffusion_block >= 1 "
+                f"dividing max_seq ({self.max_seq}), a mask_token among the "
+                f"{self.vocab_size} held rows, pos='rope' (each stream's "
+                "positions 0..L-1; a learned table would be read twice) and "
+                "attn_kind='mha' blocks without a window: the mask by block "
+                "and stream is written for softmax attention over the whole "
+                "past, not for windowed, latent (mla), CCA, KDA or "
+                "state-space layers, and its loss adds the dropless experts' "
+                "balance and z terms over both streams, not a per-sequence "
+                "balance, a 'no expert' count or Switch experts' "
+                f"(got block {self.diffusion_block}, "
+                f"mask_token {self.mask_token}, pos {self.pos!r}, "
+                f"attn_kind {self.attn_kind!r})")
         validate_attn(self.attn)
 
     def is_moe_layer(self, i: int) -> bool:
@@ -436,7 +495,9 @@ class TransformerConfig:
         ``linear_layers``, else ``attn_kind`` (``"mha"`` | ``"mla"``) — or,
         in a model with ``window_layers``, ``"swa"`` for those (windowed,
         rotary) and ``"full"`` for its other softmax blocks (whole causal
-        past, no positions). A ``layer_pattern`` model's layers are one
+        past, no positions), and under ``objective="block_diffusion"``
+        ``"full"`` for every block (no window: the kind the flash gauges'
+        readers count unwindowed kernels by). A ``layer_pattern`` model's layers are one
         sublayer each: ``"ssd"`` (``M``), ``"attn"`` (``*``: attention and
         nothing after it), ``"moe"`` (``E``: experts and no mixer). The
         block, ``init``, the trainer's vectors (``kda_decay_mean [kda
@@ -449,6 +510,8 @@ class TransformerConfig:
         def kind(i):
             if i in self.linear_layers:
                 return "kda"
+            if self.objective == "block_diffusion":
+                return "full"  # the whole past of its stream, by block
             if not self.window_layers:
                 return self.attn_kind
             return "swa" if i in self.window_layers else "full"
@@ -520,14 +583,16 @@ class TransformerConfig:
                 and not (self.n_kv_heads or self.mha_head_dim
                          or self.window_layers)
                 and not (self.cca or self.merge_scaled
-                         or self.rope_fraction != 1.0)):
+                         or self.rope_fraction != 1.0)
+                and not self.head_norm
+                and self.objective == "next_token"):
             raise ValueError(
                 f"{who} runs the GPT-2-era block only (learned positions, "
                 "GELU, tied readout, Switch experts); rotary / no-position / "
                 "QK-norm / SwiGLU / untied / dropless / latent-attention / "
                 "KDA linear-attention / grouped-query / windowed / leading-dense "
-                "/ layer-pattern / CCA / partial-rotary / scaled-merge configs "
-                "train through "
+                "/ layer-pattern / CCA / partial-rotary / scaled-merge / "
+                "head-norm / block-diffusion configs train through "
                 "TransformerLM.loss and TransformerTrainer")
 
 
@@ -747,6 +812,9 @@ class TransformerLM:
             if cfg.qk_norm:
                 layer["q_norm"] = jnp.ones((d,), jnp.float32)
                 layer["k_norm"] = jnp.ones((d,), jnp.float32)
+            if cfg.head_norm:
+                layer["q_head_norm"] = jnp.ones((cfg.head_dim,), jnp.float32)
+                layer["k_head_norm"] = jnp.ones((cfg.head_dim,), jnp.float32)
             if cfg.cca:
                 layer["cca"] = init_cca_params(
                     jax.random.fold_in(ks[0], 1), cfg)
@@ -892,6 +960,9 @@ class TransformerLM:
             if cfg.qk_norm:
                 layer["q_norm"] = np.ones((d,), np.float32)
                 layer["k_norm"] = np.ones((d,), np.float32)
+            if cfg.head_norm:
+                layer["q_head_norm"] = np.ones((cfg.head_dim,), np.float32)
+                layer["k_head_norm"] = np.ones((cfg.head_dim,), np.float32)
             if cfg.cca:
                 K, hd = CCA_TAPS, cfg.head_dim
                 heads = cfg.n_heads + cfg.kv_heads
@@ -965,6 +1036,11 @@ class TransformerLM:
     def _attention(self, q, k, v, axis_name: Optional[str],
                    window: Optional[int] = None):
         cfg = self.config
+        if cfg.objective == "block_diffusion":
+            if axis_name is not None:
+                raise ValueError("the sequence-parallel attention tiers do "
+                                 "not run the mask by block and stream")
+            return self._stream_attention(q, k, v)
         if axis_name is not None:
             if window is not None or k.shape[1] != q.shape[1]:
                 raise ValueError("the sequence-parallel attention tiers run "
@@ -980,6 +1056,40 @@ class TransformerLM:
         if attn == "flash":  # the kernels tile themselves from the shape
             return flash_on_mesh(q, k, v, causal=True, **band)
         return blockwise_attention(q, k, v, causal=True, **band)
+
+    def _stream_attention(self, q, k, v):
+        """Attention of a block-diffusion step on ``[2N, heads, L, hd]``
+        operands — rows ``n`` the clean and ``N + n`` the noisy copy of
+        sequence ``n``, the ONE place the two streams meet. One call of the
+        attention tier with both streams' queries stacked along the sequence
+        axis against the CLEAN keys and values under the mask by block and
+        stream (ops/attention.py ``diffusion_block``: a causal triangle
+        whose edge is rounded up to the block for clean rows and down for
+        noisy ones), then the noisy rows' own-block term — ``L / B`` tiles of
+        ``B x B`` against the NOISY keys, plain ``jnp`` — merged in by the
+        two log-sum-exps. Noisy keys never stream through a kernel, and no
+        ``[L, L]`` array exists."""
+        from harmony_tpu.models.common import flash_on_mesh, resolve_attn
+        from harmony_tpu.ops.attention import (blockwise_attention_lse,
+                                               merge_by_lse,
+                                               own_block_attention)
+
+        cfg = self.config
+        N, L, B = q.shape[0] // 2, q.shape[2], cfg.diffusion_block
+        attn = resolve_attn(cfg.attn, L, head_dim=q.shape[3],
+                            v_head_dim=v.shape[3], dtype=q.dtype)
+        with step_scope("mixer.streams"):
+            stacked = jnp.concatenate([q[:N], q[N:]], axis=2)  # [N, H, 2L, hd]
+        if attn == "flash":  # the kernels tile themselves from the shape
+            o, lse = flash_on_mesh(stacked, k[:N], v[:N], causal=True,
+                                   diffusion_block=B, with_lse=True)
+        else:
+            o, lse = blockwise_attention_lse(stacked, k[:N], v[:N],
+                                             causal=True, diffusion_block=B)
+        with step_scope("mixer.streams"):
+            own, own_lse = own_block_attention(q[N:], k[N:], v[N:], B)
+            noisy = merge_by_lse(o[:, :, L:], lse[:, :, L:], own, own_lse)
+            return jnp.concatenate([o[:, :, :L], noisy], axis=0)
 
     def _latent_qkv(self, xn, layer, pos_offset):
         """DeepSeek-V3's latent attention operands from the normed input
@@ -1228,6 +1338,9 @@ class TransformerLM:
                     0, 2, 1, 3)
                 if not cfg.cca:
                     q, k, v = to_heads(q), to_heads(k), to_heads(v)
+                if cfg.head_norm:  # a head at a time, one weight for all
+                    q = _norm(q, layer["q_head_norm"].astype(cfg.dtype), eps)
+                    k = _norm(k, layer["k_head_norm"].astype(cfg.dtype), eps)
             if cfg.cca:
                 with step_scope("mixer.cca"):
                     q, k, v = (to_heads(t) for t in self._cca_latent(
@@ -1319,6 +1432,8 @@ class TransformerLM:
                 aux["skipped_by_layer"] = jnp.stack(
                     [a["skipped"] for a in routed])
         with step_scope("head"):
+            if cfg.objective == "block_diffusion":
+                x = x[x.shape[0] // 2:]  # ONE readout: the noisy rows
             x = _norm(x, params["ln_f"].astype(cfg.dtype), cfg.norm_eps)
             # f32 logits for a stable softmax; the readout is the embedding
             # (weight-tied) unless the model has a head of its own
@@ -1330,8 +1445,58 @@ class TransformerLM:
 
     def loss(self, params, tokens, axis_name=None) -> jnp.ndarray:
         """Mean next-token cross-entropy over the (single-device) batch,
-        plus the weighted MoE auxiliary losses for expert configs."""
+        plus the weighted MoE auxiliary losses for expert configs. Under
+        ``objective="block_diffusion"`` ``tokens`` is the batch tuple
+        ``(tokens, masked, rate)`` and the loss the weighted one
+        (``loss_and_metrics``)."""
         return self.loss_and_metrics(params, tokens, axis_name)[0]
+
+    def noised(self, tokens, masked):
+        """The ``[2N, L]`` tokens a block-diffusion step runs: the clean
+        rows ``tokens [N, L]``, then the same rows with ``mask_token`` where
+        ``masked`` is set. ``apply`` of them gives the noisy rows' logits
+        ``[N, L, V]``."""
+        with step_scope("noise"):
+            noisy = jnp.where(masked != 0, jnp.asarray(
+                self.config.mask_token, tokens.dtype), tokens)
+            return jnp.concatenate([tokens, noisy], axis=0)
+
+    def _diffusion_loss_and_metrics(self, params, batch, axis_name):
+        """Block diffusion's ``(loss, metrics)`` on the batch ``(tokens [N,
+        L], masked [N, L], rate [N, L / B])``: ``(1 / (N L)) sum_b (1 /
+        rate_b) sum_{p in b, masked} CE(logits_p, tokens_p)`` — the noisy
+        rows' logits at their OWN positions, no shift — plus the routing
+        losses over all ``2 N L`` positions computed. Metrics: the
+        unweighted mean CE over the masked positions (``ce``), the masked
+        share, ``diffusion_tokens [masked, all]`` (a vector: the counters'),
+        and an expert configuration's routing terms."""
+        cfg = self.config
+        if axis_name is not None or not isinstance(batch, (tuple, list)) \
+                or len(batch) != 3:
+            raise ValueError(
+                "objective='block_diffusion' trains on the batch tuple "
+                "(tokens, masked, rate) and on no sequence-parallel axis")
+        tokens, masked, rate = batch
+        logits, aux, _ = self._forward(params, self.noised(tokens, masked))
+        with step_scope("loss"):
+            f32 = jnp.float32
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            nll = -jnp.take_along_axis(logp, tokens[..., None], axis=-1)[..., 0]
+            m = (masked != 0).astype(f32)
+            weight = m / jnp.repeat(rate.astype(f32), cfg.diffusion_block,
+                                    axis=1)
+            count = m.sum()
+            loss = (nll * weight).sum() / m.size
+            out = {"ce": (nll * m).sum() / jnp.maximum(count, 1.0),
+                   "masked_share": count / m.size,
+                   "diffusion_tokens": jnp.stack(
+                       [count, jnp.asarray(m.size, f32)])}
+            if cfg.moe_top_k:
+                lb, z = routing_losses(aux, cfg.moe_experts)
+                loss = loss + cfg.moe_aux_weight * lb + cfg.moe_z_weight * z
+                out.update(aux_lb=lb, aux_z=z,
+                           moe_expert_tokens=aux["tokens_by_layer"])
+            return loss, out
 
     def loss_and_metrics(self, params, tokens, axis_name=None):
         """``(loss, metrics)``: dropless expert configs report the loss's
@@ -1339,6 +1504,8 @@ class TransformerLM:
         chosen for (a vector per step); models with KDA blocks each such
         block's mean decay and mean beta (vectors ``[kda blocks]``)."""
         cfg = self.config
+        if cfg.objective == "block_diffusion":
+            return self._diffusion_loss_and_metrics(params, tokens, axis_name)
         logits, aux, mixers = self._forward(params, tokens[:, :-1],
                                             axis_name=axis_name)
         kda = {}
@@ -1917,6 +2084,9 @@ class TransformerTrainer(PyTreeTrainer):
         return self.loss_and_metrics_on_batch(params, batch)[0]
 
     def loss_and_metrics_on_batch(self, params, batch):
+        if self.config.objective == "block_diffusion":
+            # the whole tuple (tokens, masked, rate): the noise is data
+            return self.model.loss_and_metrics(params, tuple(batch))
         tokens = batch[0] if isinstance(batch, (tuple, list)) else batch
         return self.model.loss_and_metrics(params, tokens)
 
@@ -1935,6 +2105,10 @@ class TransformerTrainer(PyTreeTrainer):
                         self.config.dropless_cfg.experts_held,
                         self.config.moe_layers(),
                         null_slots=vectors.get("moe_null_slots"))
+        if "diffusion_tokens" in vectors:
+            from harmony_tpu.metrics import diffusion
+
+            diffusion.observe(job_id, vectors["diffusion_tokens"])
         from harmony_tpu.metrics import kda
 
         for kind, stats in kda.STATS.items():  # the recurrent layers' pairs

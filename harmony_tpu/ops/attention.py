@@ -44,6 +44,27 @@ trace time, so a call without them traces the program it always did:
     outside the band do not exist, and a step past a tile's last needed
     block repeats that block's index and fetches nothing. Windowed calls
     carry their own kernel names (``harmony_flash_win_*``).
+
+A third shape, the same way (``diffusion_block``; causal, no window):
+
+  * **a mask by block and stream**, a block-diffusion step's (SDAR,
+    arXiv:2510.06303): ``q`` holds the CLEAN rows and then the NOISY rows of
+    each sequence, stacked along the sequence axis (``2 L`` rows), against
+    the clean keys and values (``L`` columns). Stacked row ``r`` is position
+    ``p = r mod L`` of stream ``s = r // L`` and sees column ``c`` iff ``c //
+    B <= p // B - s``: a causal triangle whose edge is rounded UP to the
+    block for clean rows and DOWN for noisy ones (``_seen_until`` /
+    ``_seen_from``, the one pair every bound comes from). The sub-block
+    loops keep their form with block-rounded bounds, only the sub-blocks the
+    edge crosses are masked (``_apply_block_mask``, shared by the three
+    kernels), tiles divide ONE stream's length so none straddles the two,
+    and the index maps clamp by the same bounds. Rows that see no column
+    (block 0's noisy rows) leave with output 0 and an LSE of ``-1e30``,
+    which :func:`merge_by_lse` weighs at nothing, and get and give no
+    gradient. The noisy rows' own-block term against the NOISY keys is
+    :func:`own_block_attention` (``L / B`` tiles of ``B x B``, plain
+    ``jnp``), merged in by the two LSEs: noisy keys never stream through a
+    kernel. Kernel names ``harmony_flash_bd_*``.
 """
 from __future__ import annotations
 
@@ -92,6 +113,52 @@ def _apply_band_mask(s, row0, col0, window):
     return jnp.where((ahead >= lo) & (ahead < lo + window), s, _NEG_INF)
 
 
+def _block_floor(x, block):
+    """``x`` rounded down to a multiple of ``block`` (``x >= 0``; traced
+    int32 vectors and scalars, or numpy for the static count)."""
+    if block & (block - 1) == 0:
+        return x - (x & (block - 1))
+    return x - x % block
+
+
+def _seen_until(p, block, stream):
+    """One past the last column position ``p`` of ``stream`` sees under the
+    mask by block and stream: its own block's end for a clean row (stream
+    0), its own block's start for a noisy one (stream 1)."""
+    return _block_floor(p, block) + (1 - stream) * block
+
+
+def _seen_from(c, block, stream):
+    """The first position of ``stream`` that sees column ``c``: the start of
+    ``c``'s block for a clean row, of the block after it for a noisy one
+    (``_seen_until``'s inverse: ``_seen_until(p) > c  <=>  p >=
+    _seen_from(c)``)."""
+    return _block_floor(c, block) + stream * block
+
+
+def _apply_block_mask(s, row0, col0, block, stream):
+    """:func:`_apply_causal_mask` with the diagonal's edge rounded to the
+    diffusion block: the tile's first row is POSITION ``row0`` of
+    ``stream`` (0 clean, 1 noisy) and sees column ``c`` iff ``c // block <=
+    row // block - stream``. Shared by the forward and both backward
+    kernels, as the causal mask is."""
+    rows = row0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    cols = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    return jnp.where(cols < _seen_until(rows, block, stream), s, _NEG_INF)
+
+
+def _check_diffusion(block, causal, window, sq, sk) -> None:
+    if block is None:
+        return
+    if not causal or window is not None or block < 1 or sq != 2 * sk \
+            or sk % block:
+        raise ValueError(
+            f"attention: diffusion_block {block} masks by block and stream: "
+            "q holds the clean rows and then the noisy rows of each sequence "
+            f"(2 x {sk} rows, got {sq}) against the clean keys, whose count "
+            "the block must divide; causal=True and no window")
+
+
 def _head_group(q, k, v) -> int:
     """Query heads a K/V head serves (1: as many K/V heads as query
     heads)."""
@@ -127,6 +194,7 @@ def blockwise_attention(
     block_k: int = DEFAULT_BLOCK_K,
     scale: Optional[float] = None,
     window: Optional[int] = None,
+    diffusion_block: Optional[int] = None,
 ) -> jnp.ndarray:
     """Streaming-softmax attention: scan over KV blocks carrying (acc, m, l).
 
@@ -136,12 +204,32 @@ def blockwise_attention(
     scan gives the memory-efficient backward. ``k`` / ``v`` with fewer
     heads (a divisor of ``H``) are repeated, query head ``h`` reading K/V
     head ``h // group``; ``window`` (causal only) keeps ``0 <= i - j <
-    window``.
+    window``; ``diffusion_block``: the mask by block and stream (module
+    docstring; :func:`blockwise_attention_lse` also returns the LSE the
+    own-block term merges by).
     """
+    return _blockwise(q, k, v, causal, block_k, scale, window,
+                      diffusion_block)[0]
+
+
+def blockwise_attention_lse(q, k, v, causal=False, block_k=DEFAULT_BLOCK_K,
+                            scale=None, window=None, diffusion_block=None):
+    """:func:`blockwise_attention` with the per-row log-sum-exp ``[B, H,
+    Sq]`` (float32) beside the output — :func:`flash_attention_lse`'s pair
+    on any backend. A row that sees no key (block 0's noisy rows under
+    ``diffusion_block``) has output 0 and an LSE of ``-1e30``, which merges
+    to nothing."""
+    return _blockwise(q, k, v, causal, block_k, scale, window,
+                      diffusion_block, with_lse=True)
+
+
+def _blockwise(q, k, v, causal, block_k, scale, window, diffusion_block,
+               with_lse=False):
     B, H, Sq, D = q.shape
     Sk, Dv = k.shape[2], v.shape[3]
     group = _head_group(q, k, v)
     _check_window(window, causal)
+    _check_diffusion(diffusion_block, causal, window, Sq, Sk)
     if group > 1:
         k, v = (jnp.repeat(t, group, axis=1) for t in (k, v))
     scale = scale if scale is not None else D ** -0.5
@@ -157,6 +245,8 @@ def blockwise_attention(
 
     qf = q.astype(jnp.float32) * scale
     q_pos = jnp.arange(Sq)[:, None]
+    if diffusion_block is not None:  # rows: Sk clean positions, then noisy
+        stream, q_pos = q_pos // Sk, q_pos % Sk
 
     def step(carry, blk):
         acc, m, l = carry
@@ -164,7 +254,10 @@ def blockwise_attention(
         s = jnp.einsum("bhqd,bhkd->bhqk", qf, kblk.astype(jnp.float32))
         kv_pos = start + jnp.arange(block_k)[None, :]
         mask = kv_pos < Sk  # padding
-        if causal:
+        if diffusion_block is not None:
+            mask = mask & (kv_pos < _seen_until(q_pos, diffusion_block,
+                                                stream))
+        elif causal:
             mask = mask & (q_pos >= kv_pos)
         if window is not None:
             mask = mask & (q_pos - kv_pos < window)
@@ -186,8 +279,17 @@ def blockwise_attention(
     m0 = jnp.full_like(qf[..., 0], _NEG_INF)
     l0 = jnp.zeros_like(qf[..., 0])
     starts = jnp.arange(nk) * block_k
-    (acc, _, l), _ = jax.lax.scan(step, (acc0, m0, l0), (kb, vb, starts))
-    return (acc / jnp.maximum(l, 1e-30)[..., None]).astype(q.dtype)
+    (acc, m, l), _ = jax.lax.scan(step, (acc0, m0, l0), (kb, vb, starts))
+    out = acc / jnp.maximum(l, 1e-30)[..., None]
+    if diffusion_block is not None:  # a row no key reached: nothing
+        dead = m <= _NEG_INF
+        out = jnp.where(dead[..., None], 0.0, out)
+    if not with_lse:
+        return out.astype(q.dtype), None
+    lse = m + jnp.log(jnp.maximum(l, 1e-30))
+    if diffusion_block is not None:
+        lse = jnp.where(dead, _NEG_INF, lse)
+    return out.astype(q.dtype), lse
 
 
 # ---------------------------------------------------------------------------
@@ -224,8 +326,17 @@ _WIN_KERNEL_NAMES = {"fwd": "harmony_flash_win_fwd",
                      "dq": "harmony_flash_win_bwd_dq"}
 
 
-def kernel_name(kernel: str, window: Optional[int]) -> str:
+#: ... and under the mask by block and stream (``diffusion_block``)
+_BD_KERNEL_NAMES = {"fwd": "harmony_flash_bd_fwd",
+                    "dkv": "harmony_flash_bd_bwd_dkv",
+                    "dq": "harmony_flash_bd_bwd_dq"}
+
+
+def kernel_name(kernel: str, window: Optional[int],
+                diffusion_block: Optional[int] = None) -> str:
     """The trace name of ``"fwd"`` / ``"dkv"`` / ``"dq"``."""
+    if diffusion_block is not None:
+        return _BD_KERNEL_NAMES[kernel]
     return (_KERNEL_NAMES if window is None else _WIN_KERNEL_NAMES)[kernel]
 _ONE_BLOCK = 256              # a whole length up to this is one block as it is
 _RESIDENT = (512, 256, 128)   # resident-block lengths tried, largest first
@@ -350,11 +461,16 @@ def tile_plan(sq, sk, d, dtype, causal=False, block_q=None, block_k=None,
     return None if None in tiles else TilePlan(*tiles, True)
 
 
-def _require_plan(q, k, v, causal, block_q, block_k, window=None):
+def _require_plan(q, k, v, causal, block_q, block_k, window=None,
+                  diffusion_block=None):
     sq, sk, d = q.shape[2], k.shape[2], q.shape[3]
     if k.shape[3] != d:
         raise ValueError(f"flash attention: q is {d} wide and k "
                          f"{k.shape[3]}; only v may have a width of its own")
+    if diffusion_block is not None:
+        # the stacked rows are two streams of sk positions: tiles that
+        # divide ONE stream's length never straddle the two
+        sq = sk
     plan = tile_plan(sq, sk, d, q.dtype, causal, block_q, block_k,
                      dv=v.shape[3], window=window)
     if plan is None:
@@ -366,13 +482,15 @@ def _require_plan(q, k, v, causal, block_q, block_k, window=None):
     return plan
 
 
-def _note_plan(plan, kernels, q, k, v, causal, window):
+def _note_plan(plan, kernels, q, k, v, causal, window, diffusion_block=None):
     """Trace-time record of the tiling a compiled program runs — the plan is
     static per shape, so it engages always or never; STATUS ``kernel_plans``
     (beside ``compiles``) says which one a job got. A call with grouped
     heads or a window adds what the BAND needs of the plan
     (:func:`band_work`, summed over the call's heads) and sets
-    ``harmony_flash_masked_share``. Never fails a trace."""
+    ``harmony_flash_masked_share``; so does a call under
+    ``diffusion_block``, counted from the block-rounded bounds. Never fails
+    a trace."""
     try:
         from harmony_tpu.runtime.progcache import note_kernel_plan
 
@@ -381,8 +499,10 @@ def _note_plan(plan, kernels, q, k, v, causal, window):
             t = getattr(plan, kern)
             band = None
             steps = (sq // t.block_q) * (sk // t.block_k)
-            if window is not None or k.shape[1] != q.shape[1]:
-                work = band_work(kern, t, sq, sk, causal, window)
+            if (window is not None or k.shape[1] != q.shape[1]
+                    or diffusion_block is not None):
+                work = band_work(kern, t, sq, sk, causal, window,
+                                 diffusion_block)
                 steps = work["grid_steps"]
                 band = {"window": window or 0, "kv_heads": k.shape[1],
                         "band_grid_steps": bh * work["with_work"],
@@ -391,7 +511,8 @@ def _note_plan(plan, kernels, q, k, v, causal, window):
                         "computed": bh * work["computed"],
                         "masked_share": 1.0 - work["kept"] / work["computed"]}
             note_kernel_plan(
-                kernel_name(kern, window), t.block_q, t.block_k, t.sub,
+                kernel_name(kern, window, diffusion_block), t.block_q,
+                t.block_k, t.sub,
                 bh * steps, plan.planned, d=q.shape[3], dv=v.shape[3],
                 band=band)
     except Exception:
@@ -433,6 +554,26 @@ def _q_sub_ranges(causal, q0, k0, block_k, sub, n_sub):
         return 0, 0
     first = jnp.clip((k0 - q0) // sub, 0, n_sub)
     full_from = jnp.clip((k0 + block_k - q0 + sub - 2) // sub, 0, n_sub)
+    return first, full_from
+
+
+def _kv_block_ranges(p0, block_q, k0, sub, n_sub, block, stream, xp=jnp):
+    """:func:`_kv_sub_ranges` under the mask by block and stream, for a
+    resident q tile of positions ``p0 .. p0+block_q-1`` of ``stream``: the
+    diagonal's edge is rounded UP to the diffusion block for clean rows
+    and DOWN for noisy ones (``_seen_until``)."""
+    n_full = xp.clip((_seen_until(p0, block, stream) - k0) // sub, 0, n_sub)
+    n_need = xp.clip((_seen_until(p0 + block_q - 1, block, stream) - k0
+                      + sub - 1) // sub, 0, n_sub)
+    return n_full, n_need
+
+
+def _q_block_ranges(p0, k0, block_k, sub, n_sub, block, stream, xp=jnp):
+    """:func:`_q_sub_ranges` under the mask by block and stream, for a
+    streamed q tile of positions ``p0 ..`` of ``stream`` (``_seen_from``)."""
+    first = xp.clip((_seen_from(k0, block, stream) - p0) // sub, 0, n_sub)
+    full_from = xp.clip((_seen_from(k0 + block_k - 1, block, stream) - p0
+                         + sub - 1) // sub, 0, n_sub)
     return first, full_from
 
 
@@ -496,20 +637,45 @@ def _band_steps(kernel, tiles, sq, sk, window) -> int:
     return int((last - first + 1).max())
 
 
-def band_work(kernel, tiles, sq, sk, causal, window):
+def band_work(kernel, tiles, sq, sk, causal, window, diffusion_block=None):
     """What one (batch, q head) of a call of ``kernel`` under ``tiles`` runs
     and what the mask needs of it, counted from the same bounds the kernel
     loops by (static, a few thousand integer operations): ``grid_steps``
     the streamed axis has and those ``with_work``, the ``sub_blocks`` the
     in-kernel loops take and the ``masked`` ones among them, the score
     elements ``computed`` and those the mask ``kept``. A causal call
-    without a window counts as one whose window reaches past every key."""
+    without a window counts as one whose window reaches past every key;
+    under ``diffusion_block`` (``sq`` the stacked ``2 sk`` rows) the bounds
+    are the block-rounded ones and ``kept`` is ``sk ** 2`` exactly."""
     import numpy as np
 
     bq, bk, sub = tiles[:3]
     nq, nk = sq // bq, sk // bk
     dkv = kernel == "dkv"
     n_res, n_str, n_sub = (nk, nq, bq // sub) if dkv else (nq, nk, bk // sub)
+    if diffusion_block is not None:
+        B = diffusion_block
+        pos = np.arange(sk)
+        kept = int(sum(np.clip(_seen_until(pos, B, s), 0, sk).sum()
+                       for s in (0, 1)))
+        with_work = run = masked = 0
+        for i in range(nq):
+            stream, p0 = divmod(i * bq, sk)
+            for j in range(nk):
+                if dkv:
+                    lo, full = (int(x) for x in _q_block_ranges(
+                        p0, j * bk, bk, sub, n_sub, B, stream, np))
+                    ran, cut = n_sub - lo, full - lo
+                else:
+                    full, need = (int(x) for x in _kv_block_ranges(
+                        p0, bq, j * bk, sub, n_sub, B, stream, np))
+                    ran, cut = need, need - full
+                run += ran
+                masked += cut
+                with_work += ran > 0
+        return {"grid_steps": nq * nk, "with_work": with_work,
+                "sub_blocks": run, "masked_sub_blocks": masked,
+                "computed": run * (bk if dkv else bq) * sub, "kept": kept}
     if not causal:
         run = n_res * n_str * n_sub
         return {"grid_steps": n_res * n_str, "with_work": n_res * n_str,
@@ -569,11 +735,24 @@ def _for_band(ranges, has_work, n_sub, step, window):
     _for_sub_blocks(c, d, n_sub, step(band))
 
 
+def _stream_of(q0, bd):
+    """``(first position, stream, mask)`` of the q tile whose first STACKED
+    row is ``q0`` under ``bd = (diffusion block, positions a stream)``: a
+    tile lies in one stream (``_require_plan``)."""
+    block, length = bd
+    stream = q0 // length
+    return q0 - stream * length, stream, functools.partial(
+        _apply_block_mask, block=block, stream=stream)
+
+
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
-               scale, causal, block_q, block_k, sub, window=None, nk=None):
+               scale, causal, block_q, block_k, sub, window=None, nk=None,
+               bd=None):
     iq = pl.program_id(1)
     q0 = iq * block_q
     ik = pl.program_id(2)
+    if bd is not None:  # rows are positions of a stream from here on
+        q0, stream, block_mask = _stream_of(q0, bd)
     if window is None:
         k0 = ik * block_k
     else:  # the band's own grid axis: this q tile's ik-th needed KV block
@@ -613,7 +792,12 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
         return body
 
     n_sub = block_k // sub
-    if window is None:
+    if bd is not None:
+        n_full, n_need = _kv_block_ranges(q0, block_q, k0, sub, n_sub, bd[0],
+                                          stream)
+        _for_sub_blocks(0, n_full, n_sub, step(None))
+        _for_sub_blocks(n_full, n_need, n_sub, step(block_mask))
+    elif window is None:
         n_full, n_need = _kv_sub_ranges(causal, q0, block_q, k0, sub, n_sub)
         _for_sub_blocks(0, n_full, n_sub, step(None))
         if causal:
@@ -625,14 +809,24 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
     @pl.when(ik == pl.num_programs(2) - 1)
     def _write():
         l = jnp.maximum(l_ref[:, :1], 1e-30)
-        o_ref[0] = (acc_ref[:] / l).astype(o_ref.dtype)
+        out = acc_ref[:] / l
+        if bd is not None:
+            # a row no column reached (block 0's noisy rows) kept m at the
+            # mask's value and summed p = 1 over masked columns: it leaves
+            # with output 0 and an LSE that merges to nothing
+            dead = m_ref[:, :1] <= _NEG_INF
+            out = jnp.where(dead, 0.0, out)
+        o_ref[0] = out.astype(o_ref.dtype)
+        lse = m_ref[:, :1] + jnp.log(l)
+        if bd is not None:
+            lse = jnp.where(dead, _NEG_INF, lse)
         # log-sum-exp per row, consumed by the fused backward. Stored
         # broadcast across a 128-lane trailing dim: Mosaic requires the last
         # two block dims be (8,128)-tileable, and a (1, block_q) row block is
         # not — the lane-replicated layout is the canonical TPU shape for
         # per-row softmax stats (cf. jax.experimental.pallas.ops.tpu
         # flash_attention's l/m outputs).
-        lse_ref[0] = jnp.broadcast_to(m_ref[:, :1] + jnp.log(l), lse_ref.shape[1:])
+        lse_ref[0] = jnp.broadcast_to(lse, lse_ref.shape[1:])
 
 
 def _out_struct(shape, dtype, *refs):
@@ -646,13 +840,20 @@ def _out_struct(shape, dtype, *refs):
     return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
-def _last_needed_kv(causal, block_q, block_k, window=None, nk=None):
+def _last_needed_kv(causal, block_q, block_k, window=None, nk=None,
+                    bd=None):
     """index_map clamp for a streamed KV tile: a grid step above the
     diagonal repeats the block index of the last needed one, so Pallas
     fetches nothing for it (its arithmetic is skipped in the kernel). Under
     a window grid step ``j`` is the q tile's ``j``-th needed block."""
     if not causal:
         return lambda i, j: j
+    if bd is not None:
+        def blocked(i, j):
+            p0, stream, _ = _stream_of(i * block_q, bd)
+            until = _seen_until(p0 + block_q - 1, bd[0], stream)
+            return jnp.minimum(j, jnp.maximum(until - 1, 0) // block_k)
+        return blocked
     if window is not None:
         def banded(i, j):
             first, last = _kv_blocks_of(i, block_q, block_k, window, nk)
@@ -661,12 +862,20 @@ def _last_needed_kv(causal, block_q, block_k, window=None, nk=None):
     return lambda i, j: jnp.minimum(j, (i * block_q + block_q - 1) // block_k)
 
 
-def _first_needed_q(causal, block_q, block_k, nq, window=None):
+def _first_needed_q(causal, block_q, block_k, nq, window=None, bd=None):
     """The same clamp for the dK/dV kernel's streamed q tile: steps before
     the first q tile that reaches this KV tile's columns fetch that one
     (the last one where none does: more columns than rows)."""
     if not causal:
         return lambda j, i: i
+    if bd is not None:
+        half = nq // 2  # q tiles a stream
+
+        def blocked(j, i):
+            stream = i // half
+            first = _seen_from(j * block_k, bd[0], stream) // block_q
+            return jnp.maximum(i, stream * half + jnp.minimum(first, half - 1))
+        return blocked
     if window is not None:
         def banded(j, i):
             first, last = _q_blocks_of(j, block_q, block_k, window, nq)
@@ -688,8 +897,9 @@ def _kv_head(group):
 # the layers of a step, and every later job's re-trace of it, reuse that
 # (job.build_step: twelve layers' kernels cost one trace, not twelve).
 
-@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7))
-def _flash_forward(q, k, v, causal, tiles, scale, interpret, window=None):
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_forward(q, k, v, causal, tiles, scale, interpret, window=None,
+                   diffusion_block=None):
     B, H, Sq, D = q.shape
     Hkv, Sk, Dv = k.shape[1], k.shape[2], v.shape[3]
     block_q, block_k, sub = tiles[:3]
@@ -701,6 +911,8 @@ def _flash_forward(q, k, v, causal, tiles, scale, interpret, window=None):
             nk if window is None else _band_steps("fwd", tiles, Sq, Sk,
                                                   window))
     band = {} if window is None else {"window": window, "nk": nk}
+    if diffusion_block is not None:
+        band = {"bd": (diffusion_block, Sk)}
     kernel = functools.partial(
         _fa_kernel, scale=scale, causal=causal,
         block_q=block_q, block_k=block_k, sub=sub, **band,
@@ -709,7 +921,7 @@ def _flash_forward(q, k, v, causal, tiles, scale, interpret, window=None):
     kv_b = _kv_head(H // Hkv)
     out, lse = pl.pallas_call(
         kernel,
-        name=kernel_name("fwd", window),
+        name=kernel_name("fwd", window, diffusion_block),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
@@ -754,7 +966,7 @@ def _bwd_p_ds(q, k, v, do, lse, delta, row0, col0, scale, mask):
 def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                        dk_ref, dv_ref, dk_acc, dv_acc, *,
                        scale, causal, block_q, block_k, sub, window=None,
-                       nq=None, steps=None):
+                       nq=None, steps=None, bd=None):
     jk = pl.program_id(1)             # kv tile (this output tile)
     k0 = jk * block_k
     iq = pl.program_id(2)             # q tiles stream by
@@ -768,6 +980,8 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             first, last = _q_blocks_of(jk, block_q, block_k, window, nq)
             step_i = first + step_i
         q0 = step_i * block_q
+    if bd is not None:  # rows are positions of a stream from here on
+        q0, stream, block_mask = _stream_of(q0, bd)
 
     @pl.when(iq == 0)
     def _init():
@@ -787,7 +1001,12 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         return body
 
     n_sub = block_q // sub
-    if window is None:
+    if bd is not None:
+        first, full_from = _q_block_ranges(q0, k0, block_k, sub, n_sub, bd[0],
+                                           stream)
+        _for_sub_blocks(first, full_from, n_sub, step(block_mask))
+        _for_sub_blocks(full_from, n_sub, n_sub, step(None))
+    elif window is None:
         first, full_from = _q_sub_ranges(causal, q0, k0, block_k, sub, n_sub)
         if causal:
             _for_sub_blocks(first, full_from, n_sub, step(_apply_causal_mask))
@@ -804,10 +1023,12 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       dq_ref, dq_acc, *, scale, causal, block_q, block_k,
-                      sub, window=None, nk=None):
+                      sub, window=None, nk=None, bd=None):
     iq = pl.program_id(1)             # q tile (this output tile)
     q0 = iq * block_q
     ik = pl.program_id(2)             # kv tiles stream by
+    if bd is not None:  # rows are positions of a stream from here on
+        q0, stream, block_mask = _stream_of(q0, bd)
     if window is None:
         k0 = ik * block_k
     else:
@@ -830,7 +1051,12 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         return body
 
     n_sub = block_k // sub
-    if window is None:
+    if bd is not None:
+        n_full, n_need = _kv_block_ranges(q0, block_q, k0, sub, n_sub, bd[0],
+                                          stream)
+        _for_sub_blocks(0, n_full, n_sub, step(None))
+        _for_sub_blocks(n_full, n_need, n_sub, step(block_mask))
+    elif window is None:
         n_full, n_need = _kv_sub_ranges(causal, q0, block_q, k0, sub, n_sub)
         _for_sub_blocks(0, n_full, n_sub, step(None))
         if causal:
@@ -865,7 +1091,7 @@ def _bwd_row_stats(out, lse, do, lse_cotangent):
 
 
 def _flash_bwd_dkv(qf, kf, vf, dof, lsef, delta, causal, tiles, scale,
-                   interpret, window=None):
+                   interpret, window=None, diffusion_block=None):
     """dK/dV kernel on [B*H, S, D] (q), [B*Hkv, S, D] (k), [B*Hkv, S, Dv]
     (v) and [B*H, S, Dv] (dO) operands: grid over kv tiles, q tiles stream
     by — those of every query head of the K/V head's group, one head after
@@ -879,13 +1105,16 @@ def _flash_bwd_dkv(qf, kf, vf, dof, lsef, delta, causal, tiles, scale,
     steps = nq if window is None else _band_steps("dkv", tiles, Sq, Sk,
                                                   window)
     grid = (BHkv, Sk // block_k, group * steps)
-    q_i = _first_needed_q(causal, block_q, block_k, nq, window)
+    bd = None if diffusion_block is None else (diffusion_block, Sk)
+    q_i = _first_needed_q(causal, block_q, block_k, nq, window, bd)
     if group == 1 and window is None:
         q_row = lambda b, j, i: (b, q_i(j, i), 0)
         band = {}
     else:
         q_row = lambda b, j, i: (b * group + i // steps, q_i(j, i % steps), 0)
         band = {"window": window, "nq": nq, "steps": steps}
+    if bd is not None:
+        band["bd"] = bd
     q_spec, do_spec = (pl.BlockSpec((1, block_q, w), q_row) for w in (D, Dv))
     k_spec, v_spec = (pl.BlockSpec((1, block_k, w), lambda b, j, i: (b, j, 0))
                       for w in (D, Dv))
@@ -893,7 +1122,7 @@ def _flash_bwd_dkv(qf, kf, vf, dof, lsef, delta, causal, tiles, scale,
     return pl.pallas_call(
         functools.partial(_fa_bwd_dkv_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, sub=sub, **band),
-        name=kernel_name("dkv", window),
+        name=kernel_name("dkv", window, diffusion_block),
         grid=grid,
         in_specs=[q_spec, k_spec, v_spec, do_spec, row_spec, row_spec],
         out_specs=[k_spec, v_spec],
@@ -908,7 +1137,7 @@ def _flash_bwd_dkv(qf, kf, vf, dof, lsef, delta, causal, tiles, scale,
 
 
 def _flash_bwd_dq(qf, kf, vf, dof, lsef, delta, causal, tiles, scale,
-                  interpret, window=None):
+                  interpret, window=None, diffusion_block=None):
     """dQ kernel on the same operands: grid over q tiles, kv tiles stream
     by."""
     BH, Sq, D = qf.shape
@@ -919,6 +1148,8 @@ def _flash_bwd_dq(qf, kf, vf, dof, lsef, delta, causal, tiles, scale,
             nk if window is None else _band_steps("dq", tiles, Sq, Sk,
                                                   window))
     band = {} if window is None else {"window": window, "nk": nk}
+    if diffusion_block is not None:
+        band = {"bd": (diffusion_block, Sk)}
     kv_j = _last_needed_kv(causal, block_q, block_k, **band)
     kv_b = _kv_head(BH // BHkv)
     q_spec, do_spec = (pl.BlockSpec((1, block_q, w), lambda b, i, j: (b, i, 0))
@@ -930,7 +1161,7 @@ def _flash_bwd_dq(qf, kf, vf, dof, lsef, delta, causal, tiles, scale,
     return pl.pallas_call(
         functools.partial(_fa_bwd_dq_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, sub=sub, **band),
-        name=kernel_name("dq", window),
+        name=kernel_name("dq", window, diffusion_block),
         grid=grid,
         in_specs=[q_spec, k_spec, v_spec, do_spec, row_spec, row_spec],
         out_specs=q_spec,
@@ -941,9 +1172,9 @@ def _flash_bwd_dq(qf, kf, vf, dof, lsef, delta, causal, tiles, scale,
     )(qf, kf, vf, dof, lsef, delta)
 
 
-@functools.partial(jax.jit, static_argnums=(7, 8, 9, 10, 11))
+@functools.partial(jax.jit, static_argnums=(7, 8, 9, 10, 11, 12))
 def _flash_backward(q, k, v, out, lse, do, lse_cotangent, causal, plan,
-                    scale, interpret, window=None):
+                    scale, interpret, window=None, diffusion_block=None):
     """Fused flash backward: dK/dV kernel (grid over kv tiles) + dQ kernel
     (grid over q tiles); softmax recomputed per tile from the saved LSE —
     the O(S) memory trade the forward made, carried into the backward."""
@@ -953,11 +1184,17 @@ def _flash_backward(q, k, v, out, lse, do, lse_cotangent, causal, plan,
     kf = k.reshape(B * Hkv, Sk, D)
     vf = v.reshape(B * Hkv, Sk, Dv)
     dof = do.reshape(B * H, Sq, Dv)
+    if diffusion_block is not None:
+        # a row that saw no key left with an LSE of -1e30: the backward's
+        # p = exp(s - lse) would read 1 on its masked scores; +1e30 makes
+        # them 0, so the row gives no gradient (and got none: its output
+        # is a constant)
+        lse = jnp.where(lse <= _NEG_INF / 2, -_NEG_INF, lse)
     lsef, delta = _bwd_row_stats(out, lse, do, lse_cotangent)
     dk, dv = _flash_bwd_dkv(qf, kf, vf, dof, lsef, delta, causal, plan.dkv,
-                            scale, interpret, window)
+                            scale, interpret, window, diffusion_block)
     dq = _flash_bwd_dq(qf, kf, vf, dof, lsef, delta, causal, plan.dq,
-                       scale, interpret, window)
+                       scale, interpret, window, diffusion_block)
     return (dq.reshape(B, H, Sq, D), dk.reshape(B, Hkv, Sk, D),
             dv.reshape(B, Hkv, Sk, Dv))
 
@@ -978,6 +1215,7 @@ def flash_attention(
     scale: Optional[float] = None,
     interpret: bool = False,
     window: Optional[int] = None,
+    diffusion_block: Optional[int] = None,
 ) -> jnp.ndarray:
     """Fused attention. Forward AND backward are Pallas TPU kernels
     (``interpret=True`` runs them in the Pallas interpreter, for CPU
@@ -987,18 +1225,19 @@ def flash_attention(
     from them — flash attention's memory/FLOPs trade in both directions.
     The kernels tile themselves from the shape (:func:`tile_plan`);
     ``block_q`` / ``block_k`` override it. ``k`` / ``v`` may have fewer
-    heads than ``q`` (grouped queries) and ``window`` bounds how far back a
-    row sees (module docstring).
+    heads than ``q`` (grouped queries), ``window`` bounds how far back a
+    row sees and ``diffusion_block`` masks by block and stream (module
+    docstring).
 
     Thin wrapper over :func:`flash_attention_lse` (the kernel always writes
     the LSE output; discarding it costs nothing, and a zero LSE cotangent
     folds to the identical backward) — ONE custom_vjp to maintain."""
     out, _ = flash_attention_lse(q, k, v, causal, block_q, block_k, scale,
-                                 interpret, window)
+                                 interpret, window, diffusion_block)
     return out
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def flash_attention_lse(
     q: jnp.ndarray,
     k: jnp.ndarray,
@@ -1009,18 +1248,20 @@ def flash_attention_lse(
     scale: Optional[float] = None,
     interpret: bool = False,
     window: Optional[int] = None,
+    diffusion_block: Optional[int] = None,
 ) -> "tuple[jnp.ndarray, jnp.ndarray]":
     """:func:`flash_attention` that ALSO returns the per-row log-sum-exp
     ([B, H, Sq], fp32) — the composable form: outputs of independent KV
-    chunks merge exactly via their LSEs (``ring_attention``'s flash inner).
+    chunks merge exactly via their LSEs (``ring_attention``'s flash inner;
+    the noisy rows' own-block term under ``diffusion_block``).
     Differentiable in both outputs; the LSE cotangent folds into the
     backward kernels' delta term (see ``_bwd_row_stats``)."""
     return _fa_lse_fwd(q, k, v, causal, block_q, block_k, scale,
-                       interpret, window)[0]
+                       interpret, window, diffusion_block)[0]
 
 
 def _fa_lse_fwd(q, k, v, causal, block_q, block_k, scale, interpret,
-                window=None):
+                window=None, diffusion_block=None):
     if not (q.dtype == k.dtype == v.dtype):
         raise TypeError(
             f"flash attention feeds the MXU in the operands' dtype, so "
@@ -1029,20 +1270,62 @@ def _fa_lse_fwd(q, k, v, causal, block_q, block_k, scale, interpret,
         )
     _head_group(q, k, v)
     _check_window(window, causal)
-    plan = _require_plan(q, k, v, causal, block_q, block_k, window)
-    _note_plan(plan, ("fwd",), q, k, v, causal, window)
+    _check_diffusion(diffusion_block, causal, window, q.shape[2], k.shape[2])
+    plan = _require_plan(q, k, v, causal, block_q, block_k, window,
+                         diffusion_block)
+    _note_plan(plan, ("fwd",), q, k, v, causal, window, diffusion_block)
     out, lse = _flash_forward(q, k, v, causal, plan.fwd,
-                              _resolve_scale(q, scale), interpret, window)
+                              _resolve_scale(q, scale), interpret, window,
+                              diffusion_block)
     return (out, lse), (q, k, v, out, lse)
 
 
-def _fa_lse_bwd(causal, block_q, block_k, scale, interpret, window, res, g):
+def _fa_lse_bwd(causal, block_q, block_k, scale, interpret, window,
+                diffusion_block, res, g):
     q, k, v, out, lse = res
     g_out, g_lse = g
-    plan = _require_plan(q, k, v, causal, block_q, block_k, window)
-    _note_plan(plan, ("dkv", "dq"), q, k, v, causal, window)
+    plan = _require_plan(q, k, v, causal, block_q, block_k, window,
+                         diffusion_block)
+    _note_plan(plan, ("dkv", "dq"), q, k, v, causal, window, diffusion_block)
     return _flash_backward(q, k, v, out, lse, g_out, g_lse, causal, plan,
-                           _resolve_scale(q, scale), interpret, window)
+                           _resolve_scale(q, scale), interpret, window,
+                           diffusion_block)
 
 
 flash_attention_lse.defvjp(_fa_lse_fwd, _fa_lse_bwd)
+
+
+def own_block_attention(q, k, v, block: int, scale: Optional[float] = None):
+    """The noisy stream's own-block term of a block-diffusion step: row
+    ``p`` against the keys of its OWN block of ``block`` positions, both
+    directions — ``S / block`` tiles of ``block x block``, ``S x block``
+    pairs beside the kernels' ``S ** 2``, so plain ``jnp`` over ``[.., S /
+    block, block, D]`` (grouped K/V heads repeated: a tile is tiny).
+    ``(out [B, H, S, Dv], lse [B, H, S] float32)``, the pair
+    :func:`merge_by_lse` takes."""
+    B, H, S, D = q.shape
+    group = _head_group(q, k, v)
+    if group > 1:
+        k, v = (jnp.repeat(t, group, axis=1) for t in (k, v))
+    tiles = lambda t: t.reshape(B, H, S // block, block, t.shape[-1])
+    s = jnp.einsum("bhnqd,bhnkd->bhnqk", tiles(q), tiles(k),
+                   preferred_element_type=jnp.float32) * _resolve_scale(
+                       q, scale)
+    m = s.max(axis=-1, keepdims=True)
+    p = jnp.exp(s - m)
+    l = p.sum(axis=-1, keepdims=True)
+    o = jnp.einsum("bhnqk,bhnkd->bhnqd", (p / l).astype(v.dtype), tiles(v),
+                   preferred_element_type=jnp.float32)
+    return (o.reshape(B, H, S, v.shape[-1]).astype(q.dtype),
+            (m + jnp.log(l)).reshape(B, H, S))
+
+
+def merge_by_lse(o_a, lse_a, o_b, lse_b):
+    """Two attention outputs of the same rows over DISJOINT key sets, each
+    normalised over its own keys, as one softmax over both: weights ``exp(lse
+    - logaddexp(lse_a, lse_b))``. A side that saw no key (``lse <= -1e30``)
+    weighs nothing and, through the weight, gets no gradient."""
+    lse = jnp.logaddexp(lse_a, lse_b)
+    w_a, w_b = jnp.exp(lse_a - lse), jnp.exp(lse_b - lse)
+    return (w_a[..., None] * o_a.astype(jnp.float32)
+            + w_b[..., None] * o_b.astype(jnp.float32)).astype(o_a.dtype)

@@ -51,8 +51,9 @@ from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
 VOCABULARY = (
     "table.pull", "table.grad_rows", "table.push",
     "compute",
-    "embed", "blk", "norm", "merge",
-    "mixer.qkv", "mixer.cca", "mixer.rope", "mixer.core", "mixer.out",
+    "embed", "noise", "blk", "norm", "merge",
+    "mixer.qkv", "mixer.cca", "mixer.rope", "mixer.core", "mixer.streams",
+    "mixer.out",
     "kda.proj", "kda.conv", "kda.gate", "kda.scan", "kda.out",
     "ssd.proj", "ssd.conv", "ssd.gate", "ssd.scan", "ssd.out",
     "ffn",

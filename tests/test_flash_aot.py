@@ -75,6 +75,38 @@ def test_flash_kernels_with_a_value_width_compile_for_v5e(one_chip, b, h, s, d,
         jax.grad(loss, argnums=(0, 1, 2)), sd(d), sd(d), sd(dv))] == [d, d, dv]
 
 
+@pytest.mark.parametrize("h,hkv,s,block,dtype", [
+    (32, 4, 8192, 4, jnp.bfloat16),    # the sdar-30b-a3b cell's stacked call
+    (4, 1, 1024, 16, jnp.float32),     # ... a block the sub-blocks align to
+])
+def test_block_diffusion_flash_kernels_compile_for_v5e(one_chip, h, hkv, s,
+                                                       block, dtype):
+    """Both streams' queries stacked (2 s rows a query head) against the
+    clean keys (s rows a K/V head) under the mask by block and stream: the
+    three kernels under the plan of ONE stream's length, grouped heads
+    through the index maps, with the own-block term and the merge by the
+    two log-sum-exps behind them."""
+    from harmony_tpu.ops.attention import merge_by_lse, own_block_attention
+
+    sd = lambda heads, rows: jax.ShapeDtypeStruct(
+        (1, heads, rows, 128), dtype, sharding=one_chip)
+
+    def loss(q, k, v, kn, vn):
+        out, lse = flash_attention_lse(q, k, v, True, diffusion_block=block)
+        own, own_lse = own_block_attention(q[:, :, s:], kn, vn, block)
+        noisy = merge_by_lse(out[:, :, s:], lse[:, :, s:], own, own_lse)
+        return (out[:, :, :s].astype(jnp.float32).sum()
+                + noisy.astype(jnp.float32).sum())
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        sd(h, 2 * s), sd(hkv, s), sd(hkv, s), sd(hkv, s),
+        sd(hkv, s)).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+    for name in ("harmony_flash_bd_fwd", "harmony_flash_bd_bwd_dkv",
+                 "harmony_flash_bd_bwd_dq"):
+        assert name in text, name
+
+
 @pytest.mark.parametrize("m,k,n,groups,dtype", [
     (65536, 2048, 1024, 16, jnp.bfloat16),  # olmoe-1b-7b.solo: gate / up
     (65536, 1024, 2048, 16, jnp.bfloat16),  # ... and down
