@@ -29,23 +29,26 @@ def validate_attn(attn: str) -> str:
 
 
 def flash_ok(seq: int, block: int | None = None, *, head_dim: int = 128,
-             v_head_dim: int | None = None, dtype=jnp.bfloat16) -> bool:
+             v_head_dim: int | None = None, dtype=jnp.bfloat16,
+             group: int = 1, streams: int = 1) -> bool:
     """Can the Pallas flash kernels tile a self-attention over ``seq``
     positions? The kernels' own answer (ops.attention.tile_plan): the gate
     here and the kernel's ValueError cannot disagree. ``block`` is an
     explicit block size the caller will pass to flash_attention (default:
-    none — the kernels choose their tiles from the shape)."""
+    none — the kernels choose their tiles from the shape); ``group`` the
+    query heads a K/V head serves and ``streams`` the copies of the
+    sequence a query head stacks (what the backward keeps resident)."""
     from harmony_tpu.ops.attention import tile_plan
 
     return tile_plan(seq, seq, head_dim, dtype, block_q=block, block_k=block,
-                     dv=v_head_dim) is not None
+                     dv=v_head_dim, group=group, streams=streams) is not None
 
 
 def resolve_attn(attn: str, seq: int, block: int | None = None,
                  **operands) -> str:
     """'auto' -> 'flash' when the program being traced runs on TPUs
     (utils.platform.trace_is_tpu) and the kernels can tile (``operands``:
-    flash_ok's ``head_dim`` / ``v_head_dim`` / ``dtype``), else 'blockwise'. Call at trace
+    flash_ok's ``head_dim`` / ``v_head_dim`` / ``dtype`` / ``group`` / ``streams``), else 'blockwise'. Call at trace
     time."""
     if attn != "auto":
         return attn

@@ -1051,7 +1051,8 @@ class TransformerLM:
         from harmony_tpu.models.common import flash_on_mesh, resolve_attn
 
         attn = resolve_attn(cfg.attn, S, head_dim=q.shape[3],
-                            v_head_dim=v.shape[3], dtype=q.dtype)
+                            v_head_dim=v.shape[3], dtype=q.dtype,
+                            group=q.shape[1] // k.shape[1])
         band = {} if window is None else {"window": window}
         if attn == "flash":  # the kernels tile themselves from the shape
             return flash_on_mesh(q, k, v, causal=True, **band)
@@ -1077,7 +1078,8 @@ class TransformerLM:
         cfg = self.config
         N, L, B = q.shape[0] // 2, q.shape[2], cfg.diffusion_block
         attn = resolve_attn(cfg.attn, L, head_dim=q.shape[3],
-                            v_head_dim=v.shape[3], dtype=q.dtype)
+                            v_head_dim=v.shape[3], dtype=q.dtype,
+                            group=q.shape[1] // k.shape[1], streams=2)
         with step_scope("mixer.streams"):
             stacked = jnp.concatenate([q[:N], q[N:]], axis=2)  # [N, H, 2L, hd]
         if attn == "flash":  # the kernels tile themselves from the shape

@@ -1,4 +1,4 @@
-"""Flash attention for TPU: three Pallas kernels and a differentiable blockwise.
+"""Flash attention for TPU: two Pallas kernels and a differentiable blockwise.
 
 The reference has no attention models at all (SURVEY.md §5.7) — long-context
 support is a first-class extension of this framework, not a port. Two tiers:
@@ -7,19 +7,24 @@ support is a first-class extension of this framework, not a port. Two tiers:
     (lax.scan over KV blocks, O(S) memory). Differentiable by autodiff;
     numerically identical to flash attention. Works on any backend.
   * :func:`flash_attention` — Pallas TPU kernels forward AND backward
-    (``harmony_flash_fwd``, ``harmony_flash_bwd_dkv``, ``harmony_flash_bwd_dq``):
-    online softmax state in VMEM scratch, QK^T and PV on the MXU in the
-    operands' dtype with fp32 accumulation; the forward saves only the
-    per-row log-sum-exp and the backward recomputes each softmax tile from
-    it (the flash-attention trade of FLOPs for HBM traffic).
+    (``harmony_flash_fwd``, ``harmony_flash_bwd``): online softmax state in
+    VMEM scratch, QK^T and PV on the MXU in the operands' dtype with fp32
+    accumulation; the forward saves only the per-row log-sum-exp and the
+    backward recomputes each softmax tile from it (the flash-attention
+    trade of FLOPs for HBM traffic) — ONCE: one backward kernel evaluates a
+    score tile (``q k^T``, ``exp``, ``dO v^T``, ``ds``) and takes all three
+    gradients from it (``p^T dO``, ``ds^T q``, ``ds k``: five products a
+    tile), a query head's whole dQ resident in VMEM beside the K/V tile's
+    dK and dV.
 
 A grid step of a Pallas kernel costs ~0.35 us on a v5e whatever it
 computes, so the kernels choose how much one step does from the shape
-(:func:`tile_plan`): a step holds one RESIDENT tile (q rows for the forward
-and dQ, kv rows for dK/dV) and one STREAMED tile of the other operand — the
-whole sequence where VMEM allows (a windowed dK/dV: the band) — which an
-in-kernel loop walks in sub-blocks. Under a causal mask the loop skips the
-sub-blocks above the diagonal and masks only those the diagonal crosses.
+(:func:`tile_plan`): a step holds one RESIDENT tile (q rows for the
+forward, kv rows for the backward) and one STREAMED tile of the other
+operand — the whole sequence where VMEM allows (a windowed backward: the
+band) — which an in-kernel loop walks in sub-blocks. Under a causal mask the
+loop skips the sub-blocks above the diagonal and masks only those the
+diagonal crosses.
 
 Layout: (batch, heads, seq, head_dim). Any head_dim compiles; VMEM tiles pad
 it to the 128-lane width, so 64 (GPT-2) fills half of each vector register
@@ -28,14 +33,16 @@ and dV) may have a width of its own: latent attention multiplies 192-wide q
 and k and sums 128-wide values, and pays for neither a padded v nor a
 second lowering — equal widths trace the programs they always did.
 
-Two more shapes of the same three kernels, each a branch taken in Python at
+Two more shapes of the same two kernels, each a branch taken in Python at
 trace time, so a call without them traces the program it always did:
 
   * **grouped queries**: ``k`` / ``v`` may have fewer heads than ``q``, a
     divisor of its count; query head ``h`` reads K/V head ``h // (H //
     Hkv)`` through the BlockSpec index map (nothing is repeated in HBM), and
-    the dK/dV kernel's innermost grid axis walks the group's query heads so
-    one K/V head's gradient sums over them in its accumulator;
+    the backward kernel walks the group's query heads on a grid axis OUTSIDE
+    the K/V tiles — a head's dQ stays resident for its whole pass — with
+    the K/V head's dK and dV summed over them in whole-length accumulators
+    and written after the group's last head;
   * **a window** ``W`` (causal only): row ``i`` sees keys ``j`` with ``0 <=
     i - j < W``. The in-kernel loop gains a lower bound (sub-blocks behind
     the window are skipped, those its edge crosses masked), and the
@@ -56,7 +63,7 @@ A third shape, the same way (``diffusion_block``; causal, no window):
     block for clean rows and DOWN for noisy ones (``_seen_until`` /
     ``_seen_from``, the one pair every bound comes from). The sub-block
     loops keep their form with block-rounded bounds, only the sub-blocks the
-    edge crosses are masked (``_apply_block_mask``, shared by the three
+    edge crosses are masked (``_apply_block_mask``, shared by the two
     kernels), tiles divide ONE stream's length so none straddles the two,
     and the index maps clamp by the same bounds. Rows that see no column
     (block 0's noisy rows) leave with output 0 and an LSE of ``-1e30``,
@@ -94,7 +101,7 @@ def _dot_f32_trans_a(a, b):
 
 def _apply_causal_mask(s, row0, col0):
     """Mask one score tile whose first row / column are global positions
-    ``row0`` / ``col0``. Shared by the forward and both backward kernels —
+    ``row0`` / ``col0``. Shared by the forward and the backward kernel —
     they MUST mask identically or gradients silently diverge from the
     forward."""
     # row0 + i >= col0 + j, with the tile-invariant i - j on one side so a
@@ -140,8 +147,8 @@ def _apply_block_mask(s, row0, col0, block, stream):
     """:func:`_apply_causal_mask` with the diagonal's edge rounded to the
     diffusion block: the tile's first row is POSITION ``row0`` of
     ``stream`` (0 clean, 1 noisy) and sees column ``c`` iff ``c // block <=
-    row // block - stream``. Shared by the forward and both backward
-    kernels, as the causal mask is."""
+    row // block - stream``. Shared by the forward and the backward
+    kernel, as the causal mask is."""
     rows = row0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
     cols = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     return jnp.where(cols < _seen_until(rows, block, stream), s, _NEG_INF)
@@ -299,7 +306,7 @@ def _blockwise(q, k, v, causal, block_k, scale, window, diffusion_block,
 class Tiles(NamedTuple):
     """One kernel's tiling. ``block_q`` x ``block_k`` is what a grid step
     holds in VMEM; ``sub`` is the length of the STREAMED tile (kv rows for
-    the forward and dQ, q rows for dK/dV) one in-kernel loop iteration
+    the forward, q rows for the backward) one in-kernel loop iteration
     takes, so the score-sized temporaries are resident-block x ``sub``.
     ``vmem_limit_bytes`` is set only where the tiles need more than
     Mosaic's default scoped VMEM."""
@@ -311,33 +318,29 @@ class Tiles(NamedTuple):
 
 class TilePlan(NamedTuple):
     fwd: Tiles
-    dkv: Tiles
-    dq: Tiles
+    bwd: Tiles
     planned: bool  # False: the caller's explicit blocks, taken as given
 
 
 #: the kernels' names in a device trace (perf/trace_reduce.py reads them) and
 #: in STATUS ``kernel_plans``
-_KERNEL_NAMES = {"fwd": "harmony_flash_fwd", "dkv": "harmony_flash_bwd_dkv",
-                 "dq": "harmony_flash_bwd_dq"}
+_KERNEL_NAMES = {"fwd": "harmony_flash_fwd", "bwd": "harmony_flash_bwd"}
 #: the same kernels under a window: told from the full-causal calls by name
 _WIN_KERNEL_NAMES = {"fwd": "harmony_flash_win_fwd",
-                     "dkv": "harmony_flash_win_bwd_dkv",
-                     "dq": "harmony_flash_win_bwd_dq"}
-
-
+                     "bwd": "harmony_flash_win_bwd"}
 #: ... and under the mask by block and stream (``diffusion_block``)
 _BD_KERNEL_NAMES = {"fwd": "harmony_flash_bd_fwd",
-                    "dkv": "harmony_flash_bd_bwd_dkv",
-                    "dq": "harmony_flash_bd_bwd_dq"}
+                    "bwd": "harmony_flash_bd_bwd"}
 
 
 def kernel_name(kernel: str, window: Optional[int],
                 diffusion_block: Optional[int] = None) -> str:
-    """The trace name of ``"fwd"`` / ``"dkv"`` / ``"dq"``."""
+    """The trace name of ``"fwd"`` / ``"bwd"``."""
     if diffusion_block is not None:
         return _BD_KERNEL_NAMES[kernel]
     return (_KERNEL_NAMES if window is None else _WIN_KERNEL_NAMES)[kernel]
+
+
 _ONE_BLOCK = 256              # a whole length up to this is one block as it is
 _RESIDENT = (512, 256, 128)   # resident-block lengths tried, largest first
 _SUB = (1024, 512, 256, 128)  # sub-block lengths tried, largest first
@@ -345,68 +348,90 @@ _TEMPS_MAX = 6 * 2**20        # score-sized temporaries of one sub-block: past
                               # this a wider sub-block loses on the v5e (PERF.md)
 _VMEM_DEFAULT = 16 * 2**20    # Mosaic's scoped-VMEM default on every TPU so far
 _VMEM_FREE = 12 * 2**20       # estimates up to here run under that default
-_VMEM_CAP = 40 * 2**20        # the most a plan may need (a v5e has 128 MiB)
-_BAND_TILES = 4               # a windowed dK/dV streams q tiles of at most
+_VMEM_CAP = 96 * 2**20        # the most a plan may need: a v5e has 128 MiB, the
+                              # limit asked for is this + ``_VMEM_DEFAULT``
+_BAND_TILES = 4               # a windowed backward streams q tiles of at most
                               # window / this many rows (``_plan_kernel``)
+_PART_ROWS = 2048             # ... and one whose q tile cannot be a head's
+                              # whole rows, tiles of at most this many
+
+
+class _Shape(NamedTuple):
+    """What the VMEM budget reads of a call (all a trace can observe)."""
+    d: int          # q / k head width
+    dv: int         # v head width
+    itemsize: int   # of the operands' dtype
+    q_rows: int     # rows of ONE query head: the backward's resident dQ
+    kv_rows: int    # rows the backward's dK / dV accumulators hold: the K/V
+                    # tile's, or the whole K/V length under grouped heads
 
 
 def _temp_bytes(kernel, resident, sub):
     """The score-sized f32 temporaries one sub-block keeps live: s, p and
-    p's cast in the forward; s/p, dp, ds and two casts in the backward."""
+    p's cast in the forward; s/p, dp, ds and two casts in the backward (the
+    ``ds`` cast feeds both ``ds^T q`` and ``ds k``)."""
     return (3 if kernel == "fwd" else 5) * resident * sub * 4
 
 
-def _vmem_bytes(kernel, block_q, block_k, sub, d, itemsize, dv=None):
+def _vmem_bytes(kernel, block_q, block_k, sub, shape):
     """VMEM one grid step of ``kernel`` needs, in bytes: the BlockSpec tiles
     double-buffered (q/k/dq/dk ``d`` wide, v/o/do/dv ``dv`` wide, in the
     operands' dtype; the lane-replicated statistics in f32), the f32
-    accumulators, and the temporaries of one sub-block. A tile's last dim
-    pads to the lane width in VMEM: a 192-wide tile counts as 256 lanes."""
-    dl = -(-d // _LANES) * _LANES
-    dvl = dl if dv is None else -(-dv // _LANES) * _LANES
-    qk = lambda rows: rows * dl * itemsize
-    vo = lambda rows: rows * dvl * itemsize
+    accumulators, and the temporaries of one sub-block. The backward holds
+    beside its streamed q tile a query head's WHOLE dQ — its output block
+    and an f32 accumulator of ``q_rows`` — and accumulators of ``kv_rows``
+    for dK and dV. A tile's last dim pads to the lane width in VMEM: a
+    192-wide tile counts as 256 lanes."""
+    dl = -(-shape.d // _LANES) * _LANES
+    dvl = -(-shape.dv // _LANES) * _LANES
+    qk = lambda rows: rows * dl * shape.itemsize
+    vo = lambda rows: rows * dvl * shape.itemsize
     stat = lambda rows: rows * _LANES * 4
     if kernel == "fwd":  # q, o | k, v
         tiles = (qk(block_q) + vo(block_q) + qk(block_k) + vo(block_k)
                  + stat(block_q))
         scratch = 2 * stat(block_q) + block_q * dvl * 4
-    elif kernel == "dkv":  # q, do | k, dk, v, dv
+        resident = block_q
+    else:  # q, do, lse, delta | k, dk, v, dv | dq
         tiles = (qk(block_q) + vo(block_q) + 2 * stat(block_q)
-                 + 2 * qk(block_k) + 2 * vo(block_k))
-        scratch = block_k * (dl + dvl) * 4
-    else:  # dq: q, dq, do | k, v
-        tiles = (2 * qk(block_q) + vo(block_q) + 2 * stat(block_q)
-                 + qk(block_k) + vo(block_k))
-        scratch = block_q * dl * 4
-    resident = block_k if kernel == "dkv" else block_q
+                 + 2 * qk(block_k) + 2 * vo(block_k) + qk(shape.q_rows))
+        scratch = (max(shape.kv_rows, block_k) * (dl + dvl) * 4
+                   + shape.q_rows * dl * 4)
+        resident = block_k
     return 2 * tiles + scratch + _temp_bytes(kernel, resident, sub)
 
 
-def _with_limit(kernel, block_q, block_k, sub, d, itemsize, dv):
-    need = _vmem_bytes(kernel, block_q, block_k, sub, d, itemsize, dv)
+def _with_limit(kernel, block_q, block_k, sub, shape):
+    need = _vmem_bytes(kernel, block_q, block_k, sub, shape)
     limit = None if need <= _VMEM_FREE else need + _VMEM_DEFAULT
     return Tiles(block_q, block_k, sub, limit)
 
 
-def _plan_kernel(kernel, sq, sk, d, itemsize, dv, window=None):
+def _plan_kernel(kernel, sq, sk, shape, window=None):
     """Largest tiles that divide the lengths and fit the budget: the
     resident block first, then the widest sub-block whose temporaries stay
     under ``_TEMPS_MAX``, then as much of the streamed length as fits
-    ``_VMEM_CAP`` (the whole of it at every shape a model here runs) — or,
-    for the dK/dV kernel under a window, as much as STREAMS THE BAND: at
-    most ``window / _BAND_TILES`` rows a tile. That kernel's streamed tile
-    is one query head's q, dO and two lane-replicated statistics (1.5 KB a
-    row), fetched again for every K/V tile and every head of a group, so
-    rows outside the band are worth not fetching; the forward and dQ
-    kernels stream K and V, which every q tile and every query head of a
-    group shares — whole, they are fetched once a K/V head, window or not
-    (on the chip at 28 heads over 4 x 16,384 x 128, window 4,096: dK/dV
-    26.9 ms a call with 8,192-row tiles, 16.4 / 13.9 / 12.9 / 13.1 with
-    4,096 / 2,048 / 1,024 / 512; the forward 8.4 ms whole against 9.1-9.3
-    in tiles of 1,024-4,096: PERF.md, PR 36)."""
-    res_len, str_len = (sk, sq) if kernel == "dkv" else (sq, sk)
-    most = str_len if kernel != "dkv" or window is None else \
+    ``_VMEM_CAP`` (the whole of it at every shape a model here runs: 86
+    MiB for 16,384 rows of 28 heads over 4) — or, for the backward under a
+    window, as much as STREAMS THE BAND: at most ``window / _BAND_TILES``
+    rows a tile. That kernel's streamed tile is one query head's q, dO and
+    two lane-replicated statistics (1.5 KB a row), fetched ONCE a head
+    where it is the head's whole rows and otherwise again for every K/V
+    tile, so rows outside the band are worth not fetching — and, where the
+    whole cannot be one tile (the two stacked streams of a block-diffusion
+    call; a length past the cap), neither are rows above the diagonal: at
+    most ``_PART_ROWS`` a tile (on the chip at 32 heads over 4 x 2 x 8,192
+    x 128 under ``diffusion_block``: 22.1 / 20.2 / 19.3 / 19.8 ms a call
+    with 8,192 / 4,096 / 2,048 / 1,024-row tiles: PERF.md, PR 50); the
+    forward streams K and V, which every q tile and
+    every query head of a group shares — whole, they are fetched once a K/V
+    head, window or not (on the chip at 28 heads over 4 x 16,384 x 128,
+    window 4,096: dK/dV 26.9 ms a call with 8,192-row tiles, 16.4 / 13.9 /
+    12.9 / 13.1 with 4,096 / 2,048 / 1,024 / 512; the forward 8.4 ms whole
+    against 9.1-9.3 in tiles of 1,024-4,096: PERF.md, PR 36)."""
+    bwd = kernel == "bwd"
+    res_len, str_len = (sk, sq) if bwd else (sq, sk)
+    most = str_len if not bwd or window is None else \
         max(window // _BAND_TILES, 1)
 
     def divisors(length, sizes):
@@ -421,43 +446,45 @@ def _plan_kernel(kernel, sq, sk, d, itemsize, dv, window=None):
             for n in range(max(min(str_len, most) // sub, 1), 0, -1):
                 if (str_len // sub) % n:
                     continue
-                bq, bk = (n * sub, res) if kernel == "dkv" else (res, n * sub)
-                if _vmem_bytes(kernel, bq, bk, sub, d, itemsize,
-                               dv) <= _VMEM_CAP:
-                    return _with_limit(kernel, bq, bk, sub, d, itemsize, dv)
+                bq, bk = (n * sub, res) if bwd else (res, n * sub)
+                if bwd and window is None and _PART_ROWS < bq < shape.q_rows:
+                    continue
+                if _vmem_bytes(kernel, bq, bk, sub, shape) <= _VMEM_CAP:
+                    return _with_limit(kernel, bq, bk, sub, shape)
     return None
 
 
 def tile_plan(sq, sk, d, dtype, causal=False, block_q=None, block_k=None,
-              dv=None, window=None):
-    """The tiles of the three kernels for q [.., sq, d] against k
+              dv=None, window=None, group=1, streams=1):
+    """The tiles of the two kernels for q [.., sq, d] against k
     [.., sk, d] and v [.., sk, dv] (``dv`` None: ``d``), or None where the
     kernels cannot tile the lengths: a length over ``_ONE_BLOCK`` must
-    divide by 128. The ONE gate: the kernels raise where this returns None,
-    and ``models.common.flash_ok`` asks here.
+    divide by 128, and the backward must fit a query head's whole dQ in
+    VMEM beside its tiles. The ONE gate: the kernels raise where this
+    returns None, and ``models.common.flash_ok`` asks here.
 
     Inputs are what a trace can observe — lengths, both head widths, operand
-    dtype — and the budget is VMEM (``_vmem_bytes``); a plan over Mosaic's
-    default carries ``vmem_limit_bytes`` instead of shrinking. Explicit
-    ``block_q`` / ``block_k`` win over the plan and are taken as given (one
-    sub-block a grid step: the tiling of the interpreter tests and the
-    ring's callers). ``causal`` does not change the tiles: the in-kernel
-    loop bounds carry the causal skip at sub-block grain; a ``window``
-    shortens the dK/dV kernel's streamed tile to the band
-    (``_plan_kernel``) and nothing else."""
+    dtype, the query heads a K/V head serves (``group``), the ``streams`` of
+    ``sq`` rows a query head stacks (2 under ``diffusion_block``) — and the
+    budget is VMEM (``_vmem_bytes``); a plan over Mosaic's default carries
+    ``vmem_limit_bytes`` instead of shrinking. Explicit ``block_q`` /
+    ``block_k`` win over the plan and are taken as given (one sub-block a
+    grid step: the tiling of the interpreter tests and the ring's callers).
+    ``causal`` does not change the tiles: the in-kernel loop bounds carry
+    the causal skip at sub-block grain; a ``window`` shortens the backward's
+    streamed tile to the band (``_plan_kernel``) and nothing else."""
     del causal
-    itemsize = jnp.dtype(dtype).itemsize
-    dv = d if dv is None else dv
+    shape = _Shape(d, d if dv is None else dv, jnp.dtype(dtype).itemsize,
+                   streams * sq, sk if group > 1 else 0)
     if block_q is not None or block_k is not None:
         bq = min(block_q or block_k, sq)  # one given alone stands for both
         bk = min(block_k or block_q, sk)
         if sq % bq or sk % bk:
             return None
-        return TilePlan(_with_limit("fwd", bq, bk, bk, d, itemsize, dv),
-                        _with_limit("dkv", bq, bk, bq, d, itemsize, dv),
-                        _with_limit("dq", bq, bk, bk, d, itemsize, dv), False)
-    tiles = [_plan_kernel(kern, sq, sk, d, itemsize, dv, window)
-             for kern in ("fwd", "dkv", "dq")]
+        return TilePlan(_with_limit("fwd", bq, bk, bk, shape),
+                        _with_limit("bwd", bq, bk, bq, shape), False)
+    tiles = [_plan_kernel(kern, sq, sk, shape, window)
+             for kern in ("fwd", "bwd")]
     return None if None in tiles else TilePlan(*tiles, True)
 
 
@@ -472,13 +499,15 @@ def _require_plan(q, k, v, causal, block_q, block_k, window=None,
         # divide ONE stream's length never straddle the two
         sq = sk
     plan = tile_plan(sq, sk, d, q.dtype, causal, block_q, block_k,
-                     dv=v.shape[3], window=window)
+                     dv=v.shape[3], window=window, group=_head_group(q, k, v),
+                     streams=q.shape[2] // sq)
     if plan is None:
         raise ValueError(
             f"flash attention cannot tile seq lens ({sq},{sk})"
             + (f" by blocks ({block_q},{block_k})"
                if block_q is not None or block_k is not None else
-               f": a length over {_ONE_BLOCK} must divide by {_LANES}"))
+               f": a length over {_ONE_BLOCK} must divide by {_LANES} (and "
+               "a query head's whole dQ fit in VMEM)"))
     return plan
 
 
@@ -628,7 +657,7 @@ def _band_steps(kernel, tiles, sq, sk, window) -> int:
     import numpy as np
 
     nq, nk = sq // tiles.block_q, sk // tiles.block_k
-    if kernel == "dkv":
+    if kernel == "bwd":
         first, last = _q_blocks_of(np.arange(nk), tiles.block_q,
                                    tiles.block_k, window, nq, np)
     else:
@@ -651,8 +680,8 @@ def band_work(kernel, tiles, sq, sk, causal, window, diffusion_block=None):
 
     bq, bk, sub = tiles[:3]
     nq, nk = sq // bq, sk // bk
-    dkv = kernel == "dkv"
-    n_res, n_str, n_sub = (nk, nq, bq // sub) if dkv else (nq, nk, bk // sub)
+    bwd = kernel == "bwd"
+    n_res, n_str, n_sub = (nk, nq, bq // sub) if bwd else (nq, nk, bk // sub)
     if diffusion_block is not None:
         B = diffusion_block
         pos = np.arange(sk)
@@ -662,7 +691,7 @@ def band_work(kernel, tiles, sq, sk, causal, window, diffusion_block=None):
         for i in range(nq):
             stream, p0 = divmod(i * bq, sk)
             for j in range(nk):
-                if dkv:
+                if bwd:
                     lo, full = (int(x) for x in _q_block_ranges(
                         p0, j * bk, bk, sub, n_sub, B, stream, np))
                     ran, cut = n_sub - lo, full - lo
@@ -675,7 +704,7 @@ def band_work(kernel, tiles, sq, sk, causal, window, diffusion_block=None):
                 with_work += ran > 0
         return {"grid_steps": nq * nk, "with_work": with_work,
                 "sub_blocks": run, "masked_sub_blocks": masked,
-                "computed": run * (bk if dkv else bq) * sub, "kept": kept}
+                "computed": run * (bk if bwd else bq) * sub, "kept": kept}
     if not causal:
         run = n_res * n_str * n_sub
         return {"grid_steps": n_res * n_str, "with_work": n_res * n_str,
@@ -687,13 +716,13 @@ def band_work(kernel, tiles, sq, sk, causal, window, diffusion_block=None):
                 - np.clip(rows + 1 - reach, 0, sk)).sum())
     with_work = run = masked = widest = 0
     for r in range(n_res):
-        first, last = (_q_blocks_of(r, bq, bk, reach, nq, np) if dkv else
+        first, last = (_q_blocks_of(r, bq, bk, reach, nq, np) if bwd else
                        _kv_blocks_of(r, bq, bk, reach, nk, np))
         widest = max(widest, int(last) - int(first) + 1)  # _band_steps'
         for s in range(int(first), int(last) + 1):
             a, b, c, d = (int(x) for x in (
                 _q_band_ranges(s * bq, r * bk, bk, sub, n_sub, reach, np)
-                if dkv else
+                if bwd else
                 _kv_band_ranges(r * bq, bq, s * bk, sub, n_sub, reach, np)))
             run += d - a
             masked += (b - a) + (d - c)
@@ -701,13 +730,18 @@ def band_work(kernel, tiles, sq, sk, causal, window, diffusion_block=None):
     steps = n_str if window is None else widest
     return {"grid_steps": steps * n_res, "with_work": with_work,
             "sub_blocks": run, "masked_sub_blocks": masked,
-            "computed": run * (bk if dkv else bq) * sub, "kept": kept}
+            "computed": run * (bk if bwd else bq) * sub, "kept": kept}
+
+
+def _rows_at(start, n):
+    """``n`` rows from ``start``, a multiple of ``n`` (static or traced)."""
+    if isinstance(start, int):
+        return pl.ds(start, n)
+    return pl.ds(pl.multiple_of(start, n), n)
 
 
 def _sub_slice(i, sub):
-    if isinstance(i, int):
-        return pl.ds(i * sub, sub)
-    return pl.ds(pl.multiple_of(i * sub, sub), sub)
+    return _rows_at(i * sub, sub)
 
 
 def _for_sub_blocks(lo, hi, n_sub, body):
@@ -820,7 +854,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
         lse = m_ref[:, :1] + jnp.log(l)
         if bd is not None:
             lse = jnp.where(dead, _NEG_INF, lse)
-        # log-sum-exp per row, consumed by the fused backward. Stored
+        # log-sum-exp per row, consumed by the backward kernel. Stored
         # broadcast across a 128-lane trailing dim: Mosaic requires the last
         # two block dims be (8,128)-tileable, and a (1, block_q) row block is
         # not — the lane-replicated layout is the canonical TPU shape for
@@ -863,7 +897,7 @@ def _last_needed_kv(causal, block_q, block_k, window=None, nk=None,
 
 
 def _first_needed_q(causal, block_q, block_k, nq, window=None, bd=None):
-    """The same clamp for the dK/dV kernel's streamed q tile: steps before
+    """The same clamp for the backward kernel's streamed q tile: steps before
     the first q tile that reaches this KV tile's columns fetch that one
     (the last one where none does: more columns than rows)."""
     if not causal:
@@ -963,44 +997,57 @@ def _bwd_p_ds(q, k, v, do, lse, delta, row0, col0, scale, mask):
     return p, ds
 
 
-def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                       dk_ref, dv_ref, dk_acc, dv_acc, *,
-                       scale, causal, block_q, block_k, sub, window=None,
-                       nq=None, steps=None, bd=None):
-    jk = pl.program_id(1)             # kv tile (this output tile)
-    k0 = jk * block_k
-    iq = pl.program_id(2)             # q tiles stream by
-    if steps is None:
-        q0 = iq * block_q
-    else:
-        # ``steps`` q tiles of each query head of this K/V head's group in
-        # turn; under a window the tile's own first needed q block onward
-        step_i = iq % steps
-        if window is not None:
-            first, last = _q_blocks_of(jk, block_q, block_k, window, nq)
-            step_i = first + step_i
-        q0 = step_i * block_q
+def _fa_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                   dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
+                   scale, causal, block_q, block_k, sub, group, nq, nk,
+                   window=None, bd=None):
+    """dQ, dK and dV from ONE evaluation of each score tile. Grid (K/V head,
+    query head of its group, kv tile, q tiles streaming by): ``dq_acc``
+    holds the query head's whole dQ over its kv tiles; ``dk_acc`` /
+    ``dv_acc`` hold this kv tile's rows (one K/V head a query head) or the
+    K/V head's whole length, summed over the group's heads."""
+    g = pl.program_id(1)              # query head of the K/V head's group
+    jk = pl.program_id(2)             # kv tile (this dK / dV tile)
+    k0 = 0 if nk == 1 else jk * block_k
+    iq = pl.program_id(3)             # q tiles stream by
+    step_i = iq
+    if window is not None:  # the tile's own first needed q block onward
+        first, last = _q_blocks_of(jk, block_q, block_k, window, nq)
+        step_i = first + iq
+    row0 = q0 = 0 if nq == 1 else step_i * block_q  # the tile's first q row
     if bd is not None:  # rows are positions of a stream from here on
         q0, stream, block_mask = _stream_of(q0, bd)
+    kv_rows = _rows_at(0 if group == 1 else k0, block_k)
+    n_sub = block_q // sub
 
-    @pl.when(iq == 0)
-    def _init():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
+    @pl.when(jnp.logical_and(jk == 0, iq == 0))
+    def _init_dq():
+        def zero(i):
+            dq_acc[_sub_slice(i, sub), :] = jnp.zeros((sub, dq_acc.shape[1]),
+                                                      dq_acc.dtype)
+        _for_sub_blocks(0, nq * n_sub, nq * n_sub, zero)
+
+    @pl.when(jnp.logical_and(g == 0, iq == 0))
+    def _init_dkv():
+        dk_acc[kv_rows, :] = jnp.zeros((block_k, dk_acc.shape[1]),
+                                       dk_acc.dtype)
+        dv_acc[kv_rows, :] = jnp.zeros((block_k, dv_acc.shape[1]),
+                                       dv_acc.dtype)
 
     def step(mask):
         def body(i):
             rows = _sub_slice(i, sub)
-            q, do = q_ref[0, rows, :], do_ref[0, rows, :]
+            q, do, k = q_ref[0, rows, :], do_ref[0, rows, :], k_ref[0]
             p, ds = _bwd_p_ds(
-                q, k_ref[0], v_ref[0], do,
+                q, k, v_ref[0], do,
                 lse_ref[0, rows, :1], delta_ref[0, rows, :1],
                 q0 + i * sub, k0, scale, mask)
-            dv_acc[:] += _dot_f32_trans_a(p.astype(do.dtype), do)   # (bk, d)
-            dk_acc[:] += _dot_f32_trans_a(ds.astype(q.dtype), q)    # (bk, d)
+            ds = ds.astype(q.dtype)
+            dv_acc[kv_rows, :] += _dot_f32_trans_a(p.astype(do.dtype), do)
+            dk_acc[kv_rows, :] += _dot_f32_trans_a(ds, q)           # (bk, d)
+            dq_acc[_rows_at(row0 + i * sub, sub), :] += _dot_f32(ds, k)
         return body
 
-    n_sub = block_q // sub
     if bd is not None:
         first, full_from = _q_block_ranges(q0, k0, block_k, sub, n_sub, bd[0],
                                            stream)
@@ -1015,70 +1062,30 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         _for_band(_q_band_ranges(q0, k0, block_k, sub, n_sub, window),
                   step_i <= last, n_sub, step, window)
 
-    @pl.when(iq == pl.num_programs(2) - 1)
-    def _write():
-        dk_ref[0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+    last_q = iq == pl.num_programs(3) - 1
 
+    @pl.when(jnp.logical_and(g == group - 1, last_q))
+    def _write_dkv():
+        dk_ref[0] = (dk_acc[kv_rows, :] * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[kv_rows, :].astype(dv_ref.dtype)
 
-def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                      dq_ref, dq_acc, *, scale, causal, block_q, block_k,
-                      sub, window=None, nk=None, bd=None):
-    iq = pl.program_id(1)             # q tile (this output tile)
-    q0 = iq * block_q
-    ik = pl.program_id(2)             # kv tiles stream by
-    if bd is not None:  # rows are positions of a stream from here on
-        q0, stream, block_mask = _stream_of(q0, bd)
-    if window is None:
-        k0 = ik * block_k
-    else:
-        first, last = _kv_blocks_of(iq, block_q, block_k, window, nk)
-        k0 = (first + ik) * block_k
-
-    @pl.when(ik == 0)
-    def _init():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
-
-    def step(mask):
-        def body(j):
-            cols = _sub_slice(j, sub)
-            k = k_ref[0, cols, :]
-            _, ds = _bwd_p_ds(
-                q_ref[0], k, v_ref[0, cols, :], do_ref[0],
-                lse_ref[0, :, :1], delta_ref[0, :, :1],
-                q0, k0 + j * sub, scale, mask)
-            dq_acc[:] += _dot_f32(ds.astype(k.dtype), k)
-        return body
-
-    n_sub = block_k // sub
-    if bd is not None:
-        n_full, n_need = _kv_block_ranges(q0, block_q, k0, sub, n_sub, bd[0],
-                                          stream)
-        _for_sub_blocks(0, n_full, n_sub, step(None))
-        _for_sub_blocks(n_full, n_need, n_sub, step(block_mask))
-    elif window is None:
-        n_full, n_need = _kv_sub_ranges(causal, q0, block_q, k0, sub, n_sub)
-        _for_sub_blocks(0, n_full, n_sub, step(None))
-        if causal:
-            _for_sub_blocks(n_full, n_need, n_sub, step(_apply_causal_mask))
-    else:
-        _for_band(_kv_band_ranges(q0, block_q, k0, sub, n_sub, window),
-                  first + ik <= last, n_sub, step, window)
-
-    @pl.when(ik == pl.num_programs(2) - 1)
-    def _write():
-        dq_ref[0] = (dq_acc[:] * scale).astype(dq_ref.dtype)
+    @pl.when(jnp.logical_and(jk == nk - 1, last_q))
+    def _write_dq():
+        def write(i):
+            rows = _sub_slice(i, sub)
+            dq_ref[0, rows, :] = (dq_acc[rows, :] * scale).astype(dq_ref.dtype)
+        _for_sub_blocks(0, nq * n_sub, nq * n_sub, write)
 
 
 def _bwd_row_stats(out, lse, do, lse_cotangent):
-    """The backward kernels' per-row inputs, lane-replicated (see _LANES):
+    """The backward kernel's per-row inputs, lane-replicated (see _LANES):
     the saved LSE and delta_i = dO_i . O_i, cheap enough to leave to XLA —
     which materializes the broadcasts; the kernels read lane 0.
 
     ``lse_cotangent`` supports callers that consume the LSE output (the
     ring-attention chunk merge): d lse_r / d s_rc = p_rc, so the extra term
     is ``g_lse_r * p_rc`` — algebraically it folds into the delta:
-    ds = p * (dp - (delta - g_lse)). The kernels are unchanged."""
+    ds = p * (dp - (delta - g_lse)). The kernel is unchanged."""
     B, H, Sq, D = out.shape  # the value width: out and do are v wide
     lsef = jnp.broadcast_to(lse.reshape(B * H, Sq)[:, :, None],
                             (B * H, Sq, _LANES))
@@ -1090,96 +1097,20 @@ def _bwd_row_stats(out, lse, do, lse_cotangent):
     return lsef, jnp.broadcast_to(delta[:, :, None], (B * H, Sq, _LANES))
 
 
-def _flash_bwd_dkv(qf, kf, vf, dof, lsef, delta, causal, tiles, scale,
-                   interpret, window=None, diffusion_block=None):
-    """dK/dV kernel on [B*H, S, D] (q), [B*Hkv, S, D] (k), [B*Hkv, S, Dv]
-    (v) and [B*H, S, Dv] (dO) operands: grid over kv tiles, q tiles stream
-    by — those of every query head of the K/V head's group, one head after
-    another, into the one accumulator; softmax recomputed per tile from the
-    saved LSE."""
-    BH, Sq, D = qf.shape
-    BHkv, Sk, Dv = kf.shape[0], kf.shape[1], vf.shape[2]
-    group = BH // BHkv
-    block_q, block_k, sub = tiles[:3]
-    nq = Sq // block_q
-    steps = nq if window is None else _band_steps("dkv", tiles, Sq, Sk,
-                                                  window)
-    grid = (BHkv, Sk // block_k, group * steps)
-    bd = None if diffusion_block is None else (diffusion_block, Sk)
-    q_i = _first_needed_q(causal, block_q, block_k, nq, window, bd)
-    if group == 1 and window is None:
-        q_row = lambda b, j, i: (b, q_i(j, i), 0)
-        band = {}
-    else:
-        q_row = lambda b, j, i: (b * group + i // steps, q_i(j, i % steps), 0)
-        band = {"window": window, "nq": nq, "steps": steps}
-    if bd is not None:
-        band["bd"] = bd
-    q_spec, do_spec = (pl.BlockSpec((1, block_q, w), q_row) for w in (D, Dv))
-    k_spec, v_spec = (pl.BlockSpec((1, block_k, w), lambda b, j, i: (b, j, 0))
-                      for w in (D, Dv))
-    row_spec = pl.BlockSpec((1, block_q, _LANES), q_row)
-    return pl.pallas_call(
-        functools.partial(_fa_bwd_dkv_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, sub=sub, **band),
-        name=kernel_name("dkv", window, diffusion_block),
-        grid=grid,
-        in_specs=[q_spec, k_spec, v_spec, do_spec, row_spec, row_spec],
-        out_specs=[k_spec, v_spec],
-        out_shape=[
-            _out_struct((BHkv, Sk, D), kf.dtype, qf, kf, vf, dof),
-            _out_struct((BHkv, Sk, Dv), vf.dtype, qf, kf, vf, dof),
-        ],
-        scratch_shapes=[_vmem((block_k, D)), _vmem((block_k, Dv))],
-        interpret=interpret,
-        **_compiler_params(tiles),
-    )(qf, kf, vf, dof, lsef, delta)
-
-
-def _flash_bwd_dq(qf, kf, vf, dof, lsef, delta, causal, tiles, scale,
-                  interpret, window=None, diffusion_block=None):
-    """dQ kernel on the same operands: grid over q tiles, kv tiles stream
-    by."""
-    BH, Sq, D = qf.shape
-    BHkv, Sk, Dv = kf.shape[0], kf.shape[1], vf.shape[2]
-    block_q, block_k, sub = tiles[:3]
-    nk = Sk // block_k
-    grid = (BH, Sq // block_q,
-            nk if window is None else _band_steps("dq", tiles, Sq, Sk,
-                                                  window))
-    band = {} if window is None else {"window": window, "nk": nk}
-    if diffusion_block is not None:
-        band = {"bd": (diffusion_block, Sk)}
-    kv_j = _last_needed_kv(causal, block_q, block_k, **band)
-    kv_b = _kv_head(BH // BHkv)
-    q_spec, do_spec = (pl.BlockSpec((1, block_q, w), lambda b, i, j: (b, i, 0))
-                       for w in (D, Dv))
-    k_spec, v_spec = (pl.BlockSpec((1, block_k, w),
-                                   lambda b, i, j: (kv_b(b), kv_j(i, j), 0))
-                      for w in (D, Dv))
-    row_spec = pl.BlockSpec((1, block_q, _LANES), lambda b, i, j: (b, i, 0))
-    return pl.pallas_call(
-        functools.partial(_fa_bwd_dq_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, sub=sub, **band),
-        name=kernel_name("dq", window, diffusion_block),
-        grid=grid,
-        in_specs=[q_spec, k_spec, v_spec, do_spec, row_spec, row_spec],
-        out_specs=q_spec,
-        out_shape=_out_struct((BH, Sq, D), qf.dtype, qf, kf, vf, dof),
-        scratch_shapes=[_vmem((block_q, D))],
-        interpret=interpret,
-        **_compiler_params(tiles),
-    )(qf, kf, vf, dof, lsef, delta)
-
-
 @functools.partial(jax.jit, static_argnums=(7, 8, 9, 10, 11, 12))
-def _flash_backward(q, k, v, out, lse, do, lse_cotangent, causal, plan,
+def _flash_backward(q, k, v, out, lse, do, lse_cotangent, causal, tiles,
                     scale, interpret, window=None, diffusion_block=None):
-    """Fused flash backward: dK/dV kernel (grid over kv tiles) + dQ kernel
-    (grid over q tiles); softmax recomputed per tile from the saved LSE —
-    the O(S) memory trade the forward made, carried into the backward."""
+    """The flash backward, one kernel: grid (K/V head, query head of its
+    group, kv tile, q tiles streaming by); each score tile's softmax is
+    recomputed from the saved LSE once — the O(S) memory trade the forward
+    made — and gives dV, dK and dQ. A query head's whole dQ is resident
+    (f32) over its kv tiles and written once; under grouped heads dK and dV
+    are resident whole, summed over the group, and each tile written after
+    the group's last head (until then the output's block index stays put,
+    so nothing is written back)."""
     B, H, Sq, D = q.shape
     Hkv, Sk, Dv = k.shape[1], k.shape[2], v.shape[3]
+    group = H // Hkv
     qf = q.reshape(B * H, Sq, D)
     kf = k.reshape(B * Hkv, Sk, D)
     vf = v.reshape(B * Hkv, Sk, Dv)
@@ -1191,10 +1122,42 @@ def _flash_backward(q, k, v, out, lse, do, lse_cotangent, causal, plan,
         # is a constant)
         lse = jnp.where(lse <= _NEG_INF / 2, -_NEG_INF, lse)
     lsef, delta = _bwd_row_stats(out, lse, do, lse_cotangent)
-    dk, dv = _flash_bwd_dkv(qf, kf, vf, dof, lsef, delta, causal, plan.dkv,
-                            scale, interpret, window, diffusion_block)
-    dq = _flash_bwd_dq(qf, kf, vf, dof, lsef, delta, causal, plan.dq,
-                       scale, interpret, window, diffusion_block)
+    block_q, block_k, sub = tiles[:3]
+    nq, nk = Sq // block_q, Sk // block_k
+    steps = nq if window is None else _band_steps("bwd", tiles, Sq, Sk,
+                                                  window)
+    bd = None if diffusion_block is None else (diffusion_block, Sk)
+    q_i = _first_needed_q(causal, block_q, block_k, nq, window, bd)
+    q_row = lambda b, g, j, i: (b * group + g, q_i(j, i), 0)
+    kv_row = lambda b, g, j, i: (b, j, 0)
+    # a K/V tile's gradient is whole after the group's LAST head
+    dkv_row = kv_row if group == 1 else (
+        lambda b, g, j, i: (b, jnp.where(g == group - 1, j, 0), 0))
+    q_spec, do_spec = (pl.BlockSpec((1, block_q, w), q_row) for w in (D, Dv))
+    k_spec, v_spec = (pl.BlockSpec((1, block_k, w), kv_row) for w in (D, Dv))
+    row_spec = pl.BlockSpec((1, block_q, _LANES), q_row)
+    kv_acc = block_k if group == 1 else Sk
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_fa_bwd_kernel, scale=scale, causal=causal,
+                          block_q=block_q, block_k=block_k, sub=sub,
+                          group=group, nq=nq, nk=nk, window=window, bd=bd),
+        name=kernel_name("bwd", window, diffusion_block),
+        grid=(B * Hkv, group, nk, steps),
+        in_specs=[q_spec, k_spec, v_spec, do_spec, row_spec, row_spec],
+        out_specs=[
+            pl.BlockSpec((1, Sq, D), lambda b, g, j, i: (b * group + g, 0, 0)),
+            *(pl.BlockSpec((1, block_k, w), dkv_row) for w in (D, Dv)),
+        ],
+        out_shape=[
+            _out_struct((B * H, Sq, D), q.dtype, qf, kf, vf, dof),
+            _out_struct((B * Hkv, Sk, D), k.dtype, qf, kf, vf, dof),
+            _out_struct((B * Hkv, Sk, Dv), v.dtype, qf, kf, vf, dof),
+        ],
+        scratch_shapes=[_vmem((Sq, D)), _vmem((kv_acc, D)),
+                        _vmem((kv_acc, Dv))],
+        interpret=interpret,
+        **_compiler_params(tiles),
+    )(qf, kf, vf, dof, lsef, delta)
     return (dq.reshape(B, H, Sq, D), dk.reshape(B, Hkv, Sk, D),
             dv.reshape(B, Hkv, Sk, Dv))
 
@@ -1255,7 +1218,7 @@ def flash_attention_lse(
     chunks merge exactly via their LSEs (``ring_attention``'s flash inner;
     the noisy rows' own-block term under ``diffusion_block``).
     Differentiable in both outputs; the LSE cotangent folds into the
-    backward kernels' delta term (see ``_bwd_row_stats``)."""
+    backward kernel's delta term (see ``_bwd_row_stats``)."""
     return _fa_lse_fwd(q, k, v, causal, block_q, block_k, scale,
                        interpret, window, diffusion_block)[0]
 
@@ -1286,8 +1249,8 @@ def _fa_lse_bwd(causal, block_q, block_k, scale, interpret, window,
     g_out, g_lse = g
     plan = _require_plan(q, k, v, causal, block_q, block_k, window,
                          diffusion_block)
-    _note_plan(plan, ("dkv", "dq"), q, k, v, causal, window, diffusion_block)
-    return _flash_backward(q, k, v, out, lse, g_out, g_lse, causal, plan,
+    _note_plan(plan, ("bwd",), q, k, v, causal, window, diffusion_block)
+    return _flash_backward(q, k, v, out, lse, g_out, g_lse, causal, plan.bwd,
                            _resolve_scale(q, scale), interpret, window,
                            diffusion_block)
 

@@ -64,7 +64,9 @@ def a2a_attention(
     from harmony_tpu.utils.platform import trace_is_tpu
 
     if trace_is_tpu() and tile_plan(S, S, D, qh.dtype, causal,
-                                    dv=vh.shape[3]) is not None:
+                                    dv=vh.shape[3],
+                                    group=qh.shape[1] // kh.shape[1]
+                                    ) is not None:
         o = flash_attention(qh, kh, vh, causal=causal, scale=scale)
     else:
         o = blockwise_attention(qh, kh, vh, causal=causal, scale=scale)

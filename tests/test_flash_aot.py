@@ -40,7 +40,7 @@ def one_chip():
 def test_planned_flash_kernels_compile_for_v5e(one_chip, b, h, s, d, dtype,
                                                causal):
     plan = tile_plan(s, s, d, dtype, causal)
-    assert (plan.dkv.vmem_limit_bytes is not None) == (s >= 4096)
+    assert (plan.bwd.vmem_limit_bytes is not None) == (s >= 4096)
     x = jax.ShapeDtypeStruct((b, h, s, d), dtype, sharding=one_chip)
 
     def loss(q, k, v):
@@ -49,7 +49,7 @@ def test_planned_flash_kernels_compile_for_v5e(one_chip, b, h, s, d, dtype,
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         x, x, x).compile()
-    assert compiled.as_text().count("tpu_custom_call") == 3
+    assert compiled.as_text().count("tpu_custom_call") == 2
 
 
 @pytest.mark.parametrize("b,h,s,d,dv,dtype", [
@@ -69,7 +69,7 @@ def test_flash_kernels_with_a_value_width_compile_for_v5e(one_chip, b, h, s, d,
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         sd(d), sd(d), sd(dv)).compile()
-    assert compiled.as_text().count("tpu_custom_call") == 3
+    assert compiled.as_text().count("tpu_custom_call") == 2
     dq, dk, dvv = compiled.output_shardings  # three outputs came back
     assert [o.shape[-1] for o in jax.eval_shape(
         jax.grad(loss, argnums=(0, 1, 2)), sd(d), sd(d), sd(dv))] == [d, d, dv]
@@ -83,7 +83,7 @@ def test_block_diffusion_flash_kernels_compile_for_v5e(one_chip, h, hkv, s,
                                                        block, dtype):
     """Both streams' queries stacked (2 s rows a query head) against the
     clean keys (s rows a K/V head) under the mask by block and stream: the
-    three kernels under the plan of ONE stream's length, grouped heads
+    two kernels under the plan of ONE stream's length, grouped heads
     through the index maps, with the own-block term and the merge by the
     two log-sum-exps behind them."""
     from harmony_tpu.ops.attention import merge_by_lse, own_block_attention
@@ -101,10 +101,69 @@ def test_block_diffusion_flash_kernels_compile_for_v5e(one_chip, h, hkv, s,
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
         sd(h, 2 * s), sd(hkv, s), sd(hkv, s), sd(hkv, s),
         sd(hkv, s)).compile().as_text()
-    assert text.count("tpu_custom_call") == 3
-    for name in ("harmony_flash_bd_fwd", "harmony_flash_bd_bwd_dkv",
-                 "harmony_flash_bd_bwd_dq"):
+    assert text.count("tpu_custom_call") == 2
+    for name in ("harmony_flash_bd_fwd", "harmony_flash_bd_bwd"):
         assert name in text, name
+    assert "harmony_flash_bd_bwd_d" not in text  # ONE backward kernel
+
+
+#: the flash call of every LM cell: (batch, query heads, K/V heads, positions,
+#: q.k width, v width, window, diffusion block) -> the backward's tiles and
+#: the MiB of VMEM its plan counts (``vmem_limit_bytes`` is 16 MiB over that)
+_CELL_CALLS = {
+    "gpt2-124m": ((8, 12, 12, 1024, 64, 64, None, None),
+                  (1024, 512, 512), None),
+    "olmoe-1b-7b": ((2, 16, 16, 4096, 128, 128, None, None),
+                    (4096, 512, 512), 22.5),
+    "moonlight-16b-a3b": ((2, 16, 16, 8192, 192, 128, None, None),
+                          (8192, 512, 512), 51.25),
+    "kimi-linear-48b-a3b": ((1, 8, 8, 8192, 192, 128, None, None),
+                            (8192, 512, 512), 51.25),
+    "nemotron-3-super-120b-a12b": ((1, 4, 1, 8192, 128, 128, None, None),
+                                   (8192, 512, 512), 46.0),
+    "zaya1-8b": ((1, 8, 2, 8192, 128, 128, None, None),
+                 (8192, 512, 512), 46.0),
+    # a head's whole 16,384 rows one streamed tile: fetched once a head
+    "smallthinker-21b-a3b-full": ((1, 28, 4, 16384, 128, 128, None, None),
+                                  (16384, 512, 512), 86.0),
+    "smallthinker-21b-a3b-window": ((1, 28, 4, 16384, 128, 128, 4096, None),
+                                    (1024, 512, 512), 41.0),
+    # two stacked streams cannot be one tile: parts of 2,048 rows
+    "sdar-30b-a3b": ((1, 32, 4, 8192, 128, 128, None, 4),
+                     (2048, 512, 512), 36.0),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_CELL_CALLS))
+def test_fused_flash_backward_compiles_at_each_cells_shape(one_chip, cell):
+    """The ONE backward kernel under its own plan at every LM cell's call,
+    compiled by Mosaic for a v5e: a query head's whole dQ resident beside
+    the tiles (and, under grouped heads, the K/V head's whole dK and dV),
+    under the VMEM limit the plan asks for — a plan that cannot lower or
+    over-asks fails here, not at the driver. No shape takes two kernels."""
+    from harmony_tpu.ops.attention import kernel_name
+
+    (b, h, hkv, s, d, dv, window, block), tiles, mib = _CELL_CALLS[cell]
+    streams = 2 if block else 1
+    plan = tile_plan(s, s, d, jnp.bfloat16, True, dv=dv, window=window,
+                     group=h // hkv, streams=streams)
+    assert plan.bwd[:3] == tiles
+    assert plan.bwd.vmem_limit_bytes == (
+        None if mib is None else (mib + 16) * 2**20)
+    sd = lambda heads, rows, w: jax.ShapeDtypeStruct(
+        (b, heads, rows, w), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        out, lse = flash_attention_lse(q, k, v, True, window=window,
+                                       diffusion_block=block)
+        return out.astype(jnp.float32).sum() + lse.sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        sd(h, streams * s, d), sd(hkv, s, d), sd(hkv, s, dv)
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    name = kernel_name("bwd", window, block)
+    assert name in text and name + "_d" not in text
 
 
 @pytest.mark.parametrize("m,k,n,groups,dtype", [

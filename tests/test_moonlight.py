@@ -312,30 +312,36 @@ def test_equal_widths_give_the_plans_of_today(sq, sk, d, dtype):
     plan = tile_plan(sq, sk, d, dtype, True)
     assert plan == tile_plan(sq, sk, d, dtype, True, dv=d)
     if (sq, d) == (1024, 64):
-        assert [t[:3] for t in plan[:3]] == [
-            (512, 1024, 1024), (1024, 512, 512), (512, 1024, 512)]
-        assert all(t.vmem_limit_bytes is None for t in plan[:3])
+        assert [t[:3] for t in plan[:2]] == [
+            (512, 1024, 1024), (1024, 512, 512)]
+        assert all(t.vmem_limit_bytes is None for t in plan[:2])
     if (sq, d) == (4096, 128):
-        assert [t[:3] for t in plan[:3]] == [
-            (512, 4096, 1024), (4096, 512, 512), (512, 4096, 512)]
-        assert plan.dkv.vmem_limit_bytes == 36175872
+        assert [t[:3] for t in plan[:2]] == [
+            (512, 4096, 1024), (4096, 512, 512)]
+        # 18.5 MiB as the dK/dV kernel had + a head's resident dQ (4096
+        # rows x 128 lanes x (4 + 2 x 2) B = 4 MiB) + Mosaic's 16
+        assert plan.bwd.vmem_limit_bytes == (22.5 + 16) * 2**20
 
 
 def test_the_plan_counts_a_192_wide_tile_as_256_lanes():
     from harmony_tpu.models.common import flash_ok
     from harmony_tpu.ops import attention as A
 
-    for kern in ("fwd", "dkv", "dq"):
-        assert (A._vmem_bytes(kern, 512, 8192, 512, 192, 2, 128)
-                == A._vmem_bytes(kern, 512, 8192, 512, 256, 2, 128))
-        assert (A._vmem_bytes(kern, 512, 8192, 512, 192, 2, 128)
-                < A._vmem_bytes(kern, 512, 8192, 512, 192, 2))
+    shape = lambda d, dv: A._Shape(d, dv, 2, 8192, 0)
+    for kern in ("fwd", "bwd"):
+        assert (A._vmem_bytes(kern, 512, 8192, 512, shape(192, 128))
+                == A._vmem_bytes(kern, 512, 8192, 512, shape(256, 128)))
+        assert (A._vmem_bytes(kern, 512, 8192, 512, shape(192, 128))
+                < A._vmem_bytes(kern, 512, 8192, 512, shape(192, 192)))
     # the cell's call: 8192 positions, (192, 128), bf16 — the whole K and V
     # stay resident under a vmem limit, as at 4096 x 128
     plan = tile_plan(8192, 8192, 192, jnp.bfloat16, True, dv=128)
-    assert [t[:3] for t in plan[:3]] == [
-        (512, 8192, 1024), (8192, 512, 512), (512, 8192, 512)]
-    assert all(t.vmem_limit_bytes for t in plan[:3])
+    assert [t[:3] for t in plan[:2]] == [(512, 8192, 1024), (8192, 512, 512)]
+    assert all(t.vmem_limit_bytes for t in plan[:2])
+    # the backward: 35.25 MiB of tiles, accumulators and temporaries as the
+    # dK/dV kernel had, a head's dQ resident beside them (8192 rows x 256
+    # lanes: 8 MiB f32 + 2 x 4 MiB of output block), Mosaic's 16 on top
+    assert plan.bwd.vmem_limit_bytes == (51.25 + 16) * 2**20
     assert flash_ok(8192, head_dim=192, v_head_dim=128)
     assert not flash_ok(8200, head_dim=192, v_head_dim=128)
 
@@ -360,8 +366,7 @@ def test_both_widths_reach_kernel_plans():
         jax.jit(jax.grad(lambda q, k, v: flash_attention(
             q, k, v, causal=True).astype(jnp.float32).sum())).trace(q, q, v)
     rows = {r["kernel"]: r for r in progcache.kernel_plans()["plan-mla"]}
-    assert set(rows) == {"harmony_flash_fwd", "harmony_flash_bwd_dkv",
-                         "harmony_flash_bwd_dq"}
+    assert set(rows) == {"harmony_flash_fwd", "harmony_flash_bwd"}
     assert all((r["d"], r["dv"]) == (192, 128) for r in rows.values())
     assert rows["harmony_flash_fwd"]["grid_steps"] == 32 * 16
 

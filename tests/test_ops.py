@@ -388,13 +388,13 @@ def test_tile_plan_divides_fits_and_agrees_with_flash_ok(sq, sk, d, dtype):
     if plan is None:
         return
     assert plan.planned
-    itemsize = jnp.dtype(dtype).itemsize
-    for kern in ("fwd", "dkv", "dq"):
+    shape = A._Shape(d, d, jnp.dtype(dtype).itemsize, sq, 0)
+    for kern in ("fwd", "bwd"):
         t = getattr(plan, kern)
         assert sq % t.block_q == 0 and sk % t.block_k == 0
-        streamed = t.block_q if kern == "dkv" else t.block_k
+        streamed = t.block_q if kern == "bwd" else t.block_k
         assert streamed % t.sub == 0
-        need = A._vmem_bytes(kern, t.block_q, t.block_k, t.sub, d, itemsize)
+        need = A._vmem_bytes(kern, t.block_q, t.block_k, t.sub, shape)
         assert need <= A._VMEM_CAP
         if t.vmem_limit_bytes is None:
             assert need <= A._VMEM_FREE < A._VMEM_DEFAULT
@@ -402,8 +402,7 @@ def test_tile_plan_divides_fits_and_agrees_with_flash_ok(sq, sk, d, dtype):
             assert need < t.vmem_limit_bytes
     if sq == sk == 1024 and d == 64:  # the gpt2 cell: 192 grid steps a call
         assert plan.fwd[:3] == (512, 1024, 1024)
-        assert plan.dkv[:3] == (1024, 512, 512)
-        assert plan.dq[:3] == (512, 1024, 512)
+        assert plan.bwd[:3] == (1024, 512, 512)
 
 
 def test_tile_plan_explicit_blocks_win():
@@ -414,8 +413,7 @@ def test_tile_plan_explicit_blocks_win():
                      block_q=128, block_k=256)
     assert not plan.planned
     assert plan.fwd[:3] == (128, 256, 256)     # one sub-block a grid step
-    assert plan.dkv[:3] == (128, 256, 128)
-    assert plan.dq[:3] == (128, 256, 256)
+    assert plan.bwd[:3] == (128, 256, 128)
     # blocks clamp to the length; a length they do not divide cannot tile
     assert tile_plan(64, 64, 16, jnp.float32, block_q=128).fwd[:2] == (64, 64)
     assert tile_plan(200, 200, 64, jnp.bfloat16, block_q=128) is None
@@ -426,6 +424,25 @@ def test_tile_plan_explicit_blocks_win():
     q = jnp.zeros((1, 1, 300, 8))
     with pytest.raises(ValueError, match="must divide by 128"):
         flash_attention(q, q, q, interpret=True)
+
+
+def test_tile_plan_refuses_what_the_backward_cannot_hold():
+    """The backward keeps a query head's WHOLE dQ (and, under grouped
+    heads, the K/V head's whole dK and dV) in VMEM: where that cannot fit
+    beside the smallest tiles there is no plan — by shape alone — and the
+    models' gate says blockwise."""
+    from harmony_tpu.models.common import flash_ok
+    from harmony_tpu.ops import attention as A
+
+    plan = A.tile_plan(65536, 65536, 128, jnp.bfloat16, True)
+    shape = A._Shape(128, 128, 2, 65536, 0)
+    assert A._vmem_bytes("bwd", *plan.bwd[:3], shape) <= A._VMEM_CAP
+    assert plan.bwd.block_q < 65536          # the streamed tile gave way
+    assert plan.bwd.vmem_limit_bytes <= 112 * 2**20 < 128 * 2**20
+    assert A.tile_plan(65536, 65536, 128, jnp.bfloat16, True, group=8) is None
+    assert A.tile_plan(131072, 131072, 128, jnp.bfloat16, True) is None
+    assert flash_ok(65536) and not flash_ok(65536, group=8)
+    assert flash_ok(32768, group=8) and not flash_ok(32768, group=8, streams=4)
 
 
 def _assert_flash_matches_naive(q, k, v, causal, atol=2e-5, gtol=2e-4, **kw):
@@ -499,7 +516,7 @@ def test_flash_causal_skips_and_clamps(sq, sk, bq, bk):
 def test_flash_sub_block_walk_matches_naive(tiles, causal):
     """The in-kernel walk at small sizes: a streamed tile of several
     sub-blocks (skipped / masked / unmasked by the loop bounds) gives what
-    one sub-block a grid step gives, in all three kernels."""
+    one sub-block a grid step gives, in both kernels."""
     from harmony_tpu.ops import attention as A
 
     q, k, v = _qkv_lens(256, 256, 16, bh=2, seed=7)
@@ -510,19 +527,91 @@ def test_flash_sub_block_walk_matches_naive(tiles, causal):
                                 True)
     np.testing.assert_allclose(out, naive_attention(q, k, v, causal),
                                atol=2e-5)
-    plan = A.TilePlan(A.Tiles(bq, bk, sub), A.Tiles(bk, bq, sub),
-                      A.Tiles(bq, bk, sub), True)
-    got = A._flash_backward(q, k, v, out, lse, do, None, causal, plan, scale,
-                            True)
+    got = A._flash_backward(q, k, v, out, lse, do, None, causal,
+                            A.Tiles(bk, bq, sub), scale, True)
     want = jax.vjp(lambda q, k, v: naive_attention(q, k, v, causal),
                    q, k, v)[1](do)
     for a, b in zip(got, want):
         np.testing.assert_allclose(a, b, atol=2e-4)
 
 
+#: name -> (H, Hkv, Sq rows of one stream, Sk, D, Dv, causal, window,
+#: diffusion_block, block_q, block_k, lse cotangent)
+_FUSED_BWD_CASES = {
+    "causal": (2, 2, 128, 128, 16, 16, True, None, None, 32, 64, False),
+    "not-causal-longer-kv": (2, 2, 128, 256, 16, 16, False, None, None, 64,
+                             64, False),
+    "causal-planned": (2, 2, 1024, 1024, 16, 16, True, None, None, None,
+                       None, False),
+    "not-causal-planned": (1, 1, 512, 1024, 16, 16, False, None, None, None,
+                           None, False),
+    "widths-192-128": (2, 2, 128, 128, 192, 128, True, None, None, 64, 64,
+                       False),
+    "widths-192-128-planned": (1, 1, 512, 512, 192, 128, True, None, None,
+                               None, None, True),
+    "group-2": (4, 2, 128, 128, 16, 16, True, None, None, 64, 32, False),
+    "group-7": (7, 1, 128, 128, 16, 16, True, None, None, 32, 32, False),
+    "group-7-planned-not-causal": (7, 1, 512, 512, 16, 16, False, None, None,
+                                   None, None, False),
+    "window-across-tiles": (2, 2, 256, 256, 16, 16, True, 100, None, 64, 32,
+                            False),
+    "window-group-7": (7, 1, 256, 256, 16, 16, True, 100, None, 32, 64,
+                       True),
+    "window-group-2-planned": (4, 2, 1024, 1024, 16, 16, True, 300, None,
+                               None, None, False),
+    "diffusion-block": (2, 2, 64, 64, 16, 16, True, None, 4, 16, 16, False),
+    "diffusion-block-group-4-planned": (4, 1, 128, 128, 16, 16, True, None,
+                                        4, None, None, True),
+    "lse-cotangent": (2, 2, 128, 128, 16, 16, True, None, None, 32, 64,
+                      True),
+    "lse-cotangent-group-2-not-causal": (4, 2, 128, 256, 16, 16, False, None,
+                                         None, 64, 64, True),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_FUSED_BWD_CASES))
+def test_fused_flash_backward_matches_blockwise(name):
+    """The ONE backward kernel (interpret mode): dQ — resident over the kv
+    tiles, under explicit blocks assembled from several q tiles —, dK and
+    dV — summed over a group's query heads, the query head the OUTER axis
+    — against ``jax.grad`` of ``blockwise_attention``; the LSE's cotangent
+    (the ring's path) folds into delta. Under ``diffusion_block`` q stacks
+    both streams and block 0's noisy rows see no key."""
+    from harmony_tpu.ops.attention import (
+        blockwise_attention_lse, flash_attention_lse)
+
+    (h, hkv, sq, sk, d, dv, causal, window, bd, bq, bk,
+     lse_ct) = _FUSED_BWD_CASES[name]
+    rows = sq * (2 if bd else 1)
+    ks = jax.random.split(jax.random.PRNGKey(len(name)), 5)
+    q = jax.random.normal(ks[0], (1, h, rows, d))
+    k = jax.random.normal(ks[1], (1, hkv, sk, d))
+    v = jax.random.normal(ks[2], (1, hkv, sk, dv))
+    w = jax.random.normal(ks[3], (1, h, rows, dv))
+    w_lse = jax.random.normal(ks[4], (1, h, rows)) * float(lse_ct)
+
+    def loss(attend):
+        def fn(q, k, v):
+            out, lse = attend(q, k, v)
+            lse = jnp.where(lse > -1e29, lse, 0.0)  # a row that saw no key
+            return (out * w).sum() + (lse * w_lse).sum()
+        return fn
+
+    got = jax.grad(loss(lambda q, k, v: flash_attention_lse(
+        q, k, v, causal, bq, bk, None, True, window, bd)), (0, 1, 2))(q, k, v)
+    want = jax.grad(loss(lambda q, k, v: blockwise_attention_lse(
+        q, k, v, causal, window=window, diffusion_block=bd)), (0, 1, 2))(
+            q, k, v)
+    for a, b in zip(got, want):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(a, b, atol=2e-4)
+    if bd:  # block 0's noisy rows: no key seen, no gradient
+        assert float(jnp.abs(got[0][:, :, sq:sq + bd]).max()) == 0.0
+
+
 def test_flash_plan_lowers_for_tpu_at_the_gpt2_shape():
-    """fwd + both backward kernels under the plan at the gpt2 cell's own
-    shape cross-lower through the Pallas TPU front end (block shapes,
+    """The forward and the backward kernel under the plan at the gpt2
+    cell's own shape cross-lower through the Pallas TPU front end (block shapes,
     memory spaces, dynamic loop bounds) without a chip."""
     x = jax.ShapeDtypeStruct((8, 12, 1024, 64), jnp.bfloat16)
 
@@ -531,10 +620,10 @@ def test_flash_plan_lowers_for_tpu_at_the_gpt2_shape():
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(x, x, x).lower(
         lowering_platforms=("tpu",)).as_text()
-    assert text.count("tpu_custom_call") == 3
-    for name in ("harmony_flash_fwd", "harmony_flash_bwd_dkv",
-                 "harmony_flash_bwd_dq"):
+    assert text.count("tpu_custom_call") == 2
+    for name in ("harmony_flash_fwd", "harmony_flash_bwd"):
         assert name in text
+    assert "harmony_flash_bwd_d" not in text  # ONE backward kernel
 
 
 def test_flash_plan_is_recorded_at_trace_time():
@@ -551,8 +640,10 @@ def test_flash_plan_is_recorded_at_trace_time():
     assert rows["harmony_flash_fwd"] == {
         "kernel": "harmony_flash_fwd", "block_q": 512, "block_k": 1024,
         "sub": 1024, "planned": True, "d": 64, "dv": 64, "grid_steps": 192}
-    assert rows["harmony_flash_bwd_dkv"]["grid_steps"] == 192
-    assert rows["harmony_flash_bwd_dq"]["sub"] == 512
+    assert set(rows) == {"harmony_flash_fwd", "harmony_flash_bwd"}
+    assert rows["harmony_flash_bwd"] == {
+        "kernel": "harmony_flash_bwd", "block_q": 1024, "block_k": 512,
+        "sub": 512, "planned": True, "d": 64, "dv": 64, "grid_steps": 192}
 
 
 # -- the router's selection: harmony_top_k_rows (ops/top_k_rows.py) ----------

@@ -62,6 +62,18 @@ globals().update({name: obj for name, obj in vars(_perf).items()
                   and name != "test_rehearsal_runs_to_a_correct_line"})
 
 
+def test_work_functions_count_the_stacked_call():  # noqa: F811
+    """``perf/tests/test_sdar.py``'s check of the work file, whose LAST line
+    holds the file's kernel names to the program's: they parted when the
+    backward became one kernel (PR 50; the work files are the benchmark's,
+    and a ``benchmark`` PR gives ``harmony_flash_bd_bwd`` its five
+    products). Every line before that one still has to hold."""
+    with pytest.raises(KeyError, match="dkv"):
+        _perf.test_work_functions_count_the_stacked_call()
+    assert A.kernel_name("fwd", None, 4) in _perf.WORK.KERNELS
+    assert A.kernel_name("bwd", None, 4) not in _perf.WORK.KERNELS
+
+
 def _config(app):
     return TransformerConfig(**{k: v for k, v in app.items() if k in FIELDS})
 
@@ -308,7 +320,7 @@ def test_block_zeros_noisy_rows_see_no_clean_key(tier):
     assert float(jnp.abs(dq[:, :, :L]).max()) == 0.0
 
 
-@pytest.mark.parametrize("kernel", ["fwd", "dkv", "dq"])
+@pytest.mark.parametrize("kernel", ["fwd", "bwd"])
 @pytest.mark.parametrize("L,B,bq,bk", TILINGS)
 def test_band_work_counts_what_the_mask_keeps(kernel, L, B, bq, bk):
     plan = A.tile_plan(L, L, 16, jnp.float32, True, bq, bk)
@@ -321,11 +333,15 @@ def test_band_work_counts_what_the_mask_keeps(kernel, L, B, bq, bk):
 
 def test_the_cells_tiles_discard_under_15_percent():
     """At the cell's shape the mask discards 11.1% of what the forward
-    computes and 5.9% of either backward kernel — a causal call of the same
+    computes and 5.9% of the backward kernel's — a causal call of the same
     tiles 11.1% / 5.9%: the block's edge adds at most B - 1 columns a row."""
     L, B = 8192, 4
-    plan = A.tile_plan(L, L, 128, jnp.bfloat16, True)
-    for kernel, most in (("fwd", 0.1112), ("dkv", 0.0589), ("dq", 0.0589)):
+    plan = A.tile_plan(L, L, 128, jnp.bfloat16, True, group=8, streams=2)
+    # a head's 16,384 stacked rows cannot be ONE tile (a tile lies in one
+    # stream): short tiles, so rows above the diagonal are not fetched
+    assert plan.bwd[:3] == (2048, 512, 512)
+    assert plan.bwd.vmem_limit_bytes == (36 + 16) * 2**20
+    for kernel, most in (("fwd", 0.1112), ("bwd", 0.0589)):
         tiles = getattr(plan, kernel)
         work = A.band_work(kernel, tiles, 2 * L, L, True, None, B)
         share = 1 - work["kept"] / work["computed"]
@@ -362,8 +378,7 @@ def test_the_flash_gauges_carry_the_new_kernels_names():
     share = {l["kernel"]: x for _, l, x in
              fams["harmony_flash_masked_share"]["samples"]
              if l["job"] == "sdar-gauges"}
-    assert set(share) == {"harmony_flash_bd_fwd", "harmony_flash_bd_bwd_dkv",
-                          "harmony_flash_bd_bwd_dq"}
+    assert set(share) == {"harmony_flash_bd_fwd", "harmony_flash_bd_bwd"}
     assert all(v == pytest.approx(0.2) for v in share.values())  # 1 - 4096/5120
     elements = {l["kernel"]: x for _, l, x in
                 fams["harmony_flash_score_elements"]["samples"]
@@ -636,9 +651,9 @@ def test_the_lowered_step_holds_the_kernels_and_no_square_array():
             params, batch).lower(lowering_platforms=("tpu",)).as_text()
     finally:
         platform.mesh_is_tpu = real
-    for name in ("harmony_flash_bd_fwd", "harmony_flash_bd_bwd_dkv",
-                 "harmony_flash_bd_bwd_dq"):
+    for name in ("harmony_flash_bd_fwd", "harmony_flash_bd_bwd"):
         assert name in text, name
+    assert "harmony_flash_bd_bwd_d" not in text  # ONE backward kernel
     assert "harmony_flash_fwd" not in text  # and no causal call beside them
     shapes = set(re.findall(r"tensor<([0-9x]+)x(?:f32|bf16|f16|i1)>", text))
     square = [s for s in shapes

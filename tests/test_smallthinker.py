@@ -122,12 +122,14 @@ def _kernel_case(name):
     return got, want
 
 
-@pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv"])
+@pytest.mark.parametrize("output", ["fwd", "dq", "dkv"])
 @pytest.mark.parametrize("name", sorted(CASES))
 def test_each_kernel_equals_blockwise_with_the_same_window_and_groups(
-        name, kernel):
+        name, output):
+    """The forward's output, and the one backward kernel's dQ and its dK /
+    dV, a case each."""
     got, want = _kernel_case(name)
-    for i in {"fwd": (0,), "dq": (1,), "dkv": (2, 3)}[kernel]:
+    for i in {"fwd": (0,), "dq": (1,), "dkv": (2, 3)}[output]:
         assert got[i].shape == want[i].shape
         assert np.isfinite(np.asarray(got[i])).all()
         _close(got[i], want[i], 1e-5)
@@ -148,7 +150,7 @@ def test_blockwise_window_and_groups_equal_the_explicit_mask():
            want, 1e-5)
 
 
-@pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv"])
+@pytest.mark.parametrize("kernel", ["fwd", "bwd"])
 @pytest.mark.parametrize("S,W,tiles", [
     (1024, 256, (128, 256, 128)), (1024, 100, (256, 512, 256)),
     (512, None, (128, 512, 128)), (2048, 512, (512, 2048, 1024))])
@@ -157,7 +159,7 @@ def test_band_work_counts_what_the_mask_keeps(kernel, S, W, tiles):
     run cover every kept element, and a window runs fewer than the
     triangle."""
     bq, bk, sub = tiles
-    t = A.Tiles(*((bk, bq, sub) if kernel == "dkv" else tiles))
+    t = A.Tiles(*((bk, bq, sub) if kernel == "bwd" else tiles))
     work = A.band_work(kernel, t, S, S, True, W)
     ahead = np.arange(S)[:, None] - np.arange(S)[None, :]
     keep = (ahead >= 0) & (ahead < (W or S))
@@ -414,7 +416,7 @@ def test_windowed_calls_carry_their_own_names_and_plan_columns(monkeypatch):
     with trace_span("job.build_step", job_id="plan-swa"):
         text = jax.jit(jax.grad(lm.loss)).trace(params, toks).lower(
             lowering_platforms=("tpu",)).as_text()
-    for kern in ("fwd", "dkv", "dq"):
+    for kern in ("fwd", "bwd"):
         assert A._KERNEL_NAMES[kern] in text
         assert A._WIN_KERNEL_NAMES[kern] in text
     rows = {r["kernel"]: r for r in progcache.kernel_plans()["plan-swa"]}
@@ -443,35 +445,43 @@ def test_windowed_calls_carry_their_own_names_and_plan_columns(monkeypatch):
 #: and index maps included), and the lowered StableHLO with each Mosaic kernel's serialized body cut out
 #: (it carries source lines). The new fields at their defaults must trace
 #: these programs; a PR that means to change one records it again here.
+# ALL EIGHT RECORDED AGAIN BY PR 50, which meant to change every one: the
+# flash backward is ONE kernel (``harmony_flash_bwd``: dQ resident beside
+# dK / dV) where ``_bwd_dkv`` and ``_bwd_dq`` stood, in every model that
+# attends. The parent's (commit db33bdf), in the order below: 39d757c8d62c52d0
+# / f24e521c6f1b759e, 2072f548af7248f4 / 4b53909c4639758e, c5d9c8027f4acd6d /
+# 83dc38593d13b721, 2281e4f1a27b6ac2 / 149e47f8d1a77ef0, 35221c1eb04af432 /
+# 715a9f21cc4f8472, bf4fd3bcc0118c99 / cb972f89dd457a6d, bf626cc9ea895962 /
+# be5b78b8fdfb037f, a8edc618dcb24dcd / f1350c827e6a9a11
 PARENT_PROGRAMS = {
-    "gpt2-124m": ("39d757c8d62c52d0", "f24e521c6f1b759e"),
+    "gpt2-124m": ("660f2021a869c9ab", "8a1a3c43bb09c3ac"),
     # every expert model RECORDED AGAIN BY PR 43, which meant to change these
     # seven and not gpt2's: the router's selection is ``harmony_top_k_rows``
     # (ops/top_k_rows.py) where ``lax.top_k`` + ``take_along_axis`` stood.
     # The parent's (commit f8cd6be), in this order: 99ea9dafb2bff55d /
     # 4c3e6855a920bbd3, 64405e693a7be5ee / dc4d831f97c232bb, 4237db3deb745649
     # / f6de1660c093e2b3
-    "olmoe-1b-7b": ("2072f548af7248f4", "4b53909c4639758e"),
-    "moonlight-16b-a3b": ("c5d9c8027f4acd6d", "83dc38593d13b721"),
+    "olmoe-1b-7b": ("75ceda348ce3154c", "d43e530dff62a5a3"),
+    "moonlight-16b-a3b": ("531f0959486902da", "53d4b25acadbb869"),
     # Kimi Linear's two RECORDED AGAIN BY PR 46, which meant to change them
     # and no other: the KDA forward kernel also writes (I + A)^-1 and the
     # backward's body is hand-derived around it (ops/kda.py). The parent's
     # (commit eba0826): 613a652ead1e7c25 / 822ef39f72cedb41 and, chunked,
     # 900b195c6eb54df7 / 76c2b3e32f6b2c41
-    "kimi-linear-48b-a3b": ("2281e4f1a27b6ac2", "149e47f8d1a77ef0"),
+    "kimi-linear-48b-a3b": ("396810f4ec5de066", "cd739747aab22e0c"),
     # the same two with 8 of 64 experts held, top-4: the chunked expert
     # layer and its hand-written backward (the rehearse presets hold half
     # their experts and take the full-length pass); PR 37 recorded these two
     # when the layer's row sums became the kernel of ops/sum_rows.py (the
     # parent's of PR 43: a4926d2815adc9a1 / b95e5090d1b8c013 and
     # 5e16810afaf161cd / a87befccf5a25f54)
-    "moonlight-16b-a3b+chunked": ("35221c1eb04af432", "715a9f21cc4f8472"),
-    "kimi-linear-48b-a3b+chunked": ("bf4fd3bcc0118c99", "cb972f89dd457a6d"),
+    "moonlight-16b-a3b+chunked": ("61b6c3772b338a23", "c34d611c3e92c6d3"),
+    "kimi-linear-48b-a3b+chunked": ("dc40c99d2568e5b5", "9af63f44b4b6e150"),
     # SmallThinker's own preset (the parent's of PR 43: ca2c218290bfadc4 /
     # f29d583db0040dd7 and 70aaf04974a21872 / cb19e362794a5553, recorded on
     # the parent of PR 38)
-    "smallthinker-21b-a3b": ("bf626cc9ea895962", "be5b78b8fdfb037f"),
-    "smallthinker-21b-a3b+chunked": ("a8edc618dcb24dcd", "f1350c827e6a9a11"),
+    "smallthinker-21b-a3b": ("2188ab4c6c19546e", "912e8e5990a52d30"),
+    "smallthinker-21b-a3b+chunked": ("d0ab4711e80a87c5", "beee8b690cf0657b"),
 }
 CHUNKED = {"moe_experts": 64, "moe_top_k": 4, "moe_experts_held": 8}
 
