@@ -29,6 +29,7 @@ compute-heavy and network-heavy spans (ref: LocalTaskUnitScheduler.java:
 from __future__ import annotations
 
 import contextlib
+import functools
 import logging
 import os
 import threading
@@ -358,7 +359,18 @@ class WorkerTasklet:
         # timers — are inside the wall they are phases of.
         self._phase_ctl = {"grant_wait": 0.0, "probe": 0.0,
                            "bookkeeping": 0.0}
-        self._budget_mark: Optional[float] = None
+        self._budget_mark: Optional[int] = None  # time.monotonic_ns()
+        # the window ledger (metrics/phases.py): the training thread's
+        # SELF seconds under each light span since the last feed, by the
+        # spans' own clock reads (_span_acc); _span_total is every second
+        # a tracked span has covered so far, which is how a span that
+        # closes knows what closed inside it. _window counts the drained
+        # windows of this attempt, _compile_mark is the job's JAX compile
+        # seconds at the last feed.
+        self._win_spans: Dict[str, float] = {}
+        self._span_total = 0.0
+        self._window = 0
+        self._compile_mark = 0.0
         # drained step VECTORS (tokens per expert ...) waiting for the
         # trainer's counters: see _observe_vector_backlog
         self._vector_backlog: List[Dict[str, np.ndarray]] = []
@@ -1516,7 +1528,8 @@ class WorkerTasklet:
         ctx = self.ctx
         with job_stage(self.job_id, "build_step"):
             self._build_step()
-        self._budget_mark = time.perf_counter()
+        self._budget_mark = time.monotonic_ns()
+        self._compile_mark = self._compile_seconds()
         # job.first_window: from here to the end of the first drained
         # window's bookkeeping — when the job's counters first move
         first_window = contextlib.ExitStack()
@@ -1560,14 +1573,17 @@ class WorkerTasklet:
                         # jobs: the probe is chief-only, and a chief-only
                         # unit would misalign the multi-worker quorum's
                         # per-worker seq streams
-                        scope = (self._taskunit_scope("CPU")
-                                 if self.ctx.num_workers == 1
-                                 else contextlib.nullcontext())
-                        with trace_span("dolphin.comm_probe",
-                                        job_id=self.job_id, epoch=epoch):
-                            with self._turn(), scope:
+                        with trace_span(
+                                "dolphin.comm_probe", job_id=self.job_id,
+                                epoch=epoch,
+                                acc=self._span_acc("dolphin.comm_probe")):
+                            with self._turn(), (
+                                    self._taskunit_scope("CPU")
+                                    if self.ctx.num_workers == 1
+                                    else contextlib.nullcontext()):
                                 self._timed_probe(first)
             window = self._epoch_window_len(epoch, params.num_epochs)
+            batch_idx0 = global_batch_idx
             if window > 1:
                 # Multi-epoch window: dispatches chain on the table state
                 # with trainer hooks run between them (declared windowable
@@ -1585,6 +1601,7 @@ class WorkerTasklet:
                     worker_id=self.ctx.worker_id,
                     epoch=epoch,
                     epochs=window,
+                    window=self._window,
                     fused=self._use_fused_epoch(),
                 ):
                     if self._use_fused_epoch():
@@ -1602,10 +1619,12 @@ class WorkerTasklet:
                         )
                 # an epoch's wall is the window's / k, so the window's
                 # control seconds are fed / k too (as drain_t / k is)
-                wall, ctl = self._take_budget_feed(window)
+                wall, ctl = self._take_budget_feed(
+                    window, epoch, global_batch_idx - batch_idx0)
                 with trace_span("window.bookkeeping", job_id=self.job_id,
                                 epoch=epoch, epochs=window,
-                                acc=self._add_bookkeeping):
+                                acc=self._span_acc("window.bookkeeping",
+                                                   self._add_bookkeeping)):
                     for j, (epoch_examples, last_metrics, nb) in enumerate(
                             results):
                         # account THIS epoch's ops just before its callback
@@ -1634,6 +1653,7 @@ class WorkerTasklet:
                 job_id=self.job_id,
                 worker_id=self.ctx.worker_id,
                 epoch=epoch,
+                window=self._window,
                 fused=self._use_fused_epoch(),
             ) as span:
                 if self._use_fused_epoch():
@@ -1651,10 +1671,12 @@ class WorkerTasklet:
                     span.discard()
             if epoch_examples == 0 and stop:
                 break  # stopped before any batch: not an epoch at all
-            wall, ctl = self._take_budget_feed(1)
+            wall, ctl = self._take_budget_feed(
+                1, epoch, global_batch_idx - batch_idx0)
             with trace_span("window.bookkeeping", job_id=self.job_id,
                             epoch=epoch, epochs=1,
-                            acc=self._add_bookkeeping):
+                            acc=self._span_acc("window.bookkeeping",
+                                               self._add_bookkeeping)):
                 self._finish_epoch(epoch, epoch_t0, epoch_examples,
                                    last_metrics, epoch_losses,
                                    budget_wall=wall, budget_ctl=ctl)
@@ -1743,7 +1765,8 @@ class WorkerTasklet:
             # without per-step syncs; smear the epoch's work time (barrier
             # waits excluded) evenly — averages feeding the optimizer stay
             # right, per-batch variance is deliberately given up.
-            with trace_span("drain.emit", acc=self._add_bookkeeping):
+            with trace_span("drain.emit", acc=self._span_acc(
+                    "drain.emit", self._add_bookkeeping)):
                 last_metrics = self._emit_batch_metrics(
                     epoch, host, batch_sizes, work_t / len(pending),
                     dispatch_sec=dispatch_sec,
@@ -1804,7 +1827,8 @@ class WorkerTasklet:
         # between two epochs' dispatches the host puts the hyperparameters
         # on the device and opens the next batch stream: a light span, so a
         # device that runs dry here says so
-        with trace_span("epoch.turnover", record=False):
+        with trace_span("epoch.turnover", record=False,
+                        acc=self._span_acc("epoch.turnover")):
             hyper = self._hyper()
             it = self._epoch_batch_stream(epoch)
             nxt = next(it, None)
@@ -1816,8 +1840,10 @@ class WorkerTasklet:
                         # turn (a separate probe turn would skew the cycle by
                         # one turn per probe epoch, unboundedly across epochs)
                         first, self._pending_probe = self._pending_probe, None
-                        with trace_span("dolphin.comm_probe",
-                                        job_id=self.job_id, epoch=epoch):
+                        with trace_span(
+                                "dolphin.comm_probe", job_id=self.job_id,
+                                epoch=epoch,
+                                acc=self._span_acc("dolphin.comm_probe")):
                             self._timed_probe(first)
                     if self.batch_barrier is not None:  # SYNC TaskUnit
                         stop = self.batch_barrier(global_batch_idx)
@@ -1836,7 +1862,9 @@ class WorkerTasklet:
                         while nxt is not None and done < group:
                             batch_idx, batch, staged = nxt
                             t0 = time.perf_counter()
-                            with trace_span("step.dispatch", record=False):
+                            with trace_span(
+                                    "step.dispatch", record=False,
+                                    acc=self._span_acc("step.dispatch")):
                                 metrics = self._dispatch_batch(
                                     batch_idx, batch, hyper, staged
                                 )
@@ -1846,8 +1874,10 @@ class WorkerTasklet:
                                 # Sliding window: block on the OLDEST
                                 # outstanding step so the device queue stays
                                 # full.
-                                with trace_span("step.backpressure",
-                                                record=False):
+                                with trace_span(
+                                        "step.backpressure", record=False,
+                                        acc=self._span_acc(
+                                            "step.backpressure")):
                                     jax.block_until_ready(
                                         pending[len(pending) - cap])
                             # dt spans dispatch AND the backpressure sync: on
@@ -1919,7 +1949,8 @@ class WorkerTasklet:
         # that inverts a rendezvous and aborts the process
         # (parallel/dispatch.py). The D2H copies below stay outside.
         combined = None
-        with trace_span("drain.stack"), self.ctx.model_table._lock:
+        with trace_span("drain.stack", acc=self._span_acc("drain.stack")), \
+                self.ctx.model_table._lock:
             with dispatch_scope(self.mesh) as finish:
                 stacked = finish({
                     k: [jnp.stack([m[k] for m in r]) for r in runs]
@@ -1951,7 +1982,7 @@ class WorkerTasklet:
         # group's transfer is asked for NOW, so each starts as its data
         # lands: asked for one after another once the window is done, three
         # groups were three round trips of device idle (4.5 ms a drain)
-        with trace_span("drain.d2h"):
+        with trace_span("drain.d2h", acc=self._span_acc("drain.d2h")):
             for _, arr in (combined or {}).values():
                 arr.copy_to_host_async()
             if combined is not None:
@@ -1985,7 +2016,8 @@ class WorkerTasklet:
             )
             per_epoch.append((pending, sizes, examples, work_t,
                               self._take_dispatch_sec()))
-            with trace_span("epoch.turnover", record=False):
+            with trace_span("epoch.turnover", record=False,
+                            acc=self._span_acc("epoch.turnover")):
                 # next epoch's producer overlaps either the next dispatch
                 # run (j+1 < k) or the window drain below
                 self._spawn_next_pipeline(first_epoch + j + 1)
@@ -2011,7 +2043,8 @@ class WorkerTasklet:
         # per-batch records, the ledger and the work split of k epochs:
         # host bookkeeping the device does not wait for — unless both
         # tenants do it at once
-        with trace_span("drain.emit", acc=self._add_bookkeeping):
+        with trace_span("drain.emit", acc=self._span_acc(
+                "drain.emit", self._add_bookkeeping)):
             for pending, sizes, examples, work_t, disp_t in per_epoch:
                 nb = len(pending)
                 last: Dict[str, float] = {}
@@ -2045,18 +2078,66 @@ class WorkerTasklet:
         finally:
             self._phase_ctl["probe"] += time.perf_counter() - t0
 
-    def _take_budget_feed(self, k: int) -> Tuple[float, Dict[str, float]]:
+    def _span_acc(self, name: str, also=None):
+        """The ``acc`` of a span of the window ledger's vocabulary, made
+        where the span OPENS: when it closes, its seconds less those of
+        the tracked spans that closed meanwhile — its self time — are
+        added under ``name``, and ``also`` (a phase accumulator that was
+        the span's ``acc`` before) gets the whole of them. No clock read
+        beside the span's own."""
+        return functools.partial(self._add_span, name, self._span_total,
+                                 also)
+
+    def _add_span(self, name: str, opened_at: float, also, sec: float) -> None:
+        own = sec - (self._span_total - opened_at)
+        self._span_total = opened_at + sec
+        self._win_spans[name] = self._win_spans.get(name, 0.0) + own
+        if also is not None:
+            also(sec)
+
+    def _compile_seconds(self) -> float:
+        """JAX's compile seconds so far under this job's spans
+        (``harmony_compile_seconds_total``, all stages)."""
+        return float(progcache.compiles_by_job().get(
+            self.job_id, {}).get("seconds", 0.0))
+
+    def _take_budget_feed(self, k: int, epoch: int, steps: int
+                          ) -> Tuple[float, Dict[str, float]]:
         """``(wall per epoch, control phases per epoch)`` of the ``k``
-        epochs that just ran: the wall since the last feed's end and the
-        control seconds accumulated since, both / k; resets both."""
-        now = time.perf_counter()
+        epochs from ``epoch`` that just ran: the wall since the last
+        feed's end and the control seconds accumulated since, both / k;
+        resets both. The same stretch, whole, is the window ledger's
+        record (metrics/phases.py): closed here, judged by the store, and
+        counted by the chief."""
+        now = time.monotonic_ns()
         mark = self._budget_mark if self._budget_mark is not None else now
         self._budget_mark = now
         k = max(int(k), 1)
         ctl = {p: v / k for p, v in self._phase_ctl.items()}
         for p in self._phase_ctl:
             self._phase_ctl[p] = 0.0
-        return (now - mark) / k, ctl
+        wall = (now - mark) * 1e-9
+        spans, self._win_spans = self._win_spans, {}
+        try:  # the ledger must never fail the epoch boundary
+            from harmony_tpu.metrics.phases import budget, count_window
+
+            compiled = self._compile_seconds()
+            record = {
+                "window": self._window, "epoch": int(epoch), "epochs": k,
+                "steps": int(steps), "start_ns": mark, "end_ns": now,
+                "wall_s": wall, "spans": spans,
+                "compile_s": compiled - self._compile_mark,
+                "first": self._window == 0,
+            }
+            self._compile_mark = compiled
+            stall = budget().observe_window(
+                self.job_id, self.attempt_key, self.ctx.worker_id, record)
+            if self.global_init:  # per JOB, as _check_slo is
+                count_window(self.job_id, record, stall)
+        except Exception:
+            pass
+        self._window += 1
+        return wall / k, ctl
 
     def _take_dispatch_sec(self) -> float:
         """Drain the host-dispatch accumulator (one epoch's placement
@@ -2074,7 +2155,8 @@ class WorkerTasklet:
         phase."""
         backlog, self._vector_backlog = self._vector_backlog, []
         for vectors in backlog:
-            with trace_span("drain.vectors", acc=self._add_bookkeeping):
+            with trace_span("drain.vectors", acc=self._span_acc(
+                    "drain.vectors", self._add_bookkeeping)):
                 self.trainer.observe_step_vectors(self.job_id, vectors)
 
     def _emit_batch_metrics(
@@ -2440,21 +2522,6 @@ class WorkerTasklet:
                 loss=progress,
             )
         )
-        try:  # per-tenant epoch-time histogram for /metrics scrapers
-            from harmony_tpu.metrics.registry import (
-                EPOCH_TIME_BUCKETS,
-                get_registry,
-            )
-
-            get_registry().histogram(
-                "harmony_epoch_time_seconds",
-                "Per-epoch wall seconds per worker",
-                ("job", "attempt"),
-                buckets=EPOCH_TIME_BUCKETS,
-            ).labels(job=self.job_id, attempt=self.attempt_key).observe(
-                epoch_sec)
-        except Exception:
-            pass
         # Step-phase budget feed: the epoch wall is finally known here —
         # join the staged work split + host-dispatch with this epoch's
         # input-wait and hand the row to the process budget store
@@ -2559,7 +2626,8 @@ class WorkerTasklet:
     def _taskunit_scope(self, kind: str):
         if self.taskunit is None:
             return contextlib.nullcontext()
-        return self.taskunit.scope(kind, wait_acc=self._add_grant_wait)
+        return self.taskunit.scope(kind, wait_acc=self._span_acc(
+            "taskunit.wait", self._add_grant_wait))
 
     def _turn(self):
         """This worker's turnstile admission (pod lockstep), else a no-op.
@@ -2567,8 +2635,10 @@ class WorkerTasklet:
         whose seconds join the ``grant_wait`` phase."""
         if self.dispatch_turn is None:
             return contextlib.nullcontext()
-        return _TimedAdmission(self.dispatch_turn(), self._add_grant_wait,
-                               self.job_id)
+        return _TimedAdmission(
+            self.dispatch_turn(),
+            self._span_acc("taskunit.wait", self._add_grant_wait),
+            self.job_id)
 
     def _balanced_turns(self) -> bool:
         """True when this worker must take no-op turns to keep the cyclic

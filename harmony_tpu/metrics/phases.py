@@ -51,14 +51,36 @@ names the epoch critical path from this store.
 Knob: ``HARMONY_PHASE_WINDOW`` (seconds of budget window, default =
 ``HARMONY_LEDGER_WINDOW`` — the two vectors describe the same tenant
 and should cover the same span; docs/OBSERVABILITY.md §9).
+
+**The window ledger.** The budget above is fed an epoch at a time with a
+window's wall / k, and every reader sums it: a window that came late is
+smeared over the run. So the store also keeps one RECORD a drained window
+(``observe_window``; the worker closes it where it takes the window's wall,
+dolphin/worker.py ``_take_budget_feed``)::
+
+    {window, epoch, epochs, steps, start_ns, end_ns, wall_s,
+     spans: {name: seconds}, unnamed_s, compile_s, first, late}
+
+``start_ns`` / ``end_ns`` are ``time.monotonic_ns()`` readings, the spans'
+own clock; ``spans`` is the training thread's SELF time under each light
+span of the window (a span's seconds less those of the spans that closed
+inside it), so ``spans`` and ``unnamed_s = wall_s - sum(spans)`` partition
+the wall. A window is LATE when its wall an epoch exceeds ``STALL_FACTOR``
+times the median of the ring's earlier regular windows (``_judge``); its
+verdict ``{window, epoch, epochs, lost_s, cause, excess, start_sec}`` names
+as CAUSE the span whose seconds stand furthest over that span's own median,
+``unnamed`` for what ran under no span and ``compile`` when JAX compiled for
+most of the loss. The causes are the spans' names; what each means is a
+line of docs/OBSERVABILITY.md §9.
 """
 from __future__ import annotations
 
 import os
+import statistics
 import threading
 import time
 from collections import deque
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 ENV_PHASE_WINDOW = "HARMONY_PHASE_WINDOW"
 
@@ -72,6 +94,29 @@ RESIDUAL = "residual"
 #: feed samples kept per tenant — one per worker-epoch; covers days of
 #: a long job while bounding a pathological feeder (accounting's shape)
 _MAX_SAMPLES = 4096
+
+#: window records (and verdicts) kept per (job, worker): the newest few
+#: minutes of a job that drains about once a second
+_MAX_WINDOWS = 256
+#: a window whose wall an epoch is this many times the median of the
+#: earlier regular ones is LATE — perf/rates.py's ``STALL_FACTOR``, so the
+#: benchmark's polled ``stall_s`` and this side can be laid side by side
+STALL_FACTOR = 1.5
+#: the median is over the newest this many regular windows, and there is
+#: no verdict before this many
+_REGULAR_NEWEST = 32
+_REGULAR_MIN = 3
+#: how many records / verdicts a snapshot row (STATUS, a flight dump) shows
+_SHOWN_WINDOWS = 32
+#: the cause of what ran under no span, and of a window that compiled
+UNNAMED = "unnamed"
+COMPILE = "compile"
+#: wall an epoch (seconds) of a drained window: a keyed tenant's 15 ms
+#: epochs through hour-long production ones
+WINDOW_SECONDS_BUCKETS: Tuple[float, ...] = (
+    0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 15.0, 60.0, 300.0,
+    900.0, 3600.0,
+)
 
 
 def phase_window_seconds() -> float:
@@ -141,7 +186,8 @@ def split_device_phases(work_sec: float, steps: int, *,
 class _TenantPhases:
     """Mutable per-job phase state; all mutation under the store lock."""
 
-    __slots__ = ("job", "attempt", "samples", "device_split")
+    __slots__ = ("job", "attempt", "samples", "device_split", "windows",
+                 "stalls")
 
     def __init__(self, job: str) -> None:
         self.job = job
@@ -156,6 +202,10 @@ class _TenantPhases:
         #: mixes epoch walls across an elastic restart (attempt 2
         #: re-runs the same epoch indices; see snapshot())
         self.samples: deque = deque(maxlen=_MAX_SAMPLES)
+        #: worker -> ring of its newest window records; the verdicts on
+        #: the late ones beside them
+        self.windows: Dict[str, deque] = {}
+        self.stalls: deque = deque(maxlen=_MAX_WINDOWS)
 
 
 class PhaseBudgetStore:
@@ -204,7 +254,56 @@ class PhaseBudgetStore:
                               int(epoch_idx), wall, clean))
             self._version += 1
 
+    def observe_window(self, job: str, attempt: str, worker: str,
+                       record: Dict[str, Any]) -> Optional[Dict[str, Any]]:
+        """Keep one drained window's record (module docstring) and judge
+        it against the ring's earlier regular windows. Returns the verdict
+        when the window is LATE, else None. A window of another attempt
+        than the ring's newest starts the ring anew: an elastic restart
+        runs on another mesh, and its walls are not this one's."""
+        spans = {str(k): round(float(v), 6)
+                 for k, v in record["spans"].items()}
+        wall = round(max(float(record["wall_s"]), 0.0), 6)
+        rec = dict(
+            record, epochs=max(int(record["epochs"]), 1), wall_s=wall,
+            spans=spans, unnamed_s=round(wall - sum(spans.values()), 6),
+            compile_s=round(max(float(record["compile_s"]), 0.0), 6),
+            first=bool(record["first"]), attempt=str(attempt or job),
+            worker=str(worker))
+        with self._lock:
+            t = self._tenants.get(job)
+            if t is None:
+                t = self._tenants[job] = _TenantPhases(job)
+            ring = t.windows.get(rec["worker"])
+            if ring is None:
+                ring = t.windows[rec["worker"]] = deque(maxlen=_MAX_WINDOWS)
+            if ring and ring[-1]["attempt"] != rec["attempt"]:
+                ring.clear()
+            stall = None if rec["first"] else _judge(rec, ring)
+            rec["late"] = stall is not None
+            ring.append(rec)
+            if stall is not None:
+                t.stalls.append(stall)
+            self._version += 1
+        return stall
+
     # -- queries ---------------------------------------------------------
+
+    def window_ledger(self, job: str, newest: int = _MAX_WINDOWS
+                      ) -> Dict[str, List[Dict[str, Any]]]:
+        """``{windows, stalls}`` of ``job``: its newest window records over
+        all workers, oldest first, and the verdicts on its late windows.
+        READ-ONLY rows, as a snapshot's are."""
+        with self._lock:
+            t = self._tenants.get(job)
+            if t is None:
+                return {"windows": [], "stalls": []}
+            rings = [list(r) for r in t.windows.values()]
+            stalls = list(t.stalls)
+        windows = (rings[0] if len(rings) == 1 else
+                   sorted((r for ring in rings for r in ring),
+                          key=lambda r: r["end_ns"]))
+        return {"windows": windows[-newest:], "stalls": stalls[-newest:]}
 
     def snapshot(self, window_sec: Optional[float] = None
                  ) -> Dict[str, Dict[str, Any]]:
@@ -216,7 +315,9 @@ class PhaseBudgetStore:
         ``fractions`` the same over the tenant's wall (sums to 1.0 when
         wall > 0); ``per_worker`` one budget per worker;
         ``epoch_walls`` maps epoch index -> {worker: wall_sec} (the
-        critical-path analyzer's raw material). ``barrier_wait`` for a
+        critical-path analyzer's raw material); ``windows`` / ``stalls``
+        are the window ledger's newest records and verdicts (module
+        docstring). ``barrier_wait`` for a
         worker-epoch is ``max(sibling walls) - own wall`` — the
         chief-observed gap between that worker's last step and the
         epoch drain; single-worker epochs pay none. The join is
@@ -289,6 +390,8 @@ class PhaseBudgetStore:
                 "epoch_walls": {
                     str(ep): {wk: round(v, 6) for wk, v in ws.items()}
                     for ep, ws in sorted(epoch_walls.items())},
+                # the window ledger's newest records and verdicts
+                **self.window_ledger(job, _SHOWN_WINDOWS),
             }
         return rows
 
@@ -326,6 +429,45 @@ class PhaseBudgetStore:
             self._tenants.clear()
             self._memo.clear()
             self._version += 1
+
+
+def _judge(rec: Dict[str, Any], ring) -> Optional[Dict[str, Any]]:
+    """The verdict on ``rec`` against the earlier records of its ring, or
+    None: no verdict before ``_REGULAR_MIN`` regular windows (never the
+    first, never a late one), LATE above ``STALL_FACTOR`` medians an epoch.
+    The loss is the wall over the median's; the excess of a span (and of
+    ``unnamed``) is its seconds over that span's own median an epoch in the
+    same regular windows."""
+    regular = [r for r in ring
+               if not r["first"] and not r["late"]][-_REGULAR_NEWEST:]
+    if len(regular) < _REGULAR_MIN:
+        return None
+    k = rec["epochs"]
+    med = statistics.median(r["wall_s"] / r["epochs"] for r in regular)
+    if med <= 0.0 or rec["wall_s"] / k <= STALL_FACTOR * med:
+        return None
+    lost = rec["wall_s"] - med * k
+    usually = [(_seconds(r), r["epochs"]) for r in regular]
+    excess: Dict[str, float] = {}
+    for name, sec in _seconds(rec).items():
+        over = sec - k * statistics.median(
+            by.get(name, 0.0) / epochs for by, epochs in usually)
+        if over > 0.0:
+            excess[name] = round(over, 6)
+    cause = max(excess, key=excess.get) if excess else UNNAMED
+    if rec["compile_s"] >= 0.5 * lost:
+        cause = COMPILE
+    from harmony_tpu.tracing.span import wall_sec
+
+    return {"window": rec["window"], "epoch": rec["epoch"],
+            "epochs": k, "lost_s": round(lost, 6), "cause": cause,
+            "excess": dict(sorted(excess.items(), key=lambda kv: -kv[1])),
+            "start_sec": wall_sec(rec["start_ns"])}
+
+
+def _seconds(rec: Dict[str, Any]) -> Dict[str, float]:
+    """A record's wall by span, what ran under none among them."""
+    return {**rec["spans"], UNNAMED: rec["unnamed_s"]}
 
 
 def _fractions(phases: Dict[str, float],
@@ -367,6 +509,38 @@ def reset_budget() -> None:
     global _store
     with _store_lock:
         _store = None
+
+
+def count_window(job: str, record: Dict[str, Any],
+                 stall: Optional[Dict[str, Any]]) -> None:
+    """The window ledger's exposition, fed by a job's chief once a drained
+    window: the wall an epoch into ``harmony_window_seconds{job}`` and a
+    late window's loss into ``harmony_window_stall_seconds_total{job,
+    cause}`` / ``harmony_window_stalls_total{job,cause}``."""
+    from harmony_tpu.metrics.registry import get_registry
+
+    reg = get_registry()
+    if not record["first"]:
+        reg.histogram(
+            "harmony_window_seconds",
+            "Wall seconds an epoch of a drained window (its first, which "
+            "holds the compile, left out)",
+            ("job",), buckets=WINDOW_SECONDS_BUCKETS,
+        ).labels(job=job).observe(
+            float(record["wall_s"]) / max(int(record["epochs"]), 1))
+    if stall is None:
+        return
+    labels = {"job": job, "cause": stall["cause"]}
+    reg.counter(
+        "harmony_window_stall_seconds_total",
+        "Seconds late windows lost over the regular windows' median wall, "
+        "by the span that grew (unnamed: under no span; compile)",
+        ("job", "cause")).labels(**labels).inc(stall["lost_s"])
+    reg.counter(
+        "harmony_window_stalls_total",
+        "Drained windows whose wall an epoch exceeded 1.5 x the median of "
+        "the regular windows before them, by cause",
+        ("job", "cause")).labels(**labels).inc()
 
 
 def _install_callbacks() -> None:

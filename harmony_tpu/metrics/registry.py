@@ -53,7 +53,6 @@ __all__ = [
     "Histogram",
     "MetricRegistry",
     "STEP_TIME_BUCKETS",
-    "EPOCH_TIME_BUCKETS",
     "TRANSFER_SIZE_BUCKETS",
     "get_registry",
     "set_registry",
@@ -67,13 +66,6 @@ __all__ = [
 STEP_TIME_BUCKETS: Tuple[float, ...] = (
     0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25,
     0.5, 1.0, 2.5, 5.0, 10.0, 30.0,
-)
-
-#: Fixed epoch-wall-time boundaries (seconds): toy CPU epochs through
-#: hour-scale production epochs — the step-time boundaries top out at
-#: 30s and would collapse every real epoch into +Inf.
-EPOCH_TIME_BUCKETS: Tuple[float, ...] = (
-    0.01, 0.05, 0.1, 0.5, 1.0, 5.0, 15.0, 60.0, 300.0, 900.0, 3600.0,
 )
 
 #: Fixed transfer-size boundaries (bytes): one cache line of metadata up

@@ -736,8 +736,9 @@ def test_an_accepted_cells_entry_is_whole_on_the_benchmark_as_it_was(
     ten and its three metrics the last three, and ``test_smallthinker.py``
     that ``flash_masked_share`` lists its cell ALONE: both hold only until
     the next appended entry, and a PR may not edit a benchmark file. So
-    they run here on a view cut before this PR's entries: everything else
-    they say about those cells still has to hold."""
+    they run here on a view cut before this PR's entries (and those later
+    PRs appended): everything else they say about those cells still has to
+    hold."""
     spec = importlib.util.spec_from_file_location(
         "perf_" + name, os.path.join(ROOT, "perf", "tests", name + ".py"))
     mod = importlib.util.module_from_spec(spec)
@@ -748,7 +749,9 @@ def test_an_accepted_cells_entry_is_whole_on_the_benchmark_as_it_was(
     bench["configs"] = [c for c in bench["configs"]
                         if c["name"] != "sdar-30b-a3b"]
     bench["per_layer"] = [m for m in bench["per_layer"]
-                          if m.get("workloads") != [cell]]
+                          if m.get("workloads") != [cell]
+                          # PR 52's six window-ledger entries, appended since
+                          and not m["name"].startswith("window_")]
     for m in bench["per_layer"] + bench["end_to_end"]:
         if cell in m.get("workloads", []):
             m["workloads"].remove(cell)
