@@ -999,6 +999,9 @@ class JobServer:
             # the tiles each traced Pallas kernel chose and the grid steps
             # a call takes under them (ops/attention.py tile_plan)
             "kernel_plans": progcache.kernel_plans(),
+            # what each job's rematerialised blocks keep by name, a step
+            # (ops/residuals.py): the kernels' forwards run once a layer
+            "remat_saved": progcache.remat_saved(),
             # seconds of each job's start by stage (the job.<stage> spans)
             "job_stages": job_stage_seconds(),
             "metrics_port": (self.metrics_exporter.port

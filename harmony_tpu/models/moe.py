@@ -74,6 +74,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from harmony_tpu.ops.residuals import ROUTER_LOGITS, keep
 from harmony_tpu.tracing.stepscopes import step_scope
 
 
@@ -368,6 +369,9 @@ def _route(params, x, cfg: DroplessConfig, seqs: int, state=None):
         else:
             logits = jnp.dot(x.astype(jnp.float32), params["router"],
                              precision=lax.Precision.HIGHEST)    # [T, E]
+        # kept by a rematerialised block (ops/residuals.py), like the
+        # selection below: neither the matmul nor the rounds run again
+        logits = keep(logits, ROUTER_LOGITS)
         # the selection is one op (ops/top_k_rows.py): lax.top_k's experts
         # in its order, no sort, no scalar gather, no scatter-add behind it
         if cfg.score == "softmax":

@@ -35,6 +35,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from harmony_tpu.ops.attention import blockwise_attention
+from harmony_tpu.ops.residuals import NAMES, collecting
 from harmony_tpu.ops.ring import ring_attention
 from harmony_tpu.ops.ulysses import a2a_attention
 from harmony_tpu.parallel.mesh import DATA_AXIS, SEQ_AXIS
@@ -748,6 +749,38 @@ def _causal_conv(t, taps):
     return sum(tp[:, j:j + S] * taps[j] for j in range(K))
 
 
+def _remat(f, kept: Dict[str, list]):
+    """``f`` (a block's body) rematerialised, the ONE place and policy:
+    the backward recomputes the block's activations from its input instead
+    of keeping them — activation HBM drops from O(n_layers * B * S * d) to
+    O(B * S * d) — EXCEPT what the kernels' ``fwd`` rules and the router
+    name (``ops/residuals.py`` ``NAMES``: a few hundred MB a step), so the
+    norms, projections, rotary and glue run a second time and no Pallas
+    forward, router matmul or selection does. Each call adds what it keeps
+    to ``kept`` (``{name: [arrays, bytes]}``, STATUS ``remat_saved``);
+    ``jax.checkpoint`` traces a signature once, so a call it has traced
+    before counts what that trace named."""
+    body = jax.checkpoint(
+        f, policy=jax.checkpoint_policies.save_only_these_names(*NAMES))
+    by_signature: Dict[Any, Dict[str, list]] = {}
+
+    def call(*args, **kwargs):
+        leaves, tree = jax.tree.flatten((args, kwargs))
+        signature = (tree, tuple((jnp.shape(a), jnp.result_type(a))
+                                 for a in leaves))
+        with collecting() as named:
+            out = body(*args, **kwargs)
+        if named:
+            by_signature[signature] = named
+        for name, (arrays, nbytes) in by_signature.get(signature, {}).items():
+            row = kept.setdefault(name, [0, 0])
+            row[0] += arrays
+            row[1] += nbytes
+        return out
+
+    return call
+
+
 class TransformerLM:
     """Pure-functional decoder-only LM: ``init`` -> params, ``apply`` ->
     logits, ``loss`` -> mean next-token cross-entropy."""
@@ -1392,17 +1425,15 @@ class TransformerLM:
             return self._block(x, layer, axis_name, moe_axis=moe_axis,
                                pos_offset=pos_offset, **state)
 
-        if cfg.remat:
-            # Per-layer rematerialization: the backward recomputes each
-            # block's activations instead of keeping them — activation HBM
-            # drops from O(n_layers * B * S * d) to O(B * S * d), bought
-            # with one extra forward pass of FLOPs (the MXU has headroom;
-            # HBM usually doesn't).
-            block = jax.checkpoint(block)
+        # cfg.remat: every body below is checkpointed under ONE policy
+        # (``_remat``); ``kept`` sums what it keeps over the layers
+        kept: Dict[str, list] = {}
+        wrap = (functools.partial(_remat, kept=kept) if cfg.remat
+                else (lambda f: f))
+        block = wrap(block)
         # a model with window_layers has two kinds of softmax block, each
         # its own traced body (no other model traces a second one); a
         # layer_pattern model a body a kind of layer
-        wrap = jax.checkpoint if cfg.remat else (lambda f: f)
         by_kind = {kind: wrap(functools.partial(
             self._block, axis_name=axis_name, moe_axis=moe_axis,
             pos_offset=pos_offset, kind=kind)) for kind in ("swa", "full")
@@ -1427,6 +1458,10 @@ class TransformerLM:
                 routed.append(a)
             else:
                 aux = aux + a
+        if kept:
+            from harmony_tpu.runtime.progcache import note_remat_saved
+
+            note_remat_saved(kept)
         if routed:
             aux = jax.tree.map(lambda *xs: sum(xs), *routed)
             aux["tokens_by_layer"] = jnp.stack([a["tokens"] for a in routed])

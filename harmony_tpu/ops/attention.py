@@ -82,6 +82,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from harmony_tpu.ops.residuals import FLASH_LSE, FLASH_OUT, keep
+
 DEFAULT_BLOCK_K = 256  # blockwise_attention's scan block
 _NEG_INF = -1e30  # finite "-inf": keeps masked softmax NaN-free
 _LANES = 128  # TPU lane width: per-row stats (LSE, delta) are stored
@@ -1219,12 +1221,12 @@ def flash_attention_lse(
     the noisy rows' own-block term under ``diffusion_block``).
     Differentiable in both outputs; the LSE cotangent folds into the
     backward kernel's delta term (see ``_bwd_row_stats``)."""
-    return _fa_lse_fwd(q, k, v, causal, block_q, block_k, scale,
-                       interpret, window, diffusion_block)[0]
+    return _fa_lse_call(q, k, v, causal, block_q, block_k, scale,
+                        interpret, window, diffusion_block)
 
 
-def _fa_lse_fwd(q, k, v, causal, block_q, block_k, scale, interpret,
-                window=None, diffusion_block=None):
+def _fa_lse_call(q, k, v, causal, block_q, block_k, scale, interpret,
+                 window=None, diffusion_block=None):
     if not (q.dtype == k.dtype == v.dtype):
         raise TypeError(
             f"flash attention feeds the MXU in the operands' dtype, so "
@@ -1237,9 +1239,17 @@ def _fa_lse_fwd(q, k, v, causal, block_q, block_k, scale, interpret,
     plan = _require_plan(q, k, v, causal, block_q, block_k, window,
                          diffusion_block)
     _note_plan(plan, ("fwd",), q, k, v, causal, window, diffusion_block)
-    out, lse = _flash_forward(q, k, v, causal, plan.fwd,
-                              _resolve_scale(q, scale), interpret, window,
-                              diffusion_block)
+    return _flash_forward(q, k, v, causal, plan.fwd, _resolve_scale(q, scale),
+                          interpret, window, diffusion_block)
+
+
+def _fa_lse_fwd(q, k, v, causal, block_q, block_k, scale, interpret,
+                window=None, diffusion_block=None):
+    out, lse = _fa_lse_call(q, k, v, causal, block_q, block_k, scale,
+                            interpret, window, diffusion_block)
+    # named HERE, before they are both outputs and residuals: what a
+    # rematerialised block keeps (ops/residuals.py)
+    out, lse = keep(out, FLASH_OUT), keep(lse, FLASH_LSE)
     return (out, lse), (q, k, v, out, lse)
 
 

@@ -67,6 +67,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from harmony_tpu.ops.residuals import KDA_OUT, KDA_SOLVE, KDA_STATE, keep
+
 #: the kernels' names in a device trace (perf/layer_metrics read them) and in
 #: STATUS ``kernel_plans``
 KERNEL_NAMES = {"fwd": "harmony_kda_fwd", "bwd": "harmony_kda_bwd"}
@@ -393,6 +395,8 @@ def _kda_kernels_fwd(q, k, kb, vb, G, interpret):
     BH, N, C, dk = q.shape
     _note_plans(("fwd",), BH, N * C, dk, vb.shape[-1])
     o, h, X = _kda_fwd_call(q, k, kb, vb, G, interpret)
+    # what a rematerialised block keeps (ops/residuals.py)
+    o, h, X = keep(o, KDA_OUT), keep(h, KDA_STATE), keep(X, KDA_SOLVE)
     return o, (q, k, kb, vb, G, h, X)
 
 

@@ -49,6 +49,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from harmony_tpu.ops.kda import _NN, _NT, _TN, _kernel_route, tile_plan
+from harmony_tpu.ops.residuals import SSD_OUT, SSD_STATE, keep
 
 #: the kernels' names in a device trace (perf/layer_metrics read them) and in
 #: STATUS ``kernel_plans``
@@ -230,6 +231,8 @@ def _ssd_kernels_fwd(x, b, c, G, interpret):
     BH, N, C, P = x.shape
     _note_plans(("fwd",), BH, N * C, C, P, b.shape[-1])
     y, h = _ssd_fwd_call(x, b, c, G, interpret)
+    # what a rematerialised block keeps (ops/residuals.py)
+    y, h = keep(y, SSD_OUT), keep(h, SSD_STATE)
     return y, (x, b, c, G, h)
 
 

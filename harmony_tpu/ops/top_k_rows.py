@@ -45,6 +45,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from harmony_tpu.ops.residuals import ROUTER_EXPERT, ROUTER_WEIGHT, keep
+
 KERNEL_NAME = "harmony_top_k_rows"
 #: token tiles tried, largest first. On the chip (PERF.md, PR 43) a call at
 #: Nemotron-H's router took 0.365 / 0.270 / 0.224 / 0.239 ms at 128 / 256 /
@@ -166,6 +168,8 @@ def _top_k_rows(sel, val, k, weighed, interpret):
 
 def _fwd(sel, val, k, weighed, interpret):
     weight, expert = _select(sel, val, k, interpret)
+    # what a rematerialised block keeps (ops/residuals.py)
+    weight, expert = keep(weight, ROUTER_WEIGHT), keep(expert, ROUTER_EXPERT)
     # the lanes carry E to the backward: an iota, not a saved activation
     lanes = lax.broadcasted_iota(jnp.int32, (1, sel.shape[1]), 1)
     return (weight, expert), (expert, lanes)

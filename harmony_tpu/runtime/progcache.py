@@ -537,6 +537,60 @@ def kernel_plans() -> Dict[str, list]:
     return out
 
 
+# -- what a rematerialised block keeps, by job --------------------------------
+#
+# ``TransformerConfig.remat`` checkpoints each block under ONE policy that
+# keeps the kernels' named residuals (ops/residuals.py); like a kernel plan
+# the record is static per traced program and made at trace time.
+
+def _remat_saved_families():
+    from harmony_tpu.metrics.registry import get_registry
+
+    reg = get_registry()
+    return (reg.gauge(
+        "harmony_remat_saved_arrays",
+        "Arrays a step keeps across its rematerialised blocks under a "
+        "residual's name instead of computing them again, by job",
+        ("job", "name")),
+            reg.gauge(
+        "harmony_remat_saved_bytes",
+        "Bytes of the arrays a step keeps across its rematerialised blocks "
+        "under a residual's name, by job", ("job", "name")))
+
+
+def note_remat_saved(kept: Dict[str, Any]) -> None:
+    """``kept``: ``{name: (arrays, bytes)}`` one traced forward keeps under
+    its ``remat`` policy (models/transformer.py ``_remat``), over all its
+    layers. Never fails a trace."""
+    try:
+        from harmony_tpu.tracing.span import current_job
+
+        job = current_job() or "-"
+        arrays_by, bytes_by = _remat_saved_families()
+        for name, (arrays, nbytes) in kept.items():
+            arrays_by.labels(job=job, name=name).set(arrays)
+            bytes_by.labels(job=job, name=name).set(nbytes)
+    except Exception:
+        pass
+
+
+def remat_saved() -> Dict[str, list]:
+    """``{job: [{name, arrays, bytes}]}`` of every rematerialised step traced
+    in this process — STATUS ``remat_saved``; a job whose model has no
+    ``remat`` is absent."""
+    out: Dict[str, list] = {}
+    try:
+        arrays_by, bytes_by = _remat_saved_families()
+        nbytes = {key: int(child.value) for key, child in bytes_by.children()}
+        for (job, name), child in arrays_by.children():
+            out.setdefault(job, []).append({
+                "name": name, "arrays": int(child.value),
+                "bytes": nbytes.get((job, name), 0)})
+    except Exception:
+        return {}
+    return out
+
+
 def clear() -> None:
     with _lock:
         _cache.clear()
