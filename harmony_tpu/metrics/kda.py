@@ -19,7 +19,11 @@ and the trainer hands here:
   * ``harmony_model_layers{job,kind}`` — how many layers of each kind
     (``TransformerConfig.layer_kinds()``: ``kda`` | ``mha`` | ``mla`` |
     ``swa`` | ``full``, and a ``layer_pattern`` model's ``ssd`` | ``attn`` |
-    ``moe``) the job's model has, set when the job initialises its table.
+    ``moe``) the job's model has, set when the job initialises its table;
+  * ``harmony_model_heads{job,kind}`` — the heads a block of each kind
+    mixes with (a softmax block's QUERY heads, ``TransformerConfig.heads``:
+    a model whose ``full`` and ``swa`` blocks differ in them reads two
+    values), set at the same place.
 
 Under a profiler session the light span ``kda.observe`` marks each drain.
 STATUS shows, per tenant, ``layer_kinds`` (:func:`kinds_by_job`) and ``kda:
@@ -28,7 +32,7 @@ STATUS shows, per tenant, ``layer_kinds`` (:func:`kinds_by_job`) and ``kda:
 """
 from __future__ import annotations
 
-from typing import Dict, Sequence
+from typing import Dict, Optional, Sequence
 
 import numpy as np
 
@@ -67,11 +71,22 @@ def _layers_gauge():
         "Blocks of each token-mixer kind in the job's model", ("job", "kind"))
 
 
-def note_layer_kinds(job: str, kinds: Sequence[str]) -> None:
-    """Record the job's blocks by kind (``layer_kinds()``)."""
+def note_layer_kinds(job: str, kinds: Sequence[str],
+                     heads: Optional[Dict[str, int]] = None) -> None:
+    """Record the job's blocks by kind (``layer_kinds()``) and, for the
+    kinds in ``heads``, the heads a block of that kind mixes with."""
     gauge = _layers_gauge()
     for kind in sorted(set(kinds)):
         gauge.labels(job=job, kind=kind).set(list(kinds).count(kind))
+    if heads:
+        from harmony_tpu.metrics.registry import get_registry
+
+        by_kind = get_registry().gauge(
+            "harmony_model_heads",
+            "Heads of a block of each token-mixer kind in the job's model "
+            "(query heads for softmax attention)", ("job", "kind"))
+        for kind, n in sorted(heads.items()):
+            by_kind.labels(job=job, kind=kind).set(n)
 
 
 def observe(job: str, first: np.ndarray, second: np.ndarray,

@@ -26,7 +26,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -40,6 +40,86 @@ from harmony_tpu.ops.ring import ring_attention
 from harmony_tpu.ops.ulysses import a2a_attention
 from harmony_tpu.parallel.mesh import DATA_AXIS, SEQ_AXIS
 from harmony_tpu.tracing.stepscopes import step_scope
+
+
+class Rotary(NamedTuple):
+    """One kind of softmax block's rotary positions (``TransformerConfig.
+    rotary``): rotate-half at base ``theta`` on the first ``fraction`` of a
+    head's columns; under ``yarn`` = ``(factor, original positions,
+    beta_fast, beta_slow)`` the frequencies are YaRN's (arXiv:2309.00071, as
+    ``transformers``' ``_compute_yarn_parameters`` writes it:
+    :meth:`inv_freq`) and cos and sin are scaled by ``attention_factor``."""
+    theta: float
+    fraction: float = 1.0
+    yarn: Optional[Tuple[float, int, float, float]] = None
+    attention_factor: float = 1.0
+
+    #: the published ``rope_parameters`` keys a spec may carry
+    KEYS = ("rope_theta", "partial_rotary_factor", "rope_type", "factor",
+            "original_max_position_embeddings", "beta_fast", "beta_slow",
+            "attention_factor")
+
+    @classmethod
+    def of(cls, spec) -> Optional["Rotary"]:
+        """A :class:`Rotary` from one entry of ``kind_rope``: None (no
+        positions), a :class:`Rotary`, or the published keys."""
+        if spec is None or isinstance(spec, cls):
+            return spec
+        spec = dict(spec)
+        kind = spec.get("rope_type", "default")
+        unknown = sorted(set(spec) - set(cls.KEYS))
+        if unknown or kind not in ("default", "yarn") or (
+                kind == "default" and set(spec) - set(cls.KEYS[:3])):
+            raise ValueError(
+                f"kind_rope: rope_type 'default' (rope_theta, "
+                f"partial_rotary_factor) or 'yarn' (also {cls.KEYS[3:]}); "
+                f"got {spec}")
+        fraction = float(spec.get("partial_rotary_factor", 1.0))
+        if kind == "default":
+            return cls(float(spec["rope_theta"]), fraction)
+        factor = float(spec["factor"])
+        return cls(float(spec["rope_theta"]), fraction,
+                   (factor, int(spec["original_max_position_embeddings"]),
+                    float(spec.get("beta_fast", 32.0)),
+                    float(spec.get("beta_slow", 1.0))),
+                   float(spec.get("attention_factor",
+                                  0.1 * np.log(factor) + 1.0)))
+
+    def width(self, head_dim: int) -> int:
+        """The columns of a ``head_dim``-wide head that turn."""
+        return int(self.fraction * head_dim)
+
+    def check(self, head_dim: int, kind: str) -> None:
+        rot = self.fraction * head_dim
+        if not 0.0 < self.fraction <= 1.0 or rot != int(rot) or int(rot) % 2 \
+                or self.theta <= 0 or (self.yarn is not None and (
+                    self.yarn[0] < 1.0 or self.yarn[1] < 1
+                    or not self.yarn[2] > self.yarn[3] > 0)):
+            raise ValueError(
+                f"kind_rope[{kind!r}] = {self}: a positive base, a whole "
+                f"even number of a {head_dim}-wide head's columns, and under "
+                "YaRN factor >= 1, original positions >= 1 and beta_fast > "
+                "beta_slow > 0")
+
+    def inv_freq(self, dim: int):
+        """The ``dim / 2`` frequencies (float32), or None for the plain
+        ``theta ** (-2 i / dim)`` that :func:`rope` forms itself. YaRN:
+        pair ``i`` keeps its frequency where it turns more than
+        ``beta_fast`` times over the original positions, is slowed by
+        ``factor`` where it turns fewer than ``beta_slow`` times, and a
+        linear ramp over the pairs lies between."""
+        if self.yarn is None:
+            return None
+        factor, original, fast, slow = self.yarn
+        f32 = jnp.float32
+        at = lambda turns: dim * np.log(original / (turns * 2 * np.pi)) / (
+            2 * np.log(self.theta))
+        low = max(int(np.floor(at(fast))), 0)
+        high = min(int(np.ceil(at(slow))), dim - 1)
+        ramp = jnp.clip((jnp.arange(dim // 2, dtype=f32) - low)
+                        / ((high - low) or 1e-3), 0.0, 1.0)
+        plain = f32(self.theta) ** (-jnp.arange(0, dim, 2, dtype=f32) / dim)
+        return (1.0 - ramp) * plain + ramp * plain / f32(factor)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -204,6 +284,28 @@ class TransformerConfig:
     objective: str = "next_token"   # "next_token" | "block_diffusion"
     diffusion_block: int = 0
     mask_token: int = -1
+    # Laguna's block (``model_type`` "laguna": poolside's Laguna-S-2.1),
+    # ``attn_kind="mha"`` with ``window_layers``: its two kinds of softmax
+    # block (``layer_kinds()``'s ``"full"`` / ``"swa"``) differ in more than
+    # the mask. ``kind_heads``: the QUERY heads of a kind, ``{"full": 48,
+    # "swa": 72}`` (``num_attention_heads_per_layer``; a kind left out has
+    # ``n_heads``) — ``wqkv``'s q columns, ``wo``'s rows and the queries a
+    # K/V head serves are the kind's, the ``n_kv_heads`` K/V heads and the
+    # head width the model's. ``kind_rope``: the rotary of EACH kind under
+    # the published ``rope_parameters``' own keys, ``{"full": {"rope_theta":
+    # 500000, "partial_rotary_factor": 0.5, "rope_type": "yarn", "factor":
+    # 128, "original_max_position_embeddings": 8192, "beta_fast": 32,
+    # "beta_slow": 1, "attention_factor": 1.485...}, "swa": {"rope_theta":
+    # 10000}}`` (:class:`Rotary`; ``None`` for a kind: it carries no
+    # positions); empty, ``window_layers`` reads as SmallThinker's — the
+    # windowed blocks turn by ``rope_theta`` / ``rope_fraction``, the full
+    # ones carry no positions. ``attn_gate="head"``: each head's attention
+    # output times ``sigmoid`` of one scalar a head and position, a
+    # projection ``wgate [heads, d]`` of the block's normed input, before
+    # ``wo`` (headwise gating at the attention output, arXiv:2505.06708).
+    kind_heads: Any = ()
+    kind_rope: Any = ()
+    attn_gate: str = "none"         # "none" | "head"
     # Standard deviation the embedding rows are drawn with. GPT-2's 0.02
     # leaves a row at a fiftieth of what a block's fan-in projections add to
     # it, which nothing here divides by depth: attention's average over the
@@ -270,10 +372,16 @@ class TransformerConfig:
             raise ValueError("ssd_heads / ssd_head_dim / ssd_groups / "
                              "ssd_state / ssd_chunk belong to the 'M' layers "
                              "of a layer_pattern")
-        if (not self.moe_gated or self.moe_latent or self.moe_shared_d_ff) \
+        if (not self.moe_gated or self.moe_latent) \
                 and "E" not in self.layer_pattern:
-            raise ValueError("moe_gated / moe_latent / moe_shared_d_ff "
-                             "belong to the 'E' layers of a layer_pattern")
+            raise ValueError("moe_gated / moe_latent belong to the 'E' "
+                             "layers of a layer_pattern")
+        if self.moe_shared_d_ff and "E" not in self.layer_pattern and (
+                self.attn_kind != "mha" or not self.moe_top_k):
+            raise ValueError(
+                "moe_shared_d_ff is the held width of a dropless expert "
+                "layer's shared MLP: the 'E' layers of a layer_pattern, or "
+                "the expert blocks of an attn_kind='mha' model (moe_top_k)")
         if self.moe_shared_d_ff and self.moe_shared_experts != 1:
             raise ValueError("moe_shared_d_ff is the held width of ONE "
                              "shared MLP: set moe_shared_experts=1")
@@ -470,7 +578,75 @@ class TransformerConfig:
                 f"(got block {self.diffusion_block}, "
                 f"mask_token {self.mask_token}, pos {self.pos!r}, "
                 f"attn_kind {self.attn_kind!r})")
+        self._check_kinds()
         validate_attn(self.attn)
+
+    def _check_kinds(self) -> None:
+        """``kind_heads`` / ``kind_rope`` / ``attn_gate``: held as tuples of
+        pairs (a job's ``app_params`` arrive as JSON objects), checked
+        against the two kinds a model with ``window_layers`` has."""
+        heads = tuple(sorted((str(k), int(v))
+                             for k, v in dict(self.kind_heads).items()))
+        ropes = tuple(sorted((str(k), Rotary.of(v))
+                             for k, v in dict(self.kind_rope).items()))
+        object.__setattr__(self, "kind_heads", heads)
+        object.__setattr__(self, "kind_rope", ropes)
+        if self.attn_gate not in ("none", "head"):
+            raise ValueError(f"unknown attn_gate {self.attn_gate!r}: 'none' "
+                             "or 'head'")
+        if (heads or ropes) and (not self.window_layers or self.cca):
+            raise ValueError(
+                "kind_heads / kind_rope tell the 'full' and 'swa' blocks of "
+                "a model with window_layers apart (attn_kind='mha', no cca): "
+                "a model of one kind of softmax block says n_heads, "
+                "rope_theta and rope_fraction")
+        if self.attn_gate != "none" and (
+                self.attn_kind != "mha" or self.cca or self.linear_layers
+                or self.objective != "next_token"):
+            raise ValueError(
+                "attn_gate='head' gates the heads of an attn_kind='mha' "
+                "block's softmax attention under the next-token objective: "
+                "not beside latent (mla) or CCA attention, KDA blocks (their "
+                "mixer has its own output gate) or the two streams of "
+                "block diffusion")
+        kinds = {"full", "swa"}
+        if set(dict(heads)) - kinds or (ropes and set(dict(ropes)) != kinds):
+            raise ValueError(
+                "kind_heads names 'full' and / or 'swa'; kind_rope BOTH, a "
+                f"kind without positions as null (got {sorted(dict(heads))}"
+                f", {sorted(dict(ropes))})")
+        for kind, h in heads:
+            if h < 1 or h % self.kv_heads or self.qk_norm:
+                raise ValueError(
+                    f"kind_heads[{kind!r}] = {h}: whole groups of the "
+                    f"{self.kv_heads} key/value heads (n_kv_heads), and no "
+                    "d_model-wide qk_norm")
+        if ropes and (self.rope_fraction != 1.0 or self.pos != "rope"):
+            raise ValueError("kind_rope says each kind's rotary under "
+                             "pos='rope': rope_fraction has no part beside it")
+        for kind, r in ropes:
+            if r is not None:
+                r.check(self.head_dim, kind)
+
+    def heads(self, kind: Optional[str] = None) -> int:
+        """The heads a block of ``kind`` (``layer_kinds()``'s) mixes with:
+        a softmax block's QUERY heads, a KDA or state-space mixer's own."""
+        if kind in ("kda", "ssd"):
+            return self.linear_heads if kind == "kda" else self.ssd_heads
+        return dict(self.kind_heads).get(kind, self.n_heads)
+
+    def rotary(self, kind: Optional[str] = None) -> Optional["Rotary"]:
+        """How a softmax block of ``kind`` turns q and k — the ONE answer
+        to "which positions does this block carry": None where it carries
+        none (no ``pos="rope"``; a ``"full"`` block of a model with
+        ``window_layers`` unless ``kind_rope`` gives it one)."""
+        if self.pos != "rope":
+            return None
+        if self.kind_rope:
+            return dict(self.kind_rope)[kind]
+        if kind == "full" and self.window_layers:
+            return None
+        return Rotary(self.rope_theta, self.rope_fraction)
 
     def is_moe_layer(self, i: int) -> bool:
         """Block i uses the MoE FFN: past the ``moe_first_dense`` leading
@@ -560,8 +736,12 @@ class TransformerConfig:
     @property
     def qkv_widths(self) -> Tuple[int, int, int]:
         """The widths of ``wqkv``'s three column blocks (q, k, v)."""
+        return self.qkv_widths_of(None)
+
+    def qkv_widths_of(self, kind: Optional[str]) -> Tuple[int, int, int]:
+        """``qkv_widths`` in a block of ``kind`` (``kind_heads``)."""
         hd = self.head_dim
-        return self.n_heads * hd, self.kv_heads * hd, self.kv_heads * hd
+        return self.heads(kind) * hd, self.kv_heads * hd, self.kv_heads * hd
 
     @property
     def ssd_widths(self) -> Tuple[int, int, int]:
@@ -586,34 +766,46 @@ class TransformerConfig:
                 and not (self.cca or self.merge_scaled
                          or self.rope_fraction != 1.0)
                 and not self.head_norm
-                and self.objective == "next_token"):
+                and self.objective == "next_token"
+                and not (self.kind_heads or self.kind_rope
+                         or self.attn_gate != "none")):
             raise ValueError(
                 f"{who} runs the GPT-2-era block only (learned positions, "
                 "GELU, tied readout, Switch experts); rotary / no-position / "
                 "QK-norm / SwiGLU / untied / dropless / latent-attention / "
                 "KDA linear-attention / grouped-query / windowed / leading-dense "
                 "/ layer-pattern / CCA / partial-rotary / scaled-merge / "
-                "head-norm / block-diffusion configs train through "
-                "TransformerLM.loss and TransformerTrainer")
+                "head-norm / block-diffusion / per-kind heads and rotary / "
+                "gated-attention configs train through TransformerLM.loss "
+                "and TransformerTrainer")
 
 
 from harmony_tpu.models.common import rms_norm as _norm  # noqa: E402
 
 
-def rope(x, theta: float, pos_offset=0, width: Optional[int] = None):
+def rope(x, theta: float, pos_offset=0, width: Optional[int] = None,
+         scaled: Optional[Rotary] = None):
     """Rotate-half rotary positions on ``x [B, H, S, hd]`` (positions
     ``pos_offset .. pos_offset+S-1``): float32 angles, result in x's dtype.
     ``width``: only the first that many columns of a head are turned, as a
-    head that wide would be (``rope_fraction``); the others pass."""
+    head that wide would be (``rope_fraction``); the others pass.
+    ``scaled``: a :class:`Rotary` under YaRN — its frequencies in place of
+    ``theta``'s, cos and sin times its ``attention_factor``."""
     if width is not None and width != x.shape[-1]:
         return jnp.concatenate(
-            [rope(x[..., :width], theta, pos_offset), x[..., width:]], axis=-1)
+            [rope(x[..., :width], theta, pos_offset, scaled=scaled),
+             x[..., width:]], axis=-1)
     hd = x.shape[-1]
-    inv_freq = theta ** (-jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
+    if scaled is None:
+        inv_freq = theta ** (-jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
+    else:
+        inv_freq = scaled.inv_freq(hd)
     ang = (pos_offset + jnp.arange(x.shape[2], dtype=jnp.float32)
            )[:, None] * inv_freq[None, :]                        # [S, hd/2]
     cos = jnp.concatenate([jnp.cos(ang)] * 2, axis=-1)
     sin = jnp.concatenate([jnp.sin(ang)] * 2, axis=-1)
+    if scaled is not None:
+        cos, sin = (t * scaled.attention_factor for t in (cos, sin))
     xf = x.astype(jnp.float32)
     x1, x2 = jnp.split(xf, 2, axis=-1)
     return (xf * cos + jnp.concatenate([-x2, x1], axis=-1) * sin
@@ -836,12 +1028,16 @@ class TransformerLM:
                     "ln2": jnp.ones((d,), jnp.float32),
                 }
             else:
+                widths = cfg.qkv_widths_of(kinds[i])
                 layer = {
                     "ln1": jnp.ones((d,), jnp.float32),
-                    "wqkv": dense(ks[0], (d, sum(cfg.qkv_widths))),
-                    "wo": dense(ks[1], (cfg.qkv_widths[0], d)),
+                    "wqkv": dense(ks[0], (d, sum(widths))),
+                    "wo": dense(ks[1], (widths[0], d)),
                     "ln2": jnp.ones((d,), jnp.float32),
                 }
+                if cfg.attn_gate == "head":  # stored [heads, d], as KDA's wb
+                    layer["wgate"] = dense(jax.random.fold_in(ks[0], 2),
+                                           (d, cfg.heads(kinds[i]))).T
             if cfg.qk_norm:
                 layer["q_norm"] = jnp.ones((d,), jnp.float32)
                 layer["k_norm"] = jnp.ones((d,), jnp.float32)
@@ -984,12 +1180,15 @@ class TransformerLM:
                     "ln2": np.ones((d,), np.float32),
                 }
             else:
+                widths = cfg.qkv_widths_of(kinds[i])
                 layer = {
                     "ln1": np.ones((d,), np.float32),
-                    "wqkv": dense((d, sum(cfg.qkv_widths))),
-                    "wo": dense((cfg.qkv_widths[0], d)),
+                    "wqkv": dense((d, sum(widths))),
+                    "wo": dense((widths[0], d)),
                     "ln2": np.ones((d,), np.float32),
                 }
+                if cfg.attn_gate == "head":
+                    layer["wgate"] = dense((d, cfg.heads(kinds[i]))).T.copy()
             if cfg.qk_norm:
                 layer["q_norm"] = np.ones((d,), np.float32)
                 layer["k_norm"] = np.ones((d,), np.float32)
@@ -1037,7 +1236,7 @@ class TransformerLM:
                     if cfg.moe_score == "sigmoid":
                         layer["moe"]["bias"] = np.zeros((E,), np.float32)
                     if cfg.moe_shared_experts:
-                        fs = cfg.moe_shared_experts * f
+                        fs = cfg.dropless_cfg.shared_width
                         layer["moe"].update(
                             shared_wg=dense((d, fs)), shared_wu=dense((d, fs)),
                             shared_wd=dense((fs, d)))
@@ -1289,9 +1488,11 @@ class TransformerLM:
         published q/k/v projections are the three column blocks of
         ``wqkv``. ``kind`` (``layer_kinds()``'s) matters in a model with
         ``window_layers`` only: ``"swa"`` blocks window and turn, ``"full"``
-        ones do neither. ``route_state``: what the MLP router of the block
-        before left for this one's (``moe_router_hidden``; None in the first
-        block); this block's is ``aux["state"]``."""
+        ones do neither unless ``kind_rope`` says how they turn (and
+        ``kind_heads`` how many query heads each has). ``route_state``: what
+        the MLP router of the block before left for this one's
+        (``moe_router_hidden``; None in the first block); this block's is
+        ``aux["state"]``."""
         cfg = self.config
         eps = cfg.norm_eps
         x_in = x
@@ -1351,20 +1552,23 @@ class TransformerLM:
 
     def _softmax_mixer(self, xn, layer, axis_name, pos_offset, kind=None):
         """Softmax attention (``attn_kind``) on the normed input ``xn [B,
-        S, d]`` through its output projection."""
+        S, d]`` through its output projection. ``kind`` (``layer_kinds()``'s)
+        says the block's window, its query heads (``heads``) and how it
+        turns q and k (``rotary``); under ``attn_gate="head"`` each head's
+        output is gated before ``wo``."""
         cfg = self.config
         B, S = xn.shape[0], xn.shape[1]
-        h, hd, eps = cfg.n_heads, cfg.head_dim, cfg.norm_eps
+        h, hd, eps = cfg.heads(kind), cfg.head_dim, cfg.norm_eps
         window = cfg.window if kind == "swa" else None
         if cfg.attn_kind == "mla":
             q, k, v = self._latent_qkv(xn, layer, pos_offset)
         else:
             with step_scope("mixer.qkv"):
                 qkv = xn @ layer["wqkv"].astype(cfg.dtype)      # [B, S, 3d]
-                if cfg.qkv_widths == (cfg.d_model,) * 3:
+                if cfg.qkv_widths_of(kind) == (cfg.d_model,) * 3:
                     q, k, v = jnp.split(qkv, 3, axis=-1)
                 else:  # grouped queries: k and v are kv_heads heads wide
-                    wq, wk, _ = cfg.qkv_widths
+                    wq, wk, _ = cfg.qkv_widths_of(kind)
                     q, k, v = jnp.split(qkv, (wq, wq + wk), axis=-1)
                 if cfg.qk_norm:
                     q = _norm(q, layer["q_norm"].astype(cfg.dtype), eps)
@@ -1380,14 +1584,23 @@ class TransformerLM:
                 with step_scope("mixer.cca"):
                     q, k, v = (to_heads(t) for t in self._cca_latent(
                         q, k, v, layer["cca"]))
-            if cfg.pos == "rope" and kind != "full":
-                part = ({} if cfg.rope_fraction == 1.0 else
-                        {"width": int(cfg.rope_fraction * hd)})
+            rot = cfg.rotary(kind)
+            if rot is not None:
+                part = ({} if rot.fraction == 1.0 else
+                        {"width": rot.width(hd)})
+                if rot.yarn is not None:
+                    part["scaled"] = rot
                 with step_scope("mixer.rope"):
-                    q = rope(q, cfg.rope_theta, pos_offset, **part)
-                    k = rope(k, cfg.rope_theta, pos_offset, **part)
+                    q = rope(q, rot.theta, pos_offset, **part)
+                    k = rope(k, rot.theta, pos_offset, **part)
         with step_scope("mixer.core"):
             o = self._attention(q, k, v, axis_name, window)
+        if cfg.attn_gate == "head":  # one scalar a head and position
+            with step_scope("mixer.gate"):
+                gate = jax.nn.sigmoid(jnp.einsum(
+                    "bsd,hd->bhs", xn, layer["wgate"].astype(cfg.dtype)
+                ).astype(jnp.float32))
+                o = o * gate[..., None].astype(o.dtype)
         with step_scope("mixer.out"):
             o = o.transpose(0, 2, 1, 3).reshape(B, S, h * v.shape[3])
             return o @ layer["wo"].astype(cfg.dtype)
@@ -2132,7 +2345,10 @@ class TransformerTrainer(PyTreeTrainer):
         from harmony_tpu.metrics import kda
         from harmony_tpu.tracing.span import current_job
 
-        kda.note_layer_kinds(current_job() or "-", self.config.layer_kinds())
+        cfg = self.config
+        kinds = cfg.layer_kinds()
+        kda.note_layer_kinds(current_job() or "-", kinds, heads={
+            kind: cfg.heads(kind) for kind in set(kinds) if kind != "moe"})
 
     def observe_step_vectors(self, job_id: str, vectors) -> None:
         if "moe_expert_tokens" in vectors:

@@ -536,6 +536,7 @@ def _note_plan(plan, kernels, q, k, v, causal, window, diffusion_block=None):
                                  diffusion_block)
                 steps = work["grid_steps"]
                 band = {"window": window or 0, "kv_heads": k.shape[1],
+                        "group": q.shape[1] // k.shape[1],
                         "band_grid_steps": bh * work["with_work"],
                         "sub_blocks": bh * work["sub_blocks"],
                         "masked_sub_blocks": bh * work["masked_sub_blocks"],

@@ -499,7 +499,8 @@ def note_kernel_plan(kernel: str, block_q: int, block_k: int, sub: int,
     """``d`` / ``dv``: the two widths the tiles were planned for — a flash
     kernel's q.k and v head widths, a grouped matmul's k and n. ``band``:
     what a flash call with grouped heads or a window adds to its row
-    (``window``, ``kv_heads``, ``band_grid_steps``, ``sub_blocks``,
+    (``window``, ``kv_heads``, ``group`` — the query heads a K/V head
+    serves —, ``band_grid_steps``, ``sub_blocks``,
     ``masked_sub_blocks``, ``computed``, ``masked_share``)."""
     from harmony_tpu.tracing.span import current_job
 

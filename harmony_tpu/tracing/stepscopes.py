@@ -53,7 +53,7 @@ VOCABULARY = (
     "compute",
     "embed", "noise", "blk", "norm", "merge",
     "mixer.qkv", "mixer.cca", "mixer.rope", "mixer.core", "mixer.streams",
-    "mixer.out",
+    "mixer.gate", "mixer.out",
     "kda.proj", "kda.conv", "kda.gate", "kda.scan", "kda.out",
     "ssd.proj", "ssd.conv", "ssd.gate", "ssd.scan", "ssd.out",
     "ffn",

@@ -427,13 +427,22 @@ def test_the_configuration_refuses_what_it_cannot_run(change, match):
         _config({**APP, **change})
 
 
-@pytest.mark.parametrize("field, value", [
-    ("moe_gated", False), ("moe_latent", 32), ("moe_shared_d_ff", 24),
-    ("ssd_heads", 4), ("ssd_chunk", 32)])
-def test_the_new_fields_belong_to_a_pattern(field, value):
+#: a latent block's fields: since PR 54 ``moe_shared_d_ff`` also stands in
+#: the expert blocks of an ``attn_kind="mha"`` model (a shared expert's held
+#: columns beside a leading dense layer), and stays refused beside ``mla``
+MLA = dict(attn_kind="mla", kv_lora_rank=8, qk_nope_head_dim=8,
+           qk_rope_head_dim=8, v_head_dim=8, pos="rope")
+
+
+@pytest.mark.parametrize("field, value, beside", [
+    ("moe_gated", False, {}), ("moe_latent", 32, {}),
+    ("moe_shared_d_ff", 24, MLA), ("ssd_heads", 4, {}),
+    ("ssd_chunk", 32, {})])
+def test_the_new_fields_belong_to_a_pattern(field, value, beside):
     base = dict(vocab_size=96, d_model=64, n_heads=4, n_layers=2, d_ff=32,
                 ffn="swiglu", moe_experts=8, moe_top_k=2, moe_every=1,
-                moe_shared_experts=1, moe_score="sigmoid", moe_seq_aux=True)
+                moe_shared_experts=1, moe_score="sigmoid", moe_seq_aux=True,
+                **beside)
     TransformerConfig(**base)
     with pytest.raises(ValueError, match="layer_pattern"):
         TransformerConfig(**{**base, field: value})

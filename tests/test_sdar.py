@@ -737,23 +737,26 @@ def test_an_accepted_cells_entry_is_whole_on_the_benchmark_as_it_was(
     that ``flash_masked_share`` lists its cell ALONE: both hold only until
     the next appended entry, and a PR may not edit a benchmark file. So
     they run here on a view cut before this PR's entries (and those later
-    PRs appended): everything else they say about those cells still has to
-    hold."""
+    PRs appended, their cells included): everything else they say about
+    those cells still has to hold."""
     spec = importlib.util.spec_from_file_location(
         "perf_" + name, os.path.join(ROOT, "perf", "tests", name + ".py"))
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     bench = json.loads(json.dumps(mod.BENCH))
-    cell = "sdar-30b-a3b.solo"
-    bench["workloads"] = [w for w in bench["workloads"] if w["name"] != cell]
-    bench["configs"] = [c for c in bench["configs"]
-                        if c["name"] != "sdar-30b-a3b"]
+    # this PR's cell and every cell a later PR appended after it
+    cells = [w["name"] for w in bench["workloads"]]
+    later = set(cells[cells.index("sdar-30b-a3b.solo"):])
+    bench["workloads"] = [w for w in bench["workloads"]
+                          if w["name"] not in later]
+    used = {w["config"] for w in bench["workloads"]}
+    bench["configs"] = [c for c in bench["configs"] if c["name"] in used]
     bench["per_layer"] = [m for m in bench["per_layer"]
-                          if m.get("workloads") != [cell]
+                          if not set(m.get("workloads", ["-"])) <= later
                           # PR 52's six window-ledger entries, appended since
                           and not m["name"].startswith("window_")]
     for m in bench["per_layer"] + bench["end_to_end"]:
-        if cell in m.get("workloads", []):
-            m["workloads"].remove(cell)
+        if "workloads" in m:
+            m["workloads"] = [c for c in m["workloads"] if c not in later]
     monkeypatch.setattr(mod, "BENCH", bench)
     mod.test_the_cell_and_its_metrics_are_in_the_benchmark()

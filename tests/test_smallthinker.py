@@ -496,6 +496,12 @@ PARENT_PROGRAMS = {
     # the parent of PR 38)
     "smallthinker-21b-a3b": ("709017347b63e3dd", "9bbe6ef02ea94ffa"),
     "smallthinker-21b-a3b+chunked": ("9c01aa78db5036f3", "db51af1082833df1"),
+    # Laguna-S-2.1's own preset, recorded by the PR that added it (PR 54:
+    # per-kind head counts and rotaries, the gate a head); the seven
+    # accepted configurations above and Nemotron-H's and ZAYA1's presets
+    # traced the parent's programs under the new fields' defaults (88b5b68807359971 /
+    # 27338aa0d2cbb5eb and 0f4b402cd00dfcc0 / 271358fd1d490474 on both trees)
+    "laguna-s-2.1": ("50031e1f4b0835bd", "68986f85b99d9687"),
 }
 CHUNKED = {"moe_experts": 64, "moe_top_k": 4, "moe_experts_held": 8}
 

@@ -427,11 +427,13 @@ class TestChkpIoBenchSmoke:
     def test_chkp_io_bench_tiny(self, tmp_path):
         """Tier-1 smoke of benchmarks/chkp_io_bench.py at toy sizes: the
         sweep runs both profiles, parity holds (asserted inside), and
-        every arm reports positive timings."""
+        every arm reports positive timings. Best of three (``repeats``): on
+        a host loaded by the other test workers one 40 ms reading drowns in
+        the scheduler's noise (0.28 against 0.26 s read, twice, PR 54)."""
         from benchmarks.chkp_io_bench import run_bench
 
         res = run_bench(num_blocks=8, block_rows=8, dim=4,
-                        threads=(1, 4), repeats=1,
+                        threads=(1, 4), repeats=3,
                         tmp_root=str(tmp_path))
         assert set(res["profiles"]) == {"local", "remote_5ms"}
         for profile, arm in res["profiles"].items():
