@@ -1629,6 +1629,49 @@ class TransformerLM:
         recurrent layers' statistics — KDA blocks' ``{"decay", "beta"}``
         each ``[kda blocks]``, state-space layers' ``{"decay", "dt"}`` each
         ``[ssd layers]`` (None for a model without such layers)."""
+        x, aux, mixers = self._trunk(params, tokens, axis_name, pos_offset,
+                                     moe_axis)
+        with step_scope("head"):
+            return self._readout(params, x), aux, mixers
+
+    def _readout(self, params, x):
+        """The plain readout: the normed rows' float32 logits (for a stable
+        softmax). The readout is the embedding (weight-tied) unless the
+        model has a head of its own."""
+        head = (params["embed"].T if self.config.tie_embeddings
+                else params["head"])
+        return x.astype(jnp.float32) @ head
+
+    def _readout_tiles(self, x):
+        """The tiles of the ONE readout-and-cross-entropy op
+        (ops/readout_loss.py) for the normed rows ``x``, or None where its
+        ``plan`` leaves the shape to the plain readout."""
+        from harmony_tpu.ops import readout_loss
+
+        cfg = self.config
+        return readout_loss.plan(x.size // x.shape[-1], x.shape[-1],
+                                 cfg.vocab_size, cfg.tie_embeddings, x.dtype)
+
+    def _fused_nll(self, params, x, targets, tiles):
+        """``-log softmax(readout(x))[targets]``, float32 in ``targets``'
+        shape, by that op: the logits' passes ride on the tiles of the
+        readout's products."""
+        from harmony_tpu.ops.readout_loss import readout_nll
+        from harmony_tpu.utils.platform import trace_is_tpu
+
+        tied = self.config.tie_embeddings
+        with step_scope("head"):
+            nll = readout_nll(
+                x.reshape(-1, x.shape[-1]),
+                params["embed" if tied else "head"], targets.reshape(-1),
+                tied=tied, tiles=tiles, interpret=not trace_is_tpu())
+        return nll.reshape(targets.shape)
+
+    def _trunk(self, params, tokens, axis_name=None, pos_offset=0,
+               moe_axis=None):
+        """``(x, aux, mixers)``: everything up to the final norm — its rows
+        ``[B, S, d]`` in the activation dtype (block diffusion: the noisy
+        half), what the readout reads — and ``_forward``'s other two."""
         cfg = self.config
         with step_scope("embed"):
             x = _embed_in(cfg, params["embed"], params.get("pos"), tokens,
@@ -1685,13 +1728,9 @@ class TransformerLM:
             if cfg.objective == "block_diffusion":
                 x = x[x.shape[0] // 2:]  # ONE readout: the noisy rows
             x = _norm(x, params["ln_f"].astype(cfg.dtype), cfg.norm_eps)
-            # f32 logits for a stable softmax; the readout is the embedding
-            # (weight-tied) unless the model has a head of its own
-            head = params["embed"].T if cfg.tie_embeddings else params["head"]
         mixers = (jax.tree.map(lambda *xs: jnp.stack(xs), *mixers)
                   if mixers else None)
-        with step_scope("head"):
-            return x.astype(jnp.float32) @ head, aux, mixers
+        return x, aux, mixers
 
     def loss(self, params, tokens, axis_name=None) -> jnp.ndarray:
         """Mean next-token cross-entropy over the (single-device) batch,
@@ -1727,11 +1766,19 @@ class TransformerLM:
                 "objective='block_diffusion' trains on the batch tuple "
                 "(tokens, masked, rate) and on no sequence-parallel axis")
         tokens, masked, rate = batch
-        logits, aux, _ = self._forward(params, self.noised(tokens, masked))
+        x, aux, _ = self._trunk(params, self.noised(tokens, masked))
+        tiles = self._readout_tiles(x)
+        if tiles is None:
+            with step_scope("head"):
+                logits = self._readout(params, x)
+        else:
+            nll = self._fused_nll(params, x, tokens, tiles)
         with step_scope("loss"):
             f32 = jnp.float32
-            logp = jax.nn.log_softmax(logits, axis=-1)
-            nll = -jnp.take_along_axis(logp, tokens[..., None], axis=-1)[..., 0]
+            if tiles is None:
+                logp = jax.nn.log_softmax(logits, axis=-1)
+                nll = -jnp.take_along_axis(logp, tokens[..., None],
+                                           axis=-1)[..., 0]
             m = (masked != 0).astype(f32)
             weight = m / jnp.repeat(rate.astype(f32), cfg.diffusion_block,
                                     axis=1)
@@ -1756,14 +1803,21 @@ class TransformerLM:
         cfg = self.config
         if cfg.objective == "block_diffusion":
             return self._diffusion_loss_and_metrics(params, tokens, axis_name)
-        logits, aux, mixers = self._forward(params, tokens[:, :-1],
-                                            axis_name=axis_name)
+        x, aux, mixers = self._trunk(params, tokens[:, :-1],
+                                     axis_name=axis_name)
+        tiles = self._readout_tiles(x)
+        if tiles is None:
+            with step_scope("head"):
+                logits = self._readout(params, x)
+        else:
+            nll = self._fused_nll(params, x, tokens[:, 1:], tiles)
         kda = {}
         if mixers is not None:  # one recurrent kind a model
             kind = "ssd" if cfg.layer_pattern else "kda"
             kda = {f"{kind}_{stat}_mean": v for stat, v in mixers.items()}
         with step_scope("loss"):
-            ce = _next_token_ce(logits, tokens[:, 1:])
+            ce = (_next_token_ce(logits, tokens[:, 1:]) if tiles is None
+                  else nll.mean())
             if cfg.moe_seq_aux:  # each layer's mean over sequences, summed
                 return ce + cfg.moe_aux_weight * aux["seq_lb"], {
                     "ce": ce, "aux_seq": aux["seq_lb"],

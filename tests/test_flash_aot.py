@@ -222,6 +222,46 @@ def test_router_selection_compiles_for_v5e(one_chip, tokens, experts, k,
         2048: 256 if weighed else 512, 8: 60}.get(experts, 512)
 
 
+@pytest.mark.parametrize("n,d,v,tied", [
+    (8192, 768, 50257, True),      # gpt2-124m.solo: 81 rows in the last tile
+    (4096, 768, 50257, True),      # gpt2-124m.pair, a tenant
+    (8192, 2048, 32784, True),     # zaya1-8b.solo: 256 x 128 + 16 rows
+    (16384, 3072, 12544, False),   # laguna-s-2.1.solo
+    (16384, 2048, 20480, False),   # moonlight-16b-a3b.solo
+    (8192, 4096, 16384, False),    # nemotron-3-super-120b-a12b.solo: widest
+    (8192, 2304, 20480, False),    # kimi-linear-48b-a3b.solo
+    (16384, 2560, 18992, False),   # smallthinker-21b-a3b.solo: lanes ragged
+    (8192, 2048, 12576, False),    # olmoe-1b-7b.solo
+    (8192, 2048, 18992, False),    # sdar-30b-a3b.solo
+])
+def test_readout_loss_kernels_compile_for_v5e(one_chip, n, d, v, tied):
+    """``harmony_readout_fwd`` / ``_bwd_dx`` / ``_bwd_dw`` under their own
+    plan at the ten LM cells' readouts: every plan inside the kernels' VMEM
+    scope, ``dW``'s last block written as far as a ragged vocabulary goes,
+    and no ``[N, V]`` array but the logits (the other temporaries — the
+    head rounded to bfloat16, ``x`` transposed, the ``[N, 1]`` columns — are
+    a third of them at most)."""
+    from harmony_tpu.ops import readout_loss as R
+
+    tiles = R.plan(n, d, v, tied, jnp.bfloat16)
+    sd = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                   sharding=one_chip)
+
+    def loss(x, head, targets, w):
+        return (R.readout_nll(x, head, targets, tied=tied) * w).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        sd((n, d), jnp.bfloat16),
+        sd((v, d) if tied else (d, v), jnp.float32),
+        sd((n,), jnp.int32), sd((n,), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 3
+    for name in (R.FWD_NAME, R.DX_NAME, R.DW_NAME):
+        assert name in text
+    logits = 4 * n * (-(-v // tiles.vocab_tile) * tiles.vocab_tile)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5 * logits
+
+
 @pytest.mark.parametrize("tokens,cfg,checkpoint", [
     # kimi-linear-48b-a3b.solo: 65,536 slots in 16 chunks of 4,096, remat
     (8192, dict(num_experts=256, top_k=8, d_model=2304, d_ff=1024,

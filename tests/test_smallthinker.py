@@ -502,8 +502,22 @@ PARENT_PROGRAMS = {
     # traced the parent's programs under the new fields' defaults (88b5b68807359971 /
     # 27338aa0d2cbb5eb and 0f4b402cd00dfcc0 / 271358fd1d490474 on both trees)
     "laguna-s-2.1": ("50031e1f4b0835bd", "68986f85b99d9687"),
+    # PR 56 (the readout and the cross-entropy as ONE op where its plan
+    # serves the shape, ops/readout_loss.py) meant to change NO program
+    # above and changed none: every rehearse preset is 64 wide, ``plan``
+    # returns None there and the plain readout is the parent's, equation for
+    # equation (all nine pairs are the parent's, commit f579cd5). What the
+    # cells run is pinned at a preset the op engages in — gpt2's (tied) and
+    # OLMoE's (a head of its own) 128 wide over 8,192 columns, 2^24 logits
+    # — recorded by PR 56; the parent's programs at the same preset, plain:
+    # f96d5702cd91ca47 / 04afbd327fc46630 and 6d3d23a449c1ed3a /
+    # bfbcf0c316f09398
+    "gpt2-124m+readout": ("3cdaeb5b997d6567", "cfe17acfe599734d"),
+    "olmoe-1b-7b+readout": ("5574b70d6e1dfaca", "fdc7856073f44563"),
 }
 CHUNKED = {"moe_experts": 64, "moe_top_k": 4, "moe_experts_held": 8}
+READOUT = {"d_model": 128, "vocab_size": 8192}
+VARIANTS = {"": {}, "chunked": CHUNKED, "readout": READOUT}
 
 
 def _renumbered(text):
@@ -521,12 +535,12 @@ def _renumbered(text):
 
 
 def _program_hashes(config):
-    name, _, chunked = config.partition("+")
+    name, _, variant = config.partition("+")
     with open(os.path.join(ROOT, "perf", "configs", name + ".json")) as f:
         conf = json.load(f)
     app = {**conf["job"]["app_params"], **conf["rehearse"]["app_params"],
            "max_seq": 1024, "dtype": jnp.bfloat16,
-           **(CHUNKED if chunked else {})}
+           **VARIANTS[variant]}
     lm = TransformerLM(_config(app))
     params = jax.eval_shape(lambda: lm.init(jax.random.PRNGKey(0)))
     toks = jax.ShapeDtypeStruct((2, 1025), jnp.int32)
