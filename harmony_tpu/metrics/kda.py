@@ -72,15 +72,22 @@ def _layers_gauge():
 
 
 def note_layer_kinds(job: str, kinds: Sequence[str],
-                     heads: Optional[Dict[str, int]] = None) -> None:
-    """Record the job's blocks by kind (``layer_kinds()``) and, for the
-    kinds in ``heads``, the heads a block of that kind mixes with."""
+                     heads: Optional[Dict[str, int]] = None,
+                     loop_steps: int = 1) -> None:
+    """Record the job's blocks by kind (``layer_kinds()``), for the kinds in
+    ``heads`` the heads a block of that kind mixes with, and how many times
+    a step passes the blocks (``loop_steps``: 1 but for a looped model)."""
+    from harmony_tpu.metrics.registry import get_registry
+
     gauge = _layers_gauge()
     for kind in sorted(set(kinds)):
         gauge.labels(job=job, kind=kind).set(list(kinds).count(kind))
+    get_registry().gauge(
+        "harmony_model_loop_steps",
+        "Times a step of the job's model passes its layers over ONE set of "
+        "weights (1: an ordinary model)", ("job",)
+    ).labels(job=job).set(loop_steps)
     if heads:
-        from harmony_tpu.metrics.registry import get_registry
-
         by_kind = get_registry().gauge(
             "harmony_model_heads",
             "Heads of a block of each token-mixer kind in the job's model "

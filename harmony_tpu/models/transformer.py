@@ -306,6 +306,27 @@ class TransformerConfig:
     kind_heads: Any = ()
     kind_rope: Any = ()
     attn_gate: str = "none"         # "none" | "head"
+    # Ouro's block and step (``model_type`` "ouro": ByteDance's Ouro-2.6B,
+    # the looped language model of arXiv:2510.25741), ``attn_kind="mha"``
+    # under ``pos="rope"``, dense: ``loop_steps`` (``total_ut_steps``): the
+    # whole stack of layers runs that many times over ONE set of weights —
+    # pass ``t + 1`` starts from pass ``t``'s rows AFTER the final norm,
+    # which therefore closes every pass, and a weight's gradient is the sum
+    # over its uses. ``sandwich_norm``: a block norms each sublayer's OUTPUT
+    # as well as its input, ``x + norm(f(norm(x)))``, four norms a block
+    # (``ln1_post`` / ``ln2_post`` beside ``ln1`` / ``ln2``).
+    # ``exit_gate``: after every pass an exit — the pass's normed rows
+    # through the ONE readout, and ``lam = sigmoid(rows . exit_w + exit_b)``,
+    # one scalar a position; the exit distribution ``p(t) = lam(t) prod_{s <
+    # t} (1 - lam(s))``, the last pass taking what is left, joins the passes'
+    # cross-entropies into one loss, ``mean_n [sum_t p(t) nll(t) -
+    # exit_entropy_weight H(p)]`` (the paper's entropy-regularised expected
+    # loss over the exit step; ``loss_and_metrics``). Without the gate a
+    # looped model's loss is the last pass's alone.
+    loop_steps: int = 1
+    sandwich_norm: bool = False
+    exit_gate: bool = False
+    exit_entropy_weight: float = 0.0
     # Standard deviation the embedding rows are drawn with. GPT-2's 0.02
     # leaves a row at a fiftieth of what a block's fan-in projections add to
     # it, which nothing here divides by depth: attention's average over the
@@ -579,7 +600,39 @@ class TransformerConfig:
                 f"mask_token {self.mask_token}, pos {self.pos!r}, "
                 f"attn_kind {self.attn_kind!r})")
         self._check_kinds()
+        self._check_loop()
         validate_attn(self.attn)
+
+    def _check_loop(self) -> None:
+        """``loop_steps`` / ``sandwich_norm`` / ``exit_gate`` /
+        ``exit_entropy_weight``: Ouro's, beside dense ``mha`` blocks under
+        rotary positions and nothing else."""
+        if self.loop_steps < 1 or self.exit_entropy_weight < 0:
+            raise ValueError(
+                f"loop_steps {self.loop_steps} counts the passes (>= 1) and "
+                f"exit_entropy_weight {self.exit_entropy_weight} weighs an "
+                "entropy (>= 0)")
+        if self.exit_gate and self.loop_steps < 2:
+            raise ValueError("exit_gate joins the exits of several passes: "
+                             "set loop_steps > 1")
+        if self.exit_entropy_weight and not self.exit_gate:
+            raise ValueError("exit_entropy_weight weighs the entropy of the "
+                             "gate's exit distribution: set exit_gate")
+        if (self.loop_steps > 1 or self.sandwich_norm) and (
+                self.attn_kind != "mha" or self.pos != "rope"
+                or self.moe_experts or self.layer_pattern
+                or self.linear_layers or self.window_layers or self.cca
+                or self.objective != "next_token"):
+            raise ValueError(
+                "loop_steps > 1 / sandwich_norm run dense attn_kind='mha' "
+                "blocks under pos='rope' and the next-token objective: a "
+                "learned position table would be added once for all passes, "
+                "an expert layer's balance loss and counts have no rule for "
+                "a layer that routes several times a step, the recurrent "
+                "(KDA, state-space) mixers' statistics are one a layer, the "
+                "two kinds of a windowed model and CCA's blocks carry no "
+                "ln*_post leaves, and block diffusion's two streams have no "
+                "exit")
 
     def _check_kinds(self) -> None:
         """``kind_heads`` / ``kind_rope`` / ``attn_gate``: held as tuples of
@@ -768,7 +821,8 @@ class TransformerConfig:
                 and not self.head_norm
                 and self.objective == "next_token"
                 and not (self.kind_heads or self.kind_rope
-                         or self.attn_gate != "none")):
+                         or self.attn_gate != "none")
+                and self.loop_steps == 1 and not self.sandwich_norm):
             raise ValueError(
                 f"{who} runs the GPT-2-era block only (learned positions, "
                 "GELU, tied readout, Switch experts); rotary / no-position / "
@@ -776,7 +830,8 @@ class TransformerConfig:
                 "KDA linear-attention / grouped-query / windowed / leading-dense "
                 "/ layer-pattern / CCA / partial-rotary / scaled-merge / "
                 "head-norm / block-diffusion / per-kind heads and rotary / "
-                "gated-attention configs train through TransformerLM.loss "
+                "gated-attention / looped / sandwich-norm / exit-gate "
+                "configs train through TransformerLM.loss "
                 "and TransformerTrainer")
 
 
@@ -1038,6 +1093,9 @@ class TransformerLM:
                 if cfg.attn_gate == "head":  # stored [heads, d], as KDA's wb
                     layer["wgate"] = dense(jax.random.fold_in(ks[0], 2),
                                            (d, cfg.heads(kinds[i]))).T
+                if cfg.sandwich_norm:  # on the sublayers' outputs
+                    layer["ln1_post"] = jnp.ones((d,), jnp.float32)
+                    layer["ln2_post"] = jnp.ones((d,), jnp.float32)
             if cfg.qk_norm:
                 layer["q_norm"] = jnp.ones((d,), jnp.float32)
                 layer["k_norm"] = jnp.ones((d,), jnp.float32)
@@ -1074,6 +1132,9 @@ class TransformerLM:
         if not cfg.tie_embeddings:
             params["head"] = dense(jax.random.fold_in(k_emb, 1),
                                    (d, cfg.vocab_size))
+        if cfg.exit_gate:  # fan-in rule: a logit of unit scale on normed rows
+            params["exit_w"] = dense(jax.random.fold_in(k_emb, 2), (d, 1))[:, 0]
+            params["exit_b"] = jnp.zeros((), jnp.float32)
         return params
 
     def init_numpy(self, seed: int = 0) -> Dict[str, Any]:
@@ -1189,6 +1250,9 @@ class TransformerLM:
                 }
                 if cfg.attn_gate == "head":
                     layer["wgate"] = dense((d, cfg.heads(kinds[i]))).T.copy()
+                if cfg.sandwich_norm:
+                    layer["ln1_post"] = np.ones((d,), np.float32)
+                    layer["ln2_post"] = np.ones((d,), np.float32)
             if cfg.qk_norm:
                 layer["q_norm"] = np.ones((d,), np.float32)
                 layer["k_norm"] = np.ones((d,), np.float32)
@@ -1261,6 +1325,9 @@ class TransformerLM:
                 (cfg.max_seq, d))).astype(np.float32)
         if not cfg.tie_embeddings:
             params["head"] = dense((d, cfg.vocab_size))
+        if cfg.exit_gate:
+            params["exit_w"] = dense((d, 1))[:, 0].copy()
+            params["exit_b"] = np.zeros((), np.float32)
         return params
 
     # -- forward ---------------------------------------------------------
@@ -1492,7 +1559,9 @@ class TransformerLM:
         ``kind_heads`` how many query heads each has). ``route_state``: what
         the MLP router of the block before left for this one's
         (``moe_router_hidden``; None in the first block); this block's is
-        ``aux["state"]``."""
+        ``aux["state"]``. Under ``sandwich_norm`` each sublayer's output
+        passes a norm of its own (``ln1_post`` / ``ln2_post``) before its
+        residual add."""
         cfg = self.config
         eps = cfg.norm_eps
         x_in = x
@@ -1503,6 +1572,9 @@ class TransformerLM:
         else:
             y, mix = self._softmax_mixer(xn, layer, axis_name, pos_offset,
                                          kind), None
+        if cfg.sandwich_norm:
+            with step_scope("norm"):
+                y = _norm(y, layer["ln1_post"].astype(cfg.dtype), eps)
         x = _merge(x, y, layer.get("merge1"))
         with step_scope("norm"):
             xn = _norm(x, layer["ln2"].astype(cfg.dtype), eps)
@@ -1510,6 +1582,9 @@ class TransformerLM:
         if route_state is not None:
             route["route_state"] = route_state
         out, aux = ffn_apply(cfg, layer, xn, moe_axis=moe_axis, **route)
+        if cfg.sandwich_norm:
+            with step_scope("norm"):
+                out = _norm(out, layer["ln2_post"].astype(cfg.dtype), eps)
         return _merge(x, out, layer.get("merge2")), aux, mix
 
     def _cca_latent(self, q, k, v, p):
@@ -1629,10 +1704,10 @@ class TransformerLM:
         recurrent layers' statistics — KDA blocks' ``{"decay", "beta"}``
         each ``[kda blocks]``, state-space layers' ``{"decay", "dt"}`` each
         ``[ssd layers]`` (None for a model without such layers)."""
-        x, aux, mixers = self._trunk(params, tokens, axis_name, pos_offset,
-                                     moe_axis)
-        with step_scope("head"):
-            return self._readout(params, x), aux, mixers
+        exits, aux, mixers = self._trunk(params, tokens, axis_name,
+                                         pos_offset, moe_axis)
+        with step_scope("head"):  # a looped model answers with its last pass
+            return self._readout(params, exits[-1]), aux, mixers
 
     def _readout(self, params, x):
         """The plain readout: the normed rows' float32 logits (for a stable
@@ -1669,9 +1744,14 @@ class TransformerLM:
 
     def _trunk(self, params, tokens, axis_name=None, pos_offset=0,
                moe_axis=None):
-        """``(x, aux, mixers)``: everything up to the final norm — its rows
-        ``[B, S, d]`` in the activation dtype (block diffusion: the noisy
-        half), what the readout reads — and ``_forward``'s other two."""
+        """``(exits, aux, mixers)``: everything up to the final norm — its
+        rows ``[B, S, d]`` in the activation dtype (block diffusion: the noisy
+        half), what the readout reads, as a tuple of one — and ``_forward``'s
+        other two. Under ``loop_steps`` = T > 1 the layers run T times over
+        the same ``params["layers"]`` (a static loop: ``blk<i>`` stays the
+        LAYER's, so a layer's T uses fold into one row of a scope table), the
+        final norm closes every pass — its rows are that pass's exit AND the
+        next pass's input — and ``exits`` holds all T passes' rows."""
         cfg = self.config
         with step_scope("embed"):
             x = _embed_in(cfg, params["embed"], params.get("pos"), tokens,
@@ -1703,17 +1783,25 @@ class TransformerLM:
         routed = []  # dropless layers' statistics
         mixers = []  # KDA blocks' statistics
         state = {}   # an MLP router's rows, for the next block's router
-        for i, layer in enumerate(params["layers"]):
-            with step_scope("blk", i):
-                x, a, mix = by_kind.get(kinds[i], block)(x, layer, **state)
-            if mix is not None:
-                mixers.append(mix)
-            if isinstance(a, dict):
-                if "state" in a:
-                    state = {"route_state": a.pop("state")}
-                routed.append(a)
-            else:
-                aux = aux + a
+        exits = []   # a looped model's passes, each after the final norm
+        for _ in range(cfg.loop_steps):
+            for i, layer in enumerate(params["layers"]):
+                with step_scope("blk", i):
+                    x, a, mix = by_kind.get(kinds[i], block)(x, layer,
+                                                             **state)
+                if mix is not None:
+                    mixers.append(mix)
+                if isinstance(a, dict):
+                    if "state" in a:
+                        state = {"route_state": a.pop("state")}
+                    routed.append(a)
+                else:
+                    aux = aux + a
+            if cfg.loop_steps > 1:
+                with step_scope("head"):
+                    x = _norm(x, params["ln_f"].astype(cfg.dtype),
+                              cfg.norm_eps)
+                exits.append(x)
         if kept:
             from harmony_tpu.runtime.progcache import note_remat_saved
 
@@ -1724,13 +1812,69 @@ class TransformerLM:
             if "skipped" in aux:
                 aux["skipped_by_layer"] = jnp.stack(
                     [a["skipped"] for a in routed])
-        with step_scope("head"):
-            if cfg.objective == "block_diffusion":
-                x = x[x.shape[0] // 2:]  # ONE readout: the noisy rows
-            x = _norm(x, params["ln_f"].astype(cfg.dtype), cfg.norm_eps)
+        if not exits:
+            with step_scope("head"):
+                if cfg.objective == "block_diffusion":
+                    x = x[x.shape[0] // 2:]  # ONE readout: the noisy rows
+                x = _norm(x, params["ln_f"].astype(cfg.dtype), cfg.norm_eps)
+            exits = [x]
         mixers = (jax.tree.map(lambda *xs: jnp.stack(xs), *mixers)
                   if mixers else None)
-        return x, aux, mixers
+        return tuple(exits), aux, mixers
+
+    def _exit_gate(self, params, exits):
+        """``lam [T, B, S]`` float32: each pass's exit gate on its normed
+        rows, ``sigmoid(rows . exit_w + exit_b)`` — the products in float32
+        on the vector unit (a ``d``-wide row a position: no matmul)."""
+        f32 = jnp.float32
+        return jnp.stack([jax.nn.sigmoid(
+            jnp.sum(h.astype(f32) * params["exit_w"], axis=-1)
+            + params["exit_b"]) for h in exits])
+
+    def _exit_loss_and_metrics(self, params, exits, targets):
+        """A gated looped model's ``(loss, metrics)`` from its T passes'
+        normed rows: every pass read out by the ONE readout (the fused op
+        where its plan serves the shape: ``nll(t)`` a row, whose row weights
+        ``p(t) / N`` carry a gradient of their own — the gate learns through
+        ``nll(t)``'s value, the trunk through the weights), joined by
+        :func:`exit_distribution` under the scope ``exit.gate``. Metrics:
+        ``ce`` the last pass's mean cross-entropy (what inference answers
+        with), ``exit_entropy`` the mean entropy of ``p``, and the vectors
+        ``ce_by_exit [T]`` and ``exit_mass [T]`` — the SUM over the step's
+        positions of ``p(t)``, so its own sum counts the positions
+        (metrics/loop.py)."""
+        cfg = self.config
+        tiles = self._readout_tiles(exits[-1])
+
+        def nll_of(h):
+            if tiles is not None:
+                return self._fused_nll(params, h, targets, tiles)
+            with step_scope("head"):
+                logp = jax.nn.log_softmax(self._readout(params, h), axis=-1)
+                return -jnp.take_along_axis(logp, targets[..., None],
+                                            axis=-1)[..., 0]
+
+        nll = [nll_of(h) for h in exits]
+        with step_scope("exit.gate"):
+            nll = jnp.stack(nll)                              # [T, B, S]
+            p = exit_distribution(self._exit_gate(params, exits))
+            entropy = exit_entropy(p)                         # [B, S]
+            loss = jnp.mean(jnp.sum(p * nll, axis=0)
+                            - cfg.exit_entropy_weight * entropy)
+            return loss, {"ce": nll[-1].mean(),
+                          "exit_entropy": entropy.mean(),
+                          "ce_by_exit": nll.mean(axis=(1, 2)),
+                          "exit_mass": p.sum(axis=(1, 2))}
+
+    def exits(self, params, tokens):
+        """``(logits [T, B, S, V], lam [T, B, S])`` of every pass of a
+        looped model, through the plain readout: what a check against a
+        reference reads (``apply`` answers with ``logits[-1]``)."""
+        exits, _, _ = self._trunk(params, tokens)
+        with step_scope("head"):
+            logits = jnp.stack([self._readout(params, h) for h in exits])
+        with step_scope("exit.gate"):
+            return logits, self._exit_gate(params, exits)
 
     def loss(self, params, tokens, axis_name=None) -> jnp.ndarray:
         """Mean next-token cross-entropy over the (single-device) batch,
@@ -1766,7 +1910,7 @@ class TransformerLM:
                 "objective='block_diffusion' trains on the batch tuple "
                 "(tokens, masked, rate) and on no sequence-parallel axis")
         tokens, masked, rate = batch
-        x, aux, _ = self._trunk(params, self.noised(tokens, masked))
+        (x,), aux, _ = self._trunk(params, self.noised(tokens, masked))
         tiles = self._readout_tiles(x)
         if tiles is None:
             with step_scope("head"):
@@ -1803,8 +1947,11 @@ class TransformerLM:
         cfg = self.config
         if cfg.objective == "block_diffusion":
             return self._diffusion_loss_and_metrics(params, tokens, axis_name)
-        x, aux, mixers = self._trunk(params, tokens[:, :-1],
-                                     axis_name=axis_name)
+        exits, aux, mixers = self._trunk(params, tokens[:, :-1],
+                                         axis_name=axis_name)
+        if cfg.exit_gate:
+            return self._exit_loss_and_metrics(params, exits, tokens[:, 1:])
+        x = exits[-1]  # a looped model without the gate: the last pass alone
         tiles = self._readout_tiles(x)
         if tiles is None:
             with step_scope("head"):
@@ -1848,6 +1995,27 @@ def routing_losses(stats, num_experts: int):
     lb = num_experts * jnp.sum(
         lax.stop_gradient(stats["tokens"]) / n * stats["prob_sum"] / n)
     return lb, stats["z_sum"] / n
+
+
+def exit_distribution(lam):
+    """The exit distribution ``p [T, ...]`` of the gates ``lam [T, ...]``:
+    ``p(t) = lam(t) S(t-1)`` with ``S(0) = 1``, ``S(t) = S(t-1) (1 -
+    lam(t))`` — the chance of not having left before pass ``t`` times that of
+    leaving there — and the LAST pass takes what is left, ``p(T) = S(T-1)``
+    (its own gate decides nothing), so ``p`` sums to 1."""
+    stay, p = jnp.ones_like(lam[0]), []
+    for t in range(lam.shape[0] - 1):
+        p.append(lam[t] * stay)
+        stay = stay * (1.0 - lam[t])
+    return jnp.stack(p + [stay])
+
+
+def exit_entropy(p):
+    """``H(p) = -sum_t p(t) log p(t)`` over the leading axis, ``0 log 0``
+    read as 0 (in the value and in the gradient)."""
+    live = p > 0
+    return -jnp.sum(jnp.where(live, p * jnp.log(jnp.where(live, p, 1.0)),
+                              0.0), axis=0)
 
 
 def _next_token_ce(logits, targets) -> jnp.ndarray:
@@ -2402,7 +2570,8 @@ class TransformerTrainer(PyTreeTrainer):
         cfg = self.config
         kinds = cfg.layer_kinds()
         kda.note_layer_kinds(current_job() or "-", kinds, heads={
-            kind: cfg.heads(kind) for kind in set(kinds) if kind != "moe"})
+            kind: cfg.heads(kind) for kind in set(kinds) if kind != "moe"},
+            loop_steps=cfg.loop_steps)
 
     def observe_step_vectors(self, job_id: str, vectors) -> None:
         if "moe_expert_tokens" in vectors:
@@ -2416,6 +2585,10 @@ class TransformerTrainer(PyTreeTrainer):
             from harmony_tpu.metrics import diffusion
 
             diffusion.observe(job_id, vectors["diffusion_tokens"])
+        if "exit_mass" in vectors:
+            from harmony_tpu.metrics import loop
+
+            loop.observe(job_id, vectors["exit_mass"], vectors["ce_by_exit"])
         from harmony_tpu.metrics import kda
 
         for kind, stats in kda.STATS.items():  # the recurrent layers' pairs

@@ -59,7 +59,7 @@ VOCABULARY = (
     "ffn",
     "moe.route", "moe.latent", "moe.dispatch", "moe.experts", "moe.shared",
     "moe.combine", "moe.aux",
-    "head", "loss",
+    "head", "loss", "exit.gate",
     "fm.interact", "fm.loss",
 )
 BLOCK = "blk"

@@ -115,6 +115,9 @@ _CELL_CALLS = {
                   (1024, 512, 512), None),
     "olmoe-1b-7b": ((2, 16, 16, 4096, 128, 128, None, None),
                     (4096, 512, 512), 22.5),
+    # sixteen block applications a step (4 layers x 4 passes), one call each
+    "ouro-2.6b": ((1, 16, 16, 4096, 128, 128, None, None),
+                  (4096, 512, 512), 22.5),
     "moonlight-16b-a3b": ((2, 16, 16, 8192, 192, 128, None, None),
                           (8192, 512, 512), 51.25),
     "kimi-linear-48b-a3b": ((1, 8, 8, 8192, 192, 128, None, None),
@@ -233,10 +236,11 @@ def test_router_selection_compiles_for_v5e(one_chip, tokens, experts, k,
     (16384, 2560, 18992, False),   # smallthinker-21b-a3b.solo: lanes ragged
     (8192, 2048, 12576, False),    # olmoe-1b-7b.solo
     (8192, 2048, 18992, False),    # sdar-30b-a3b.solo
+    (4096, 2048, 49152, False),    # ouro-2.6b.solo: an exit, four a step
 ])
 def test_readout_loss_kernels_compile_for_v5e(one_chip, n, d, v, tied):
     """``harmony_readout_fwd`` / ``_bwd_dx`` / ``_bwd_dw`` under their own
-    plan at the ten LM cells' readouts: every plan inside the kernels' VMEM
+    plan at the LM cells' readouts: every plan inside the kernels' VMEM
     scope, ``dW``'s last block written as far as a ragged vocabulary goes,
     and no ``[N, V]`` array but the logits (the other temporaries — the
     head rounded to bfloat16, ``x`` transposed, the ``[N, 1]`` columns — are

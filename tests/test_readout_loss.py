@@ -265,7 +265,7 @@ def test_apply_still_returns_the_logits(config):
     rows = batch[0].shape[0] if isinstance(batch, tuple) else tokens.shape[0]
     assert logits.shape == (rows, tokens.shape[1], lm.config.vocab_size)
     assert logits.dtype == F32
-    x, _, _ = lm._trunk(params, tokens)
+    (x,), _, _ = lm._trunk(params, tokens)  # one pass, one exit (PR 57)
     np.testing.assert_array_equal(logits, lm._readout(params, x))
 
 

@@ -514,6 +514,11 @@ PARENT_PROGRAMS = {
     # bfbcf0c316f09398
     "gpt2-124m+readout": ("3cdaeb5b997d6567", "cfe17acfe599734d"),
     "olmoe-1b-7b+readout": ("5574b70d6e1dfaca", "fdc7856073f44563"),
+    # Ouro-2.6B's own preset, recorded by the PR that added it (PR 57: 2
+    # layers run 4 times over one set of weights, four norms a block, an exit
+    # a pass joined by the gate); every pair above is the parent's (commit
+    # feca772) under the new fields' defaults, none recorded again
+    "ouro-2.6b": ("ffde8f15c4af6b77", "71078731be939ba9"),
 }
 CHUNKED = {"moe_experts": 64, "moe_top_k": 4, "moe_experts_held": 8}
 READOUT = {"d_model": 128, "vocab_size": 8192}
