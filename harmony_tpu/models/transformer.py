@@ -842,6 +842,9 @@ def rope(x, theta: float, pos_offset=0, width: Optional[int] = None,
          scaled: Optional[Rotary] = None):
     """Rotate-half rotary positions on ``x [B, H, S, hd]`` (positions
     ``pos_offset .. pos_offset+S-1``): float32 angles, result in x's dtype.
+    Since PR 58 this is the REFERENCE ``ops/rotary.py``'s kernel is held to
+    and the fallback (``_turned``) wherever that kernel declines: off the
+    TPU, heads that are not whole lane tiles, the latent blocks.
     ``width``: only the first that many columns of a head are turned, as a
     head that wide would be (``rope_fraction``); the others pass.
     ``scaled``: a :class:`Rotary` under YaRN — its frequencies in place of
@@ -865,6 +868,38 @@ def rope(x, theta: float, pos_offset=0, width: Optional[int] = None,
     x1, x2 = jnp.split(xf, 2, axis=-1)
     return (xf * cos + jnp.concatenate([-x2, x1], axis=-1) * sin
             ).astype(x.dtype)
+
+
+def _rotary_serves(positions, hd, dtype, heads=(None,)) -> bool:
+    """Does ``ops.rotary``'s kernel take this trace's turn — a TPU's, and
+    a shape its plan serves for every one of ``heads`` (a head count: the
+    operand lies ``[B, S, heads hd]``; None: ``[B, heads, S, hd]``)?"""
+    from harmony_tpu.ops import rotary
+    from harmony_tpu.utils.platform import trace_is_tpu
+
+    return trace_is_tpu() and all(
+        rotary.plan(positions, hd, dtype, n) is not None for n in heads)
+
+
+def _turned(q, k, theta, pos_offset, heads=None, **part):
+    """``rope`` of a softmax block's ``q`` and ``k [B, heads, S, hd]`` —
+    where ``_rotary_serves`` by ``ops.rotary``'s kernel, two calls over ONE
+    pair of tables. ``heads`` = (query heads, key heads): both lie ``[B, S,
+    heads hd]`` and the kernel turns them to heads as it reads (the caller
+    has asked ``_rotary_serves``)."""
+    from harmony_tpu.ops import rotary
+
+    if heads is None:
+        S, hd = q.shape[2], q.shape[3]
+        if not _rotary_serves(S, hd, q.dtype):
+            return (rope(q, theta, pos_offset, **part),
+                    rope(k, theta, pos_offset, **part))
+        heads = (None, None)
+    else:
+        S, hd = q.shape[1], q.shape[2] // heads[0]
+    tab, shifts = rotary.tables(S, hd, theta, pos_offset, **part)
+    return tuple(rotary.turn(t, tab, shifts, heads=n)
+                 for t, n in zip((q, k), heads))
 
 
 #: a KDA block's initial decay, as flash-linear-attention's ``kda`` layer
@@ -1650,7 +1685,18 @@ class TransformerLM:
                     k = _norm(k, layer["k_norm"].astype(cfg.dtype), eps)
                 to_heads = lambda t: t.reshape(B, S, -1, hd).transpose(
                     0, 2, 1, 3)
-                if not cfg.cca:
+                rot = cfg.rotary(kind)
+                # where the rotary kernel serves the shape and nothing works
+                # on single heads before it, q and k stay as the projection
+                # left them: the kernel's index map is their transpose
+                heads = (h, cfg.kv_heads)
+                by_rows = (rot is not None and not cfg.cca
+                           and not cfg.head_norm
+                           and _rotary_serves(S, hd, q.dtype, heads))
+                heads = heads if by_rows else None
+                if by_rows:
+                    v = to_heads(v)
+                elif not cfg.cca:
                     q, k, v = to_heads(q), to_heads(k), to_heads(v)
                 if cfg.head_norm:  # a head at a time, one weight for all
                     q = _norm(q, layer["q_head_norm"].astype(cfg.dtype), eps)
@@ -1659,15 +1705,14 @@ class TransformerLM:
                 with step_scope("mixer.cca"):
                     q, k, v = (to_heads(t) for t in self._cca_latent(
                         q, k, v, layer["cca"]))
-            rot = cfg.rotary(kind)
             if rot is not None:
                 part = ({} if rot.fraction == 1.0 else
                         {"width": rot.width(hd)})
                 if rot.yarn is not None:
                     part["scaled"] = rot
                 with step_scope("mixer.rope"):
-                    q = rope(q, rot.theta, pos_offset, **part)
-                    k = rope(k, rot.theta, pos_offset, **part)
+                    q, k = _turned(q, k, rot.theta, pos_offset, heads,
+                                   **part)
         with step_scope("mixer.core"):
             o = self._attention(q, k, v, axis_name, window)
         if cfg.attn_gate == "head":  # one scalar a head and position

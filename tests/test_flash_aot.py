@@ -266,6 +266,46 @@ def test_readout_loss_kernels_compile_for_v5e(one_chip, n, d, v, tied):
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5 * logits
 
 
+#: the rotary turn of every LM cell that has one a 128-wide head: (batch,
+#: heads, positions, columns turned, by rows: the operand lies [B, S, H hd])
+_ROTARY_CALLS = {
+    "sdar-30b-a3b-q": (2, 32, 8192, 128, False),   # behind a norm a head
+    "sdar-30b-a3b-k": (2, 4, 8192, 128, False),
+    "zaya1-8b-q": (1, 8, 8192, 64, False),         # behind CCA, half turned
+    "smallthinker-21b-a3b-q": (1, 28, 16384, 128, True),
+    "smallthinker-21b-a3b-k": (1, 4, 16384, 128, True),
+    "olmoe-1b-7b": (2, 16, 4096, 128, True),
+    "ouro-2.6b": (1, 16, 4096, 128, True),
+    "laguna-s-2.1-full-q": (1, 6, 16384, 64, True),    # six heads a step
+    "laguna-s-2.1-window-q": (1, 9, 16384, 128, True),  # nine
+    "laguna-s-2.1-k": (1, 1, 16384, 128, True),
+}
+
+
+@pytest.mark.parametrize("call", sorted(_ROTARY_CALLS))
+def test_rotary_kernel_compiles_at_each_cells_shape(one_chip, call):
+    """``harmony_rotary`` forward and backward under its own plan: the lane
+    rolls, a row tile of 2,048 x 128 with three tables inside the scoped
+    VMEM, and the ``[B, S, H hd]`` walk's blocks of several heads."""
+    from harmony_tpu.ops import rotary as R
+
+    b, h, s, turned, by_rows = _ROTARY_CALLS[call]
+    x = jax.ShapeDtypeStruct((b, s, h * 128) if by_rows else (b, h, s, 128),
+                             jnp.bfloat16, sharding=one_chip)
+    assert R.plan(s, 128, jnp.bfloat16, h if by_rows else None) is not None
+
+    def loss(x, offset):
+        tab, shifts = R.tables(s, 128, 1e6, offset, turned)
+        y = R.turn(x, tab, shifts, heads=h if by_rows else None)
+        return (y.astype(jnp.float32) ** 2).sum()
+
+    text = jax.jit(jax.grad(loss)).lower(
+        x, jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    ).compile().as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 2
+    assert R.KERNEL_NAME in text
+
+
 @pytest.mark.parametrize("tokens,cfg,checkpoint", [
     # kimi-linear-48b-a3b.solo: 65,536 slots in 16 chunks of 4,096, remat
     (8192, dict(num_experts=256, top_k=8, d_model=2304, d_ff=1024,

@@ -516,10 +516,13 @@ def test_the_step_holds_each_kernel_once_an_application_and_one_traced_body(
     assert calls == {
         "harmony_flash_fwd": 12, "harmony_flash_bwd": 12,
         "harmony_readout_fwd": 4, "harmony_readout_bwd_dx": 4,
-        "harmony_readout_bwd_dw": 4}
+        "harmony_readout_bwd_dw": 4,
+        # the turn of q and of k (PR 58: 128-wide heads), forward, again
+        # under ``remat`` and backward, an application
+        "harmony_rotary": 12 * 2 * 3}
     rows = {r["kernel"] for r in progcache.kernel_plans()["plan-ouro"]}
     assert {"harmony_flash_fwd", "harmony_flash_bwd",
-            "harmony_readout_fwd"} <= rows
+            "harmony_readout_fwd", "harmony_rotary"} <= rows
     kept = {r["name"]: r for r in progcache.remat_saved()["plan-ouro"]}
     assert kept["flash_out"]["arrays"] == kept["flash_lse"]["arrays"] == 12
     assert kept["flash_out"]["bytes"] == 12 * 2048 * 256 * 2

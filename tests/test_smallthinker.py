@@ -519,10 +519,32 @@ PARENT_PROGRAMS = {
     # a pass joined by the gate); every pair above is the parent's (commit
     # feca772) under the new fields' defaults, none recorded again
     "ouro-2.6b": ("ffde8f15c4af6b77", "71078731be939ba9"),
+    # PR 58 (the rotary turn of q and k as ONE lane-roll kernel where its
+    # plan serves the shape, ops/rotary.py) meant to change NO program above
+    # and changed none: every preset's heads are 16 wide, ``plan`` returns
+    # None there and ``_turned`` is ``rope`` twice, equation for equation —
+    # gpt2's (learned positions), Kimi Linear's (no positions) and
+    # Moonlight's (latent blocks: their rotary keeps ``rope``) with them,
+    # all twelve pairs the parent's (commit 95dff55). What the cells run is
+    # pinned at a preset the kernel engages in — 512 wide, heads of 128,
+    # 1,024 positions: OLMoE's (QK-norm, q and k read as the projection left
+    # them), SmallThinker's (28-over-4 grouped, windowed), Laguna's (two
+    # kinds: YaRN on half a head = two rolls, plain on a whole head = one)
+    # and Ouro's (a layer's turn four times a step) — RECORDED BY PR 58,
+    # which meant to change these four; the parent's programs at the same
+    # preset, ``rope`` in XLA, in this order: e7db93dd984b6f4f /
+    # dd0f930dc7f05ebd, 42d953ceb953ee36 / 85c61d826e37492f,
+    # a99ae645e09b7f70 / 342af6f6bfff2811, 8c72b87ff484cdad /
+    # 8bae449d34655260
+    "olmoe-1b-7b+rotary": ("094302b80fb7dddb", "7b218ee55bddeb64"),
+    "smallthinker-21b-a3b+rotary": ("8dcf353b53475065", "685826e3ec19b101"),
+    "laguna-s-2.1+rotary": ("11c72726b3016872", "feff4d686765a0aa"),
+    "ouro-2.6b+rotary": ("f93bdbd386b3970a", "9ad9ead714ed8b52"),
 }
 CHUNKED = {"moe_experts": 64, "moe_top_k": 4, "moe_experts_held": 8}
 READOUT = {"d_model": 128, "vocab_size": 8192}
-VARIANTS = {"": {}, "chunked": CHUNKED, "readout": READOUT}
+ROTARY = {"d_model": 512, "mha_head_dim": 128}
+VARIANTS = {"": {}, "chunked": CHUNKED, "readout": READOUT, "rotary": ROTARY}
 
 
 def _renumbered(text):
