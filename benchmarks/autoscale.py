@@ -207,8 +207,7 @@ def _run_arm(policy_on, low_epochs=LOW_EPOCHS, hi_epochs=HI_EPOCHS):
 
 def run_autoscale(rounds: int = 2) -> dict:
     """Interleaved OFF/ON rounds, best-of (highest agg_sps) per arm;
-    importable — bench.py's ``measure_autoscale`` hook runs a 1-round
-    version so the headline series ride every BENCH line."""
+    importable."""
     arms = {"off": [], "act": []}
     # warmup: compile every program shape once so neither timed arm
     # pays a compile the other inherits (interleaving absorbs drift,

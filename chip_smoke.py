@@ -20,9 +20,11 @@ What it does, in ONE process (the process that owns the chips):
   4. jobs       starts the jobserver the way ``harmony-tpu start-jobserver``
                 does, and submits over its TCP endpoint, through the jax-free
                 client ``harmony-tpu submit`` uses: the BASELINE config-4
-                trio (MLR + NMF + LDA) concurrently at bench.py's widths,
-                then the transformer LM (benchmarks/lm.py's widths,
-                ``attn="auto"`` — which must trace the flash kernels) beside
+                trio (MLR + NMF + LDA) concurrently at widths whose
+                per-sample work is large matmuls (MLR 8192 x 256, NMF rank
+                256, LDA 8192 words x 64 topics), then the transformer LM
+                (``LM_WIDTHS``; ``attn="auto"`` — which must trace the flash
+                kernels) beside
                 one keyed tenant whose 128-wide rows take the Pallas gather
                 and the Pallas row scatter-add; WAIT, STATUS, SHUTDOWN. A few
                 steps each; every tenant must step, stay finite and improve.
@@ -87,7 +89,7 @@ def _job(job_id: str, trainer: str, app_params: Dict[str, Any], data_fn: str,
 def mlr_job(job_id: str = "smoke-mlr", *, n: int = 2048, features: int = 8192,
             classes: int = 256, fpp: int = 512, epochs: int = 3,
             batches: int = 4, user: Optional[Dict[str, Any]] = None):
-    """bench.py's MLR: 8192 features x 256 classes (dataset cut to n)."""
+    """The trio's MLR: 8192 features x 256 classes (dataset cut to n)."""
     return _job(
         job_id, "harmony_tpu.apps.mlr:MLRTrainer",
         {"num_classes": classes, "num_features": features,
@@ -100,10 +102,11 @@ def mlr_job(job_id: str = "smoke-mlr", *, n: int = 2048, features: int = 8192,
 def nmf_job(job_id: str = "smoke-nmf", *, rows: int = 512, cols: int = 4096,
             rank: int = 256, epochs: int = 3, batches: int = 4,
             step_size: float = 1e-5, user: Optional[Dict[str, Any]] = None):
-    """bench.py's NMF: 4096 columns, rank 256 (rows cut). NOT bench.py's
-    step size: at this width its 0.01 overshoots, the non-negativity clamp
-    zeroes both factors within one epoch and the loss sits at ||X||^2 from
-    then on (seen on the CPU and on the chip); 1e-5 descends."""
+    """The trio's NMF: 4096 columns, rank 256 (rows cut). The step size is
+    NOT the trainer's default 0.01: at this width that overshoots, the
+    non-negativity clamp zeroes both factors within one epoch and the loss
+    sits at ||X||^2 from then on (seen on the CPU and on the chip); 1e-5
+    descends."""
     return _job(
         job_id, "harmony_tpu.apps.nmf:NMFTrainer",
         {"num_rows": rows, "num_cols": cols, "rank": rank,
@@ -116,7 +119,7 @@ def nmf_job(job_id: str = "smoke-nmf", *, rows: int = 512, cols: int = 4096,
 def lda_job(job_id: str = "smoke-lda", *, docs: int = 512, vocab: int = 8192,
             topics: int = 64, doc_len: int = 128, epochs: int = 3,
             batches: int = 4):
-    """bench.py's LDA: 8192 words x 64 topics (docs cut)."""
+    """The trio's LDA: 8192 words x 64 topics (docs cut)."""
     return _job(
         job_id, "harmony_tpu.apps.lda:LDATrainer",
         {"vocab_size": vocab, "num_topics": topics, "num_docs": docs,
@@ -134,8 +137,8 @@ LM_WIDTHS = dict(vocab_size=8192, d_model=512, n_heads=8, n_layers=8,
 def lm_job(job_id: str = "smoke-lm", *, widths: Dict[str, int] = LM_WIDTHS,
            batch: int = 8, epochs: int = 2, batches: int = 2,
            user: Optional[Dict[str, Any]] = None):
-    """benchmarks/lm.py's on-chip LM (vocab 8192, d_model 512, 8 heads,
-    8 layers, d_ff 2048, sequence 1024, bf16 activations) as an ordinary
+    """The smoke's LM (vocab 8192, d_model 512, 8 heads, 8 layers, d_ff
+    2048, sequence 1024, bf16 activations: ``LM_WIDTHS``) as an ordinary
     job through the PS table. Sequences carry one extra token: the loss
     shifts by one, so the model sees exactly ``max_seq`` positions."""
     return _job(

@@ -297,8 +297,8 @@ class CodebaseIndex:
 
     def repo_py_texts(self) -> Dict[str, str]:
         """Raw text of every tracked-ish .py under repo_root (scanned
-        tree + tests/benchmarks/bench.py) — for 'is this knob read
-        ANYWHERE' style questions that are wider than the lint root."""
+        tree + tests + benchmarks) — for 'is this knob read ANYWHERE'
+        style questions that are wider than the lint root."""
         out = {sf.rel: sf.text for sf in self.files}
         for extra in ("tests", "benchmarks"):
             d = os.path.join(self.repo_root, extra)
@@ -316,10 +316,6 @@ class CodebaseIndex:
                                 out[rel] = f.read()
                         except OSError:
                             continue
-        bench = os.path.join(self.repo_root, "bench.py")
-        if os.path.isfile(bench):
-            with open(bench, encoding="utf-8") as f:
-                out["bench.py"] = f.read()
         return out
 
 

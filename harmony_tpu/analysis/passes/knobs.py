@@ -175,8 +175,8 @@ class KnobConsistencyPass(Pass):
 
         # direction 2+3 need the WIDER read surface (tests/benchmarks
         # legitimately read bench-only knobs like HARMONY_POD_UNIT_LAT_MS)
-        # — still as AST-level READS; a file that does not parse falls
-        # back to a raw-text scan rather than marking its knobs unread
+        # — still as AST-level READS (tests/test_scripts_compile.py keeps
+        # every script of that surface parsing)
         read_names: Set[str] = {k for k, _, _ in reads}
         for sf in index.files:
             if sf.tree is not None:
@@ -185,11 +185,7 @@ class KnobConsistencyPass(Pass):
         for rel, text in index.repo_py_texts().items():
             if rel in scanned:
                 continue
-            try:
-                tree = ast.parse(text)
-            except (SyntaxError, ValueError):
-                read_names.update(_KNOB_RE.findall(text))
-                continue
+            tree = ast.parse(text, rel)
             read_names.update(k for k, _, _ in _reads_in_tree(tree, rel))
             read_names.update(_read_fodder(tree))
 

@@ -1211,8 +1211,8 @@ def _render_tenant_top(tenants: dict) -> "List[str]":
     """One-screen per-tenant cost view from a single STATUS scrape
     (docs/OBSERVABILITY.md "Tenant accounting" has the column glossary).
     Unknown-vs-zero is load-bearing: a None (no cost model, no target,
-    no peers) renders as '-', never as 0 — bench.py's convention
-    reserves 0 for real zeros. Rows sort by windowed device seconds,
+    no peers) renders as '-', never as 0 — 0 is reserved for real
+    zeros. Rows sort by windowed device seconds,
     heaviest first (the 'top' semantic)."""
     cols = ("TENANT", "ATTEMPT", "W", "DEV-S", "SPS", "MFU", "HBM",
             "HBM%", "INWAIT%", "SLO", "STRAG")

@@ -1701,7 +1701,7 @@ class WorkerTasklet:
     MAX_INFLIGHT = 32
     # Under multi-tenant contention the deep window becomes the UNFAIRNESS:
     # another tenant's next unit waits behind this job's whole enqueued
-    # backlog (measured 15x slowdown for the cheapest tenant, FAIRNESS_r02)
+    # backlog (a 15x slowdown for the cheapest of three tenants when measured)
     # — so contended jobs keep the device queue shallow.
     CONTENDED_INFLIGHT = 2
 

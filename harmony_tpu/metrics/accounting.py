@@ -18,8 +18,8 @@ The vector per tenant (docs/OBSERVABILITY.md "Tenant accounting"):
   carries), windowed and cumulative;
 * **model FLOPs** — XLA ``cost_analysis()`` FLOPs of the tenant's
   compiled step × steps run (progcache's per-program cost table). None
-  — never 0.0 — when the backend exposes no cost model: bench.py's
-  unreachable-accelerator convention reserves 0.0 for real zeros;
+  — never 0.0 — when the backend exposes no cost model: 0.0 is
+  reserved for real zeros, "not known" is None;
 * **achieved MFU** — windowed model FLOPs / device seconds / (peak
   bf16 FLOP/s × devices), peak from ``utils.platform.peak_bf16_flops``.
   None unless BOTH the FLOP count and the chip peak are known (CPU has

@@ -808,31 +808,28 @@ class TransformerConfig:
     def require_classic_block(self, who: str) -> None:
         """The side training steps and the decode path below still assume
         the GPT-2-era block (learned positions, GELU, tied readout, Switch
-        experts if any)."""
-        if not (self.pos == "learned" and self.ffn == "gelu"
-                and self.tie_embeddings and not self.qk_norm
-                and not self.moe_top_k and self.attn_kind == "mha"
-                and not self.moe_first_dense and not self.linear_layers
-                and not self.layer_pattern
-                and not (self.n_kv_heads or self.mha_head_dim
-                         or self.window_layers)
-                and not (self.cca or self.merge_scaled
-                         or self.rope_fraction != 1.0)
-                and not self.head_norm
-                and self.objective == "next_token"
-                and not (self.kind_heads or self.kind_rope
-                         or self.attn_gate != "none")
-                and self.loop_steps == 1 and not self.sandwich_norm):
+        experts if any): every field outside ``_CLASSIC_FIELDS`` must hold
+        its default, so a field added later is refused here as it stands."""
+        other = [f.name for f in dataclasses.fields(self)
+                 if f.name not in _CLASSIC_FIELDS
+                 and getattr(self, f.name) != f.default]
+        if other:
             raise ValueError(
                 f"{who} runs the GPT-2-era block only (learned positions, "
-                "GELU, tied readout, Switch experts); rotary / no-position / "
-                "QK-norm / SwiGLU / untied / dropless / latent-attention / "
-                "KDA linear-attention / grouped-query / windowed / leading-dense "
-                "/ layer-pattern / CCA / partial-rotary / scaled-merge / "
-                "head-norm / block-diffusion / per-kind heads and rotary / "
-                "gated-attention / looped / sandwich-norm / exit-gate "
-                "configs train through TransformerLM.loss "
-                "and TransformerTrainer")
+                "GELU, tied readout, Switch experts) and reads no "
+                f"{' / '.join(other)}: such configs train through "
+                "TransformerLM.loss and TransformerTrainer")
+
+
+#: what the GPT-2-era side steps and the decode path read of a
+#: ``TransformerConfig``: the sizes, the attention tiers, ``remat``, the
+#: Switch experts, the norms' epsilon and the embedding's scale
+#: (``rope_theta`` turns nothing under the learned positions they require)
+_CLASSIC_FIELDS = frozenset({
+    "vocab_size", "d_model", "n_heads", "n_layers", "d_ff", "max_seq",
+    "dtype", "attn", "sp_attn", "remat", "moe_experts", "moe_every",
+    "moe_capacity_factor", "moe_aux_weight", "norm_eps", "rope_theta",
+    "embed_std"})
 
 
 from harmony_tpu.models.common import rms_norm as _norm  # noqa: E402
@@ -1004,11 +1001,11 @@ def init_cca_params(key: jax.Array, cfg: TransformerConfig
     }
 
 
-def merge_init(d: int, xp=jnp):
+def merge_init(d: int):
     """A scaled residual merge's ``[a_x, b_x, a_y, b_y]`` as rows of
     ``[4, d]``: ones and zeros, a plain sum at the start."""
-    return xp.stack([xp.ones((d,), xp.float32), xp.zeros((d,), xp.float32)]
-                    * 2)
+    return jnp.stack([jnp.ones((d,), jnp.float32),
+                      jnp.zeros((d,), jnp.float32)] * 2)
 
 
 def _merge(x, y, m):
@@ -1170,199 +1167,6 @@ class TransformerLM:
         if cfg.exit_gate:  # fan-in rule: a logit of unit scale on normed rows
             params["exit_w"] = dense(jax.random.fold_in(k_emb, 2), (d, 1))[:, 0]
             params["exit_b"] = jnp.zeros((), jnp.float32)
-        return params
-
-    def init_numpy(self, seed: int = 0) -> Dict[str, Any]:
-        """``init`` with numpy arrays and NO jax op — same layout and
-        scaling, usable where touching jax would initialize a backend that
-        might hang (e.g. the graft entry point on a wedged transport).
-        Values differ from ``init`` (different RNG); structure is pinned
-        against ``init`` by test."""
-        cfg = self.config
-        rng = np.random.default_rng(seed)
-        d, f = cfg.d_model, cfg.d_ff
-
-        def dense(shape):
-            return (rng.standard_normal(shape)
-                    * shape[0] ** -0.5).astype(np.float32)
-
-        def stacked(n, a, b):  # n experts of [a, b], fan-in a
-            return (rng.standard_normal((n, a, b)) * a ** -0.5
-                    ).astype(np.float32)
-
-        layers = []
-        kinds = cfg.layer_kinds()
-        for i in range(cfg.n_layers):
-            f = cfg.ffn_width(i)
-            if cfg.layer_pattern:
-                layer = {"ln": np.ones((d,), np.float32)}
-                if kinds[i] == "ssd":
-                    H, K = cfg.ssd_heads, cfg.short_conv
-                    inner, conv, proj = cfg.ssd_widths
-                    dt = np.exp(rng.uniform(np.log(KDA_DT[0]),
-                                            np.log(KDA_DT[1]), H))
-                    layer["ssd"] = {
-                        "w_in": dense((d, proj)),
-                        "conv": rng.uniform(-K ** -0.5, K ** -0.5, (K, conv)
-                                            ).astype(np.float32),
-                        "conv_b": np.zeros((conv,), np.float32),
-                        "a_log": np.log(rng.uniform(*KDA_A, H)
-                                        ).astype(np.float32),
-                        "dt_bias": (dt + np.log(-np.expm1(-dt))
-                                    ).astype(np.float32),
-                        "skip": np.ones((H,), np.float32),
-                        "o_norm": np.ones((inner,), np.float32),
-                        "w_out": dense((inner, d))}
-                elif kinds[i] == "attn":
-                    layer["wqkv"] = dense((d, sum(cfg.qkv_widths)))
-                    layer["wo"] = dense((cfg.qkv_widths[0], d))
-                else:
-                    mc = cfg.dropless_cfg
-                    r, fs = mc.expert_d, mc.shared_width
-                    layer["moe"] = m = {
-                        "router": dense((d, mc.num_experts)),
-                        "wu": stacked(mc.experts_held, r, f),
-                        "wd": stacked(mc.experts_held, f, r)}
-                    if mc.gated:
-                        m["wg"] = stacked(mc.experts_held, r, f)
-                    if mc.score == "sigmoid":
-                        m["bias"] = np.zeros((mc.num_experts,), np.float32)
-                    if mc.shared_experts:
-                        m.update(shared_wu=dense((d, fs)),
-                                 shared_wd=dense((fs, d)))
-                        if mc.gated:
-                            m["shared_wg"] = dense((d, fs))
-                    if mc.latent:
-                        m.update(latent_down=dense((d, r)),
-                                 latent_up=dense((r, d)))
-                layers.append(layer)
-                continue
-            if kinds[i] == "kda":
-                H, dh, K = (cfg.linear_heads, cfg.linear_head_dim,
-                            cfg.short_conv)
-                taps = lambda: rng.uniform(-K ** -0.5, K ** -0.5,
-                                           (K, H * dh)).astype(np.float32)
-                dt = np.exp(rng.uniform(np.log(KDA_DT[0]), np.log(KDA_DT[1]),
-                                        H * dh))
-                layer = {
-                    "ln1": np.ones((d,), np.float32),
-                    "kda": {
-                        "wq": dense((d, H * dh)), "wk": dense((d, H * dh)),
-                        "wv": dense((d, H * dh)), "conv_q": taps(),
-                        "conv_k": taps(), "conv_v": taps(),
-                        "wf_a": dense((d, dh)), "wf_b": dense((dh, H * dh)),
-                        "a_log": np.log(rng.uniform(*KDA_A, H)
-                                        ).astype(np.float32),
-                        "dt_bias": (dt + np.log(-np.expm1(-dt))
-                                    ).astype(np.float32),
-                        "wb": dense((d, H)).T.copy(),
-                        "wg_a": dense((d, dh)),
-                        "wg_b": dense((dh, H * dh)),
-                        "o_norm": np.ones((dh,), np.float32),
-                        "wo": dense((H * dh, d))},
-                    "ln2": np.ones((d,), np.float32),
-                }
-            elif cfg.attn_kind == "mla":
-                h, nope, rot = (cfg.n_heads, cfg.qk_nope_head_dim,
-                                cfg.qk_rope_head_dim)
-                r, vd = cfg.kv_lora_rank, cfg.v_head_dim
-                layer = {
-                    "ln1": np.ones((d,), np.float32),
-                    "wq": dense((d, h * (nope + rot))),
-                    "wkv_a": dense((d, r + rot)),
-                    "kv_norm": np.ones((r,), np.float32),
-                    "wkv_b": dense((r, h * (nope + vd))),
-                    "wo": dense((h * vd, d)),
-                    "ln2": np.ones((d,), np.float32),
-                }
-            else:
-                widths = cfg.qkv_widths_of(kinds[i])
-                layer = {
-                    "ln1": np.ones((d,), np.float32),
-                    "wqkv": dense((d, sum(widths))),
-                    "wo": dense((widths[0], d)),
-                    "ln2": np.ones((d,), np.float32),
-                }
-                if cfg.attn_gate == "head":
-                    layer["wgate"] = dense((d, cfg.heads(kinds[i]))).T.copy()
-                if cfg.sandwich_norm:
-                    layer["ln1_post"] = np.ones((d,), np.float32)
-                    layer["ln2_post"] = np.ones((d,), np.float32)
-            if cfg.qk_norm:
-                layer["q_norm"] = np.ones((d,), np.float32)
-                layer["k_norm"] = np.ones((d,), np.float32)
-            if cfg.head_norm:
-                layer["q_head_norm"] = np.ones((cfg.head_dim,), np.float32)
-                layer["k_head_norm"] = np.ones((cfg.head_dim,), np.float32)
-            if cfg.cca:
-                K, hd = CCA_TAPS, cfg.head_dim
-                heads = cfg.n_heads + cfg.kv_heads
-                layer["cca"] = {
-                    "conv0": rng.uniform(-K ** -0.5, K ** -0.5,
-                                         (K, heads * hd)).astype(np.float32),
-                    "conv0_b": np.zeros((heads * hd,), np.float32),
-                    "conv1": ((K * hd) ** -0.5 * rng.standard_normal(
-                        (K, heads, hd, hd))).astype(np.float32),
-                    "conv1_b": np.zeros((heads * hd,), np.float32),
-                    "temp": np.ones((cfg.kv_heads,), np.float32)}
-            if cfg.merge_scaled:
-                layer["merge1"] = merge_init(d, np)
-                layer["merge2"] = merge_init(d, np)
-            if cfg.is_moe_layer(i):
-                E = cfg.moe_experts
-                if cfg.moe_top_k:
-                    H = cfg.dropless_cfg.experts_held
-                    layer["moe"] = {
-                        "router": dense((d, E)), "wg": stacked(H, d, f),
-                        "wu": stacked(H, d, f), "wd": stacked(H, f, d)}
-                    if cfg.moe_router_hidden:
-                        R = cfg.moe_router_hidden
-                        out = E + cfg.moe_null_expert
-                        bias = np.zeros((out,), np.float32)
-                        bias[E:] = -1.0
-                        centred = lambda w: w - w.mean(axis=0)
-                        del layer["moe"]["router"]
-                        layer["moe"].update(
-                            r_down=dense((d, R)),
-                            r_down_b=np.zeros((R,), np.float32),
-                            r_eda=np.ones((R,), np.float32),
-                            r_norm=np.ones((R,), np.float32),
-                            r_w1=dense((R, R)),
-                            r_b1=np.zeros((R,), np.float32),
-                            r_w2=centred(dense((R, R))),
-                            r_b2=np.zeros((R,), np.float32),
-                            r_w3=centred(dense((R, out))), bias=bias)
-                    if cfg.moe_score == "sigmoid":
-                        layer["moe"]["bias"] = np.zeros((E,), np.float32)
-                    if cfg.moe_shared_experts:
-                        fs = cfg.dropless_cfg.shared_width
-                        layer["moe"].update(
-                            shared_wg=dense((d, fs)), shared_wu=dense((d, fs)),
-                            shared_wd=dense((fs, d)))
-                else:
-                    layer["moe"] = {
-                        "router": dense((d, E)), "w1": stacked(E, d, f),
-                        "w2": stacked(E, f, d)}
-            else:
-                layer["w1"] = dense((d, f))
-                layer["w2"] = dense((f, d))
-                if cfg.ffn == "swiglu":
-                    layer["w3"] = dense((d, f))
-            layers.append(layer)
-        params = {
-            "embed": (cfg.embed_std * rng.standard_normal(
-                (cfg.vocab_size, d))).astype(np.float32),
-            "ln_f": np.ones((d,), np.float32),
-            "layers": layers,
-        }
-        if cfg.pos == "learned":
-            params["pos"] = (0.02 * rng.standard_normal(
-                (cfg.max_seq, d))).astype(np.float32)
-        if not cfg.tie_embeddings:
-            params["head"] = dense((d, cfg.vocab_size))
-        if cfg.exit_gate:
-            params["exit_w"] = dense((d, 1))[:, 0].copy()
-            params["exit_b"] = np.zeros((), np.float32)
         return params
 
     # -- forward ---------------------------------------------------------

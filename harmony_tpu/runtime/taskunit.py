@@ -71,9 +71,9 @@ class GlobalTaskUnitScheduler:
 
     Fairness: grants are DEFICIT-ORDERED and, under contention, METERED.
     The reference's pure quorum broadcast produces *an* order, not a fair
-    one — measured on the multi-tenant bench, the cheapest job's units
-    queued behind the other tenants' device backlogs for a 15x slowdown
-    (FAIRNESS_r02). Here, when more than one job is waiting, each job may
+    one — measured with three tenants, the cheapest job's units queued
+    behind the other tenants' device backlogs for a 15x slowdown. Here,
+    when more than one job is waiting, each job may
     hold at most one un-finished granted unit per resource kind (the
     TaskUnitClient reports scope exit — the reference's
     onTaskUnitFinished), and ready units are granted lowest-deficit-first

@@ -1,7 +1,8 @@
 """Where XLA's persistent compilation cache lives — and when it is safe.
 
 One rule for every entry point that compiles (``harmony-tpu run`` /
-``start-jobserver`` / ``start-pod``, ``bench.py``, ``chip_smoke.py``):
+``start-jobserver`` / ``start-pod``, ``chip_smoke.py``, the benchmark's
+server):
 
   * ``JAX_COMPILATION_CACHE_DIR`` set — the operator placed the cache; JAX
     reads that variable itself and this module names no directory;

@@ -5,8 +5,8 @@ surfaces, and the sampled continuous profiler.
 
 The None-vs-zero distinction is load-bearing throughout: a backend
 without a cost model yields flops=None and mfu=None — never 0.0, which
-bench.py's unreachable-accelerator convention reserves for real zeros —
-and every renderer must show such rows as '-', not crash, not zero.
+is reserved for real zeros — and every renderer must show such rows as
+'-', not crash, not zero.
 """
 import json
 import os

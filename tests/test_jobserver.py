@@ -78,7 +78,7 @@ class TestTaskUnits:
         """Under contention the scheduler meters ONE non-VOID unit at a
         time across jobs, and when several units wait, the lowest
         DEVICE-TIME deficit wins — measured unit seconds, not unit counts
-        (count-pacing was the 15x starvation of FAIRNESS_r02)."""
+        (count-pacing starved the cheapest of three tenants 15x)."""
         g = GlobalTaskUnitScheduler()
         g.on_job_start("cheap", ["c0"])
         g.on_job_start("dear", ["d0"])

@@ -479,9 +479,10 @@ def test_the_shares_add_up_to_the_uncut_layer():
 
 # -- the configuration's answers -----------------------------------------------------
 
-def test_init_numpy_matches_init_layout_and_the_kinds():
+def test_init_traced_abstractly_has_inits_layout_and_the_kinds():
     model = TransformerLM(TransformerConfig(**_app(4)))
-    a, b = model.init(jax.random.PRNGKey(0)), model.init_numpy()
+    a, b = model.init(jax.random.PRNGKey(0)), jax.eval_shape(
+        model.init, jax.ShapeDtypeStruct((2,), jnp.uint32))
     assert jax.tree_util.tree_structure(a) == jax.tree_util.tree_structure(b)
     for la, lb in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
         assert la.shape == lb.shape and la.dtype == lb.dtype
@@ -539,11 +540,12 @@ def test_side_steps_and_decode_refuse_the_new_block():
     from harmony_tpu.models import make_generate_fn
 
     lm = TransformerLM(TransformerConfig(**APP))
-    with pytest.raises(ValueError, match="KDA linear-attention"):
+    with pytest.raises(ValueError, match="GPT-2-era block .* linear_layers"):
         make_generate_fn(lm, 4, 4)
     plain = TransformerConfig(vocab_size=8, n_layers=2, linear_layers=(0,),
                               linear_heads=2, linear_head_dim=16, short_conv=4)
-    with pytest.raises(ValueError, match="no-position"):
+    with pytest.raises(ValueError, match="reads no linear_layers / "
+                       "linear_heads / linear_head_dim / short_conv:"):
         plain.require_classic_block("a side step")
 
 

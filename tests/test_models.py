@@ -334,11 +334,12 @@ def test_load_text_tokens_and_trains(tmp_path):
     assert losses[-1] < losses[0], losses  # real text is learnable
 
 
-def test_init_numpy_matches_init_layout():
-    """init_numpy (no jax ops; used by the graft entry point) must mirror
-    init's tree structure, shapes and dtypes exactly — for dense AND MoE
-    configs."""
+def test_init_traced_abstractly_has_inits_layout():
+    """``jax.eval_shape`` of init (what the graft entry point fills in numpy:
+    no jax op, no backend) has init's tree structure, shapes and dtypes
+    exactly — for dense AND MoE configs."""
     import jax
+    import jax.numpy as jnp
 
     from harmony_tpu.models import TransformerConfig, TransformerLM
 
@@ -347,8 +348,30 @@ def test_init_numpy_matches_init_layout():
                                 n_layers=2, d_ff=32, max_seq=16, **kw)
         model = TransformerLM(cfg)
         a = model.init(jax.random.PRNGKey(0))
-        b = model.init_numpy()
+        b = jax.eval_shape(model.init, jax.ShapeDtypeStruct((2,), jnp.uint32))
         assert (jax.tree_util.tree_structure(a)
                 == jax.tree_util.tree_structure(b))
         for la, lb in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
             assert la.shape == lb.shape and la.dtype == lb.dtype
+
+
+def test_a_field_the_side_steps_do_not_read_is_refused_without_an_edit():
+    """``require_classic_block`` lists what the GPT-2-era side steps read,
+    not what they do not: a field a later configuration adds is refused the
+    day it holds anything but its default, and named."""
+    import dataclasses
+
+    from harmony_tpu.models import TransformerConfig
+
+    @dataclasses.dataclass(frozen=True)
+    class Later(TransformerConfig):
+        a_later_field: int = 0
+
+    read = dict(vocab_size=32, d_model=16, n_heads=2, d_ff=32, max_seq=16,
+                attn="blockwise", sp_attn="a2a", remat=True, moe_experts=2,
+                norm_eps=1e-5, embed_std=1.0)
+    Later(**read).require_classic_block("make_sp_train_step")
+    with pytest.raises(ValueError, match="make_sp_train_step runs the "
+                       "GPT-2-era block only .* reads no a_later_field:"):
+        Later(**read, a_later_field=1).require_classic_block(
+            "make_sp_train_step")

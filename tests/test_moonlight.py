@@ -419,10 +419,11 @@ def test_grouped_matmul_tiles_keep_1408_whole():
 
 # -- parameters: layouts, refusals ----------------------------------------------
 
-def test_init_numpy_matches_init_layout_for_the_new_block():
+def test_init_traced_abstractly_has_inits_layout_for_the_new_block():
     for held in HELD:
         model = TransformerLM(TransformerConfig(**_app(held)))
-        a, b = model.init(jax.random.PRNGKey(0)), model.init_numpy()
+        a, b = model.init(jax.random.PRNGKey(0)), jax.eval_shape(
+            model.init, jax.ShapeDtypeStruct((2,), jnp.uint32))
         assert (jax.tree_util.tree_structure(a)
                 == jax.tree_util.tree_structure(b))
         for la, lb in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
@@ -487,7 +488,8 @@ def test_side_steps_and_decode_refuse_the_new_block():
         "dense_d_ff": 0, "moe_shared_experts": 0, "moe_score": "softmax",
         "moe_norm_topk": False, "moe_routed_scale": 1.0, "moe_seq_aux": False,
         "ffn": "gelu", "tie_embeddings": True})
-    with pytest.raises(ValueError, match="latent-attention"):
+    with pytest.raises(ValueError,
+                       match="GPT-2-era block .* attn_kind / kv_lora_rank"):
         dense.require_classic_block("a side step")
 
 

@@ -17,7 +17,7 @@ import time
 
 import pytest
 
-# launch harness shared with benchmarks/pod.py (children pinned to an
+# launch harness shared with benchmarks/podunits.py (children pinned to an
 # n-device CPU backend; bounded READY waits)
 from benchmarks.common import (  # noqa: E402
     free_port as _free_port,

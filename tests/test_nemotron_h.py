@@ -115,10 +115,6 @@ def test_the_seeded_parameters_are_the_references():
     assert jax.tree.structure(got) == jax.tree.structure(ref)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(ref)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    numpy = TransformerLM(_config(APP)).init_numpy(0)
-    assert jax.tree.structure(numpy) == jax.tree.structure(params)
-    assert [a.shape for a in jax.tree.leaves(numpy)] == [
-        a.shape for a in jax.tree.leaves(params)]
 
 
 def test_the_cells_parameter_count_is_the_files():
@@ -454,7 +450,7 @@ def test_layer_kinds_and_moe_layers_answer_from_the_pattern():
     assert cfg.layer_kinds() == ("ssd", "moe", "ssd", "moe", "ssd", "moe",
                                  "ssd", "attn", "moe", "ssd", "moe")
     assert cfg.moe_layers() == (1, 3, 5, 8, 10)
-    with pytest.raises(ValueError, match="layer-pattern"):
+    with pytest.raises(ValueError, match="GPT-2-era block .* layer_pattern"):
         cfg.require_classic_block("make_sp_train_step")
 
 

@@ -198,12 +198,13 @@ def test_each_ablation_moves_the_reference_itself(ablate):
     assert got == pytest.approx(by_flag, rel=1e-6)  # a name or a flag
 
 
-def test_init_numpy_has_the_same_leaves():
+def test_init_traced_abstractly_has_the_same_leaves():
     lm = TransformerLM(_config(APP))
-    a = jax.tree.map(lambda x: x.shape, lm.init(jax.random.PRNGKey(0)))
-    b = jax.tree.map(lambda x: x.shape, lm.init_numpy(0))
-    assert a == b
-    assert a["layers"][0]["q_head_norm"] == (16,)
+    of = lambda tree: jax.tree.map(lambda x: (x.shape, x.dtype), tree)
+    a = of(lm.init(jax.random.PRNGKey(0)))
+    assert a == of(jax.eval_shape(
+        lm.init, jax.ShapeDtypeStruct((2,), jnp.uint32)))
+    assert a["layers"][0]["q_head_norm"] == ((16,), jnp.float32)
 
 
 # -- the kernels, the blockwise tier and the dense mask -----------------------
@@ -709,7 +710,7 @@ def test_the_side_steps_refuse_the_objective(make):
 
     lm = TransformerLM(_config(APP))
     mesh = build_mesh(jax.devices()[:1], data=1)
-    with pytest.raises(ValueError, match="block-diffusion"):
+    with pytest.raises(ValueError, match="GPT-2-era block .* objective"):
         getattr(T, make)(lm, mesh)
 
 

@@ -336,14 +336,13 @@ def test_chunked_relu_layer_and_its_written_backward_equal_autodiff():
 
 @pytest.mark.parametrize("std", [None, 0.02, 1.0])
 def test_embed_std_scales_the_rows_in_every_initialiser(std):
-    """``embed_std`` is the embedding rows' standard deviation in ``init``,
-    ``init_numpy`` and the reference's ``init_params`` alike (0.02 where the
-    field is left out), and moves no other parameter."""
+    """``embed_std`` is the embedding rows' standard deviation in ``init``
+    and the reference's ``init_params`` alike (0.02 where the field is left
+    out), and moves no other parameter."""
     app = APP if std is None else {**APP, "embed_std": std}
     want = 0.02 if std is None else std
     lm = TransformerLM(_config(app))
     drawn = {"init": lm.init(jax.random.PRNGKey(3)),
-             "init_numpy": lm.init_numpy(3),
              "reference": REF.init_params(app, 3)}
     for name, params in drawn.items():
         got = float(np.std(np.asarray(params["embed"])))
