@@ -878,12 +878,14 @@ def _rotary_serves(positions, hd, dtype, heads=(None,)) -> bool:
         rotary.plan(positions, hd, dtype, n) is not None for n in heads)
 
 
-def _turned(q, k, theta, pos_offset, heads=None, **part):
+def _turned(q, k, theta, pos_offset, heads=None, norms=(None, None),
+            **part):
     """``rope`` of a softmax block's ``q`` and ``k [B, heads, S, hd]`` —
     where ``_rotary_serves`` by ``ops.rotary``'s kernel, two calls over ONE
     pair of tables. ``heads`` = (query heads, key heads): both lie ``[B, S,
     heads hd]`` and the kernel turns them to heads as it reads (the caller
-    has asked ``_rotary_serves``)."""
+    has asked ``_rotary_serves``); only then ``norms`` = q's and k's
+    ``(weight [hd], eps)``: the kernel norms each head before it turns it."""
     from harmony_tpu.ops import rotary
 
     if heads is None:
@@ -895,8 +897,8 @@ def _turned(q, k, theta, pos_offset, heads=None, **part):
     else:
         S, hd = q.shape[1], q.shape[2] // heads[0]
     tab, shifts = rotary.tables(S, hd, theta, pos_offset, **part)
-    return tuple(rotary.turn(t, tab, shifts, heads=n)
-                 for t, n in zip((q, k), heads))
+    return tuple(rotary.turn(t, tab, shifts, heads=n, norm=m)
+                 for t, n, m in zip((q, k), heads, norms))
 
 
 #: a KDA block's initial decay, as flash-linear-attention's ``kda`` layer
@@ -1490,19 +1492,23 @@ class TransformerLM:
                 to_heads = lambda t: t.reshape(B, S, -1, hd).transpose(
                     0, 2, 1, 3)
                 rot = cfg.rotary(kind)
-                # where the rotary kernel serves the shape and nothing works
-                # on single heads before it, q and k stay as the projection
-                # left them: the kernel's index map is their transpose
+                # where the rotary kernel serves the shape and nothing but
+                # a norm works on single heads before it, q and k stay as
+                # the projection left them: the kernel's index map is their
+                # transpose, and it norms each head as it turns it
                 heads = (h, cfg.kv_heads)
                 by_rows = (rot is not None and not cfg.cca
-                           and not cfg.head_norm
                            and _rotary_serves(S, hd, q.dtype, heads))
                 heads = heads if by_rows else None
                 if by_rows:
                     v = to_heads(v)
                 elif not cfg.cca:
                     q, k, v = to_heads(q), to_heads(k), to_heads(v)
-                if cfg.head_norm:  # a head at a time, one weight for all
+                norms = (None, None)
+                if cfg.head_norm and by_rows:
+                    norms = ((layer["q_head_norm"], eps),
+                             (layer["k_head_norm"], eps))
+                elif cfg.head_norm:  # a head at a time, one weight for all
                     q = _norm(q, layer["q_head_norm"].astype(cfg.dtype), eps)
                     k = _norm(k, layer["k_head_norm"].astype(cfg.dtype), eps)
             if cfg.cca:
@@ -1516,7 +1522,7 @@ class TransformerLM:
                     part["scaled"] = rot
                 with step_scope("mixer.rope"):
                     q, k = _turned(q, k, rot.theta, pos_offset, heads,
-                                   **part)
+                                   norms, **part)
         with step_scope("mixer.core"):
             o = self._attention(q, k, v, axis_name, window)
         if cfg.attn_gate == "head":  # one scalar a head and position

@@ -46,11 +46,46 @@ Numerical contract: float32 inside, each product rounded before it is added,
 ONE rounding to the input's dtype on the way out — ``rope``'s arithmetic
 (``x2 * -sin`` is ``-x2 * sin``; a column that passes is ``x * 1 + p * 0``).
 
+The norm a head, in the same pass (``norm=(w [hd], eps)``, PR 60). A block
+that norms each head of q and k before it turns them (``head_norm``: the
+Qwen3 family's) would make four float32 XLA passes and two transposes round
+this kernel over rows it already holds ``hd`` lanes at a time. With ``norm``
+the body first computes, per head row in float32,
+
+    xh = x * rsqrt(mean(x^2) + eps)        n = xh * w
+
+and turns ``n`` — the statistic is a lane reduce over the row the step has
+loaded. Still ONE rounding, on the way out, where ``rms_norm`` + ``rope``
+round the unit row and the weighed row to the activations' dtype before
+``rope`` widens them again: measured on the chip against float64, bfloat16
+rows come out 1.66e-3 of the result's RMS off (the XLA path 2.75e-3; ``dw``
+2e-7 against 2.5e-3, a float32 sum for a bfloat16 one) and float32 rows
+7e-8 (PERF.md, PR 60). :func:`turn_ref` states it in plain ``jnp``. Only the walk over
+``[B, S, H hd]`` takes it (``heads`` = H): a block that norms its heads
+hands q and k over as the projection left them, and no caller has them by
+heads before the norm.
+
+Its backward, under the same ``custom_vjp``: with ``gt = T^T g`` (the
+transposed turn above, as the first stage of the body)
+
+    dx = rstd * (w gt - xh * mean(xh * w gt))        dw = sum xh * gt
+
+over rows and heads. ``xh`` and ``rstd`` are computed again from ``x`` in
+the kernel: the residuals are ``x`` (which the projection's own backward
+keeps anyway), ``w`` and the tables. THREE streams where the plain turn has
+two — ``g`` by heads and ``x`` by rows in, ``dx`` by rows out: ``3 * B * H *
+S * hd * itemsize`` bytes, SDAR's q 403 MB = 0.49 ms at 819 GB/s; the chip
+read 0.71, and 0.50 for the forward beside the plain turn's 0.43 (the two
+lane reduces a row). ``dw`` leaves the kernel as one ``[1, hd]`` partial sum
+a grid step (every axis stays ``parallel``) and XLA adds the few KB.
+
 Where the kernel declines (:func:`plan` returns None and the caller keeps
-``rope``): a head that is not a whole number of lane tiles (``hd % 128``),
-positions that no row tile of 2,048..16 divides, operands that are neither
-bfloat16 nor float32. Off the TPU the caller keeps ``rope`` too (the kernel
-runs there only interpreted).
+``rope`` — after ``rms_norm``, where the block norms its heads): a head that
+is not a whole number of lane tiles (``hd % 128``), positions that no row
+tile of 2,048..16 divides, operands that are neither bfloat16 nor float32.
+Off the TPU the caller keeps ``rope`` too (the kernel runs there only
+interpreted). ``norm`` changes nothing in the plan: the third stream and the
+float32 temporaries fit the scoped VMEM asked for at the widest tile.
 """
 from __future__ import annotations
 
@@ -91,17 +126,18 @@ def plan(positions: int, hd: int, dtype, heads: Optional[int] = None
 
 
 def note_plan(rows: int, hd: int, turned: int, heads: int,
-              grid_steps: int) -> None:
+              grid_steps: int, normed: bool = False) -> None:
     """Trace-time record of the kernel's tiling (STATUS ``kernel_plans``):
     block_q = the row tile, block_k = d = the head width, dv = the columns
     turned, sub = the heads (batch x heads) one table tile serves — q's row
-    and k's differ in it —, grid_steps = the tiles a call walks. Never
-    fails a trace."""
+    and k's differ in it —, grid_steps = the tiles a call walks, normed =
+    whether the call norms each head before it turns it. Never fails a
+    trace."""
     try:
         from harmony_tpu.runtime.progcache import note_kernel_plan
 
         note_kernel_plan(KERNEL_NAME, rows, hd, heads, grid_steps, True,
-                         d=hd, dv=turned)
+                         d=hd, dv=turned, extra={"normed": normed})
     except Exception:
         pass
 
@@ -137,43 +173,86 @@ def tables(positions: int, hd: int, theta: float, pos_offset=0,
     ]), (half, hd - half)
 
 
-def turn_ref(x, tab, shifts):
-    """What the kernel computes, in plain ``jnp``: ``x [..., S, hd]``."""
+def turn_ref(x, tab, shifts, norm=None):
+    """What the kernel computes, in plain ``jnp``: ``x [..., S, hd]``.
+    ``norm`` = ``(w [hd], eps)``: each head row RMS-normed and weighed in
+    float32 before it turns, as the kernel does it."""
     xf = x.astype(jnp.float32)
+    if norm is not None:
+        w, eps = norm
+        xf = xf * jax.lax.rsqrt(
+            jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+        xf = xf * w.astype(jnp.float32)
     y = xf * tab[0]
     for j, s in enumerate(shifts):
         y = y + jnp.roll(xf, s, axis=-1) * tab[1 + j]
     return y.astype(x.dtype)
 
 
-def _make_kernel(shifts, group, hd, rows_in, rows_out, back):
+def _make_kernel(shifts, group, hd, rows_in, rows_out, back, eps=None):
     """``group`` heads a grid step. ``rows_in`` / ``rows_out``: that side's
     block is ``[rows, group hd]`` — the heads side by side, as a projection
     leaves them — and not ``[group, rows, hd]``. ``back``: the transpose,
-    the sines negated."""
+    the sines negated. ``eps``: the normed turn's kernels — forward ``(tab,
+    x, w, out)``, backward ``(tab, g, x, w, dx, dw)`` with ``x`` the
+    forward's input (by rows) and ``dw [1, hd]`` this step's share of the
+    weight's gradient."""
+    f32 = jnp.float32
+
+    def head(ref, g, rows):
+        return (ref[:, g * hd:(g + 1) * hd] if rows else ref[g]).astype(f32)
+
+    def turned(tab, xf):
+        y = xf * tab[0]
+        for j, s in enumerate(shifts):
+            term = pltpu.roll(xf, s, 1) * tab[1 + j]
+            y = y - term if back else y + term
+        return y
+
+    def put(out, g, y):
+        if rows_out:
+            out[:, g * hd:(g + 1) * hd] = y.astype(out.dtype)
+        else:
+            out[g] = y.astype(out.dtype)
+
+    def unit(xf):  # (x / rms(x), 1 / rms(x)) of each row
+        rstd = jax.lax.rsqrt(
+            jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+        return xf * rstd, rstd
+
     def kernel(tab, x, out):
         for g in range(group):
-            cols = slice(g * hd, (g + 1) * hd)
-            xf = (x[:, cols] if rows_in else x[g]).astype(jnp.float32)
-            y = xf * tab[0]
-            for j, s in enumerate(shifts):
-                term = pltpu.roll(xf, s, 1) * tab[1 + j]
-                y = y - term if back else y + term
-            if rows_out:
-                out[:, cols] = y.astype(out.dtype)
-            else:
-                out[g] = y.astype(out.dtype)
+            put(out, g, turned(tab, head(x, g, rows_in)))
 
-    return kernel
+    def normed(tab, x, w, out):
+        weight = w[...]
+        for g in range(group):
+            put(out, g, turned(tab, unit(head(x, g, rows_in))[0] * weight))
+
+    def normed_back(tab, g, x, w, dx, dw):
+        weight, share = w[...], jnp.zeros((1, hd), f32)
+        for i in range(group):
+            gt = turned(tab, head(g, i, rows_in))       # d loss / d (xh w)
+            xh, rstd = unit(head(x, i, True))
+            wg = gt * weight
+            put(dx, i, rstd * (wg - xh * jnp.mean(xh * wg, axis=-1,
+                                                  keepdims=True)))
+            share = share + jnp.sum(xh * gt, axis=0, keepdims=True)
+        dw[...] = share
+
+    return kernel if eps is None else normed_back if back else normed
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("shifts", "heads", "back", "interpret"))
-def _turn_call(x, tab, shifts, heads, back, interpret):
+@functools.partial(jax.jit, static_argnames=(
+    "shifts", "heads", "back", "eps", "interpret"))
+def _turn_call(x, tab, w, of, shifts, heads, back, eps, interpret):
     """``back``: the transpose of the turn — the sines negated. ``heads``
     None: ``x [B, H, S, hd]`` in and out. ``heads`` = H: the forward reads
     ``[B, S, H hd]`` and writes ``[B, H, S, hd]``; ``back`` reads the latter
-    and writes the former."""
+    and writes the former. ``w [hd]`` (else None) and ``eps``: the normed
+    turn, ``heads`` given; its ``back`` takes ``of``, the forward's input
+    ``[B, S, H hd]``, beside the cotangent ``x`` and returns ``(dx, dw
+    [hd])``."""
     by_heads = x.shape
     if heads is None:  # [B H, S, hd]: rows of ONE head's width on both sides
         x = x.reshape(-1, *x.shape[2:])
@@ -185,54 +264,79 @@ def _turn_call(x, tab, shifts, heads, back, interpret):
     else:
         B, _, S, hd = x.shape
     rows, group = plan(S, hd, x.dtype, heads)
+    grid = (S // rows, B, H // group)
     by_row = pl.BlockSpec((None, rows, group * hd), lambda s, b, g: (b, s, g))
     by_head = pl.BlockSpec((None, group, rows, hd),
                            lambda s, b, g: (b, g, s, 0))
+    # the table's block index holds still along the batch and the heads:
+    # one fetch a row tile
+    operands = [tab, x]
+    in_specs = [pl.BlockSpec((len(shifts) + 1, rows, hd),
+                             lambda s, b, g: (0, s, 0)),
+                by_row if rows_in else by_head]
+    out_shape = jax.ShapeDtypeStruct(
+        (B, S, H * hd) if rows_out else (B, H, S, hd), x.dtype)
+    out_specs = by_row if rows_out else by_head
+    if w is not None:
+        if back:  # the forward's input, by rows as the output
+            operands.append(of)
+            in_specs.append(by_row)
+            out_shape = (out_shape,
+                         jax.ShapeDtypeStruct(grid + (1, hd), jnp.float32))
+            out_specs = (out_specs, pl.BlockSpec(
+                (None, None, None, 1, hd), lambda s, b, g: (s, b, g, 0, 0)))
+        operands.append(w.astype(jnp.float32).reshape(1, hd))
+        in_specs.append(pl.BlockSpec((1, hd), lambda s, b, g: (0, 0)))
     y = pl.pallas_call(
-        _make_kernel(shifts, group, hd, rows_in, rows_out, back),
+        _make_kernel(shifts, group, hd, rows_in, rows_out, back, eps),
         name=KERNEL_NAME,
-        out_shape=jax.ShapeDtypeStruct(
-            (B, S, H * hd) if rows_out else (B, H, S, hd), x.dtype),
-        grid=(S // rows, B, H // group),
-        # the table's block index holds still along the batch and the
-        # heads: one fetch a row tile
-        in_specs=[pl.BlockSpec((len(shifts) + 1, rows, hd),
-                               lambda s, b, g: (0, s, 0)),
-                  by_row if rows_in else by_head],
-        out_specs=by_row if rows_out else by_head,
+        out_shape=out_shape,
+        grid=grid,
+        in_specs=in_specs,
+        out_specs=out_specs,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",) * 3,
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
-    )(tab, x)
-    return y.reshape(by_heads) if heads is None else y
+    )(*operands)
+    if w is not None and back:
+        y, dw = y[0], y[1].sum(axis=(0, 1, 2, 3))
+    y = y.reshape(by_heads) if heads is None else y
+    return y if w is None or not back else (y, dw)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
-def _turn(x, tab, shifts, heads, interpret):
-    return _turn_call(x, tab, shifts, heads, False, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _turn(x, tab, w, shifts, heads, eps, interpret):
+    return _turn_call(x, tab, w, None, shifts, heads, False, eps, interpret)
 
 
-def _fwd(x, tab, shifts, heads, interpret):
-    return _turn_call(x, tab, shifts, heads, False, interpret), tab
+def _fwd(x, tab, w, shifts, heads, eps, interpret):
+    y = _turn_call(x, tab, w, None, shifts, heads, False, eps, interpret)
+    return y, (tab, w, None if w is None else x)
 
 
-def _bwd(shifts, heads, interpret, tab, g):
+def _bwd(shifts, heads, eps, interpret, res, g):
     # the transpose of (C + S_a R + S_b R^T): the same turn, sines negated
-    return _turn_call(g, tab, shifts, heads, True, interpret), None
+    tab, w, x = res
+    out = _turn_call(g, tab, w, x, shifts, heads, True, eps, interpret)
+    if w is None:
+        return out, None, None
+    return out[0], None, out[1].astype(w.dtype)
 
 
 _turn.defvjp(_fwd, _bwd)
 
 
 def turn(x: jnp.ndarray, tab: jnp.ndarray, shifts: Tuple[int, ...], *,
-         heads: Optional[int] = None, interpret: bool = False
+         heads: Optional[int] = None, norm=None, interpret: bool = False
          ) -> jnp.ndarray:
     """``x`` turned by :func:`tables`' pair: ``[B, H, S, hd]`` in x's dtype.
     ``x`` is ``[B, H, S, hd]`` or, with ``heads`` = H, ``[B, S, H hd]`` as a
     projection leaves it (head ``h`` is column block ``h``): the transpose
     to heads is then the input's index map, and the backward writes that
-    layout back. Differentiable in ``x``. The shape must be one
+    layout back. ``norm`` = ``(w [hd], eps)``, with ``heads`` only: each
+    head row is RMS-normed and weighed by ``w`` (float32 statistics) before
+    it turns, in the same pass. Differentiable in ``x`` and ``w``. The shape must be one
     :func:`plan` serves; every trace notes the plan (:func:`note_plan`)."""
     if heads is None:
         B, H, S, hd = x.shape
@@ -240,11 +344,17 @@ def turn(x: jnp.ndarray, tab: jnp.ndarray, shifts: Tuple[int, ...], *,
         (B, S, width), H = x.shape, heads
         hd = width // H
     tiles = plan(S, hd, x.dtype, heads)
+    w, eps = (None, None) if norm is None else norm
     if tiles is None or tab.shape != (len(shifts) + 1, S, hd) or (
-            heads is not None and x.shape[2] != H * hd):
+            heads is not None and x.shape[2] != H * hd) or (
+            w is not None and (heads is None or w.shape != (hd,))):
         raise ValueError(f"rotary.turn: no plan serves x {x.shape} {x.dtype} "
                          f"(heads={heads}) with tables {tab.shape}, shifts "
-                         f"{shifts}")
+                         f"{shifts}" + ("" if w is None else
+                                        f", norm weight {w.shape} (a norm "
+                                        "wants heads)"))
     turned = hd if len(shifts) == 1 else 2 * shifts[0]
-    note_plan(tiles[0], hd, turned, B * H, S // tiles[0] * B * H // tiles[1])
-    return _turn(x, tab.astype(jnp.float32), tuple(shifts), heads, interpret)
+    note_plan(tiles[0], hd, turned, B * H, S // tiles[0] * B * H // tiles[1],
+              normed=w is not None)
+    return _turn(x, tab.astype(jnp.float32), w, tuple(shifts), heads,
+                 None if eps is None else float(eps), interpret)

@@ -472,10 +472,11 @@ def _kernel_plan_family():
         ("job", "kernel", "block_q", "block_k", "sub", "planned", "d", "dv"))
 
 
-#: (the plan gauge's label values) -> what a flash call with grouped heads
-#: or a window needs of its plan (ops/attention.py ``_note_plan``): columns
-#: of the STATUS row, not labels of the gauge
-_plan_bands: Dict[tuple, Dict[str, Any]] = {}
+#: (the plan gauge's label values) -> columns of the STATUS row that are no
+#: labels of the gauge: what a flash call with grouped heads or a window
+#: needs of its plan (ops/attention.py ``_note_plan``: ``band``) and what
+#: another kernel adds to its row (``extra``)
+_plan_columns: Dict[tuple, Dict[str, Any]] = {}
 
 
 def _masked_share_families():
@@ -495,13 +496,16 @@ def _masked_share_families():
 
 def note_kernel_plan(kernel: str, block_q: int, block_k: int, sub: int,
                      grid_steps: int, planned: bool, *, d: int,
-                     dv: int, band: Optional[Dict[str, Any]] = None) -> None:
+                     dv: int, band: Optional[Dict[str, Any]] = None,
+                     extra: Optional[Dict[str, Any]] = None) -> None:
     """``d`` / ``dv``: the two widths the tiles were planned for — a flash
     kernel's q.k and v head widths, a grouped matmul's k and n. ``band``:
     what a flash call with grouped heads or a window adds to its row
     (``window``, ``kv_heads``, ``group`` — the query heads a K/V head
     serves —, ``band_grid_steps``, ``sub_blocks``,
-    ``masked_sub_blocks``, ``computed``, ``masked_share``)."""
+    ``masked_sub_blocks``, ``computed``, ``masked_share``). ``extra``:
+    further columns of the row, as they come (the rotary kernel's
+    ``normed``: does the call norm each head before it turns it)."""
     from harmony_tpu.tracing.span import current_job
 
     job = current_job() or "-"
@@ -510,8 +514,10 @@ def note_kernel_plan(kernel: str, block_q: int, block_k: int, sub: int,
         block_k=str(block_k), sub=str(sub), planned=str(int(planned)),
         d=str(d), dv=str(dv))
     _kernel_plan_family().labels(**labels).set(grid_steps)
+    if band is not None or extra is not None:
+        _plan_columns[tuple(labels.values())] = {**(band or {}),
+                                                 **(extra or {})}
     if band is not None:
-        _plan_bands[tuple(labels.values())] = dict(band)
         share, elements = _masked_share_families()
         share.labels(job=job, kernel=kernel).set(band["masked_share"])
         elements.labels(job=job, kernel=kernel).set(band["computed"])
@@ -522,7 +528,7 @@ def kernel_plans() -> Dict[str, list]:
     grid_steps}]}`` of
     every kernel traced in this process — STATUS ``kernel_plans``. A flash
     kernel traced with grouped heads or a window carries ``band``'s columns
-    too (``note_kernel_plan``)."""
+    too, any kernel its ``extra`` ones (``note_kernel_plan``)."""
     out: Dict[str, list] = {}
     try:
         for key, child in _kernel_plan_family().children():
@@ -532,7 +538,7 @@ def kernel_plans() -> Dict[str, list]:
                 "sub": int(sub), "planned": planned == "1",
                 "d": int(d), "dv": int(dv),
                 "grid_steps": int(child.value),
-                **_plan_bands.get(tuple(key), {})})
+                **_plan_columns.get(tuple(key), {})})
     except Exception:
         return {}
     return out

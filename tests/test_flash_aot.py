@@ -267,10 +267,12 @@ def test_readout_loss_kernels_compile_for_v5e(one_chip, n, d, v, tied):
 
 
 #: the rotary turn of every LM cell that has one a 128-wide head: (batch,
-#: heads, positions, columns turned, by rows: the operand lies [B, S, H hd])
+#: heads, positions, columns turned, by rows: the operand lies [B, S, H hd]);
+#: SDAR's norm a head runs inside the kernel (PR 60)
+_NORMED = {"sdar-30b-a3b-q", "sdar-30b-a3b-k"}
 _ROTARY_CALLS = {
-    "sdar-30b-a3b-q": (2, 32, 8192, 128, False),   # behind a norm a head
-    "sdar-30b-a3b-k": (2, 4, 8192, 128, False),
+    "sdar-30b-a3b-q": (2, 32, 8192, 128, True),
+    "sdar-30b-a3b-k": (2, 4, 8192, 128, True),
     "zaya1-8b-q": (1, 8, 8192, 64, False),         # behind CCA, half turned
     "smallthinker-21b-a3b-q": (1, 28, 16384, 128, True),
     "smallthinker-21b-a3b-k": (1, 4, 16384, 128, True),
@@ -286,7 +288,8 @@ _ROTARY_CALLS = {
 def test_rotary_kernel_compiles_at_each_cells_shape(one_chip, call):
     """``harmony_rotary`` forward and backward under its own plan: the lane
     rolls, a row tile of 2,048 x 128 with three tables inside the scoped
-    VMEM, and the ``[B, S, H hd]`` walk's blocks of several heads."""
+    VMEM, the ``[B, S, H hd]`` walk's blocks of several heads, and the
+    normed turn's third stream and float32 temporaries beside them."""
     from harmony_tpu.ops import rotary as R
 
     b, h, s, turned, by_rows = _ROTARY_CALLS[call]
@@ -294,13 +297,15 @@ def test_rotary_kernel_compiles_at_each_cells_shape(one_chip, call):
                              jnp.bfloat16, sharding=one_chip)
     assert R.plan(s, 128, jnp.bfloat16, h if by_rows else None) is not None
 
-    def loss(x, offset):
+    def loss(x, w, offset):
         tab, shifts = R.tables(s, 128, 1e6, offset, turned)
-        y = R.turn(x, tab, shifts, heads=h if by_rows else None)
+        y = R.turn(x, tab, shifts, heads=h if by_rows else None,
+                   norm=(w, 1e-6) if call in _NORMED else None)
         return (y.astype(jnp.float32) ** 2).sum()
 
-    text = jax.jit(jax.grad(loss)).lower(
-        x, jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    text = jax.jit(jax.grad(loss, (0, 1))).lower(
+        x, jax.ShapeDtypeStruct((128,), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
     ).compile().as_text()
     assert text.count("custom_call_target=\"tpu_custom_call\"") == 2
     assert R.KERNEL_NAME in text

@@ -1,7 +1,8 @@
 """The rotary kernel (ops/rotary.py), on the CPU in interpret mode: against
 ``models.transformer.rope`` — the reference and the fallback — forward and
-gradient; its plan; where ``_softmax_mixer`` takes it and where it keeps
-``rope``. Times and the chip are PERF.md's (PR 58)."""
+gradient; the norm a head inside it (PR 60) against ``rms_norm`` + ``rope``;
+its plan; where ``_softmax_mixer`` takes it and where it keeps ``rope``.
+Times and the chip are PERF.md's (PRs 58 and 60)."""
 from __future__ import annotations
 
 import functools
@@ -16,6 +17,7 @@ import pytest
 
 from harmony_tpu.models import TransformerConfig, TransformerLM
 from harmony_tpu.models import transformer as T
+from harmony_tpu.models.common import rms_norm
 from harmony_tpu.models.transformer import Rotary, rope
 from harmony_tpu.ops import rotary as R
 
@@ -57,17 +59,24 @@ def _kernel(x, theta, pos_offset=0, width=None, scaled=None):
     return R.turn(x, tab, shifts, interpret=True)
 
 
+def _near(got, want, steps, of=None):
+    """Within ``steps`` rounding steps of the result's dtype at the
+    operands' size (``of``: at that size throughout, a sum's)."""
+    assert got.dtype == want.dtype and got.shape == want.shape
+    g, w = np.asarray(got.astype(F32)), np.asarray(want.astype(F32))
+    size = np.maximum(np.abs(w), 1.0) if of is None else of
+    tol = steps * float(jnp.finfo(got.dtype).eps) * size
+    assert np.all(np.abs(g - w) <= tol), float(np.max(np.abs(g - w) / tol))
+
+
 def _close(got, want, dtype):
     """Equal to float32 rounding: a float32 result to a few float32 ulps of
     the operands' size, a bfloat16 one to ONE rounding step (the float32
     sum may fall either side of a tie)."""
-    assert got.dtype == want.dtype == dtype and got.shape == want.shape
-    g, w = np.asarray(got.astype(F32)), np.asarray(want.astype(F32))
-    step = float(jnp.finfo(dtype).eps)
-    tol = step * np.maximum(np.abs(w), 1.0) * (1.0 if dtype == BF16 else 4.0)
-    assert np.all(np.abs(g - w) <= tol), float(np.max(np.abs(g - w)))
+    assert got.dtype == dtype
+    _near(got, want, 1 if dtype == BF16 else 4)
     # and nearly every element is the reference's to the bit
-    assert np.mean(g == w) > (0.99 if dtype == BF16 else 0.5)
+    assert np.mean(np.asarray(got == want)) > (0.99 if dtype == BF16 else 0.5)
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
@@ -122,6 +131,77 @@ def test_the_transpose_to_heads_as_the_index_map(case, dtype):
     _close(by_rows(x), plain(x), dtype)
     loss = lambda f: lambda x: (f(x).astype(F32) * w).sum()
     _close(jax.grad(loss(by_rows))(x), jax.grad(loss(plain))(x), dtype)
+
+
+#: name -> (B, H, S, eps, rope's arguments): x lies [B, S, H hd], ``_GROUP``
+#: heads a step or (9, 3, 2, 1) all of them. Row tiles of 2,048 and of 16
+#: among them
+NORM_CASES = {
+    "three-heads": (2, 3, 64, 1e-6, dict(theta=1e4)),
+    "two-heads-eps-1e-5": (1, 2, 64, 1e-5, dict(theta=1e4)),
+    "four-heads-half-turned": (1, 4, 48, 1e-6, dict(theta=1e4, width=64)),
+    "thirty-two-heads": (1, 32, 64, 1e-6, dict(theta=1e6)),
+    "four-heads": (2, 4, 64, 1e-6, dict(theta=1e6, pos_offset=9)),
+    "four-heads-eps-1e-5": (2, 4, 64, 1e-5, dict(theta=1e6)),
+    "nine-heads-in-one": (1, 9, 48, 1e-6, dict(theta=1e4)),
+    "three-heads-half-turned": (1, 3, 48, 1e-5, dict(
+        theta=YARN.theta, width=64, scaled=YARN)),
+    "rows-of-2048": (1, 4, 2048, 1e-6, dict(theta=1e6)),
+    "rows-of-2048-one-head": (1, 1, 4096, 1e-6, dict(theta=1e6)),
+    "rows-of-16": (2, 4, 16, 1e-6, dict(theta=1e4)),
+    "rows-of-16-two-heads": (1, 2, 16, 1e-5, dict(theta=1e4)),
+}
+
+
+@pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("case", sorted(NORM_CASES))
+def test_the_norm_a_head_inside_the_turn(case, dtype):
+    """``turn(..., norm=(w, eps))`` — forward, ``dx`` and ``dw`` — against
+    ``turn_ref`` (the norm in float32, ONE rounding: equal but for the
+    order of sums) and against the parent's path, ``rms_norm`` in x's dtype
+    then ``rope`` (three roundings: near)."""
+    B, H, S, eps, args = NORM_CASES[case]
+    hd = 128
+    x = 3 * _x((B, S, H * hd), dtype)
+    w, c = 1 + 0.3 * _x((hd,), F32, seed=2), _x((B, H, S, hd), F32, seed=1)
+    to_heads = lambda t: t.reshape(B, S, H, hd).transpose(0, 2, 1, 3)
+    tab, shifts = R.tables(S, hd, args["theta"], args.get("pos_offset", 0),
+                           args.get("width"), args.get("scaled"))
+    fused = lambda x, w: R.turn(x, tab, shifts, heads=H, norm=(w, eps),
+                                interpret=True)
+    plain = lambda x, w: R.turn_ref(to_heads(x), tab, shifts, (w, eps))
+    parent = lambda x, w: rope(rms_norm(to_heads(x), w.astype(dtype), eps),
+                               **args)
+    loss = lambda f: lambda x, w: (f(x, w).astype(F32) * c).sum()
+    y, (dx, dw) = fused(x, w), jax.grad(loss(fused), (0, 1))(x, w)
+    assert (y.shape, dx.shape, dw.shape, dw.dtype) == (
+        (B, H, S, hd), x.shape, (hd,), F32)
+    wide = 1 if dtype == BF16 else 16  # float32: the sums' order shows
+    want, (want_dx, want_dw) = plain(x, w), jax.grad(loss(plain), (0, 1))(x, w)
+    _near(y, want, wide)
+    _near(dx, want_dx, wide)
+    assert np.mean(np.asarray(y == want)) > (0.99 if dtype == BF16 else 0.5)
+    sums = float(jnp.abs(want_dw).max())       # B H S float32 terms a column
+    _near(dw, want_dw, 64, of=sums)
+    # the parent rounds the unit rows, the weighed rows and the turn's
+    # result: a few steps apart in bfloat16, and its dw is a bfloat16 sum
+    old, (old_dx, old_dw) = parent(x, w), jax.grad(loss(parent), (0, 1))(x, w)
+    _near(y, old, 3 if dtype == BF16 else wide)
+    _near(dx, old_dx, 6 if dtype == BF16 else wide)
+    np.testing.assert_allclose(dw, old_dw, atol=sums * (
+        0.1 if dtype == BF16 else 1e-5))
+
+
+@pytest.mark.parametrize("case", ["another-width", "by-heads"])
+def test_a_norm_the_kernel_does_not_serve_is_refused(case):
+    """A weight that is not a head wide; and q or k already by heads (no
+    caller norms them there: the norm goes with the walk by rows)."""
+    tab, shifts = R.tables(64, 128, 1e4)
+    x, w, heads = {"another-width": ((1, 64, 256), 64, 2),
+                   "by-heads": ((1, 2, 64, 128), 128, None)}[case]
+    with pytest.raises(ValueError, match="norm weight"):
+        R.turn(_x(x, BF16), tab, shifts, heads=heads,
+               norm=(jnp.ones((w,), F32), 1e-6), interpret=True)
 
 
 def test_a_traced_offset_is_the_static_ones_turn():
@@ -205,6 +285,18 @@ def _pallas_calls(jaxpr, out, shapes=None):
     return out
 
 
+def _head_rsqrts(jaxpr, out):
+    """The ``rsqrt`` equations over one statistic a head and position
+    (``[B, H, S, 1]``) under ``jaxpr``, kernel bodies left out."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "rsqrt" and eqn.invars[0].aval.ndim == 4:
+            out.append(eqn)
+        if eqn.primitive.name != "pallas_call":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                _head_rsqrts(sub, out)
+    return out
+
+
 def test_grouped_heads_share_the_tables(as_tpu):
     q, k = _x((2, 8, 64, 128), BF16), _x((2, 2, 64, 128), BF16, seed=1)
     got = T._turned(q, k, 1e6, 5)
@@ -257,10 +349,17 @@ def test_the_plan_row_says_which_path_a_job_took(as_tpu):
         T._turned(wide, wide[:, :2], 1e4, 0, width=64)
     with trace_span("job.build_step", job_id="plan-rope"):
         T._turned(narrow, narrow[:, :2], 1e4, 0)
+    by_rows = _x((1, 64, 4 * 128), BF16)
+    with trace_span("job.build_step", job_id="plan-normed"):  # q alone
+        T._turned(by_rows, by_rows[..., :256], 1e4, 0, (4, 2),
+                  ((jnp.ones((128,), F32), 1e-6), None))
     plans = progcache.kernel_plans()
     rows = [r for r in plans["plan-rotary"] if r["kernel"] == R.KERNEL_NAME]
-    assert {(r["block_q"], r["d"], r["dv"], r["sub"], r["grid_steps"])
-            for r in rows} == {(64, 128, 64, 4, 4), (64, 128, 64, 2, 2)}
+    assert {(r["block_q"], r["d"], r["dv"], r["sub"], r["grid_steps"],
+             r["normed"]) for r in rows} == {
+        (64, 128, 64, 4, 4, False), (64, 128, 64, 2, 2, False)}
+    assert {(r["sub"], r["normed"]) for r in plans["plan-normed"]
+            if r["kernel"] == R.KERNEL_NAME} == {(4, True), (2, False)}
     assert not [r for r in plans.get("plan-rope", ())
                 if r["kernel"] == R.KERNEL_NAME]
 
@@ -292,10 +391,18 @@ def test_the_models_loss_and_gradient_through_the_kernel(monkeypatch, over):
     # remat)
     assert calls == 2 * 2 * (3 if over.get("remat") else 2)
     # q (and k, if it has several heads) comes as the projection left it,
-    # [B, S, H hd], unless a norm a head stands between (a forward's
-    # operand: the backward's lies by heads)
+    # [B, S, H hd], a norm a head with it (a forward's operand: the
+    # backward's lies by heads)
     by_rows = sum(shape[-1] > 128 for shape in shapes)
-    assert by_rows == 0 if over.get("head_norm") else by_rows >= calls // 4
+    assert by_rows >= calls // 4
+    # ... and the kernel norms the heads: outside it no rsqrt of one row a
+    # head [B, H, S, 1] is left, where the plain trace has q's and k's
+    assert not _head_rsqrts(jaxpr.jaxpr, [])
+    if over.get("head_norm"):
+        with monkeypatch.context() as m:
+            m.setattr(platform, "trace_is_tpu", lambda: False)
+            assert _head_rsqrts(jax.make_jaxpr(jax.value_and_grad(
+                lm.loss))(params, toks).jaxpr, [])
     fused = jax.jit(jax.value_and_grad(lm.loss))(params, toks)
     np.testing.assert_allclose(fused[0], plain[0], rtol=2e-3)
     rel = lambda a, b: float(jnp.linalg.norm((a - b).astype(F32))

@@ -172,11 +172,41 @@ def test_remat_changes_nothing():
         _close(x, y, rtol=1e-5)
 
 
-def test_every_ablation_is_told_apart_and_the_program_is_not():
+#: the preset with heads of 128 columns, which ``ops/rotary.py``'s plan
+#: serves: the norm a head runs INSIDE ``harmony_rotary`` (PR 60)
+KERNEL_APP = {**APP, "mha_head_dim": 128}
+
+
+@pytest.fixture
+def rotary_engaged(monkeypatch):
+    """The rotary kernel steered onto the CPU's trace as a TPU's takes it
+    (by its plan alone: ``trace_is_tpu`` would bring every other kernel
+    along), interpreted."""
+    from harmony_tpu.models import transformer as T
+    from harmony_tpu.ops import rotary as R
+
+    monkeypatch.setattr(
+        T, "_rotary_serves", lambda S, hd, dtype, heads=(None,): all(
+            R.plan(S, hd, dtype, n) is not None for n in heads))
+    monkeypatch.setattr(R, "turn", functools.partial(R.turn, interpret=True))
+
+
+def _app_of(path, request):
+    """``"plain"``: the preset, ``rope`` in XLA; ``"kernel"``: 128-wide
+    heads through ``harmony_rotary``, the norm a head inside it."""
+    if path == "plain":
+        return APP
+    request.getfixturevalue("rotary_engaged")
+    return KERNEL_APP
+
+
+@pytest.mark.parametrize("path", ["plain", "kernel"])
+def test_every_ablation_is_told_apart_and_the_program_is_not(path, request):
     """``check_logits`` as a chip run uses it, at the small size: the
     program passes both comparisons; every logit ablation reads above the
     float32 limit, every loss ablation's gradient above the gradient's."""
-    report = REF.check_logits(APP, _batch(seed=6), seed=6)
+    app = _app_of(path, request)
+    report = REF.check_logits(app, _batch(seed=6, app=app), seed=6)
     assert report["ok"], report
     assert set(report["detected"]) == set(REF.ABLATIONS)
     assert all(report["detected"].values())
@@ -452,17 +482,48 @@ def test_the_loss_reads_the_masked_positions_without_a_shift():
     assert 0 < aux < 0.1  # the balance loss at its weight, nothing else
 
 
-def test_each_head_is_normed_with_separate_weights_for_q_and_k():
-    lm, params, _, _ = _both()
+@pytest.mark.parametrize("path", ["plain", "kernel"])
+def test_each_head_is_normed_with_separate_weights_for_q_and_k(path, request):
+    app = _app_of(path, request)
+    lm, params, _, _ = _both(app)
     layer = params["layers"][0]
-    assert layer["q_head_norm"].shape == layer["k_head_norm"].shape == (16,)
-    tokens, masked, _ = _batch(seed=11)
+    assert layer["q_head_norm"].shape == layer["k_head_norm"].shape == (
+        app["mha_head_dim"],)
+    tokens, masked, _ = _batch(seed=11, app=app)
     run = lambda p: lm.apply(p, lm.noised(tokens, masked))
     base = run(params)
     for name in ("q_head_norm", "k_head_norm"):
         moved = {**params, "layers": [{**layer, name: layer[name] * 1.5}]
                  + params["layers"][1:]}
         assert float(jnp.abs(run(moved) - base).max()) > 1e-3, name
+
+
+def test_the_norm_inside_the_rotary_kernel_is_the_plain_paths(
+        rotary_engaged, monkeypatch):
+    """Loss and every gradient — ``q_head_norm`` and ``k_head_norm`` among
+    them, the kernel's ``dw`` — through ``harmony_rotary`` against the same
+    program on ``_norm`` + ``rope``, and against the reference."""
+    from harmony_tpu.runtime import progcache
+    from harmony_tpu.tracing import trace_span
+
+    lm, params, app, ref = _both(KERNEL_APP)
+    batch = _batch(seed=3, app=KERNEL_APP)
+    with trace_span("job.build_step", job_id="sdar-normed"):
+        loss, grads = jax.value_and_grad(lm.loss)(params, batch)
+    rows = [r for r in progcache.kernel_plans()["sdar-normed"]
+            if r["kernel"] == "harmony_rotary"]
+    assert {(r["sub"], r["normed"]) for r in rows} == {(16, True), (4, True)}
+    monkeypatch.undo()                                # ... and now plain
+    plain_loss, plain = jax.value_and_grad(lm.loss)(params, batch)
+    assert float(loss) == pytest.approx(float(plain_loss), rel=RTOL)
+    for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(plain)):
+        _close(a, b)
+    with jax.default_matmul_precision("highest"):
+        _, want_g = REF.loss_and_grad(ref, batch, app, REF.flags_of(None))
+    for a, b in zip(REF.from_program(grads, app)["layers"], want_g["layers"]):
+        for name in ("nq", "nk"):
+            assert float(jnp.abs(b[name]).max()) > 0, name
+            _close(a[name], b[name])
 
 
 def test_the_chosen_weights_are_renormalised_over_all_eight():

@@ -539,6 +539,21 @@ PARENT_PROGRAMS = {
     "smallthinker-21b-a3b+rotary": ("8dcf353b53475065", "685826e3ec19b101"),
     "laguna-s-2.1+rotary": ("11c72726b3016872", "feff4d686765a0aa"),
     "ouro-2.6b+rotary": ("f93bdbd386b3970a", "9ad9ead714ed8b52"),
+    # SDAR's own preset — a block-diffusion step over the tuple (tokens,
+    # masked, rate), a norm a head of q and k — RECORDED BY PR 60, which
+    # pins it for the first time (no PR before it did, so a later one could
+    # have changed it unseen). Its heads are 16 wide: ``plan`` declines, the
+    # norm and ``rope`` stay in XLA and the pair IS the parent's (commit
+    # 4f856ea, computed there with this file's helper). At the preset the
+    # kernel engages in (heads of 128) PR 60 meant to change the program
+    # and did: q and k reach ``harmony_rotary`` as the projection left
+    # them and the kernel norms each head before it turns it
+    # (ops/rotary.py ``turn(..., norm=)``); the parent's there, ``_norm``
+    # by heads in XLA round the plain kernel: 810f57d3f7f99992 /
+    # d8eb0e80fe4780f8. Every pair above is the parent's, none recorded
+    # again: ``turn`` without ``norm`` traces the parent's kernel
+    "sdar-30b-a3b": ("b3b0c2d6dad53fa7", "8fa884fbbbb6b95d"),
+    "sdar-30b-a3b+rotary": ("b10fe6a45f72cf12", "c6770d511f1b214a"),
 }
 CHUNKED = {"moe_experts": 64, "moe_top_k": 4, "moe_experts_held": 8}
 READOUT = {"d_model": 128, "vocab_size": 8192}
@@ -570,6 +585,10 @@ def _program_hashes(config):
     lm = TransformerLM(_config(app))
     params = jax.eval_shape(lambda: lm.init(jax.random.PRNGKey(0)))
     toks = jax.ShapeDtypeStruct((2, 1025), jnp.int32)
+    if lm.config.objective == "block_diffusion":  # (tokens, masked, rate)
+        toks = tuple(jax.ShapeDtypeStruct((2, n), t) for n, t in (
+            (1024, jnp.int32), (1024, jnp.int8),
+            (1024 // lm.config.diffusion_block, jnp.float32)))
     fn = jax.value_and_grad(lm.loss_and_metrics, has_aux=True)
     jaxpr = str(jax.make_jaxpr(fn)(params, toks))
     text = jax.jit(fn).trace(params, toks).lower(
