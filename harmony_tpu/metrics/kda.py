@@ -1,6 +1,6 @@
-"""What a model with recurrent layers — KDA blocks (gated delta-rule linear
-attention, ops/kda.py) or Mamba-2 state-space layers (ops/ssd.py) — tells an
-operator, and which kinds of layer a tenant runs.
+"""What a model with recurrent layers — KDA or Gated DeltaNet blocks (gated
+delta-rule linear attention with a decay a channel / a head, ops/kda.py) or
+Mamba-2 state-space layers (ops/ssd.py) — tells an operator, and which kinds of layer a tenant runs.
 
 A step of such a tenant reports, beside its scalars, two statistics of each
 recurrent layer (``STATS``) — vectors ``[layers of the kind]``, which the
@@ -12,22 +12,28 @@ and the trainer hands here:
     is the block's index in the model. A decay pinned at 1 never forgets (the
     state saturates), one pinned at 0 remembers one token;
   * ``harmony_kda_beta_mean{job,layer}`` — the mean write strength;
+  * ``harmony_gdn_decay_mean{job,layer}`` / ``harmony_gdn_beta_mean{job,
+    layer}`` — a Gated DeltaNet block's mean decay ``exp(g)`` over tokens
+    and VALUE heads (one scalar a head) and its mean write strength, read
+    as KDA's;
   * ``harmony_ssd_decay_mean{job,layer}`` — a state-space layer's mean
     per-head decay ``exp(-dt exp(a_log))``, read the same way;
   * ``harmony_ssd_dt_mean{job,layer}`` — its mean step ``dt = softplus(dt +
     dt_bias)``: what a token writes with and forgets by;
   * ``harmony_model_layers{job,kind}`` — how many layers of each kind
-    (``TransformerConfig.layer_kinds()``: ``kda`` | ``mha`` | ``mla`` |
-    ``swa`` | ``full``, and a ``layer_pattern`` model's ``ssd`` | ``attn`` |
+    (``TransformerConfig.layer_kinds()``: ``kda`` | ``gdn`` | ``mha`` |
+    ``mla`` | ``swa`` | ``full``, and a ``layer_pattern`` model's ``ssd`` | ``attn`` |
     ``moe``) the job's model has, set when the job initialises its table;
   * ``harmony_model_heads{job,kind}`` — the heads a block of each kind
-    mixes with (a softmax block's QUERY heads, ``TransformerConfig.heads``:
+    mixes with (a softmax block's QUERY heads, a ``gdn`` block's VALUE
+    heads — the states it carries —, ``TransformerConfig.heads``:
     a model whose ``full`` and ``swa`` blocks differ in them reads two
     values), set at the same place.
 
 Under a profiler session the light span ``kda.observe`` marks each drain.
 STATUS shows, per tenant, ``layer_kinds`` (:func:`kinds_by_job`) and ``kda:
-{decay_mean, beta_mean}`` / ``ssd: {decay_mean, dt_mean}`` over the layers
+{decay_mean, beta_mean}`` / ``gdn: {decay_mean, beta_mean}`` / ``ssd:
+{decay_mean, dt_mean}`` over the layers
 (:func:`stats_by_job`).
 """
 from __future__ import annotations
@@ -38,7 +44,8 @@ import numpy as np
 
 
 #: a recurrent layer's two step statistics, by kind
-STATS = {"kda": ("decay", "beta"), "ssd": ("decay", "dt")}
+STATS = {"kda": ("decay", "beta"), "gdn": ("decay", "beta"),
+         "ssd": ("decay", "dt")}
 
 
 def _gauges(kind: str):
@@ -55,6 +62,15 @@ def _gauges(kind: str):
                 reg.gauge("harmony_ssd_dt_mean",
                           "Mean step dt of a Mamba-2 state-space layer at "
                           "the newest drained step", ("job", "layer")))
+    if kind == "gdn":
+        return (reg.gauge("harmony_gdn_decay_mean",
+                          "Mean per-head decay exp(g) of a Gated DeltaNet "
+                          "block at the newest drained step",
+                          ("job", "layer")),
+                reg.gauge("harmony_gdn_beta_mean",
+                          "Mean write strength beta of a Gated DeltaNet "
+                          "block at the newest drained step",
+                          ("job", "layer")))
     return (reg.gauge("harmony_kda_decay_mean",
                       "Mean per-channel decay exp(g) of a KDA block at the "
                       "newest drained step", ("job", "layer")),

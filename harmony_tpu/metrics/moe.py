@@ -22,7 +22,11 @@ trainer hands here:
     static capacity they ran (models/moe.py ``chunk_plan``: ``ceil(held /
     C)`` a call, host arithmetic on the same vector; 0 a call where the
     plain full-length path runs). Their ratio is 1.0 while every call's held
-    slots fit one chunk.
+    slots fit one chunk;
+  * ``harmony_moe_shared_gate_mean{job,layer}`` — of a layer whose shared
+    MLP is gated (``moe_shared_gate``), the mean over tokens of ``sigmoid(x .
+    shared_gate)`` at the newest drained step: pinned at 0 the shared expert
+    is switched off, at 1 it is an ungated one.
 
 Under a profiler session the span ``moe.observe`` (light; opened at each
 drain) carries the drained steps' held token-slots one by one
@@ -79,6 +83,15 @@ def _dropped_families():
                 "Token-slots whose router chose no expert", ("job",)))
 
 
+def _shared_gate_gauge():
+    from harmony_tpu.metrics.registry import get_registry
+
+    return get_registry().gauge(
+        "harmony_moe_shared_gate_mean",
+        "Mean sigmoid gate on an expert layer's shared MLP at the newest "
+        "drained step (moe_shared_gate)", ("job", "layer"))
+
+
 def chunks_run(expert_tokens: np.ndarray, experts_held: int) -> np.ndarray:
     """Chunks each layer call ran, ``[steps, layers]``, from its token
     counts ``[steps, layers, experts]``: the plan is the program's own
@@ -114,13 +127,15 @@ def _grid(tokens, job: str, layers: Sequence[int], experts: int):
 
 def observe(job: str, expert_tokens: np.ndarray, experts_held: int,
             layers: Optional[Sequence[int]] = None,
-            null_slots: Optional[np.ndarray] = None) -> None:
+            null_slots: Optional[np.ndarray] = None,
+            shared_gate: Optional[np.ndarray] = None) -> None:
     """Add ``expert_tokens [steps, expert layers, experts]`` to the counters;
     ``layers`` are those layers' block indices (``TransformerConfig.
     moe_layers()``: the ``layer`` label is the block's index, so a leading
     dense block has no row at all; None: every block is an expert layer);
     ``null_slots [steps, expert layers]``: the slots that chose no expert,
-    of a router that has that output."""
+    of a router that has that output; ``shared_gate [steps, expert layers]``:
+    the mean gate on a gated shared expert (the newest step stands)."""
     from harmony_tpu.tracing import trace_span
 
     by_step = np.asarray(expert_tokens, np.float64)
@@ -143,6 +158,10 @@ def observe(job: str, expert_tokens: np.ndarray, experts_held: int,
         absent.labels(job=job).inc(float(per.sum() - held_by_step.sum()))
         if null_slots is not None:
             null.labels(job=job).inc(float(np.asarray(null_slots).sum()))
+        if shared_gate is not None:
+            gauge = _shared_gate_gauge()
+            for layer, value in zip(layers, np.asarray(shared_gate)[-1]):
+                gauge.labels(job=job, layer=str(layer)).set(float(value))
         held.labels(job=job).set(experts_held)
         calls.labels(job=job).inc(by_step.shape[0] * by_step.shape[1])
         chunks.labels(job=job).inc(float(chunks_run(by_step,

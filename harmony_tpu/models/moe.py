@@ -199,6 +199,9 @@ class DroplessConfig:
     gated: bool = True
     latent: int = 0
     shared_d_ff: int = 0
+    # Qwen3-Next's shared expert: ``shared_gate`` multiplies the shared
+    # MLP's output by ``sigmoid(x . shared_gate [d])``, one scalar a token
+    shared_gate: bool = False
     # ZAYA1's router (arXiv:2511.17127; :func:`_mlp_logits`):
     # ``router_hidden`` > 0 is the width of an MLP where the other routers
     # have one matrix — its pre-norm rows also reach the next expert layer's
@@ -257,6 +260,9 @@ def init_dropless_params(rng: jax.Array, cfg: DroplessConfig
             params["shared_wg"] = jax.random.normal(ksg, (d, fs), jnp.float32) * (d ** -0.5)
         params["shared_wu"] = jax.random.normal(ksu, (d, fs), jnp.float32) * (d ** -0.5)
         params["shared_wd"] = jax.random.normal(ksd, (fs, d), jnp.float32) * (fs ** -0.5)
+        if cfg.shared_gate:
+            params["shared_gate"] = jax.random.normal(
+                jax.random.fold_in(rng, 3), (d,), jnp.float32) * (d ** -0.5)
     if cfg.latent:
         kld, klu = jax.random.split(jax.random.fold_in(rng, 2))
         params["latent_down"] = jax.random.normal(kld, (d, r), jnp.float32) * (d ** -0.5)
@@ -755,5 +761,12 @@ def moe_ffn_dropless(params: Dict[str, jnp.ndarray], x: jnp.ndarray,
                       * (full @ params["shared_wu"].astype(dtype)))
             else:
                 hs = _ACTS[cfg.act](full @ params["shared_wu"].astype(dtype))
-            out = out + hs @ params["shared_wd"].astype(dtype)
+            shared = hs @ params["shared_wd"].astype(dtype)
+            if cfg.shared_gate:  # a d-wide row a token: float32, no matmul
+                gate = jax.nn.sigmoid(jnp.sum(
+                    full.astype(jnp.float32) * params["shared_gate"],
+                    axis=-1, keepdims=True))
+                shared = shared * gate.astype(dtype)
+                stats["shared_gate"] = lax.stop_gradient(gate.mean())
+            out = out + shared
     return out, stats

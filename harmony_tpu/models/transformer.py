@@ -305,7 +305,7 @@ class TransformerConfig:
     # ``wo`` (headwise gating at the attention output, arXiv:2505.06708).
     kind_heads: Any = ()
     kind_rope: Any = ()
-    attn_gate: str = "none"         # "none" | "head"
+    attn_gate: str = "none"         # "none" | "head" | "element" (below)
     # Ouro's block and step (``model_type`` "ouro": ByteDance's Ouro-2.6B,
     # the looped language model of arXiv:2510.25741), ``attn_kind="mha"``
     # under ``pos="rope"``, dense: ``loop_steps`` (``total_ut_steps``): the
@@ -327,6 +327,31 @@ class TransformerConfig:
     sandwich_norm: bool = False
     exit_gate: bool = False
     exit_entropy_weight: float = 0.0
+    # Qwen3-Next's block (``model_type`` "qwen3_next": Qwen3-Next-80B-A3B),
+    # ``attn_kind="mha"`` under ``pos="rope"`` beside ``linear_layers``:
+    # ``linear_kind="gdn"``: the blocks in ``linear_layers`` mix by Gated
+    # DeltaNet (arXiv:2412.06464; ops/kda.py ``gdn_attention``) — ONE scalar
+    # decay a head and position where KDA has one a channel, ``linear_heads``
+    # KEY heads serving ``linear_value_heads`` value heads in groups (0: one
+    # each; value head ``j`` reads key head ``j // group``), ONE input
+    # projection ``w_qkvz`` and ONE ``short_conv``-tap convolution over its
+    # ``q | k | v`` columns, the decay's and beta's scalars from one
+    # ``w_ba``, and the output gate ``silu(z)`` AFTER the per-head norm;
+    # rotary turns the softmax blocks only, the delta-rule blocks carry no
+    # positions. ``attn_gate="element"``: ``wqkv``'s q block is ``2 x heads
+    # x head_dim`` wide — a head's query columns, then its gate's — and the
+    # head's attention output is multiplied element by element by
+    # ``sigmoid`` of those (``head_norm`` and the rotary act on the query
+    # half only). ``norm_offset``: the weight of every norm of the MODEL's
+    # kind (``ln1``, ``ln2``, ``ln_f``, ``q_head_norm``, ``k_head_norm``)
+    # enters as ``1 + w`` and starts at 0 (``norm_weight``); a delta-rule
+    # block's output norm keeps the plain weight. ``moe_shared_gate``: an
+    # expert layer's shared MLP times ``sigmoid(x . shared_gate)``, one
+    # scalar a token (models/moe.py).
+    linear_kind: str = "kda"        # "kda" | "gdn"
+    linear_value_heads: int = 0
+    norm_offset: bool = False
+    moe_shared_gate: bool = False
     # Standard deviation the embedding rows are drawn with. GPT-2's 0.02
     # leaves a row at a fiftieth of what a block's fan-in projections add to
     # it, which nothing here divides by depth: attention's average over the
@@ -562,7 +587,9 @@ class TransformerConfig:
                 "layer_pattern layer is one sublayer under one norm")
         if self.head_norm and (self.qk_norm or self.cca
                                or self.attn_kind != "mha"
-                               or self.linear_layers or self.layer_pattern):
+                               or (self.linear_layers
+                                   and self.linear_kind != "gdn")
+                               or self.layer_pattern):
             raise ValueError(
                 "head_norm norms each head's columns of an attn_kind='mha' "
                 "block's q and k: qk_norm would norm the same columns over "
@@ -601,7 +628,49 @@ class TransformerConfig:
                 f"attn_kind {self.attn_kind!r})")
         self._check_kinds()
         self._check_loop()
+        self._check_gdn()
         validate_attn(self.attn)
+
+    def _check_gdn(self) -> None:
+        """``linear_kind`` / ``linear_value_heads`` / ``norm_offset`` /
+        ``moe_shared_gate``: Qwen3-Next's, beside ``attn_kind="mha"`` blocks
+        whose whole causal past one kind of softmax block sees."""
+        if self.linear_kind not in ("kda", "gdn"):
+            raise ValueError(f"unknown linear_kind {self.linear_kind!r}: "
+                             "'kda' or 'gdn'")
+        gdn = self.linear_kind == "gdn"
+        if (gdn or self.linear_value_heads) and not (
+                gdn and self.linear_layers
+                and self.linear_value_heads >= 0
+                and self.linear_value_heads % self.linear_heads == 0):
+            raise ValueError(
+                "linear_kind='gdn' names the mixer of the blocks in "
+                "linear_layers, and linear_value_heads its value heads: "
+                "whole groups of the linear_heads key heads (got "
+                f"linear_layers {self.linear_layers}, linear_kind "
+                f"{self.linear_kind!r}, heads {self.linear_heads} / "
+                f"{self.linear_value_heads})")
+        if gdn and (self.attn_kind != "mha" or self.window_layers
+                    or self.pos == "learned"):
+            raise ValueError(
+                "linear_kind='gdn' runs beside attn_kind='mha' blocks that "
+                "see the whole causal past, under pos='rope' (rotary in "
+                "the softmax blocks only) or 'none': not beside latent "
+                "(mla) or windowed blocks or a learned position table")
+        if self.norm_offset and (
+                self.qk_norm or self.attn_kind != "mha" or self.cca
+                or self.layer_pattern or self.sandwich_norm
+                or self.moe_router_hidden):
+            raise ValueError(
+                "norm_offset reads ln1 / ln2 / ln_f and the head norms as "
+                "1 + w: a d_model-wide qk_norm, a latent's (mla), an MLP "
+                "router's, a layer_pattern layer's and the ln*_post norms "
+                "have no such form, and cca norms nothing by weight")
+        if self.moe_shared_gate and not (self.moe_top_k
+                                         and self.moe_shared_experts):
+            raise ValueError("moe_shared_gate gates a dropless expert "
+                             "layer's shared MLP: set moe_top_k and "
+                             "moe_shared_experts")
 
     def _check_loop(self) -> None:
         """``loop_steps`` / ``sandwich_norm`` / ``exit_gate`` /
@@ -644,9 +713,9 @@ class TransformerConfig:
                              for k, v in dict(self.kind_rope).items()))
         object.__setattr__(self, "kind_heads", heads)
         object.__setattr__(self, "kind_rope", ropes)
-        if self.attn_gate not in ("none", "head"):
-            raise ValueError(f"unknown attn_gate {self.attn_gate!r}: 'none' "
-                             "or 'head'")
+        if self.attn_gate not in ("none", "head", "element"):
+            raise ValueError(f"unknown attn_gate {self.attn_gate!r}: 'none', "
+                             "'head' or 'element'")
         if (heads or ropes) and (not self.window_layers or self.cca):
             raise ValueError(
                 "kind_heads / kind_rope tell the 'full' and 'swa' blocks of "
@@ -654,14 +723,18 @@ class TransformerConfig:
                 "a model of one kind of softmax block says n_heads, "
                 "rope_theta and rope_fraction")
         if self.attn_gate != "none" and (
-                self.attn_kind != "mha" or self.cca or self.linear_layers
-                or self.objective != "next_token"):
+                self.attn_kind != "mha" or self.cca
+                or (self.linear_layers and self.linear_kind != "gdn")
+                or self.objective != "next_token"
+                or (self.attn_gate == "element" and self.window_layers)):
             raise ValueError(
                 "attn_gate='head' gates the heads of an attn_kind='mha' "
                 "block's softmax attention under the next-token objective: "
                 "not beside latent (mla) or CCA attention, KDA blocks (their "
                 "mixer has its own output gate) or the two streams of "
-                "block diffusion")
+                "block diffusion; 'element' (a gate as wide as the head, out "
+                "of wqkv's q block) not in a model of two kinds of softmax "
+                "block (window_layers)")
         kinds = {"full", "swa"}
         if set(dict(heads)) - kinds or (ropes and set(dict(ropes)) != kinds):
             raise ValueError(
@@ -686,7 +759,14 @@ class TransformerConfig:
         a softmax block's QUERY heads, a KDA or state-space mixer's own."""
         if kind in ("kda", "ssd"):
             return self.linear_heads if kind == "kda" else self.ssd_heads
+        if kind == "gdn":  # its VALUE heads: the states it carries
+            return self.linear_value_heads or self.linear_heads
         return dict(self.kind_heads).get(kind, self.n_heads)
+
+    def norm_weight(self, w):
+        """A norm's weight as the model's norm multiplies by it: the leaf,
+        or under ``norm_offset`` ``1 + w`` (float32, before any cast)."""
+        return w + 1.0 if self.norm_offset else w
 
     def rotary(self, kind: Optional[str] = None) -> Optional["Rotary"]:
         """How a softmax block of ``kind`` turns q and k — the ONE answer
@@ -721,8 +801,8 @@ class TransformerConfig:
 
     def layer_kinds(self) -> Tuple[str, ...]:
         """Each block's token mixer, in order — the ONE answer to "which
-        kind of layer is this": ``"kda"`` for the blocks in
-        ``linear_layers``, else ``attn_kind`` (``"mha"`` | ``"mla"``) — or,
+        kind of layer is this": ``linear_kind`` (``"kda"`` | ``"gdn"``) for
+        the blocks in ``linear_layers``, else ``attn_kind`` (``"mha"`` | ``"mla"``) — or,
         in a model with ``window_layers``, ``"swa"`` for those (windowed,
         rotary) and ``"full"`` for its other softmax blocks (whole causal
         past, no positions), and under ``objective="block_diffusion"``
@@ -739,7 +819,7 @@ class TransformerConfig:
 
         def kind(i):
             if i in self.linear_layers:
-                return "kda"
+                return self.linear_kind
             if self.objective == "block_diffusion":
                 return "full"  # the whole past of its stream, by block
             if not self.window_layers:
@@ -775,6 +855,7 @@ class TransformerConfig:
             shared_experts=self.moe_shared_experts, seq_aux=self.moe_seq_aux,
             act=self.moe_act, gated=self.moe_gated, latent=self.moe_latent,
             shared_d_ff=self.moe_shared_d_ff,
+            shared_gate=self.moe_shared_gate,
             router_hidden=self.moe_router_hidden,
             null_expert=self.moe_null_expert, norm_eps=self.norm_eps)
 
@@ -795,6 +876,15 @@ class TransformerConfig:
         """``qkv_widths`` in a block of ``kind`` (``kind_heads``)."""
         hd = self.head_dim
         return self.heads(kind) * hd, self.kv_heads * hd, self.kv_heads * hd
+
+    @property
+    def gdn_widths(self) -> Tuple[int, int, int]:
+        """A Gated DeltaNet mixer's widths: its key heads' channels ``Hk
+        dh`` (q and k each), its value heads' ``Hv dh`` (v and z each), and
+        what the convolution runs over (``q | k | v``)."""
+        key = self.linear_heads * self.linear_head_dim
+        value = self.heads("gdn") * self.linear_head_dim
+        return key, value, 2 * key + value
 
     @property
     def ssd_widths(self) -> Tuple[int, int, int]:
@@ -945,6 +1035,39 @@ def init_kda_params(k_in: jax.Array, k_out: jax.Array,
     }
 
 
+#: a Gated DeltaNet block's initial decay, as ``transformers``'
+#: ``Qwen3NextGatedDeltaNet`` draws it: ``a_log = log U(0, 16)`` and
+#: ``dt_bias = 1`` a value head
+GDN_A = (0.0, 16.0)
+
+
+def init_gdn_params(k_in: jax.Array, k_out: jax.Array,
+                    cfg: TransformerConfig) -> Dict[str, jnp.ndarray]:
+    """A Gated DeltaNet mixer's parameters (``TransformerLM._gdn_mixer``):
+    ONE input projection ``w_qkvz [d, q | k | v | z]`` (``Hk dh`` columns
+    each of q and k, ``Hv dh`` each of v and z), the taps ``conv [K, q | k |
+    v]`` of its one convolution (uniform in ``+-K^-1/2``, no bias), ``w_ba
+    [2 Hv, d]`` for beta's and the decay's scalars (rows ``b | a``; stored
+    transposed, as KDA's ``wb``), ``a_log`` and ``dt_bias [Hv]``, the
+    per-head output norm ``o_norm [dh]`` (a plain weight, one for every
+    head) and ``wo [Hv dh, d]``."""
+    from harmony_tpu.models.common import dense_init as dense
+
+    d, K, Hv = cfg.d_model, cfg.short_conv, cfg.heads("gdn")
+    key, value, conv = cfg.gdn_widths
+    ki, kc, ka, kb = jax.random.split(k_in, 4)
+    return {
+        "w_qkvz": dense(ki, (d, conv + value)),
+        "conv": jax.random.uniform(kc, (K, conv), jnp.float32,
+                                   -K ** -0.5, K ** -0.5),
+        "w_ba": dense(kb, (d, 2 * Hv)).T,
+        "a_log": jnp.log(jax.random.uniform(ka, (Hv,), jnp.float32, *GDN_A)),
+        "dt_bias": jnp.ones((Hv,), jnp.float32),
+        "o_norm": jnp.ones((cfg.linear_head_dim,), jnp.float32),
+        "wo": dense(k_out, (value, d)),
+    }
+
+
 def init_ssd_params(k_in: jax.Array, k_out: jax.Array,
                     cfg: TransformerConfig) -> Dict[str, jnp.ndarray]:
     """A Mamba-2 mixer's parameters (``TransformerLM._ssd_mixer``): the
@@ -1022,6 +1145,12 @@ def _merge(x, y, m):
                 + m[2] * (y.astype(f32) + m[3])).astype(x.dtype)
 
 
+def _l2norm(t):
+    """``t`` over its last axis' l2 norm (``KDA_L2_EPS`` under the root): a
+    delta-rule block's q and k, a head each."""
+    return t * lax.rsqrt(jnp.sum(t * t, axis=-1, keepdims=True) + KDA_L2_EPS)
+
+
 def _causal_conv(t, taps):
     """The depthwise causal convolution of ``t [B, S, c]`` by ``taps [K,
     c]``, in float32: ``y_t = sum_j taps[j] t_{t - (K-1) + j}``."""
@@ -1080,6 +1209,10 @@ class TransformerLM:
 
         layers = []
         kinds = cfg.layer_kinds()
+        # the model's norm starts as the identity: a weight of 1, or 0
+        # where it enters as 1 + w
+        unit = ((lambda n: jnp.zeros((n,), jnp.float32)) if cfg.norm_offset
+                else (lambda n: jnp.ones((n,), jnp.float32)))
         for i, kl in enumerate(k_layers):
             ks = jax.random.split(kl, 4)
             f = cfg.ffn_width(i)
@@ -1097,11 +1230,13 @@ class TransformerLM:
                         ks[2], cfg.dropless_cfg)
                 layers.append(layer)
                 continue
-            if kinds[i] == "kda":
+            if kinds[i] in ("kda", "gdn"):
+                mixer = init_kda_params if kinds[i] == "kda" \
+                    else init_gdn_params
                 layer = {
-                    "ln1": jnp.ones((d,), jnp.float32),
-                    "kda": init_kda_params(ks[0], ks[1], cfg),
-                    "ln2": jnp.ones((d,), jnp.float32),
+                    "ln1": unit(d),
+                    kinds[i]: mixer(ks[0], ks[1], cfg),
+                    "ln2": unit(d),
                 }
             elif cfg.attn_kind == "mla":
                 kq, ka, kb = jax.random.split(ks[0], 3)
@@ -1118,11 +1253,13 @@ class TransformerLM:
                 }
             else:
                 widths = cfg.qkv_widths_of(kinds[i])
+                # attn_gate="element": the q block carries the gate's columns
+                gate = widths[0] if cfg.attn_gate == "element" else 0
                 layer = {
-                    "ln1": jnp.ones((d,), jnp.float32),
-                    "wqkv": dense(ks[0], (d, sum(widths))),
+                    "ln1": unit(d),
+                    "wqkv": dense(ks[0], (d, sum(widths) + gate)),
                     "wo": dense(ks[1], (widths[0], d)),
-                    "ln2": jnp.ones((d,), jnp.float32),
+                    "ln2": unit(d),
                 }
                 if cfg.attn_gate == "head":  # stored [heads, d], as KDA's wb
                     layer["wgate"] = dense(jax.random.fold_in(ks[0], 2),
@@ -1133,9 +1270,9 @@ class TransformerLM:
             if cfg.qk_norm:
                 layer["q_norm"] = jnp.ones((d,), jnp.float32)
                 layer["k_norm"] = jnp.ones((d,), jnp.float32)
-            if cfg.head_norm:
-                layer["q_head_norm"] = jnp.ones((cfg.head_dim,), jnp.float32)
-                layer["k_head_norm"] = jnp.ones((cfg.head_dim,), jnp.float32)
+            if cfg.head_norm and kinds[i] not in ("kda", "gdn"):
+                layer["q_head_norm"] = unit(cfg.head_dim)
+                layer["k_head_norm"] = unit(cfg.head_dim)
             if cfg.cca:
                 layer["cca"] = init_cca_params(
                     jax.random.fold_in(ks[0], 1), cfg)
@@ -1157,7 +1294,7 @@ class TransformerLM:
         params = {
             "embed": jax.random.normal(k_emb, (cfg.vocab_size, d), jnp.float32)
             * cfg.embed_std,
-            "ln_f": jnp.ones((d,), jnp.float32),
+            "ln_f": unit(d),
             "layers": layers,
         }
         if cfg.pos == "learned":
@@ -1287,17 +1424,13 @@ class TransformerLM:
         def conv(t, taps):  # no bias
             return heads(jax.nn.silu(_causal_conv(t, taps)))
 
-        def l2(t):
-            return t * lax.rsqrt(jnp.sum(t * t, axis=-1, keepdims=True)
-                                 + KDA_L2_EPS)
-
         def proj(name):
             with step_scope("kda.proj"):
                 return xn @ w(name)
 
         with step_scope("kda.conv"):
-            q = l2(conv(proj("wq"), p["conv_q"])) * dh ** -0.5
-            k = l2(conv(proj("wk"), p["conv_k"]))
+            q = _l2norm(conv(proj("wq"), p["conv_q"])) * dh ** -0.5
+            k = _l2norm(conv(proj("wk"), p["conv_k"]))
             v = conv(proj("wv"), p["conv_v"])
         with step_scope("kda.gate"):
             f = heads(((xn @ w("wf_a")) @ w("wf_b")).astype(f32)
@@ -1314,6 +1447,54 @@ class TransformerLM:
         with step_scope("kda.out"):
             o = _norm(o, w("o_norm"), cfg.norm_eps) * gate
             y = o.transpose(0, 2, 1, 3).reshape(B, S, H * dh) @ w("wo")
+        with step_scope("kda.gate"):
+            return y, {"decay": lax.stop_gradient(jnp.exp(g).mean()),
+                       "beta": lax.stop_gradient(beta.mean())}
+
+    def _gdn_mixer(self, xn, p):
+        """Qwen3-Next's Gated DeltaNet token mixer on the normed input ``xn
+        [B, S, d]``: ``(y [B, S, d], {"decay", "beta"})``, under
+        ``_kda_mixer``'s scopes. ``[q | k | v | z] = xn W_qkvz``; ``q | k |
+        v`` through ONE depthwise causal convolution and a SiLU; per key
+        head ``q = l2norm(q) dh^-1/2``, ``k = l2norm(k)``; per VALUE head and
+        position the scalars ``beta = sigmoid(b)`` and the log-decay ``g =
+        -exp(a_log) softplus(a + dt_bias)`` (float32) from ``[b | a] = xn
+        W_ba``; the gated delta rule with value head ``j`` on key head ``j
+        // (Hv / Hk)`` (ops/kda.py ``gdn_attention``); the output ``W_o
+        [rmsnorm per head (o) * silu(z)]`` — the norm first, a plain weight.
+        The statistics are the layer's mean decay ``exp(g)`` and mean
+        ``beta`` (no gradient)."""
+        from harmony_tpu.ops.kda import gdn_attention
+
+        cfg = self.config
+        B, S = xn.shape[0], xn.shape[1]
+        Hv, dh, dt = cfg.heads("gdn"), cfg.linear_head_dim, cfg.dtype
+        key, value, conv = cfg.gdn_widths
+        f32 = jnp.float32
+        heads = lambda t: t.reshape(B, S, -1, dh).transpose(0, 2, 1, 3)
+
+        with step_scope("kda.proj"):
+            qkvz = xn @ p["w_qkvz"].astype(dt)
+            ba = jnp.einsum("bsd,hd->bhs", xn, p["w_ba"].astype(dt))
+        with step_scope("kda.conv"):
+            q, k, v = jnp.split(
+                jax.nn.silu(_causal_conv(qkvz[..., :conv], p["conv"])),
+                (key, 2 * key), axis=-1)
+            q, k = _l2norm(heads(q)) * dh ** -0.5, _l2norm(heads(k))
+            v = heads(v)
+        with step_scope("kda.gate"):
+            beta = jax.nn.sigmoid(ba[:, :Hv].astype(f32))
+            g = -jnp.exp(p["a_log"])[None, :, None] * jax.nn.softplus(
+                ba[:, Hv:].astype(f32) + p["dt_bias"][None, :, None])
+        with step_scope("kda.scan"):
+            o = gdn_attention(q.astype(dt), k.astype(dt), v.astype(dt), g,
+                              beta)
+        with step_scope("kda.gate"):
+            gate = jax.nn.silu(heads(qkvz[..., conv:]).astype(f32)).astype(dt)
+        with step_scope("kda.out"):
+            o = _norm(o, p["o_norm"].astype(dt), cfg.norm_eps) * gate
+            y = o.transpose(0, 2, 1, 3).reshape(B, S, value) \
+                @ p["wo"].astype(dt)
         with step_scope("kda.gate"):
             return y, {"decay": lax.stop_gradient(jnp.exp(g).mean()),
                        "beta": lax.stop_gradient(beta.mean())}
@@ -1406,10 +1587,13 @@ class TransformerLM:
         cfg = self.config
         eps = cfg.norm_eps
         x_in = x
+        weight = lambda name: cfg.norm_weight(layer[name]).astype(cfg.dtype)
         with step_scope("norm"):
-            xn = _norm(x, layer["ln1"].astype(cfg.dtype), eps)
+            xn = _norm(x, weight("ln1"), eps)
         if "kda" in layer:
             y, mix = self._kda_mixer(xn, layer["kda"])
+        elif "gdn" in layer:
+            y, mix = self._gdn_mixer(xn, layer["gdn"])
         else:
             y, mix = self._softmax_mixer(xn, layer, axis_name, pos_offset,
                                          kind), None
@@ -1418,7 +1602,7 @@ class TransformerLM:
                 y = _norm(y, layer["ln1_post"].astype(cfg.dtype), eps)
         x = _merge(x, y, layer.get("merge1"))
         with step_scope("norm"):
-            xn = _norm(x, layer["ln2"].astype(cfg.dtype), eps)
+            xn = _norm(x, weight("ln2"), eps)
         route = {"router_x": x_in} if cfg.moe_route_block_input else {}
         if route_state is not None:
             route["route_state"] = route_state
@@ -1481,7 +1665,13 @@ class TransformerLM:
         else:
             with step_scope("mixer.qkv"):
                 qkv = xn @ layer["wqkv"].astype(cfg.dtype)      # [B, S, 3d]
-                if cfg.qkv_widths_of(kind) == (cfg.d_model,) * 3:
+                gate = None
+                if cfg.attn_gate == "element":  # a head: query | gate
+                    wq, wk, _ = cfg.qkv_widths_of(kind)
+                    qg, k, v = jnp.split(qkv, (2 * wq, 2 * wq + wk), axis=-1)
+                    qg = qg.reshape(B, S, h, 2 * hd)
+                    q, gate = qg[..., :hd].reshape(B, S, wq), qg[..., hd:]
+                elif cfg.qkv_widths_of(kind) == (cfg.d_model,) * 3:
                     q, k, v = jnp.split(qkv, 3, axis=-1)
                 else:  # grouped queries: k and v are kv_heads heads wide
                     wq, wk, _ = cfg.qkv_widths_of(kind)
@@ -1505,12 +1695,13 @@ class TransformerLM:
                 elif not cfg.cca:
                     q, k, v = to_heads(q), to_heads(k), to_heads(v)
                 norms = (None, None)
+                head_w = lambda name: cfg.norm_weight(layer[name])
                 if cfg.head_norm and by_rows:
-                    norms = ((layer["q_head_norm"], eps),
-                             (layer["k_head_norm"], eps))
+                    norms = ((head_w("q_head_norm"), eps),
+                             (head_w("k_head_norm"), eps))
                 elif cfg.head_norm:  # a head at a time, one weight for all
-                    q = _norm(q, layer["q_head_norm"].astype(cfg.dtype), eps)
-                    k = _norm(k, layer["k_head_norm"].astype(cfg.dtype), eps)
+                    q = _norm(q, head_w("q_head_norm").astype(cfg.dtype), eps)
+                    k = _norm(k, head_w("k_head_norm").astype(cfg.dtype), eps)
             if cfg.cca:
                 with step_scope("mixer.cca"):
                     q, k, v = (to_heads(t) for t in self._cca_latent(
@@ -1533,6 +1724,12 @@ class TransformerLM:
                 o = o * gate[..., None].astype(o.dtype)
         with step_scope("mixer.out"):
             o = o.transpose(0, 2, 1, 3).reshape(B, S, h * v.shape[3])
+        if cfg.attn_gate == "element":  # one a column of the head, which
+            # lies as the projection left it: no transpose of the gate
+            with step_scope("mixer.gate"):
+                o = o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(
+                    o.dtype).reshape(o.shape)
+        with step_scope("mixer.out"):
             return o @ layer["wo"].astype(cfg.dtype)
 
     def apply(
@@ -1654,8 +1851,8 @@ class TransformerLM:
                     aux = aux + a
             if cfg.loop_steps > 1:
                 with step_scope("head"):
-                    x = _norm(x, params["ln_f"].astype(cfg.dtype),
-                              cfg.norm_eps)
+                    x = _norm(x, cfg.norm_weight(params["ln_f"]).astype(
+                        cfg.dtype), cfg.norm_eps)
                 exits.append(x)
         if kept:
             from harmony_tpu.runtime.progcache import note_remat_saved
@@ -1667,11 +1864,15 @@ class TransformerLM:
             if "skipped" in aux:
                 aux["skipped_by_layer"] = jnp.stack(
                     [a["skipped"] for a in routed])
+            if "shared_gate" in aux:
+                aux["shared_gate_by_layer"] = jnp.stack(
+                    [a["shared_gate"] for a in routed])
         if not exits:
             with step_scope("head"):
                 if cfg.objective == "block_diffusion":
                     x = x[x.shape[0] // 2:]  # ONE readout: the noisy rows
-                x = _norm(x, params["ln_f"].astype(cfg.dtype), cfg.norm_eps)
+                x = _norm(x, cfg.norm_weight(params["ln_f"]).astype(
+                    cfg.dtype), cfg.norm_eps)
             exits = [x]
         mixers = (jax.tree.map(lambda *xs: jnp.stack(xs), *mixers)
                   if mixers else None)
@@ -1815,7 +2016,7 @@ class TransformerLM:
             nll = self._fused_nll(params, x, tokens[:, 1:], tiles)
         kda = {}
         if mixers is not None:  # one recurrent kind a model
-            kind = "ssd" if cfg.layer_pattern else "kda"
+            kind = "ssd" if cfg.layer_pattern else cfg.linear_kind
             kda = {f"{kind}_{stat}_mean": v for stat, v in mixers.items()}
         with step_scope("loss"):
             ce = (_next_token_ce(logits, tokens[:, 1:]) if tiles is None
@@ -1829,6 +2030,8 @@ class TransformerLM:
                 loss = ce + cfg.moe_aux_weight * lb + cfg.moe_z_weight * z
                 if cfg.moe_null_expert:
                     kda["moe_null_slots"] = aux["skipped_by_layer"]
+                if cfg.moe_shared_gate:
+                    kda["moe_shared_gate_mean"] = aux["shared_gate_by_layer"]
                 return loss, {"ce": ce, "aux_lb": lb, "aux_z": z,
                               "moe_expert_tokens": aux["tokens_by_layer"],
                               **kda}
@@ -2435,7 +2638,8 @@ class TransformerTrainer(PyTreeTrainer):
             moe.observe(job_id, vectors["moe_expert_tokens"],
                         self.config.dropless_cfg.experts_held,
                         self.config.moe_layers(),
-                        null_slots=vectors.get("moe_null_slots"))
+                        null_slots=vectors.get("moe_null_slots"),
+                        shared_gate=vectors.get("moe_shared_gate_mean"))
         if "diffusion_tokens" in vectors:
             from harmony_tpu.metrics import diffusion
 

@@ -52,6 +52,19 @@ XLA's, outside: their derivatives are autodiff's. The XLA form
 (``_scan_chunks``) keeps autodiff through the whole of ``_chunk``: it is what
 the kernels are tested against.
 
+**One decay a head** (``gdn_attention``: Gated DeltaNet, arXiv:2412.06464,
+Qwen3-Next's linear mixer). With ``G [C, 1]`` the decay leaves the sums over
+channels: ``A = b (k k^T) * D`` and ``Aqk = (q k^T) * D`` with ``D[r, i] =
+exp(G_r - G_i)`` — two MXU products under one mask (``_pair_scalar``) where
+the channel form takes 16 row shifts and three split products; everything
+after the pair matrices — the solve, the application, the backward around
+``X``, the state's carry, the kept residuals — is the code above, reading a
+``[C, 1]`` decay by broadcasting. Its kernels (``harmony_gdn_fwd``,
+``harmony_gdn_bwd``) walk VALUE heads and read ``q, k`` at their key head
+(value head ``j`` on key head ``j // (Hv / Hk)``, by the index map: no repeated
+copy in HBM), take ``g`` and ``beta`` as ``[1, C]`` rows a chunk and form ``b
+k``, ``b v`` inside.
+
 One predicate chooses (``_kernel_route``: the traced program runs on a
 one-chip TPU mesh), as for the flash kernels; no option and no environment
 variable.
@@ -71,7 +84,8 @@ from harmony_tpu.ops.residuals import KDA_OUT, KDA_SOLVE, KDA_STATE, keep
 
 #: the kernels' names in a device trace (perf/layer_metrics read them) and in
 #: STATUS ``kernel_plans``
-KERNEL_NAMES = {"fwd": "harmony_kda_fwd", "bwd": "harmony_kda_bwd"}
+KERNEL_NAMES = {"fwd": "harmony_kda_fwd", "bwd": "harmony_kda_bwd",
+                "gdn_fwd": "harmony_gdn_fwd", "gdn_bwd": "harmony_gdn_bwd"}
 CHUNK = 64     # positions a chunk (the published choice)
 SUB = 16       # rows a sub-block: pairs inside it come from row shifts
 
@@ -150,9 +164,47 @@ def _row(G, n):
     return jnp.sum(jnp.where(row == n, G, 0.0), axis=0, keepdims=True)
 
 
+def _eye(C):
+    return (lax.broadcasted_iota(jnp.int32, (C, C), 0)
+            == lax.broadcasted_iota(jnp.int32, (C, C), 1))
+
+
+def _col(x):
+    """``x [1, C]`` as a column ``[C, 1]`` (a masked sum: no transpose)."""
+    return jnp.sum(jnp.where(_eye(x.shape[1]), x, 0.0), axis=1, keepdims=True)
+
+
+def _lane(x):
+    """``x [C, 1]`` along the lanes, ``[1, C]``."""
+    return jnp.sum(jnp.where(_eye(x.shape[0]), x, 0.0), axis=0, keepdims=True)
+
+
+def _pair_scalar(qf, kf, kbf, G, mxu):
+    """``_pair`` under ONE decay a head, ``G [C, 1]``: the decay leaves the
+    sum over channels, ``A = b (k k^T) * D`` and ``Aqk = (q k^T) * D`` with
+    ``D[r, i] = exp(G_r - G_i)`` — two MXU products under one ``[C, C]``
+    mask of differences that are at most 0 where it is read."""
+    C = qf.shape[0]
+    ri = lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    ci = lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    D = jnp.exp(jnp.minimum(G - _lane(G), 0.0))
+    A = jnp.where(ci < ri, _mm(kbf, kf, _NT, mxu) * D, 0.0)
+    Aqk = jnp.where(ci <= ri, _mm(qf, kf, _NT, mxu) * D, 0.0)
+    return A, Aqk
+
+
+def _like(x, G):
+    """``x [.., dk]`` summed over the channels where the decay ``G`` is one
+    scalar a head (its cotangent is then one a row)."""
+    return x if G.shape[1] == x.shape[1] else jnp.sum(x, axis=1, keepdims=True)
+
+
 def _pair(qf, kf, kbf, G, mxu, on_tpu=False):
     """The chunk's pair matrices ``(A, Aqk) [C, C]`` (module docstring) from
-    float32 ``q, k, b k`` and ``G``; products take ``mxu`` operands."""
+    float32 ``q, k, b k`` and ``G``; products take ``mxu`` operands. ``G
+    [C, 1]`` — one decay a head — takes the scalar form."""
+    if G.shape[1] == 1:
+        return _pair_scalar(qf, kf, kbf, G, mxu)
     C = qf.shape[0]
     f32 = jnp.float32
     row = lax.broadcasted_iota(jnp.int32, (C, 1), 0)
@@ -265,10 +317,11 @@ def _chunk_bwd(q, k, kb, vb, G, St, X, do, dSt, on_tpu=False):
     dKG = -mm(dR, St, _NN)
     dkdec = mm(U, dSt, _NN)
     dq, dk, dkb, dG = pull((-mm(dR, U, _NT), mm(do, U, _NT)))
-    gk = dkdec * kdec
-    dg_end = jnp.sum(gk, axis=0, keepdims=True) + e_end * jnp.sum(
-        dSt * St, axis=0, keepdims=True)
-    dG = dG + dqe * qe + dKG * KG - gk + jnp.where(row == C - 1, dg_end, 0.0)
+    gk = _like(dkdec * kdec, G)
+    dg_end = jnp.sum(gk, axis=0, keepdims=True) + e_end * _like(jnp.sum(
+        dSt * St, axis=0, keepdims=True), G)
+    dG = dG + _like(dqe * qe, G) + _like(dKG * KG, G) - gk + jnp.where(
+        row == C - 1, dg_end, 0.0)
     dSt = dSt * e_end + mm(do, qe, _TN) - mm(dR, KG, _TN)
     return dq + dqe * eG, dk + dkdec * dec, dkb + dKG * eG, dR, dG, dSt
 
@@ -411,6 +464,156 @@ _kda_kernels.defvjp(_kda_kernels_fwd, _kda_kernels_bwd)
 
 
 # ---------------------------------------------------------------------------
+# the scalar-decay route (Gated DeltaNet): the same chunk, other operands
+# ---------------------------------------------------------------------------
+
+def _gdn_operands(q_ref, k_ref, v_ref, b_ref, g_ref):
+    """A chunk's operands as ``_chunk`` takes them, from what the scalar
+    route keeps in HBM: ``q, k`` of the KEY head, ``v`` of the value head,
+    ``beta`` and the running log-decay ``G`` as ``[1, C]`` rows (float32).
+    ``b k`` and ``b v`` are formed here, rounded as the XLA form rounds
+    them."""
+    f32 = jnp.float32
+    q, k, v = q_ref[...], k_ref[...], v_ref[...]
+    b, G = _col(b_ref[...]), _col(g_ref[...])
+    kb = (b * k.astype(f32)).astype(k.dtype)
+    vb = (b * v.astype(f32)).astype(v.dtype)
+    return q, k, v, b, kb, vb, G
+
+
+def _gdn_fwd_kernel(q_ref, k_ref, v_ref, b_ref, g_ref, o_ref, h_ref, x_ref,
+                    st_ref):
+    @pl.when(pl.program_id(1) == 0)
+    def _zero():
+        st_ref[...] = jnp.zeros_like(st_ref)
+
+    h_ref[...] = st_ref[...]
+    q, k, _, _, kb, vb, G = _gdn_operands(q_ref, k_ref, v_ref, b_ref, g_ref)
+    o, st, x = _chunk_keeping_solve(q, k, kb, vb, G, st_ref[...])
+    o_ref[...] = o.astype(o_ref.dtype)
+    x_ref[...] = x
+    st_ref[...] = st
+
+
+def _gdn_bwd_kernel(q_ref, k_ref, v_ref, b_ref, g_ref, h_ref, x_ref, do_ref,
+                    dq_ref, dk_ref, dv_ref, db_ref, dg_ref, dst_ref):
+    @pl.when(pl.program_id(1) == 0)
+    def _zero():
+        dst_ref[...] = jnp.zeros_like(dst_ref)
+
+    f32 = jnp.float32
+    q, k, v, b, kb, vb, G = _gdn_operands(q_ref, k_ref, v_ref, b_ref, g_ref)
+    dq, dk, dkb, dvb, dg, dst = _chunk_bwd(
+        q, k, kb, vb, G, h_ref[...], x_ref[...], do_ref[...].astype(f32),
+        dst_ref[...])
+    dq_ref[...] = dq.astype(dq_ref.dtype)
+    dk_ref[...] = (dk + b * dkb).astype(dk_ref.dtype)
+    dv_ref[...] = (b * dvb).astype(dv_ref.dtype)
+    db_ref[...] = _lane(
+        jnp.sum(dkb * k.astype(f32), axis=1, keepdims=True)
+        + jnp.sum(dvb * v.astype(f32), axis=1, keepdims=True))
+    dg_ref[...] = _lane(dg)
+    dst_ref[...] = dst
+
+
+def _gdn_specs(q, v, index):
+    """``(qk, vo, row)``: the block of a KEY head's chunk under value head
+    ``h`` (``h // R``: value heads a key head, no repeated copy in HBM), of a
+    value head's chunk, and of a ``[1, C]`` row of scalars."""
+    (_, _, C, dk), dv = q.shape, v.shape[-1]
+    R = v.shape[0] // q.shape[0]
+
+    def shared(h, n):
+        h, n, *rest = index(h, n)
+        return (h // R, n, *rest)
+
+    return (pl.BlockSpec((None, None, C, dk), shared),
+            pl.BlockSpec((None, None, C, dv), index),
+            pl.BlockSpec((None, None, 1, C), index))
+
+
+@functools.partial(jax.jit, static_argnums=(5,))
+def _gdn_fwd_call(q, k, v, beta, G, interpret):
+    """``_kda_fwd_call`` on the scalar route: ``q, k [B Hk, N, C, dk]``, ``v
+    [B Hv, N, C, dv]``, ``beta`` and the running log-decay ``G [B Hv, N, 1,
+    C]`` (float32)."""
+    BH, N, C, dv = v.shape
+    dk = q.shape[-1]
+    at = lambda h, n: (h, n, 0, 0)
+    qk, vo, row = _gdn_specs(q, v, at)
+    return pl.pallas_call(
+        _gdn_fwd_kernel,
+        name=KERNEL_NAMES["gdn_fwd"],
+        out_shape=(jax.ShapeDtypeStruct((BH, N, C, dv), q.dtype),
+                   jax.ShapeDtypeStruct((BH, N, dv, dk), jnp.float32),
+                   jax.ShapeDtypeStruct((BH, N, C, C), jnp.float32)),
+        grid=(BH, N),
+        in_specs=[qk, qk, vo, row, row],
+        out_specs=(vo, pl.BlockSpec((None, None, dv, dk), at),
+                   pl.BlockSpec((None, None, C, C), at)),
+        scratch_shapes=[pltpu.VMEM((dv, dk), jnp.float32)],
+        compiler_params=_params(),
+        interpret=interpret,
+    )(q, k, v, beta, G)
+
+
+@functools.partial(jax.jit, static_argnums=(8,))
+def _gdn_bwd_call(q, k, v, beta, G, h, X, do, interpret):
+    """The cotangents of ``q, k`` (a VALUE head each: the caller sums the
+    heads that share a key head), ``v, beta, G``, chunks last to first."""
+    BH, N, C, dv = v.shape
+    dk = q.shape[-1]
+    back = lambda h, n: (h, N - 1 - n, 0, 0)
+    qk, vo, row = _gdn_specs(q, v, back)
+    per_head = pl.BlockSpec((None, None, C, dk), back)
+    like = lambda t: jax.ShapeDtypeStruct(t.shape, t.dtype)
+    dqk = jax.ShapeDtypeStruct((BH, N, C, dk), q.dtype)
+    return pl.pallas_call(
+        _gdn_bwd_kernel,
+        name=KERNEL_NAMES["gdn_bwd"],
+        out_shape=(dqk, dqk, like(v), like(beta), like(G)),
+        grid=(BH, N),
+        in_specs=[qk, qk, vo, row, row,
+                  pl.BlockSpec((None, None, dv, dk), back),
+                  pl.BlockSpec((None, None, C, C), back), vo],
+        out_specs=(per_head, per_head, vo, row, row),
+        scratch_shapes=[pltpu.VMEM((dv, dk), jnp.float32)],
+        compiler_params=_params(),
+        interpret=interpret,
+    )(q, k, v, beta, G, h, X, do)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _gdn_kernels(q, k, v, beta, G, interpret):
+    return _gdn_fwd_call(q, k, v, beta, G, interpret)[0]
+
+
+def _gdn_kernels_fwd(q, k, v, beta, G, interpret):
+    BH, N, C, dv = v.shape
+    _note_plans(("gdn_fwd",), BH, N * C, q.shape[-1], dv)
+    o, h, X = _gdn_fwd_call(q, k, v, beta, G, interpret)
+    o, h, X = keep(o, KDA_OUT), keep(h, KDA_STATE), keep(X, KDA_SOLVE)
+    return o, (q, k, v, beta, G, h, X)
+
+
+def _gdn_kernels_bwd(interpret, res, do):
+    q, v = res[0], res[2]
+    BH, N, C, dv = v.shape
+    _note_plans(("gdn_bwd",), BH, N * C, q.shape[-1], dv)
+    dq, dk, dv_, db, dG = _gdn_bwd_call(*res, do, interpret)
+    R = BH // q.shape[0]
+
+    def shared(t):  # the value heads of a key head add up, in float32
+        t = t.astype(jnp.float32).reshape(q.shape[0], R, *t.shape[1:])
+        return t.sum(axis=1).astype(q.dtype)
+
+    return shared(dq), shared(dk), dv_, db, dG
+
+
+_gdn_kernels.defvjp(_gdn_kernels_fwd, _gdn_kernels_bwd)
+
+
+# ---------------------------------------------------------------------------
 # the differentiable op
 # ---------------------------------------------------------------------------
 
@@ -422,6 +625,17 @@ def _kernel_route() -> bool:
 
     mesh = trace_mesh()
     return trace_is_tpu() and (mesh is None or mesh.devices.size == 1)
+
+
+def _chunks(t, chunk: int = CHUNK):
+    """``t [B, H, S, d]`` as ``[B H, N, C, d]``: whole chunks, the padding
+    zeros (positions that leave the state alone)."""
+    S = t.shape[2]
+    N = -(-S // chunk)
+    t = t.reshape(-1, S, t.shape[-1])
+    if N * chunk != S:
+        t = jnp.pad(t, ((0, 0), (0, N * chunk - S), (0, 0)))
+    return t.reshape(t.shape[0], N, chunk, t.shape[-1])
 
 
 def kda_attention(q, k, v, g, beta, interpret: Optional[bool] = None):
@@ -446,16 +660,51 @@ def kda_attention(q, k, v, g, beta, interpret: Optional[bool] = None):
     kb = (b * k.astype(jnp.float32)).astype(k.dtype)
     vb = (b * v.astype(jnp.float32)).astype(v.dtype)
 
-    def chunks(t):  # [B, H, S, d] -> [BH, N, C, d]; padding is zeros
-        t = t.reshape(B * H, S, t.shape[-1])
-        if N * C != S:
-            t = jnp.pad(t, ((0, 0), (0, N * C - S), (0, 0)))
-        return t.reshape(B * H, N, C, t.shape[-1])
-
-    G = jnp.cumsum(chunks(g.astype(jnp.float32)), axis=2)
-    args = (chunks(q), chunks(k), chunks(kb), chunks(vb), G)
+    G = jnp.cumsum(_chunks(g.astype(jnp.float32)), axis=2)
+    args = (_chunks(q), _chunks(k), _chunks(kb), _chunks(vb), G)
     if interpret == "xla" or (interpret is None and not _kernel_route()):
         o = _scan_chunks(*args)
     else:
         o = _kda_kernels(*args, bool(interpret))
     return o.reshape(B * H, N * C, dv)[:, :S].reshape(B, H, S, dv)
+
+
+def gdn_attention(q, k, v, g, beta, interpret: Optional[bool] = None):
+    """``kda_attention`` under ONE scalar decay a head and grouped heads
+    (Gated DeltaNet, Qwen3-Next's linear mixer): ``q, k [B, Hk, S, dk]``,
+    ``v [B, Hv, S, dv]`` with ``Hv`` a multiple of ``Hk`` — value head ``j``
+    reads key head ``j // (Hv / Hk)`` —, the log-decay ``g [B, Hv, S]`` (at
+    most 0, float32) and ``beta [B, Hv, S]``. The same chunked mathematics
+    (module docstring) with ``G [C, 1]``: the pair matrices are two MXU
+    products under one mask of decay differences (``_pair_scalar``), the
+    solve, the application, the backward around ``X`` and the kept
+    residuals are ``kda_attention``'s own. Kernels ``harmony_gdn_fwd`` /
+    ``harmony_gdn_bwd``: q and k are read at their key head (no repeated
+    copy in HBM), ``g`` and ``beta`` travel ``[S]`` a head, and ``dq``,
+    ``dk`` are summed over the value heads that share them. ``interpret``
+    as ``kda_attention``'s."""
+    B, Hk, S, dk = q.shape
+    Hv, dv = v.shape[1], v.shape[-1]
+    if k.shape != q.shape or v.shape[:3:2] != (B, S) or Hv % Hk \
+            or g.shape != (B, Hv, S) or beta.shape != (B, Hv, S):
+        raise ValueError(f"gdn_attention: q {q.shape}, k {k.shape}, v "
+                         f"{v.shape}, g {g.shape}, beta {beta.shape}")
+    C = CHUNK
+    N = -(-S // C)
+
+    chunks = _chunks
+    f32 = jnp.float32
+    G = jnp.cumsum(chunks(g.astype(f32)[..., None]), axis=2)   # [BHv, N, C, 1]
+    b = chunks(beta.astype(f32)[..., None])
+    if interpret == "xla" or (interpret is None and not _kernel_route()):
+        R = Hv // Hk
+        q, k = (chunks(jnp.repeat(t, R, axis=1)) for t in (q, k))
+        v = chunks(v)
+        kb = (b * k.astype(f32)).astype(k.dtype)
+        vb = (b * v.astype(f32)).astype(v.dtype)
+        o = _scan_chunks(q, k, kb, vb, G)
+    else:
+        rows = lambda t: t.reshape(B * Hv, N, 1, C)
+        o = _gdn_kernels(chunks(q), chunks(k), chunks(v), rows(b), rows(G),
+                         bool(interpret))
+    return o.reshape(B * Hv, N * C, dv)[:, :S].reshape(B, Hv, S, dv)

@@ -134,6 +134,9 @@ _CELL_CALLS = {
     # two stacked streams cannot be one tile: parts of 2,048 rows
     "sdar-30b-a3b": ((1, 32, 4, 8192, 128, 128, None, 4),
                      (2048, 512, 512), 36.0),
+    # 256-wide heads, eight query heads a K/V head: parts of 2,048 rows
+    "qwen3-next-80b-a3b": ((1, 16, 2, 16384, 256, 256, None, None),
+                           (2048, 512, 512), 79.0),
 }
 
 
@@ -309,6 +312,64 @@ def test_rotary_kernel_compiles_at_each_cells_shape(one_chip, call):
     ).compile().as_text()
     assert text.count("custom_call_target=\"tpu_custom_call\"") == 2
     assert R.KERNEL_NAME in text
+
+
+@pytest.mark.parametrize("heads", [16, 2], ids=["qwen3-next-q", "qwen3-next-k"])
+def test_rotary_kernel_compiles_at_256_wide_heads_a_quarter_turned(one_chip,
+                                                                   heads):
+    """Qwen3-Next's turn (PR 61): heads of 256 columns — two lane tiles —
+    of which the first 64 turn, the norm a head (the ``1 + w`` weight is
+    handed over as one vector) inside the kernel, q's 16 heads and k's 2 by
+    rows at 16,384 positions, forward and backward."""
+    from harmony_tpu.ops import rotary as R
+
+    s, hd, turned = 16384, 256, 64
+    assert R.plan(s, hd, jnp.bfloat16, heads) is not None
+    x = jax.ShapeDtypeStruct((1, s, heads * hd), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(x, w, offset):
+        tab, shifts = R.tables(s, hd, 1e7, offset, turned)
+        y = R.turn(x, tab, shifts, heads=heads, norm=(1.0 + w, 1e-6))
+        return (y.astype(jnp.float32) ** 2).sum()
+
+    text = jax.jit(jax.grad(loss, (0, 1))).lower(
+        x, jax.ShapeDtypeStruct((hd,), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    ).compile().as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 2
+    assert R.KERNEL_NAME in text
+
+
+def test_scalar_decay_delta_rule_kernels_compile_at_the_cells_shape(one_chip):
+    """``harmony_gdn_fwd`` / ``harmony_gdn_bwd`` (ops/kda.py, PR 61) at
+    ``qwen3-next-80b-a3b.solo``'s call: 32 value heads over 16 key heads of
+    128, 16,384 positions in chunks of 64, ``g`` and ``beta`` a scalar a
+    position — the ``[1, C]`` rows, their turn to columns and back, and the
+    ``h // 2`` index maps through Mosaic for a v5e; ONE forward and ONE
+    backward kernel, and what the forward keeps is 1 / 64 of a state a
+    position."""
+    from harmony_tpu.ops import kda as K
+
+    s, hk, hv, d = 16384, 16, 32, 128
+    sd = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                   sharding=one_chip)
+
+    def loss(q, k, v, g, beta):
+        o = K.gdn_attention(q, k, v, g, beta, interpret=False)
+        return (o.astype(jnp.float32) ** 2).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        sd((1, hk, s, d), jnp.bfloat16), sd((1, hk, s, d), jnp.bfloat16),
+        sd((1, hv, s, d), jnp.bfloat16), sd((1, hv, s), jnp.float32),
+        sd((1, hv, s), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 2
+    for name in ("harmony_gdn_fwd", "harmony_gdn_bwd"):
+        assert name in text, name
+    assert "harmony_kda" not in text
+    states = hv * (s // K.CHUNK) * (d * d + K.CHUNK * K.CHUNK) * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * states
 
 
 @pytest.mark.parametrize("tokens,cfg,checkpoint", [

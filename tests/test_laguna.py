@@ -66,14 +66,16 @@ def test_the_cell_and_its_metrics_are_in_the_benchmark(monkeypatch):  # noqa: F8
     """``perf/tests/test_laguna.py``'s check holds ``hetero_flash_roofline_
     share`` to Laguna's cell ALONE, and a PR may not edit a file the benchmark
     has. The reader was written for later configurations to join ("the next
-    configuration brings a work file, not a reader") and PR 57 appended one:
-    the check runs on the benchmark with the cells appended since taken off
-    that one list (Laguna's stays first)."""
+    configuration brings a work file, not a reader") and PR 57 appended one;
+    PR 61 appended a cell to ``attn_gate_time_share``'s list too (its gate
+    lies under the same scope): the check runs on the benchmark with the
+    cells appended since taken off those two lists (Laguna's stays first)."""
     import copy
 
     bench = copy.deepcopy(_perf.BENCH)
     for m in bench["per_layer"]:
-        if m["name"] == "hetero_flash_roofline_share":
+        if m["name"] in ("hetero_flash_roofline_share",
+                         "attn_gate_time_share"):
             assert m["workloads"][0] == _perf.CELL
             m["workloads"] = m["workloads"][:1]
     monkeypatch.setattr(_perf, "BENCH", bench)

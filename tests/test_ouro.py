@@ -57,6 +57,26 @@ globals().update({name: obj for name, obj in vars(_perf).items()
                   and name != "test_rehearsal_runs_to_a_correct_line"})
 
 
+def test_the_cell_and_its_metrics_are_in_the_benchmark(monkeypatch):  # noqa: F811
+    """``perf/tests/test_ouro.py``'s check counts THIRTEEN cells and holds its
+    own the last of the rate's list, and a PR may not edit a file the
+    benchmark has; a later ``model_config`` PR appends a cell (PR 61 did). The
+    check runs on the benchmark as it stood when this cell was the newest:
+    the cells appended since taken off every list."""
+    import copy
+
+    bench = copy.deepcopy(_perf.BENCH)
+    names = [w["name"] for w in bench["workloads"]]
+    later = set(names[names.index(_perf.CELL) + 1:])
+    bench["workloads"] = [w for w in bench["workloads"]
+                          if w["name"] not in later]
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if "workloads" in m:
+            m["workloads"] = [w for w in m["workloads"] if w not in later]
+    monkeypatch.setattr(_perf, "BENCH", bench)
+    _perf.test_the_cell_and_its_metrics_are_in_the_benchmark()
+
+
 def _config(app):
     return TransformerConfig(**{k: v for k, v in app.items() if k in FIELDS})
 

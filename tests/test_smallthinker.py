@@ -554,6 +554,15 @@ PARENT_PROGRAMS = {
     # again: ``turn`` without ``norm`` traces the parent's kernel
     "sdar-30b-a3b": ("b3b0c2d6dad53fa7", "8fa884fbbbb6b95d"),
     "sdar-30b-a3b+rotary": ("b10fe6a45f72cf12", "c6770d511f1b214a"),
+    # Qwen3-Next's own preset, RECORDED BY PR 61, the PR that added it (three
+    # Gated DeltaNet blocks on the scalar-decay route of ops/kda.py, a gated
+    # softmax block with a norm a head and a quarter of it turned, 1 + w
+    # norms, a gated shared expert); every pair above is the parent's (commit
+    # 6862df0) under the new fields' defaults, none recorded again — Kimi
+    # Linear's two above all: ``_pair`` takes its scalar form only where the
+    # decay is ``[C, 1]``, and ``_chunk_bwd``'s sums over the channels are
+    # the identity where it is not
+    "qwen3-next-80b-a3b": ("ed150b90b8b57e9e", "819c84d1ec54b36e"),
 }
 CHUNKED = {"moe_experts": 64, "moe_top_k": 4, "moe_experts_held": 8}
 READOUT = {"d_model": 128, "vocab_size": 8192}
