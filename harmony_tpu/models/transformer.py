@@ -1153,10 +1153,33 @@ def _l2norm(t):
 
 def _causal_conv(t, taps):
     """The depthwise causal convolution of ``t [B, S, c]`` by ``taps [K,
-    c]``, in float32: ``y_t = sum_j taps[j] t_{t - (K-1) + j}``."""
+    c]``, in float32: ``y_t = sum_j taps[j] t_{t - (K-1) + j}``. Who calls
+    it since PR 62: ``_ssd_mixer`` (a bias, float32 out, ``B | C`` not by
+    heads) and the CCA block's two convolutions, always; the delta-rule
+    blocks only where ``ops/conv_heads.py``'s kernel
+    declines — off the TPU, heads not 128 wide."""
     K, S = taps.shape[0], t.shape[1]
     tp = jnp.pad(t.astype(jnp.float32), ((0, 0), (K - 1, 0), (0, 0)))
     return sum(tp[:, j:j + S] * taps[j] for j in range(K))
+
+
+def _conv_to_heads(t, taps, sections, dh):
+    """A delta-rule block's operands from its projection ``t [B, S, C]`` by
+    ``ops.conv_heads``'s kernel — the first ``taps.shape[1]`` columns
+    through the depthwise causal convolution and a SiLU, each of
+    ``sections`` (``(kind, heads)`` in column order; ``l2``: ``_l2norm`` a
+    head, ``l2_scaled``: times ``dh^-1/2`` too) as ``[B, heads, S, dh]`` in
+    t's dtype, ONE pass over the rows as they lie — or None where the
+    caller keeps its own lines: where the block's scan does not take its
+    kernels either (off the TPU, a mesh of several chips), and where the
+    kernel's plan declines the shape."""
+    from harmony_tpu.ops import conv_heads
+    from harmony_tpu.ops.kda import _kernel_route
+
+    if not _kernel_route() or conv_heads.plan(
+            t.shape[1], dh, t.dtype, sections, taps.shape[0]) is None:
+        return None
+    return conv_heads.conv_heads(t, taps, sections, KDA_L2_EPS)
 
 
 def _remat(f, kept: Dict[str, list]):
@@ -1421,17 +1444,24 @@ class TransformerLM:
         w = lambda name: p[name].astype(dt)
         heads = lambda t: t.reshape(B, S, H, dh).transpose(0, 2, 1, 3)
 
-        def conv(t, taps):  # no bias
-            return heads(jax.nn.silu(_causal_conv(t, taps)))
-
         def proj(name):
             with step_scope("kda.proj"):
                 return xn @ w(name)
 
+        def conv(name, taps, kind):  # no bias; ONE section a projection
+            t = proj(name)
+            served = _conv_to_heads(t, taps, ((kind, H),), dh)
+            if served is not None:
+                return served[0]
+            t = heads(jax.nn.silu(_causal_conv(t, taps)))
+            if kind != "plain":
+                t = _l2norm(t)
+            return t * dh ** -0.5 if kind == "l2_scaled" else t
+
         with step_scope("kda.conv"):
-            q = _l2norm(conv(proj("wq"), p["conv_q"])) * dh ** -0.5
-            k = _l2norm(conv(proj("wk"), p["conv_k"]))
-            v = conv(proj("wv"), p["conv_v"])
+            q = conv("wq", p["conv_q"], "l2_scaled")
+            k = conv("wk", p["conv_k"], "l2")
+            v = conv("wv", p["conv_v"], "plain")
         with step_scope("kda.gate"):
             f = heads(((xn @ w("wf_a")) @ w("wf_b")).astype(f32)
                       + p["dt_bias"])
@@ -1476,12 +1506,18 @@ class TransformerLM:
         with step_scope("kda.proj"):
             qkvz = xn @ p["w_qkvz"].astype(dt)
             ba = jnp.einsum("bsd,hd->bhs", xn, p["w_ba"].astype(dt))
-        with step_scope("kda.conv"):
-            q, k, v = jnp.split(
-                jax.nn.silu(_causal_conv(qkvz[..., :conv], p["conv"])),
-                (key, 2 * key), axis=-1)
-            q, k = _l2norm(heads(q)) * dh ** -0.5, _l2norm(heads(k))
-            v = heads(v)
+        with step_scope("kda.conv"):  # z's columns stay where they are
+            served = _conv_to_heads(qkvz, p["conv"], (
+                ("l2_scaled", key // dh), ("l2", key // dh),
+                ("plain", value // dh)), dh)
+            if served is not None:
+                q, k, v = served
+            else:
+                q, k, v = jnp.split(
+                    jax.nn.silu(_causal_conv(qkvz[..., :conv], p["conv"])),
+                    (key, 2 * key), axis=-1)
+                q, k = _l2norm(heads(q)) * dh ** -0.5, _l2norm(heads(k))
+                v = heads(v)
         with step_scope("kda.gate"):
             beta = jax.nn.sigmoid(ba[:, :Hv].astype(f32))
             g = -jnp.exp(p["a_log"])[None, :, None] * jax.nn.softplus(

@@ -341,6 +341,44 @@ def test_rotary_kernel_compiles_at_256_wide_heads_a_quarter_turned(one_chip,
     assert R.KERNEL_NAME in text
 
 
+#: the delta-rule cells' hand-over (PR 62): positions, the operand's
+#: columns, the sections of a call
+_CONV_CALLS = {
+    "qwen3-next-80b-a3b-qkvz": (16384, 12288, (
+        ("l2_scaled", 16), ("l2", 16), ("plain", 32))),
+    "kimi-linear-48b-a3b-q": (8192, 1024, (("l2_scaled", 8),)),
+    "kimi-linear-48b-a3b-k": (8192, 1024, (("l2", 8),)),
+    "kimi-linear-48b-a3b-v": (8192, 1024, (("plain", 8),)),
+}
+
+
+@pytest.mark.parametrize("call", sorted(_CONV_CALLS))
+def test_conv_heads_kernel_compiles_at_each_cells_shape(one_chip, call):
+    """``harmony_conv_heads`` forward and backward under its own plan: the
+    taps as sublane-offset reads of a float32 scratch, the 16-row views
+    before and after a tile, a row tile of 1,024 x 4 heads with both
+    scratches inside the scoped VMEM, ``dtaps`` a ``[K, 512]`` partial a
+    step — a kernel a section, each way."""
+    from harmony_tpu.ops import conv_heads as C
+
+    s, columns, sections = _CONV_CALLS[call]
+    conv = 128 * sum(h for _, h in sections)
+    assert C.plan(s, 128, jnp.bfloat16, sections, 4) == (1024, 4)
+
+    def loss(x, taps):
+        return sum((y.astype(jnp.float32) ** 2).sum()
+                   for y in C.conv_heads(x, taps, sections, 1e-6))
+
+    text = jax.jit(jax.grad(loss, (0, 1))).lower(
+        jax.ShapeDtypeStruct((1, s, columns), jnp.bfloat16,
+                             sharding=one_chip),
+        jax.ShapeDtypeStruct((4, conv), jnp.float32, sharding=one_chip)
+    ).compile().as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 2 * len(
+        sections)
+    assert C.KERNEL_NAME in text
+
+
 def test_scalar_decay_delta_rule_kernels_compile_at_the_cells_shape(one_chip):
     """``harmony_gdn_fwd`` / ``harmony_gdn_bwd`` (ops/kda.py, PR 61) at
     ``qwen3-next-80b-a3b.solo``'s call: 32 value heads over 16 key heads of

@@ -271,6 +271,56 @@ def test_kernel_plans_reach_status_with_both_widths():
         assert (r["d"], r["dv"], r["grid_steps"]) == (128, 128, 1024)
 
 
+def _kernel_calls(jaxpr, out=None):
+    """``{kernel name: calls}`` of every ``pallas_call`` under ``jaxpr``."""
+    out = {} if out is None else out
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            name = eqn.params["name"]
+            out[name] = out.get(name, 0) + 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _kernel_calls(sub, out)
+    return out
+
+
+def test_the_step_holds_each_kernel_once_a_block(monkeypatch):
+    """Traced for a TPU under ``remat`` at the published head width: the
+    channel route's forward and backward once a KDA block (what the forward
+    keeps is named, so ``remat`` does not run it again), and the by-rows
+    kernel that hands them q, k and v — three projections, a call each,
+    forward, again under ``remat``, backward (PR 62); STATUS
+    ``kernel_plans`` names all three."""
+    from harmony_tpu.runtime import progcache
+    from harmony_tpu.tracing import trace_span
+    from harmony_tpu.utils import platform
+
+    monkeypatch.setattr(platform, "trace_is_tpu", lambda: True)
+    app = {**APP, "d_model": 256, "linear_heads": 8, "linear_head_dim": 128,
+           "max_seq": 2048, "moe_experts_held": 4, "dtype": jnp.bfloat16,
+           "remat": True}
+    lm = TransformerLM(TransformerConfig(**app))
+    params = jax.eval_shape(lambda: lm.init(jax.random.PRNGKey(0)))
+    toks = jax.ShapeDtypeStruct((1, 2049), jnp.int32)
+    with trace_span("job.build_step", job_id="plan-kimi-linear"):
+        traced = jax.jit(jax.grad(lm.loss)).trace(params, toks)
+    calls = _kernel_calls(traced.jaxpr.jaxpr)
+    blocks = len(APP["linear_layers"])
+    assert calls["harmony_kda_fwd"] == calls["harmony_kda_bwd"] == blocks
+    assert calls["harmony_conv_heads"] == 3 * 3 * blocks
+    assert "harmony_gdn_fwd" not in calls
+    rows = {r["kernel"]: r for r in
+            progcache.kernel_plans()["plan-kimi-linear"]}
+    assert {"harmony_kda_fwd", "harmony_kda_bwd",
+            "harmony_conv_heads"} <= set(rows)
+    # the three calls tile alike and share the row: the last one's section
+    conv = rows["harmony_conv_heads"]
+    assert (conv["block_q"], conv["sub"], conv["grid_steps"],
+            conv["sections"]) == (1024, 4, 4, "plain:8")
+    text = traced.lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
+    for scope in ("kda.proj", "kda.conv", "kda.gate", "kda.scan", "kda.out"):
+        assert scope in text, scope
+
+
 def test_the_kernels_lower_for_a_tpu():
     """The Pallas TPU front end takes both kernel bodies at the cell's
     widths (the backward's hand-derived around the forward's solve, with

@@ -511,8 +511,9 @@ def _kernel_calls(jaxpr, out=None):
 def test_the_step_holds_each_kernel_once_a_block_and_one_traced_body_a_kind(
         monkeypatch):
     """Traced for a TPU under ``remat`` at the published head widths: the
-    scalar route's forward and backward once a delta-rule block, flash's once
-    in the softmax block, the rotary with its fused head norm on q and k; the
+    scalar route's forward and backward once a delta-rule block, the by-rows
+    kernel that hands them q, k and v a section a call, flash's once in the
+    softmax block, the rotary with its fused head norm on q and k; the
     block's Python body traced once a KIND of block; ``kernel_plans`` and
     ``remat_saved`` name the kernels and what they keep; the lowered step's
     locations carry the accepted leaf scopes."""
@@ -541,10 +542,18 @@ def test_the_step_holds_each_kernel_once_a_block_and_one_traced_body_a_kind(
     assert calls["harmony_flash_fwd"] == calls["harmony_flash_bwd"] == 1
     # q and k of the one softmax block: forward, again under remat, backward
     assert calls["harmony_rotary"] == 2 * 3
+    # q | k | v of a delta-rule block's ONE projection, a section a call
+    # (PR 62): forward, again under remat, backward, three blocks
+    assert calls["harmony_conv_heads"] == 3 * 3 * 3
     assert "harmony_kda_fwd" not in calls
     rows = {r["kernel"]: r for r in progcache.kernel_plans()["plan-qwen3-next"]}
     assert {"harmony_gdn_fwd", "harmony_gdn_bwd", "harmony_flash_fwd",
-            "harmony_flash_bwd", "harmony_rotary"} <= set(rows)
+            "harmony_flash_bwd", "harmony_rotary",
+            "harmony_conv_heads"} <= set(rows)
+    assert (rows["harmony_conv_heads"]["block_q"],
+            rows["harmony_conv_heads"]["sub"],
+            rows["harmony_conv_heads"]["sections"]) == (
+        1024, 4, "l2_scaled:16,l2:16,plain:32")
     assert (rows["harmony_gdn_fwd"]["d"], rows["harmony_gdn_fwd"]["dv"],
             rows["harmony_gdn_fwd"]["block_k"]) == (128, 128, 32)
     kept = {r["name"]: r for r in progcache.remat_saved()["plan-qwen3-next"]}

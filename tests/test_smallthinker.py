@@ -563,11 +563,26 @@ PARENT_PROGRAMS = {
     # decay is ``[C, 1]``, and ``_chunk_bwd``'s sums over the channels are
     # the identity where it is not
     "qwen3-next-80b-a3b": ("ed150b90b8b57e9e", "819c84d1ec54b36e"),
+    # PR 62 (a delta-rule block's convolution, SiLU, l2 norms and transposes
+    # to heads as ONE by-rows kernel where its plan serves the shape,
+    # ops/conv_heads.py) meant to change NO program above and changed none:
+    # the presets' delta-rule heads are 16 wide, ``plan`` declines them and
+    # the mixers keep their own lines, equation for equation — Kimi
+    # Linear's two and Qwen3-Next's with them, every pair the parent's
+    # (commit ee4a82d). What the two cells run is pinned at the preset with
+    # the PUBLISHED head width (128), where the kernel engages — RECORDED BY
+    # PR 62, which meant to change these two; the parent's programs at the
+    # same preset, ``_causal_conv`` and the rest in XLA: d39dc12fa8834415 /
+    # 5d9f5ba01f1e1cfd and c565f9e1f4f01b23 / fc707682eead3b4e
+    "kimi-linear-48b-a3b+conv": ("714ab12e60457d2c", "afa5087dc07ad745"),
+    "qwen3-next-80b-a3b+conv": ("1311e8684a010228", "b257652905cef403"),
 }
 CHUNKED = {"moe_experts": 64, "moe_top_k": 4, "moe_experts_held": 8}
 READOUT = {"d_model": 128, "vocab_size": 8192}
 ROTARY = {"d_model": 512, "mha_head_dim": 128}
-VARIANTS = {"": {}, "chunked": CHUNKED, "readout": READOUT, "rotary": ROTARY}
+CONV = {"linear_head_dim": 128}
+VARIANTS = {"": {}, "chunked": CHUNKED, "readout": READOUT, "rotary": ROTARY,
+            "conv": CONV}
 
 
 def _renumbered(text):
