@@ -33,7 +33,8 @@ first level is a mask and five levels multiply); the other products take the
 operands' dtype into the MXU (bfloat16 on hardware) and accumulate in float32.
 
 **The kernels** (``harmony_kda_fwd``, ``harmony_kda_bwd``): the grid walks
-(heads, chunks), one head's chunk a step, the chunks in order with the
+(heads, chunks), one head's chunk a step (the scalar route below: several),
+the chunks in order with the
 transposed state ``S^T [dv, dk]`` in float32 in VMEM scratch (the decay then
 multiplies along lanes).
 The forward also writes the state each chunk STARTS from (``[N, dv, dk]`` a
@@ -63,7 +64,17 @@ after the pair matrices — the solve, the application, the backward around
 ``harmony_gdn_bwd``) walk VALUE heads and read ``q, k`` at their key head
 (value head ``j`` on key head ``j // (Hv / Hk)``, by the index map: no repeated
 copy in HBM), take ``g`` and ``beta`` as ``[1, C]`` rows a chunk and form ``b
-k``, ``b v`` inside.
+k``, ``b v`` inside. A grid step of theirs holds ``T`` CONSECUTIVE chunks of
+one value head — ``T`` the largest of 8, 4, 2, 1 that divides the head's
+chunks, from the shape alone (``gdn_plan``) — and does first what does not
+read the state: ``A``, ``Aqk`` and ``X = (I + A)^-1`` depend on a chunk's
+own ``q, k, beta, g``, so two chunks go through ``_pair_scalar`` and
+``_solve`` on the diagonal of ONE ``[128, 128]`` tile (block-diagonal in,
+block-diagonal out, exactly: half the float32 products, each at the MXU's
+own size), and only ``_apply`` walks the state, chunk after chunk. The
+backward runs ``_chunk_bwd`` for the step's chunks last to first in one
+basic block: ``dU -> dR -> dS'`` alone waits for the chunk after. The
+arrays in HBM keep their shapes, so what the forward keeps is unchanged.
 
 One predicate chooses (``_kernel_route``: the traced program runs on a
 one-chip TPU mesh), as for the flash kernels; no option and no environment
@@ -104,20 +115,41 @@ def tile_plan(bh: int, seq: int, chunk: int = CHUNK) -> Plan:
     """The kernels' grid for ``bh`` heads (x sequences) of ``seq``
     positions: one head's chunk of ``chunk`` a step (padded to whole chunks
     with positions that change nothing; ops/ssd.py walks the same grid at
-    its own chunk). Several heads a
+    its own chunk). Several HEADS a
     step buy nothing: 8 heads x 8,192 positions read 3.10 / 2.95 / 2.84 /
     2.80 ms forward at 1 / 2 / 4 / 8 heads a step (my chip run, PR 31) for
-    8 times the code."""
+    8 times the code. Several CHUNKS of one head a step do, where their
+    solves share a tile: the scalar route's ``gdn_plan``."""
     return Plan(chunk, bh * -(-seq // chunk))
 
 
-def _note_plans(kernels, bh: int, seq: int, dk: int, dv: int) -> None:
-    """Trace-time record (STATUS ``kernel_plans``): block_q = the chunk,
-    block_k = heads x sequences, sub = the sub-block. Never fails a trace."""
+#: chunks of one value head a grid step of the scalar route, largest first:
+#: ``harmony_gdn_fwd`` / ``_bwd`` alone at 32 value heads of 128 x 256 chunks
+#: read 18.73 / 10.09 ms at 1, 13.84 / 7.60 at 2, 12.83 / 6.69 at 4, 12.49 /
+#: 6.24 at 8; 16 read 12.36 forward for twice the code to trace and compile
+#: (my chip runs, PR 63)
+GDN_CHUNKS_A_STEP = (8, 4, 2, 1)
+
+
+def gdn_plan(bh: int, n: int) -> Plan:
+    """The scalar route's grid for ``bh`` value heads (x sequences) of ``n``
+    chunks: ``T`` CONSECUTIVE chunks of one head a step, ``T`` the largest
+    of ``GDN_CHUNKS_A_STEP`` that divides ``n`` (from the shape alone; 1 is
+    ``tile_plan``'s walk). A step's pair matrices and solves do not read the
+    state, so two chunks share one ``[2 C, 2 C]`` tile through ``_solve`` —
+    the MXU's own 128 rows at ``C`` = 64, half the float32 products — and
+    ``T`` chunks pay one grid step (``_gdn_fwd_kernel``)."""
+    t = next(t for t in GDN_CHUNKS_A_STEP if n % t == 0)
+    return Plan(t * CHUNK, bh * n // t)
+
+
+def _note_plans(kernels, plan: Plan, bh: int, dk: int, dv: int) -> None:
+    """Trace-time record (STATUS ``kernel_plans``): block_q = the positions
+    a grid step holds, block_k = heads x sequences, sub = the sub-block.
+    Never fails a trace."""
     try:
         from harmony_tpu.runtime.progcache import note_kernel_plan
 
-        plan = tile_plan(bh, seq)
         for kern in kernels:
             note_kernel_plan(KERNEL_NAMES[kern], plan.chunk, bh, SUB,
                              plan.grid_steps, True, d=dk, dv=dv)
@@ -234,10 +266,15 @@ def _pair(qf, kf, kbf, G, mxu, on_tpu=False):
     return A, Aqk
 
 
-def _solve(A):
+def _solve(A, rows=None):
     """``(I + A)^-1`` for a strictly lower ``A [C, C]``, in float32, block by
     block: ``X`` holds the inverses of the diagonal blocks of ``size`` rows;
-    the lower-left quarter of each block twice that size is -X22 A21 X11."""
+    the lower-left quarter of each block twice that size is -X22 A21 X11.
+    ``rows`` (static; ``C`` where None) stops the recursion at diagonal
+    blocks of that many rows: an ``A`` that is zero outside them — several
+    chunks' on one tile's diagonal — comes back as their inverses, each the
+    value it has alone (a product of block-diagonal matrices adds exact
+    zeros to each block's own sums)."""
     C = A.shape[0]
     ri = lax.broadcasted_iota(jnp.int32, (C, C), 0)
     ci = lax.broadcasted_iota(jnp.int32, (C, C), 1)
@@ -253,7 +290,7 @@ def _solve(A):
     # blocks of one row are 1: X - X M X with X = I is the mask itself
     X = (ri == ci).astype(jnp.float32) - jnp.where(low_left(1), A, 0.0)
     size = 2
-    while size < C:
+    while size < (rows or C):
         M = jnp.where(low_left(size), A, 0.0)
         X = X - _mm(_mm(X, M, _NN, jnp.float32), X, _NN, jnp.float32)
         size *= 2
@@ -446,7 +483,7 @@ def _kda_kernels(q, k, kb, vb, G, interpret):
 
 def _kda_kernels_fwd(q, k, kb, vb, G, interpret):
     BH, N, C, dk = q.shape
-    _note_plans(("fwd",), BH, N * C, dk, vb.shape[-1])
+    _note_plans(("fwd",), tile_plan(BH, N * C), BH, dk, vb.shape[-1])
     o, h, X = _kda_fwd_call(q, k, kb, vb, G, interpret)
     # what a rematerialised block keeps (ops/residuals.py)
     o, h, X = keep(o, KDA_OUT), keep(h, KDA_STATE), keep(X, KDA_SOLVE)
@@ -456,7 +493,7 @@ def _kda_kernels_fwd(q, k, kb, vb, G, interpret):
 def _kda_kernels_bwd(interpret, res, do):
     q, vb = res[0], res[3]
     BH, N, C, dk = q.shape
-    _note_plans(("bwd",), BH, N * C, dk, vb.shape[-1])
+    _note_plans(("bwd",), tile_plan(BH, N * C), BH, dk, vb.shape[-1])
     return _kda_bwd_call(*res, do, interpret)
 
 
@@ -481,45 +518,88 @@ def _gdn_operands(q_ref, k_ref, v_ref, b_ref, g_ref):
     return q, k, v, b, kb, vb, G
 
 
+def _gdn_solved(chunks, mxu):
+    """``(Aqk, X)`` of each chunk of a grid step from its float32 ``(q, k,
+    b k, G)``: what a chunk computes before it meets the state. Two chunks
+    go through ``_pair_scalar`` and ``_solve`` as ONE ``[2 C, 2 C]`` tile
+    — stacked rows, the other chunk's columns masked off, the recursion
+    stopped at ``C`` — and come back as its diagonal blocks; one chunk
+    alone as ``_chunk_keeping_solve`` has it."""
+    if len(chunks) == 1:
+        A, Aqk = _pair_scalar(*chunks[0], mxu)
+        return [(Aqk, _solve(A))]
+    C = chunks[0][0].shape[0]
+    ri = lax.broadcasted_iota(jnp.int32, (2 * C, 2 * C), 0)
+    ci = lax.broadcasted_iota(jnp.int32, (2 * C, 2 * C), 1)
+    own = (ri < C) == (ci < C)
+    out = []
+    for two in zip(chunks[::2], chunks[1::2]):
+        A, Aqk = _pair_scalar(
+            *(jnp.concatenate(pair, axis=0) for pair in zip(*two)), mxu)
+        X = _solve(jnp.where(own, A, 0.0), C)
+        out += [(Aqk[:C, :C], X[:C, :C]), (Aqk[C:, C:], X[C:, C:])]
+    return out
+
+
 def _gdn_fwd_kernel(q_ref, k_ref, v_ref, b_ref, g_ref, o_ref, h_ref, x_ref,
                     st_ref):
+    """``T`` consecutive chunks of one value head (``gdn_plan``): first what
+    no chunk needs the state for — the operands, the pair matrices, the
+    solves (``_gdn_solved``) — then the walk, ``_apply`` alone, in order."""
     @pl.when(pl.program_id(1) == 0)
     def _zero():
         st_ref[...] = jnp.zeros_like(st_ref)
 
-    h_ref[...] = st_ref[...]
-    q, k, _, _, kb, vb, G = _gdn_operands(q_ref, k_ref, v_ref, b_ref, g_ref)
-    o, st, x = _chunk_keeping_solve(q, k, kb, vb, G, st_ref[...])
-    o_ref[...] = o.astype(o_ref.dtype)
-    x_ref[...] = x
+    f32 = jnp.float32
+    mxu = q_ref.dtype
+    chunks = []
+    for t in range(q_ref.shape[0]):
+        q, k, _, _, kb, vb, G = _gdn_operands(
+            *(r.at[t] for r in (q_ref, k_ref, v_ref, b_ref, g_ref)))
+        chunks.append((q.astype(f32), k.astype(f32), kb.astype(f32), G,
+                       vb.astype(f32)))
+    solved = _gdn_solved([c[:4] for c in chunks], mxu)
+    st = st_ref[...]
+    for t, ((qf, kf, kbf, G, vbf), (Aqk, X)) in enumerate(zip(chunks, solved)):
+        h_ref[t] = st
+        x_ref[t] = X
+        o, st = _apply(qf, kf, kbf, vbf, G, st, Aqk, X, mxu)
+        o_ref[t] = o.astype(o_ref.dtype)
     st_ref[...] = st
 
 
 def _gdn_bwd_kernel(q_ref, k_ref, v_ref, b_ref, g_ref, h_ref, x_ref, do_ref,
                     dq_ref, dk_ref, dv_ref, db_ref, dg_ref, dst_ref):
+    """The step's ``T`` chunks last to first, ``_chunk_bwd`` each, in ONE
+    basic block: only ``dU -> dR -> dS'`` of a chunk waits for the chunk
+    after it, the rest (``U`` from the kept ``h`` and ``X``, the pair
+    matrices' ``jax.vjp``) is the scheduler's to place under that chain."""
     @pl.when(pl.program_id(1) == 0)
     def _zero():
         dst_ref[...] = jnp.zeros_like(dst_ref)
 
     f32 = jnp.float32
-    q, k, v, b, kb, vb, G = _gdn_operands(q_ref, k_ref, v_ref, b_ref, g_ref)
-    dq, dk, dkb, dvb, dg, dst = _chunk_bwd(
-        q, k, kb, vb, G, h_ref[...], x_ref[...], do_ref[...].astype(f32),
-        dst_ref[...])
-    dq_ref[...] = dq.astype(dq_ref.dtype)
-    dk_ref[...] = (dk + b * dkb).astype(dk_ref.dtype)
-    dv_ref[...] = (b * dvb).astype(dv_ref.dtype)
-    db_ref[...] = _lane(
-        jnp.sum(dkb * k.astype(f32), axis=1, keepdims=True)
-        + jnp.sum(dvb * v.astype(f32), axis=1, keepdims=True))
-    dg_ref[...] = _lane(dg)
+    dst = dst_ref[...]
+    for t in reversed(range(q_ref.shape[0])):
+        q, k, v, b, kb, vb, G = _gdn_operands(
+            *(r.at[t] for r in (q_ref, k_ref, v_ref, b_ref, g_ref)))
+        dq, dk, dkb, dvb, dg, dst = _chunk_bwd(
+            q, k, kb, vb, G, h_ref[t], x_ref[t], do_ref[t].astype(f32), dst)
+        dq_ref[t] = dq.astype(dq_ref.dtype)
+        dk_ref[t] = (dk + b * dkb).astype(dk_ref.dtype)
+        dv_ref[t] = (b * dvb).astype(dv_ref.dtype)
+        db_ref[t] = _lane(
+            jnp.sum(dkb * k.astype(f32), axis=1, keepdims=True)
+            + jnp.sum(dvb * v.astype(f32), axis=1, keepdims=True))
+        dg_ref[t] = _lane(dg)
     dst_ref[...] = dst
 
 
-def _gdn_specs(q, v, index):
-    """``(qk, vo, row)``: the block of a KEY head's chunk under value head
-    ``h`` (``h // R``: value heads a key head, no repeated copy in HBM), of a
-    value head's chunk, and of a ``[1, C]`` row of scalars."""
+def _gdn_specs(q, v, T, index):
+    """``(qk, vo, row)``: the block of ``T`` chunks of a KEY head under value
+    head ``h`` (``h // R``: value heads a key head, no repeated copy in
+    HBM), of a value head's ``T`` chunks, and of their ``[1, C]`` rows of
+    scalars."""
     (_, _, C, dk), dv = q.shape, v.shape[-1]
     R = v.shape[0] // q.shape[0]
 
@@ -527,55 +607,57 @@ def _gdn_specs(q, v, index):
         h, n, *rest = index(h, n)
         return (h // R, n, *rest)
 
-    return (pl.BlockSpec((None, None, C, dk), shared),
-            pl.BlockSpec((None, None, C, dv), index),
-            pl.BlockSpec((None, None, 1, C), index))
+    return (pl.BlockSpec((None, T, C, dk), shared),
+            pl.BlockSpec((None, T, C, dv), index),
+            pl.BlockSpec((None, T, 1, C), index))
 
 
-@functools.partial(jax.jit, static_argnums=(5,))
-def _gdn_fwd_call(q, k, v, beta, G, interpret):
+@functools.partial(jax.jit, static_argnums=(5, 6))
+def _gdn_fwd_call(q, k, v, beta, G, T, interpret):
     """``_kda_fwd_call`` on the scalar route: ``q, k [B Hk, N, C, dk]``, ``v
     [B Hv, N, C, dv]``, ``beta`` and the running log-decay ``G [B Hv, N, 1,
-    C]`` (float32)."""
+    C]`` (float32); ``T`` chunks a grid step (``gdn_plan``: it divides
+    ``N``)."""
     BH, N, C, dv = v.shape
     dk = q.shape[-1]
     at = lambda h, n: (h, n, 0, 0)
-    qk, vo, row = _gdn_specs(q, v, at)
+    qk, vo, row = _gdn_specs(q, v, T, at)
     return pl.pallas_call(
         _gdn_fwd_kernel,
         name=KERNEL_NAMES["gdn_fwd"],
         out_shape=(jax.ShapeDtypeStruct((BH, N, C, dv), q.dtype),
                    jax.ShapeDtypeStruct((BH, N, dv, dk), jnp.float32),
                    jax.ShapeDtypeStruct((BH, N, C, C), jnp.float32)),
-        grid=(BH, N),
+        grid=(BH, N // T),
         in_specs=[qk, qk, vo, row, row],
-        out_specs=(vo, pl.BlockSpec((None, None, dv, dk), at),
-                   pl.BlockSpec((None, None, C, C), at)),
+        out_specs=(vo, pl.BlockSpec((None, T, dv, dk), at),
+                   pl.BlockSpec((None, T, C, C), at)),
         scratch_shapes=[pltpu.VMEM((dv, dk), jnp.float32)],
         compiler_params=_params(),
         interpret=interpret,
     )(q, k, v, beta, G)
 
 
-@functools.partial(jax.jit, static_argnums=(8,))
-def _gdn_bwd_call(q, k, v, beta, G, h, X, do, interpret):
+@functools.partial(jax.jit, static_argnums=(8, 9))
+def _gdn_bwd_call(q, k, v, beta, G, h, X, do, T, interpret):
     """The cotangents of ``q, k`` (a VALUE head each: the caller sums the
-    heads that share a key head), ``v, beta, G``, chunks last to first."""
+    heads that share a key head), ``v, beta, G``, the blocks of ``T`` chunks
+    last to first."""
     BH, N, C, dv = v.shape
     dk = q.shape[-1]
-    back = lambda h, n: (h, N - 1 - n, 0, 0)
-    qk, vo, row = _gdn_specs(q, v, back)
-    per_head = pl.BlockSpec((None, None, C, dk), back)
+    back = lambda h, n: (h, N // T - 1 - n, 0, 0)
+    qk, vo, row = _gdn_specs(q, v, T, back)
+    per_head = pl.BlockSpec((None, T, C, dk), back)
     like = lambda t: jax.ShapeDtypeStruct(t.shape, t.dtype)
     dqk = jax.ShapeDtypeStruct((BH, N, C, dk), q.dtype)
     return pl.pallas_call(
         _gdn_bwd_kernel,
         name=KERNEL_NAMES["gdn_bwd"],
         out_shape=(dqk, dqk, like(v), like(beta), like(G)),
-        grid=(BH, N),
+        grid=(BH, N // T),
         in_specs=[qk, qk, vo, row, row,
-                  pl.BlockSpec((None, None, dv, dk), back),
-                  pl.BlockSpec((None, None, C, C), back), vo],
+                  pl.BlockSpec((None, T, dv, dk), back),
+                  pl.BlockSpec((None, T, C, C), back), vo],
         out_specs=(per_head, per_head, vo, row, row),
         scratch_shapes=[pltpu.VMEM((dv, dk), jnp.float32)],
         compiler_params=_params(),
@@ -583,25 +665,32 @@ def _gdn_bwd_call(q, k, v, beta, G, h, X, do, interpret):
     )(q, k, v, beta, G, h, X, do)
 
 
+def _gdn_planned(kernel, q, v):
+    """``T`` of ``gdn_plan`` at the call's shape, noted under ``kernel``."""
+    BH, N, _, dv = v.shape
+    plan = gdn_plan(BH, N)
+    _note_plans((kernel,), plan, BH, q.shape[-1], dv)
+    return plan.chunk // CHUNK
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
 def _gdn_kernels(q, k, v, beta, G, interpret):
-    return _gdn_fwd_call(q, k, v, beta, G, interpret)[0]
+    T = _gdn_planned("gdn_fwd", q, v)
+    return _gdn_fwd_call(q, k, v, beta, G, T, interpret)[0]
 
 
 def _gdn_kernels_fwd(q, k, v, beta, G, interpret):
-    BH, N, C, dv = v.shape
-    _note_plans(("gdn_fwd",), BH, N * C, q.shape[-1], dv)
-    o, h, X = _gdn_fwd_call(q, k, v, beta, G, interpret)
+    T = _gdn_planned("gdn_fwd", q, v)
+    o, h, X = _gdn_fwd_call(q, k, v, beta, G, T, interpret)
     o, h, X = keep(o, KDA_OUT), keep(h, KDA_STATE), keep(X, KDA_SOLVE)
     return o, (q, k, v, beta, G, h, X)
 
 
 def _gdn_kernels_bwd(interpret, res, do):
     q, v = res[0], res[2]
-    BH, N, C, dv = v.shape
-    _note_plans(("gdn_bwd",), BH, N * C, q.shape[-1], dv)
-    dq, dk, dv_, db, dG = _gdn_bwd_call(*res, do, interpret)
-    R = BH // q.shape[0]
+    T = _gdn_planned("gdn_bwd", q, v)
+    dq, dk, dv_, db, dG = _gdn_bwd_call(*res, do, T, interpret)
+    R = v.shape[0] // q.shape[0]
 
     def shared(t):  # the value heads of a key head add up, in float32
         t = t.astype(jnp.float32).reshape(q.shape[0], R, *t.shape[1:])

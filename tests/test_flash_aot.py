@@ -382,14 +382,16 @@ def test_conv_heads_kernel_compiles_at_each_cells_shape(one_chip, call):
 def test_scalar_decay_delta_rule_kernels_compile_at_the_cells_shape(one_chip):
     """``harmony_gdn_fwd`` / ``harmony_gdn_bwd`` (ops/kda.py, PR 61) at
     ``qwen3-next-80b-a3b.solo``'s call: 32 value heads over 16 key heads of
-    128, 16,384 positions in chunks of 64, ``g`` and ``beta`` a scalar a
-    position — the ``[1, C]`` rows, their turn to columns and back, and the
-    ``h // 2`` index maps through Mosaic for a v5e; ONE forward and ONE
-    backward kernel, and what the forward keeps is 1 / 64 of a state a
-    position."""
+    128, 16,384 positions in chunks of 64, eight chunks a grid step (PR 63:
+    two chunks' solves a ``[128, 128]`` tile, its diagonal blocks sliced out
+    at a lane offset of 64), ``g`` and ``beta`` a scalar a position — the
+    ``[1, C]`` rows, their turn to columns and back, and the ``h // 2``
+    index maps through Mosaic for a v5e; ONE forward and ONE backward
+    kernel, and what the forward keeps is 1 / 64 of a state a position."""
     from harmony_tpu.ops import kda as K
 
     s, hk, hv, d = 16384, 16, 32, 128
+    assert K.gdn_plan(hv, s // K.CHUNK) == (8 * K.CHUNK, hv * s // K.CHUNK // 8)
     sd = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
                                                    sharding=one_chip)
 

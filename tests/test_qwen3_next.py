@@ -192,14 +192,22 @@ def _operands(S=150, B=1, Hk=2, Hv=4, d=16):
             jax.random.normal(ks[5], (B, Hv, S, d)))
 
 
-@pytest.mark.parametrize("route", ["xla", "kernels"])
-def test_scalar_route_equals_the_recurrence_forward_and_backward(route):
-    """``gdn_attention`` at two value heads a key head and a length that is
+# lengths that take every count of chunks a grid step ``gdn_plan`` returns:
+# 150 = 3 chunks (one a step; the last a part of one), 384 = 6 (two a
+# step), 256 = 4 (four), 500 = 8 (eight; the last a part of one)
+LENGTHS = {150: 1, 384: 2, 256: 4, 500: 8}
+
+
+@pytest.mark.parametrize("route,S", [("xla", 150)] + [
+    ("kernels", S) for S in LENGTHS])
+def test_scalar_route_equals_the_recurrence_forward_and_backward(route, S):
+    """``gdn_attention`` at two value heads a key head and lengths that are
     no whole number of chunks (150 = 2 x 64 + 22), both the XLA form and the
-    interpreted kernels, against the position-by-position recurrence: the
-    outputs and all five gradients (``dq``, ``dk`` summed over the value
-    heads that share them)."""
-    *args, w = _operands()
+    interpreted kernels at every count of chunks a grid step, against the
+    position-by-position recurrence: the outputs and all five gradients
+    (``dq``, ``dk`` summed over the value heads that share them)."""
+    *args, w = _operands(S)
+    assert K.gdn_plan(4, -(-S // K.CHUNK)).chunk == LENGTHS[S] * K.CHUNK
     mode = "xla" if route == "xla" else True
     with jax.default_matmul_precision("highest"):
         got = K.gdn_attention(*args, interpret=mode)
@@ -213,10 +221,12 @@ def test_scalar_route_equals_the_recurrence_forward_and_backward(route):
         _close(a, b)
 
 
-def test_scalar_route_equals_the_channel_route_fed_a_broadcast_decay():
+@pytest.mark.parametrize("S", sorted(LENGTHS))
+def test_scalar_route_equals_the_channel_route_fed_a_broadcast_decay(S):
     """STEP 0's route (a): ``kda_attention`` given ``g`` broadcast over the
-    channels and q, k repeated to the value heads computes the same."""
-    q, k, v, g, beta, w = _operands()
+    channels and q, k repeated to the value heads computes the same — one
+    chunk a grid step there, 1 / 2 / 4 / 8 here."""
+    q, k, v, g, beta, w = _operands(S)
     R, d = v.shape[1] // q.shape[1], q.shape[-1]
     wide = lambda g: jnp.broadcast_to(g[..., None], (*g.shape, d))
     a = lambda q, k, v, g, beta: K.kda_attention(
@@ -230,6 +240,80 @@ def test_scalar_route_equals_the_channel_route_fed_a_broadcast_decay():
                   for f in (a, b))
     for x, y in zip(gb, ga):
         _close(x, y)
+
+
+def _kernel_operands(N=8, Hk=2, Hv=4, d=16):
+    """What ``gdn_attention`` hands ``_gdn_kernels`` for ``N`` whole chunks,
+    and a cotangent of the output."""
+    q, k, v, g, beta, w = _operands(N * K.CHUNK, Hk=Hk, Hv=Hv, d=d)
+    rows = lambda t: K._chunks(t[..., None]).reshape(Hv, N, 1, K.CHUNK)
+    G = jnp.cumsum(K._chunks(g[..., None]), axis=2)
+    return (K._chunks(q), K._chunks(k), K._chunks(v), rows(beta),
+            G.reshape(Hv, N, 1, K.CHUNK)), K._chunks(w)
+
+
+@pytest.fixture(scope="module")
+def one_chunk_a_step():
+    """The parent's walk (``T`` = 1) of eight chunks: the operands, the
+    forward's ``(o, h, X)``, a cotangent and the five gradients."""
+    args, do = _kernel_operands()
+    with jax.default_matmul_precision("highest"):
+        kept = K._gdn_fwd_call(*args, 1, True)
+        grads = K._gdn_bwd_call(*args, *kept[1:], do, 1, True)
+    return args, kept, do, grads
+
+
+@pytest.mark.parametrize("T", [t for t in K.GDN_CHUNKS_A_STEP if t > 1])
+def test_every_count_of_chunks_a_step_computes_what_one_computes(
+        one_chunk_a_step, T):
+    """``T`` chunks a grid step against one: the backward — ``_chunk_bwd``
+    ``T`` times in a row, the per-chunk arithmetic untouched — bit for bit;
+    the forward — two chunks' solves on one ``[128, 128]`` tile, whose
+    products may sum in another order — to 1e-6 of the largest element, the
+    outputs, the kept states and the kept solves."""
+    args, kept, do, grads = one_chunk_a_step
+    with jax.default_matmul_precision("highest"):
+        got = K._gdn_fwd_call(*args, T, True)
+        back = K._gdn_bwd_call(*args, *kept[1:], do, T, True)
+    for a, b in zip(got, kept):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        assert float(jnp.max(jnp.abs(a - b))) <= 1e-6 * float(
+            jnp.max(jnp.abs(b)))
+    for a, b in zip(back, grads):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_two_chunks_on_one_tile_solve_as_each_alone():
+    """``_solve`` stopped at ``C`` rows, fed two chunks' strictly lower
+    ``A`` on the diagonal of one ``[2 C, 2 C]`` tile: each diagonal block is
+    that chunk's ``(I + A)^-1`` and the rest is exactly zero; without
+    ``rows`` every caller that was there gets what it got."""
+    C = K.CHUNK
+    a, b = (jnp.tril(0.3 * jax.random.normal(jax.random.PRNGKey(i), (C, C)),
+                     -1) for i in (1, 2))
+    z = jnp.zeros((C, C))
+    two = K._solve(jnp.block([[a, z], [z, b]]), C)
+    for got, alone in ((two[:C, :C], a), (two[C:, C:], b)):
+        want = K._solve(alone)
+        _close(got, want)
+        _close(want @ (jnp.eye(C) + alone), jnp.eye(C))
+    assert not two[:C, C:].any() and not two[C:, :C].any()
+    np.testing.assert_array_equal(K._solve(a, C), K._solve(a))
+
+
+@pytest.mark.parametrize("bh,n,block,steps", [
+    (32, 256, 512, 1024),   # the cell's call: 8 chunks a step
+    (4, 2, 128, 4),         # the rehearse preset: 80 positions, 2 chunks
+    (8, 16, 512, 16),       # the pinned preset: 2 x 4 heads, 1,024 positions
+    (4, 12, 256, 12),       # by 4 only
+    (4, 6, 128, 12),        # by 2 only
+    (4, 3, 64, 12),         # odd: tile_plan's walk
+])
+def test_the_plan_holds_the_most_chunks_a_step_that_divide(
+        bh, n, block, steps):
+    assert K.gdn_plan(bh, n) == (block, steps)
+    assert K.gdn_plan(bh, n).chunk // K.CHUNK in K.GDN_CHUNKS_A_STEP
+    assert K.tile_plan(bh, n * K.CHUNK) == (K.CHUNK, bh * n)
 
 
 def test_scalar_route_refuses_shapes_it_does_not_serve():
@@ -556,6 +640,10 @@ def test_the_step_holds_each_kernel_once_a_block_and_one_traced_body_a_kind(
         1024, 4, "l2_scaled:16,l2:16,plain:32")
     assert (rows["harmony_gdn_fwd"]["d"], rows["harmony_gdn_fwd"]["dv"],
             rows["harmony_gdn_fwd"]["block_k"]) == (128, 128, 32)
+    # 32 chunks a head: eight a grid step (PR 63), 512 positions
+    for name in ("harmony_gdn_fwd", "harmony_gdn_bwd"):
+        assert (rows[name]["block_q"], rows[name]["grid_steps"]) == (
+            8 * K.CHUNK, 32 * (2048 // K.CHUNK) // 8)
     kept = {r["name"]: r for r in progcache.remat_saved()["plan-qwen3-next"]}
     assert kept["kda_out"]["arrays"] == kept["kda_state"]["arrays"] == 3
     assert kept["kda_state"]["bytes"] == 3 * 32 * (2048 // 64) * 128 * 128 * 4

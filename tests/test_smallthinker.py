@@ -562,7 +562,16 @@ PARENT_PROGRAMS = {
     # Linear's two above all: ``_pair`` takes its scalar form only where the
     # decay is ``[C, 1]``, and ``_chunk_bwd``'s sums over the channels are
     # the identity where it is not
-    "qwen3-next-80b-a3b": ("ed150b90b8b57e9e", "819c84d1ec54b36e"),
+    # RECORDED AGAIN BY PR 63, which meant to change Qwen3-Next's two and no
+    # other: ``harmony_gdn_fwd`` / ``_bwd`` hold eight consecutive chunks of
+    # a value head a grid step (``ops.kda.gdn_plan``: 16 chunks a head at
+    # this preset), two chunks' pair matrices and solves on one tile, off
+    # the state's chain. The jaxprs change (the parent's, commit c97be18:
+    # ed150b90b8b57e9e here and 1311e8684a010228 at ``+conv`` below); the
+    # lowered texts do not — a kernel's body, its grid with it, is masked
+    # out of them. Every other pair is the parent's, Kimi Linear's four
+    # among them: ``_solve`` without ``rows`` traces what it traced
+    "qwen3-next-80b-a3b": ("02ea663af65eb1e3", "819c84d1ec54b36e"),
     # PR 62 (a delta-rule block's convolution, SiLU, l2 norms and transposes
     # to heads as ONE by-rows kernel where its plan serves the shape,
     # ops/conv_heads.py) meant to change NO program above and changed none:
@@ -575,7 +584,7 @@ PARENT_PROGRAMS = {
     # same preset, ``_causal_conv`` and the rest in XLA: d39dc12fa8834415 /
     # 5d9f5ba01f1e1cfd and c565f9e1f4f01b23 / fc707682eead3b4e
     "kimi-linear-48b-a3b+conv": ("714ab12e60457d2c", "afa5087dc07ad745"),
-    "qwen3-next-80b-a3b+conv": ("1311e8684a010228", "b257652905cef403"),
+    "qwen3-next-80b-a3b+conv": ("9e54b78b2fd24047", "b257652905cef403"),
 }
 CHUNKED = {"moe_experts": 64, "moe_top_k": 4, "moe_experts_held": 8}
 READOUT = {"d_model": 128, "vocab_size": 8192}
